@@ -42,8 +42,6 @@ CONFIG_DEFS: dict[str, tuple[type, Any, str]] = {
                                           "behind your own proxy"),
     "MAX_LINEAGE_BYTES": (int, 512 << 20, "lineage byte budget per worker; "
                                           "oldest entries evict past it"),
-    "WORKER_JAX_PLATFORMS": (str, "cpu", "JAX_PLATFORMS for spawned "
-                                         "workers"),
     # --- compiled graphs
     "DAG_BUFFER_SIZE": (int, 256 * 1024, "channel slot capacity (bytes)"),
     "DAG_MAX_BUFFERED": (int, 8, "max in-flight executions per DAG"),

@@ -530,9 +530,8 @@ def collective_bench(quick: bool = False) -> list[dict]:
     trials = 5
 
     # XLA path: psum over every device on the mesh (ICI on real TPUs).
+    from jax import shard_map
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-    from ray_tpu._private.jax_compat import shard_map
 
     mesh = Mesh(np.asarray(devs, object).reshape(world), ("x",))
     shards = jax.device_put(

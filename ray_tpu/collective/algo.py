@@ -344,9 +344,9 @@ def hierarchical_allreduce(
 
     import jax
     import jax.numpy as jnp
+    from jax import shard_map
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from ray_tpu._private.jax_compat import shard_map
     from ray_tpu.collective import codec
     from ray_tpu.collective.flight_recorder import (
         record_dcn_slices,

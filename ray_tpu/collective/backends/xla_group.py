@@ -30,9 +30,9 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ray_tpu._private.jax_compat import shard_map
 
 from ray_tpu.collective import algo as colalgo
 from ray_tpu.collective import codec

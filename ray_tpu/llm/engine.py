@@ -83,6 +83,11 @@ class LLMEngine:
         prefill_chunk: int | None = None,  # tokens per prefill chunk
         prefill_delay_s: float = 0.0,  # chaos: injected TTFT (tests)
     ):
+        from ray_tpu._private import chip
+
+        # Where this engine runs: the platform the worker's lease fixed
+        # (raises if a chip was promised and cannot be opened).
+        self.platform = chip.platform()
         cfg = PRESETS[model] if isinstance(model, str) else model
         self.cfg = cfg
         self.max_batch = max_batch
@@ -103,7 +108,7 @@ class LLMEngine:
 
         # Flash prefill on a bare TPU backend; under a mesh the dense
         # path keeps XLA's SPMD partitioner in charge.
-        use_flash = mesh is None and jax.default_backend() == "tpu"
+        use_flash = mesh is None and self.platform == "tpu"
         if speculate and kv != "paged":
             raise ValueError("speculative decoding needs kv='paged'")
         if prefill_chunk is not None and kv != "paged":
@@ -170,9 +175,7 @@ class LLMEngine:
             if env_flag in ("0", "1"):
                 use_kernel = env_flag == "1"
             else:
-                use_kernel = (
-                    mesh is None and jax.default_backend() == "tpu"
-                )
+                use_kernel = mesh is None and self.platform == "tpu"
             self.paged_attn_kernel = use_kernel
             # Chunked prefill: a prompt longer than the chunk is
             # prefilled one page-aligned chunk per step(), interleaved
@@ -779,6 +782,11 @@ class LLMEngine:
         chunked-prefill progress, and the pool/slot occupancy."""
         with self._lock:
             out = dict(self._stats)
+            out["platform"] = self.platform
+            out["device_kind"] = jax.devices()[0].device_kind
+            out["paged_attn_kernel"] = (
+                self.kv == "paged" and self.paged_attn_kernel
+            )
             out["active_requests"] = len(self._active)
             out["queued_requests"] = len(self._queue)
             out["prefilling"] = self._prefilling is not None

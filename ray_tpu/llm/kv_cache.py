@@ -19,6 +19,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from ray_tpu._private import chip
 from ray_tpu.models.llama import LlamaConfig, Params
 from ray_tpu.ops.attention import causal_attention
 from ray_tpu.ops.norms import rms_norm
@@ -91,7 +92,7 @@ def forward_prefill(
 
             # interpret mode runs the same kernel on CPU (tests).
             return flash_attention(
-                q, k, v, interpret=jax.default_backend() != "tpu"
+                q, k, v, interpret=chip.platform() != "tpu"
             )
         return causal_attention(q, k, v)
 

@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu._private import chip
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
@@ -435,7 +436,7 @@ def paged_verify(
             attn = paged_attention(
                 q, k_pool, v_pool, block_tables, positions,
                 n_kv_heads=cfg.n_kv_heads,
-                interpret=jax.default_backend() != "tpu",
+                interpret=chip.platform() != "tpu",
             )
         else:
             attn = _gather_page_attention(

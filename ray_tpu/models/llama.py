@@ -99,7 +99,7 @@ PRESETS: dict[str, LlamaConfig] = {
     # AND its q/k/v inputs across the remat boundary — the backward
     # replay skips the whole attention forward (kernel + projections +
     # RoPE). ~97 MB/layer of residuals; measured +10% step throughput
-    # over full remat on v5e (PROFILE_r04.md).
+    # over full remat on v5e.
     "bench": LlamaConfig(
         vocab_size=32768, d_model=1024, n_layers=24, n_heads=8, n_kv_heads=4,
         d_ff=4096, max_seq=2048, remat="flash_qkv",
@@ -244,7 +244,7 @@ def _dense_ffn_q8(h: jnp.ndarray, p: Params, cfg: LlamaConfig):
     """FFN whose gate-pre/up activations cross the remat boundary as
     int8: with their names pinned by the checkpoint policy, the
     backward replay skips BOTH [B,S,d]x[d,ff] forward matmuls
-    (PROFILE_r04 'int8 saved FFN activations' lever)."""
+    (the 'int8 saved FFN activations' lever)."""
     dt = cfg.dtype
     gate_pre = _int8_ckpt(h @ p["w_gate"].astype(dt), "ffn_gate")
     up = _int8_ckpt(h @ p["w_up"].astype(dt), "ffn_up")
@@ -349,7 +349,7 @@ def forward_with_aux(
     elif cfg.remat == "flash_qkv_ffn":
         # bf16-saved FFN activations (no quantization): same skipped
         # recompute as ffn8 at 2x the residual memory — OOM-bound at
-        # bench scale (PROFILE_r03/r04), kept for smaller models.
+        # bench scale (PROFILE_r03), kept for smaller models.
         if ffn_fn is _dense_ffn:
             ffn_fn = _dense_ffn_save
             body = partial(
@@ -366,8 +366,7 @@ def forward_with_aux(
     elif cfg.remat == "flash_qkv_ffn8":
         # "flash_qkv" plus int8-saved FFN activations: the replay skips
         # the two FFN up-projection matmuls too, from residuals stored
-        # at half the bf16 footprint (gate over loss parity — see
-        # PROFILE_r04).
+        # at half the bf16 footprint (gate over loss parity).
         if ffn_fn is _dense_ffn:
             ffn_fn = _dense_ffn_q8
             body = partial(

@@ -24,10 +24,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ray_tpu._private.jax_compat import shard_map
 
 _NEG_INF = float("-inf")
 # Finite mask value: exp(_MASK - m) underflows to exactly 0 for any
@@ -562,7 +562,10 @@ def make_flash_attention(mesh, batch_axes=("dp", "fsdp"), head_axis="tp"):
     for ray_tpu.models.llama.forward(attn_fn=...)."""
     from jax.sharding import PartitionSpec as P
 
-    interpret = jax.default_backend() != "tpu"
+    from ray_tpu._private import chip
+
+    # Interpreted only where JAX runs on the CPU (tests, dry runs).
+    interpret = chip.platform() != "tpu"
     spec = P(batch_axes, None, head_axis, None)
 
     def kernel(q, k, v):

@@ -22,9 +22,9 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu._private.jax_compat import shard_map
 
 StageFn = Callable[[Any, jnp.ndarray], jnp.ndarray]
 

@@ -20,9 +20,9 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu._private.jax_compat import shard_map
 
 from ray_tpu.ops.attention import _repeat_kv
 

@@ -14,9 +14,9 @@ from functools import partial
 from typing import Callable
 
 import jax
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu._private.jax_compat import shard_map
 
 from ray_tpu.ops.attention import causal_attention
 

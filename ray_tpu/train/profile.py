@@ -8,9 +8,10 @@ halves that join into one MFU decomposition:
 **Static (analytic)** — :func:`analyze_compiled` lowers a train step
 once and walks its optimized HLO (``_private/xla_profile.py``), bucketing
 every instruction into matmul / collective / elementwise_fusion /
-layout and pricing each bucket against a per-chip roofline: PEAK_FLOPS
-(telemetry's table) for math, the HBM_GBPS table for bytes, the
-ICI_GBPS table (with standard algorithm wire factors) for collectives.
+layout and pricing each bucket against a per-chip roofline from the one
+peak table (``_private/chip.py`` CHIP_SPECS): bf16 FLOP/s for math, HBM
+bandwidth for bytes, ICI bandwidth (with standard algorithm wire
+factors) for collectives.
 The result is an *analytic ideal step time* and per-category floors.
 Honesty caveat: these are cost-model numbers, not measurements —
 ``cost_analysis()``/HLO byte counts assume perfect fusion-boundary
@@ -50,51 +51,18 @@ CATEGORIES = (
     "unattributed",
 )
 
-# Peak HBM bandwidth per chip, GB/s, by TPU generation (public spec
-# sheets; the bandwidth analogue of telemetry.PEAK_FLOPS and
-# runtime/memory.DEVICE_HBM_GB).
-HBM_GBPS = {
-    "v5e": 819.0,
-    "v5litepod": 819.0,
-    "v5p": 2765.0,
-    "v4": 1228.0,
-    "v6e": 1638.0,
-}
-DEFAULT_HBM_GBPS = 819.0
-
-# Per-chip ICI bandwidth, GB/s (one-directional aggregate across links).
-ICI_GBPS = {
-    "v5e": 200.0,
-    "v5litepod": 200.0,
-    "v5p": 600.0,
-    "v4": 300.0,
-    "v6e": 448.0,
-}
-DEFAULT_ICI_GBPS = 200.0
-
-
-def _chip_table_lookup(table: dict[str, float], default: float) -> float:
-    try:
-        import jax
-
-        kind = jax.devices()[0].device_kind.lower().replace(" ", "")
-    # tpulint: allow(broad-except reason=device probing for a roofline denominator; any jax/backend failure falls back to the documented default rather than failing analysis)
-    except Exception:  # noqa: BLE001 - no jax/devices: documented default
-        return default
-    for name, value in table.items():
-        if name in kind:
-            return value
-    return default
-
-
 def hbm_bandwidth_per_chip() -> float:
-    """Peak HBM bytes/s of this host's chip generation."""
-    return _chip_table_lookup(HBM_GBPS, DEFAULT_HBM_GBPS) * 1e9
+    """Peak HBM bytes/s of this host's chip (_private/chip.py)."""
+    from ray_tpu._private import chip
+
+    return chip.local_chip_spec().hbm_bps
 
 
 def ici_bandwidth_per_chip() -> float:
-    """Peak ICI bytes/s of this host's chip generation."""
-    return _chip_table_lookup(ICI_GBPS, DEFAULT_ICI_GBPS) * 1e9
+    """Peak ICI bytes/s of this host's chip (_private/chip.py)."""
+    from ray_tpu._private import chip
+
+    return chip.local_chip_spec().ici_bps
 
 
 def collective_wire_factor(op: str, group: int | None) -> float:

@@ -30,17 +30,6 @@ import time
 
 from ray_tpu.util.metrics import Counter, Gauge, Histogram
 
-# Peak bf16 FLOP/s per chip by TPU generation (public spec sheets; the
-# same table bench.py uses for its vs_baseline normalization).
-PEAK_FLOPS = {
-    "v5e": 197e12,
-    "v5litepod": 197e12,
-    "v5p": 459e12,
-    "v4": 275e12,
-    "v6e": 918e12,
-}
-DEFAULT_PEAK_FLOPS = 197e12
-
 STEP_PHASE_SECONDS = Histogram(
     "ray_tpu_train_step_phase_seconds",
     "train step time by phase ('step' = the whole step)",
@@ -76,17 +65,11 @@ def telemetry_enabled() -> bool:
 
 
 def peak_flops_per_chip() -> float:
-    try:
-        import jax
+    """Published bf16 peak of this process's chip (_private/chip.py:
+    an unknown TPU raises; a CPU rig gets the v5e figure)."""
+    from ray_tpu._private import chip
 
-        kind = jax.devices()[0].device_kind.lower().replace(" ", "")
-    # tpulint: allow(broad-except reason=device probing for an MFU denominator; any jax/backend failure falls back to the documented proxy peak rather than failing the step)
-    except Exception:  # noqa: BLE001 - no jax/devices: proxy peak
-        return DEFAULT_PEAK_FLOPS
-    for name, flops in PEAK_FLOPS.items():
-        if name in kind:
-            return flops
-    return DEFAULT_PEAK_FLOPS
+    return chip.local_chip_spec().bf16_flops
 
 
 class _NoopPhase:

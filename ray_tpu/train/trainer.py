@@ -229,17 +229,7 @@ class TrainWorker:
     ):
         import os
 
-        # The JAX platform override must be applied by this process (the
-        # node manager only bakes env into *newly spawned* workers): an
-        # empty value means "let jax pick the TPU runtime", anything else
-        # pins the named platform.
-        jax_platform = backend_env.pop("RAY_TPU_WORKER_JAX_PLATFORMS", None)
         os.environ.update(backend_env)
-        if jax_platform is not None:
-            if jax_platform:
-                os.environ["JAX_PLATFORMS"] = jax_platform
-            else:
-                os.environ.pop("JAX_PLATFORMS", None)
         # RAY_TPU_SANITIZE=1: install the jit-discipline twins (compile
         # watch + host-sync tracer) BEFORE any jax.jit in this process,
         # so the flagship train step itself is under the watch.
@@ -741,10 +731,6 @@ class JaxTrainer:
         }
         if self.scaling.topology:
             env["TPU_TOPOLOGY"] = self.scaling.topology
-        if self.scaling.use_tpu:
-            # TPU workers own the chip runtime; everything else stays on
-            # the JAX CPU backend so it never contends for the slice.
-            env["RAY_TPU_WORKER_JAX_PLATFORMS"] = ""
         # Attempt is always exposed (not only for distributed) so train
         # loops can scope their own collective groups per attempt.
         env["RAY_TPU_TRAIN_ATTEMPT"] = str(attempt)

@@ -123,8 +123,7 @@ def test_sharded_matches_single_device(mesh8):
 def test_ffn_checkpoint_remat_modes_match_full():
     """flash_qkv_ffn / flash_qkv_ffn8 numerics: the saved-activation
     (and int8-quantized) FFN paths must match remat=full to bf16-level
-    (exact for bf16-saved; small bounded quantization error for int8 —
-    PROFILE_r04 records both modes' measured TPU throughput)."""
+    (exact for bf16-saved; small bounded quantization error for int8)."""
     import dataclasses
 
     from ray_tpu.models.llama import forward_with_aux

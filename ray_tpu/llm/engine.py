@@ -11,6 +11,12 @@ Slot model: the KV cache holds `max_batch` rows. add_request() parks
 requests in a FIFO; step() admits queued requests into free slots
 (one prefill each, bucketed to power-of-two lengths to bound compile
 count) and then advances all active slots with one decode program.
+
+Host phases are `jax.profiler.TraceAnnotation` spans (`engine:step` and
+its children, `engine:add_request`, `engine:abort_request`): with a
+profiler session open they land in the trace's host plane, on the
+device events' clock; with none each is one flag test. README "Serve
+observability" lists them.
 """
 
 from __future__ import annotations
@@ -18,6 +24,7 @@ from __future__ import annotations
 import itertools
 import threading
 import time
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 from functools import partial
 from typing import Any
@@ -25,6 +32,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ray_tpu.llm.kv_cache import forward_decode, forward_prefill, init_kv_cache
 from ray_tpu.models.llama import LlamaConfig, PRESETS, init_params, param_logical_axes
@@ -85,6 +93,7 @@ class LLMEngine:
     ):
         from ray_tpu._private import chip
 
+        init_began = time.perf_counter()
         # Where this engine runs: the platform the worker's lease fixed
         # (raises if a chip was promised and cannot be opened).
         self.platform = chip.platform()
@@ -242,9 +251,34 @@ class LLMEngine:
             "requests_aborted": 0,
             "preemptions": 0,
             "prefill_chunks": 0,
+            # The engine loop's own account (PERF.md's span-and-counter
+            # table): occupancy = slot_steps / (decode_steps * max_batch);
+            # the two sums are seconds requests waited for a slot and
+            # add/abort callers waited for `_lock`.
+            "steps": 0,
+            "decode_steps": 0,
+            "slot_steps": 0,
+            "admitted": 0,
+            "queue_wait_s_sum": 0.0,
+            "lock_wait_s_sum": 0.0,
+            "init_s": time.perf_counter() - init_began,
         }
 
     # ------------------------------------------------------ request API
+    @contextmanager
+    def _locked(self, span, request_id: str):
+        """`_lock` for a caller on the replica's event loop. step() holds
+        it for a whole step, the wait for the device included, so what
+        the caller waited goes on its span and into `lock_wait_s_sum`."""
+        began = time.perf_counter()
+        with self._lock:
+            waited = time.perf_counter() - began
+            self._stats["lock_wait_s_sum"] += waited
+            span.set_metadata(
+                rid=request_id, lock_wait_ms=round(waited * 1e3, 3)
+            )
+            yield
+
     def add_request(
         self,
         prompt: list[int],
@@ -252,47 +286,56 @@ class LLMEngine:
         request_id: str | None = None,
         stream: bool = False,
     ) -> str:
-        if len(prompt) >= self.max_seq:
-            raise ValueError(
-                f"prompt length {len(prompt)} >= max_seq {self.max_seq}"
-            )
-        sampling = sampling or SamplingParams()
-        if self.kv == "paged":
-            # Reject requests the pool could NEVER hold (prompt plus its
-            # full max_tokens growth) at submission — admitting one and
-            # crashing mid-decode would take every in-flight request
-            # down with it.
-            P = self.page_size
-            worst = min(len(prompt) + sampling.max_tokens, self.max_seq)
-            pad = min(
-                max(_bucket(worst), P), self.max_pages_per_seq * P
-            )
-            if pad // P > self.alloc.num_pages:
+        with TraceAnnotation(
+            "engine:add_request", prompt_len=len(prompt)
+        ) as span:
+            if len(prompt) >= self.max_seq:
                 raise ValueError(
-                    f"prompt+max_tokens needs {pad // P} pages but the "
-                    f"pool holds {self.alloc.num_pages}; raise num_pages "
-                    "or lower max_tokens"
+                    f"prompt length {len(prompt)} >= max_seq {self.max_seq}"
                 )
-        rid = request_id or f"req-{next(self._ids)}"
-        with self._lock:
-            self._stats["requests_submitted"] += 1
-            if stream:
-                self._stream_ids.add(rid)
-            self._queue.append(
-                _Request(
-                    rid, list(prompt), sampling,
-                    submit_ts=time.time(),
+            sampling = sampling or SamplingParams()
+            if self.kv == "paged":
+                # Reject requests the pool could NEVER hold (prompt plus
+                # its full max_tokens growth) at submission — admitting
+                # one and crashing mid-decode would take every in-flight
+                # request down with it.
+                P = self.page_size
+                worst = min(len(prompt) + sampling.max_tokens, self.max_seq)
+                pad = min(
+                    max(_bucket(worst), P), self.max_pages_per_seq * P
                 )
+                if pad // P > self.alloc.num_pages:
+                    raise ValueError(
+                        f"prompt+max_tokens needs {pad // P} pages but the "
+                        f"pool holds {self.alloc.num_pages}; raise "
+                        "num_pages or lower max_tokens"
+                    )
+            rid = request_id or f"req-{next(self._ids)}"
+            # Stamped before the wait for `_lock`, so that queue_s and
+            # ttft_s count it.
+            req = _Request(
+                rid, list(prompt), sampling, submit_ts=time.time()
             )
+            with self._locked(span, rid):
+                self._stats["requests_submitted"] += 1
+                if stream:
+                    self._stream_ids.add(rid)
+                self._queue.append(req)
         return rid
 
-    def _begin_prefill(self, req: _Request) -> None:
-        """Mark prefill start (first-write-wins) and apply the injected
-        prefill delay (the ``prefill_delay_s`` engine kwarg, or the
-        RAY_TPU_LLM_PREFILL_DELAY env knob) — a deterministic TTFT
-        injection the serve-tracing tests bound spans against."""
+    def _begin_prefill(self, req: _Request, span) -> None:
+        """Mark prefill start (first-write-wins, so a preemption's
+        re-admission counts neither as admitted nor as queue wait again)
+        and apply the injected prefill delay (the ``prefill_delay_s``
+        engine kwarg, or the RAY_TPU_LLM_PREFILL_DELAY env knob) — a
+        deterministic TTFT injection the serve-tracing tests bound spans
+        against. ``span`` is the admission's `engine:admit`."""
         if req.prefill_start_ts == 0.0:
             req.prefill_start_ts = time.time()
+            waited = max(0.0, req.prefill_start_ts - req.submit_ts)
+            self._stats["admitted"] += 1
+            self._stats["queue_wait_s_sum"] += waited
+            span.set_metadata(queue_ms=round(waited * 1e3, 3))
         delay = self.prefill_delay_s
         if delay <= 0:
             from ray_tpu._private import config
@@ -389,16 +432,22 @@ class LLMEngine:
                     return
                 continue
             req = self._queue.pop(0)
-            slot = self._free.pop(0)
-            self._begin_prefill(req)
-            pad = min(_bucket(len(req.prompt)), self.max_seq)
-            tokens = np.zeros((1, pad), np.int32)
-            tokens[0, : len(req.prompt)] = req.prompt
-            logits, self.cache = self._prefill(
-                self.params, jnp.asarray(tokens), self.cache,
-                jnp.int32(slot),
-            )
-            self._post_prefill(req, slot, logits, len(req.prompt), finished)
+            with TraceAnnotation(
+                "engine:admit", rid=req.request_id,
+                prompt_len=len(req.prompt),
+            ) as span:
+                slot = self._free.pop(0)
+                self._begin_prefill(req, span)
+                pad = min(_bucket(len(req.prompt)), self.max_seq)
+                tokens = np.zeros((1, pad), np.int32)
+                tokens[0, : len(req.prompt)] = req.prompt
+                logits, self.cache = self._prefill(
+                    self.params, jnp.asarray(tokens), self.cache,
+                    jnp.int32(slot),
+                )
+                self._post_prefill(
+                    req, slot, logits, len(req.prompt), finished
+                )
 
     def _post_prefill(
         self, req, slot, logits, ctx_len, finished, logit_idx=None
@@ -409,29 +458,31 @@ class LLMEngine:
         any tokens generated before a preemption. logit_idx overrides
         the row to sample from (chunked prefill: the last token's index
         LOCAL to the final chunk)."""
-        last = np.asarray(
-            logits[0, ctx_len - 1 if logit_idx is None else logit_idx]
-        )
-        req.slot = slot
-        req.position = ctx_len
-        if req.first_token_ts == 0.0:
-            req.first_token_ts = time.time()
-        req.last_token = self._sample(last, req.sampling)
-        self._stats["tokens_generated"] += 1  # the prefill-sampled token
-        req.out_tokens.append(req.last_token)
-        if req.request_id in self._stream_ids:
-            self._deltas.setdefault(req.request_id, []).append(
-                req.last_token
+        with TraceAnnotation("engine:first_token", rid=req.request_id):
+            # The host waits here for the prefill program.
+            last = np.asarray(
+                logits[0, ctx_len - 1 if logit_idx is None else logit_idx]
             )
-        self._active[slot] = req
-        # The prefill-sampled token can already hit max_tokens=1 or a
-        # stop token; finishing here frees the slot for this _admit
-        # loop itself.
-        if not self._finish_if_done(req, finished):
-            self._tokens[slot, 0] = req.last_token
-            self._positions[slot] = req.position
-            if self.kv == "paged":
-                self._temps[slot] = req.sampling.temperature
+            req.slot = slot
+            req.position = ctx_len
+            if req.first_token_ts == 0.0:
+                req.first_token_ts = time.time()
+            req.last_token = self._sample(last, req.sampling)
+            self._stats["tokens_generated"] += 1  # the prefill-sampled token
+            req.out_tokens.append(req.last_token)
+            if req.request_id in self._stream_ids:
+                self._deltas.setdefault(req.request_id, []).append(
+                    req.last_token
+                )
+            self._active[slot] = req
+            # The prefill-sampled token can already hit max_tokens=1 or a
+            # stop token; finishing here frees the slot for this _admit
+            # loop itself.
+            if not self._finish_if_done(req, finished):
+                self._tokens[slot, 0] = req.last_token
+                self._positions[slot] = req.position
+                if self.kv == "paged":
+                    self._temps[slot] = req.sampling.temperature
 
     def _admit_one_paged(self, finished: list[dict]) -> bool:
         """Admit the head of the queue if its pages fit the pool —
@@ -445,76 +496,85 @@ class LLMEngine:
             return False
         P = self.page_size
         req = self._queue[0]
-        # Full context: the prompt plus anything generated before a
-        # preemption (recompute-style resume). req.prompt stays pristine.
-        context = list(req.prompt) + list(req.out_tokens)
-        pad = min(
-            max(_bucket(len(context)), P),
-            self.max_pages_per_seq * P,
-        )
-        need_pages = pad // P
-        # Prefix sharing: leading FULL pages whose token prefix matches a
-        # live page are reused (refcounted), not re-allocated.
-        hashes = prefix_hashes(context, P)
-        shared: list[int] = []
-        for h in hashes:
-            pg = self.alloc.lookup_prefix(h)
-            if pg is None:
-                break
-            shared.append(pg)
-        if need_pages > self.alloc.num_pages:
-            # Would never fit even with the pool empty — a config error,
-            # not backpressure; failing loud beats spinning forever.
-            self._queue.pop(0)
-            raise RuntimeError(
-                f"prompt needs {need_pages} pages but the pool holds "
-                f"{self.alloc.num_pages}; raise num_pages or page_size"
+        # An attempt the pool turns away is a span too (the prefix
+        # lookup is host time), without the attributes set below.
+        with TraceAnnotation(
+            "engine:admit", rid=req.request_id, prompt_len=len(req.prompt)
+        ) as span:
+            # Full context: the prompt plus anything generated before a
+            # preemption (recompute-style resume). req.prompt stays
+            # pristine.
+            context = list(req.prompt) + list(req.out_tokens)
+            pad = min(
+                max(_bucket(len(context)), P),
+                self.max_pages_per_seq * P,
             )
-        if need_pages - len(shared) > self.alloc.free_pages:
-            return False
-        self._queue.pop(0)
-        slot = self._free.pop(0)
-        self._begin_prefill(req)
-        pages = [self.alloc.share(pg) for pg in shared]
-        for i in range(len(shared), need_pages):
-            pg = self.alloc.alloc()
-            if i < len(hashes):
-                self.alloc.register_prefix(hashes[i], pg)
-            pages.append(pg)
-        req.pages = pages
-        if (
-            self.prefill_chunk is not None
-            and len(context) > self.prefill_chunk
-        ):
-            # Long prompt: hold the slot and prefill one chunk per
-            # step(), interleaved with decode. Chunks cover only the
-            # context's own pages (ceil(ctx/P)); the bucket's growth
-            # pages stay unwritten until decode reaches them.
-            self._prefilling = {
-                "req": req,
-                "slot": slot,
-                "context": context,
-                "pages": np.asarray(pages, np.int32),
-                "next_start": 0,
-                "ctx_pad": -(-len(context) // P) * P,
-                "need_pages": need_pages,
-            }
-            self._prefill_step(finished)
+            need_pages = pad // P
+            # Prefix sharing: leading FULL pages whose token prefix
+            # matches a live page are reused (refcounted), not
+            # re-allocated.
+            hashes = prefix_hashes(context, P)
+            shared: list[int] = []
+            for h in hashes:
+                pg = self.alloc.lookup_prefix(h)
+                if pg is None:
+                    break
+                shared.append(pg)
+            if need_pages > self.alloc.num_pages:
+                # Would never fit even with the pool empty — a config
+                # error, not backpressure; failing loud beats spinning
+                # forever.
+                self._queue.pop(0)
+                raise RuntimeError(
+                    f"prompt needs {need_pages} pages but the pool holds "
+                    f"{self.alloc.num_pages}; raise num_pages or page_size"
+                )
+            if need_pages - len(shared) > self.alloc.free_pages:
+                return False
+            self._queue.pop(0)
+            slot = self._free.pop(0)
+            span.set_metadata(pages=need_pages, pages_shared=len(shared))
+            self._begin_prefill(req, span)
+            pages = [self.alloc.share(pg) for pg in shared]
+            for i in range(len(shared), need_pages):
+                pg = self.alloc.alloc()
+                if i < len(hashes):
+                    self.alloc.register_prefix(hashes[i], pg)
+                pages.append(pg)
+            req.pages = pages
+            if (
+                self.prefill_chunk is not None
+                and len(context) > self.prefill_chunk
+            ):
+                # Long prompt: hold the slot and prefill one chunk per
+                # step(), interleaved with decode. Chunks cover only the
+                # context's own pages (ceil(ctx/P)); the bucket's growth
+                # pages stay unwritten until decode reaches them.
+                self._prefilling = {
+                    "req": req,
+                    "slot": slot,
+                    "context": context,
+                    "pages": np.asarray(pages, np.int32),
+                    "next_start": 0,
+                    "ctx_pad": -(-len(context) // P) * P,
+                    "need_pages": need_pages,
+                }
+                self._prefill_step(finished)
+                return True
+            tokens = np.zeros((1, pad), np.int32)
+            tokens[0, : len(context)] = context
+            # Prefill rewrites shared pages with byte-identical values
+            # (K/V at position i depend only on tokens <= i) —
+            # idempotent, so no write mask is needed.
+            logits, self.cache = self._prefill_paged(
+                self.params,
+                jnp.asarray(tokens),
+                self.cache,
+                jnp.asarray(np.asarray(pages, np.int32)),
+                n_write_pages=need_pages,
+            )
+            self._post_prefill(req, slot, logits, len(context), finished)
             return True
-        tokens = np.zeros((1, pad), np.int32)
-        tokens[0, : len(context)] = context
-        # Prefill rewrites shared pages with byte-identical values (K/V
-        # at position i depend only on tokens <= i) — idempotent, so no
-        # write mask is needed.
-        logits, self.cache = self._prefill_paged(
-            self.params,
-            jnp.asarray(tokens),
-            self.cache,
-            jnp.asarray(np.asarray(pages, np.int32)),
-            n_write_pages=need_pages,
-        )
-        self._post_prefill(req, slot, logits, len(context), finished)
-        return True
 
     def _prefill_step(self, finished: list[dict]) -> None:
         """Advance the in-flight chunked prefill by ONE chunk; on the
@@ -525,33 +585,47 @@ class LLMEngine:
         context = st["context"]
         start = st["next_start"]
         end = min(start + self.prefill_chunk, st["ctx_pad"])
-        tokens = np.zeros((1, end - start), np.int32)
-        valid = context[start: min(end, len(context))]
-        tokens[0, : len(valid)] = valid
-        logits, self.cache = self._prefill_chunk_fn(
-            self.params,
-            jnp.asarray(tokens),
-            self.cache,
-            jnp.asarray(st["pages"]),
-            jnp.int32(start),
-            n_write_pages=st["need_pages"],
-            chunk_pages=(end - start) // P,
-        )
-        st["next_start"] = end
-        self._stats["prefill_chunks"] += 1
-        if end >= st["ctx_pad"]:
-            self._prefilling = None
-            # ctx_len-1 always falls in the final chunk: ctx_pad is
-            # page-aligned, so ctx_pad - len(context) < P <= chunk.
-            self._post_prefill(
-                st["req"], st["slot"], logits, len(context), finished,
-                logit_idx=len(context) - 1 - start,
+        with TraceAnnotation(
+            "engine:prefill_chunk", rid=st["req"].request_id,
+            start=start, tokens=end - start,
+        ):
+            tokens = np.zeros((1, end - start), np.int32)
+            valid = context[start: min(end, len(context))]
+            tokens[0, : len(valid)] = valid
+            logits, self.cache = self._prefill_chunk_fn(
+                self.params,
+                jnp.asarray(tokens),
+                self.cache,
+                jnp.asarray(st["pages"]),
+                jnp.int32(start),
+                n_write_pages=st["need_pages"],
+                chunk_pages=(end - start) // P,
             )
+            st["next_start"] = end
+            self._stats["prefill_chunks"] += 1
+            if end >= st["ctx_pad"]:
+                self._prefilling = None
+                # ctx_len-1 always falls in the final chunk: ctx_pad is
+                # page-aligned, so ctx_pad - len(context) < P <= chunk.
+                self._post_prefill(
+                    st["req"], st["slot"], logits, len(context), finished,
+                    logit_idx=len(context) - 1 - start,
+                )
 
     def step(self) -> list[dict]:
         """Admit + one decode step. Returns finished request dicts."""
         finished: list[dict] = []
-        with self._lock:
+        # The span begins before the wait for `_lock`; its attributes are
+        # the engine's state as the step found it.
+        with TraceAnnotation(
+            "engine:step",
+            step=self._stats["steps"],
+            active=len(self._active),
+            queued=len(self._queue),
+            prefilling=int(self._prefilling is not None),
+            max_batch=self.max_batch,
+        ), self._lock:
+            self._stats["steps"] += 1
             if self._prefilling is not None:
                 # Continue the in-flight chunked prefill: one chunk per
                 # step bounds the stall it adds to this step's decodes.
@@ -563,17 +637,37 @@ class LLMEngine:
                 self._step_paged(finished)
                 return finished
 
-            logits, self.cache = self._decode(
-                self.params,
-                jnp.asarray(self._tokens),
-                self.cache,
-                jnp.asarray(self._positions),
-            )
-            logits = np.asarray(logits)
-            for slot, req in list(self._active.items()):
-                tok = self._sample(logits[slot], req.sampling)
-                self._record_token(req, tok, finished)
+            self._count_decode_step()
+            with TraceAnnotation("engine:decode_dispatch"):
+                logits, self.cache = self._decode(
+                    self.params,
+                    jnp.asarray(self._tokens),
+                    self.cache,
+                    jnp.asarray(self._positions),
+                )
+            with TraceAnnotation("engine:decode_sync"):
+                logits = np.asarray(logits)
+            with self._emit_span(finished):
+                for slot, req in list(self._active.items()):
+                    tok = self._sample(logits[slot], req.sampling)
+                    self._record_token(req, tok, finished)
         return finished
+
+    def _count_decode_step(self) -> None:
+        self._stats["decode_steps"] += 1
+        self._stats["slot_steps"] += len(self._active)
+
+    @contextmanager
+    def _emit_span(self, finished: list[dict]):
+        """`engine:emit` around a step's `_record_token` loop, with what
+        the loop emitted and finished."""
+        with TraceAnnotation("engine:emit") as span:
+            tokens, done = self._stats["tokens_generated"], len(finished)
+            yield
+            span.set_metadata(
+                tokens=self._stats["tokens_generated"] - tokens,
+                finished=len(finished) - done,
+            )
 
     def _record_token(self, req, tok: int, finished: list[dict]) -> None:
         req.position += 1
@@ -600,81 +694,95 @@ class LLMEngine:
         req.slot = -1
         self._queue.insert(0, req)
 
+    @staticmethod
+    def _host_sampled(s: SamplingParams) -> bool:
+        """top-k needs host logic on the [B, V] logits. (top_k with
+        temperature 0 IS greedy — the on-device argmax already answered
+        it; don't ship the logits for it.)"""
+        return bool(s.top_k) and s.temperature > 0
+
     def _step_paged(self, finished: list[dict]) -> None:
         P = self.page_size
         K = 1 + self.speculate
-        # Grow block tables to cover every position this step may write
-        # ([position, position + K - 1] with speculation); exhausted
-        # pool → preempt the youngest active request until pages fit.
-        for slot, req in list(self._active.items()):
-            if req.slot == -1 or req.done:
-                continue
-            # Clamp to the table width: near max_seq a K-wide step may
-            # reach past capacity — the kernel routes those writes to
-            # the dump page and _finish_if_done stops the request at
-            # max_seq before any overflow token is kept.
-            needed = min(
-                (req.position + K - 1) // P + 1, self.max_pages_per_seq
+        with TraceAnnotation("engine:grow_tables") as span:
+            preempted = self._stats["preemptions"]
+            # Grow block tables to cover every position this step may
+            # write ([position, position + K - 1] with speculation);
+            # exhausted pool → preempt the youngest active request until
+            # pages fit.
+            for slot, req in list(self._active.items()):
+                if req.slot == -1 or req.done:
+                    continue
+                # Clamp to the table width: near max_seq a K-wide step
+                # may reach past capacity — the kernel routes those
+                # writes to the dump page and _finish_if_done stops the
+                # request at max_seq before any overflow token is kept.
+                needed = min(
+                    (req.position + K - 1) // P + 1, self.max_pages_per_seq
+                )
+                while len(req.pages) < needed and req.slot != -1:
+                    if self.alloc.free_pages == 0:
+                        victims = [
+                            r for r in self._active.values() if r is not req
+                        ]
+                        if not victims:
+                            self._preempt(req)
+                            break
+                        self._preempt(victims[-1])
+                    else:
+                        req.pages.append(self.alloc.alloc())
+            span.set_metadata(
+                preempted=self._stats["preemptions"] - preempted
             )
-            while len(req.pages) < needed and req.slot != -1:
-                if self.alloc.free_pages == 0:
-                    victims = [
-                        r for r in self._active.values() if r is not req
-                    ]
-                    if not victims:
-                        self._preempt(req)
-                        break
-                    self._preempt(victims[-1])
-                else:
-                    req.pages.append(self.alloc.alloc())
-        if not self._active:
-            return
+            if not self._active:
+                return
 
-        tables = np.full(
-            (self.max_batch, self.max_pages_per_seq), -1, np.int32
-        )
-        for slot, req in self._active.items():
-            tables[slot, : len(req.pages)] = req.pages
-        self._step_key, sub = jax.random.split(self._step_key)
+            tables = np.full(
+                (self.max_batch, self.max_pages_per_seq), -1, np.int32
+            )
+            for slot, req in self._active.items():
+                tables[slot, : len(req.pages)] = req.pages
+            self._step_key, sub = jax.random.split(self._step_key)
+            if self.speculate:
+                toks, draft_len = self._propose_drafts()
+        self._count_decode_step()
         if self.speculate:
-            self._step_paged_speculative(tables, sub, finished)
+            self._step_paged_speculative(
+                tables, sub, toks, draft_len, finished
+            )
             return
-        sampled, logits, self.cache = self._decode_paged(
-            self.params,
-            jnp.asarray(self._tokens),
-            self.cache,
-            jnp.asarray(tables),
-            jnp.asarray(self._positions),
-            jnp.asarray(self._temps),
-            sub,
-        )
-        sampled = np.asarray(sampled)  # [B] ints — the only transfer
-        host_logits = None
-        for slot, req in list(self._active.items()):
-            if req.sampling.top_k and req.sampling.temperature > 0:
-                # top-k needs host logic; transfer logits lazily, once.
-                # (top_k with temperature 0 IS greedy — the on-device
-                # argmax already answered it; don't ship [B,V] for it.)
-                if host_logits is None:
-                    host_logits = np.asarray(logits)
-                tok = self._sample(host_logits[slot], req.sampling)
-            else:
-                tok = int(sampled[slot])
-            self._record_token(req, tok, finished)
+        with TraceAnnotation("engine:decode_dispatch"):
+            sampled, logits, self.cache = self._decode_paged(
+                self.params,
+                jnp.asarray(self._tokens),
+                self.cache,
+                jnp.asarray(tables),
+                jnp.asarray(self._positions),
+                jnp.asarray(self._temps),
+                sub,
+            )
+        with TraceAnnotation("engine:decode_sync"):
+            sampled = np.asarray(sampled)  # [B] ints
+            host_logits = self._host_logits(logits)
+        with self._emit_span(finished):
+            for slot, req in list(self._active.items()):
+                if self._host_sampled(req.sampling):
+                    tok = self._sample(host_logits[slot], req.sampling)
+                else:
+                    tok = int(sampled[slot])
+                self._record_token(req, tok, finished)
 
-    def _step_paged_speculative(self, tables, sub, finished) -> None:
-        """Prompt-lookup speculative step (reference capability: vLLM
-        speculative decoding behind ray.llm): verify K = 1 + speculate
-        positions per slot in one dispatch and accept the longest
-        draft prefix the model agrees with. Greedy slots accept on
-        argmax equality (bit-identical to plain decode); stochastic
-        slots use exact rejection sampling computed on device (see
-        paged_kv.paged_verify) so their emitted stream is distributed
-        exactly as plain temperature sampling. top_k slots run with an
-        empty draft (their position-0 output is a normal decode step).
+    def _host_logits(self, logits) -> np.ndarray | None:
+        """The [B, V] logits on the host, only if a slot samples there."""
+        if any(self._host_sampled(r.sampling) for r in self._active.values()):
+            return np.asarray(logits)
+        return None
 
-        Acceptance is one vectorized mismatch-argmax over [B, K-1] —
-        not a per-slot interpreted loop on the serial dispatch path."""
+    def _propose_drafts(self) -> tuple[np.ndarray, np.ndarray]:
+        """Prompt-lookup drafts for a speculative step: ``toks [B, K]``
+        (column 0 the last token, then the draft) and ``draft_len [B]``.
+        top_k slots run with an empty draft (their position-0 output is
+        a normal decode step)."""
         from ray_tpu.llm.paged_kv import propose_ngram_draft
 
         K = 1 + self.speculate
@@ -682,7 +790,7 @@ class LLMEngine:
         toks[:, 0] = self._tokens[:, 0]
         draft_len = np.zeros((self.max_batch,), np.int32)
         for slot, req in self._active.items():
-            if req.sampling.top_k and req.sampling.temperature > 0:
+            if self._host_sampled(req.sampling):
                 continue  # host-sampled: no draft
             draft = propose_ngram_draft(
                 req.prompt + req.out_tokens, K - 1
@@ -691,7 +799,23 @@ class LLMEngine:
                 draft_len[slot] = len(draft)
                 self._stats["draft_tokens_proposed"] += len(draft)
                 toks[slot, 1: 1 + len(draft)] = draft
+        return toks, draft_len
 
+    def _step_paged_speculative(
+        self, tables, sub, toks, draft_len, finished
+    ) -> None:
+        """Prompt-lookup speculative step (reference capability: vLLM
+        speculative decoding behind ray.llm): verify K = 1 + speculate
+        positions per slot in one dispatch and accept the longest
+        draft prefix the model agrees with. Greedy slots accept on
+        argmax equality (bit-identical to plain decode); stochastic
+        slots use exact rejection sampling computed on device (see
+        paged_kv.paged_verify) so their emitted stream is distributed
+        exactly as plain temperature sampling.
+
+        Acceptance is one vectorized mismatch-argmax over [B, K-1] —
+        not a per-slot interpreted loop on the serial dispatch path."""
+        K = 1 + self.speculate
         # Static flag: an all-greedy batch (the common speculative
         # configuration) skips the rejection-sampling tensors entirely
         # — at most two compiled variants, like use_kernel.
@@ -699,56 +823,60 @@ class LLMEngine:
             r.sampling.temperature > 0 and not r.sampling.top_k
             for r in self._active.values()
         )
-        sampled, accept, rej, logits, self.cache = self._verify_paged(
-            self.params,
-            jnp.asarray(toks),
-            self.cache,
-            jnp.asarray(tables),
-            jnp.asarray(self._positions),
-            jnp.asarray(self._temps),
-            sub,
-            stochastic=any_stochastic,
-        )
-        sampled = np.asarray(sampled)  # [B, K]
-        accept = np.asarray(accept)  # [B, K-1] bool
-        rej = np.asarray(rej)  # [B, K-1]
-        # Vectorized acceptance: n_acc[b] = index of the first rejected
-        # (or absent) draft position.
-        stop = ~accept
-        stop |= np.arange(K - 1)[None, :] >= draft_len[:, None]
-        n_acc = np.where(stop.any(axis=1), stop.argmax(axis=1), K - 1)
-        host_logits = None
-        for slot, req in list(self._active.items()):
-            if req.sampling.top_k and req.sampling.temperature > 0:
-                if host_logits is None:
-                    host_logits = np.asarray(logits)  # [B, V]: pos 0
-                tok = self._sample(host_logits[slot], req.sampling)
-                self._record_token(req, tok, finished)
-                continue
-            na = int(n_acc[slot])
-            # Accepted drafts verbatim, then the boundary token: the
-            # residual sample if a draft was REJECTED there, the full-p
-            # sample if the draft simply ran out (or none existed).
-            emit = list(toks[slot, 1: 1 + na])
-            if na < draft_len[slot]:
-                emit.append(int(rej[slot, na]))
-            else:
-                emit.append(int(sampled[slot, na]))
-            for idx, tok in enumerate(emit):
-                self._record_token(req, int(tok), finished)
-                if idx < na:
-                    # Count acceptance by tokens actually EMITTED —
-                    # verified drafts discarded when the request
-                    # finishes mid-emit must not inflate the rate.
-                    self._stats["draft_tokens_accepted"] += 1
-                if req.done:
-                    break
+        with TraceAnnotation("engine:decode_dispatch"):
+            sampled, accept, rej, logits, self.cache = self._verify_paged(
+                self.params,
+                jnp.asarray(toks),
+                self.cache,
+                jnp.asarray(tables),
+                jnp.asarray(self._positions),
+                jnp.asarray(self._temps),
+                sub,
+                stochastic=any_stochastic,
+            )
+        with TraceAnnotation("engine:decode_sync"):
+            sampled = np.asarray(sampled)  # [B, K]
+            accept = np.asarray(accept)  # [B, K-1] bool
+            rej = np.asarray(rej)  # [B, K-1]
+            host_logits = self._host_logits(logits)  # [B, V]: pos 0
+        with self._emit_span(finished):
+            # Vectorized acceptance: n_acc[b] = index of the first
+            # rejected (or absent) draft position.
+            stop = ~accept
+            stop |= np.arange(K - 1)[None, :] >= draft_len[:, None]
+            n_acc = np.where(stop.any(axis=1), stop.argmax(axis=1), K - 1)
+            for slot, req in list(self._active.items()):
+                if self._host_sampled(req.sampling):
+                    tok = self._sample(host_logits[slot], req.sampling)
+                    self._record_token(req, tok, finished)
+                    continue
+                na = int(n_acc[slot])
+                # Accepted drafts verbatim, then the boundary token: the
+                # residual sample if a draft was REJECTED there, the
+                # full-p sample if the draft simply ran out (or none
+                # existed).
+                emit = list(toks[slot, 1: 1 + na])
+                if na < draft_len[slot]:
+                    emit.append(int(rej[slot, na]))
+                else:
+                    emit.append(int(sampled[slot, na]))
+                for idx, tok in enumerate(emit):
+                    self._record_token(req, int(tok), finished)
+                    if idx < na:
+                        # Count acceptance by tokens actually EMITTED —
+                        # verified drafts discarded when the request
+                        # finishes mid-emit must not inflate the rate.
+                        self._stats["draft_tokens_accepted"] += 1
+                    if req.done:
+                        break
 
     def abort_request(self, request_id: str) -> bool:
         """Drop a request (queued or active), freeing its slot — the
         client-disconnect path for streaming (reference: vLLM engine
         abort_request). Safe to call after completion (returns False)."""
-        with self._lock:
+        with TraceAnnotation("engine:abort_request") as span, self._locked(
+            span, request_id
+        ):
             self._stream_ids.discard(request_id)
             self._deltas.pop(request_id, None)
             st = self._prefilling
@@ -775,11 +903,24 @@ class LLMEngine:
                     return True
         return False
 
+    def occupancy(self) -> dict:
+        """Decode slots and pool pages in use, for the serve gauges.
+        Takes no lock: the pump asks on the event loop between steps."""
+        paged = self.kv == "paged"
+        return {
+            "active": len(self._active),
+            "max_batch": self.max_batch,
+            "pages_free": self.alloc.free_pages if paged else None,
+            "pages_total": self.alloc.num_pages if paged else None,
+        }
+
     def stats(self) -> dict:
         """Serving counters + live occupancy (reference shape: the
         vLLM engine stats ray.llm's deployments surface): request and
         token totals, speculative proposal/acceptance, preemptions,
-        chunked-prefill progress, and the pool/slot occupancy."""
+        chunked-prefill progress, the engine loop's own account (steps,
+        slot-steps, queue and lock waits, `init_s`) and the pool/slot
+        occupancy."""
         with self._lock:
             out = dict(self._stats)
             out["platform"] = self.platform

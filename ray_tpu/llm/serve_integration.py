@@ -11,7 +11,8 @@ behind each other.
 from __future__ import annotations
 
 import asyncio
-import time
+
+from jax.profiler import TraceAnnotation
 
 from ray_tpu.llm.engine import LLMEngine, SamplingParams
 from ray_tpu.llm.tokenizer import ByteTokenizer
@@ -56,36 +57,34 @@ class LLMServer:
                 # compile) — run it off-loop so this replica keeps
                 # answering RPCs, including the controller's health polls.
                 finished = await loop.run_in_executor(None, self.engine.step)
-                for rid, toks in self.engine.drain_deltas().items():
-                    q = self._streams.get(rid)
-                    if q is not None:
-                        q.put_nowait(toks)
-                for fin in finished:
-                    fut = self._waiters.pop(fin["request_id"], None)
-                    if fut is not None and not fut.done():
-                        fut.set_result(fin)
-                    q = self._streams.get(fin["request_id"])
-                    if q is not None:
-                        self._timings[fin["request_id"]] = fin
-                        q.put_nowait(None)
-                if tel_on:
-                    # Saturation gauges at step cadence: decode-slot
-                    # occupancy + paged-KV pool utilization — the
-                    # engine-side signals the SLO autoscaler reads.
-                    eng = self.engine
-                    stel.set_engine_gauges(
-                        self._deployment,
-                        active=len(eng._active),
-                        max_batch=eng.max_batch,
-                        pages_free=(
-                            eng.alloc.free_pages
-                            if eng.kv == "paged" else None
-                        ),
-                        pages_total=(
-                            eng.alloc.num_pages
-                            if eng.kv == "paged" else None
-                        ),
-                    )
+                # The event loop's share of a step, on the device
+                # trace's clock like the engine's own spans: from the
+                # executor's return to the next hand-off.
+                with TraceAnnotation(
+                    "pump:deliver", finished=len(finished)
+                ) as span:
+                    frames = 0
+                    for rid, toks in self.engine.drain_deltas().items():
+                        q = self._streams.get(rid)
+                        if q is not None:
+                            q.put_nowait(toks)
+                            frames += 1
+                    for fin in finished:
+                        fut = self._waiters.pop(fin["request_id"], None)
+                        if fut is not None and not fut.done():
+                            fut.set_result(fin)
+                        q = self._streams.get(fin["request_id"])
+                        if q is not None:
+                            self._timings[fin["request_id"]] = fin
+                            q.put_nowait(None)
+                    if tel_on:
+                        # Saturation gauges at step cadence: decode-slot
+                        # occupancy + paged-KV pool utilization — the
+                        # engine-side signals the SLO autoscaler reads.
+                        stel.set_engine_gauges(
+                            self._deployment, **self.engine.occupancy()
+                        )
+                    span.set_metadata(frames=frames)
         # tpulint: allow(broad-except reason=the pump failure is fanned out to every pending waiter future and stream queue - nothing is swallowed)
         except Exception as e:  # noqa: BLE001
             # Fail every pending caller rather than hanging them forever.
@@ -164,7 +163,6 @@ class LLMServer:
         self._streams[rid] = q
         self._ensure_pump()
         produced = 0
-        last_ts = time.time()
         try:
             while True:
                 delta = await q.get()
@@ -173,14 +171,6 @@ class LLMServer:
                 if isinstance(delta, BaseException):
                     raise delta
                 produced += len(delta)
-                if tel_on:
-                    # Per-delta decode spans ride the high-rate sampler
-                    # so a long generation can't storm the recorder.
-                    now = time.time()
-                    stel.record_token_span(
-                        deployment, last_ts, now - last_ts, len(delta)
-                    )
-                    last_ts = now
                 yield {
                     "tokens": delta,
                     "text": self.tokenizer.decode(delta),

@@ -271,22 +271,6 @@ def record_queue_wait(app: str, deployment: str, start: float,
     tracing.emit_span("serve:queue", start, dur, **attrs)
 
 
-def record_token_span(deployment: str, start: float, dur: float,
-                      tokens: int) -> None:
-    """Engine-side: one streamed decode delta as a ``serve:token`` span
-    under the active trace context, through the same high-rate sampler
-    (a 100-token/s stream per request would otherwise be a span storm)."""
-    from ray_tpu.collective import flight_recorder
-
-    emit, n = flight_recorder.span_sample(deployment, "serve:token", dur)
-    if not emit:
-        return
-    attrs = {"deployment": deployment, "tokens": int(tokens)}
-    if n > 1:
-        attrs["sample_rate"] = n
-    tracing.emit_span("serve:token", start, dur, **attrs)
-
-
 def record_engine_phases(deployment: str, timing: dict | None,
                          tokens: int) -> None:
     """Engine-side: emit ``serve:prefill`` and ``serve:decode`` spans

@@ -151,3 +151,68 @@ def test_step_telemetry_disabled_overhead():
         f"(budget {STEP_TELEMETRY_DISABLED_CEILING_S * 1e6:.0f}µs) — "
         "instrumentation is taxing the train loop"
     )
+
+
+# The engine's host spans are jax.profiler.TraceAnnotation, always in
+# the code: with no profiler session each is one flag test. Measured
+# ~5µs on the CPU for a paged decode step's five annotations
+# (constructor, the keyword arguments, set_metadata, the generator of
+# `engine:emit`); 50µs is 0.1% of a 48 ms decode step on the chip.
+ENGINE_STEP_ANNOTATIONS_CEILING_S = 50e-6
+
+
+def test_engine_step_annotations_cost_without_a_session(monkeypatch):
+    """What one `LLMEngine.step()` spends on its annotations when nobody
+    traces: the step's own annotation calls are recorded once, then
+    replayed on the real class."""
+    import time
+
+    from jax.profiler import TraceAnnotation
+
+    from ray_tpu.llm import engine as engine_mod
+
+    calls: list[tuple] = []  # (name, keyword arguments, set_metadata's)
+
+    class Recording(TraceAnnotation):
+        def __init__(self, name, **kw):
+            super().__init__(name, **kw)
+            self.call = (name, kw, [])
+            calls.append(self.call)
+
+        def set_metadata(self, **kw):
+            self.call[2].append(kw)
+            super().set_metadata(**kw)
+
+    eng = engine_mod.LLMEngine("tiny", max_batch=2, page_size=16)
+    eng.add_request([1, 2, 3], engine_mod.SamplingParams(max_tokens=8))
+    eng.add_request([4, 5, 6], engine_mod.SamplingParams(max_tokens=8))
+    eng.step()  # admits both; the next step is a plain decode step
+    monkeypatch.setattr(engine_mod, "TraceAnnotation", Recording)
+    eng.step()
+    monkeypatch.undo()
+    names = [c[0] for c in calls]
+    assert names == ["engine:step", "engine:grow_tables",
+                     "engine:decode_dispatch", "engine:decode_sync",
+                     "engine:emit"], names
+    assert not TraceAnnotation.is_enabled()
+
+    def replay():
+        for name, kw, metadata in calls:
+            with TraceAnnotation(name, **kw) as span:
+                for more in metadata:
+                    span.set_metadata(**more)
+        with eng._emit_span([]):  # the generator around `engine:emit`
+            pass
+
+    n = 2000
+    for _ in range(100):
+        replay()
+    t0 = time.perf_counter()
+    for _ in range(n):
+        replay()
+    per_step = (time.perf_counter() - t0) / n
+    assert per_step < ENGINE_STEP_ANNOTATIONS_CEILING_S, (
+        f"a step's annotations cost {per_step * 1e6:.1f}µs with no "
+        f"profiler session (budget "
+        f"{ENGINE_STEP_ANNOTATIONS_CEILING_S * 1e6:.0f}µs)"
+    )

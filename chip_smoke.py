@@ -71,11 +71,11 @@ SIZES = {
         "runs": [{"mesh": {"fsdp": 4}, "devices": 4},
                  {"mesh": {"dp": 1}, "devices": 1}],
     },
-    # Full vocabulary; the engine holds fp32 weights (4.2 GB of
-    # embedding + head, 0.87 GB a layer) and its programs keep a bf16
-    # copy of the blocks, so 8 layers: compiled for a described v5e the
-    # prefill, decode and reference programs peak at 14.3, 14.3 and
-    # 13.7 of 15.75 GiB.
+    # Full vocabulary; the engine holds its matmul weights in bf16 (2.1
+    # GB of embedding + head, 0.44 GB a layer). 8 layers is what fit
+    # while it held fp32 and each program kept a bf16 copy of the blocks
+    # (prefill, decode and reference programs compiled to 14.3, 14.3 and
+    # 13.7 of 15.75 GiB); the depth has not been cut again.
     "serve": {
         "cfg": {"n_layers": 8},
         "reduced": {"n_layers": "8 of 32"},

@@ -12,6 +12,12 @@ requests in a FIFO; step() admits queued requests into free slots
 (one prefill each, bucketed to power-of-two lengths to bound compile
 count) and then advances all active slots with one decode program.
 
+Weights: the engine holds `self.params` as its programs multiply, the
+matmul weights and the embedding in `cfg.dtype` (`kv_cache.matmul_weights`,
+once, in `__init__`), the norm scales as given; `stats()["param_bytes"]`
+is that tree's size. An fp32 tree handed in as `params=` stays the
+caller's, untouched.
+
 Host phases are `jax.profiler.TraceAnnotation` spans (`engine:step` and
 its children, `engine:add_request`, `engine:abort_request`): with a
 profiler session open they land in the trace's host plane, on the
@@ -34,7 +40,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from ray_tpu.llm.kv_cache import forward_decode, forward_prefill, init_kv_cache
+from ray_tpu.llm.kv_cache import (
+    forward_decode,
+    forward_prefill,
+    init_kv_cache,
+    matmul_weights,
+)
 from ray_tpu.models.llama import LlamaConfig, PRESETS, init_params, param_logical_axes
 
 
@@ -74,6 +85,17 @@ def _bucket(n: int, lo: int = 16) -> int:
     return b
 
 
+_cast_weights = jax.jit(matmul_weights, static_argnames="cfg")
+
+
+@partial(jax.jit, static_argnames="cfg")
+def _init_weights(key, cfg):
+    """An engine's own weights, made as it holds them: `init_params`'
+    values rounded once, without an fp32 copy of the tree on the device
+    beside them."""
+    return matmul_weights(init_params(key, cfg), cfg)
+
+
 class LLMEngine:
     def __init__(
         self,
@@ -103,11 +125,21 @@ class LLMEngine:
         self.max_seq = max_seq or cfg.max_seq
         self.mesh = mesh
         if params is None:
-            params = init_params(jax.random.key(seed), cfg)
+            params = _init_weights(jax.random.key(seed), cfg=cfg)
         if mesh is not None:
             from ray_tpu.parallel.sharding import shard_pytree
 
             params = shard_pytree(params, mesh, param_logical_axes(cfg))
+        # One program casts what every program would otherwise cast on
+        # every call; each leaf stays sharded as it came, and the
+        # caller's tree is neither donated nor kept. Where nothing is to
+        # be cast (a float32 config, a tree already held) the arrays
+        # stay the caller's own.
+        held = _cast_weights.eval_shape(params, cfg=cfg)
+        if [x.dtype for x in jax.tree.leaves(held)] != [
+            x.dtype for x in jax.tree.leaves(params)
+        ]:
+            params = _cast_weights(params, cfg=cfg)
         self.params = params
         if kv not in ("paged", "dense"):
             raise ValueError(f"kv must be 'paged' or 'dense', got {kv!r}")
@@ -262,6 +294,11 @@ class LLMEngine:
             "queue_wait_s_sum": 0.0,
             "lock_wait_s_sum": 0.0,
             "init_s": time.perf_counter() - init_began,
+            # Bytes of the held tree: half of an fp32 tree's where
+            # cfg.dtype is bfloat16.
+            "param_bytes": sum(
+                x.nbytes for x in jax.tree.leaves(self.params)
+            ),
         }
 
     # ------------------------------------------------------ request API
@@ -919,7 +956,8 @@ class LLMEngine:
         vLLM engine stats ray.llm's deployments surface): request and
         token totals, speculative proposal/acceptance, preemptions,
         chunked-prefill progress, the engine loop's own account (steps,
-        slot-steps, queue and lock waits, `init_s`) and the pool/slot
+        slot-steps, queue and lock waits, `init_s`), `param_bytes` (the
+        held weights, matmul leaves in `cfg.dtype`) and the pool/slot
         occupancy."""
         with self._lock:
             out = dict(self._stats)

@@ -40,22 +40,45 @@ def init_kv_cache(
     }
 
 
+# The leaves the programs below and in `paged_kv.py` multiply by (the
+# embedding is gathered from), all in cfg.dtype. The norm scales are not
+# among them: `rms_norm` reads them as given.
+_MATMUL_BLOCK_LEAVES = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+
+
+def matmul_weights(params: Params, cfg: LlamaConfig) -> Params:
+    """`params` with every weight the serving programs multiply by in
+    cfg.dtype, the rest as given. Each program starts with it, so a raw
+    fp32 tree (`init_params`) gives what it always gave; `LLMEngine`
+    calls it once and holds the result, on which it is the identity: no
+    program of an engine then reads an fp32 stack to round it again.
+    A weight that a program multiplies by goes into this list."""
+    dt = cfg.dtype
+    blocks = dict(params["blocks"])
+    for name in _MATMUL_BLOCK_LEAVES:
+        blocks[name] = blocks[name].astype(dt)
+    return {
+        **params,
+        "tok_emb": params["tok_emb"].astype(dt),
+        "lm_head": params["lm_head"].astype(dt),
+        "blocks": blocks,
+    }
+
+
 def _project_qkv(x, p, cfg):
     b, s, _ = x.shape
-    dt = cfg.dtype
     h = rms_norm(x, p["attn_norm"])
-    q = (h @ p["wq"].astype(dt)).reshape(b, s, cfg.n_heads, cfg.head_dim)
-    k = (h @ p["wk"].astype(dt)).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
-    v = (h @ p["wv"].astype(dt)).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
+    q = (h @ p["wq"]).reshape(b, s, cfg.n_heads, cfg.head_dim)
+    k = (h @ p["wk"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
+    v = (h @ p["wv"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
     return q, k, v
 
 
 def _mlp(x, p, cfg):
-    dt = cfg.dtype
     h = rms_norm(x, p["mlp_norm"])
-    gate = jax.nn.silu(h @ p["w_gate"].astype(dt))
-    up = h @ p["w_up"].astype(dt)
-    return x + (gate * up) @ p["w_down"].astype(dt)
+    gate = jax.nn.silu(h @ p["w_gate"])
+    up = h @ p["w_up"]
+    return x + (gate * up) @ p["w_down"]
 
 
 def forward_prefill(
@@ -74,9 +97,10 @@ def forward_prefill(
     overwrites them one by one. ``use_flash`` routes attention through the
     Pallas flash kernel (forward-only path, so no VJP needed).
     """
+    params = matmul_weights(params, cfg)
     seq = tokens.shape[1]
     cos, sin = rope_frequencies(cfg.head_dim, seq, cfg.rope_theta)
-    x = params["tok_emb"].astype(cfg.dtype)[tokens]
+    x = params["tok_emb"][tokens]
     # The kernel accepts any length (blocks clamp to the largest divisor
     # of seq), but awkward lengths degrade: gate on the FITTED block
     # being MXU-friendly (>=128, multiple of 8) so prime-ish prompt
@@ -102,7 +126,7 @@ def forward_prefill(
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
         attn = attend(q, k, v)
-        x = x + attn.reshape(x.shape) @ p["wo"].astype(cfg.dtype)
+        x = x + attn.reshape(x.shape) @ p["wo"]
         x = _mlp(x, p, cfg)
         # [B=1, S, Hkv, Dh] → write into this layer's [Bmax, Smax, ...] row.
         k_row = jax.lax.dynamic_update_slice(
@@ -117,7 +141,7 @@ def forward_prefill(
         body, x, (params["blocks"], cache["k"], cache["v"])
     )
     x = rms_norm(x, params["final_norm"])
-    logits = (x @ params["lm_head"].astype(cfg.dtype)).astype(jnp.float32)
+    logits = (x @ params["lm_head"]).astype(jnp.float32)
     return logits, {"k": k_cache, "v": v_cache}
 
 
@@ -129,7 +153,8 @@ def forward_decode(
     cfg: LlamaConfig,
 ) -> tuple[jnp.ndarray, KVCache]:
     """One decode step for all slots. Returns logits [B, V] + cache."""
-    x = params["tok_emb"].astype(cfg.dtype)[tokens]  # [B, 1, d]
+    params = matmul_weights(params, cfg)
+    x = params["tok_emb"][tokens]  # [B, 1, d]
     b = tokens.shape[0]
     max_seq = cache["k"].shape[2]
     # Table sized to the CACHE length, not cfg.max_seq: an engine may run
@@ -166,7 +191,7 @@ def forward_decode(
         logits = jnp.where(mask[:, None, None, :], _NEG_INF, logits)
         probs = jax.nn.softmax(logits, axis=-1).astype(cfg.dtype)
         attn = jnp.einsum("bhqk,bkhd->bqhd", probs, vv)
-        x = x + attn.reshape(b, 1, -1) @ p["wo"].astype(cfg.dtype)
+        x = x + attn.reshape(b, 1, -1) @ p["wo"]
         x = _mlp(x, p, cfg)
         return x, (k_row, v_row)
 
@@ -174,5 +199,5 @@ def forward_decode(
         body, x, (params["blocks"], cache["k"], cache["v"])
     )
     x = rms_norm(x, params["final_norm"])
-    logits = (x @ params["lm_head"].astype(cfg.dtype)).astype(jnp.float32)
+    logits = (x @ params["lm_head"]).astype(jnp.float32)
     return logits[:, 0], {"k": k_cache, "v": v_cache}

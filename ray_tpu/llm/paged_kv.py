@@ -146,7 +146,7 @@ def prefix_hashes(tokens: list[int], page_size: int) -> list[int]:
 # ------------------------------------------------------------- programs
 # One source of truth for the per-layer blocks: divergence between the
 # paged and dense cache paths would silently change decode results.
-from ray_tpu.llm.kv_cache import _mlp, _project_qkv  # noqa: E402
+from ray_tpu.llm.kv_cache import _mlp, _project_qkv, matmul_weights  # noqa: E402
 
 
 def _gather_page_attention(q, k_pool, v_pool, page_index, mask, cfg):
@@ -212,10 +212,11 @@ def paged_prefill(
     depend only on tokens <= i), so sharing needs no scatter mask.
     Returns (logits [1, S_pad, V] fp32, pool).
     """
+    params = matmul_weights(params, cfg)
     seq = tokens.shape[1]
     page_size = pool["k"].shape[3]
     cos, sin = rope_frequencies(cfg.head_dim, seq, cfg.rope_theta)
-    x = params["tok_emb"].astype(cfg.dtype)[tokens]
+    x = params["tok_emb"][tokens]
 
     from ray_tpu.ops.attention import causal_attention
 
@@ -225,7 +226,7 @@ def paged_prefill(
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
         attn = causal_attention(q, k, v)
-        x = x + attn.reshape(x.shape) @ p["wo"].astype(cfg.dtype)
+        x = x + attn.reshape(x.shape) @ p["wo"]
         x = _mlp(x, p, cfg)
         # [1, S, Hkv, Dh] → [n_pages, P, Hkv, Dh] scatter at page ids.
         kp = k.astype(cfg.dtype).reshape(
@@ -242,7 +243,7 @@ def paged_prefill(
         body, x, (params["blocks"], pool["k"], pool["v"])
     )
     x = rms_norm(x, params["final_norm"])
-    logits = (x @ params["lm_head"].astype(cfg.dtype)).astype(jnp.float32)
+    logits = (x @ params["lm_head"]).astype(jnp.float32)
     return logits, {"k": k_pool, "v": v_pool}
 
 
@@ -275,12 +276,13 @@ def paged_prefill_chunk(
 
     Returns (logits [1, C, V] fp32, pool).
     """
+    params = matmul_weights(params, cfg)
     c = tokens.shape[1]
     page_size = pool["k"].shape[3]
     window = n_write_pages * page_size
     cos, sin = rope_frequencies(cfg.head_dim, window, cfg.rope_theta)
     pos = start + jnp.arange(c, dtype=jnp.int32)[None, :]  # [1, C]
-    x = params["tok_emb"].astype(cfg.dtype)[tokens]
+    x = params["tok_emb"][tokens]
     chunk_slice = jax.lax.dynamic_slice(
         pages, [start // page_size], [chunk_pages]
     )
@@ -303,7 +305,7 @@ def paged_prefill_chunk(
         attn = _gather_page_attention(
             q, k_pool, v_pool, pages[None, :], mask, cfg
         )
-        x = x + attn.reshape(1, c, -1) @ p["wo"].astype(cfg.dtype)
+        x = x + attn.reshape(1, c, -1) @ p["wo"]
         x = _mlp(x, p, cfg)
         return x, (k_pool, v_pool)
 
@@ -311,7 +313,7 @@ def paged_prefill_chunk(
         body, x, (params["blocks"], pool["k"], pool["v"])
     )
     x = rms_norm(x, params["final_norm"])
-    logits = (x @ params["lm_head"].astype(cfg.dtype)).astype(jnp.float32)
+    logits = (x @ params["lm_head"]).astype(jnp.float32)
     return logits, {"k": k_pool, "v": v_pool}
 
 
@@ -388,8 +390,9 @@ def paged_verify(
     rej [B, K-1] int32 residual samples, logits [B, V] fp32 for
     position 0, pool).
     """
+    params = matmul_weights(params, cfg)
     b, kk_w = tokens.shape
-    x = params["tok_emb"].astype(cfg.dtype)[tokens]  # [B, K, d]
+    x = params["tok_emb"][tokens]  # [B, K, d]
     page_size = pool["k"].shape[3]
     max_pages = block_tables.shape[1]
     window = max_pages * page_size
@@ -443,7 +446,7 @@ def paged_verify(
                 q, k_pool, v_pool, jnp.maximum(block_tables, 0),
                 mask, cfg,
             )
-        x = x + attn.reshape(b, kk_w, -1) @ p["wo"].astype(cfg.dtype)
+        x = x + attn.reshape(b, kk_w, -1) @ p["wo"]
         x = _mlp(x, p, cfg)
         return x, (k_pool, v_pool)
 
@@ -451,7 +454,7 @@ def paged_verify(
         body, x, (params["blocks"], pool["k"], pool["v"])
     )
     x = rms_norm(x, params["final_norm"])
-    logits = (x @ params["lm_head"].astype(cfg.dtype)).astype(jnp.float32)
+    logits = (x @ params["lm_head"]).astype(jnp.float32)
 
     # Per-position sampling: greedy for temp 0, temperature draw
     # otherwise (the full-p sample — used for position 0, for the

@@ -131,7 +131,11 @@ def param_logical_axes(cfg: LlamaConfig) -> Params:
 
 
 def init_params(key: jax.Array, cfg: LlamaConfig) -> Params:
-    """Initialize fp32 parameters (truncated-normal, 1/sqrt(fan_in))."""
+    """Initialize fp32 parameters (truncated-normal, 1/sqrt(fan_in)).
+
+    The trainer keeps them fp32 and `forward` casts at use. For serving,
+    `LLMEngine` casts the matmul weights to cfg.dtype once and holds
+    that tree (`llm/kv_cache.py matmul_weights`)."""
     d, f = cfg.d_model, cfg.d_ff
     hq = cfg.n_heads * cfg.head_dim
     hkv = cfg.n_kv_heads * cfg.head_dim

@@ -58,6 +58,11 @@ class LlamaConfig:
     #             compiler replicates the whole activation to reshard)
     #   "auto"    onehot when >1 device is visible, else gather
     embed_impl: str = "auto"
+    # RMSNorm (learned weight) over the whole query and key projections,
+    # before the split into heads and the rope (OLMoE; its config has no
+    # key for it, modeling_olmoe.py does it always). A field of the
+    # model's shape like n_kv_heads, not a knob.
+    qk_norm: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -111,8 +116,7 @@ PRESETS: dict[str, LlamaConfig] = {
 
 def param_logical_axes(cfg: LlamaConfig) -> Params:
     """Pytree of logical-axis tuples, mirroring init_params' structure."""
-    del cfg
-    return {
+    axes = {
         "tok_emb": ("vocab", "embed"),
         "blocks": {
             "attn_norm": ("layers", "embed"),
@@ -128,6 +132,10 @@ def param_logical_axes(cfg: LlamaConfig) -> Params:
         "final_norm": ("embed",),
         "lm_head": ("embed", "vocab"),
     }
+    if cfg.qk_norm:
+        axes["blocks"]["q_norm"] = ("layers", "heads")
+        axes["blocks"]["k_norm"] = ("layers", "kv_heads")
+    return axes
 
 
 def init_params(key: jax.Array, cfg: LlamaConfig) -> Params:
@@ -148,7 +156,7 @@ def init_params(key: jax.Array, cfg: LlamaConfig) -> Params:
             * fan_in**-0.5
         )
 
-    return {
+    params = {
         "tok_emb": w(keys[0], (cfg.vocab_size, d), d),
         "blocks": {
             "attn_norm": jnp.zeros((L, d), jnp.float32),
@@ -164,6 +172,10 @@ def init_params(key: jax.Array, cfg: LlamaConfig) -> Params:
         "final_norm": jnp.zeros((d,), jnp.float32),
         "lm_head": w(keys[8], (d, cfg.vocab_size), d),
     }
+    if cfg.qk_norm:
+        params["blocks"]["q_norm"] = jnp.zeros((L, hq), jnp.float32)
+        params["blocks"]["k_norm"] = jnp.zeros((L, hkv), jnp.float32)
+    return params
 
 
 def _embed(table: jnp.ndarray, tokens: jnp.ndarray, cfg: LlamaConfig):
@@ -188,7 +200,10 @@ def _embed(table: jnp.ndarray, tokens: jnp.ndarray, cfg: LlamaConfig):
 AttnFn = Callable[..., jnp.ndarray]
 
 
-FfnFn = Callable[..., tuple[jnp.ndarray, jnp.ndarray]]
+# (h, layer_params, cfg) -> (out, aux). ``aux`` is the layer's side
+# output, any pytree of arrays: 0.0 for the dense FFN, the router's
+# losses, loads and routes for MoE. The scan stacks it over layers.
+FfnFn = Callable[..., tuple[jnp.ndarray, Any]]
 
 
 def _dense_ffn(h: jnp.ndarray, p: Params, cfg: LlamaConfig):
@@ -265,16 +280,21 @@ def _block(
     cfg: LlamaConfig,
     attn_fn: AttnFn,
     ffn_fn: FfnFn,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
+) -> tuple[jnp.ndarray, Any]:
     """Pre-norm attention + FFN sublayers; ffn_fn returns (out, aux) so
-    MoE layers (ray_tpu.models.moe) reuse this block unchanged."""
+    MoE layers (ray_tpu.models.moe) reuse this block unchanged. The
+    signature is a scan body's: (carry, layer_params) -> (carry, aux)."""
     b, s, d = x.shape
     dt = cfg.dtype
 
     x = constrain(x, "batch", "act_seq", "act_embed")
     h = rms_norm(x, p["attn_norm"])
-    q = (h @ p["wq"].astype(dt)).reshape(b, s, cfg.n_heads, cfg.head_dim)
-    k = (h @ p["wk"].astype(dt)).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
+    q = h @ p["wq"].astype(dt)
+    k = h @ p["wk"].astype(dt)
+    if cfg.qk_norm:
+        q, k = rms_norm(q, p["q_norm"]), rms_norm(k, p["k_norm"])
+    q = q.reshape(b, s, cfg.n_heads, cfg.head_dim)
+    k = k.reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
     v = (h @ p["wv"].astype(dt)).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
@@ -294,7 +314,8 @@ def forward_with_aux(
     ffn_fn: FfnFn | None = None,
     return_hidden: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """tokens [B, S] int32 → (logits [B, S, V] fp32, summed aux loss).
+    """tokens [B, S] int32 → (logits [B, S, V] fp32, the FFN hook's aux
+    stacked over layers: float32[L] zeros for the dense FFN).
 
     With ``return_hidden`` the final-norm hidden states [B, S, d] come
     back instead of logits — the chunked-CE loss projects them to the
@@ -390,20 +411,13 @@ def forward_with_aux(
             policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
         )
 
-    def scan_fn(carry, layer_params):
-        x, aux_sum = carry
-        x, aux = body(x, layer_params)
-        return (x, aux_sum + aux), None
-
-    (x, aux_total), _ = jax.lax.scan(
-        scan_fn, (x, jnp.float32(0.0)), params["blocks"]
-    )
+    x, aux = jax.lax.scan(body, x, params["blocks"])
 
     x = rms_norm(x, params["final_norm"])
     if return_hidden:
-        return x, aux_total
+        return x, aux
     logits = (x @ params["lm_head"].astype(cfg.dtype)).astype(jnp.float32)
-    return logits, aux_total
+    return logits, aux
 
 
 def forward(

@@ -1,24 +1,27 @@
-"""Mixture-of-Experts decoder with native expert parallelism.
+"""Mixture-of-Experts decoder: dropless top-k routing with a grouped
+expert matmul.
 
-GShard/Switch-style MoE done the TPU way: routing is a static-shape
-einsum pipeline (top-k gates → capacity-bounded one-hot dispatch tensor →
-dispatch einsum → expert FFNs → combine einsum). Tokens are routed in
-fixed-size GROUPS (GShard §3.2) so the dispatch tensors stay
-O(groups · g²) with a bounded group size instead of O((B·S)²). Experts
-carry the "expert" logical axis, sharded over the mesh's ep axis — XLA
-inserts the token all-to-alls during SPMD partitioning; there is no
-manual routing code on the host.
+Every (token, expert) pair the router picks is computed: the pairs are
+sorted by expert (one stable argsort), the rows gathered in that order,
+the three expert matmuls run as grouped matmuls whose ``group_sizes``
+are the tokens each expert received (``jax.lax.ragged_dot``), the
+results weighted by the gates and added back per token. There is no
+per-expert slot limit and no one-hot dispatch tensor, so the work is
+tokens x top_k whatever the skew. Experts carry the "expert" logical
+axis, sharded over the mesh's ep axis.
 
 The attention sublayer, scan scaffolding, and non-expert parameters are
 the flagship Llama's (ray_tpu.models.llama — this module only swaps the
-FFN hook). The reference ships no MoE/expert parallelism at all
-(SURVEY.md §2.3: TP/PP/EP "not implemented in Ray itself"); this makes
-EP a first-class strategy next to DP/FSDP/TP/SP.
+FFN hook). The shape fields are those of OLMoE-1B-7B-0125-Instruct
+(Muennighoff et al. 2024, arXiv:2409.02060: 64 experts of width 1024,
+8 per token, gates not renormalised, QK-norm), the public model the
+benchmark trains through this file.
 """
 
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -30,37 +33,59 @@ from ray_tpu.models.llama import (
     init_params,
     param_logical_axes,
 )
-from ray_tpu.parallel.sharding import constrain
 
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig(LlamaConfig):
+    """``d_ff`` is the width of one expert."""
+
     num_experts: int = 8
     top_k: int = 2
-    # capacity per expert per group = capacity_factor * g * top_k / num_experts
-    capacity_factor: float = 1.25
-    # routing group size (tokens); bounds the dispatch tensor at
-    # O(g * capacity) per group regardless of batch*seq.
-    group_size: int = 1024
-    # weight of the load-balancing auxiliary loss (Switch §2.2)
+    # Whether the top-k gates are renormalised to sum to 1: a field of
+    # the model's shape, read from its published config
+    # (``norm_topk_prob``), like n_kv_heads.
+    norm_topk_prob: bool = False
+    # Weights of the router's two auxiliary losses (OLMoE section 3):
+    # load balance (Switch section 2.2) and the z-loss on its logits.
     aux_loss_weight: float = 0.01
+    z_loss_weight: float = 0.001
+
+    def _matmul_params(self, experts: int) -> int:
+        """Parameters of the matrices a token meets when each layer
+        applies ``experts`` experts: projections, router, experts, head."""
+        d, hd = self.d_model, self.head_dim
+        attn = 2 * d * self.n_heads * hd + 2 * d * self.n_kv_heads * hd
+        per_layer = attn + d * self.num_experts + experts * 3 * d * self.d_ff
+        return self.n_layers * per_layer + d * self.vocab_size
+
+    def num_params(self) -> int:
+        """All parameters held: what memory and the optimizer pay for."""
+        d = self.d_model
+        norms = 2 * d
+        if self.qk_norm:
+            norms += (self.n_heads + self.n_kv_heads) * self.head_dim
+        return (
+            self._matmul_params(self.num_experts)
+            + self.n_layers * norms + d + self.vocab_size * d
+        )
+
+    def flops_per_token(self, seq: int) -> float:
+        """Training (fwd+bwd) FLOPs per token: 6 x the matmul parameters
+        a token passes through (``top_k`` experts a layer, not all) plus
+        causal attention (a query at position t reads t + 1 keys)."""
+        attention = 12 * self.n_layers * self.n_heads * self.head_dim * (
+            seq + 1
+        ) / 2
+        return 6.0 * self._matmul_params(self.top_k) + attention
 
 
 MOE_PRESETS: dict[str, MoEConfig] = {
+    # CPU-test scale, with OLMoE's switches on (as many key as query
+    # heads, QK-norm, gates as they are).
     "moe_tiny": MoEConfig(
-        vocab_size=512, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
+        vocab_size=512, d_model=64, n_layers=2, n_heads=4, n_kv_heads=4,
         d_ff=128, max_seq=256, dtype=jnp.float32, remat="none",
-        num_experts=4, top_k=2, group_size=64,
-    ),
-    # Single-chip scale (fp32 master params + adam fit a v5e's HBM).
-    "moe_bench": MoEConfig(
-        vocab_size=32768, d_model=1024, n_layers=6, n_heads=16,
-        n_kv_heads=8, d_ff=2048, max_seq=2048, num_experts=4, top_k=2,
-    ),
-    # Pod scale: experts sharded over the ep axis (won't fit one chip).
-    "moe_8x430m": MoEConfig(
-        vocab_size=32768, d_model=1024, n_layers=12, n_heads=16,
-        n_kv_heads=8, d_ff=4096, max_seq=2048, num_experts=8, top_k=2,
+        num_experts=4, top_k=2, qk_norm=True,
     ),
 }
 
@@ -97,73 +122,83 @@ def init_moe_params(key: jax.Array, cfg: MoEConfig) -> Params:
     return params
 
 
-def moe_ffn(x: jnp.ndarray, p: Params, cfg: MoEConfig):
-    """FFN hook for llama._block: x [B, S, d] → (out, aux_loss).
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _take_rows(x, index, inverse, fan):
+    """``x[index // fan]`` where ``index`` is a permutation of
+    ``range(fan * len(x))`` and ``inverse`` its inverse: each row is read
+    ``fan`` times. The cotangent is a gather by ``inverse`` and a sum of
+    each row's ``fan`` copies, where the gather's own transpose would be
+    a scatter-add of the same rows."""
+    return x[index // fan]
 
-    Static-shape grouped dispatch: every expert gets exactly `capacity`
-    slots per group; overflow tokens are dropped (their residual passes
-    through) — the standard TPU MoE trade (GShard §3.2).
-    """
+
+def _take_rows_fwd(x, index, inverse, fan):
+    return x[index // fan], inverse
+
+
+def _take_rows_bwd(fan, inverse, g):
+    rows = g[inverse]
+    return rows.reshape(-1, fan, g.shape[-1]).sum(1), None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+def moe_ffn(x: jnp.ndarray, p: Params, cfg: MoEConfig):
+    """FFN hook for llama._block: x [B, S, d] -> (out, aux).
+
+    ``aux`` is the layer's router record: ``balance_loss`` and
+    ``z_loss`` (unweighted), ``expert_load`` (pairs each expert
+    computed, int32[e]: their sum is tokens x top_k, nothing is
+    dropped) and ``routes`` (the experts of each token, int32[T, k])."""
     b, s, d = x.shape
     e, k = cfg.num_experts, cfg.top_k
     n = b * s
-    g = min(cfg.group_size, n)
-    if n % g:
-        g = n  # fall back to one group rather than failing odd shapes
-    G = n // g
-    capacity = max(1, int(cfg.capacity_factor * g * k / e))
     dt = cfg.dtype
+    tokens = x.reshape(n, d)
 
-    tokens = x.reshape(G, g, d)
-    logits = (
-        jnp.einsum("Ggd,de->Gge", tokens, p["router"].astype(dt))
-    ).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)  # [G, g, e]
+    with jax.named_scope("moe:route"):
+        # Matmul, softmax and top-k in float32: the 8th and 9th
+        # probabilities of a token are often closer than bf16 rounding.
+        logits = jnp.dot(
+            tokens.astype(jnp.float32), p["router"].astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
+        )
+        probs = jax.nn.softmax(logits, axis=-1)  # [n, e]
+        gates, routes = jax.lax.top_k(probs, k)  # [n, k]
+        if cfg.norm_topk_prob:
+            gates = gates / gates.sum(-1, keepdims=True)
 
-    # Top-k gates, renormalized over the selected experts.
-    gate_vals, gate_idx = jax.lax.top_k(probs, k)  # [G, g, k]
-    gate_vals = gate_vals / (gate_vals.sum(-1, keepdims=True) + 1e-9)
+    with jax.named_scope("moe:dispatch"):
+        # Pairs in expert order; a stable sort keeps each expert's rows
+        # in token order.
+        pair_expert = routes.reshape(n * k)
+        order = jnp.argsort(pair_expert, stable=True)
+        inverse = jnp.argsort(order)
+        load = jnp.bincount(pair_expert, length=e).astype(jnp.int32)
+        rows = _take_rows(tokens, order, inverse, k)  # [n * k, d]
 
-    # Slot of each (token, choice) within its expert's per-group capacity.
-    sel = jax.nn.one_hot(gate_idx, e, dtype=jnp.int32)  # [G, g, k, e]
-    flat_sel = sel.reshape(G, g * k, e)
-    pos_in_expert = jnp.cumsum(flat_sel, axis=1) - flat_sel
-    slot = (pos_in_expert * flat_sel).sum(-1).reshape(G, g, k)
-    keep = (slot < capacity).astype(jnp.float32)
+    with jax.named_scope("moe:experts"):
+        gate = jax.lax.ragged_dot(rows, p["w_gate"].astype(dt), load)
+        up = jax.lax.ragged_dot(rows, p["w_up"].astype(dt), load)
+        rows_out = jax.lax.ragged_dot(
+            jax.nn.silu(gate) * up, p["w_down"].astype(dt), load
+        )
 
-    slot_oh = jax.nn.one_hot(slot, capacity, dtype=jnp.float32)  # [G,g,k,c]
-    masked = slot_oh * keep[..., None]
-    dispatch = jnp.einsum("Ggke,Ggkc->Ggec", sel.astype(jnp.float32), masked)
-    combine = jnp.einsum(
-        "Ggk,Ggke,Ggkc->Ggec", gate_vals, sel.astype(jnp.float32), masked
-    )
+    with jax.named_scope("moe:combine"):
+        # Back to token order: pair j of token t sits at row t * k + j.
+        pairs = _take_rows(rows_out, inverse, order, 1).reshape(n, k, d)
+        out = (pairs.astype(jnp.float32) * gates[..., None]).sum(1)
+        out = out.astype(dt)
 
-    # [e, G, capacity, d] expert inputs — sharding e over ep makes XLA
-    # emit the all-to-all here.
-    expert_in = jnp.einsum(
-        "Ggec,Ggd->eGcd", dispatch, tokens.astype(jnp.float32)
-    )
-    expert_in = constrain(
-        expert_in.astype(dt), "expert", None, None, "act_embed"
-    )
-    gate = jax.nn.silu(
-        jnp.einsum("eGcd,edf->eGcf", expert_in, p["w_gate"].astype(dt))
-    )
-    up = jnp.einsum("eGcd,edf->eGcf", expert_in, p["w_up"].astype(dt))
-    expert_out = jnp.einsum(
-        "eGcf,efd->eGcd", gate * up, p["w_down"].astype(dt)
-    )
-    expert_out = constrain(expert_out, "expert", None, None, "act_embed")
-
-    out = jnp.einsum(
-        "Ggec,eGcd->Ggd", combine, expert_out.astype(jnp.float32)
-    ).astype(dt)
-
-    # Load-balance aux loss: e * sum_e (fraction routed) * (mean prob),
-    # averaged over groups (Switch §2.2).
-    me = probs.mean(1)  # [G, e]
-    ce = sel.astype(jnp.float32).sum(2).mean(1)  # [G, e]
-    aux = e * (me * ce).sum(-1).mean() * cfg.aux_loss_weight
+    with jax.named_scope("moe:route"):
+        # Load balance: e * sum_e (share of pairs routed to e) * (mean
+        # probability of e) (Switch section 2.2); z-loss: the mean
+        # squared logsumexp of the router's logits.
+        balance = e * (probs.mean(0) * (load / n)).sum()
+        z = jnp.square(jax.nn.logsumexp(logits, axis=-1)).mean()
+    aux = {"balance_loss": balance, "z_loss": z, "expert_load": load,
+           "routes": routes}
     return out.reshape(b, s, d), aux
 
 
@@ -173,11 +208,35 @@ def moe_forward(
     cfg: MoEConfig,
     attn_fn=None,
     return_hidden: bool = False,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """tokens [B, S] → (logits [B, S, V] fp32 — or final hidden states
-    with ``return_hidden`` — and the mean aux loss)."""
-    out, aux_total = forward_with_aux(
-        params, tokens, cfg, attn_fn=attn_fn, ffn_fn=moe_ffn,
-        return_hidden=return_hidden,
+) -> tuple[jnp.ndarray, dict]:
+    """tokens [B, S] -> (logits [B, S, V] fp32 — or final hidden states
+    with ``return_hidden`` — and every layer's router record, stacked
+    on a leading layer dimension: see :func:`moe_ffn`)."""
+    # The experts are cast to the compute dtype as whole stacks, before
+    # the scan over layers, so that the scan's backward pass stacks their
+    # gradients in that dtype too (they are the grouped matmul's outputs,
+    # upcast) and the float32 gradient exists only inside the fusions
+    # that consume it: at OLMoE's widths 1.6 GB less at the step's peak.
+    blocks = dict(params["blocks"])
+    for name in ("w_gate", "w_up", "w_down"):
+        blocks[name] = blocks[name].astype(cfg.dtype)
+    return forward_with_aux(
+        {**params, "blocks": blocks}, tokens, cfg, attn_fn=attn_fn,
+        ffn_fn=moe_ffn, return_hidden=return_hidden,
     )
-    return out, aux_total / cfg.n_layers
+
+
+def router_losses(aux: dict, cfg: MoEConfig) -> dict[str, jnp.ndarray]:
+    """The weighted auxiliary losses and the step's router counters,
+    from ``moe_forward``'s record."""
+    load = aux["expert_load"].astype(jnp.float32)  # [L, e]
+    return {
+        "aux_loss": cfg.aux_loss_weight * aux["balance_loss"].mean(),
+        "router_z_loss": cfg.z_loss_weight * aux["z_loss"].mean(),
+        # Largest expert's pairs over the mean, in the worst layer.
+        "expert_load_max_over_mean": (
+            load.max(-1) / load.mean(-1)
+        ).max(),
+        # Pairs computed this step: tokens x top_k x layers, always.
+        "moe_pairs": aux["expert_load"].sum(),
+    }

@@ -136,7 +136,8 @@ def plan(
     compression: str | None = None,
     reserve_bytes: int = XLA_RESERVE_BYTES,
 ) -> MemoryPlan:
-    """Price one train-step config (a models.llama LlamaConfig plus
+    """Price one train-step config (a models.llama LlamaConfig, or a
+    models.moe MoEConfig, whose ``num_params`` counts every expert; plus
     batch/seq) against a chip's HBM and return the
     :class:`MemoryPlan` verdict. ``fsdp`` divides the resident state
     (params/optimizer/grads) ZeRO-3 style; ``zero`` divides the
@@ -157,7 +158,10 @@ def plan(
     grads_bytes = n_params * GRAD_BYTES // shard
     act_dtype = _dtype_bytes(cfg.dtype)
     boundary = cfg.n_layers * batch * seq * cfg.d_model * act_dtype
-    working_unit = batch * seq * cfg.d_ff * act_dtype
+    # A sparse-expert layer runs top_k experts of width d_ff per token.
+    working_unit = (
+        batch * seq * cfg.d_ff * getattr(cfg, "top_k", 1) * act_dtype
+    )
     remat = getattr(cfg, "remat", "full")
     if remat == "full":
         activation_bytes = boundary + int(

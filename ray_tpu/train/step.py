@@ -259,7 +259,7 @@ def loss_fn(
 ) -> tuple[jnp.ndarray, dict[str, jnp.ndarray]]:
     """Next-token cross entropy. batch["tokens"]: [B, S+1] int32."""
     from ray_tpu.models.llama import forward_with_aux
-    from ray_tpu.models.moe import MoEConfig, moe_forward
+    from ray_tpu.models.moe import MoEConfig, moe_forward, router_losses
 
     tokens = batch["tokens"]
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
@@ -267,19 +267,20 @@ def loss_fn(
         hidden, aux = moe_forward(
             params, inputs, cfg, attn_fn=attn_fn, return_hidden=True
         )
+        router = router_losses(aux, cfg)
     else:
-        hidden, aux = forward_with_aux(
+        hidden, _ = forward_with_aux(
             params, inputs, cfg, attn_fn=attn_fn, return_hidden=True
         )
-        aux = None
+        router = None
     ce = chunked_cross_entropy(
         hidden, params["lm_head"], targets, cfg.dtype
     )
     metrics = {"loss": ce, "perplexity": jnp.exp(ce)}
-    if aux is None:
+    if router is None:
         return ce, metrics
-    metrics["aux_loss"] = aux
-    return ce + aux, metrics
+    metrics.update(router)
+    return ce + router["aux_loss"] + router["router_z_loss"], metrics
 
 
 def make_train_step(

@@ -142,7 +142,7 @@ def test_ffn_checkpoint_remat_modes_match_full():
             lp = jax.nn.log_softmax(logits)
             return (
                 -jnp.take_along_axis(lp, tgt[..., None], axis=-1).mean()
-                + aux
+                + aux.sum()
             )
 
         return jax.jit(jax.value_and_grad(loss))(params)

@@ -1,62 +1,161 @@
 """MoE / expert-parallelism tests on the virtual 8-device mesh.
 
 The reference ships no MoE (SURVEY.md §2.3: EP "not implemented in Ray
-itself"); these tests pin the native implementation: static-shape
-dispatch correctness, EP sharding, and a full sharded train step.
+itself"); these tests pin the native implementation: dropless dispatch
+against the plain reference (``benchmarks/reference_olmoe.py``), the
+router's switches, EP sharding, and a full sharded train step.
 """
 
 import dataclasses
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmarks import reference_olmoe
+from benchmarks.models import olmoe
 from ray_tpu.models.moe import (
     MOE_PRESETS,
     MoEConfig,
-    moe_ffn,
+    _take_rows,
     init_moe_params,
+    moe_ffn,
     moe_forward,
     moe_param_logical_axes,
 )
 from ray_tpu.parallel import make_mesh
-from ray_tpu.parallel.sharding import shard_pytree, tree_shardings, use_mesh
+from ray_tpu.parallel.sharding import shard_pytree, use_mesh
 from ray_tpu.train.step import (
     init_train_state,
     jit_train_step,
+    loss_fn,
     make_optimizer,
-    state_logical_axes,
 )
 
 CFG = MOE_PRESETS["moe_tiny"]
+REF_KW = {"n_heads": CFG.n_heads, "rope_theta": CFG.rope_theta,
+          "top_k": CFG.top_k, "norm_topk_prob": CFG.norm_topk_prob}
+# OLMoE-1B-7B-0125-Instruct's published keys (the catalog's), at 2 of
+# its 16 layers: the benchmark's configuration.
+OLMOE_2L = {
+    "model_type": "olmoe", "hidden_size": 2048, "intermediate_size": 1024,
+    "num_attention_heads": 16, "num_key_value_heads": 16,
+    "num_hidden_layers": 2, "num_experts": 64, "num_experts_per_tok": 8,
+    "norm_topk_prob": False, "vocab_size": 50304, "rope_theta": 10000,
+    "max_position_embeddings": 4096, "rms_norm_eps": 1e-05,
+    "tie_word_embeddings": False, "hidden_act": "silu",
+    "attention_bias": False, "clip_qkv": None, "rope_scaling": None,
+}
+
+
+def seeded(cfg=CFG, batch=2, seq=32):
+    """Seeded weights with every norm's scale moved off its initial 0,
+    or the reading of the weight as 1 + scale is not exercised."""
+    params = init_moe_params(jax.random.key(0), cfg)
+    keys = iter(jax.random.split(jax.random.key(3), 8))
+    for name in ("attn_norm", "mlp_norm", "q_norm", "k_norm"):
+        if name in params["blocks"]:
+            shape = params["blocks"][name].shape
+            params["blocks"][name] = 0.1 * jax.random.normal(next(keys), shape)
+    params["final_norm"] = 0.1 * jax.random.normal(
+        next(keys), params["final_norm"].shape
+    )
+    tokens = jax.random.randint(
+        jax.random.key(1), (batch, seq + 1), 0, cfg.vocab_size
+    )
+    return params, tokens
 
 
 def test_moe_forward_shapes_and_finite():
-    params = init_moe_params(jax.random.key(0), CFG)
-    tokens = jax.random.randint(jax.random.key(1), (2, 32), 0, CFG.vocab_size)
-    logits, aux = moe_forward(params, tokens, CFG)
+    params, tokens = seeded()
+    logits, aux = moe_forward(params, tokens[:, :-1], CFG)
     assert logits.shape == (2, 32, CFG.vocab_size)
     assert np.isfinite(np.asarray(logits)).all()
-    assert float(aux) > 0.0  # load-balance loss is positive
+    assert aux["routes"].shape == (CFG.n_layers, 64, CFG.top_k)
+    assert aux["expert_load"].shape == (CFG.n_layers, CFG.num_experts)
+    assert (np.asarray(aux["balance_loss"]) > 0.0).all()
 
 
-def test_moe_ffn_matches_dense_ensemble_when_capacity_ample():
-    """With capacity >= all tokens, MoE output == gate-weighted sum of
-    each selected expert's dense FFN — validates dispatch/combine."""
-    cfg = dataclasses.replace(CFG, capacity_factor=8.0)  # no drops
+def test_logits_match_the_reference_in_two_parts():
+    """Routing is discrete: (a) on the system's routes the logits agree
+    to float32 rounding; (b) the reference's own routes are the system's
+    wherever its margin is not itself rounding. On the CPU both sides
+    are float32, so the epsilon is a rounding error's size."""
+    params, tokens = seeded()
+    got, aux = moe_forward(params, tokens[:, :-1], CFG)
+    want, record = reference_olmoe.forward_with_router(
+        params, tokens[:, :-1], routes=aux["routes"], **REF_KW
+    )
+    np.testing.assert_allclose(got, want, atol=3e-5, rtol=1e-5)
+    clear = np.asarray(record["margin"]) > 1e-5
+    same = (np.sort(aux["routes"], -1) == np.sort(record["routes"], -1)).all(-1)
+    assert clear.mean() > 0.99 and same[clear].all()
+    assert float(record["slack"].max()) <= 1e-5
+    # The benchmark's own form of the same check, on its own sample.
+    mesh = make_mesh({"dp": 1}, devices=jax.devices()[:1])
+    model = {**OLMOE_2L, "num_attention_heads": CFG.n_heads,
+             "num_experts_per_tok": CFG.top_k, "rope_theta": CFG.rope_theta}
+    check = olmoe.reference_check(params, model, CFG, mesh, None, seed=5,
+                                  tokens_per_row=32, epsilon=1e-5)
+    assert check["logit_max_abs_err"] < 3e-5
+    assert not olmoe.check_problems(check)
+
+
+def test_loss_and_gradients_match_the_reference():
+    params, tokens = seeded()
+    (got_loss, metrics), got = jax.value_and_grad(loss_fn, has_aux=True)(
+        params, {"tokens": tokens}, CFG
+    )
+    routes = moe_forward(params, tokens[:, :-1], CFG)[1]["routes"]
+    want_loss, want = jax.value_and_grad(reference_olmoe.loss)(
+        params, tokens, routes=routes,
+        aux_loss_weight=CFG.aux_loss_weight,
+        z_loss_weight=CFG.z_loss_weight, **REF_KW,
+    )
+    assert float(got_loss) == pytest.approx(float(want_loss), abs=2e-5)
+    assert float(metrics["router_z_loss"]) > 0.0
+    flat_got = dict(jax.tree.leaves_with_path(got))
+    for path, w in jax.tree.leaves_with_path(want):
+        np.testing.assert_allclose(flat_got[path], w, atol=1e-5, rtol=2e-4,
+                                   err_msg=str(path))
+
+
+def test_dropless_under_a_router_forced_onto_one_expert():
+    """A router of zeros gives every expert the same probability and
+    top-k takes the lowest ids: every token goes to experts 0 and 1.
+    Nothing is dropped: the pairs computed are tokens x top_k x layers
+    and the output is the reference's."""
+    params, tokens = seeded()
+    params["blocks"]["router"] = jnp.zeros_like(params["blocks"]["router"])
+    got, aux = moe_forward(params, tokens[:, :-1], CFG)
+    n = 2 * 32
+    load = np.asarray(aux["expert_load"])
+    assert (load == [[n, n, 0, 0]] * CFG.n_layers).all()
+    assert int(load.sum()) == n * CFG.top_k * CFG.n_layers
+    want = reference_olmoe.forward(params, tokens[:, :-1], **REF_KW)
+    np.testing.assert_allclose(got, want, atol=3e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("norm_topk_prob", [False, True])
+def test_moe_ffn_matches_dense_ensemble(norm_topk_prob):
+    """MoE output == gate-weighted sum of each selected expert's dense
+    FFN, every pair computed; the gates are the router's probabilities
+    as they are unless the model's config says to renormalise them."""
+    cfg = dataclasses.replace(CFG, norm_topk_prob=norm_topk_prob)
     params = init_moe_params(jax.random.key(0), cfg)
     layer = jax.tree.map(lambda x: x[0], params["blocks"])  # layer 0
     x = jax.random.normal(jax.random.key(2), (1, 8, cfg.d_model), jnp.float32)
 
-    out, _aux = moe_ffn(x, layer, cfg)
+    out, aux = moe_ffn(x, layer, cfg)
 
-    # Reference: route each token through its top-k experts densely.
     tokens = x.reshape(-1, cfg.d_model)
-    logits = tokens @ layer["router"]
-    probs = jax.nn.softmax(logits, -1)
+    probs = jax.nn.softmax(tokens @ layer["router"], -1)
     gv, gi = jax.lax.top_k(probs, cfg.top_k)
-    gv = gv / gv.sum(-1, keepdims=True)
+    assert float(gv.sum(-1).max()) < 0.9  # far from summing to 1
+    if norm_topk_prob:
+        gv = gv / gv.sum(-1, keepdims=True)
     expect = np.zeros_like(np.asarray(tokens))
     for t in range(tokens.shape[0]):
         for j in range(cfg.top_k):
@@ -69,21 +168,148 @@ def test_moe_ffn_matches_dense_ensemble_when_capacity_ample():
     np.testing.assert_allclose(
         np.asarray(out).reshape(-1, cfg.d_model), expect, rtol=2e-3, atol=2e-3
     )
+    assert int(aux["expert_load"].sum()) == 8 * cfg.top_k
 
 
-def test_moe_capacity_drops_tokens():
-    """Tiny capacity: output is still finite and some tokens pass
-    through un-routed (residual only)."""
-    cfg = dataclasses.replace(CFG, capacity_factor=0.25)
-    params = init_moe_params(jax.random.key(0), cfg)
+@pytest.mark.parametrize("qk_norm", [True, False])
+def test_qk_norm_is_a_field_of_the_shape(qk_norm):
+    cfg = dataclasses.replace(CFG, qk_norm=qk_norm)
+    params, tokens = seeded(cfg)
+    axes = moe_param_logical_axes(cfg)
+    for name, width in (("q_norm", cfg.n_heads), ("k_norm", cfg.n_kv_heads)):
+        assert (name in params["blocks"]) == qk_norm
+        assert (name in axes["blocks"]) == qk_norm
+        if qk_norm:
+            assert params["blocks"][name].shape == (
+                cfg.n_layers, width * cfg.head_dim
+            )
+    logits, _ = moe_forward(params, tokens[:, :-1], cfg)
+    with_norm, _ = moe_forward(seeded()[0], tokens[:, :-1], CFG)
+    # Off, the same weights give other logits: the norm is not a no-op.
+    assert (np.abs(np.asarray(logits - with_norm)).max() < 1e-6) == qk_norm
+
+
+def test_dense_forward_is_unchanged_by_the_block_edit():
+    """Bitwise, on the ``tiny`` preset: ``_block`` as it was before
+    QK-norm and the stacked aux, through the same scan."""
+    from ray_tpu.models import PRESETS
+    from ray_tpu.models.llama import _embed, forward, init_params
+    from ray_tpu.ops.attention import causal_attention
+    from ray_tpu.ops.norms import rms_norm
+    from ray_tpu.ops.rope import apply_rope, rope_frequencies
+
+    cfg = PRESETS["tiny"]
+    params = init_params(jax.random.key(0), cfg)
     tokens = jax.random.randint(jax.random.key(1), (2, 32), 0, cfg.vocab_size)
-    logits, aux = moe_forward(params, tokens, cfg)
-    assert np.isfinite(np.asarray(logits)).all()
+
+    def before(params, tokens):
+        cos, sin = rope_frequencies(cfg.head_dim, 32, cfg.rope_theta)
+
+        def block(carry, p):
+            x, aux_sum = carry
+            b, s, _ = x.shape
+            h = rms_norm(x, p["attn_norm"])
+            q = (h @ p["wq"]).reshape(b, s, cfg.n_heads, cfg.head_dim)
+            k = (h @ p["wk"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
+            v = (h @ p["wv"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
+            q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+            x = x + causal_attention(q, k, v).reshape(b, s, -1) @ p["wo"]
+            h = rms_norm(x, p["mlp_norm"])
+            gate = jax.nn.silu(h @ p["w_gate"])
+            x = x + (gate * (h @ p["w_up"])) @ p["w_down"]
+            return (x, aux_sum + jnp.float32(0.0)), None
+
+        x = _embed(params["tok_emb"], tokens, cfg)
+        (x, _), _ = jax.lax.scan(block, (x, jnp.float32(0.0)), params["blocks"])
+        x = rms_norm(x, params["final_norm"])
+        return (x @ params["lm_head"]).astype(jnp.float32)
+
+    want = jax.jit(before)(params, tokens)
+    got = jax.jit(partial(forward, cfg=cfg))(params, tokens)
+    assert "q_norm" not in params["blocks"]
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_take_rows_cotangent_is_the_gathers_own():
+    """The hand-written transpose (a gather by the inverse permutation
+    and a sum over copies) equals the scatter-add JAX would derive."""
+    x = jax.random.normal(jax.random.key(0), (6, 5))
+    order = jax.random.permutation(jax.random.key(1), 18)
+    inverse = jnp.argsort(order)
+    w = jax.random.normal(jax.random.key(2), (18, 5))
+    got = jax.grad(lambda x: (_take_rows(x, order, inverse, 3) * w).sum())(x)
+    want = jax.grad(lambda x: (x[order // 3] * w).sum())(x)
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+
+
+def test_full_remat_gives_the_same_loss_and_gradients():
+    params, tokens = seeded()
+    full = dataclasses.replace(CFG, remat="full")
+    (a, _), ga = jax.value_and_grad(loss_fn, has_aux=True)(
+        params, {"tokens": tokens}, CFG
+    )
+    (b, _), gb = jax.value_and_grad(loss_fn, has_aux=True)(
+        params, {"tokens": tokens}, full
+    )
+    assert float(a) == pytest.approx(float(b), abs=1e-6)
+    for x, y in zip(jax.tree.leaves(ga), jax.tree.leaves(gb), strict=True):
+        np.testing.assert_allclose(x, y, atol=1e-6, rtol=1e-5)
+
+
+def test_num_params_counts_every_expert():
+    cfg = olmoe.config(OLMOE_2L)
+    one = dataclasses.replace(cfg, n_layers=1).num_params()
+    none = dataclasses.replace(cfg, n_layers=0).num_params()
+    assert one - none == 419_569_664  # a layer: 402.7M of it in experts
+    assert none == 206_047_232  # embedding, head, final norm
+    assert cfg.num_params() == 1_045_186_560 == olmoe.total_params(OLMOE_2L)
+    shapes = jax.eval_shape(partial(init_moe_params, cfg=cfg), jax.random.key(0))
+    assert sum(x.size for x in jax.tree.leaves(shapes)) == cfg.num_params()
+
+
+def test_flops_per_token_counts_the_active_experts():
+    cfg = olmoe.config(OLMOE_2L)
+    # 237.5M active matmul parameters; 1.526 GFLOP a token trained.
+    assert olmoe.matmul_params(OLMOE_2L) == 2 * 67_239_936 + 103_022_592
+    assert cfg.flops_per_token(4096) == pytest.approx(1.5257e9, rel=1e-4)
+    assert cfg.flops_per_token(4096) == olmoe.train_flops_per_token(
+        OLMOE_2L, 4096
+    )
+    # Read as dense, the same model would be priced 6.2 times higher.
+    dense_read = 6.0 * (cfg.num_params() - cfg.vocab_size * cfg.d_model)
+    assert dense_read / cfg.flops_per_token(4096) > 3.5
+
+
+def test_memory_plan_is_priced_on_total_parameters():
+    from ray_tpu.train import memory
+
+    cfg = olmoe.config(OLMOE_2L)
+    plan = memory.plan(cfg, batch=2, seq=4096, mu_dtype="bfloat16", hbm_gb=16)
+    assert plan.n_params == 1_045_186_560
+    resident = plan.params_bytes + plan.optimizer_bytes
+    assert resident == 1_045_186_560 * 10  # fp32 weights, bf16 + fp32 moments
+    assert plan.grads_bytes == 1_045_186_560 * 4
+    # The layer's working set is top_k experts wide, not one.
+    one = memory.plan(dataclasses.replace(cfg, top_k=1), batch=2, seq=4096,
+                      mu_dtype="bfloat16", hbm_gb=16)
+    assert plan.activation_bytes > one.activation_bytes
+
+
+def test_train_step_metrics_prove_nothing_is_dropped():
+    opt = make_optimizer(total_steps=10)
+    step = jit_train_step(CFG, opt, None)
+    state = init_train_state(jax.random.key(0), CFG, opt)
+    tokens = jax.random.randint(jax.random.key(1), (4, 33), 0, CFG.vocab_size)
+    _, metrics = step(state, {"tokens": tokens})
+    assert int(metrics["moe_pairs"]) == 4 * 32 * CFG.top_k * CFG.n_layers
+    assert float(metrics["expert_load_max_over_mean"]) >= 1.0
+    assert float(metrics["router_z_loss"]) > 0.0
+    assert float(metrics["aux_loss"]) > 0.0
 
 
 def test_moe_expert_sharding_over_ep(mesh8):
     """Params shard over the ep axis; forward under the mesh matches the
-    unsharded forward (XLA inserts the all-to-alls)."""
+    unsharded forward."""
     mesh = make_mesh({"ep": 4, "dp": 2})
     params = init_moe_params(jax.random.key(0), CFG)
     tokens = jax.random.randint(jax.random.key(1), (2, 32), 0, CFG.vocab_size)
@@ -100,7 +326,10 @@ def test_moe_expert_sharding_over_ep(mesh8):
     np.testing.assert_allclose(
         np.asarray(logits), np.asarray(ref_logits), rtol=2e-3, atol=2e-3
     )
-    np.testing.assert_allclose(float(aux), float(ref_aux), rtol=1e-4)
+    np.testing.assert_allclose(
+        aux["balance_loss"], ref_aux["balance_loss"], rtol=1e-4
+    )
+    assert np.array_equal(aux["routes"], ref_aux["routes"])
 
 
 def test_moe_train_step_on_mesh():
@@ -115,5 +344,6 @@ def test_moe_train_step_on_mesh():
     state, metrics = step(state, {"tokens": tokens})
     assert np.isfinite(float(metrics["loss"]))
     assert float(metrics["aux_loss"]) > 0.0
+    assert int(metrics["moe_pairs"]) == 4 * 32 * CFG.top_k * CFG.n_layers
     state, metrics2 = step(state, {"tokens": tokens})
     assert float(metrics2["loss"]) < float(metrics["loss"]) + 1.0

@@ -957,7 +957,9 @@ class LLMEngine:
         token totals, speculative proposal/acceptance, preemptions,
         chunked-prefill progress, the engine loop's own account (steps,
         slot-steps, queue and lock waits, `init_s`), `param_bytes` (the
-        held weights, matmul leaves in `cfg.dtype`) and the pool/slot
+        held weights, matmul leaves in `cfg.dtype`), which attention and
+        which K/V cell write the decode program was compiled with
+        (`paged_attn_kernel`, `kv_write_kernel`) and the pool/slot
         occupancy."""
         with self._lock:
             out = dict(self._stats)
@@ -966,6 +968,10 @@ class LLMEngine:
             out["paged_attn_kernel"] = (
                 self.kv == "paged" and self.paged_attn_kernel
             )
+            # The decode cell write follows the attention's path
+            # (paged_kv.paged_verify): Pallas and in place beside the
+            # kernel, XLA's scatter beside the gather.
+            out["kv_write_kernel"] = out["paged_attn_kernel"]
             out["active_requests"] = len(self._active)
             out["queued_requests"] = len(self._queue)
             out["prefilling"] = self._prefilling is not None

@@ -7,19 +7,34 @@ the vLLM-style alternative the reference gets from its serving engine
 python/ray/llm/_internal/serve/deployments/llm/vllm/vllm_models.py:234 —
 block_size / num_gpu_blocks are vLLM's page knobs):
 
-- One **page pool** per layer: [L, num_pages, Hkv, page_size, Dh]
-  (HEAD-major: the Pallas decode kernel reads one KV head's page tile
-  as a contiguous slice — measured ~40% faster than page-major; the
-  XLA fallback folds the layout into its einsums, see
-  _gather_page_attention).
+- One **page pool**: ``{"k","v": [L, num_pages, Hkv, page_size, Dh]}``,
+  each layer's pages HEAD-major and row-major in memory (``[page, head,
+  cell, dim]``: the Pallas decode kernel reads one KV head's page tile
+  as a contiguous slice — measured ~40% faster than page-major).
   Capacity is a token budget (num_pages × page_size), independent of
   how many requests share it or how long each runs.
+- **One buffer, one layout, argument to result.** Every program takes
+  the pool donated and carries it through its layer loop
+  (``_scan_layers``: a flat ``[L * num_pages, ...]`` view, each layer
+  adding its page base to the ids), so writes land in place and no
+  layer's pages are sliced out or stacked back. The layout is fixed by
+  whoever reads the pages: on the kernel path (``use_kernel=True``: a
+  bare TPU) by ``paged_attention``, a Mosaic call whose operands are
+  row-major, so the decode write is a Mosaic call too
+  (``ops/pallas/kv_cell_write.py``) and XLA never gets to choose; on
+  the XLA path (``use_kernel=False``: a ``tp`` mesh, a CPU) scatter and
+  gather are both XLA's and it gives them one layout (compiled for a
+  TPU: the carried pool is re-laid-out once at the program's entry and
+  once at its exit, where it was twice a layer; no cell measures it).
 - A **block table** per request: the ordered list of page ids holding
   its tokens. Tables live on the host (numpy, tiny) and ship to the
   device each step as a [B, max_pages] int32 array.
-- **Decode** gathers each slot's pages (jnp.take along the page axis) and
-  runs masked attention over the gathered window — static shapes, XLA
-  fuses gather+attention; no pallas needed until page counts get large.
+- **Decode**, kernel path: the K cells a slot writes are patched into
+  their pages, then ``paged_attention`` reads each slot's live pages in
+  place. XLA path: the cells are scattered (``.at[].set``), each slot's
+  pages gathered (jnp.take along the page axis) and attended under a
+  mask — static shapes, gather and attention fused, the layout folded
+  into the einsums (_gather_page_attention).
 - **Prefill** computes K/V with the normal dense program and scatters
   them into freshly-allocated pages.
 - **Prefix sharing**: full pages whose token prefix hashes equal an
@@ -191,6 +206,39 @@ def _gather_page_attention(q, k_pool, v_pool, page_index, mask, cfg):
     return attn.reshape(b, q_len, cfg.n_heads, dh)
 
 
+def _scan_layers(body, x, params, pool: PagedKV):
+    """Run ``body(x, k_pages, v_pages, p, base) -> (x, k_pages, v_pages)``
+    over the layers with the pool as the loop's CARRY: one buffer from
+    the program's argument to its result, updated in place.
+
+    ``k_pages`` / ``v_pages`` are the whole pool in a flat view,
+    ``[L * num_pages, Hkv, P, Dh]`` (a bitcast), and ``base`` is the
+    layer's first page in it: a layer reaches its pages by adding
+    ``base`` to page ids, never by slicing itself out. (Scanned as an
+    input and stacked as an output, every layer's 100 MB of pages was
+    sliced out of one stack and written into another, and the entry
+    copied both stacks.)
+    """
+    n_layers, num_pages = pool["k"].shape[:2]
+
+    def flat(a):
+        return a.reshape((n_layers * num_pages,) + a.shape[2:])
+
+    def step(carry, layer):
+        p, index = layer
+        return body(*carry, p, index * num_pages), None
+
+    (x, k_pages, v_pages), _ = jax.lax.scan(
+        step,
+        (x, flat(pool["k"]), flat(pool["v"])),
+        (params["blocks"], jnp.arange(n_layers, dtype=jnp.int32)),
+    )
+    return x, {
+        "k": k_pages.reshape(pool["k"].shape),
+        "v": v_pages.reshape(pool["v"].shape),
+    }
+
+
 @partial(
     jax.jit,
     static_argnames=("cfg", "n_write_pages"),
@@ -220,31 +268,28 @@ def paged_prefill(
 
     from ray_tpu.ops.attention import causal_attention
 
-    def body(x, layer):
-        p, k_pool, v_pool = layer  # k_pool [num_pages, Hkv, P, Dh]
+    def body(x, k_pages, v_pages, p, base):
         q, k, v = _project_qkv(x, p, cfg)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
         attn = causal_attention(q, k, v)
         x = x + attn.reshape(x.shape) @ p["wo"]
         x = _mlp(x, p, cfg)
-        # [1, S, Hkv, Dh] → [n_pages, P, Hkv, Dh] scatter at page ids.
+        # [1, S, Hkv, Dh] → [n_pages, Hkv, P, Dh] scatter at page ids.
         kp = k.astype(cfg.dtype).reshape(
             n_write_pages, page_size, cfg.n_kv_heads, cfg.head_dim
         ).transpose(0, 2, 1, 3)
         vp = v.astype(cfg.dtype).reshape(
             n_write_pages, page_size, cfg.n_kv_heads, cfg.head_dim
         ).transpose(0, 2, 1, 3)
-        k_pool = k_pool.at[pages].set(kp)
-        v_pool = v_pool.at[pages].set(vp)
-        return x, (k_pool, v_pool)
+        k_pages = k_pages.at[base + pages].set(kp)
+        v_pages = v_pages.at[base + pages].set(vp)
+        return x, k_pages, v_pages
 
-    x, (k_pool, v_pool) = jax.lax.scan(
-        body, x, (params["blocks"], pool["k"], pool["v"])
-    )
+    x, pool = _scan_layers(body, x, params, pool)
     x = rms_norm(x, params["final_norm"])
     logits = (x @ params["lm_head"]).astype(jnp.float32)
-    return logits, {"k": k_pool, "v": v_pool}
+    return logits, pool
 
 
 @partial(
@@ -289,8 +334,7 @@ def paged_prefill_chunk(
     key_idx = jnp.arange(window)[None, None, :]
     mask = key_idx > pos[:, :, None]  # [1, C, window]
 
-    def body(x, layer):
-        p, k_pool, v_pool = layer
+    def body(x, k_pages, v_pages, p, base):
         q, k, v = _project_qkv(x, p, cfg)  # [1, C, H, Dh]
         q = apply_rope(q, cos, sin, positions=pos)
         k = apply_rope(k, cos, sin, positions=pos)
@@ -300,21 +344,19 @@ def paged_prefill_chunk(
         vp = v.astype(cfg.dtype).reshape(
             chunk_pages, page_size, cfg.n_kv_heads, cfg.head_dim
         ).transpose(0, 2, 1, 3)
-        k_pool = k_pool.at[chunk_slice].set(kp)
-        v_pool = v_pool.at[chunk_slice].set(vp)
+        k_pages = k_pages.at[base + chunk_slice].set(kp)
+        v_pages = v_pages.at[base + chunk_slice].set(vp)
         attn = _gather_page_attention(
-            q, k_pool, v_pool, pages[None, :], mask, cfg
+            q, k_pages, v_pages, base + pages[None, :], mask, cfg
         )
         x = x + attn.reshape(1, c, -1) @ p["wo"]
         x = _mlp(x, p, cfg)
-        return x, (k_pool, v_pool)
+        return x, k_pages, v_pages
 
-    x, (k_pool, v_pool) = jax.lax.scan(
-        body, x, (params["blocks"], pool["k"], pool["v"])
-    )
+    x, pool = _scan_layers(body, x, params, pool)
     x = rms_norm(x, params["final_norm"])
     logits = (x @ params["lm_head"]).astype(jnp.float32)
-    return logits, {"k": k_pool, "v": v_pool}
+    return logits, pool
 
 
 def paged_decode(
@@ -414,45 +456,52 @@ def paged_verify(
     )
     write_pages = jnp.where(pos2d < window, write_pages, 0)  # [B, K]
 
-    def body(x, layer):
-        p, k_pool, v_pool = layer
+    tables = jnp.maximum(block_tables, 0)
+
+    def body(x, k_pages, v_pages, p, base):
         q, k, v = _project_qkv(x, p, cfg)  # [B, K, H, Dh]
         q = apply_rope(q, cos, sin, positions=pos2d)
         k = apply_rope(k, cos, sin, positions=pos2d)
+        k = k.astype(cfg.dtype)
+        v = v.astype(cfg.dtype)
 
-        # Scatter all K cells per slot (drafts may span a page
-        # boundary — each position indexes its own physical page).
-        # Advanced indices at dims 0 and 2 with the Hkv slice
-        # between: result dims are [B, K, Hkv, Dh], matching k.
-        k_pool = k_pool.at[write_pages, :, off_of, :].set(
-            k.astype(cfg.dtype)
-        )
-        v_pool = v_pool.at[write_pages, :, off_of, :].set(
-            v.astype(cfg.dtype)
-        )
-
+        # Write all K cells per slot (drafts may span a page boundary —
+        # each position indexes its own physical page), then attend.
+        # The write follows the attention's path, so that one party
+        # fixes the pool's layout (module docstring).
         if use_kernel:
-            # Pallas path: pages read in place, GQA-grouped, per-slot
-            # length early-exit (see ops/pallas/paged_attention.py).
+            # Pallas path: cells patched into their pages in place,
+            # slot-major (a slot's drafts on consecutive grid steps, as
+            # write_kv_cells needs); pages read in place, GQA-grouped,
+            # per-slot length early-exit (ops/pallas/paged_attention.py).
+            from ray_tpu.ops.pallas.kv_cell_write import write_kv_cells
             from ray_tpu.ops.pallas.paged_attention import paged_attention
 
+            interpret = chip.platform() != "tpu"
+            k_pages, v_pages = write_kv_cells(
+                k_pages, v_pages,
+                k.reshape(b * kk_w, cfg.n_kv_heads, cfg.head_dim),
+                v.reshape(b * kk_w, cfg.n_kv_heads, cfg.head_dim),
+                (base + write_pages).reshape(-1), off_of.reshape(-1),
+                interpret=interpret,
+            )
             attn = paged_attention(
-                q, k_pool, v_pool, block_tables, positions,
-                n_kv_heads=cfg.n_kv_heads,
-                interpret=chip.platform() != "tpu",
+                q, k_pages, v_pages, base + tables, positions,
+                n_kv_heads=cfg.n_kv_heads, interpret=interpret,
             )
         else:
+            # Advanced indices at dims 0 and 2 with the Hkv slice
+            # between: result dims are [B, K, Hkv, Dh], matching k.
+            k_pages = k_pages.at[base + write_pages, :, off_of, :].set(k)
+            v_pages = v_pages.at[base + write_pages, :, off_of, :].set(v)
             attn = _gather_page_attention(
-                q, k_pool, v_pool, jnp.maximum(block_tables, 0),
-                mask, cfg,
+                q, k_pages, v_pages, base + tables, mask, cfg
             )
         x = x + attn.reshape(b, kk_w, -1) @ p["wo"]
         x = _mlp(x, p, cfg)
-        return x, (k_pool, v_pool)
+        return x, k_pages, v_pages
 
-    x, (k_pool, v_pool) = jax.lax.scan(
-        body, x, (params["blocks"], pool["k"], pool["v"])
-    )
+    x, pool = _scan_layers(body, x, params, pool)
     x = rms_norm(x, params["final_norm"])
     logits = (x @ params["lm_head"]).astype(jnp.float32)
 
@@ -527,7 +576,7 @@ def paged_verify(
         accept,
         rej,
         logits[:, 0],
-        {"k": k_pool, "v": v_pool},
+        pool,
     )
 
 

@@ -24,9 +24,18 @@ Numerics follow the flash kernel (online softmax with finite mask
 values, fp32 accumulation); outputs match the XLA gather path to fp
 tolerance, and greedy token streams are identical (gated by tests).
 
-The pool layout is HEAD-major ([pages, Hkv, P, Dh]): each KV head's
-page tile is a contiguous slice, measured ~40% faster than page-major
-for the kernel. NOTE the honest caveat: the same round also rewrote
+The pool layout is HEAD-major ([pages, Hkv, P, Dh]) and, as for every
+Mosaic operand, row-major in memory: each KV head's page tile is a
+contiguous slice, measured ~40% faster than page-major for the kernel.
+THIS KERNEL FIXES THE POOL'S LAYOUT for the program it is in: whatever
+else touches the pool there must leave it row-major, or XLA re-lays the
+whole pool out before every call. So the decode program writes its
+cells with ``kv_cell_write.write_kv_cells`` (a Mosaic call, the pool
+aliased to its result) and not with an XLA scatter, and carries the
+pool through its layer loop (``llm/paged_kv.py _scan_layers``): the
+``k_pool`` / ``v_pool`` here are that loop's carry, all layers' pages in
+one flat view, with the layer's page base already in ``block_tables``.
+NOTE the honest caveat: the same round also rewrote
 the XLA gather fallback (einsum-folded, GQA-grouped, no repeat) which
 brought IT from 17.4 ms to ~4.6 ms at 32/8 heads — at this window
 size the kernel's remaining edge is 1.1-1.3x, and its structural

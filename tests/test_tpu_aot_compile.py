@@ -6,9 +6,17 @@ section 2). This finds what interpret mode cannot — misaligned tiles, too
 much fast memory, a kernel the compiler replaces — at about two seconds a
 case and no chip time. Widths are Llama-3-8B's, as chip_smoke.py runs
 them: 32 query / 8 KV heads of 128.
+
+The serving programs (llm/paged_kv.py) are compiled whole, at Mistral-7B
+widths with a two-layer page pool of the benchmark's size, for what only
+the compiled text shows: that nothing copies, slices out or writes back
+a layer's pages or more.
 """
 
+import math
 import os
+import re
+from functools import partial
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs under /tmp
 
@@ -104,3 +112,110 @@ def test_kernel_compiles_for_v5e_at_llama3_8b_widths(v5e, case):
     }[case]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# ------------------------------------------------- the serving programs
+# mistral7b-serve1's shapes (benchmarks/configs): 32 slots, 768 pages of
+# 64 tokens and the dump page, max_seq 8448, prefill_chunk 2048; 2 of
+# its 6 layers, enough for a loop.
+POOL_PAGES, PAGE, SLOTS, MAX_PAGES = 769, 64, 32, 132
+LAYER_PAGES_ELEMS = POOL_PAGES * HKV * PAGE * DH
+
+_MOVES = re.compile(
+    r"=\s+(\(?[a-z0-9]+\[[^=]*?)\s+"
+    r"(copy|copy-start|dynamic-slice|dynamic-update-slice)\("
+)
+_SHAPE = re.compile(r"[a-z0-9]+\[([\d,]+)\]")
+
+
+def _pool_moves(text: str) -> list[str]:
+    """Instructions of a compiled program, fused ones included, that
+    copy, slice or write back an array of K/V pages (``[..., Hkv, P,
+    Dh]``) the size of one layer's pages or more. A scatter or a kernel
+    that updates the pool in place is not among them; nor are a layer's
+    own weights, sliced out of their stack by the same loop."""
+    found = []
+    for line in text.splitlines():
+        m = _MOVES.search(line)
+        if not m:
+            continue
+        for dims in _SHAPE.findall(m.group(1)):
+            shape = tuple(int(d) for d in dims.split(","))
+            if (
+                shape[-3:] == (HKV, PAGE, DH)
+                and math.prod(shape) >= LAYER_PAGES_ELEMS
+            ):
+                found.append(f"{m.group(2)} {m.group(1)}")
+    return found
+
+
+def _serving_program(case: str, on):
+    from ray_tpu.llm import paged_kv
+    from ray_tpu.llm.kv_cache import matmul_weights
+    from ray_tpu.models.llama import LlamaConfig, init_params
+
+    cfg = LlamaConfig(
+        vocab_size=32768, d_model=H * DH, n_layers=2, n_heads=H,
+        n_kv_heads=HKV, d_ff=14336, max_seq=MAX_PAGES * PAGE,
+        rope_theta=1e6,
+    )
+
+    def shaped(tree):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=on),
+            tree,
+        )
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=on)
+
+    # The weights as LLMEngine holds them: matmul leaves in cfg.dtype.
+    params = shaped(
+        jax.eval_shape(
+            lambda key: matmul_weights(init_params(key, cfg=cfg), cfg),
+            jax.random.key(0),
+        )
+    )
+    pages = jax.ShapeDtypeStruct(
+        (cfg.n_layers, POOL_PAGES, HKV, PAGE, DH), cfg.dtype
+    )
+    pool = shaped({"k": pages, "v": pages})
+    if case.startswith("verify"):
+        k = int(case[-1])
+        return paged_kv.paged_verify.lower(
+            params, i32(SLOTS, k), pool, i32(SLOTS, MAX_PAGES), i32(SLOTS),
+            jax.ShapeDtypeStruct((SLOTS,), jnp.float32, sharding=on),
+            shaped(jax.eval_shape(partial(jax.random.key, 0))),
+            cfg=cfg, use_kernel=True, stochastic=False,
+        )
+    if case == "prefill_1024":
+        return paged_kv.paged_prefill.lower(
+            params, i32(1, 1024), pool, i32(1024 // PAGE), cfg=cfg,
+            n_write_pages=1024 // PAGE,
+        )
+    return paged_kv.paged_prefill_chunk.lower(
+        params, i32(1, 2048), pool, i32(8192 // PAGE), i32(), cfg=cfg,
+        n_write_pages=8192 // PAGE, chunk_pages=2048 // PAGE,
+    )
+
+
+@pytest.mark.parametrize(
+    "case",
+    ["verify_k1", "verify_k4", "prefill_1024", "prefill_chunk_2048_of_8192"],
+)
+def test_serving_program_moves_no_layer_of_pages(v5e, case, monkeypatch):
+    """The pool is one buffer in one layout from argument to result
+    (llm/paged_kv.py): carried through the layer loop, written in place.
+    Scanned in and stacked out, or scattered by XLA beside the Pallas
+    attention, each program re-laid-out or copied 100 MB of pages
+    several times a layer."""
+    from ray_tpu._private import chip
+
+    # The program asks which platform it runs on to choose between the
+    # Mosaic kernels and their interpreter: here it is compiled for the
+    # chip, from a CPU host.
+    monkeypatch.setattr(chip, "platform", lambda: "tpu")
+    text = _serving_program(case, v5e).compile().as_text()
+    assert _pool_moves(text) == []
+    if case.startswith("verify"):
+        assert "tpu_custom_call" in text

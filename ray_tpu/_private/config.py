@@ -423,11 +423,6 @@ CONFIG_DEFS: dict[str, tuple[type, Any, str]] = {
                                                 "saturated-but-alive "
                                                 "replicas keep queueing "
                                                 "instead"),
-    "LLM_PREFILL_DELAY": (float, 0.0, "chaos spec: sleep this long "
-                                      "inside every LLM engine prefill "
-                                      "admission (deterministic TTFT "
-                                      "injection for serve-tracing "
-                                      "tests)"),
     "MEM_TELEMETRY": (bool, True, "device/host memory sampling + "
                                   "subsystem byte registration + OOM "
                                   "forensics (always-cheap; 0 makes "

@@ -1,19 +1,23 @@
 """LLMEngine: continuous-batching inference over the static-shape
-prefill/decode programs.
+programs of `paged_kv.py`.
 
 Plays the role of vLLM's engine in the reference stack (SURVEY.md §2.4:
 ray.llm passes TP/PP sizes to vLLM and gang-schedules its workers).
 TPU-native shape: tensor parallelism is not worker processes — it is the
-same two XLA programs pjit-sharded over a mesh's 'tp' axis, so adding
+same XLA programs pjit-sharded over a mesh's 'tp' axis, so adding
 chips changes a sharding annotation, not the orchestration.
 
-Slot model: the KV cache holds `max_batch` rows. add_request() parks
-requests in a FIFO; step() admits queued requests into free slots
-(one prefill each, bucketed to power-of-two lengths to bound compile
-count) and then advances all active slots with one decode program.
+One cache, the page pool (`paged_kv.init_paged_kv`), and three programs
+over it: `paged_prefill` (a whole prompt, bucketed to power-of-two
+lengths to bound compile count), `paged_prefill_chunk` (one chunk of a
+long prompt) and `paged_verify` (the decode program: K = 1 + `speculate`
+tokens a slot). add_request() parks requests in a FIFO; step() admits
+queued requests into free slots while their pages fit the pool, runs at
+most one prefill chunk, and then advances all `max_batch` slots with
+one decode program.
 
 Weights: the engine holds `self.params` as its programs multiply, the
-matmul weights and the embedding in `cfg.dtype` (`kv_cache.matmul_weights`,
+matmul weights and the embedding in `cfg.dtype` (`paged_kv.matmul_weights`,
 once, in `__init__`), the norm scales as given; `stats()["param_bytes"]`
 is that tree's size. An fp32 tree handed in as `params=` stays the
 caller's, untouched.
@@ -28,6 +32,7 @@ observability" lists them.
 from __future__ import annotations
 
 import itertools
+import os
 import threading
 import time
 from contextlib import contextmanager
@@ -40,11 +45,15 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from ray_tpu.llm.kv_cache import (
-    forward_decode,
-    forward_prefill,
-    init_kv_cache,
+from ray_tpu.llm.paged_kv import (
+    PageAllocator,
+    init_paged_kv,
     matmul_weights,
+    paged_prefill,
+    paged_prefill_chunk,
+    paged_verify,
+    prefix_hashes,
+    propose_ngram_draft,
 )
 from ray_tpu.models.llama import LlamaConfig, PRESETS, init_params, param_logical_axes
 
@@ -68,7 +77,7 @@ class _Request:
     position: int = 0  # index the NEXT token will be written at
     last_token: int = 0
     done: bool = False
-    pages: list = field(default_factory=list)  # paged mode: block table
+    pages: list = field(default_factory=list)  # block table
     # Request-path timing (wall clock), the feed for the serve:prefill /
     # serve:decode spans and TTFT/TPOT histograms. First-write-wins so a
     # preemption's recompute re-admission never resets TTFT.
@@ -106,7 +115,7 @@ class LLMEngine:
         mesh=None,
         params=None,
         seed: int = 0,
-        kv: str = "paged",  # "paged" (block-table pool) | "dense" (slab)
+        kv: str = "paged",  # the one cache; kept for callers that name it
         page_size: int = 64,
         num_pages: int | None = None,
         speculate: int = 0,  # draft tokens per step (prompt lookup)
@@ -115,6 +124,11 @@ class LLMEngine:
     ):
         from ray_tpu._private import chip
 
+        if kv != "paged":
+            raise ValueError(
+                f"kv={kv!r}: the dense slab cache was removed; the page "
+                "pool ('paged') is the engine's only cache"
+            )
         init_began = time.perf_counter()
         # Where this engine runs: the platform the worker's lease fixed
         # (raises if a chip was promised and cannot be opened).
@@ -141,119 +155,75 @@ class LLMEngine:
         ]:
             params = _cast_weights(params, cfg=cfg)
         self.params = params
-        if kv not in ("paged", "dense"):
-            raise ValueError(f"kv must be 'paged' or 'dense', got {kv!r}")
-        self.kv = kv
         self.page_size = page_size
         self.prefill_delay_s = float(prefill_delay_s)
-
-        # Flash prefill on a bare TPU backend; under a mesh the dense
-        # path keeps XLA's SPMD partitioner in charge.
-        use_flash = mesh is None and self.platform == "tpu"
-        if speculate and kv != "paged":
-            raise ValueError("speculative decoding needs kv='paged'")
-        if prefill_chunk is not None and kv != "paged":
-            raise ValueError("chunked prefill needs kv='paged'")
         self.speculate = int(speculate)
-        if kv == "paged":
-            from ray_tpu.llm.paged_kv import (
-                PageAllocator,
-                init_paged_kv,
-                paged_decode,
-                paged_prefill,
-                paged_verify,
+
+        # Default token budget: every slot can grow to max_seq. Serving
+        # deployments pass a smaller num_pages to run memory-bound
+        # admission (the point: many variable-length requests share one
+        # budget).
+        if num_pages is None:
+            num_pages = max(
+                (max_batch * self.max_seq) // page_size, max_batch
             )
+        self.alloc = PageAllocator(num_pages, page_size)
+        # +1: physical page 0 is the allocator's dump page.
+        if (
+            mesh is not None
+            and mesh.shape.get("tp", 1) > 1
+            and cfg.n_kv_heads % mesh.shape["tp"] == 0
+        ):
+            # Shard the pool on the KV-head dim over tp (the
+            # head-major layout's natural TP split): each chip
+            # holds 1/tp of the KV bytes — the reference's
+            # tensor_parallel_size KV split — and the attention
+            # einsums contract per-head, so SPMD needs no
+            # resharding on the hot path. Allocated DIRECTLY
+            # sharded (out_shardings on the zeros program): pools
+            # are sized toward per-chip HBM x tp, so a transient
+            # unsharded replica would OOM at init.
+            from jax.sharding import NamedSharding
+            from jax.sharding import PartitionSpec as P
 
-            # Default token budget matches the dense slab so existing
-            # callers see identical capacity; serving deployments pass a
-            # smaller num_pages to run memory-bound admission (the
-            # point: many variable-length requests share one budget).
-            if num_pages is None:
-                num_pages = max(
-                    (max_batch * self.max_seq) // page_size, max_batch
-                )
-            self.alloc = PageAllocator(num_pages, page_size)
-            # +1: physical page 0 is the allocator's dump page.
-            if (
-                mesh is not None
-                and mesh.shape.get("tp", 1) > 1
-                and cfg.n_kv_heads % mesh.shape["tp"] == 0
-            ):
-                # Shard the pool on the KV-head dim over tp (the
-                # head-major layout's natural TP split): each chip
-                # holds 1/tp of the KV bytes — the reference's
-                # tensor_parallel_size KV split — and the attention
-                # einsums contract per-head, so SPMD needs no
-                # resharding on the hot path. Allocated DIRECTLY
-                # sharded (out_shardings on the zeros program): pools
-                # are sized toward per-chip HBM x tp, so a transient
-                # unsharded replica would OOM at init.
-                from jax.sharding import NamedSharding
-                from jax.sharding import PartitionSpec as P
-
-                ns = NamedSharding(
-                    mesh, P(None, None, "tp", None, None)
-                )
-                self.cache = jax.jit(
-                    partial(
-                        init_paged_kv, cfg, num_pages + 1, page_size
-                    ),
-                    out_shardings={"k": ns, "v": ns},
-                )()
-            else:
-                self.cache = init_paged_kv(
-                    cfg, num_pages + 1, page_size
-                )
-            self.max_pages_per_seq = -(-self.max_seq // page_size)
-            # Pallas paged-attention kernel on a bare TPU backend (the
-            # sharded path keeps XLA's SPMD partitioner in charge, like
-            # use_flash above). RAY_TPU_PAGED_ATTN=0/1 overrides — =1
-            # on CPU runs the kernel interpreted (parity tests).
-            import os
-
-            # tpulint: allow(TPU703 reason=emergency kernel off-switch read in library code that must work without a live runtime or config registry)
-            env_flag = os.environ.get("RAY_TPU_PAGED_ATTN", "").strip()
-            if env_flag in ("0", "1"):
-                use_kernel = env_flag == "1"
-            else:
-                use_kernel = mesh is None and self.platform == "tpu"
-            self.paged_attn_kernel = use_kernel
-            # Chunked prefill: a prompt longer than the chunk is
-            # prefilled one page-aligned chunk per step(), interleaved
-            # with decode — one long admission no longer stalls every
-            # in-flight request for its full dense pass (reference
-            # capability: vLLM chunked prefill behind ray.llm).
-            if prefill_chunk is not None:
-                prefill_chunk = max(
-                    -(-prefill_chunk // page_size) * page_size, page_size
-                )
-            self.prefill_chunk = prefill_chunk
-            self._prefilling: dict | None = None
-            from ray_tpu.llm.paged_kv import paged_prefill_chunk
-
-            self._prefill_chunk_fn = partial(paged_prefill_chunk, cfg=cfg)
-            self._prefill_paged = partial(paged_prefill, cfg=cfg)
-            self._decode_paged = partial(
-                paged_decode, cfg=cfg, use_kernel=use_kernel
-            )
-            self._verify_paged = partial(
-                paged_verify, cfg=cfg, use_kernel=use_kernel
-            )
-            self._step_key = jax.random.key(seed)
-            self._temps = np.zeros((max_batch,), np.float32)
+            ns = NamedSharding(mesh, P(None, None, "tp", None, None))
+            self.cache = jax.jit(
+                partial(init_paged_kv, cfg, num_pages + 1, page_size),
+                out_shardings={"k": ns, "v": ns},
+            )()
         else:
-            self.prefill_chunk = None
-            self._prefilling = None
-            self.cache = init_kv_cache(cfg, max_batch, self.max_seq)
-            # donate the cache slab: without donation every functional
-            # .at[].set update forces XLA to copy the whole cache.
-            self._prefill = jax.jit(
-                partial(forward_prefill, cfg=cfg, use_flash=use_flash),
-                donate_argnums=(2,),
+            self.cache = init_paged_kv(cfg, num_pages + 1, page_size)
+        self.max_pages_per_seq = -(-self.max_seq // page_size)
+        # Pallas paged-attention kernel on a bare TPU backend (under a
+        # mesh XLA's SPMD partitioner stays in charge).
+        # RAY_TPU_PAGED_ATTN=0/1 overrides — =1 on CPU runs the kernel
+        # interpreted (parity tests).
+        # tpulint: allow(TPU703 reason=emergency kernel off-switch read in library code that must work without a live runtime or config registry)
+        env_flag = os.environ.get("RAY_TPU_PAGED_ATTN", "").strip()
+        if env_flag in ("0", "1"):
+            use_kernel = env_flag == "1"
+        else:
+            use_kernel = mesh is None and self.platform == "tpu"
+        self.paged_attn_kernel = use_kernel
+        # Chunked prefill: a prompt longer than the chunk is
+        # prefilled one page-aligned chunk per step(), interleaved
+        # with decode — one long admission no longer stalls every
+        # in-flight request for its full dense pass (reference
+        # capability: vLLM chunked prefill behind ray.llm).
+        if prefill_chunk is not None:
+            prefill_chunk = max(
+                -(-prefill_chunk // page_size) * page_size, page_size
             )
-            self._decode = jax.jit(
-                partial(forward_decode, cfg=cfg), donate_argnums=(2,)
-            )
+        self.prefill_chunk = prefill_chunk
+        self._prefilling: dict | None = None
+        self._prefill_chunk_fn = partial(paged_prefill_chunk, cfg=cfg)
+        self._prefill_paged = partial(paged_prefill, cfg=cfg)
+        # The one decode program, K = 1 + speculate tokens a slot.
+        self._decode_paged = partial(
+            paged_verify, cfg=cfg, use_kernel=use_kernel
+        )
+        self._step_key = jax.random.key(seed)
+        self._temps = np.zeros((max_batch,), np.float32)
         self._queue: list[_Request] = []
         self._active: dict[int, _Request] = {}  # slot → request
         self._free = list(range(max_batch))
@@ -331,22 +301,19 @@ class LLMEngine:
                     f"prompt length {len(prompt)} >= max_seq {self.max_seq}"
                 )
             sampling = sampling or SamplingParams()
-            if self.kv == "paged":
-                # Reject requests the pool could NEVER hold (prompt plus
-                # its full max_tokens growth) at submission — admitting
-                # one and crashing mid-decode would take every in-flight
-                # request down with it.
-                P = self.page_size
-                worst = min(len(prompt) + sampling.max_tokens, self.max_seq)
-                pad = min(
-                    max(_bucket(worst), P), self.max_pages_per_seq * P
+            # Reject requests the pool could NEVER hold (prompt plus
+            # its full max_tokens growth) at submission — admitting
+            # one and crashing mid-decode would take every in-flight
+            # request down with it.
+            P = self.page_size
+            worst = min(len(prompt) + sampling.max_tokens, self.max_seq)
+            pad = min(max(_bucket(worst), P), self.max_pages_per_seq * P)
+            if pad // P > self.alloc.num_pages:
+                raise ValueError(
+                    f"prompt+max_tokens needs {pad // P} pages but the "
+                    f"pool holds {self.alloc.num_pages}; raise "
+                    "num_pages or lower max_tokens"
                 )
-                if pad // P > self.alloc.num_pages:
-                    raise ValueError(
-                        f"prompt+max_tokens needs {pad // P} pages but the "
-                        f"pool holds {self.alloc.num_pages}; raise "
-                        "num_pages or lower max_tokens"
-                    )
             rid = request_id or f"req-{next(self._ids)}"
             # Stamped before the wait for `_lock`, so that queue_s and
             # ttft_s count it.
@@ -364,22 +331,17 @@ class LLMEngine:
         """Mark prefill start (first-write-wins, so a preemption's
         re-admission counts neither as admitted nor as queue wait again)
         and apply the injected prefill delay (the ``prefill_delay_s``
-        engine kwarg, or the RAY_TPU_LLM_PREFILL_DELAY env knob) — a
-        deterministic TTFT injection the serve-tracing tests bound spans
-        against. ``span`` is the admission's `engine:admit`."""
+        engine kwarg) — a deterministic TTFT injection the serve-tracing
+        tests bound spans against. ``span`` is the admission's
+        `engine:admit`."""
         if req.prefill_start_ts == 0.0:
             req.prefill_start_ts = time.time()
             waited = max(0.0, req.prefill_start_ts - req.submit_ts)
             self._stats["admitted"] += 1
             self._stats["queue_wait_s_sum"] += waited
             span.set_metadata(queue_ms=round(waited * 1e3, 3))
-        delay = self.prefill_delay_s
-        if delay <= 0:
-            from ray_tpu._private import config
-
-            delay = config.get("LLM_PREFILL_DELAY")
-        if delay > 0:
-            time.sleep(delay)
+        if self.prefill_delay_s > 0:
+            time.sleep(self.prefill_delay_s)
 
     def has_unfinished(self) -> bool:
         return bool(
@@ -457,39 +419,19 @@ class LLMEngine:
         return t
 
     def _release_pages(self, req: _Request) -> None:
-        if self.kv == "paged":
-            for pg in req.pages:
-                self.alloc.release(pg)
-            req.pages = []
+        for pg in req.pages:
+            self.alloc.release(pg)
+        req.pages = []
 
     def _admit(self, finished: list[dict]) -> None:
         while self._queue and self._free:
-            if self.kv == "paged":
-                if not self._admit_one_paged(finished):
-                    return
-                continue
-            req = self._queue.pop(0)
-            with TraceAnnotation(
-                "engine:admit", rid=req.request_id,
-                prompt_len=len(req.prompt),
-            ) as span:
-                slot = self._free.pop(0)
-                self._begin_prefill(req, span)
-                pad = min(_bucket(len(req.prompt)), self.max_seq)
-                tokens = np.zeros((1, pad), np.int32)
-                tokens[0, : len(req.prompt)] = req.prompt
-                logits, self.cache = self._prefill(
-                    self.params, jnp.asarray(tokens), self.cache,
-                    jnp.int32(slot),
-                )
-                self._post_prefill(
-                    req, slot, logits, len(req.prompt), finished
-                )
+            if not self._admit_one(finished):
+                return
 
     def _post_prefill(
         self, req, slot, logits, ctx_len, finished, logit_idx=None
     ) -> None:
-        """Shared dense/paged tail of admission: sample the next token
+        """The tail of admission, whole or chunked: sample the next token
         from the context's last logits, activate, run stop checks.
         ctx_len is the true (unpadded) prefilled length — prompt plus
         any tokens generated before a preemption. logit_idx overrides
@@ -518,15 +460,12 @@ class LLMEngine:
             if not self._finish_if_done(req, finished):
                 self._tokens[slot, 0] = req.last_token
                 self._positions[slot] = req.position
-                if self.kv == "paged":
-                    self._temps[slot] = req.sampling.temperature
+                self._temps[slot] = req.sampling.temperature
 
-    def _admit_one_paged(self, finished: list[dict]) -> bool:
-        """Admit the head of the queue if its pages fit the pool —
-        MEMORY-bound admission (the dense engine is slot-bound). Returns
-        False when the pool cannot hold the next request yet."""
-        from ray_tpu.llm.paged_kv import prefix_hashes
-
+    def _admit_one(self, finished: list[dict]) -> bool:
+        """Admit the head of the queue if its pages fit the pool:
+        admission is MEMORY-bound, not slot-bound. Returns False when
+        the pool cannot hold the next request yet."""
         if self._prefilling is not None:
             # One chunked prefill at a time: its pages are committed and
             # its chunks are the per-step prefill budget already.
@@ -668,43 +607,9 @@ class LLMEngine:
                 # step bounds the stall it adds to this step's decodes.
                 self._prefill_step(finished)
             self._admit(finished)
-            if not self._active:
-                return finished
-            if self.kv == "paged":
+            if self._active:
                 self._step_paged(finished)
-                return finished
-
-            self._count_decode_step()
-            with TraceAnnotation("engine:decode_dispatch"):
-                logits, self.cache = self._decode(
-                    self.params,
-                    jnp.asarray(self._tokens),
-                    self.cache,
-                    jnp.asarray(self._positions),
-                )
-            with TraceAnnotation("engine:decode_sync"):
-                logits = np.asarray(logits)
-            with self._emit_span(finished):
-                for slot, req in list(self._active.items()):
-                    tok = self._sample(logits[slot], req.sampling)
-                    self._record_token(req, tok, finished)
         return finished
-
-    def _count_decode_step(self) -> None:
-        self._stats["decode_steps"] += 1
-        self._stats["slot_steps"] += len(self._active)
-
-    @contextmanager
-    def _emit_span(self, finished: list[dict]):
-        """`engine:emit` around a step's `_record_token` loop, with what
-        the loop emitted and finished."""
-        with TraceAnnotation("engine:emit") as span:
-            tokens, done = self._stats["tokens_generated"], len(finished)
-            yield
-            span.set_metadata(
-                tokens=self._stats["tokens_generated"] - tokens,
-                finished=len(finished) - done,
-            )
 
     def _record_token(self, req, tok: int, finished: list[dict]) -> None:
         req.position += 1
@@ -739,6 +644,19 @@ class LLMEngine:
         return bool(s.top_k) and s.temperature > 0
 
     def _step_paged(self, finished: list[dict]) -> None:
+        """One decode step for every active slot: K = 1 + speculate
+        positions a slot in one dispatch of the one decode program.
+
+        With speculation (reference capability: vLLM speculative
+        decoding behind ray.llm) columns 1.. of the token matrix are
+        prompt-lookup drafts and the longest draft prefix the model
+        agrees with is accepted. Greedy slots accept on argmax equality
+        (bit-identical to plain decode); stochastic slots use exact
+        rejection sampling computed on device (see
+        paged_kv.paged_verify) so their emitted stream is distributed
+        exactly as plain temperature sampling. At K = 1 there is no
+        draft, nothing is accepted and a slot emits its one sampled
+        token."""
         P = self.page_size
         K = 1 + self.speculate
         with TraceAnnotation("engine:grow_tables") as span:
@@ -780,88 +698,20 @@ class LLMEngine:
             for slot, req in self._active.items():
                 tables[slot, : len(req.pages)] = req.pages
             self._step_key, sub = jax.random.split(self._step_key)
-            if self.speculate:
-                toks, draft_len = self._propose_drafts()
-        self._count_decode_step()
-        if self.speculate:
-            self._step_paged_speculative(
-                tables, sub, toks, draft_len, finished
-            )
-            return
-        with TraceAnnotation("engine:decode_dispatch"):
-            sampled, logits, self.cache = self._decode_paged(
-                self.params,
-                jnp.asarray(self._tokens),
-                self.cache,
-                jnp.asarray(tables),
-                jnp.asarray(self._positions),
-                jnp.asarray(self._temps),
-                sub,
-            )
-        with TraceAnnotation("engine:decode_sync"):
-            sampled = np.asarray(sampled)  # [B] ints
-            host_logits = self._host_logits(logits)
-        with self._emit_span(finished):
-            for slot, req in list(self._active.items()):
-                if self._host_sampled(req.sampling):
-                    tok = self._sample(host_logits[slot], req.sampling)
-                else:
-                    tok = int(sampled[slot])
-                self._record_token(req, tok, finished)
-
-    def _host_logits(self, logits) -> np.ndarray | None:
-        """The [B, V] logits on the host, only if a slot samples there."""
-        if any(self._host_sampled(r.sampling) for r in self._active.values()):
-            return np.asarray(logits)
-        return None
-
-    def _propose_drafts(self) -> tuple[np.ndarray, np.ndarray]:
-        """Prompt-lookup drafts for a speculative step: ``toks [B, K]``
-        (column 0 the last token, then the draft) and ``draft_len [B]``.
-        top_k slots run with an empty draft (their position-0 output is
-        a normal decode step)."""
-        from ray_tpu.llm.paged_kv import propose_ngram_draft
-
-        K = 1 + self.speculate
-        toks = np.zeros((self.max_batch, K), np.int32)
-        toks[:, 0] = self._tokens[:, 0]
-        draft_len = np.zeros((self.max_batch,), np.int32)
-        for slot, req in self._active.items():
-            if self._host_sampled(req.sampling):
-                continue  # host-sampled: no draft
-            draft = propose_ngram_draft(
-                req.prompt + req.out_tokens, K - 1
-            )
-            if draft:
-                draft_len[slot] = len(draft)
-                self._stats["draft_tokens_proposed"] += len(draft)
-                toks[slot, 1: 1 + len(draft)] = draft
-        return toks, draft_len
-
-    def _step_paged_speculative(
-        self, tables, sub, toks, draft_len, finished
-    ) -> None:
-        """Prompt-lookup speculative step (reference capability: vLLM
-        speculative decoding behind ray.llm): verify K = 1 + speculate
-        positions per slot in one dispatch and accept the longest
-        draft prefix the model agrees with. Greedy slots accept on
-        argmax equality (bit-identical to plain decode); stochastic
-        slots use exact rejection sampling computed on device (see
-        paged_kv.paged_verify) so their emitted stream is distributed
-        exactly as plain temperature sampling.
-
-        Acceptance is one vectorized mismatch-argmax over [B, K-1] —
-        not a per-slot interpreted loop on the serial dispatch path."""
-        K = 1 + self.speculate
+            toks, draft_len = self._propose_drafts()
+        self._stats["decode_steps"] += 1
+        self._stats["slot_steps"] += len(self._active)
         # Static flag: an all-greedy batch (the common speculative
         # configuration) skips the rejection-sampling tensors entirely
-        # — at most two compiled variants, like use_kernel.
-        any_stochastic = any(
+        # — at most two compiled variants, like use_kernel. At K = 1
+        # there are none to skip, so one variant whatever the
+        # temperatures.
+        stochastic = K > 1 and any(
             r.sampling.temperature > 0 and not r.sampling.top_k
             for r in self._active.values()
         )
         with TraceAnnotation("engine:decode_dispatch"):
-            sampled, accept, rej, logits, self.cache = self._verify_paged(
+            sampled, logits, self.cache, accept, rej = self._decode_paged(
                 self.params,
                 jnp.asarray(toks),
                 self.cache,
@@ -869,19 +719,32 @@ class LLMEngine:
                 jnp.asarray(self._positions),
                 jnp.asarray(self._temps),
                 sub,
-                stochastic=any_stochastic,
+                stochastic=stochastic,
             )
         with TraceAnnotation("engine:decode_sync"):
-            sampled = np.asarray(sampled)  # [B, K]
-            accept = np.asarray(accept)  # [B, K-1] bool
-            rej = np.asarray(rej)  # [B, K-1]
-            host_logits = self._host_logits(logits)  # [B, V]: pos 0
-        with self._emit_span(finished):
-            # Vectorized acceptance: n_acc[b] = index of the first
-            # rejected (or absent) draft position.
-            stop = ~accept
-            stop |= np.arange(K - 1)[None, :] >= draft_len[:, None]
-            n_acc = np.where(stop.any(axis=1), stop.argmax(axis=1), K - 1)
+            sampled = np.asarray(sampled)  # [B, K] ints
+            n_acc = draft_len  # no draft, none accepted: all of K = 1
+            if K > 1:
+                # Speculation's two, [B, K-1] each ([B, 0] at K = 1: not
+                # read back), and the acceptance — one mismatch-argmax
+                # over [B, K-1], not a per-slot interpreted loop on the
+                # serial dispatch path: n_acc[b] = index of the first
+                # rejected (or absent) draft position.
+                rej = np.asarray(rej)
+                stop = ~np.asarray(accept)
+                stop |= np.arange(K - 1)[None, :] >= draft_len[:, None]
+                n_acc = np.where(
+                    stop.any(axis=1), stop.argmax(axis=1), K - 1
+                )
+            # The [B, V] logits of position 0, only if a slot samples
+            # on the host.
+            host_logits = None
+            if any(
+                self._host_sampled(r.sampling) for r in self._active.values()
+            ):
+                host_logits = np.asarray(logits)
+        with TraceAnnotation("engine:emit") as span:
+            n_tokens, n_done = self._stats["tokens_generated"], len(finished)
             for slot, req in list(self._active.items()):
                 if self._host_sampled(req.sampling):
                     tok = self._sample(host_logits[slot], req.sampling)
@@ -906,6 +769,34 @@ class LLMEngine:
                         self._stats["draft_tokens_accepted"] += 1
                     if req.done:
                         break
+            span.set_metadata(
+                tokens=self._stats["tokens_generated"] - n_tokens,
+                finished=len(finished) - n_done,
+            )
+
+    def _propose_drafts(self) -> tuple[np.ndarray, np.ndarray]:
+        """The step's token matrix ``toks [B, K]`` (column 0 the last
+        token, then a prompt-lookup draft) and ``draft_len [B]``. top_k
+        slots run with an empty draft (their position-0 output is a
+        normal decode step); without speculation every slot does, and
+        the matrix is ``self._tokens``."""
+        draft_len = np.zeros((self.max_batch,), np.int32)
+        if not self.speculate:
+            return self._tokens, draft_len
+        K = 1 + self.speculate
+        toks = np.zeros((self.max_batch, K), np.int32)
+        toks[:, 0] = self._tokens[:, 0]
+        for slot, req in self._active.items():
+            if self._host_sampled(req.sampling):
+                continue  # host-sampled: no draft
+            draft = propose_ngram_draft(
+                req.prompt + req.out_tokens, K - 1
+            )
+            if draft:
+                draft_len[slot] = len(draft)
+                self._stats["draft_tokens_proposed"] += len(draft)
+                toks[slot, 1: 1 + len(draft)] = draft
+        return toks, draft_len
 
     def abort_request(self, request_id: str) -> bool:
         """Drop a request (queued or active), freeing its slot — the
@@ -943,12 +834,11 @@ class LLMEngine:
     def occupancy(self) -> dict:
         """Decode slots and pool pages in use, for the serve gauges.
         Takes no lock: the pump asks on the event loop between steps."""
-        paged = self.kv == "paged"
         return {
             "active": len(self._active),
             "max_batch": self.max_batch,
-            "pages_free": self.alloc.free_pages if paged else None,
-            "pages_total": self.alloc.num_pages if paged else None,
+            "pages_free": self.alloc.free_pages,
+            "pages_total": self.alloc.num_pages,
         }
 
     def stats(self) -> dict:
@@ -965,9 +855,7 @@ class LLMEngine:
             out = dict(self._stats)
             out["platform"] = self.platform
             out["device_kind"] = jax.devices()[0].device_kind
-            out["paged_attn_kernel"] = (
-                self.kv == "paged" and self.paged_attn_kernel
-            )
+            out["paged_attn_kernel"] = self.paged_attn_kernel
             # The decode cell write follows the attention's path
             # (paged_kv.paged_verify): Pallas and in place beside the
             # kernel, XLA's scatter beside the gather.
@@ -975,9 +863,8 @@ class LLMEngine:
             out["active_requests"] = len(self._active)
             out["queued_requests"] = len(self._queue)
             out["prefilling"] = self._prefilling is not None
-            if self.kv == "paged":
-                out["pages_total"] = self.alloc.num_pages
-                out["pages_free"] = self.alloc.free_pages
+            out["pages_total"] = self.alloc.num_pages
+            out["pages_free"] = self.alloc.free_pages
             if out["draft_tokens_proposed"]:
                 out["draft_acceptance_rate"] = round(
                     out["draft_tokens_accepted"]

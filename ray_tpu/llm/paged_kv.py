@@ -1,11 +1,12 @@
 """Paged KV cache: block-table attention over a fixed page pool.
 
-The dense cache (`llm/kv_cache.py`) allocates max_batch × max_seq slots
-up front, so HBM cost ignores actual sequence lengths. This module is
-the vLLM-style alternative the reference gets from its serving engine
-(reference: ray.llm passes engine_kwargs straight to vLLM,
+The serving engine's one cache and the programs that read and write it
+(`LLMEngine` holds no other): the vLLM-style page pool the reference gets
+from its serving engine (reference: ray.llm passes engine_kwargs straight
+to vLLM,
 python/ray/llm/_internal/serve/deployments/llm/vllm/vllm_models.py:234 —
-block_size / num_gpu_blocks are vLLM's page knobs):
+block_size / num_gpu_blocks are vLLM's page knobs). A slab of
+max_batch × max_seq slots would cost HBM whatever the sequences' lengths:
 
 - One **page pool**: ``{"k","v": [L, num_pages, Hkv, page_size, Dh]}``,
   each layer's pages HEAD-major and row-major in memory (``[page, head,
@@ -57,7 +58,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu._private import chip
-from ray_tpu.models.llama import LlamaConfig
+from ray_tpu.models.llama import LlamaConfig, Params
+from ray_tpu.ops.attention import causal_attention
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
 
@@ -122,9 +124,6 @@ class PageAllocator:
     def free_pages(self) -> int:
         return len(self._free)
 
-    def pages_needed(self, n_tokens: int) -> int:
-        return -(-n_tokens // self.page_size)
-
     def alloc(self) -> int:
         page = self._free.pop()
         self._refs[page] = 1
@@ -159,9 +158,45 @@ def prefix_hashes(tokens: list[int], page_size: int) -> list[int]:
 
 
 # ------------------------------------------------------------- programs
-# One source of truth for the per-layer blocks: divergence between the
-# paged and dense cache paths would silently change decode results.
-from ray_tpu.llm.kv_cache import _mlp, _project_qkv, matmul_weights  # noqa: E402
+# The leaves the programs below multiply by (the embedding is gathered
+# from), all in cfg.dtype. The norm scales are not among them:
+# `rms_norm` reads them as given.
+_MATMUL_BLOCK_LEAVES = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+
+
+def matmul_weights(params: Params, cfg: LlamaConfig) -> Params:
+    """`params` with every weight the serving programs multiply by in
+    cfg.dtype, the rest as given. Each program starts with it, so a raw
+    fp32 tree (`init_params`) gives what it always gave; `LLMEngine`
+    calls it once and holds the result, on which it is the identity: no
+    program of an engine then reads an fp32 stack to round it again.
+    A weight that a program multiplies by goes into this list."""
+    dt = cfg.dtype
+    blocks = dict(params["blocks"])
+    for name in _MATMUL_BLOCK_LEAVES:
+        blocks[name] = blocks[name].astype(dt)
+    return {
+        **params,
+        "tok_emb": params["tok_emb"].astype(dt),
+        "lm_head": params["lm_head"].astype(dt),
+        "blocks": blocks,
+    }
+
+
+def _project_qkv(x, p, cfg):
+    b, s, _ = x.shape
+    h = rms_norm(x, p["attn_norm"])
+    q = (h @ p["wq"]).reshape(b, s, cfg.n_heads, cfg.head_dim)
+    k = (h @ p["wk"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
+    v = (h @ p["wv"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
+    return q, k, v
+
+
+def _mlp(x, p, cfg):
+    h = rms_norm(x, p["mlp_norm"])
+    gate = jax.nn.silu(h @ p["w_gate"])
+    up = h @ p["w_up"]
+    return x + (gate * up) @ p["w_down"]
 
 
 def _gather_page_attention(q, k_pool, v_pool, page_index, mask, cfg):
@@ -266,8 +301,6 @@ def paged_prefill(
     cos, sin = rope_frequencies(cfg.head_dim, seq, cfg.rope_theta)
     x = params["tok_emb"][tokens]
 
-    from ray_tpu.ops.attention import causal_attention
-
     def body(x, k_pages, v_pages, p, base):
         q, k, v = _project_qkv(x, p, cfg)
         q = apply_rope(q, cos, sin)
@@ -359,32 +392,6 @@ def paged_prefill_chunk(
     return logits, pool
 
 
-def paged_decode(
-    params,
-    tokens: jnp.ndarray,  # [B, 1] int32
-    pool: PagedKV,
-    block_tables: jnp.ndarray,  # [B, max_pages] int32 (-1 = unused)
-    positions: jnp.ndarray,  # [B] int32: position this token writes at
-    temperature: jnp.ndarray,  # [B] fp32 (0 = greedy)
-    rng_key: jnp.ndarray,
-    cfg: LlamaConfig,
-    use_kernel: bool = False,
-):
-    """One decode step over the page pool — exactly the K=1 case of
-    :func:`paged_verify` (one source of truth for the page-attention
-    body; divergence between cache paths would silently change decode
-    results). Sampling happens ON DEVICE — the host receives [B]
-    token ids, not [B, V] logits.
-
-    Returns (sampled [B] int32, logits [B, V] fp32, pool).
-    """
-    sampled, _accept, _rej, logits, pool = paged_verify(
-        params, tokens, pool, block_tables, positions, temperature,
-        rng_key, cfg=cfg, use_kernel=use_kernel, stochastic=False,
-    )
-    return sampled[:, 0], logits, pool
-
-
 @partial(
     jax.jit,
     static_argnames=("cfg", "use_kernel", "stochastic"),
@@ -402,12 +409,14 @@ def paged_verify(
     use_kernel: bool = False,
     stochastic: bool = True,
 ):
-    """Speculative verify step: process K tokens per slot in ONE pass
-    (reference capability: vLLM's speculative/prompt-lookup decoding,
-    the serving engine behind ray.llm). tokens[:, 0] is the ordinary
-    next token; tokens[:, 1:] are HOST-PROPOSED draft tokens (n-gram
-    prompt lookup — no draft model). The engine accepts the longest
-    prefix the model agrees with, advancing up to K tokens per
+    """The decode program: K tokens per slot in ONE pass over the page
+    pool, sampled ON DEVICE (the host receives token ids, not [B, K, V]
+    logits). K = 1 is the plain decode step. K > 1 is the speculative
+    verify step (reference capability: vLLM's speculative/prompt-lookup
+    decoding, the serving engine behind ray.llm): tokens[:, 0] is the
+    ordinary next token; tokens[:, 1:] are HOST-PROPOSED draft tokens
+    (n-gram prompt lookup — no draft model). The engine accepts the
+    longest prefix the model agrees with, advancing up to K tokens per
     dispatch.
 
     Acceptance inputs are computed ON DEVICE for every slot:
@@ -428,9 +437,11 @@ def paged_verify(
     position (scatter precedes gather within each layer, and the causal
     mask hides cells beyond each query's position until then).
 
-    Returns (sampled [B, K] int32, accept [B, K-1] bool,
-    rej [B, K-1] int32 residual samples, logits [B, V] fp32 for
-    position 0, pool).
+    Returns (sampled [B, K] int32, logits [B, V] fp32 for position 0,
+    pool, accept [B, K-1] bool, rej [B, K-1] int32 residual samples):
+    what every caller reads, then speculation's two, which are [B, 0]
+    at K = 1 (``stochastic`` then changes nothing but the cache key:
+    pass False).
     """
     params = matmul_weights(params, cfg)
     b, kk_w = tokens.shape
@@ -571,13 +582,7 @@ def paged_verify(
         rej = jnp.zeros((b, 0), jnp.int32)
     # Only position 0's logits ever reach the host (top_k fallback);
     # shipping [B, K, V] would multiply that transfer by K for nothing.
-    return (
-        sampled,
-        accept,
-        rej,
-        logits[:, 0],
-        pool,
-    )
+    return sampled, logits[:, 0], pool, accept, rej
 
 
 def propose_ngram_draft(
@@ -607,18 +612,3 @@ def propose_ngram_draft(
     start = int(idx[-1])  # rightmost: recent repetition predicts best
     follow = ctx[start + ngram: start + ngram + k]
     return follow.astype(int).tolist()
-
-
-def sample_on_device(
-    logits: jnp.ndarray,  # [B, V] fp32
-    temperature: jnp.ndarray,  # [B] fp32, 0 = greedy
-    rng_key: jnp.ndarray,
-) -> jnp.ndarray:
-    """Greedy / temperature sampling without shipping logits to host.
-    Both paths are computed and the per-slot temperature selects —
-    cheaper than a lax.cond at [B,V] widths and keeps one fused program."""
-    greedy = jnp.argmax(logits, axis=-1)
-    temp = jnp.maximum(temperature, 1e-6)[:, None]
-    keys = jax.random.split(rng_key, logits.shape[0])
-    drawn = jax.vmap(jax.random.categorical)(keys, logits / temp)
-    return jnp.where(temperature > 0.0, drawn, greedy).astype(jnp.int32)

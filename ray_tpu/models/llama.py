@@ -143,7 +143,7 @@ def init_params(key: jax.Array, cfg: LlamaConfig) -> Params:
 
     The trainer keeps them fp32 and `forward` casts at use. For serving,
     `LLMEngine` casts the matmul weights to cfg.dtype once and holds
-    that tree (`llm/kv_cache.py matmul_weights`)."""
+    that tree (`llm/paged_kv.py matmul_weights`)."""
     d, f = cfg.d_model, cfg.d_ff
     hq = cfg.n_heads * cfg.head_dim
     hkv = cfg.n_kv_heads * cfg.head_dim

@@ -446,7 +446,7 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 # Default tile sizes, tuned on v5e (see flash_attention docstring);
 # exported so gating code derives fitted blocks from the SAME value the
-# kernel will use (llm/kv_cache.py).
+# kernel will use.
 DEFAULT_BLOCK = 1024
 # Backward-sweep tiles (fused one-pass kernel), tuned separately on v5e
 # at the bench shapes — the 5-matmul body pipelines best with narrower
@@ -458,7 +458,7 @@ DEFAULT_BWD_BLOCK_KV = 1024
 def _fit_block(requested: int, s: int) -> int:
     """Largest block <= requested that divides s (s itself when s fits).
     Prime-ish lengths collapse to tiny blocks — callers that can choose
-    another path should gate on the fitted size (see llm/kv_cache.py)."""
+    another path should gate on the fitted size (>= 128, multiple of 8)."""
     if s <= requested:
         return s
     for d in range(requested, 0, -1):

@@ -88,11 +88,6 @@ def test_chunked_prefill_with_prefix_sharing(params):
     assert chunked.alloc.free_pages == chunked.alloc.num_pages
 
 
-def test_chunked_prefill_requires_paged():
-    with pytest.raises(ValueError, match="chunked prefill"):
-        LLMEngine(CFG, max_batch=1, kv="dense", prefill_chunk=32)
-
-
 def test_short_prompts_skip_chunking(params):
     """Prompts at or under the chunk threshold use the single-shot
     path — no chunk state is ever created."""

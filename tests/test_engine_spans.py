@@ -117,7 +117,9 @@ def test_step_attributes(traced):
     counts = [s.attrs["step"] for s in steps]
     assert counts == list(range(counts[0], counts[0] + len(steps)))
     assert {s.attrs["max_batch"] for s in steps} == {2}
-    assert steps[0].attrs["queued"] == 2 and steps[0].attrs["active"] == 0
+    # The first step's thread starts while the event loop adds the short
+    # request: under load it may find only the long one.
+    assert steps[0].attrs["queued"] in (1, 2) and steps[0].attrs["active"] == 0
     assert any(s.attrs["prefilling"] for s in steps)
     assert steps[-1].attrs["active"] == 2
     chunks = by_name(traced[0], "engine:prefill_chunk")
@@ -174,8 +176,8 @@ def run_to_the_end(engine, prompts, max_tokens=5):
 @pytest.mark.parametrize("kwargs", [
     {"kv": "paged", "page_size": 16, "prefill_chunk": 32},
     {"kv": "paged", "page_size": 16, "speculate": 2},
-    {"kv": "dense"},
-], ids=["paged-chunked", "paged-speculative", "dense"])
+    {},
+], ids=["paged-chunked", "paged-speculative", "paged-plain"])
 def test_counters_are_consistent_at_drain(kwargs):
     engine = LLMEngine("tiny", max_batch=2, **kwargs)
     stats = run_to_the_end(engine, [LONG, SHORT, SHORT[:5]])
@@ -190,8 +192,8 @@ def test_counters_are_consistent_at_drain(kwargs):
     assert stats["lock_wait_s_sum"] >= 0
     assert engine.occupancy() == {
         "active": 0, "max_batch": 2,
-        "pages_free": stats.get("pages_free"),
-        "pages_total": stats.get("pages_total"),
+        "pages_free": stats["pages_free"],
+        "pages_total": stats["pages_total"],
     }
 
 

@@ -15,15 +15,10 @@ import numpy as np
 import pytest
 
 from ray_tpu.llm.engine import LLMEngine, SamplingParams
-from ray_tpu.llm.kv_cache import (
-    _MATMUL_BLOCK_LEAVES,
-    forward_decode,
-    forward_prefill,
-    init_kv_cache,
-    matmul_weights,
-)
 from ray_tpu.llm.paged_kv import (
+    _MATMUL_BLOCK_LEAVES,
     init_paged_kv,
+    matmul_weights,
     paged_prefill,
     paged_prefill_chunk,
     paged_verify,
@@ -97,18 +92,6 @@ def run_verify(params, k, temperature):
     )
 
 
-def run_dense(params):
-    cache = init_kv_cache(BF16, 2, 64)
-    logits, cache = forward_prefill(
-        params, tokens((1, 32)), cache, jnp.int32(1), BF16
-    )
-    step, cache = forward_decode(
-        params, tokens((2, 1), seed=2), cache,
-        jnp.asarray([0, 32], jnp.int32), BF16,
-    )
-    return logits, step, cache
-
-
 PROGRAMS = {
     "paged_prefill": run_prefill,
     "paged_prefill_chunk": run_prefill_chunk,
@@ -116,14 +99,12 @@ PROGRAMS = {
     "paged_verify_k4_greedy": lambda p: run_verify(p, 4, 0.0),
     "paged_verify_k1_sampled": lambda p: run_verify(p, 1, 0.8),
     "paged_verify_k4_sampled": lambda p: run_verify(p, 4, 0.8),
-    "dense_prefill_decode": run_dense,
 }
 
 
 @pytest.mark.parametrize("program", sorted(PROGRAMS))
 def test_held_tree_gives_what_the_fp32_tree_gives(program, raw, engine):
-    """Logits, sampled ids, acceptance and the pool or cache: equal bit
-    for bit, because either way the program multiplies by the weight
+    """Logits, sampled ids, acceptance and the pool: equal bit for bit, because either way the program multiplies by the weight
     rounded to bfloat16 once."""
     from_raw = jax.tree.leaves(PROGRAMS[program](raw))
     from_held = jax.tree.leaves(PROGRAMS[program](engine.params))
@@ -137,13 +118,12 @@ def test_held_tree_gives_what_the_fp32_tree_gives(program, raw, engine):
     assert np.isfinite(np.asarray(from_held[0], np.float32)).all()
 
 
-@pytest.mark.parametrize("kv", ["paged", "dense"])
-def test_engine_generates_the_same_ids_from_either_tree(kv, raw, engine):
+def test_engine_generates_the_same_ids_from_either_tree(raw, engine):
     """An engine handed the held tree (nothing left to cast) and one
     handed the fp32 tree generate the same tokens."""
     prompts = [[5, 9, 2, 7, 3] * 4, [11, 4, 8]]
     sampling = SamplingParams(max_tokens=6)
-    kw = dict(max_batch=2, max_seq=64, kv=kv)
+    kw = dict(max_batch=2, max_seq=64)
     a = LLMEngine(BF16, params=raw, **kw)
     b = LLMEngine(BF16, params=engine.params, **kw)
     assert b.params["lm_head"] is engine.params["lm_head"]
@@ -187,10 +167,9 @@ def test_param_bytes_counts_the_held_tree(raw, engine):
     assert held == (given - norms) // 2 + norms
 
 
-@pytest.mark.parametrize("kv", ["paged", "dense"])
-def test_float32_config_holds_the_callers_arrays(kv):
+def test_float32_config_holds_the_callers_arrays():
     given = init_params(jax.random.key(0), FP32)
-    eng = LLMEngine(FP32, max_batch=2, max_seq=64, params=given, kv=kv)
+    eng = LLMEngine(FP32, max_batch=2, max_seq=64, params=given)
     for a, b in zip(jax.tree.leaves(eng.params), jax.tree.leaves(given)):
         assert a is b
     assert eng.stats()["param_bytes"] == sum(

@@ -144,51 +144,6 @@ def test_flash_non_divisible_seq_uses_smaller_blocks():
     )
 
 
-def test_prefill_flash_path_matches_dense():
-    """The INTEGRATED flash-inside-prefill path (use_flash=True) must
-    equal the dense path — on CPU the gate routes through the kernel in
-    interpret mode, so this runs the real kernel logic."""
-    from ray_tpu.llm.kv_cache import forward_prefill, init_kv_cache
-    from ray_tpu.models import PRESETS, init_params
-
-    cfg = PRESETS["tiny"]
-    params = init_params(jax.random.key(0), cfg)
-    tokens = jax.random.randint(jax.random.key(1), (1, 512), 0, cfg.vocab_size)
-
-    dense_logits, dense_cache = forward_prefill(
-        params, tokens, init_kv_cache(cfg, 1, 1024), jnp.int32(0), cfg,
-        use_flash=False,
-    )
-    flash_logits, flash_cache = forward_prefill(
-        params, tokens, init_kv_cache(cfg, 1, 1024), jnp.int32(0), cfg,
-        use_flash=True,
-    )
-    np.testing.assert_allclose(
-        np.asarray(flash_logits), np.asarray(dense_logits),
-        rtol=2e-3, atol=2e-3,
-    )
-    np.testing.assert_allclose(
-        np.asarray(flash_cache["k"]), np.asarray(dense_cache["k"]),
-        rtol=2e-3, atol=2e-3,
-    )
-
-
-def test_prefill_flash_gate_rejects_odd_seq():
-    """seq=768 divides by 256 but not by the kernel's 512 block — the
-    gate must fall back to dense, not crash (regression)."""
-    from ray_tpu.llm.kv_cache import forward_prefill, init_kv_cache
-    from ray_tpu.models import PRESETS, init_params
-
-    cfg = PRESETS["tiny"]
-    params = init_params(jax.random.key(0), cfg)
-    tokens = jax.random.randint(jax.random.key(1), (1, 768), 0, cfg.vocab_size)
-    logits, _ = forward_prefill(
-        params, tokens, init_kv_cache(cfg, 1, 1024), jnp.int32(0), cfg,
-        use_flash=True,
-    )
-    assert logits.shape == (1, 768, cfg.vocab_size)
-
-
 def test_flash_backward_partials_fallback_matches_dense(monkeypatch):
     """Long-seq mode: when the whole-head dq VMEM slab exceeds budget,
     the backward switches to HBM fp32 partials — same gradients."""

@@ -133,7 +133,7 @@ def test_verify_writes_each_layers_pages_on_both_paths(k):
 
     pools = {}
     for use_kernel in (False, True):
-        *_, pools[use_kernel] = paged_verify(
+        _, _, pools[use_kernel], _, _ = paged_verify(
             params, jnp.asarray(tokens),
             {n: jnp.asarray(a, cfg.dtype) for n, a in start.items()},
             jnp.asarray(tables), jnp.asarray(positions),

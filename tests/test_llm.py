@@ -18,10 +18,8 @@ from ray_tpu.llm import (
     SamplingParams,
     build_batch_inferencer,
     build_llm_deployment,
-    forward_decode,
-    forward_prefill,
-    init_kv_cache,
 )
+from ray_tpu.llm.paged_kv import init_paged_kv, paged_prefill, paged_verify
 from ray_tpu.models import PRESETS, forward, init_params
 
 CFG = PRESETS["tiny"]
@@ -32,17 +30,21 @@ def params():
     return init_params(jax.random.key(0), CFG)
 
 
-def test_cached_matches_uncached(params):
-    """Prefill + N decode steps == teacher-forced full forward."""
+@pytest.mark.parametrize("use_kernel", [False, True], ids=["xla", "kernel"])
+def test_cached_matches_uncached(params, use_kernel):
+    """Prefill + N decode steps (the K = 1 decode program, on the XLA
+    path and through the interpreted kernels) == teacher-forced full
+    forward."""
     tokens = np.array([[5, 7, 11, 13, 17, 19]], np.int32)
     full_logits = np.asarray(forward(params, jnp.asarray(tokens), CFG))
 
-    prompt_len = 3
-    cache = init_kv_cache(CFG, max_batch=2, max_seq=32)
-    pad = np.zeros((1, 8), np.int32)
+    prompt_len, page = 3, 8
+    pool = init_paged_kv(CFG, num_pages=4, page_size=page)
+    pad = np.zeros((1, page), np.int32)
     pad[0, :prompt_len] = tokens[0, :prompt_len]
-    logits, cache = forward_prefill(
-        params, jnp.asarray(pad), cache, jnp.int32(0), CFG
+    logits, pool = paged_prefill(
+        params, jnp.asarray(pad), pool, jnp.asarray([2], jnp.int32),
+        cfg=CFG, n_write_pages=1,
     )
     np.testing.assert_allclose(
         np.asarray(logits[0, :prompt_len]),
@@ -50,18 +52,24 @@ def test_cached_matches_uncached(params):
         rtol=2e-3, atol=2e-3,
     )
 
-    # Decode the remaining tokens one at a time in slot 0 (slot 1 idle).
+    # Decode the remaining tokens one at a time in slot 0 (slot 1 idle:
+    # no table, its writes go to the dump page).
+    tables = jnp.asarray([[2, 3], [-1, -1]], jnp.int32)
     for i in range(prompt_len, tokens.shape[1]):
         step_tokens = np.zeros((2, 1), np.int32)
         step_tokens[0, 0] = tokens[0, i]
         positions = np.array([i, 0], np.int32)
-        dec_logits, cache = forward_decode(
-            params, jnp.asarray(step_tokens), cache,
-            jnp.asarray(positions), CFG,
+        sampled, dec_logits, pool, accept, rej = paged_verify(
+            params, jnp.asarray(step_tokens), pool, tables,
+            jnp.asarray(positions), jnp.zeros((2,), jnp.float32),
+            jax.random.key(0), cfg=CFG, use_kernel=use_kernel,
+            stochastic=False,
         )
         np.testing.assert_allclose(
             np.asarray(dec_logits[0]), full_logits[0, i], rtol=2e-3, atol=2e-3
         )
+        assert int(sampled[0, 0]) == int(full_logits[0, i].argmax())
+        assert accept.shape == rej.shape == (2, 0)
 
 
 def test_engine_greedy_matches_manual(params):

@@ -620,9 +620,7 @@ def test_serve_request_tracing_end_to_end(cluster):
         app = build_llm_deployment(
             "tiny",
             # prefill_delay_s: deterministic TTFT injection (the engine
-            # kwarg reaches the replica regardless of worker reuse; the
-            # RAY_TPU_LLM_PREFILL_DELAY env knob is its cluster-level
-            # twin).
+            # kwarg reaches the replica regardless of worker reuse).
             engine_kwargs={"max_batch": 2, "prefill_delay_s": delay},
             ray_actor_options={"num_cpus": 0.1},
         )

@@ -7,12 +7,13 @@ python/ray/llm/_internal/serve/deployments/llm/vllm/vllm_models.py:234.)
 """
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from ray_tpu.llm.engine import LLMEngine, SamplingParams
-from ray_tpu.llm.paged_kv import PageAllocator, prefix_hashes
-from ray_tpu.models.llama import PRESETS
+from ray_tpu.llm.paged_kv import PageAllocator, paged_verify, prefix_hashes
+from ray_tpu.models.llama import PRESETS, forward
 
 CFG = PRESETS["tiny"]
 
@@ -59,14 +60,100 @@ def test_prefix_registry_evicted_on_release():
 
 
 # ------------------------------------------------- engine: correctness
-def test_paged_matches_dense_engine(params):
-    """The paged engine's greedy output == the dense engine's."""
-    prompts = [[1, 2, 3, 4, 5], [7, 8], [9, 10, 11]]
-    sp = SamplingParams(max_tokens=6)
-    dense = LLMEngine(CFG, max_batch=2, max_seq=64, params=params, kv="dense")
-    paged = LLMEngine(CFG, max_batch=2, max_seq=64, params=params, kv="paged",
-                      page_size=16)
-    assert dense.generate(prompts, sp) == paged.generate(prompts, sp)
+# The last is long enough to be chunked and repeats itself, so that
+# prompt lookup finds drafts.
+PROMPTS = [[1, 2, 3, 4, 5], [7, 8], [9, 10, 11, 12, 13] * 8]
+ENGINE_PATHS = {
+    "plain": {},
+    "chunked": {"prefill_chunk": 16},
+    "speculative": {"speculate": 3},
+    "kernel": {},  # RAY_TPU_PAGED_ATTN=1: the interpreted Pallas kernels
+}
+
+
+@pytest.fixture(scope="module")
+def greedy_reference(params):
+    """Greedy continuations by the model's whole forward pass over the
+    growing sequence: no cache, and no helper of the serving programs."""
+    outs = []
+    for prompt in PROMPTS:
+        seq = list(prompt)
+        for _ in range(6):
+            logits = forward(params, jnp.asarray([seq], jnp.int32), CFG)
+            seq.append(int(np.asarray(logits[0, -1]).argmax()))
+        outs.append(seq[len(prompt):])
+    return outs
+
+
+@pytest.mark.parametrize("path", sorted(ENGINE_PATHS))
+def test_engine_matches_full_forward(
+    path, params, greedy_reference, monkeypatch
+):
+    """Every way through the engine emits the full forward's greedy
+    tokens, and the path the case names was really taken."""
+    monkeypatch.setenv("RAY_TPU_PAGED_ATTN", "1" if path == "kernel" else "0")
+    engine = LLMEngine(
+        CFG, max_batch=2, max_seq=64, params=params, page_size=16,
+        **ENGINE_PATHS[path],
+    )
+    assert engine.paged_attn_kernel == (path == "kernel")
+    outs = engine.generate(PROMPTS, SamplingParams(max_tokens=6))
+    assert outs == greedy_reference
+    stats = engine.stats()
+    assert (stats["prefill_chunks"] >= 2) == (path == "chunked")
+    assert (stats["draft_tokens_proposed"] > 0) == (path == "speculative")
+    assert engine.alloc.free_pages == engine.alloc.num_pages
+
+
+def test_kv_keyword_has_one_legal_value(params):
+    """Configurations still pass ``kv="paged"``; the slab is gone and
+    asking for it says so."""
+    engine = LLMEngine(CFG, max_batch=1, max_seq=32, params=params, kv="paged")
+    assert not hasattr(engine, "kv")
+    with pytest.raises(ValueError, match="dense slab cache was removed"):
+        LLMEngine(CFG, max_batch=1, max_seq=32, params=params, kv="dense")
+
+
+def test_one_decode_program_whatever_the_temperatures(params):
+    """Without speculation a greedy batch and a sampled one run the same
+    compiled decode program: `stochastic` is a static argument that
+    changes nothing at K = 1, so the engine never varies it there."""
+    # A shape no other test of this module decodes at.
+    engine = LLMEngine(CFG, max_batch=3, max_seq=40, params=params,
+                       page_size=8)
+    before = paged_verify._cache_size()
+    engine.generate([[1, 2, 3]], SamplingParams(max_tokens=4))
+    assert paged_verify._cache_size() == before + 1
+    outs = engine.generate(
+        [[4, 5, 6], [7, 8]], SamplingParams(max_tokens=4, temperature=0.8)
+    )
+    assert [len(o) for o in outs] == [4, 4]
+    assert paged_verify._cache_size() == before + 1
+
+
+@pytest.mark.parametrize("speculate", [0, 3])
+def test_decode_program_is_called_once_a_step(speculate, params):
+    """What a logits tap around `_decode_paged` may count on (the
+    benchmark's reference check is one): exactly one call per decode
+    step, whose second result is position 0's logits for every slot."""
+    engine = LLMEngine(CFG, max_batch=2, max_seq=64, params=params,
+                       page_size=16, speculate=speculate)
+    real, calls = engine._decode_paged, []
+
+    def tapped(*args, **kw):
+        out = real(*args, **kw)
+        calls.append(out)
+        return out
+
+    engine._decode_paged = tapped
+    engine.generate(PROMPTS, SamplingParams(max_tokens=6))
+    assert len(calls) == engine.stats()["decode_steps"] > 0
+    for sampled, logits, pool, accept, rej in calls:
+        assert logits.shape == (2, CFG.vocab_size)
+        assert logits.dtype == jnp.float32
+        assert sampled.shape == (2, 1 + speculate)
+        assert accept.shape == rej.shape == (2, speculate)
+        assert set(pool) == {"k", "v"}
 
 
 def test_memory_bound_admission_beyond_dense_capacity(params):

@@ -127,11 +127,6 @@ def test_speculative_mixed_batch_with_sampling(tiny):
     assert out[greedy_id] == plain
 
 
-def test_speculate_requires_paged(tiny):
-    with pytest.raises(ValueError, match="paged"):
-        LLMEngine(tiny, kv="dense", speculate=2)
-
-
 def test_speculative_at_max_seq_boundary(tiny):
     """A K-wide step reaching past max_seq must not crash the batch or
     corrupt live pages: overflow writes route to the dump page and the
@@ -238,7 +233,7 @@ def test_rejection_sampling_preserves_distribution(tiny, draft_kind):
     spec_emitted, plain_sampled = [], []
     analytic = None
     for trial in range(32):
-        sampled, accept, rej, pos0_logits, pool = paged_verify(
+        sampled, pos0_logits, pool, accept, rej = paged_verify(
             params, vt, pool, tables, positions, temps,
             jax.random.key(100 + trial), cfg=tiny,
         )

@@ -151,7 +151,7 @@ def _pool_moves(text: str) -> list[str]:
 
 def _serving_program(case: str, on):
     from ray_tpu.llm import paged_kv
-    from ray_tpu.llm.kv_cache import matmul_weights
+    from ray_tpu.llm.paged_kv import matmul_weights
     from ray_tpu.models.llama import LlamaConfig, init_params
 
     cfg = LlamaConfig(

@@ -256,10 +256,16 @@ class LLMEngine:
             # The engine loop's own account (PERF.md's span-and-counter
             # table): occupancy = slot_steps / (decode_steps * max_batch);
             # the two sums are seconds requests waited for a slot and
-            # add/abort callers waited for `_lock`.
+            # add/abort callers waited for `_lock`; attn_pages_live /
+            # attn_pages_table is how much of the decode steps' block
+            # tables was pages to attend (the attention kernel's work
+            # list: a bucket's growth pages past the position are held,
+            # not attended) and not width.
             "steps": 0,
             "decode_steps": 0,
             "slot_steps": 0,
+            "attn_pages_live": 0,
+            "attn_pages_table": 0,
             "admitted": 0,
             "queue_wait_s_sum": 0.0,
             "lock_wait_s_sum": 0.0,
@@ -389,8 +395,7 @@ class LLMEngine:
             }
         )
         if req.slot in self._active:
-            del self._active[req.slot]
-            self._free.append(req.slot)
+            self._vacate(req.slot)
         self._release_pages(req)
         return True
 
@@ -417,6 +422,15 @@ class LLMEngine:
         if req.first_token_ts and req.finish_ts:
             t["decode_s"] = max(0.0, req.finish_ts - req.first_token_ts)
         return t
+
+    def _vacate(self, slot: int) -> None:
+        """Free a decode slot, its position back to 0. The decode
+        program attends every slot up to its position, and a free
+        slot's table is all -1 (the dump page): at position 0 that is
+        one page, at a finished 8k request's position 130 of them."""
+        del self._active[slot]
+        self._free.append(slot)
+        self._positions[slot] = 0
 
     def _release_pages(self, req: _Request) -> None:
         for pg in req.pages:
@@ -631,8 +645,7 @@ class LLMEngine:
         self._stats["preemptions"] += 1
         self._release_pages(req)
         if req.slot in self._active:
-            del self._active[req.slot]
-            self._free.append(req.slot)
+            self._vacate(req.slot)
         req.slot = -1
         self._queue.insert(0, req)
 
@@ -659,6 +672,17 @@ class LLMEngine:
         token."""
         P = self.page_size
         K = 1 + self.speculate
+
+        def attended(req: _Request) -> int:
+            """Pages this step writes into or attends: up to position +
+            K - 1, clamped to the table width. Near max_seq a K-wide
+            step may reach past capacity — the kernel routes those
+            writes to the dump page and _finish_if_done stops the
+            request at max_seq before any overflow token is kept."""
+            return min(
+                (req.position + K - 1) // P + 1, self.max_pages_per_seq
+            )
+
         with TraceAnnotation("engine:grow_tables") as span:
             preempted = self._stats["preemptions"]
             # Grow block tables to cover every position this step may
@@ -668,13 +692,7 @@ class LLMEngine:
             for slot, req in list(self._active.items()):
                 if req.slot == -1 or req.done:
                     continue
-                # Clamp to the table width: near max_seq a K-wide step
-                # may reach past capacity — the kernel routes those
-                # writes to the dump page and _finish_if_done stops the
-                # request at max_seq before any overflow token is kept.
-                needed = min(
-                    (req.position + K - 1) // P + 1, self.max_pages_per_seq
-                )
+                needed = attended(req)
                 while len(req.pages) < needed and req.slot != -1:
                     if self.alloc.free_pages == 0:
                         victims = [
@@ -701,6 +719,10 @@ class LLMEngine:
             toks, draft_len = self._propose_drafts()
         self._stats["decode_steps"] += 1
         self._stats["slot_steps"] += len(self._active)
+        self._stats["attn_pages_live"] += sum(
+            attended(req) for req in self._active.values()
+        )
+        self._stats["attn_pages_table"] += tables.size
         # Static flag: an all-greedy batch (the common speculative
         # configuration) skips the rejection-sampling tensors entirely
         # — at most two compiled variants, like use_kernel. At K = 1
@@ -824,8 +846,7 @@ class LLMEngine:
             for slot, r in list(self._active.items()):
                 if r.request_id == request_id:
                     r.done = True
-                    del self._active[slot]
-                    self._free.append(slot)
+                    self._vacate(slot)
                     self._release_pages(r)
                     self._stats["requests_aborted"] += 1
                     return True
@@ -846,11 +867,11 @@ class LLMEngine:
         vLLM engine stats ray.llm's deployments surface): request and
         token totals, speculative proposal/acceptance, preemptions,
         chunked-prefill progress, the engine loop's own account (steps,
-        slot-steps, queue and lock waits, `init_s`), `param_bytes` (the
-        held weights, matmul leaves in `cfg.dtype`), which attention and
-        which K/V cell write the decode program was compiled with
-        (`paged_attn_kernel`, `kv_write_kernel`) and the pool/slot
-        occupancy."""
+        slot-steps, attended and table pages, queue and lock waits,
+        `init_s`), `param_bytes` (the held weights, matmul leaves in
+        `cfg.dtype`), which attention and which K/V cell write the
+        decode program was compiled with (`paged_attn_kernel`,
+        `kv_write_kernel`) and the pool/slot occupancy."""
         with self._lock:
             out = dict(self._stats)
             out["platform"] = self.platform

@@ -32,7 +32,8 @@ max_batch × max_seq slots would cost HBM whatever the sequences' lengths:
   device each step as a [B, max_pages] int32 array.
 - **Decode**, kernel path: the K cells a slot writes are patched into
   their pages, then ``paged_attention`` reads each slot's live pages in
-  place. XLA path: the cells are scattered (``.at[].set``), each slot's
+  place (the pages up to ``positions[b] + K``: a free slot is at
+  position 0). XLA path: the cells are scattered (``.at[].set``), each slot's
   pages gathered (jnp.take along the page axis) and attended under a
   mask — static shapes, gather and attention fused, the layout folded
   into the einsums (_gather_page_attention).
@@ -484,7 +485,8 @@ def paged_verify(
             # Pallas path: cells patched into their pages in place,
             # slot-major (a slot's drafts on consecutive grid steps, as
             # write_kv_cells needs); pages read in place, GQA-grouped,
-            # per-slot length early-exit (ops/pallas/paged_attention.py).
+            # each slot's own live pages and no more
+            # (ops/pallas/paged_attention.py).
             from ray_tpu.ops.pallas.kv_cell_write import write_kv_cells
             from ray_tpu.ops.pallas.paged_attention import paged_attention
 

@@ -1,52 +1,53 @@
 """Paged decode attention as a Pallas TPU kernel.
 
 The XLA fallback in ``llm/paged_kv.py`` gathers every slot's full page
-window out of the pool (``jnp.take``) and then repeats KV to all query
-heads — per step it moves B x window x n_heads x Dh bytes of HBM
-regardless of each request's true length. This kernel removes both
-factors:
+window out of the pool (``jnp.take``): per step it moves B x window x
+Hkv x Dh bytes of HBM whatever each request's true length. This
+kernel's work list is the live pages:
 
-- **Pages are read in place.** The grid is (B, max_pages) and the K/V
-  BlockSpec index maps use the scalar-prefetched block table to point
-  each grid step at the physical page — no gathered copy of the window
-  ever exists in HBM.
-- **GQA-aware blocking.** Queries are laid out [B, Hkv, n_rep*K, Dh] so
-  each page's K/V block ([P, Hkv, Dh]) is multiplied once per KV head
-  against its whole query group — KV is never repeated to n_heads.
-  Traffic scales with n_kv_heads (8 for llama-8B), not n_heads (32).
-- **Per-slot length early-exit.** Pages past a slot's true length are
-  clamped by the index map to the slot's LAST page: Pallas skips the
-  DMA when consecutive grid steps map to the same block, and pl.when
-  skips the compute, so a 100-token request in a 4096-token-wide table
-  pays for one page, not 32.
+- **A loop over each slot's own pages.** The grid runs over the slots
+  alone. Inside, a ``fori_loop`` whose trip count is the slot's number
+  of live blocks, ``ceil((positions[b] + K) / (N * P))``, read from
+  scalar prefetch: the table's width is in no grid dimension, so a
+  100-token request in an 8,448-token-wide table costs one block and
+  an empty slot (position 0) one block of one page.
+- **Pages are read in place, N at a time.** The pool stays in HBM
+  (``pl.ANY``); a block's live pages are fetched through the block
+  table by one async copy each into a double buffer, head-major
+  (``[Hkv, N*P, Dh]``), and the next block's copies (the next slot's
+  first block, after a slot's last) start before this block's
+  arithmetic. No gathered copy of the window ever exists in HBM.
+- **One soft-max update a block, heads batched.** A block is ``N*P``
+  keys: one ``[Hkv] x [R, Dh] x [Dh, N*P]`` and one ``[Hkv] x [R, N*P]
+  x [N*P, Dh]`` product for all KV heads, queries laid out ``[B, Hkv,
+  n_rep*K, Dh]`` so that KV is never repeated to n_heads, and the
+  running max and sum carried at their own width.
+- **N follows from the shapes** (``_pages_per_block``): what the K/V
+  double buffers and a block's scores take of ``_BLOCK_VMEM_BYTES``.
 
 Numerics follow the flash kernel (online softmax with finite mask
-values, fp32 accumulation); outputs match the XLA gather path to fp
-tolerance, and greedy token streams are identical (gated by tests).
+values, operands in the pool's dtype, fp32 scores and accumulation);
+outputs match the XLA gather path to fp tolerance, and greedy token
+streams are identical (gated by tests).
 
 The pool layout is HEAD-major ([pages, Hkv, P, Dh]) and, as for every
-Mosaic operand, row-major in memory: each KV head's page tile is a
-contiguous slice, measured ~40% faster than page-major for the kernel.
-THIS KERNEL FIXES THE POOL'S LAYOUT for the program it is in: whatever
-else touches the pool there must leave it row-major, or XLA re-lays the
-whole pool out before every call. So the decode program writes its
-cells with ``kv_cell_write.write_kv_cells`` (a Mosaic call, the pool
-aliased to its result) and not with an XLA scatter, and carries the
-pool through its layer loop (``llm/paged_kv.py _scan_layers``): the
-``k_pool`` / ``v_pool`` here are that loop's carry, all layers' pages in
-one flat view, with the layer's page base already in ``block_tables``.
-NOTE the honest caveat: the same round also rewrote
-the XLA gather fallback (einsum-folded, GQA-grouped, no repeat) which
-brought IT from 17.4 ms to ~4.6 ms at 32/8 heads — at this window
-size the kernel's remaining edge is 1.1-1.3x, and its structural
-advantage (no materialized gathered window) grows with table width.
-Grouping multiple pages per grid step measured SLOWER (see
-pages_per_step below).
+Mosaic operand, row-major in memory: a page is one contiguous 131 KB
+copy at Mistral-7B's shapes. THIS KERNEL FIXES THE POOL'S LAYOUT for
+the program it is in: whatever else touches the pool there must leave
+it row-major, or XLA re-lays the whole pool out before every call. So
+the decode program writes its cells with
+``kv_cell_write.write_kv_cells`` (a Mosaic call, the pool aliased to
+its result) and not with an XLA scatter, and carries the pool through
+its layer loop (``llm/paged_kv.py _scan_layers``): the ``k_pool`` /
+``v_pool`` here are that loop's carry, all layers' pages in one flat
+view, with the layer's page base already in ``block_tables``.
 
-The reference has no paged attention of its own — ray.llm buys it from
+The reference has no paged attention of its own: ray.llm buys it from
 vLLM (reference: python/ray/llm/_internal/serve/deployments/llm/vllm/
 vllm_models.py:234, engine_kwargs pass-through); this is the TPU-native
-equivalent of vLLM's paged_attention kernel.
+equivalent of vLLM's paged_attention kernel. Models read, not imported:
+jax/experimental/pallas/ops/tpu/paged_attention (its pool is
+``[Hkv, pages, P, Dh]``, ours is not).
 """
 
 from __future__ import annotations
@@ -62,101 +63,162 @@ from jax.experimental.pallas import tpu as pltpu
 # to exactly 0 without the -inf NaN guards.
 _MASK = -1e9
 _M_INIT = -1e30
-_LANES = 128
+# What one block may take of VMEM: the K and V double buffers and the
+# block's fp32 scores and probabilities. A v5e core has 16 MiB. At 3 MiB
+# a block is 4 pages of Mistral-7B's (256 keys, 1 MB of K and V): on the
+# chip blocks of 4, 8 and 16 pages read 8,448-token contexts equally
+# fast (91% of the HBM peak) and 4 is cheapest where a slot has a page
+# or two, since a block's arithmetic is paid on its masked keys too
+# (PERF.md section 6, PR 30). The compile for a described v5e
+# (tests/test_tpu_aot_compile.py) is the proof that the serving shapes
+# fit.
+_BLOCK_VMEM_BYTES = 3 * 1024 * 1024
+_SUBLANES = 8
+
+
+def _pages_per_block(
+    n_kv_heads: int, page_size: int, head_dim: int, r: int,
+    itemsize: int, max_pages: int,
+) -> int:
+    """N: the largest power of two of pages whose block fits
+    ``_BLOCK_VMEM_BYTES``, and no more than the table holds."""
+    kv = 4 * n_kv_heads * page_size * head_dim * itemsize  # K, V, x 2
+    rows = -(-r // _SUBLANES) * _SUBLANES
+    scores = 2 * n_kv_heads * rows * page_size * 4  # s and p, fp32
+    fit = max(_BLOCK_VMEM_BYTES // (kv + scores), 1)
+    n = 1 << (fit.bit_length() - 1)
+    while n > max_pages:
+        n //= 2
+    return n
 
 
 def _make_kernel(
-    group: int, page_size: int, n_queries: int, scale: float
+    block_pages: int, page_size: int, n_queries: int, max_pages: int,
+    scale: float,
 ):
-    """Kernel over GROUPS of ``group`` pages per grid step: fewer,
-    fatter steps amortize per-step overhead and let Pallas issue the
-    group's page DMAs together. Refs: scalar prefetch (tables, lastp,
-    pos), q, group x k pages, group x v pages, out, then m/l/acc
-    scratch."""
+    """Kernel of one slot a grid step. Refs: scalar prefetch (tables,
+    pos), q, the K and V pools in HBM, out, then the K and V double
+    buffers ``[2, Hkv, N*P, Dh]``, their DMA semaphores ``[2 (K, V), 2
+    (buffer)]`` and, in SMEM, the buffer that holds this step's first
+    block (started by the step before)."""
+    block_keys = block_pages * page_size
 
-    def _kernel(tables_ref, lastp_ref, pos_ref, q_ref, *rest):
-        k_refs = rest[:group]  # each [1, Hkv, P, Dh]
-        v_refs = rest[group: 2 * group]
-        o_ref = rest[2 * group]  # [1, Hkv, R, Dh]
-        m_ref, l_ref, acc_ref = rest[2 * group + 1:]
+    def _kernel(
+        tables_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
+        k_buf, v_buf, sems, first_buf_ref,
+    ):
         b = pl.program_id(0)
-        i = pl.program_id(1)
 
-        @pl.when(i == 0)
-        def _init():
-            m_ref[...] = jnp.full_like(m_ref, _M_INIT)
-            l_ref[...] = jnp.zeros_like(l_ref)
-            acc_ref[...] = jnp.zeros_like(acc_ref)
+        def live_pages(slot):
+            # Drafts may reach past the table near max_seq: their cells
+            # went to the dump page (paged_verify), nobody attends them.
+            return jnp.minimum(
+                pl.cdiv(pos_ref[slot] + n_queries, page_size), max_pages
+            )
 
-        n_kv = q_ref.shape[1]
-        for j in range(group):
-            # Global page index of this group member; members past the
-            # slot's last page skip compute (their block index was
-            # clamped, so no DMA happened either).
-            ip = i * group + j
+        def for_block_copies(slot, blk, buf, do):
+            """``do`` (start or wait) each page copy of block ``blk`` of
+            ``slot`` into buffer ``buf``: the block's live pages only, so
+            the table's dead width costs no HBM traffic."""
+            first = blk * block_pages
+            count = jnp.minimum(live_pages(slot) - first, block_pages)
 
-            @pl.when(ip <= lastp_ref[b])
-            def _accumulate(j=j, ip=ip):
-                k_ref, v_ref = k_refs[j], v_refs[j]
-                # Static unrolled loop over KV heads: Mosaic wants
-                # plain 2D MXU matmuls, and the head-major layout makes
-                # each head's [P, Dh] tile a contiguous slice. Each
-                # group's K/V tile is touched once for all n_rep * K
-                # query rows — KV is never repeated across the group.
-                for g in range(n_kv):
-                    s = jax.lax.dot_general(
-                        q_ref[0, g], k_ref[0, g],
-                        dimension_numbers=(((1,), (1,)), ((), ())),
-                        preferred_element_type=jnp.float32,
-                    ) * scale  # [R, P]
-                    # Causal / length mask: key cell c lives at global
-                    # position ip*P + c; query row r is query token
-                    # r % K writing at pos + r % K. (Stale cells beyond
-                    # the frontier are masked; cells behind it are
-                    # valid by the scatter-before-gather invariant
-                    # shared with the XLA path.)
-                    key_pos = ip * page_size + jax.lax.broadcasted_iota(
-                        jnp.int32, s.shape, 1
-                    )
-                    q_pos = pos_ref[b] + jax.lax.broadcasted_iota(
-                        jnp.int32, s.shape, 0
-                    ) % n_queries
-                    s = jnp.where(key_pos > q_pos, _MASK, s)
+            def one_page(j, carry):
+                page = tables_ref[slot, first + j]
+                keys = pl.ds(
+                    pl.multiple_of(j * page_size, page_size), page_size
+                )
+                for hbm, vmem, sem in (
+                    (k_hbm, k_buf, sems.at[0, buf]),
+                    (v_hbm, v_buf, sems.at[1, buf]),
+                ):
+                    do(pltpu.make_async_copy(
+                        hbm.at[page], vmem.at[buf, :, keys, :], sem
+                    ))
+                return carry
 
-                    m_prev = m_ref[g, :, 0]  # [R]
-                    l_prev = l_ref[g, :, 0]
-                    m_new = jnp.maximum(m_prev, s.max(axis=-1))
-                    p = jnp.exp(s - m_new[:, None])  # masked -> 0
-                    alpha = jnp.exp(m_prev - m_new)
-                    l_ref[g] = jnp.broadcast_to(
-                        (alpha * l_prev + p.sum(axis=-1))[:, None],
-                        l_ref.shape[1:],
-                    )
-                    m_ref[g] = jnp.broadcast_to(
-                        m_new[:, None], m_ref.shape[1:]
-                    )
-                    acc_ref[g] = acc_ref[g] * alpha[:, None] + (
-                        jax.lax.dot_general(
-                            p.astype(v_ref.dtype), v_ref[0, g],
-                            dimension_numbers=(((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32,
-                        )
-                    )
+            jax.lax.fori_loop(0, count, one_page, None)
 
-        @pl.when(i == pl.num_programs(1) - 1)
-        def _finalize():
-            l = l_ref[:, :, 0]
-            denom = jnp.where(l == 0.0, 1.0, l)
-            o_ref[0] = (
-                acc_ref[...] / denom[:, :, None]
-            ).astype(o_ref.dtype)
+        def start(copy):
+            copy.start()
+
+        def wait(copy):
+            copy.wait()
+
+        @pl.when(b == 0)
+        def _first_step():
+            # A masked key's probability is exactly 0, and 0 x what the
+            # buffer held before any copy (NaN bits, perhaps) is not: V
+            # starts finite. K needs none: its scores are overwritten.
+            v_buf[...] = jnp.zeros_like(v_buf)
+            first_buf_ref[0] = 0
+            for_block_copies(0, 0, 0, start)
+
+        n_blocks = pl.cdiv(live_pages(b), block_pages)
+        q = q_ref[0]  # [Hkv, R, Dh]
+        n_kv, r, head_dim = q.shape
+
+        def block(i, carry):
+            m_prev, l_prev, acc, buf = carry
+            # The next block's copies, before this block's arithmetic:
+            # this slot's next block or, after its last, the next
+            # slot's first (every slot has one: positions >= 0).
+            last = i + 1 == n_blocks
+            next_slot = jnp.where(last, b + 1, b)
+
+            @pl.when(next_slot < pl.num_programs(0))
+            def _prefetch():
+                for_block_copies(
+                    next_slot, jnp.where(last, 0, i + 1), 1 - buf, start
+                )
+
+            for_block_copies(b, i, buf, wait)
+            s = jax.lax.dot_general(
+                q, k_buf[buf],
+                dimension_numbers=(((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32,
+            ) * scale  # [Hkv, R, N*P]
+            # Causal / length mask: key cell c of the block lives at
+            # global position i*N*P + c; query row r is query token
+            # r % K writing at pos + r % K. (Stale cells beyond the
+            # frontier, and pages of the block that were not fetched,
+            # are masked; cells behind it are valid by the
+            # scatter-before-gather invariant shared with the XLA path.)
+            key_pos = i * block_keys + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 2
+            )
+            q_pos = pos_ref[b] + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1
+            ) % n_queries
+            s = jnp.where(key_pos > q_pos, _MASK, s)
+
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)  # masked -> 0
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = alpha * l_prev + p.sum(axis=-1, keepdims=True)
+            acc = acc * alpha + jax.lax.dot_general(
+                p.astype(v_buf.dtype), v_buf[buf],
+                dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32,
+            )  # [Hkv, R, Dh]
+            return m_new, l_new, acc, 1 - buf
+
+        _, l, acc, buf = jax.lax.fori_loop(
+            0, n_blocks, block,
+            (
+                jnp.full((n_kv, r, 1), _M_INIT, jnp.float32),
+                jnp.zeros((n_kv, r, 1), jnp.float32),
+                jnp.zeros((n_kv, r, head_dim), jnp.float32),
+                first_buf_ref[0],
+            ),
+        )
+        first_buf_ref[0] = buf
+        o_ref[0] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
     return _kernel
 
 
-@functools.partial(
-    jax.jit, static_argnames=("n_kv_heads", "interpret", "pages_per_step")
-)
+@functools.partial(jax.jit, static_argnames=("n_kv_heads", "interpret"))
 def paged_attention(
     q: jnp.ndarray,  # [B, K, H, Dh] (rope applied)
     k_pool: jnp.ndarray,  # [num_pages, Hkv, P, Dh] (head-major)
@@ -166,13 +228,13 @@ def paged_attention(
     *,
     n_kv_heads: int,
     interpret: bool = False,
-    pages_per_step: int = 1,
 ) -> jnp.ndarray:
     """Decode/verify attention over the page pool; returns [B, K, H, Dh].
 
     Query token k of slot b attends to key positions <= positions[b]+k
     within the slot's block table (the K=1 case is plain decode). The
-    pool is read page-by-page in place — see module docstring.
+    pool is read in place, each slot's live pages only: see the module
+    docstring.
     """
     b, kk, n_heads, head_dim = q.shape
     num_pages, hkv, page_size, _ = k_pool.shape
@@ -180,6 +242,9 @@ def paged_attention(
     n_rep = n_heads // n_kv_heads
     r = n_rep * kk
     max_pages = block_tables.shape[1]
+    block_pages = _pages_per_block(
+        n_kv_heads, page_size, head_dim, r, k_pool.dtype.itemsize, max_pages
+    )
 
     # [B, K, H, Dh] -> [B, Hkv, n_rep*K, Dh]: head h = g*n_rep + h_rep
     # lands in group g, row h_rep*K + k — so row % K is the query index.
@@ -188,68 +253,47 @@ def paged_attention(
         .reshape(b, n_kv_heads, n_rep, kk, head_dim)
         .reshape(b, n_kv_heads, r, head_dim)
     )
-    tables = jnp.maximum(block_tables, 0).astype(jnp.int32)
-    lastp = jnp.clip(
-        (positions + kk - 1) // page_size, 0, max_pages - 1
-    ).astype(jnp.int32)
-    # pages_per_step > 1 loads a GROUP of pages per grid step. Measured
-    # on v5e at batch 64: G=1 3.4 ms, G=4 5.0 ms, G=8 3.5 ms — the
-    # extra per-spec double buffers cost more VMEM/pipelining than the
-    # step amortization saves, so 1 is the default; the knob stays for
-    # other table-width/page-size regimes.
-    group = pages_per_step
-    while max_pages % group:
-        group //= 2  # table widths are powers of two in practice
-    group = max(group, 1)
-
-    def page_spec(j):
-        # Group member j of grid step i holds page i*group + j, clamped
-        # to the slot's last live page: steps past it re-map to the
-        # same block index and Pallas elides the repeated DMA, so the
-        # table's dead width costs no HBM traffic.
-        return pl.BlockSpec(
-            (1, n_kv_heads, page_size, head_dim),
-            lambda bi, i, tab, lp, pos, j=j: (
-                tab[bi, jnp.minimum(i * group + j, lp[bi])], 0, 0, 0,
-            ),
-        )
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(b, max_pages // group),
-        in_specs=[
-            pl.BlockSpec(
-                (1, n_kv_heads, r, head_dim),
-                lambda bi, i, tab, lp, pos: (bi, 0, 0, 0),
-            ),
-            *[page_spec(j) for j in range(group)],  # K pages
-            *[page_spec(j) for j in range(group)],  # V pages
-        ],
-        out_specs=pl.BlockSpec(
-            (1, n_kv_heads, r, head_dim),
-            lambda bi, i, tab, lp, pos: (bi, 0, 0, 0),
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((n_kv_heads, r, _LANES), jnp.float32),
-            pltpu.VMEM((n_kv_heads, r, _LANES), jnp.float32),
-            pltpu.VMEM((n_kv_heads, r, head_dim), jnp.float32),
-        ],
+    q_spec = pl.BlockSpec(
+        (1, n_kv_heads, r, head_dim), lambda bi, tab, pos: (bi, 0, 0, 0)
+    )
+    kv_buf = pltpu.VMEM(
+        (2, n_kv_heads, block_pages * page_size, head_dim), k_pool.dtype
     )
     out = pl.pallas_call(
         _make_kernel(
-            group=group,
+            block_pages=block_pages,
             page_size=page_size,
             n_queries=kk,
+            max_pages=max_pages,
             scale=head_dim**-0.5,
         ),
-        grid_spec=grid_spec,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b,),
+            in_specs=[
+                q_spec,
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=q_spec,
+            scratch_shapes=[
+                kv_buf,
+                kv_buf,
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct(
             (b, n_kv_heads, r, head_dim), q.dtype
         ),
+        # A step starts the next slot's first copies: the slots in order.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
         interpret=interpret,
     )(
-        tables, lastp, positions.astype(jnp.int32), qg,
-        *([k_pool] * group), *([v_pool] * group),
+        jnp.maximum(block_tables, 0).astype(jnp.int32),
+        positions.astype(jnp.int32), qg, k_pool, v_pool,
     )
     # [B, Hkv, n_rep*K, Dh] -> [B, K, H, Dh]
     return (

@@ -197,6 +197,44 @@ def test_counters_are_consistent_at_drain(kwargs):
     }
 
 
+@pytest.mark.parametrize("speculate", [0, 2], ids=["k1", "k3"])
+def test_attended_pages_equal_the_hand_count(speculate):
+    """`attn_pages_live` / `attn_pages_table`: how much of the decode
+    steps' block tables the attention had to read. One request at a
+    time, so every step's length is known: of 5 tokens from a 30-token
+    prompt the first is the prefill's, the others come of decode steps
+    at positions 30, 31, 32, 33 (at K = 1), each attending the pages up
+    to position + K - 1, of 16 tokens each."""
+    engine = LLMEngine(
+        "tiny", max_batch=2, max_seq=64, kv="paged", page_size=16,
+        speculate=speculate,
+    )
+    width = 64 // 16
+    assert engine.stats()["attn_pages_live"] == 0
+    assert engine.stats()["attn_pages_table"] == 0
+    for prompt_len, max_tokens in ((30, 5), (47, 4), (12, 9)):
+        run_to_the_end(
+            engine, [list(range(1, 1 + prompt_len))], max_tokens=max_tokens
+        )
+        # A vacated slot is at position 0 again: the decode program
+        # attends one page for it (the dump page), not as many as its
+        # last request had.
+        assert engine._positions.tolist() == [0, 0]
+    stats = engine.stats()
+    assert stats["attn_pages_table"] == stats["decode_steps"] * 2 * width
+    if speculate:
+        # How many steps depends on which drafts were accepted.
+        assert stats["decode_steps"] <= stats["attn_pages_live"]
+        assert stats["attn_pages_live"] <= stats["decode_steps"] * width
+        return
+    assert stats["decode_steps"] == 4 + 3 + 8
+    # Positions 30..33 (32 opens the third page), 47..49 (48 the
+    # fourth), 12..19 (16 the second).
+    assert stats["attn_pages_live"] == (
+        (2 + 2 + 3 + 3) + (3 + 4 + 4) + (4 * 1 + 4 * 2)
+    )
+
+
 def test_a_preempted_request_is_admitted_once():
     # A pool too small for both requests' growth: one is preempted and
     # prefilled again, which is neither a second admission nor more

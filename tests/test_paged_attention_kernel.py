@@ -15,7 +15,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.ops.pallas.paged_attention import paged_attention
+from ray_tpu.ops.pallas.paged_attention import (
+    _pages_per_block,
+    paged_attention,
+)
 
 
 def _reference(q, kp, vp, tables, positions):
@@ -50,18 +53,51 @@ def _reference(q, kp, vp, tables, positions):
 
 
 def _case(seed, b, k, h, hkv, dh, p, maxp, positions):
+    """Pools holding the dump page and each slot's own pages. A
+    position of None is a dead slot: table -1, position 0."""
     rng = np.random.default_rng(seed)
-    npages = b * maxp + 1
+    needs = [
+        0 if pos is None else min((pos + k + p - 1) // p, maxp)
+        for pos in positions
+    ]
+    npages = sum(needs) + 1
     q = jnp.asarray(rng.normal(size=(b, k, h, dh)), jnp.float32)
     kp = jnp.asarray(rng.normal(size=(npages, hkv, p, dh)), jnp.float32)
     vp = jnp.asarray(rng.normal(size=(npages, hkv, p, dh)), jnp.float32)
     tables = np.full((b, maxp), -1, np.int32)
     nxt = 1
-    for i, pos in enumerate(positions):
-        need = (pos + k + p - 1) // p
+    for i, need in enumerate(needs):
         tables[i, :need] = np.arange(nxt, nxt + need)
         nxt += need
-    return q, kp, vp, jnp.asarray(tables), jnp.asarray(positions, jnp.int32)
+    pos = [0 if pos is None else pos for pos in positions]
+    return q, kp, vp, jnp.asarray(tables), jnp.asarray(pos, jnp.int32)
+
+
+# mistral7b-serve1's table (benchmarks/configs): 32 slots of 132 pages
+# of 64 tokens, 32 query and 8 KV heads of 128.
+SERVE = dict(b=32, h=32, hkv=8, dh=128, p=64, maxp=132)
+
+
+def _serve_case(k, lengths):
+    """A 32-slot batch at the serving shapes: ``lengths`` (tokens a
+    slot holds once this step's K are written; 0 = dead) spread over
+    the slots with dead ones before, between and after them."""
+    positions = [None] * SERVE["b"]
+    for i, n in enumerate(lengths):
+        positions[1 + 3 * i] = n - k
+    return pytest.param(
+        SERVE["b"], k, SERVE["h"], SERVE["hkv"], SERVE["dh"], SERVE["p"],
+        SERVE["maxp"], positions, id=f"serve-k{k}",
+    )
+
+
+def _serve_block(k):
+    """Tokens in one block of the kernel's loop at the serving shapes
+    (float32 pools here: half the pages a block of bf16 ones has)."""
+    r = SERVE["h"] // SERVE["hkv"] * k
+    return SERVE["p"] * _pages_per_block(
+        SERVE["hkv"], SERVE["p"], SERVE["dh"], r, 4, SERVE["maxp"]
+    )
 
 
 @pytest.mark.parametrize(
@@ -72,6 +108,20 @@ def _case(seed, b, k, h, hkv, dh, p, maxp, positions):
         (3, 4, 8, 2, 64, 16, 4, [15, 47, 60]),         # verify K=4,
         #   incl. pos 15: the K window crosses a page boundary
         (2, 2, 16, 1, 64, 8, 8, [31, 62]),             # 1 kv head (MQA)
+        (4, 1, 8, 2, 64, 16, 4, [None, 33, None, 5]),  # dead slots
+        (3, 3, 8, 2, 64, 8, 32, [6, 190, 253]),        # a long table;
+        #   pos 253 + 3 = its last cell
+        # One token, exactly one page, exactly one block, one past a
+        # block, the full 8,448.
+        _serve_case(1, [
+            1, 64, _serve_block(1), _serve_block(1) + 1, 132 * 64,
+        ]),
+        # K = 5: drafts across a page boundary (cells 62..66), across a
+        # block boundary, up to the table's last cell, and past it
+        # (positions 8,446..8,450 of 8,448: near max_seq).
+        _serve_case(5, [
+            67, _serve_block(5) + 2, 132 * 64, 132 * 64 + 3,
+        ]),
     ],
 )
 def test_kernel_matches_gather_reference(b, k, h, hkv, dh, p, maxp, positions):
@@ -79,10 +129,28 @@ def test_kernel_matches_gather_reference(b, k, h, hkv, dh, p, maxp, positions):
     out = paged_attention(
         q, kp, vp, tables, pos, n_kv_heads=hkv, interpret=True
     )
-    ref = _reference(q, kp, vp, tables, pos)
-    np.testing.assert_allclose(
-        np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5
-    )
+    # Slot by slot: the reference repeats a slot's whole window to all
+    # query heads. A dead slot's output is nobody's.
+    for i in [i for i, at in enumerate(positions) if at is not None]:
+        one = slice(i, i + 1)
+        ref = _reference(q[one], kp, vp, tables[one], pos[one])
+        np.testing.assert_allclose(
+            np.asarray(out[one]), np.asarray(ref), atol=2e-5, rtol=2e-5
+        )
+
+
+@pytest.mark.parametrize(
+    "hkv,p,dh,r,itemsize,maxp,n",
+    [
+        (8, 64, 128, 4, 2, 132, 4),    # mistral7b-serve1, K = 1
+        (8, 64, 128, 20, 2, 132, 4),   # ... K = 5
+        (8, 64, 128, 4, 4, 132, 2),    # float32 pools: half the pages
+        (2, 16, 64, 4, 4, 3, 2),       # no more than the table holds
+        (8, 1024, 128, 4, 2, 8, 1),    # a page over the budget: one
+    ],
+)
+def test_block_size_follows_from_the_shapes(hkv, p, dh, r, itemsize, maxp, n):
+    assert _pages_per_block(hkv, p, dh, r, itemsize, maxp) == n
 
 
 def test_inactive_slot_is_harmless():

@@ -26,6 +26,9 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 H, HKV, DH = 32, 8, 128
+# mistral7b-serve1's shapes (benchmarks/configs): 32 slots, 768 pages of
+# 64 tokens and the dump page, max_seq 8448.
+POOL_PAGES, PAGE, SLOTS, MAX_PAGES = 769, 64, 32, 132
 
 
 @pytest.fixture(scope="module")
@@ -84,11 +87,11 @@ def _flash_args(on):
     return bf16(2, 4096, H, DH), bf16(2, 4096, HKV, DH), bf16(2, 4096, HKV, DH)
 
 
-def _paged_args(on, k: int):
+def _paged_args(on, k: int, b=64, max_pages=32, pool_pages=64 * 32 + 1):
     # Batch 64, 64-token pages, 32 pages a sequence + the dump page.
-    b, page, max_pages = 64, 64, 32
+    page = 64
     pool = jax.ShapeDtypeStruct(
-        (b * max_pages + 1, HKV, page, DH), jnp.bfloat16, sharding=on
+        (pool_pages, HKV, page, DH), jnp.bfloat16, sharding=on
     )
     return (
         jax.ShapeDtypeStruct((b, k, H, DH), jnp.bfloat16, sharding=on),
@@ -99,26 +102,40 @@ def _paged_args(on, k: int):
     )
 
 
+def _paged_serve_args(on, k: int):
+    # mistral7b-serve1: 32 slots of 132 pages over six layers' pools
+    # (769 pages each) in one flat view, as the decode program's layer
+    # loop carries them.
+    return _paged_args(
+        on, k, b=SLOTS, max_pages=MAX_PAGES, pool_pages=6 * POOL_PAGES
+    )
+
+
 @pytest.mark.parametrize(
     "case",
-    ["flash_fwd", "flash_fwd_bwd", "paged_k1", "paged_k4"],
+    [
+        "flash_fwd", "flash_fwd_bwd", "paged_k1", "paged_k4",
+        "paged_serve_k1", "paged_serve_k5",
+    ],
 )
 def test_kernel_compiles_for_v5e_at_llama3_8b_widths(v5e, case):
+    """A compile that succeeds is also the proof that the kernel's
+    buffers fit the chip's VMEM."""
     fn, args = {
         "flash_fwd": (_flash(False), _flash_args(v5e)),
         "flash_fwd_bwd": (_flash(True), _flash_args(v5e)),
         "paged_k1": (_paged, _paged_args(v5e, 1)),
         "paged_k4": (_paged, _paged_args(v5e, 4)),
+        "paged_serve_k1": (_paged, _paged_serve_args(v5e, 1)),
+        "paged_serve_k5": (_paged, _paged_serve_args(v5e, 5)),
     }[case]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
 # ------------------------------------------------- the serving programs
-# mistral7b-serve1's shapes (benchmarks/configs): 32 slots, 768 pages of
-# 64 tokens and the dump page, max_seq 8448, prefill_chunk 2048; 2 of
-# its 6 layers, enough for a loop.
-POOL_PAGES, PAGE, SLOTS, MAX_PAGES = 769, 64, 32, 132
+# At mistral7b-serve1's shapes (above) with prefill_chunk 2048; 2 of its
+# 6 layers, enough for a loop.
 LAYER_PAGES_ELEMS = POOL_PAGES * HKV * PAGE * DH
 
 _MOVES = re.compile(

@@ -10,7 +10,10 @@ weights from ``--seed``):
 - *serve*: ``serve.run(build_llm_deployment(...))`` behind
   ``serve.start_http()`` answers a few POSTs; then, the replica gone, a
   plain ``@ray_tpu.remote(num_tpus=1)`` task builds the same
-  ``LLMEngine`` and compares its logits with ``models.forward``.
+  ``LLMEngine`` and compares its logits with ``models.forward``; and
+  another builds a tiny hybrid engine (``models/nemotron_h.py``: Mamba-2,
+  expert and attention blocks at Nemotron-3-Nano's widths) and compares
+  its chunked prefill and decode with the plain reference.
 - ``--chips 4`` instead runs the sharded trainer (one worker, four
   chips, an ``{"fsdp": 4}`` mesh) against the same seed and batch on a
   one-device mesh, and no other phase.
@@ -85,6 +88,19 @@ SIZES = {
         "requests": [(12, False), (40, False), (100, False), (61, True),
                      (5, False)],
         "check_prompt": 48, "check_decode": 4, "check_pad": 128,
+    },
+    # One tiny hybrid engine (models/nemotron_h.py) beside the Llama
+    # one: every kind of block once or twice at Nemotron-3-Nano's widths,
+    # so that the kernels see their real tiles; tiny in depth, experts
+    # and vocabulary (0.7 GB of weights). The 200-token prompt goes in
+    # two chunks of 128, the second padded.
+    "hybrid": {
+        "cfg": {"pattern": "ME*EM", "num_experts": 8, "vocab_size": 8192},
+        "reduced": {"pattern": "5 of 52 blocks", "num_experts": "8 of 128",
+                    "vocab_size": "8,192 of 131,072 rows"},
+        "engine": {"max_batch": 4, "max_seq": 1024, "page_size": 64,
+                   "prefill_chunk": 128},
+        "check_prompt": 200, "check_decode": 4,
     },
 }
 
@@ -326,6 +342,95 @@ def engine_check(cfg, engine_kwargs: dict, sizes: dict, seed: int) -> dict:
     }
 
 
+def hybrid_check(cfg, engine_kwargs: dict, sizes: dict, seed: int) -> dict:
+    """A hybrid engine (Mamba-2, expert and attention blocks over pages
+    and per-slot state): one request through add_request/step, prefilled
+    in chunks, then decode steps; the logits its programs produced (handed
+    over by `LLMEngine.on_logits`) against the plain reference's one full
+    pass on the same weights, tokens and routes."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmarks import reference_nemotron_h as reference
+    from ray_tpu.llm.engine import LLMEngine, SamplingParams
+
+    t_start = time.perf_counter()
+    compiles = _watch_compiles()
+    eng = LLMEngine(cfg, **engine_kwargs)
+    seen = []
+    eng.on_logits = lambda phase, logits, record: seen.append(
+        (phase, np.asarray(logits), np.asarray(record["routes"]))
+    )
+    rng = np.random.default_rng(seed)
+    n, n_decode = sizes["check_prompt"], sizes["check_decode"]
+    prompt = rng.integers(1, cfg.vocab_size, n).tolist()
+    (generated,) = eng.generate(
+        [prompt], SamplingParams(max_tokens=n_decode + 1)
+    )
+    prefills = [s for s in seen if s[0].startswith("prefill")]
+    decodes = [s for s in seen if s[0] == "decode"]
+    _require(
+        len(decodes) == n_decode and len(prefills) >= 1,
+        f"engine made {len(prefills)} prefill and {len(decodes)} decode "
+        f"calls for {n_decode + 1} tokens",
+    )
+    # Slot 0 serves the only request.
+    routes = np.concatenate([s[2] for s in prefills], axis=1)[:, :n]
+    routes = np.concatenate([routes] + [s[2][:, :1] for s in decodes], axis=1)
+    ref, record = reference.forward_with_record(
+        eng.params, jnp.asarray(prompt + generated[:-1], jnp.int32),
+        routes=jnp.asarray(routes), rows=list(range(n - 1, n + n_decode)),
+        pattern=cfg.pattern, mamba_heads=cfg.mamba_heads,
+        mamba_head_dim=cfg.mamba_head_dim, n_groups=cfg.ssm_groups,
+        ssm_state_size=cfg.ssm_state, conv_kernel=cfg.conv_kernel,
+        num_experts_per_tok=cfg.top_k, norm_topk_prob=cfg.norm_topk_prob,
+        routed_scaling_factor=cfg.routed_scaling_factor,
+        num_attention_heads=cfg.n_heads, num_key_value_heads=cfg.n_kv_heads,
+        head_dim=cfg.head_dim,
+    )
+    ref = np.asarray(ref)
+    got = [prefills[-1][1][0, 0]] + [s[1][0] for s in decodes]
+    return {
+        **_device_record(),
+        "wall_s": time.perf_counter() - t_start,
+        "compile_s": sum(compiles.values()),
+        "prefill_calls": len(prefills),
+        "logit_max_abs_err": [
+            float(np.abs(g - r).max()) for g, r in zip(got, ref, strict=True)
+        ],
+        "logits_finite": bool(all(np.isfinite(g).all() for g in got)),
+        "logit_scale": float(np.abs(ref).max()),
+        "largest_route_slack": float(np.asarray(record["slack"]).max()),
+        "paged_attn_kernel": bool(eng.paged_attn_kernel),
+        "engine_stats": eng.stats(),
+    }
+
+
+def phase_hybrid(sizes: dict, seed: int) -> dict:
+    """The hybrid engine on a plain task's lease."""
+    from ray_tpu.models.nemotron_h import NEMOTRON_H_PRESETS, NemotronHConfig
+
+    cfg = dataclasses.replace(
+        NEMOTRON_H_PRESETS["nemotron_h_tiny"] if PRESET == "tiny"
+        else NemotronHConfig(), **sizes["cfg"],
+    )
+    t0 = time.perf_counter()
+    check = ray_tpu.get(
+        ray_tpu.remote(num_tpus=1)(hybrid_check).remote(
+            cfg, {**sizes["engine"], "seed": seed}, sizes, seed
+        )
+    )
+    wait_chip_free()
+    return _emit({
+        "phase": "hybrid_check",
+        "cfg": sizes["cfg"],
+        "reduced": sizes["reduced"],
+        "tolerance": LOGIT_TOLERANCE,
+        **check,
+        "task_wall_s": time.perf_counter() - t0,
+    })
+
+
 # ------------------------------------------------------ the parent's side
 def _emit(record: dict) -> dict:
     print(json.dumps(record), flush=True)
@@ -529,6 +634,14 @@ def verify(records: list[dict], chips: int) -> dict:
         _require(max(check["logit_max_abs_err"]) <= LOGIT_TOLERANCE,
                  "engine logits differ from models.forward by "
                  f"{check['logit_max_abs_err']} (prefill, then decode steps)")
+    if "hybrid_check" in by_phase:
+        check = by_phase["hybrid_check"]
+        _require(check["paged_attn_kernel"],
+                 "the hybrid engine ran without the paged-attention kernel")
+        _require(check["logits_finite"], "hybrid engine logits not finite")
+        _require(max(check["logit_max_abs_err"]) <= LOGIT_TOLERANCE,
+                 "hybrid engine logits differ from the plain reference by "
+                 f"{check['logit_max_abs_err']} (prefill, then decode steps)")
     return {"platform": "tpu", "kind": kinds.pop(), "count": chips}
 
 
@@ -538,6 +651,7 @@ def run_phases(chips: int, seed: int, sizes: dict = SIZES) -> list[dict]:
     return [
         phase_train("train", sizes["train"], seed, chips=1),
         *phase_serve(sizes["serve"], seed),
+        phase_hybrid(sizes["hybrid"], seed),
     ]
 
 

@@ -7,11 +7,17 @@ TPU-native shape: tensor parallelism is not worker processes — it is the
 same XLA programs pjit-sharded over a mesh's 'tp' axis, so adding
 chips changes a sharding annotation, not the orchestration.
 
-One cache, the page pool (`paged_kv.init_paged_kv`), and three programs
-over it: `paged_prefill` (a whole prompt, bucketed to power-of-two
-lengths to bound compile count), `paged_prefill_chunk` (one chunk of a
-long prompt) and `paged_verify` (the decode program: K = 1 + `speculate`
-tokens a slot). add_request() parks requests in a FIFO; step() admits
+One cache and three programs over it, which the MODEL supplies
+(`self.serving`: `paged_kv.LlamaServing` for a `LlamaConfig`, or what a
+config's own `serving()` returns, `hybrid_kv.HybridServing` for
+`models/nemotron_h.py`). For a Llama-shaped model the cache is the page
+pool (`paged_kv.init_paged_kv`) and the programs are `paged_prefill` (a
+whole prompt, bucketed to power-of-two lengths to bound compile count),
+`paged_prefill_chunk` (one chunk of a long prompt) and `paged_verify`
+(the decode program: K = 1 + `speculate` tokens a slot); a model with
+recurrent blocks keeps per-slot state beside the pages, and its
+programs are told the slot, the context's true length and which slots
+are decoding. add_request() parks requests in a FIFO; step() admits
 queued requests into free slots while their pages fit the pool, runs at
 most one prefill chunk, and then advances all `max_batch` slots with
 one decode program.
@@ -41,21 +47,16 @@ from functools import partial
 from typing import Any
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
 from ray_tpu.llm.paged_kv import (
+    LlamaServing,
     PageAllocator,
-    init_paged_kv,
-    matmul_weights,
-    paged_prefill,
-    paged_prefill_chunk,
-    paged_verify,
     prefix_hashes,
     propose_ngram_draft,
 )
-from ray_tpu.models.llama import LlamaConfig, PRESETS, init_params, param_logical_axes
+from ray_tpu.models.llama import LlamaConfig, PRESETS
 
 
 @dataclass(frozen=True)
@@ -94,17 +95,6 @@ def _bucket(n: int, lo: int = 16) -> int:
     return b
 
 
-_cast_weights = jax.jit(matmul_weights, static_argnames="cfg")
-
-
-@partial(jax.jit, static_argnames="cfg")
-def _init_weights(key, cfg):
-    """An engine's own weights, made as it holds them: `init_params`'
-    values rounded once, without an fp32 copy of the tree on the device
-    beside them."""
-    return matmul_weights(init_params(key, cfg), cfg)
-
-
 class LLMEngine:
     def __init__(
         self,
@@ -135,26 +125,31 @@ class LLMEngine:
         self.platform = chip.platform()
         cfg = PRESETS[model] if isinstance(model, str) else model
         self.cfg = cfg
+        # What the model supplies: its weights as held, its cache and
+        # its three programs.
+        serving = cfg.serving() if hasattr(cfg, "serving") else LlamaServing(cfg)
+        self.serving = serving
+        if serving.recurrent and speculate:
+            raise ValueError(
+                "speculate > 0 with recurrent blocks: a rejected draft "
+                "would need the slot's state rolled back, which is not "
+                "written"
+            )
         self.max_batch = max_batch
         self.max_seq = max_seq or cfg.max_seq
         self.mesh = mesh
         if params is None:
-            params = _init_weights(jax.random.key(seed), cfg=cfg)
+            params = serving.init_weights(jax.random.key(seed))
         if mesh is not None:
             from ray_tpu.parallel.sharding import shard_pytree
 
-            params = shard_pytree(params, mesh, param_logical_axes(cfg))
+            params = shard_pytree(params, mesh, serving.logical_axes())
         # One program casts what every program would otherwise cast on
         # every call; each leaf stays sharded as it came, and the
         # caller's tree is neither donated nor kept. Where nothing is to
         # be cast (a float32 config, a tree already held) the arrays
         # stay the caller's own.
-        held = _cast_weights.eval_shape(params, cfg=cfg)
-        if [x.dtype for x in jax.tree.leaves(held)] != [
-            x.dtype for x in jax.tree.leaves(params)
-        ]:
-            params = _cast_weights(params, cfg=cfg)
-        self.params = params
+        self.params = serving.held_weights(params)
         self.page_size = page_size
         self.prefill_delay_s = float(prefill_delay_s)
         self.speculate = int(speculate)
@@ -187,12 +182,12 @@ class LLMEngine:
             from jax.sharding import PartitionSpec as P
 
             ns = NamedSharding(mesh, P(None, None, "tp", None, None))
-            self.cache = jax.jit(
-                partial(init_paged_kv, cfg, num_pages + 1, page_size),
-                out_shardings={"k": ns, "v": ns},
-            )()
+            self.cache = serving.init_cache(
+                num_pages + 1, page_size, max_batch,
+                shardings={"k": ns, "v": ns},
+            )
         else:
-            self.cache = init_paged_kv(cfg, num_pages + 1, page_size)
+            self.cache = serving.init_cache(num_pages + 1, page_size, max_batch)
         self.max_pages_per_seq = -(-self.max_seq // page_size)
         # Pallas paged-attention kernel on a bare TPU backend (under a
         # mesh XLA's SPMD partitioner stays in charge).
@@ -216,12 +211,20 @@ class LLMEngine:
             )
         self.prefill_chunk = prefill_chunk
         self._prefilling: dict | None = None
-        self._prefill_chunk_fn = partial(paged_prefill_chunk, cfg=cfg)
-        self._prefill_paged = partial(paged_prefill, cfg=cfg)
+        self._prefill_chunk_fn = serving.prefill_chunk
+        self._prefill_paged = serving.prefill
         # The one decode program, K = 1 + speculate tokens a slot.
-        self._decode_paged = partial(
-            paged_verify, cfg=cfg, use_kernel=use_kernel
-        )
+        self._decode_paged = partial(serving.decode, use_kernel=use_kernel)
+        # Called, where set, with every program's logits as the program
+        # returned them (on the device) and its record, if it makes one:
+        # `on_logits(phase, logits, record)`, phase "prefill",
+        # "prefill_chunk" or "decode". For checks of the timed programs'
+        # own output against a reference.
+        self.on_logits = None
+        # (token, expert) pairs a token is routed to over the model's
+        # expert blocks, where its programs keep a record of them.
+        self._pairs_per_token = serving.pairs_per_token
+        self._moe_counts: list = []  # (phase, device int32[2]), unfolded
         self._step_key = jax.random.key(seed)
         self._temps = np.zeros((max_batch,), np.float32)
         self._queue: list[_Request] = []
@@ -275,6 +278,20 @@ class LLMEngine:
             "param_bytes": sum(
                 x.nbytes for x in jax.tree.leaves(self.params)
             ),
+            # The cache's two kinds of per-sequence state: pages, and
+            # what recurrent blocks keep per slot (0 without any).
+            "pool_bytes": int(self.cache["k"].nbytes + self.cache["v"].nbytes),
+            "state_bytes": sum(
+                int(v.nbytes) for k, v in self.cache.items()
+                if k not in ("k", "v")
+            ),
+            # Expert blocks (0 without any): pairs the live tokens were
+            # routed to, the pairs among them whose expert is held here,
+            # and held experts that got a row, summed over expert blocks
+            # and decode steps.
+            "moe_pairs_routed": 0,
+            "moe_pairs_here": 0,
+            "experts_touched": 0,
         }
 
     # ------------------------------------------------------ request API
@@ -450,8 +467,11 @@ class LLMEngine:
         ctx_len is the true (unpadded) prefilled length — prompt plus
         any tokens generated before a preemption. logit_idx overrides
         the row to sample from (chunked prefill: the last token's index
-        LOCAL to the final chunk)."""
+        LOCAL to the final chunk); a model whose prefill returns the
+        last real token's logits alone has them in row 0."""
         with TraceAnnotation("engine:first_token", rid=req.request_id):
+            if self.serving.logits_last_only:
+                logit_idx = 0
             # The host waits here for the prefill program.
             last = np.asarray(
                 logits[0, ctx_len - 1 if logit_idx is None else logit_idx]
@@ -556,13 +576,17 @@ class LLMEngine:
             # Prefill rewrites shared pages with byte-identical values
             # (K/V at position i depend only on tokens <= i) —
             # idempotent, so no write mask is needed.
-            logits, self.cache = self._prefill_paged(
+            # Host arrays go in as they are: the call transfers them.
+            logits, self.cache, *record = self._prefill_paged(
                 self.params,
-                jnp.asarray(tokens),
+                tokens,
                 self.cache,
-                jnp.asarray(np.asarray(pages, np.int32)),
+                np.asarray(pages, np.int32),
                 n_write_pages=need_pages,
+                slot=slot,
+                length=len(context),
             )
+            self._account("prefill", logits, record, len(context))
             self._post_prefill(req, slot, logits, len(context), finished)
             return True
 
@@ -574,7 +598,13 @@ class LLMEngine:
         P = self.page_size
         context = st["context"]
         start = st["next_start"]
-        end = min(start + self.prefill_chunk, st["ctx_pad"])
+        end = start + self.prefill_chunk
+        if not self.serving.fixed_chunks or end > st["need_pages"] * P:
+            # As long as the context's own pages; a model whose programs
+            # take the true length pads its last chunk to the chunk's
+            # own length instead (one compiled shape), where the
+            # bucket's pages reach that far.
+            end = min(end, st["ctx_pad"])
         with TraceAnnotation(
             "engine:prefill_chunk", rid=st["req"].request_id,
             start=start, tokens=end - start,
@@ -582,15 +612,18 @@ class LLMEngine:
             tokens = np.zeros((1, end - start), np.int32)
             valid = context[start: min(end, len(context))]
             tokens[0, : len(valid)] = valid
-            logits, self.cache = self._prefill_chunk_fn(
+            logits, self.cache, *record = self._prefill_chunk_fn(
                 self.params,
-                jnp.asarray(tokens),
+                tokens,
                 self.cache,
-                jnp.asarray(st["pages"]),
-                jnp.int32(start),
+                st["pages"],
+                np.int32(start),
                 n_write_pages=st["need_pages"],
                 chunk_pages=(end - start) // P,
+                slot=st["slot"],
+                length=len(context),
             )
+            self._account("prefill_chunk", logits, record, len(valid))
             st["next_start"] = end
             self._stats["prefill_chunks"] += 1
             if end >= st["ctx_pad"]:
@@ -601,6 +634,28 @@ class LLMEngine:
                     st["req"], st["slot"], logits, len(context), finished,
                     logit_idx=len(context) - 1 - start,
                 )
+
+    def _account(self, phase: str, logits, record: list, tokens: int) -> None:
+        """After every program: hand its logits to `on_logits`, and keep
+        the expert blocks' counters of a program that records them
+        (`tokens` live tokens went through it). The counters stay on the
+        device until `stats()` asks: no program is waited for here."""
+        record = record[0] if record else None
+        if self.on_logits is not None:
+            self.on_logits(phase, logits, record)
+        if record is not None:
+            self._stats["moe_pairs_routed"] += tokens * self._pairs_per_token
+            self._moe_counts.append((phase, record["counts"]))
+            if len(self._moe_counts) >= 512:
+                self._fold_moe_counts()
+
+    def _fold_moe_counts(self) -> None:
+        counts, self._moe_counts = self._moe_counts, []
+        for phase, pair in counts:
+            here, touched = (int(v) for v in np.asarray(pair))
+            self._stats["moe_pairs_here"] += here
+            if phase == "decode":
+                self._stats["experts_touched"] += touched
 
     def step(self) -> list[dict]:
         """Admit + one decode step. Returns finished request dicts."""
@@ -732,17 +787,25 @@ class LLMEngine:
             r.sampling.temperature > 0 and not r.sampling.top_k
             for r in self._active.values()
         )
+        # The slots that decode: a recurrent model's program leaves the
+        # state of the others (free, or mid-prefill) as it is.
+        decoding = np.zeros((self.max_batch,), bool)
+        decoding[list(self._active)] = True
         with TraceAnnotation("engine:decode_dispatch"):
-            sampled, logits, self.cache, accept, rej = self._decode_paged(
+            (
+                sampled, logits, self.cache, accept, rej, *record
+            ) = self._decode_paged(
                 self.params,
-                jnp.asarray(toks),
+                toks,
                 self.cache,
-                jnp.asarray(tables),
-                jnp.asarray(self._positions),
-                jnp.asarray(self._temps),
+                tables,
+                self._positions,
+                self._temps,
                 sub,
                 stochastic=stochastic,
+                active=decoding,
             )
+            self._account("decode", logits, record, len(self._active))
         with TraceAnnotation("engine:decode_sync"):
             sampled = np.asarray(sampled)  # [B, K] ints
             n_acc = draft_len  # no draft, none accepted: all of K = 1
@@ -852,6 +915,19 @@ class LLMEngine:
                     return True
         return False
 
+    def slot_of(self, request_id: str) -> int | None:
+        """The decode slot a request holds (decoding, or mid-prefill), or
+        None: which row of a program's per-slot output is this
+        request's."""
+        with self._lock:
+            st = self._prefilling
+            if st is not None and st["req"].request_id == request_id:
+                return st["slot"]
+            for slot, req in self._active.items():
+                if req.request_id == request_id:
+                    return slot
+        return None
+
     def occupancy(self) -> dict:
         """Decode slots and pool pages in use, for the serve gauges.
         Takes no lock: the pump asks on the event loop between steps."""
@@ -873,6 +949,7 @@ class LLMEngine:
         decode program was compiled with (`paged_attn_kernel`,
         `kv_write_kernel`) and the pool/slot occupancy."""
         with self._lock:
+            self._fold_moe_counts()
             out = dict(self._stats)
             out["platform"] = self.platform
             out["device_kind"] = jax.devices()[0].device_kind

@@ -1,0 +1,315 @@
+"""Serving programs for a model whose blocks differ in kind
+(``models/nemotron_h.py``): pages for the blocks that attend, per-slot
+recurrent state for the blocks that carry one.
+
+The cache is ONE donated tree with two kinds of per-sequence state:
+
+- ``k``, ``v`` ``[L_attn, num_pages, Hkv, P, Dh]``: `paged_kv.py`'s page
+  pool, for the attention blocks only, in its one layout, reached
+  through block tables;
+- ``ssm`` ``[L_mamba, max_batch, H, P, N]`` float32 and ``conv``
+  ``[L_mamba, max_batch, K - 1, conv_dim]``: each decode SLOT's Mamba-2
+  state and convolution tail. It is not paged and does not grow.
+
+Both are carried through the Python loop over the pattern and updated in
+place (a block writes its own layer's slot rows; nothing is sliced out
+and stacked back).
+
+Two programs. ``prefill_program`` (one jitted program a shape) runs a
+prompt, or one chunk of it, for one slot: it takes the slot, where the
+chunk starts and the context's TRUE length. From position 0 it starts
+from zero state, whatever the slot's last request left; a later chunk starts from the state the chunk
+before it left. Positions from the true length on are padding: they take
+no step of the recurrence, the convolution tail is the last real
+inputs, their expert pairs are left out, and the logits returned are
+the last real token's alone. ``hybrid_decode`` advances every slot by
+one token and takes the slots that are decoding: a slot that is free or
+mid-prefill keeps its state. Attention is `paged_kv.py`'s own
+definitions (projections, page writes, the gather path and the Pallas
+kernel path), the expert mixer is ``moe_ffn``.
+"""
+
+from __future__ import annotations
+
+import functools
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ray_tpu.llm.paged_kv import (
+    _decode_attention,
+    _decode_geometry,
+    _flat_pool,
+    _gather_page_attention,
+    _project_qkv,
+    _sample_tokens,
+    _write_pages,
+    init_paged_kv,
+)
+from ray_tpu.models.moe import moe_ffn
+from ray_tpu.models.nemotron_h import (
+    NemotronHConfig,
+    init_params,
+    mamba_chunked,
+    mamba_step,
+)
+from ray_tpu.ops.norms import rms_norm
+
+HybridCache = dict[str, jnp.ndarray]
+
+
+def init_hybrid_cache(
+    cfg: NemotronHConfig, num_pages: int, page_size: int, max_batch: int
+) -> HybridCache:
+    n_mamba = cfg.count("M")
+    cache = init_paged_kv(cfg, num_pages, page_size, n_layers=cfg.count("*"))
+    cache["ssm"] = jnp.zeros(
+        (n_mamba, max_batch, cfg.mamba_heads, cfg.mamba_head_dim,
+         cfg.ssm_state),
+        jnp.float32,
+    )
+    cache["conv"] = jnp.zeros(
+        (n_mamba, max_batch, cfg.conv_kernel - 1, cfg.conv_dim), cfg.dtype
+    )
+    return cache
+
+
+def _experts(x, p, cfg, rows_live, record):
+    """An expert block on x [B, S, d], and its counters onto ``record``."""
+    out, aux = moe_ffn(rms_norm(x, p["norm"]), p, cfg, rows_live=rows_live)
+    record["routes"].append(aux["routes"])
+    record["pairs_here"].append(aux["expert_load"].sum())
+    record["experts_touched"].append((aux["expert_load"] > 0).sum())
+    return x + out
+
+
+def _record(record):
+    """Per program: ``routes`` [L_expert, T, k] (each token's experts, of
+    all the model's) and ``counts`` int32[2]: the pairs of live rows
+    whose expert is held, and the held experts that got a row, each
+    summed over the expert blocks."""
+    return {
+        "routes": jnp.stack(record["routes"]),
+        "counts": jnp.stack(
+            [sum(record["pairs_here"]), sum(record["experts_touched"])]
+        ).astype(jnp.int32),
+    }
+
+
+def _carried(cache, k_pages, v_pages, ssm, conv) -> HybridCache:
+    """The cache as a program hands it on: the flat pool back in the
+    argument's shape, the state as the loop left it."""
+    return {
+        "k": k_pages.reshape(cache["k"].shape),
+        "v": v_pages.reshape(cache["v"].shape),
+        "ssm": ssm,
+        "conv": conv,
+    }
+
+
+def _head(x, params):
+    x = rms_norm(x, params["final_norm"])
+    return (x @ params["lm_head"]).astype(jnp.float32)
+
+
+def _hybrid_prefill(
+    params,
+    tokens: jnp.ndarray,  # [1, C] int32, C = chunk_pages * page_size
+    cache: HybridCache,
+    pages: jnp.ndarray,  # [n_write_pages] int32: the FULL context table
+    start: jnp.ndarray,  # [] int32: position of tokens[0, 0], page-aligned
+    slot: jnp.ndarray,  # [] int32: the decode slot this prompt is for
+    length: jnp.ndarray,  # [] int32: the context's true length
+    cfg: NemotronHConfig,
+    n_write_pages: int,
+    chunk_pages: int,
+):
+    """A prompt (``start`` 0, ``chunk_pages == n_write_pages``) or one
+    chunk of it. Returns (logits [1, 1, V] float32 of position
+    ``length - 1`` — meaningful in the chunk that holds it —, cache,
+    record)."""
+    c = tokens.shape[1]
+    page_size = cache["k"].shape[3]
+    num_pages = cache["k"].shape[1]
+    window = n_write_pages * page_size
+    pos = start + jnp.arange(c, dtype=jnp.int32)[None, :]  # [1, C]
+    live = (pos < length)[0]  # [C]
+    chunk_slice = jax.lax.dynamic_slice(
+        pages, [start // page_size], [chunk_pages]
+    )
+    mask = jnp.arange(window)[None, None, :] > pos[:, :, None]
+    k_pages, v_pages = _flat_pool(cache)
+    ssm, conv = cache["ssm"], cache["conv"]
+    x = params["tok_emb"][tokens]
+    record = {"routes": [], "pairs_here": [], "experts_touched": []}
+    n_attn = n_mamba = 0
+    for kind, p in zip(cfg.pattern, params["blocks"], strict=True):
+        if kind == "M":
+            fresh = start == 0
+            out, ssm_end, conv_end = mamba_chunked(
+                rms_norm(x, p["norm"])[0], p, cfg,
+                jnp.where(fresh, 0.0, ssm[n_mamba, slot]),
+                jnp.where(fresh, 0, conv[n_mamba, slot]),
+                jnp.clip(length - start, 0, c),
+            )
+            with jax.named_scope("ssm:scan"):
+                ssm = ssm.at[n_mamba, slot].set(ssm_end)
+                conv = conv.at[n_mamba, slot].set(conv_end)
+            x = x + out[None]
+            n_mamba += 1
+        elif kind == "E":
+            x = _experts(x, p, cfg, live, record)
+        else:
+            base = n_attn * num_pages
+            q, k, v = _project_qkv(x, p, cfg)  # [1, C, H, Dh]
+            k_pages, v_pages = _write_pages(
+                k_pages, v_pages, k, v, base + chunk_slice, cfg
+            )
+            attn = _gather_page_attention(
+                q, k_pages, v_pages, base + pages[None, :], mask, cfg
+            )
+            x = x + attn.reshape(1, c, -1) @ p["wo"]
+            n_attn += 1
+    last = jax.lax.dynamic_slice_in_dim(x, length - 1 - start, 1, axis=1)
+    carried = _carried(cache, k_pages, v_pages, ssm, conv)
+    return _head(last, params), carried, _record(record)
+
+
+@functools.lru_cache(maxsize=None)
+def prefill_program(cfg: NemotronHConfig, n_write_pages: int, chunk_pages: int):
+    """`_hybrid_prefill` jitted for one shape, under a name that says
+    which (``hybrid_prefill_<chunk pages>_of_<table pages>``): a trace
+    then names each bucket's program, and an instruction name is looked
+    up in the text of the program it ran in."""
+
+    def program(params, tokens, cache, pages, start, slot, length):
+        return _hybrid_prefill(
+            params, tokens, cache, pages, start, slot, length, cfg,
+            n_write_pages, chunk_pages,
+        )
+
+    program.__name__ = f"hybrid_prefill_{chunk_pages}_of_{n_write_pages}"
+    return jax.jit(program, donate_argnames=("cache",))
+
+
+@partial(
+    jax.jit,
+    static_argnames=("cfg", "use_kernel"),
+    donate_argnames=("cache",),
+)
+def hybrid_decode(
+    params,
+    tokens: jnp.ndarray,  # [B, 1] int32
+    cache: HybridCache,
+    block_tables: jnp.ndarray,  # [B, max_pages] int32 (-1 = unused)
+    positions: jnp.ndarray,  # [B] int32: position tokens[:, 0] writes at
+    active: jnp.ndarray,  # [B] bool: the slots that are decoding
+    temperature: jnp.ndarray,  # [B] fp32 (0 = greedy)
+    rng_key: jnp.ndarray,
+    cfg: NemotronHConfig,
+    use_kernel: bool = False,
+):
+    """The decode program: one token a slot, sampled on device. A slot
+    that is not ``active`` computes like the others (static shapes) and
+    changes nothing that lasts: its state stays as it is, its expert
+    pairs are left out, its K/V cell goes to the dump page (its table is
+    all -1). Returns (sampled [B, 1] int32, logits [B, V] fp32, cache,
+    record)."""
+    b = tokens.shape[0]
+    page_size = cache["k"].shape[3]
+    num_pages = cache["k"].shape[1]
+    geometry = _decode_geometry(block_tables, positions, 1, page_size)
+    k_pages, v_pages = _flat_pool(cache)
+    ssm, conv = cache["ssm"], cache["conv"]
+    x = params["tok_emb"][tokens]  # [B, 1, d]
+    record = {"routes": [], "pairs_here": [], "experts_touched": []}
+    n_attn = n_mamba = 0
+    for kind, p in zip(cfg.pattern, params["blocks"], strict=True):
+        if kind == "M":
+            old_ssm, old_conv = ssm[n_mamba], conv[n_mamba]
+            out, new_ssm, new_conv = mamba_step(
+                rms_norm(x, p["norm"])[:, 0], p, cfg, old_ssm, old_conv
+            )
+            # The write back is the state update's other half: under
+            # its scope, so that its time is read with it.
+            with jax.named_scope("ssm:update"):
+                ssm = ssm.at[n_mamba].set(
+                    jnp.where(active[:, None, None, None], new_ssm, old_ssm)
+                )
+                conv = conv.at[n_mamba].set(
+                    jnp.where(active[:, None, None], new_conv, old_conv)
+                )
+            x = x + out[:, None]
+            n_mamba += 1
+        elif kind == "E":
+            x = _experts(x, p, cfg, active, record)
+        else:
+            q, k, v = _project_qkv(x, p, cfg)  # [B, 1, H, Dh]
+            attn, k_pages, v_pages = _decode_attention(
+                q, k.astype(cfg.dtype), v.astype(cfg.dtype), k_pages,
+                v_pages, n_attn * num_pages, geometry, positions, cfg,
+                use_kernel,
+            )
+            x = x + attn.reshape(b, 1, -1) @ p["wo"]
+            n_attn += 1
+    logits = _head(x, params)  # [B, 1, V]
+    sampled = _sample_tokens(logits, temperature, rng_key)
+    carried = _carried(cache, k_pages, v_pages, ssm, conv)
+    return sampled, logits[:, 0], carried, _record(record)
+
+
+class HybridServing:
+    """What `LLMEngine` serves a `NemotronHConfig` through (see
+    `paged_kv.LlamaServing` for the convention)."""
+
+    recurrent = True  # per-slot state: no rollback, so no speculation
+    logits_last_only = True  # prefill returns the last real token's logits
+    # A prompt's last chunk is padded to the chunk's length (the program
+    # takes the true length), so that chunks compile to one shape.
+    fixed_chunks = True
+
+    def __init__(self, cfg: NemotronHConfig):
+        self.cfg = cfg
+        self.pairs_per_token = cfg.top_k * cfg.count("E")
+
+    def init_weights(self, key):
+        return init_params(key, self.cfg)
+
+    def logical_axes(self):
+        raise NotImplementedError(
+            "a mesh: the hybrid programs are written for one chip's share "
+            "(experts across chips and their exchange are not)"
+        )
+
+    def held_weights(self, params):
+        return params  # `init_params` makes the tree as it is held
+
+    def init_cache(self, num_pages: int, page_size: int, max_batch: int,
+                   shardings=None):
+        return init_hybrid_cache(self.cfg, num_pages, page_size, max_batch)
+
+    def prefill(self, params, tokens, cache, pages, *, n_write_pages, slot,
+                length):
+        return prefill_program(self.cfg, n_write_pages, n_write_pages)(
+            params, tokens, cache, pages, np.int32(0), np.int32(slot),
+            np.int32(length),
+        )
+
+    def prefill_chunk(self, params, tokens, cache, pages, start, *,
+                      n_write_pages, chunk_pages, slot, length):
+        return prefill_program(self.cfg, n_write_pages, chunk_pages)(
+            params, tokens, cache, pages, start, np.int32(slot),
+            np.int32(length),
+        )
+
+    def decode(self, params, tokens, cache, block_tables, positions,
+               temperature, rng_key, *, use_kernel, stochastic, active):
+        sampled, logits, cache, record = hybrid_decode(
+            params, tokens, cache, block_tables, positions, active,
+            temperature, rng_key, cfg=self.cfg, use_kernel=use_kernel,
+        )
+        # No drafts: the engine's acceptance arrays are [B, 0].
+        none = np.zeros((tokens.shape[0], 0), np.int32)
+        return sampled, logits, cache, none.astype(bool), none, record

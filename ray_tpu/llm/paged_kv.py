@@ -74,9 +74,15 @@ _KV_REG = None
 
 
 def init_paged_kv(
-    cfg: LlamaConfig, num_pages: int, page_size: int = 64
+    cfg: LlamaConfig, num_pages: int, page_size: int = 64,
+    n_layers: int | None = None,
 ) -> PagedKV:
-    shape = (cfg.n_layers, num_pages, cfg.n_kv_heads, page_size, cfg.head_dim)
+    """``n_layers``: how many of the model's blocks attend, where not
+    all do (``llm/hybrid_kv.py``)."""
+    shape = (
+        cfg.n_layers if n_layers is None else n_layers,
+        num_pages, cfg.n_kv_heads, page_size, cfg.head_dim,
+    )
     kv = {
         "k": jnp.zeros(shape, cfg.dtype),
         "v": jnp.zeros(shape, cfg.dtype),
@@ -242,6 +248,120 @@ def _gather_page_attention(q, k_pool, v_pool, page_index, mask, cfg):
     return attn.reshape(b, q_len, cfg.n_heads, dh)
 
 
+def _write_pages(k_pages, v_pages, k, v, page_ids, cfg):
+    """A prompt's (or a chunk's) keys and values, ``[1, S, Hkv, Dh]``
+    with S = len(page_ids) * page_size, scattered into the pages
+    ``page_ids`` of the flat pool as ``[n, Hkv, P, Dh]`` cells."""
+    n, page_size = page_ids.shape[0], k_pages.shape[2]
+
+    def cells(a):
+        return a.astype(cfg.dtype).reshape(
+            n, page_size, cfg.n_kv_heads, cfg.head_dim
+        ).transpose(0, 2, 1, 3)
+
+    return (
+        k_pages.at[page_ids].set(cells(k)),
+        v_pages.at[page_ids].set(cells(v)),
+    )
+
+
+def _decode_geometry(block_tables, positions, kk_w: int, page_size: int):
+    """Where a decode step of K tokens a slot writes and what it may
+    see: ``pos2d`` [B, K], the gather path's ``mask`` [B, K, window]
+    (True = hidden), each write's physical page and cell
+    (``write_pages``, ``off_of`` [B, K]) and the tables with -1 as the
+    dump page."""
+    max_pages = block_tables.shape[1]
+    window = max_pages * page_size
+    pos2d = positions[:, None] + jnp.arange(kk_w)[None, :]  # [B, K]
+    key_idx = jnp.arange(window)[None, None, :]
+    mask = key_idx > pos2d[:, :, None]  # [B, K, window]
+
+    page_of = jnp.minimum(pos2d // page_size, max_pages - 1)  # [B, K]
+    off_of = pos2d % page_size
+    # Physical pages for each write. Two overflow routes to the dump
+    # page 0 (whose contents nobody attends): inactive slots
+    # (table -1) and draft positions past the table window — near
+    # max_seq a K-wide step can extend beyond capacity, and clamping
+    # into the LAST page would corrupt live cells.
+    write_pages = jnp.maximum(
+        jnp.take_along_axis(block_tables, page_of, axis=1), 0
+    )
+    write_pages = jnp.where(pos2d < window, write_pages, 0)  # [B, K]
+    return pos2d, mask, write_pages, off_of, jnp.maximum(block_tables, 0)
+
+
+def _decode_attention(q, k, v, k_pages, v_pages, base, geometry, positions,
+                      cfg, use_kernel: bool):
+    """A decode step's attention for one layer whose pages start at
+    ``base`` of the flat pool: write all K cells per slot (drafts may
+    span a page boundary — each position indexes its own physical
+    page), then attend. q [B, K, H, Dh], k and v [B, K, Hkv, Dh] in
+    ``cfg.dtype``. The write follows the attention's path, so that one
+    party fixes the pool's layout (module docstring). Returns (attn
+    [B, K, H, Dh], k_pages, v_pages)."""
+    _, mask, write_pages, off_of, tables = geometry
+    b, kk_w = q.shape[:2]
+    if use_kernel:
+        # Pallas path: cells patched into their pages in place,
+        # slot-major (a slot's drafts on consecutive grid steps, as
+        # write_kv_cells needs); pages read in place, GQA-grouped,
+        # each slot's own live pages and no more
+        # (ops/pallas/paged_attention.py).
+        from ray_tpu.ops.pallas.kv_cell_write import write_kv_cells
+        from ray_tpu.ops.pallas.paged_attention import paged_attention
+
+        interpret = chip.platform() != "tpu"
+        k_pages, v_pages = write_kv_cells(
+            k_pages, v_pages,
+            k.reshape(b * kk_w, cfg.n_kv_heads, cfg.head_dim),
+            v.reshape(b * kk_w, cfg.n_kv_heads, cfg.head_dim),
+            (base + write_pages).reshape(-1), off_of.reshape(-1),
+            interpret=interpret,
+        )
+        attn = paged_attention(
+            q, k_pages, v_pages, base + tables, positions,
+            n_kv_heads=cfg.n_kv_heads, interpret=interpret,
+        )
+    else:
+        # Advanced indices at dims 0 and 2 with the Hkv slice
+        # between: result dims are [B, K, Hkv, Dh], matching k.
+        k_pages = k_pages.at[base + write_pages, :, off_of, :].set(k)
+        v_pages = v_pages.at[base + write_pages, :, off_of, :].set(v)
+        attn = _gather_page_attention(
+            q, k_pages, v_pages, base + tables, mask, cfg
+        )
+    return attn, k_pages, v_pages
+
+
+def _sample_tokens(logits, temperature, rng_key):
+    """Per-position sampling of logits [B, K, V]: greedy for temp 0,
+    temperature draw otherwise (the full-p sample — used for position
+    0, for the bonus token when a whole draft is accepted, and for
+    every position on greedy slots). Returns int32 [B, K]."""
+    b, kk_w = logits.shape[:2]
+    flat = logits.reshape(b * kk_w, -1)
+    temp_flat = jnp.repeat(temperature, kk_w)
+    keys = jax.random.split(rng_key, b * kk_w)
+    greedy = jnp.argmax(flat, axis=-1)
+    drawn = jax.vmap(jax.random.categorical)(
+        keys, flat / jnp.maximum(temp_flat, 1e-6)[:, None]
+    )
+    sampled = jnp.where(temp_flat > 0.0, drawn, greedy).astype(jnp.int32)
+    return sampled.reshape(b, kk_w)
+
+
+def _flat_pool(pool: PagedKV):
+    """The pool's pages in a flat view, ``[L * num_pages, Hkv, P, Dh]``
+    (a bitcast): layer ``i``'s pages start at ``i * num_pages``."""
+    n_layers, num_pages = pool["k"].shape[:2]
+
+    def flat(a):
+        return a.reshape((n_layers * num_pages,) + a.shape[2:])
+
+    return flat(pool["k"]), flat(pool["v"])
+
+
 def _scan_layers(body, x, params, pool: PagedKV):
     """Run ``body(x, k_pages, v_pages, p, base) -> (x, k_pages, v_pages)``
     over the layers with the pool as the loop's CARRY: one buffer from
@@ -257,16 +377,13 @@ def _scan_layers(body, x, params, pool: PagedKV):
     """
     n_layers, num_pages = pool["k"].shape[:2]
 
-    def flat(a):
-        return a.reshape((n_layers * num_pages,) + a.shape[2:])
-
     def step(carry, layer):
         p, index = layer
         return body(*carry, p, index * num_pages), None
 
     (x, k_pages, v_pages), _ = jax.lax.scan(
         step,
-        (x, flat(pool["k"]), flat(pool["v"])),
+        (x, *_flat_pool(pool)),
         (params["blocks"], jnp.arange(n_layers, dtype=jnp.int32)),
     )
     return x, {
@@ -310,14 +427,9 @@ def paged_prefill(
         x = x + attn.reshape(x.shape) @ p["wo"]
         x = _mlp(x, p, cfg)
         # [1, S, Hkv, Dh] → [n_pages, Hkv, P, Dh] scatter at page ids.
-        kp = k.astype(cfg.dtype).reshape(
-            n_write_pages, page_size, cfg.n_kv_heads, cfg.head_dim
-        ).transpose(0, 2, 1, 3)
-        vp = v.astype(cfg.dtype).reshape(
-            n_write_pages, page_size, cfg.n_kv_heads, cfg.head_dim
-        ).transpose(0, 2, 1, 3)
-        k_pages = k_pages.at[base + pages].set(kp)
-        v_pages = v_pages.at[base + pages].set(vp)
+        k_pages, v_pages = _write_pages(
+            k_pages, v_pages, k, v, base + pages, cfg
+        )
         return x, k_pages, v_pages
 
     x, pool = _scan_layers(body, x, params, pool)
@@ -372,14 +484,9 @@ def paged_prefill_chunk(
         q, k, v = _project_qkv(x, p, cfg)  # [1, C, H, Dh]
         q = apply_rope(q, cos, sin, positions=pos)
         k = apply_rope(k, cos, sin, positions=pos)
-        kp = k.astype(cfg.dtype).reshape(
-            chunk_pages, page_size, cfg.n_kv_heads, cfg.head_dim
-        ).transpose(0, 2, 1, 3)
-        vp = v.astype(cfg.dtype).reshape(
-            chunk_pages, page_size, cfg.n_kv_heads, cfg.head_dim
-        ).transpose(0, 2, 1, 3)
-        k_pages = k_pages.at[base + chunk_slice].set(kp)
-        v_pages = v_pages.at[base + chunk_slice].set(vp)
+        k_pages, v_pages = _write_pages(
+            k_pages, v_pages, k, v, base + chunk_slice, cfg
+        )
         attn = _gather_page_attention(
             q, k_pages, v_pages, base + pages[None, :], mask, cfg
         )
@@ -452,23 +559,8 @@ def paged_verify(
     window = max_pages * page_size
     cos, sin = rope_frequencies(cfg.head_dim, window, cfg.rope_theta)
 
-    pos2d = positions[:, None] + jnp.arange(kk_w)[None, :]  # [B, K]
-    key_idx = jnp.arange(window)[None, None, :]
-    mask = key_idx > pos2d[:, :, None]  # [B, K, window]
-
-    page_of = jnp.minimum(pos2d // page_size, max_pages - 1)  # [B, K]
-    off_of = pos2d % page_size
-    # Physical pages for each write. Two overflow routes to the dump
-    # page 0 (whose contents nobody attends): inactive slots
-    # (table -1) and draft positions past the table window — near
-    # max_seq a K-wide step can extend beyond capacity, and clamping
-    # into the LAST page would corrupt live cells.
-    write_pages = jnp.maximum(
-        jnp.take_along_axis(block_tables, page_of, axis=1), 0
-    )
-    write_pages = jnp.where(pos2d < window, write_pages, 0)  # [B, K]
-
-    tables = jnp.maximum(block_tables, 0)
+    geometry = _decode_geometry(block_tables, positions, kk_w, page_size)
+    pos2d = geometry[0]
 
     def body(x, k_pages, v_pages, p, base):
         q, k, v = _project_qkv(x, p, cfg)  # [B, K, H, Dh]
@@ -477,39 +569,10 @@ def paged_verify(
         k = k.astype(cfg.dtype)
         v = v.astype(cfg.dtype)
 
-        # Write all K cells per slot (drafts may span a page boundary —
-        # each position indexes its own physical page), then attend.
-        # The write follows the attention's path, so that one party
-        # fixes the pool's layout (module docstring).
-        if use_kernel:
-            # Pallas path: cells patched into their pages in place,
-            # slot-major (a slot's drafts on consecutive grid steps, as
-            # write_kv_cells needs); pages read in place, GQA-grouped,
-            # each slot's own live pages and no more
-            # (ops/pallas/paged_attention.py).
-            from ray_tpu.ops.pallas.kv_cell_write import write_kv_cells
-            from ray_tpu.ops.pallas.paged_attention import paged_attention
-
-            interpret = chip.platform() != "tpu"
-            k_pages, v_pages = write_kv_cells(
-                k_pages, v_pages,
-                k.reshape(b * kk_w, cfg.n_kv_heads, cfg.head_dim),
-                v.reshape(b * kk_w, cfg.n_kv_heads, cfg.head_dim),
-                (base + write_pages).reshape(-1), off_of.reshape(-1),
-                interpret=interpret,
-            )
-            attn = paged_attention(
-                q, k_pages, v_pages, base + tables, positions,
-                n_kv_heads=cfg.n_kv_heads, interpret=interpret,
-            )
-        else:
-            # Advanced indices at dims 0 and 2 with the Hkv slice
-            # between: result dims are [B, K, Hkv, Dh], matching k.
-            k_pages = k_pages.at[base + write_pages, :, off_of, :].set(k)
-            v_pages = v_pages.at[base + write_pages, :, off_of, :].set(v)
-            attn = _gather_page_attention(
-                q, k_pages, v_pages, base + tables, mask, cfg
-            )
+        attn, k_pages, v_pages = _decode_attention(
+            q, k, v, k_pages, v_pages, base, geometry, positions, cfg,
+            use_kernel,
+        )
         x = x + attn.reshape(b, kk_w, -1) @ p["wo"]
         x = _mlp(x, p, cfg)
         return x, k_pages, v_pages
@@ -518,19 +581,7 @@ def paged_verify(
     x = rms_norm(x, params["final_norm"])
     logits = (x @ params["lm_head"]).astype(jnp.float32)
 
-    # Per-position sampling: greedy for temp 0, temperature draw
-    # otherwise (the full-p sample — used for position 0, for the
-    # bonus token when a whole draft is accepted, and for every
-    # position on greedy slots).
-    flat = logits.reshape(b * kk_w, -1)
-    temp_flat = jnp.repeat(temperature, kk_w)
-    keys = jax.random.split(rng_key, b * kk_w)
-    greedy = jnp.argmax(flat, axis=-1)
-    drawn = jax.vmap(jax.random.categorical)(
-        keys, flat / jnp.maximum(temp_flat, 1e-6)[:, None]
-    )
-    sampled = jnp.where(temp_flat > 0.0, drawn, greedy).astype(jnp.int32)
-    sampled = sampled.reshape(b, kk_w)
+    sampled = _sample_tokens(logits, temperature, rng_key)
 
     if kk_w > 1:
         # Draft acceptance inputs (see docstring). Positions 0..K-2
@@ -585,6 +636,84 @@ def paged_verify(
     # Only position 0's logits ever reach the host (top_k fallback);
     # shipping [B, K, V] would multiply that transfer by K for nothing.
     return sampled, logits[:, 0], pool, accept, rej
+
+
+class LlamaServing:
+    """What `LLMEngine` serves a Llama-shaped model through: the page
+    pool and the three programs above, under the calling convention the
+    engine has for every model. The engine also tells a program which
+    slot a prompt is for, its true length and which slots are decoding;
+    these programs need none of that (pages hold all their state, and
+    a padded tail or a free slot writes cells nobody attends), so it is
+    dropped here and the programs compile as they always did."""
+
+    recurrent = False  # no per-slot state beside the pages
+    logits_last_only = False  # prefill returns every position's logits
+    fixed_chunks = False  # the last chunk of a prompt is as long as it is
+    pairs_per_token = 0  # no expert blocks: its programs keep no record
+
+    def __init__(self, cfg: LlamaConfig):
+        self.cfg = cfg
+
+    def init_weights(self, key):
+        return _init_weights(key, cfg=self.cfg)
+
+    def logical_axes(self):
+        from ray_tpu.models.llama import param_logical_axes
+
+        return param_logical_axes(self.cfg)
+
+    def held_weights(self, params):
+        """`params` as the programs multiply by them; the caller's own
+        arrays where nothing is to be cast."""
+        held = _cast_weights.eval_shape(params, cfg=self.cfg)
+        if [x.dtype for x in jax.tree.leaves(held)] == [
+            x.dtype for x in jax.tree.leaves(params)
+        ]:
+            return params
+        return _cast_weights(params, cfg=self.cfg)
+
+    def init_cache(self, num_pages: int, page_size: int, max_batch: int,
+                   shardings=None):
+        make = partial(init_paged_kv, self.cfg, num_pages, page_size)
+        if shardings is None:
+            return make()
+        return jax.jit(make, out_shardings=shardings)()
+
+    def prefill(self, params, tokens, pool, pages, *, n_write_pages,
+                slot=None, length=None):
+        return paged_prefill(
+            params, tokens, pool, pages, cfg=self.cfg,
+            n_write_pages=n_write_pages,
+        )
+
+    def prefill_chunk(self, params, tokens, pool, pages, start, *,
+                      n_write_pages, chunk_pages, slot=None, length=None):
+        return paged_prefill_chunk(
+            params, tokens, pool, pages, start, cfg=self.cfg,
+            n_write_pages=n_write_pages, chunk_pages=chunk_pages,
+        )
+
+    def decode(self, params, tokens, pool, block_tables, positions,
+               temperature, rng_key, *, use_kernel, stochastic, active=None):
+        return paged_verify(
+            params, tokens, pool, block_tables, positions, temperature,
+            rng_key, cfg=self.cfg, use_kernel=use_kernel,
+            stochastic=stochastic,
+        )
+
+
+_cast_weights = jax.jit(matmul_weights, static_argnames="cfg")
+
+
+@partial(jax.jit, static_argnames="cfg")
+def _init_weights(key, cfg):
+    """An engine's own weights, made as it holds them: `init_params`'
+    values rounded once, without an fp32 copy of the tree on the device
+    beside them."""
+    from ray_tpu.models.llama import init_params
+
+    return matmul_weights(init_params(key, cfg), cfg)
 
 
 def propose_ngram_draft(

@@ -49,6 +49,26 @@ class MoEConfig(LlamaConfig):
     # load balance (Switch section 2.2) and the z-loss on its logits.
     aux_loss_weight: float = 0.01
     z_loss_weight: float = 0.001
+    # The rest of what `moe_ffn` reads of a config, at OLMoE's values;
+    # `models/nemotron_h.py`'s config carries the same names at its own.
+    # "softmax": gates are the k largest probabilities over all experts.
+    # "sigmoid": each expert's score is its own sigmoid, the k largest of
+    # score + p["router_bias"] are chosen and the scores are the gates.
+    router_kind: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    # "swiglu": silu(h W_gate) * (h W_up) W_down. "relu2": relu(h W_up)^2
+    # W_down, no gate matrix.
+    expert_kind: str = "swiglu"
+    # (first, count) of the experts whose weights are held here, where
+    # that is a share of `num_experts` (expert parallelism: the router
+    # stays `num_experts` wide); None where all are.
+    experts_held: tuple | None = None
+    # Up to this many rows (tokens of a call), every held expert is
+    # applied to every row (`_experts_on_every_row`); above it the pairs
+    # are sorted and each expert applied to its own rows
+    # (`_experts_on_sorted_pairs`). 0: always sorted, which is right for
+    # a train step (OLMoE's has 8,192 rows, 1,024 pairs an expert).
+    dense_expert_rows: int = 0
 
     def _matmul_params(self, experts: int) -> int:
         """Parameters of the matrices a token meets when each layer
@@ -144,13 +164,105 @@ def _take_rows_bwd(fan, inverse, g):
 _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 
 
-def moe_ffn(x: jnp.ndarray, p: Params, cfg: MoEConfig):
+def _expert_act(cfg, rows, w_gate, w_up, matmul):
+    """The width-``d_ff`` activations of an expert of ``cfg.expert_kind``;
+    ``matmul(rows, w)`` is the grouped or the plain product."""
+    if cfg.expert_kind == "relu2":
+        return jnp.square(jax.nn.relu(matmul(rows, w_up)))
+    return jax.nn.silu(matmul(rows, w_gate)) * matmul(rows, w_up)
+
+
+def _experts_on_every_row(tokens, p, cfg, routes, gates, here):
+    """Every held expert on every row, as one batched matmul over the
+    experts, and each row's sum over ITS experts by a [n, held] matrix
+    of gates that is zero elsewhere. For few rows: every expert's
+    weights are read once whatever the routes, and nothing is sorted."""
+    first, e_here = cfg.experts_held or (0, cfg.num_experts)
+    dt = cfg.dtype
+    with jax.named_scope("moe:dispatch"):
+        chosen = routes - first  # [n, k]; outside [0, held) where absent
+        if here is not None:
+            chosen = jnp.where(here, chosen, e_here)
+        onehot = chosen[:, :, None] == jnp.arange(e_here)  # [n, k, held]
+        weight = (onehot * gates[:, :, None]).sum(1)  # [n, held] float32
+        load = onehot.sum((0, 1)).astype(jnp.int32)
+    with jax.named_scope("moe:experts"):
+        batched = lambda a, w: jnp.einsum(  # noqa: E731
+            "...nd,edf->enf", a, w.astype(dt)
+        )
+        per_expert = lambda a, w: jnp.einsum(  # noqa: E731
+            "enf,efd->end", a, w.astype(dt)
+        )
+        act = _expert_act(cfg, tokens.astype(dt), p.get("w_gate"), p["w_up"],
+                          batched)
+        outs = per_expert(act, p["w_down"])  # [held, n, d]
+    with jax.named_scope("moe:combine"):
+        out = jnp.einsum(
+            "end,ne->nd", outs.astype(jnp.float32), weight
+        ).astype(dt)
+    return out, load
+
+
+def _experts_on_sorted_pairs(tokens, p, cfg, routes, gates, here):
+    """Pairs sorted by expert, the rows gathered in that order, the
+    experts applied as grouped matmuls whose groups are each expert's
+    rows, the results gathered back and summed per token."""
+    n, k = routes.shape
+    d = tokens.shape[-1]
+    first, e_here = cfg.experts_held or (0, cfg.num_experts)
+    dt = cfg.dtype
+    with jax.named_scope("moe:dispatch"):
+        # Pairs in expert order; a stable sort keeps each expert's rows
+        # in token order.
+        pair_expert = routes.reshape(n * k)
+        if here is not None:
+            # Held experts are numbered from 0; a pair that is not
+            # computed here gets the number after the last, sorts behind
+            # every group and is in no group's size.
+            pair_expert = jnp.where(
+                here.reshape(n * k), pair_expert - first, e_here
+            )
+        order = jnp.argsort(pair_expert, stable=True)
+        inverse = jnp.argsort(order)
+        # (bincount drops the number after the last.)
+        load = jnp.bincount(pair_expert, length=e_here).astype(jnp.int32)
+        rows = _take_rows(tokens, order, inverse, k)  # [n * k, d]
+
+    with jax.named_scope("moe:experts"):
+        grouped = lambda a, w: jax.lax.ragged_dot(a, w.astype(dt), load)  # noqa: E731
+        rows_out = grouped(
+            _expert_act(cfg, rows, p.get("w_gate"), p["w_up"], grouped),
+            p["w_down"],
+        )
+
+    with jax.named_scope("moe:combine"):
+        # Back to token order: pair j of token t sits at row t * k + j.
+        pairs = _take_rows(rows_out, inverse, order, 1).reshape(n, k, d)
+        weighted = pairs.astype(jnp.float32) * gates[..., None]
+        if here is not None:
+            # Rows behind the last group are no expert's output.
+            weighted = jnp.where(here[..., None], weighted, 0.0)
+        out = weighted.sum(1).astype(dt)
+    return out, load
+
+
+def moe_ffn(x: jnp.ndarray, p: Params, cfg, rows_live=None):
     """FFN hook for llama._block: x [B, S, d] -> (out, aux).
 
+    ``cfg`` is a ``MoEConfig`` or any config with its expert-layer
+    fields. Routing is over all ``cfg.num_experts``. Where
+    ``cfg.experts_held`` names a share, only pairs whose expert is held
+    are computed: the others are left out of the sort's groups and add
+    nothing, so the result is this share's part of the layer (plus the
+    shared expert, where ``p`` has one). ``rows_live`` (bool [B * S])
+    leaves out the pairs of rows that carry no token (a padded tail, a
+    free decode slot) in the same way.
+
     ``aux`` is the layer's router record: ``balance_loss`` and
-    ``z_loss`` (unweighted), ``expert_load`` (pairs each expert
-    computed, int32[e]: their sum is tokens x top_k, nothing is
-    dropped) and ``routes`` (the experts of each token, int32[T, k])."""
+    ``z_loss`` (unweighted; softmax routers), ``expert_load`` (pairs each
+    held expert computed, int32[held]: with every expert held and every
+    row live their sum is tokens x top_k, nothing is dropped) and
+    ``routes`` (the experts of each token, int32[T, k])."""
     b, s, d = x.shape
     e, k = cfg.num_experts, cfg.top_k
     n = b * s
@@ -158,47 +270,57 @@ def moe_ffn(x: jnp.ndarray, p: Params, cfg: MoEConfig):
     tokens = x.reshape(n, d)
 
     with jax.named_scope("moe:route"):
-        # Matmul, softmax and top-k in float32: the 8th and 9th
-        # probabilities of a token are often closer than bf16 rounding.
+        # Matmul, scores and top-k in float32: the k-th and (k+1)-th
+        # scores of a token are often closer than bf16 rounding.
         logits = jnp.dot(
             tokens.astype(jnp.float32), p["router"].astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST,
         )
-        probs = jax.nn.softmax(logits, axis=-1)  # [n, e]
-        gates, routes = jax.lax.top_k(probs, k)  # [n, k]
+        if cfg.router_kind == "softmax":
+            probs = jax.nn.softmax(logits, axis=-1)  # [n, e]
+            gates, routes = jax.lax.top_k(probs, k)  # [n, k]
+        else:
+            probs = jax.nn.sigmoid(logits)
+            _, routes = jax.lax.top_k(probs + p["router_bias"], k)
+            gates = jnp.take_along_axis(probs, routes, axis=-1)
         if cfg.norm_topk_prob:
             gates = gates / gates.sum(-1, keepdims=True)
+        if cfg.routed_scaling_factor != 1.0:
+            gates = gates * cfg.routed_scaling_factor
 
-    with jax.named_scope("moe:dispatch"):
-        # Pairs in expert order; a stable sort keeps each expert's rows
-        # in token order.
-        pair_expert = routes.reshape(n * k)
-        order = jnp.argsort(pair_expert, stable=True)
-        inverse = jnp.argsort(order)
-        load = jnp.bincount(pair_expert, length=e).astype(jnp.int32)
-        rows = _take_rows(tokens, order, inverse, k)  # [n * k, d]
+    # Which experts are computed here, and for which rows.
+    first, e_here = cfg.experts_held or (0, e)
+    here = None
+    if cfg.experts_held is not None or rows_live is not None:
+        local = routes - first
+        here = (local >= 0) & (local < e_here)  # [n, k]
+        if rows_live is not None:
+            here &= rows_live[:, None]
 
-    with jax.named_scope("moe:experts"):
-        gate = jax.lax.ragged_dot(rows, p["w_gate"].astype(dt), load)
-        up = jax.lax.ragged_dot(rows, p["w_up"].astype(dt), load)
-        rows_out = jax.lax.ragged_dot(
-            jax.nn.silu(gate) * up, p["w_down"].astype(dt), load
-        )
+    if n <= cfg.dense_expert_rows:
+        out, load = _experts_on_every_row(tokens, p, cfg, routes, gates, here)
+    else:
+        out, load = _experts_on_sorted_pairs(tokens, p, cfg, routes, gates, here)
 
-    with jax.named_scope("moe:combine"):
-        # Back to token order: pair j of token t sits at row t * k + j.
-        pairs = _take_rows(rows_out, inverse, order, 1).reshape(n, k, d)
-        out = (pairs.astype(jnp.float32) * gates[..., None]).sum(1)
-        out = out.astype(dt)
+    if "shared_up" in p:
+        with jax.named_scope("moe:shared"):
+            plain = lambda a, w: a @ w.astype(dt)  # noqa: E731
+            out = out + plain(
+                _expert_act(cfg, tokens.astype(dt), p.get("shared_gate"),
+                            p["shared_up"], plain),
+                p["shared_down"],
+            )
 
-    with jax.named_scope("moe:route"):
-        # Load balance: e * sum_e (share of pairs routed to e) * (mean
-        # probability of e) (Switch section 2.2); z-loss: the mean
-        # squared logsumexp of the router's logits.
-        balance = e * (probs.mean(0) * (load / n)).sum()
-        z = jnp.square(jax.nn.logsumexp(logits, axis=-1)).mean()
-    aux = {"balance_loss": balance, "z_loss": z, "expert_load": load,
-           "routes": routes}
+    aux = {"expert_load": load, "routes": routes}
+    if cfg.router_kind == "softmax":
+        with jax.named_scope("moe:route"):
+            # Load balance: e * sum_e (share of pairs routed to e) *
+            # (mean probability of e) (Switch section 2.2); z-loss: the
+            # mean squared logsumexp of the router's logits.
+            aux["balance_loss"] = e * (probs.mean(0) * (load / n)).sum()
+            aux["z_loss"] = jnp.square(
+                jax.nn.logsumexp(logits, axis=-1)
+            ).mean()
     return out.reshape(b, s, d), aux
 
 

@@ -233,6 +233,12 @@ TINY = {
         "requests": [(5, False), (20, True), (40, False)],
         "check_prompt": 12, "check_decode": 2, "check_pad": 32,
     },
+    "hybrid": {
+        "cfg": {}, "reduced": {},
+        "engine": {"max_batch": 2, "max_seq": 128, "page_size": 16,
+                   "prefill_chunk": 32},
+        "check_prompt": 40, "check_decode": 2,
+    },
 }
 
 
@@ -247,7 +253,8 @@ def test_chip_smoke_phases_at_tiny_size_on_cpu(fake_chips, monkeypatch, chips):
     ray_tpu.init(num_cpus=4)
     records = chip_smoke.run_phases(chips, seed=0, sizes=TINY)
     assert [r["phase"] for r in records] == (
-        ["fsdp"] if chips == 4 else ["train", "serve", "engine_check"]
+        ["fsdp"] if chips == 4
+        else ["train", "serve", "engine_check", "hybrid_check"]
     )
     for run in records[0]["runs"]:
         assert run["losses"][-1] < run["losses"][0]
@@ -259,5 +266,7 @@ def test_chip_smoke_phases_at_tiny_size_on_cpu(fake_chips, monkeypatch, chips):
     else:
         assert [r["tokens"] for r in records[1]["requests"]] == [4, 4, 4]
         assert max(records[2]["logit_max_abs_err"]) < 1e-4  # fp32 on CPU
+        assert max(records[3]["logit_max_abs_err"]) < 2e-4
+        assert records[3]["prefill_calls"] == 2  # 40 tokens, chunks of 32
     with pytest.raises(chip_smoke.SmokeFailure, match="platform 'cpu'"):
         chip_smoke.verify(records, chips)

@@ -347,3 +347,52 @@ def test_moe_train_step_on_mesh():
     assert int(metrics["moe_pairs"]) == 4 * 32 * CFG.top_k * CFG.n_layers
     state, metrics2 = step(state, {"tokens": tokens})
     assert float(metrics2["loss"]) < float(metrics["loss"]) + 1.0
+
+
+# What `moe_ffn` gave for OLMoE's kinds (softmax router, SwiGLU experts,
+# no shared expert, every expert held) before it learnt Nemotron-H's
+# (PR 31): routes, loads, the two router losses and the output's first
+# three columns, computed by the parent commit's file on the same seeds.
+_BEFORE = {
+    "routes": [[2, 0], [3, 1], [0, 2], [1, 2], [0, 2], [0, 2], [0, 2], [1, 0]],
+    "load": [6, 3, 6, 1],
+    "losses": (2.3233108520507812, 3.6123547554016113),
+    False: ([[-0.1048563, -0.1609883, 0.0263345], [-0.1432213, 0.127712, -0.3534738],
+             [-0.2707842, -0.1416937, -0.4770932], [-0.0711449, -0.0734339, -0.1273737],
+             [0.1117236, -0.2846368, 0.2849216], [-0.5840258, 0.0743914, -0.607134],
+             [0.01759, 0.6218014, -0.3806386], [-0.3415798, 0.3757877, -0.7133414]],
+            111.80332946777344),
+    True: ([[-0.1610655, -0.2472876, 0.0404513], [-0.1969674, 0.175638, -0.4861206],
+            [-0.3252349, -0.1701862, -0.5730295], [-0.1244904, -0.1284956, -0.2228804],
+            [0.1543252, -0.3931722, 0.3935656], [-0.6976726, 0.0888674, -0.7252774],
+            [0.0191932, 0.6784747, -0.4153315], [-0.4045245, 0.4450361, -0.8447925]],
+           141.677734375),
+}
+
+
+@pytest.mark.parametrize("norm_topk_prob", [False, True])
+@pytest.mark.parametrize("path", ["sorted_pairs", "every_row"])
+def test_olmoe_path_through_generalised_moe_ffn_gives_what_it_gave(
+    norm_topk_prob, path
+):
+    """The shared edit pinned from OLMoE's side: same routes and loads,
+    outputs within this file's tolerance for float32 against float32
+    (3e-5), by the sorted grouped matmul (OLMoE's train step) and by
+    the few-rows form alike. tests/test_nemotron_h.py pins the other
+    model's side."""
+    cfg = dataclasses.replace(
+        CFG, norm_topk_prob=norm_topk_prob,
+        dense_expert_rows=0 if path == "sorted_pairs" else 10**6,
+    )
+    params = init_moe_params(jax.random.key(0), cfg)
+    layer = {k: v[0] for k, v in params["blocks"].items()}
+    x = jax.random.normal(jax.random.key(11), (1, 8, cfg.d_model))
+    out, aux = moe_ffn(x, layer, cfg)
+    assert np.asarray(aux["routes"]).tolist() == _BEFORE["routes"]
+    assert np.asarray(aux["expert_load"]).tolist() == _BEFORE["load"]
+    np.testing.assert_allclose(
+        (aux["balance_loss"], aux["z_loss"]), _BEFORE["losses"], rtol=1e-6
+    )
+    head, total = _BEFORE[norm_topk_prob]
+    np.testing.assert_allclose(out[0, :, :3], head, atol=3e-5, rtol=1e-5)
+    np.testing.assert_allclose(jnp.abs(out).sum(), total, rtol=1e-5)

@@ -305,3 +305,25 @@ def test_abort_releases_pages(params):
     assert engine.alloc.free_pages < engine.alloc.num_pages
     assert engine.abort_request(rid)
     assert engine.alloc.free_pages == engine.alloc.num_pages
+
+
+def test_on_logits_hands_over_every_programs_logits():
+    """The public tap (`LLMEngine.on_logits`): each prefill's and each
+    decode step's logits as the program returned them, no record for a
+    model without expert blocks; `slot_of` names the request's row; the
+    cache's bytes are all pages."""
+    eng = LLMEngine("tiny", max_batch=2, max_seq=64, page_size=16)
+    seen = []
+    eng.on_logits = lambda phase, logits, record: seen.append(
+        (phase, logits.shape, record)
+    )
+    rid = eng.add_request([5, 6, 7, 8, 9], SamplingParams(max_tokens=3))
+    eng.step()
+    assert eng.slot_of(rid) == 0 and eng.slot_of("nobody") is None
+    while eng.has_unfinished():
+        eng.step()
+    v = eng.cfg.vocab_size
+    assert seen == [("prefill", (1, 16, v), None)] + [("decode", (2, v), None)] * 2
+    stats = eng.stats()
+    assert stats["state_bytes"] == 0 and stats["moe_pairs_routed"] == 0
+    assert stats["pool_bytes"] == eng.cache["k"].nbytes * 2

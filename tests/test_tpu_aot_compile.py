@@ -10,7 +10,9 @@ them: 32 query / 8 KV heads of 128.
 The serving programs (llm/paged_kv.py) are compiled whole, at Mistral-7B
 widths with a two-layer page pool of the benchmark's size, for what only
 the compiled text shows: that nothing copies, slices out or writes back
-a layer's pages or more.
+a layer's pages or more. So are the hybrid model's (llm/hybrid_kv.py),
+at Nemotron-3-Nano's widths with 64 experts held, for the same of its
+pages, of a layer's per-slot state and of an expert stack.
 """
 
 import math
@@ -236,3 +238,102 @@ def test_serving_program_moves_no_layer_of_pages(v5e, case, monkeypatch):
     assert _pool_moves(text) == []
     if case.startswith("verify"):
         assert "tpu_custom_call" in text
+
+
+# ------------------------------------------------------ the hybrid programs
+def _hybrid_moves(text: str, shapes: dict[str, tuple]) -> list[str]:
+    """Top-level instructions of a compiled program that copy, transpose,
+    slice out or write back an array as large as one of ``shapes`` (name
+    -> the trailing dimensions and the least number of elements that
+    count): a layer's pages, a layer's state, an expert stack. An
+    in-place scatter, a fused in-place update of the carried state and a
+    kernel that reads its operand where it lies are not among them."""
+    entry = text[text.index("ENTRY "):]
+    moves = re.compile(
+        r"=\s+(\(?[a-z0-9]+\[[^=]*?)\s+"
+        r"(copy|copy-start|transpose|slice|dynamic-slice|"
+        r"dynamic-update-slice|concatenate|pad)\("
+    )
+    found = []
+    for line in entry.splitlines():
+        m = moves.search(line)
+        if not m:
+            continue
+        for dims in _SHAPE.findall(m.group(1)):
+            shape = tuple(int(d) for d in dims.split(","))
+            for name, (tail, least) in shapes.items():
+                if shape[-len(tail):] == tail and math.prod(shape) >= least:
+                    found.append(f"{name}: {m.group(2)} {m.group(1)}")
+    return found
+
+
+@pytest.fixture(scope="module")
+def hybrid_programs(v5e):
+    """nemotron3nano-serve1's own sizes (benchmarks/configs) at 6 of its
+    16 blocks, every kind among them: what `aot_fit_serve_model` lowers
+    for the whole configuration."""
+    import json
+
+    from benchmarks import aot_fit_serve_model
+    from ray_tpu._private import chip
+
+    here = os.path.join(os.path.dirname(__file__), "..", "benchmarks")
+    with open(os.path.join(here, "configs", "nemotron3nano-serve1.json")) as f:
+        conf = json.load(f)
+    conf["hybrid_override_pattern"] = "ME*EM*"
+    conf["num_hidden_layers"] = 6
+    traffic = {"fit_prefill_buckets": [512, 1024]}
+    # A chunk of 1,024 rows, over `dense_expert_rows`: the one program
+    # here whose experts run as grouped matmuls over sorted pairs.
+    longer = {**conf, "engine": {**conf["engine"], "prefill_chunk": 1024}}
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(chip, "platform", lambda: "tpu")
+        device = next(iter(v5e.device_set))
+        lowered = aot_fit_serve_model.lowered_programs(conf, traffic, device)
+        lowered["prefill_chunk_1024_of_2048"] = (
+            aot_fit_serve_model.lowered_programs(
+                longer, {"fit_prefill_buckets": [2048]}, device
+            )["prefill_chunk_1024_of_2048"]
+        )
+        return conf, {name: low.compile() for name, low in lowered.items()}
+
+
+@pytest.mark.parametrize(
+    "program",
+    ["prefill_512", "prefill_chunk_512_of_1024", "prefill_chunk_1024_of_2048",
+     "decode"],
+)
+def test_hybrid_program_moves_no_pages_state_or_expert_stack(
+    hybrid_programs, program
+):
+    """The hybrid cache is one donated tree updated in place, and the
+    expert stacks are read where they lie. (Held 1856 wide, each stack
+    was copied, 0.64 GB, in front of every grouped matmul: the TPU's
+    layout for that shape is not the kernel's. models/nemotron_h.py
+    holds them 1920 wide.)"""
+    conf, programs = hybrid_programs
+    eng = conf["engine"]
+    pages = (eng["num_pages"] + 1) * conf["num_key_value_heads"] * PAGE * DH
+    state = eng["max_batch"] * 64 * 64 * 128
+    d, f = conf["hidden_size"], 1920
+    shapes = {
+        "pages": ((conf["num_key_value_heads"], PAGE, DH), pages),
+        "state": ((64, 64, 128), state),
+        "w_up": ((d, f), 64 * d * f),
+        "w_down": ((f, d), 64 * d * f),
+        "w_up at its own width": ((d, 1856), 64 * d * 1856),
+    }
+    compiled = programs[program]
+    text = compiled.as_text()
+    assert _hybrid_moves(text, shapes) == []
+    # The grouped matmul above `dense_expert_rows`; the paged-attention
+    # and cell-write kernels in the decode program.
+    assert ("ragged-dot" in text) == (program == "prefill_chunk_1024_of_2048")
+    if program == "decode":
+        assert "tpu_custom_call" in text
+    # Nothing the size of an expert stack is made beside the arguments
+    # (the decode program's temporaries are a few MB).
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 64 * d * f * 2
+    if program == "decode":
+        assert temp < state * 4

@@ -22,6 +22,18 @@ queued requests into free slots while their pages fit the pool, runs at
 most one prefill chunk, and then advances all `max_batch` slots with
 one decode program.
 
+One decode step is in flight while the host works. step() dispatches
+step t+1 with step t's sampled ids as they lie on the device, and only
+then reads step t back, emits it and returns: the device runs t+1 under
+the host's read-back, the pump's delivery and the preparation of t+2.
+A slot's position, its pages and whether its next token is its last by
+`max_tokens` or `max_seq` are known without the token's value; only a
+stop token is learnt a step late, and that slot's one extra slot-step
+is computed and thrown away. Where the next step needs token VALUES on
+the host (drafts, a slot that samples on the host, a preemption, the
+first token after a prefill) the step in flight is read back first:
+`stats()["pipeline_drains"]` counts those by cause.
+
 Weights: the engine holds `self.params` as its programs multiply, the
 matmul weights and the embedding in `cfg.dtype` (`paged_kv.matmul_weights`,
 once, in `__init__`), the norm scales as given; `stats()["param_bytes"]`
@@ -86,6 +98,39 @@ class _Request:
     prefill_start_ts: float = 0.0
     first_token_ts: float = 0.0
     finish_ts: float = 0.0
+
+
+@dataclass
+class _DecodeStep:
+    """A decode step that was dispatched and not yet read back: what its
+    program returned, on the device, and what the host needs to emit
+    it."""
+
+    slots: dict  # slot -> request, as of the dispatch
+    sampled: Any  # [B, K] int32: the next step's `tokens` at K = 1
+    logits: Any  # [B, V] fp32, position 0
+    accept: Any  # [B, K-1] bool
+    rej: Any  # [B, K-1] int32
+    drafts: np.ndarray  # [B, K-1]: the host's proposals
+    draft_len: np.ndarray  # [B]
+
+
+# Links of the chain of keys made by one program.
+_KEY_BLOCK = 64
+
+
+@jax.jit
+def _key_block(key):
+    """The next `_KEY_BLOCK` links of ``key, sub = split(key)``: the
+    chain's new head and every `sub`, each an array of its own, so that
+    no program and no unpacking stands between two decode programs."""
+
+    def link(key, _):
+        key, sub = jax.random.split(key)
+        return key, sub
+
+    key, subs = jax.lax.scan(link, key, length=_KEY_BLOCK)
+    return key, [subs[i] for i in range(_KEY_BLOCK)]
 
 
 def _bucket(n: int, lo: int = 16) -> int:
@@ -225,18 +270,28 @@ class LLMEngine:
         # expert blocks, where its programs keep a record of them.
         self._pairs_per_token = serving.pairs_per_token
         self._moe_counts: list = []  # (phase, device int32[2]), unfolded
+        # The chain of keys, ``key, sub = split(key)`` a decode step:
+        # its head, and the links made ahead (`_key_block`).
         self._step_key = jax.random.key(seed)
+        self._keys: list = []
+        # The decode step dispatched and not read back, if any; and why
+        # this step() read one back before its own dispatch, if it did.
+        self._in_flight: _DecodeStep | None = None
+        self._drained_for: str | None = None
         self._temps = np.zeros((max_batch,), np.float32)
         self._queue: list[_Request] = []
         self._active: dict[int, _Request] = {}  # slot → request
         self._free = list(range(max_batch))
         self._ids = itertools.count()
         self._rng = np.random.default_rng(seed)
-        # Host mirrors of the decode inputs, one entry per slot.
+        # Host mirrors of the decode inputs, one entry per slot: the
+        # newest token the host has read back, and the position the
+        # slot's next dispatch writes at (it advances at dispatch).
         self._tokens = np.zeros((max_batch, 1), np.int32)
         self._positions = np.zeros((max_batch,), np.int32)
         # add_request may run on a different thread than step() (the serve
         # pump runs step in an executor); guard the queue/slot state.
+        # step() lets go of it while it waits for a decode program.
         self._lock = threading.Lock()
         # Per-request tokens emitted since the last drain_deltas() call —
         # the feed for streaming responses (reference shape: vLLM's
@@ -266,6 +321,15 @@ class LLMEngine:
             # not attended) and not width.
             "steps": 0,
             "decode_steps": 0,
+            # Decode steps dispatched before the step before them was
+            # read back; reads of the step in flight that had to come
+            # before the next dispatch, by cause; slot-steps computed
+            # for a request that a stop token had already ended.
+            "decode_steps_in_flight": 0,
+            "pipeline_drains": {
+                "admit": 0, "host_sampled": 0, "speculate": 0, "preempt": 0,
+            },
+            "overrun_slot_steps": 0,
             "slot_steps": 0,
             "attn_pages_live": 0,
             "attn_pages_table": 0,
@@ -298,8 +362,9 @@ class LLMEngine:
     @contextmanager
     def _locked(self, span, request_id: str):
         """`_lock` for a caller on the replica's event loop. step() holds
-        it for a whole step, the wait for the device included, so what
-        the caller waited goes on its span and into `lock_wait_s_sum`."""
+        it for its host work (and for a prefill's wait), not while it
+        waits for a decode program; what the caller waited goes on its
+        span and into `lock_wait_s_sum`."""
         began = time.perf_counter()
         with self._lock:
             waited = time.perf_counter() - began
@@ -368,7 +433,10 @@ class LLMEngine:
 
     def has_unfinished(self) -> bool:
         return bool(
-            self._queue or self._active or self._prefilling is not None
+            self._queue
+            or self._active
+            or self._prefilling is not None
+            or self._in_flight is not None
         )
 
     def _sample(self, logits: np.ndarray, s: SamplingParams) -> int:
@@ -472,6 +540,12 @@ class LLMEngine:
         with TraceAnnotation("engine:first_token", rid=req.request_id):
             if self.serving.logits_last_only:
                 logit_idx = 0
+            # The first token is sampled on the host: the decode step in
+            # flight, which the device ran before this prefill, is read
+            # back first (its tokens are due), and the next step is
+            # dispatched from the host's tokens. Under `_lock`: this
+            # request is in no list an abort would find it in.
+            self._drain(finished, "admit", unlock=False)
             # The host waits here for the prefill program.
             last = np.asarray(
                 logits[0, ctx_len - 1 if logit_idx is None else logit_idx]
@@ -493,7 +567,6 @@ class LLMEngine:
             # loop itself.
             if not self._finish_if_done(req, finished):
                 self._tokens[slot, 0] = req.last_token
-                self._positions[slot] = req.position
                 self._temps[slot] = req.sampling.temperature
 
     def _admit_one(self, finished: list[dict]) -> bool:
@@ -658,7 +731,8 @@ class LLMEngine:
                 self._stats["experts_touched"] += touched
 
     def step(self) -> list[dict]:
-        """Admit + one decode step. Returns finished request dicts."""
+        """Admit + one decode step dispatched + the step before it read
+        back. Returns the request dicts that read-back finished."""
         finished: list[dict] = []
         # The span begins before the wait for `_lock`; its attributes are
         # the engine's state as the step found it.
@@ -671,12 +745,13 @@ class LLMEngine:
             max_batch=self.max_batch,
         ), self._lock:
             self._stats["steps"] += 1
+            self._drained_for = None
             if self._prefilling is not None:
                 # Continue the in-flight chunked prefill: one chunk per
                 # step bounds the stall it adds to this step's decodes.
                 self._prefill_step(finished)
             self._admit(finished)
-            if self._active:
+            if self._active or self._in_flight is not None:
                 self._step_paged(finished)
         return finished
 
@@ -688,7 +763,6 @@ class LLMEngine:
             self._deltas.setdefault(req.request_id, []).append(tok)
         req.last_token = tok
         self._tokens[req.slot, 0] = tok
-        self._positions[req.slot] = req.position
         self._finish_if_done(req, finished)
 
     def _preempt(self, req: _Request) -> None:
@@ -711,9 +785,40 @@ class LLMEngine:
         it; don't ship the logits for it.)"""
         return bool(s.top_k) and s.temperature > 0
 
+    def _needs_values(self) -> str | None:
+        """Why the next decode step cannot be dispatched before the one
+        in flight is read back, where it cannot: its inputs are then
+        token values that the host has to hold."""
+        if self.speculate:
+            return "speculate"  # drafts come from `req.out_tokens`
+        if any(self._host_sampled(r.sampling) for r in self._active.values()):
+            return "host_sampled"  # the token is chosen on the host
+        return None
+
+    def _last_in_flight(self, req: _Request) -> bool:
+        """Whether the token `req` has in flight is its last by
+        `max_tokens` or `max_seq` (`_finish_if_done`'s own tests, one
+        token on): known before the token's value is."""
+        return (
+            len(req.out_tokens) + 1 >= req.sampling.max_tokens
+            or req.position + 1 >= self.max_seq - 1
+        )
+
+    def _next_key(self):
+        """The decode step's link of the chain of keys. A block of links
+        is one program, dispatched behind what the device is running."""
+        if not self._keys:
+            self._step_key, self._keys = _key_block(self._step_key)
+            self._keys.reverse()
+        return self._keys.pop()
+
     def _step_paged(self, finished: list[dict]) -> None:
-        """One decode step for every active slot: K = 1 + speculate
-        positions a slot in one dispatch of the one decode program.
+        """One decode step for every slot that decodes: K = 1 +
+        speculate positions a slot in one dispatch of the one decode
+        program, and then the read-back of the step dispatched before
+        it. Whether that order holds (lag 1) or the step in flight is
+        read back first (lag 0) is chosen here, from what this step's
+        inputs are.
 
         With speculation (reference capability: vLLM speculative
         decoding behind ray.llm) columns 1.. of the token matrix are
@@ -727,55 +832,80 @@ class LLMEngine:
         token."""
         P = self.page_size
         K = 1 + self.speculate
+        cause = self._needs_values()
+        if cause:
+            self._drain(finished, cause)
 
-        def attended(req: _Request) -> int:
-            """Pages this step writes into or attends: up to position +
-            K - 1, clamped to the table width. Near max_seq a K-wide
-            step may reach past capacity — the kernel routes those
-            writes to the dump page and _finish_if_done stops the
+        def attended(position: int) -> int:
+            """Pages a step at `position` writes into or attends: up to
+            position + K - 1, clamped to the table width. Near max_seq a
+            K-wide step may reach past capacity — the kernel routes
+            those writes to the dump page and _finish_if_done stops the
             request at max_seq before any overflow token is kept."""
-            return min(
-                (req.position + K - 1) // P + 1, self.max_pages_per_seq
-            )
+            return min((position + K - 1) // P + 1, self.max_pages_per_seq)
 
-        with TraceAnnotation("engine:grow_tables") as span:
-            preempted = self._stats["preemptions"]
-            # Grow block tables to cover every position this step may
-            # write ([position, position + K - 1] with speculation);
-            # exhausted pool → preempt the youngest active request until
-            # pages fit.
-            for slot, req in list(self._active.items()):
-                if req.slot == -1 or req.done:
-                    continue
-                needed = attended(req)
+        def grow(decoding: dict, ahead: int) -> bool:
+            """Grow block tables to cover every position this step may
+            write ([position, position + K - 1] with speculation);
+            exhausted pool → preempt the youngest active request until
+            pages fit. False, with nobody preempted, where that is due
+            and a step is in flight: a victim's context has to hold its
+            token in flight before it is requeued."""
+            for req in list(decoding.values()):
+                needed = attended(req.position + ahead)
                 while len(req.pages) < needed and req.slot != -1:
-                    if self.alloc.free_pages == 0:
+                    if self.alloc.free_pages:
+                        req.pages.append(self.alloc.alloc())
+                    elif ahead:
+                        return False
+                    else:
                         victims = [
                             r for r in self._active.values() if r is not req
                         ]
-                        if not victims:
-                            self._preempt(req)
-                            break
-                        self._preempt(victims[-1])
-                    else:
-                        req.pages.append(self.alloc.alloc())
+                        self._preempt(victims[-1] if victims else req)
+            return True
+
+        with TraceAnnotation("engine:grow_tables") as span:
+            preempted = self._stats["preemptions"]
+            while True:
+                # A slot with a token in flight (K = 1 then, and every
+                # active slot has one: an admission reads the step back)
+                # is one position past what the host has read, and is
+                # not in this dispatch where that token is its last.
+                ahead = int(self._in_flight is not None)
+                decoding = {
+                    slot: req for slot, req in self._active.items()
+                    if not (ahead and self._last_in_flight(req))
+                }
+                if grow(decoding, ahead):
+                    break
+                self._drain(finished, "preempt")
             span.set_metadata(
                 preempted=self._stats["preemptions"] - preempted
             )
-            if not self._active:
+            decoding = {
+                slot: req for slot, req in decoding.items() if req.slot == slot
+            }
+            if not decoding:
+                self._drain(finished)
                 return
 
             tables = np.full(
                 (self.max_batch, self.max_pages_per_seq), -1, np.int32
             )
-            for slot, req in self._active.items():
+            # An active slot that sits this step out is as a free one.
+            self._positions[list(self._active)] = 0
+            for slot, req in decoding.items():
                 tables[slot, : len(req.pages)] = req.pages
-            self._step_key, sub = jax.random.split(self._step_key)
+                self._positions[slot] = req.position + ahead
             toks, draft_len = self._propose_drafts()
         self._stats["decode_steps"] += 1
-        self._stats["slot_steps"] += len(self._active)
+        self._stats["decode_steps_in_flight"] += ahead
+        if self._drained_for:
+            self._stats["pipeline_drains"][self._drained_for] += 1
+        self._stats["slot_steps"] += len(decoding)
         self._stats["attn_pages_live"] += sum(
-            attended(req) for req in self._active.values()
+            attended(req.position + ahead) for req in decoding.values()
         )
         self._stats["attn_pages_table"] += tables.size
         # Static flag: an all-greedy batch (the common speculative
@@ -785,52 +915,101 @@ class LLMEngine:
         # temperatures.
         stochastic = K > 1 and any(
             r.sampling.temperature > 0 and not r.sampling.top_k
-            for r in self._active.values()
+            for r in decoding.values()
         )
         # The slots that decode: a recurrent model's program leaves the
-        # state of the others (free, or mid-prefill) as it is.
-        decoding = np.zeros((self.max_batch,), bool)
-        decoding[list(self._active)] = True
+        # state of the others (free, ended, or mid-prefill) as it is.
+        mask = np.zeros((self.max_batch,), bool)
+        mask[list(decoding)] = True
         with TraceAnnotation("engine:decode_dispatch"):
             (
                 sampled, logits, self.cache, accept, rej, *record
             ) = self._decode_paged(
                 self.params,
-                toks,
+                # The step in flight's ids as they lie on the device:
+                # the same [B, 1] int32 the host's matrix is.
+                self._in_flight.sampled if ahead else toks,
                 self.cache,
                 tables,
-                self._positions,
+                # A copy: the mirror changes while the program, which
+                # may read the host's buffer in place, is in flight.
+                self._positions.copy(),
                 self._temps,
-                sub,
+                self._next_key(),
                 stochastic=stochastic,
-                active=decoding,
+                active=mask,
             )
-            self._account("decode", logits, record, len(self._active))
+            self._account("decode", logits, record, len(decoding))
+            sampled.copy_to_host_async()
+        before, self._in_flight = self._in_flight, _DecodeStep(
+            decoding, sampled, logits, accept, rej, toks[:, 1:], draft_len
+        )
+        if before is not None:
+            self._read_back(before, finished)
+
+    def _drain(
+        self, finished: list[dict], cause: str | None = None,
+        unlock: bool = True,
+    ) -> None:
+        """Read the step in flight back, if there is one, BEFORE the
+        next dispatch; `cause` says what needed its token values, and
+        is counted where a dispatch follows in this step()."""
+        step, self._in_flight = self._in_flight, None
+        if step is None:
+            return
+        self._drained_for = self._drained_for or cause
+        self._read_back(step, finished, unlock)
+
+    def _fetch(self, step: _DecodeStep) -> tuple:
+        """The host's wait for a decode program, and its results as
+        numpy: (sampled [B, K], n_acc [B], rej, position 0's logits or
+        None). Touches none of the engine's state: `_read_back` calls it
+        with `_lock` let go."""
+        sampled = np.asarray(step.sampled)  # [B, K] ints
+        K = sampled.shape[1]
+        n_acc, rej = step.draft_len, None  # no draft, none accepted
+        if K > 1:
+            # Speculation's two, [B, K-1] each ([B, 0] at K = 1: not
+            # read back), and the acceptance — one mismatch-argmax
+            # over [B, K-1], not a per-slot interpreted loop on the
+            # serial dispatch path: n_acc[b] = index of the first
+            # rejected (or absent) draft position.
+            rej = np.asarray(step.rej)
+            stop = ~np.asarray(step.accept)
+            stop |= np.arange(K - 1)[None, :] >= step.draft_len[:, None]
+            n_acc = np.where(stop.any(axis=1), stop.argmax(axis=1), K - 1)
+        # The [B, V] logits of position 0, only if a slot samples on
+        # the host.
+        host_logits = None
+        if any(self._host_sampled(r.sampling) for r in step.slots.values()):
+            host_logits = np.asarray(step.logits)
+        return sampled, n_acc, rej, host_logits
+
+    def _read_back(
+        self, step: _DecodeStep, finished: list[dict], unlock: bool = True
+    ) -> None:
+        """Wait for `step`, emit its tokens and run the stop checks, by
+        the slot -> request map of its dispatch: a request that ended or
+        was aborted since is skipped."""
         with TraceAnnotation("engine:decode_sync"):
-            sampled = np.asarray(sampled)  # [B, K] ints
-            n_acc = draft_len  # no draft, none accepted: all of K = 1
-            if K > 1:
-                # Speculation's two, [B, K-1] each ([B, 0] at K = 1: not
-                # read back), and the acceptance — one mismatch-argmax
-                # over [B, K-1], not a per-slot interpreted loop on the
-                # serial dispatch path: n_acc[b] = index of the first
-                # rejected (or absent) draft position.
-                rej = np.asarray(rej)
-                stop = ~np.asarray(accept)
-                stop |= np.arange(K - 1)[None, :] >= draft_len[:, None]
-                n_acc = np.where(
-                    stop.any(axis=1), stop.argmax(axis=1), K - 1
-                )
-            # The [B, V] logits of position 0, only if a slot samples
-            # on the host.
-            host_logits = None
-            if any(
-                self._host_sampled(r.sampling) for r in self._active.values()
-            ):
-                host_logits = np.asarray(logits)
+            # add_request and abort_request, on the replica's event
+            # loop, do not wait out a device step.
+            if unlock:
+                self._lock.release()
+            try:
+                sampled, n_acc, rej, host_logits = self._fetch(step)
+            finally:
+                if unlock:
+                    self._lock.acquire()
         with TraceAnnotation("engine:emit") as span:
             n_tokens, n_done = self._stats["tokens_generated"], len(finished)
-            for slot, req in list(self._active.items()):
+            for slot, req in step.slots.items():
+                if req.done:
+                    # Ended by a stop token that the host learnt after
+                    # this dispatch (an abort has no `finish_ts`): its
+                    # slot-step was computed for nobody.
+                    self._stats["overrun_slot_steps"] += bool(req.finish_ts)
+                    continue
                 if self._host_sampled(req.sampling):
                     tok = self._sample(host_logits[slot], req.sampling)
                     self._record_token(req, tok, finished)
@@ -840,8 +1019,8 @@ class LLMEngine:
                 # residual sample if a draft was REJECTED there, the
                 # full-p sample if the draft simply ran out (or none
                 # existed).
-                emit = list(toks[slot, 1: 1 + na])
-                if na < draft_len[slot]:
+                emit = list(step.drafts[slot, :na])
+                if na < step.draft_len[slot]:
                     emit.append(int(rej[slot, na]))
                 else:
                     emit.append(int(sampled[slot, na]))
@@ -944,13 +1123,23 @@ class LLMEngine:
         token totals, speculative proposal/acceptance, preemptions,
         chunked-prefill progress, the engine loop's own account (steps,
         slot-steps, attended and table pages, queue and lock waits,
-        `init_s`), `param_bytes` (the held weights, matmul leaves in
-        `cfg.dtype`), which attention and which K/V cell write the
+        `init_s`; decode steps dispatched with the step before them
+        still in flight, as a count and as `decode_in_flight_pct`, the
+        reads that had to come first by cause, `pipeline_drains`, and
+        `overrun_slot_steps`), `param_bytes` (the held weights, matmul
+        leaves in `cfg.dtype`), which attention and which K/V cell write the
         decode program was compiled with (`paged_attn_kernel`,
         `kv_write_kernel`) and the pool/slot occupancy."""
         with self._lock:
             self._fold_moe_counts()
             out = dict(self._stats)
+            out["pipeline_drains"] = dict(out["pipeline_drains"])
+            # How often a decode step ran under the host's work on the
+            # step before it.
+            out["decode_in_flight_pct"] = (
+                100.0 * out["decode_steps_in_flight"] / out["decode_steps"]
+                if out["decode_steps"] else 0.0
+            )
             out["platform"] = self.platform
             out["device_kind"] = jax.devices()[0].device_kind
             out["paged_attn_kernel"] = self.paged_attn_kernel

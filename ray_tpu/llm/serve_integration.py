@@ -193,9 +193,9 @@ class LLMServer:
         ray.llm deployments expose) — callable as a deployment method:
         HTTP {"method": "stats"} or handle.options(method_name=
         "stats"). Async via the executor: engine.stats() takes the
-        engine lock, which the pump holds across whole step() calls —
-        grabbing it on the event loop would freeze the replica for a
-        step (minutes on a first compile)."""
+        engine lock, which step() holds for its host work and for a
+        prefill's wait — grabbing it on the event loop would freeze the
+        replica for that long (minutes on a first compile)."""
         return await asyncio.get_running_loop().run_in_executor(
             None, self.engine.stats
         )

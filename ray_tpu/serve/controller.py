@@ -25,11 +25,11 @@ The control loop closes the serve signal plane (PR 9) into actions:
   new requests (typed ``ReplicaDrainingError`` the router re-routes
   on), killed only once in-flight work hits zero or
   ``SERVE_DRAIN_TIMEOUT_S`` expires.
-- **Replica-kill survival** — dead replicas (3 missed polls, or a
-  router's typed death observation) are dropped and replacements start
-  on healthy, non-draining nodes; when slices are labeled, replicas
-  spread across slice fault domains so one slice preemption cannot take
-  out every replica.
+- **Replica-kill survival** — dead replicas (3 failed polls, 9 that
+  only timed out, or a router's typed death observation) are dropped
+  and replacements start on healthy, non-draining nodes; when slices
+  are labeled, replicas spread across slice fault domains so one slice
+  preemption cannot take out every replica.
 """
 
 from __future__ import annotations
@@ -39,6 +39,7 @@ import logging
 import time
 
 from ray_tpu import api as core_api
+from ray_tpu.exceptions import GetTimeoutError
 from ray_tpu.runtime.core_worker import ActorSubmitTarget
 from ray_tpu.serve.replica import ReplicaActor
 
@@ -525,10 +526,18 @@ class ServeController:
             if isinstance(s, BaseException):
                 # A single missed poll is not death: a replica blocked in
                 # a long jit compile (first LLM request) must not be
-                # killed mid-request. Three consecutive misses ≈ 3 control
-                # periods + timeouts before we declare it gone.
-                r["misses"] = r.get("misses", 0) + 1
-                if r["misses"] >= 3:
+                # killed mid-request. A poll that FAILS says the process
+                # is gone: three in a row ≈ 3 control periods before we
+                # declare it so. A poll that only TIMES OUT says that a
+                # live process did not answer within 2 s, as one that
+                # holds its interpreter lock in foreign code cannot (the
+                # profiler's stop_trace held a busy LLM replica's for
+                # 5-7 s, and three timeouts killed it mid-run): nine in
+                # a row, ~20 s (the reference's health_check_timeout_s
+                # is 30). Counted in thirds of a failure.
+                timed_out = isinstance(s, GetTimeoutError)
+                r["misses"] = r.get("misses", 0) + (1 if timed_out else 3)
+                if r["misses"] >= 9:
                     dead.append(r)
             else:
                 r["misses"] = 0

@@ -123,3 +123,31 @@ def mesh8():
 
     assert len(jax.devices()) == 8
     return make_mesh({"dp": 2, "fsdp": 2, "tp": 2})
+
+
+@pytest.fixture
+def generate_at_lag0():
+    """`LLMEngine.generate` with the engine held to lag 0: whatever a
+    step() left in flight is read back before the next one, so that
+    every decode step is dispatched from the host's tokens, in the
+    order the engine had before it kept a step in flight. What the
+    lag-1 tests compare with."""
+
+    def generate(engine, prompts, sampling):
+        """`sampling`: one for all prompts, or one a prompt."""
+        if not isinstance(sampling, list):
+            sampling = [sampling] * len(prompts)
+        order = {
+            engine.add_request(p, s): i
+            for i, (p, s) in enumerate(zip(prompts, sampling, strict=True))
+        }
+        outs = [None] * len(prompts)
+        while engine.has_unfinished():
+            finished = engine.step()
+            with engine._lock:
+                engine._drain(finished, unlock=False)
+            for fin in finished:
+                outs[order[fin["request_id"]]] = fin["tokens"]
+        return outs
+
+    return generate

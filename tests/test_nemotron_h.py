@@ -345,6 +345,54 @@ def test_a_reused_slot_carries_nothing_over(params):
     assert tight.stats()["preemptions"] >= 1
 
 
+@pytest.mark.parametrize(
+    "temperature", [0.0, 0.8], ids=["greedy", "sampled"]
+)
+def test_lag_1_emits_what_lag_0_emits(temperature, params, generate_at_lag0):
+    """The decode step dispatched from the ids the step before it left
+    on the device (state, pages and expert routes carried the same)
+    emits token for token what it emits from the host's copy of them,
+    and ends known early cost no slot-step: the state a finished
+    request leaves is the one its last token was computed from."""
+    sampling = SamplingParams(max_tokens=7, temperature=temperature)
+    prompts = [_prompt(4, 21), _prompt(5, 37), _prompt(6, 9)]
+    lag1, lag0 = _engine(params, seed=2), _engine(params, seed=2)
+    assert lag1.generate(prompts, sampling) == generate_at_lag0(
+        lag0, prompts, sampling
+    )
+    np.testing.assert_array_equal(lag1.cache["ssm"], lag0.cache["ssm"])
+    np.testing.assert_array_equal(lag1.cache["conv"], lag0.cache["conv"])
+    s1, s0 = lag1.stats(), lag0.stats()
+    assert s0["decode_steps_in_flight"] == 0
+    assert s1["decode_steps"] == s0["decode_steps"] == 6
+    assert s1["decode_steps_in_flight"] == 5
+    assert s1["slot_steps"] == s0["slot_steps"] == 18
+    assert s1["experts_touched"] == s0["experts_touched"]
+    assert s1["overrun_slot_steps"] == 0
+
+
+def test_a_stop_tokens_overrun_step_leaves_nothing_behind(params):
+    """A stop token is learnt a step late, and a recurrent slot's state
+    cannot be rolled back: the overrun step advances a state that nobody
+    reads again. The next request in that slot and those pages starts
+    from `start = 0` and answers what it answers on a fresh engine."""
+    a, b = _prompt(7, 30), _prompt(8, 45)
+    sampling = SamplingParams(max_tokens=10)
+    (free,) = _engine(params, max_batch=1).generate([a], sampling)
+    (fresh,) = _engine(params, max_batch=1).generate([b], sampling)
+    k = next(i for i in range(2, 10) if free[i] not in free[:i])
+    eng = _engine(params, max_batch=1)
+    stopped = SamplingParams(max_tokens=10, stop_token_ids=(free[k],))
+    assert eng.generate([a], stopped) == [free[:k]]
+    stats = eng.stats()
+    assert stats["decode_steps"] == k + 1
+    assert stats["overrun_slot_steps"] == 1
+    assert eng.alloc.free_pages == eng.alloc.num_pages
+    overrun = np.asarray(eng.cache["ssm"][:, 0])
+    assert eng.generate([b], sampling) == [fresh]
+    assert not np.array_equal(overrun, np.asarray(eng.cache["ssm"][:, 0]))
+
+
 def test_speculation_is_refused_for_recurrent_blocks(params):
     with pytest.raises(ValueError, match="rolled back"):
         _engine(params, speculate=2)

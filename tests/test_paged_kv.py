@@ -6,6 +6,9 @@ recompute preemption, which ray.llm inherits through engine_kwargs —
 python/ray/llm/_internal/serve/deployments/llm/vllm/vllm_models.py:234.)
 """
 
+import threading
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -123,12 +126,15 @@ def test_one_decode_program_whatever_the_temperatures(params):
                        page_size=8)
     before = paged_verify._cache_size()
     engine.generate([[1, 2, 3]], SamplingParams(max_tokens=4))
-    assert paged_verify._cache_size() == before + 1
+    # Two signatures of one compiled program: `tokens` from the host
+    # (the first step) and as the step in flight left them on the device
+    # (tests/test_tpu_aot_compile.py counts the compiles).
+    assert paged_verify._cache_size() == before + 2
     outs = engine.generate(
         [[4, 5, 6], [7, 8]], SamplingParams(max_tokens=4, temperature=0.8)
     )
     assert [len(o) for o in outs] == [4, 4]
-    assert paged_verify._cache_size() == before + 1
+    assert paged_verify._cache_size() == before + 2
 
 
 @pytest.mark.parametrize("speculate", [0, 3])
@@ -327,3 +333,252 @@ def test_on_logits_hands_over_every_programs_logits():
     stats = eng.stats()
     assert stats["state_bytes"] == 0 and stats["moe_pairs_routed"] == 0
     assert stats["pool_bytes"] == eng.cache["k"].nbytes * 2
+
+
+# ------------------------------------------- one decode step in flight
+def _engine(params, **kw):
+    kw = {"max_batch": 2, "max_seq": 64, "page_size": 16, **kw}
+    return LLMEngine(CFG, params=params, **kw)
+
+
+def _count_decode_calls(engine) -> list:
+    real, calls = engine._decode_paged, []
+
+    def counted(*args, **kw):
+        calls.append(1)
+        return real(*args, **kw)
+
+    engine._decode_paged = counted
+    return calls
+
+
+def test_the_chain_of_keys_is_the_split_chain():
+    """A block of links made by one program is link for link what
+    ``key, sub = jax.random.split(key)`` makes a step at a time, over a
+    block's end too: seeded streams stay what they were."""
+    from ray_tpu.llm.engine import _KEY_BLOCK
+
+    engine = LLMEngine("tiny", max_batch=1, max_seq=32, seed=11)
+    key = jax.random.key(11)
+    for _ in range(_KEY_BLOCK + 3):
+        key, sub = jax.random.split(key)
+        got = engine._next_key()
+        assert (jax.random.key_data(got) == jax.random.key_data(sub)).all()
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.8], ids=["greedy", "sampled"])
+def test_lag_1_emits_what_lag_0_emits(temperature, params, generate_at_lag0):
+    """Same programs, same inputs, same chain of keys: a step dispatched
+    from the ids the step before left on the device emits token for
+    token what it emits from the host's copy of them."""
+    sampling = SamplingParams(max_tokens=9, temperature=temperature)
+    prompts = PROMPTS[:2]
+    lag1, lag0 = _engine(params, seed=5), _engine(params, seed=5)
+    assert lag1.generate(prompts, sampling) == generate_at_lag0(
+        lag0, prompts, sampling
+    )
+    s1, s0 = lag1.stats(), lag0.stats()
+    assert s0["decode_steps_in_flight"] == 0 == s0["decode_in_flight_pct"]
+    # Of 8 decode steps all but the first follow one still in flight.
+    assert s1["decode_steps"] == s0["decode_steps"] == 8
+    assert s1["decode_steps_in_flight"] == 7
+    assert s1["decode_in_flight_pct"] == pytest.approx(87.5)
+    assert s1["slot_steps"] == s0["slot_steps"] == 16
+    assert s1["overrun_slot_steps"] == 0
+    assert not any(s1["pipeline_drains"].values())
+
+
+def test_greedy_streams_do_not_depend_on_when_a_slot_frees(
+    params, generate_at_lag0
+):
+    """Three requests over two slots: at lag 1 an end is reported one
+    call later, so the third is admitted into another step than at lag
+    0; greedy tokens are the same, and the admission read the step in
+    flight back first."""
+    samplings = [SamplingParams(max_tokens=n) for n in (4, 12, 6)]
+    outs = generate_at_lag0(_engine(params), PROMPTS, samplings)
+    lag1 = _engine(params)
+    order = [lag1.add_request(p, s) for p, s in zip(PROMPTS, samplings)]
+    done = {}
+    while lag1.has_unfinished():
+        done.update((f["request_id"], f["tokens"]) for f in lag1.step())
+    assert [done[rid] for rid in order] == outs
+    assert [len(o) for o in outs] == [4, 12, 6]
+    stats = lag1.stats()
+    assert stats["pipeline_drains"]["admit"] == 1
+    assert stats["decode_steps_in_flight"] > 0
+    assert lag1.alloc.free_pages == lag1.alloc.num_pages
+
+
+@pytest.mark.parametrize(
+    "max_tokens,max_seq,decodes",
+    [(1, 64, 0), (2, 64, 1), (6, 64, 5), (30, 16, 10)],
+    ids=["n1", "n2", "n6", "max_seq"],
+)
+def test_an_end_known_early_costs_no_extra_decode_call(
+    max_tokens, max_seq, decodes, params
+):
+    """An end by `max_tokens` or by `max_seq` is known before the last
+    token's value is: the slot is not in the next dispatch, and a
+    request costs the decode calls it cost at lag 0 (n - 1 for n
+    tokens; `benchmarks/server.py check` counts them)."""
+    engine = _engine(params, max_seq=max_seq, page_size=8)
+    calls = _count_decode_calls(engine)
+    (out,) = engine.generate(
+        [[1, 2, 3, 4, 5]], SamplingParams(max_tokens=max_tokens)
+    )
+    assert len(out) == decodes + 1
+    stats = engine.stats()
+    assert len(calls) == stats["decode_steps"] == decodes
+    assert stats["slot_steps"] == decodes
+    assert stats["overrun_slot_steps"] == 0
+    assert engine._in_flight is None and not engine.has_unfinished()
+
+
+def test_a_stop_token_costs_one_overrun_slot_step(params):
+    """An end by a stop token is learnt a step late: the slot's one
+    extra slot-step is computed and thrown away, nothing after the stop
+    token is emitted, every page comes back, and the next request, in
+    the freed slot and pages, reads what it reads alone."""
+    prompt, other = [1, 2, 3], [9, 10, 11, 12]
+    free = _engine(params, max_batch=1).generate(
+        [prompt], SamplingParams(max_tokens=8)
+    )[0]
+    k = next(i for i in range(2, 8) if free[i] not in free[:i])
+    (alone,) = _engine(params, max_batch=1).generate(
+        [other], SamplingParams(max_tokens=6)
+    )
+    engine = _engine(params, max_batch=1)
+    calls = _count_decode_calls(engine)
+    rid = engine.add_request(
+        prompt, SamplingParams(max_tokens=8, stop_token_ids=(free[k],)),
+        stream=True,
+    )
+    streamed, finished = [], []
+    while engine.has_unfinished():
+        finished += engine.step()
+        streamed += engine.drain_deltas().get(rid, [])
+    assert finished[0]["tokens"] == streamed == free[:k]
+    stats = engine.stats()
+    # k decode steps reach the stop token; one more was in flight.
+    assert len(calls) == stats["decode_steps"] == k + 1
+    assert stats["overrun_slot_steps"] == 1
+    assert engine.alloc.free_pages == engine.alloc.num_pages
+    assert engine.generate([other], SamplingParams(max_tokens=6)) == [alone]
+
+
+def test_abort_with_a_step_in_flight(params):
+    """The step in flight still names the aborted request's slot: its
+    token is dropped, its pages are back at once, and the other slot's
+    stream is what it is alone."""
+    sampling = SamplingParams(max_tokens=10)
+    (alone,) = _engine(params).generate([PROMPTS[0]], sampling)
+    engine = _engine(params)
+    keep = engine.add_request(PROMPTS[0], sampling)
+    drop = engine.add_request(PROMPTS[1], sampling, stream=True)
+    engine.step()
+    engine.step()
+    assert engine._in_flight is not None
+    assert drop in {r.request_id for r in engine._in_flight.slots.values()}
+    assert engine.abort_request(drop)
+    finished = []
+    while engine.has_unfinished():
+        finished += engine.step()
+    assert [f["request_id"] for f in finished] == [keep]
+    assert finished[0]["tokens"] == alone
+    assert drop not in engine.drain_deltas()
+    stats = engine.stats()
+    assert stats["requests_aborted"] == 1 and stats["overrun_slot_steps"] == 0
+    assert engine.alloc.free_pages == engine.alloc.num_pages
+
+
+def test_a_preemption_reads_the_step_in_flight_back_first(params):
+    """A victim's context has to hold its token in flight before it is
+    requeued: the pool's exhaustion drains, by name, and the streams are
+    a roomy pool's."""
+    sampling = SamplingParams(max_tokens=20)
+    prompts = [[1, 2, 3, 4, 5, 6, 7, 8], [9, 10, 11, 12, 13, 14]]
+    tight = _engine(params, page_size=8, num_pages=4)
+    outs = tight.generate(prompts, sampling)
+    assert outs == _engine(params, page_size=8).generate(prompts, sampling)
+    stats = tight.stats()
+    assert stats["preemptions"] >= 1
+    assert stats["pipeline_drains"]["preempt"] >= 1
+    assert tight.alloc.free_pages == 4
+
+
+@pytest.mark.parametrize(
+    "cause,engine_kw,sampling",
+    [
+        ("speculate", {"speculate": 2}, SamplingParams(max_tokens=8)),
+        ("host_sampled", {},
+         SamplingParams(max_tokens=8, temperature=1.0, top_k=4)),
+    ],
+    ids=["speculate", "top_k"],
+)
+def test_a_step_that_needs_token_values_runs_at_lag_0(
+    cause, engine_kw, sampling, params
+):
+    """Drafts come from the tokens emitted so far, and a top-k slot
+    samples on the host: such a step reads the one in flight back
+    before it is dispatched, and `pipeline_drains` says why."""
+    engine = _engine(params, **engine_kw)
+    (out,) = engine.generate([PROMPTS[2]], sampling)
+    assert len(out) == 8
+    stats = engine.stats()
+    assert stats["decode_steps_in_flight"] == 0
+    drains = stats["pipeline_drains"]
+    assert drains[cause] == stats["decode_steps"] - 1 > 0
+    assert sum(drains.values()) == drains[cause]
+
+
+def test_the_last_step_in_flight_is_not_lost(params):
+    """`has_unfinished` holds while a step is in flight, so the loop
+    that drives step() reads the last one back: every streamed delta
+    and every finished list arrive, and nothing is left on the device."""
+    engine = _engine(params)
+    sampling = SamplingParams(max_tokens=5)
+    rids = [engine.add_request(p, sampling, stream=True) for p in PROMPTS[:2]]
+    streamed = {rid: [] for rid in rids}
+    finished = {}
+    while engine.has_unfinished():
+        for fin in engine.step():
+            finished[fin["request_id"]] = fin["tokens"]
+        for rid, toks in engine.drain_deltas().items():
+            streamed[rid] += toks
+    assert engine._in_flight is None
+    assert streamed == finished and all(len(t) == 5 for t in finished.values())
+    assert finished == dict(
+        zip(rids, _engine(params).generate(PROMPTS[:2], sampling))
+    )
+
+
+def test_add_request_does_not_wait_for_a_device_step(params):
+    """step() lets go of `_lock` while it waits for a decode program:
+    a caller on the replica's event loop gets in during the wait."""
+    engine = _engine(params)
+    real, waiting, held = engine._fetch, threading.Event(), 1.0
+
+    def slow_fetch(step):
+        waiting.set()
+        time.sleep(held)
+        return real(step)
+
+    engine._fetch = slow_fetch
+    engine.add_request(PROMPTS[0], SamplingParams(max_tokens=3))
+    stepper = threading.Thread(
+        target=lambda: [engine.step() for _ in range(2)]
+    )
+    stepper.start()
+    assert waiting.wait(30)
+    began = time.perf_counter()
+    rid = engine.add_request(PROMPTS[1], SamplingParams(max_tokens=2))
+    waited = time.perf_counter() - began
+    stepper.join(30)
+    assert not stepper.is_alive()
+    assert waited < 0.5 * held
+    engine._fetch = real
+    done = {}
+    while engine.has_unfinished():
+        done.update((f["request_id"], f["tokens"]) for f in engine.step())
+    assert len(done) == 2 and len(done[rid]) == 2

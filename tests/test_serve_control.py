@@ -58,6 +58,59 @@ def test_breaker_open_half_open_close_transitions():
     assert br.state(5.3, reset_s) == "closed"  # count restarted at 0
 
 
+# ------------------------------------------------------- health polls
+@pytest.mark.parametrize(
+    "error,polls_to_death",
+    [(ConnectionError("actor died: connection lost"), 3),
+     (ray_tpu.exceptions.GetTimeoutError("timed out waiting"), 9)],
+    ids=["failed", "timed_out"],
+)
+def test_a_replica_that_only_times_out_is_given_longer(error, polls_to_death):
+    """A poll that fails says the process is gone; a poll that times out
+    says a live process did not answer in 2 s (one that holds its
+    interpreter lock in foreign code cannot: the profiler's stop_trace
+    held a busy LLM replica's for 5-7 s). Three failures in a row are
+    death, nine timeouts are, and one answer forgets both."""
+    from ray_tpu.serve.controller import ServeController
+
+    class Core:
+        killed = []
+        answer = error
+
+        async def submit_task(self, *a, **kw):
+            return ["ref"]
+
+        async def get(self, refs, timeout):
+            if isinstance(self.answer, BaseException):
+                raise self.answer
+            return [self.answer]
+
+        async def kill_actor(self, actor_id, addr):
+            self.killed.append(actor_id)
+
+    async def run():
+        controller, core = ServeController(), Core()
+        replica = {"actor_id": "a1", "addr": ("h", 1)}
+        dep = {"replicas": [replica], "version": 0}
+        for _ in range(polls_to_death - 1):
+            await controller._poll_stats(core, dep)
+        assert dep["replicas"] == [replica] and dep["version"] == 0
+        core.answer = {"num_ongoing_requests": 2}
+        assert await controller._poll_stats(core, dep) == {
+            "num_ongoing_requests": 2
+        }
+        assert replica["misses"] == 0
+        core.answer = error
+        for _ in range(polls_to_death):
+            assert dep["replicas"] == [replica]
+            await controller._poll_stats(core, dep)
+        assert dep["replicas"] == [] and dep["version"] == 1
+        await asyncio.gather(*controller._bg_tasks)
+        assert core.killed == ["a1"]
+
+    asyncio.run(run())
+
+
 # --------------------------------------------------- autoscale decisions
 def _decide(state, desired, now, **kw):
     defaults = dict(

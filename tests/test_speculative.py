@@ -269,22 +269,20 @@ def test_rejection_sampling_preserves_distribution(tiny, draft_kind):
 
 def test_stochastic_speculation_accepts_drafts(tiny):
     """Speculation must actually fire on stochastic slots now: a
-    repetitive prompt at moderate temperature advances more than one
-    token in some steps (acceptance > 0), and all tokens are in-vocab."""
+    repetitive prompt at a low temperature advances more than one
+    token in some steps (acceptance > 0, so fewer decode steps than
+    decoded tokens), and all tokens are in-vocab. (Counted by the
+    engine: a step() returns the step BEFORE the one it dispatched, so
+    what one call adds to a request says nothing about one program.)"""
     prompt = [7, 8, 9, 7, 8, 9, 7, 8, 9, 7, 8]
     eng = LLMEngine(
         tiny, max_batch=1, kv="paged", page_size=8, speculate=3, seed=0,
     )
-    rid = eng.add_request(
-        prompt, SamplingParams(max_tokens=24, temperature=0.7)
+    (out,) = eng.generate(
+        [prompt], SamplingParams(max_tokens=64, temperature=0.05)
     )
-    multi_token_steps = 0
-    req = None
-    while eng.has_unfinished():
-        before = 0 if req is None else len(req.out_tokens)
-        eng.step()
-        if req is None and eng._active:
-            req = next(iter(eng._active.values()))
-        if req is not None and len(req.out_tokens) - before > 1:
-            multi_token_steps += 1
-    assert multi_token_steps > 0
+    assert len(out) == 64 and all(0 <= t < tiny.vocab_size for t in out)
+    stats = eng.stats()
+    assert stats["draft_tokens_accepted"] > 0
+    # 63 tokens come of decode steps (the first is the prefill's).
+    assert stats["decode_steps"] < 63

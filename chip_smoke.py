@@ -13,7 +13,10 @@ weights from ``--seed``):
   ``LLMEngine`` and compares its logits with ``models.forward``; and
   another builds a tiny hybrid engine (``models/nemotron_h.py``: Mamba-2,
   expert and attention blocks at Nemotron-3-Nano's widths) and compares
-  its chunked prefill and decode with the plain reference.
+  its chunked prefill and decode with the plain reference; and a third
+  does the same for a tiny latent-attention engine
+  (``models/pangu_ultra_moe.py``: MLA over a latent page pool at
+  openPangu-Ultra-MoE's widths).
 - ``--chips 4`` instead runs the sharded trainer (one worker, four
   chips, an ``{"fsdp": 4}`` mesh) against the same seed and batch on a
   one-device mesh, and no other phase.
@@ -101,6 +104,22 @@ SIZES = {
         "engine": {"max_batch": 4, "max_seq": 1024, "page_size": 64,
                    "prefill_chunk": 128},
         "check_prompt": 200, "check_decode": 4,
+    },
+    # One tiny latent-attention engine (models/pangu_ultra_moe.py): a
+    # dense and an expert layer at openPangu-Ultra-MoE's widths, 128
+    # heads over a 640-lane latent pool, tiny in depth, experts and
+    # vocabulary (1.5 GB of weights). The 600-token prompt goes in three
+    # chunks of 256, the last padded, each attending the earlier chunks'
+    # latent pages.
+    "latent": {
+        "cfg": {"n_layers": 2, "first_k_dense": 1, "experts_held": (0, 8),
+                "vocab_size": 8192},
+        "reduced": {"n_layers": "2 of 61 (1 dense + 1 expert)",
+                    "experts_held": "8 of 256",
+                    "vocab_size": "8,192 of 153,600 rows"},
+        "engine": {"max_batch": 4, "max_seq": 1024, "page_size": 64,
+                   "prefill_chunk": 256},
+        "check_prompt": 600, "check_decode": 4,
     },
 }
 
@@ -342,16 +361,16 @@ def engine_check(cfg, engine_kwargs: dict, sizes: dict, seed: int) -> dict:
     }
 
 
-def hybrid_check(cfg, engine_kwargs: dict, sizes: dict, seed: int) -> dict:
-    """A hybrid engine (Mamba-2, expert and attention blocks over pages
-    and per-slot state): one request through add_request/step, prefilled
-    in chunks, then decode steps; the logits its programs produced (handed
-    over by `LLMEngine.on_logits`) against the plain reference's one full
-    pass on the same weights, tokens and routes."""
+def _check_against_reference(cfg, engine_kwargs: dict, sizes: dict, seed: int,
+                             reference, ref_sizes: dict) -> dict:
+    """One request through add_request/step of an engine whose programs
+    keep a record of their routes, prefilled in chunks, then decode
+    steps; the logits its programs produced (handed over by
+    `LLMEngine.on_logits`) against the plain reference's one full pass
+    on the same weights, tokens and routes."""
     import jax.numpy as jnp
     import numpy as np
 
-    from benchmarks import reference_nemotron_h as reference
     from ray_tpu.llm.engine import LLMEngine, SamplingParams
 
     t_start = time.perf_counter()
@@ -380,13 +399,7 @@ def hybrid_check(cfg, engine_kwargs: dict, sizes: dict, seed: int) -> dict:
     ref, record = reference.forward_with_record(
         eng.params, jnp.asarray(prompt + generated[:-1], jnp.int32),
         routes=jnp.asarray(routes), rows=list(range(n - 1, n + n_decode)),
-        pattern=cfg.pattern, mamba_heads=cfg.mamba_heads,
-        mamba_head_dim=cfg.mamba_head_dim, n_groups=cfg.ssm_groups,
-        ssm_state_size=cfg.ssm_state, conv_kernel=cfg.conv_kernel,
-        num_experts_per_tok=cfg.top_k, norm_topk_prob=cfg.norm_topk_prob,
-        routed_scaling_factor=cfg.routed_scaling_factor,
-        num_attention_heads=cfg.n_heads, num_key_value_heads=cfg.n_kv_heads,
-        head_dim=cfg.head_dim,
+        **ref_sizes,
     )
     ref = np.asarray(ref)
     got = [prefills[-1][1][0, 0]] + [s[1][0] for s in decodes]
@@ -406,6 +419,63 @@ def hybrid_check(cfg, engine_kwargs: dict, sizes: dict, seed: int) -> dict:
     }
 
 
+def hybrid_check(cfg, engine_kwargs: dict, sizes: dict, seed: int) -> dict:
+    """A hybrid engine (Mamba-2, expert and attention blocks over pages
+    and per-slot state) against ``reference_nemotron_h``."""
+    from benchmarks import reference_nemotron_h as reference
+
+    return _check_against_reference(
+        cfg, engine_kwargs, sizes, seed, reference, dict(
+            pattern=cfg.pattern, mamba_heads=cfg.mamba_heads,
+            mamba_head_dim=cfg.mamba_head_dim, n_groups=cfg.ssm_groups,
+            ssm_state_size=cfg.ssm_state, conv_kernel=cfg.conv_kernel,
+            num_experts_per_tok=cfg.top_k, norm_topk_prob=cfg.norm_topk_prob,
+            routed_scaling_factor=cfg.routed_scaling_factor,
+            num_attention_heads=cfg.n_heads,
+            num_key_value_heads=cfg.n_kv_heads, head_dim=cfg.head_dim,
+        ),
+    )
+
+
+def latent_check(cfg, engine_kwargs: dict, sizes: dict, seed: int) -> dict:
+    """A latent-attention engine (MLA in every layer over one latent
+    page pool: expanded chunked prefill, absorbed decode through the
+    kernel; a dense and an expert layer) against
+    ``reference_pangu_ultra_moe``'s non-absorbed pass."""
+    from benchmarks import reference_pangu_ultra_moe as reference
+
+    return _check_against_reference(
+        cfg, engine_kwargs, sizes, seed, reference, dict(
+            first_k_dense_replace=cfg.first_k_dense,
+            num_attention_heads=cfg.n_heads,
+            qk_nope_head_dim=cfg.qk_nope_head_dim,
+            qk_rope_head_dim=cfg.qk_rope_head_dim,
+            kv_lora_rank=cfg.kv_lora_rank, rope_theta=cfg.rope_theta,
+            num_experts_per_tok=cfg.top_k, norm_topk_prob=cfg.norm_topk_prob,
+            routed_scaling_factor=cfg.routed_scaling_factor,
+        ),
+    )
+
+
+def _phase_check(name: str, check, cfg, sizes: dict, seed: int) -> dict:
+    """``check`` of an engine for ``cfg`` on a plain task's lease."""
+    t0 = time.perf_counter()
+    record = ray_tpu.get(
+        ray_tpu.remote(num_tpus=1)(check).remote(
+            cfg, {**sizes["engine"], "seed": seed}, sizes, seed
+        )
+    )
+    wait_chip_free()
+    return _emit({
+        "phase": name,
+        "cfg": sizes["cfg"],
+        "reduced": sizes["reduced"],
+        "tolerance": LOGIT_TOLERANCE,
+        **record,
+        "task_wall_s": time.perf_counter() - t0,
+    })
+
+
 def phase_hybrid(sizes: dict, seed: int) -> dict:
     """The hybrid engine on a plain task's lease."""
     from ray_tpu.models.nemotron_h import NEMOTRON_H_PRESETS, NemotronHConfig
@@ -414,21 +484,21 @@ def phase_hybrid(sizes: dict, seed: int) -> dict:
         NEMOTRON_H_PRESETS["nemotron_h_tiny"] if PRESET == "tiny"
         else NemotronHConfig(), **sizes["cfg"],
     )
-    t0 = time.perf_counter()
-    check = ray_tpu.get(
-        ray_tpu.remote(num_tpus=1)(hybrid_check).remote(
-            cfg, {**sizes["engine"], "seed": seed}, sizes, seed
-        )
+    return _phase_check("hybrid_check", hybrid_check, cfg, sizes, seed)
+
+
+def phase_latent(sizes: dict, seed: int) -> dict:
+    """The latent-attention engine on a plain task's lease."""
+    from ray_tpu.models.pangu_ultra_moe import (
+        PANGU_PRESETS,
+        PanguUltraMoEConfig,
     )
-    wait_chip_free()
-    return _emit({
-        "phase": "hybrid_check",
-        "cfg": sizes["cfg"],
-        "reduced": sizes["reduced"],
-        "tolerance": LOGIT_TOLERANCE,
-        **check,
-        "task_wall_s": time.perf_counter() - t0,
-    })
+
+    cfg = dataclasses.replace(
+        PANGU_PRESETS["pangu_tiny"] if PRESET == "tiny"
+        else PanguUltraMoEConfig(), **sizes["cfg"],
+    )
+    return _phase_check("latent_check", latent_check, cfg, sizes, seed)
 
 
 # ------------------------------------------------------ the parent's side
@@ -634,13 +704,15 @@ def verify(records: list[dict], chips: int) -> dict:
         _require(max(check["logit_max_abs_err"]) <= LOGIT_TOLERANCE,
                  "engine logits differ from models.forward by "
                  f"{check['logit_max_abs_err']} (prefill, then decode steps)")
-    if "hybrid_check" in by_phase:
-        check = by_phase["hybrid_check"]
+    for name in ("hybrid_check", "latent_check"):
+        if name not in by_phase:
+            continue
+        check, what = by_phase[name], name.split("_")[0]
         _require(check["paged_attn_kernel"],
-                 "the hybrid engine ran without the paged-attention kernel")
-        _require(check["logits_finite"], "hybrid engine logits not finite")
+                 f"the {what} engine ran without its attention kernel")
+        _require(check["logits_finite"], f"{what} engine logits not finite")
         _require(max(check["logit_max_abs_err"]) <= LOGIT_TOLERANCE,
-                 "hybrid engine logits differ from the plain reference by "
+                 f"{what} engine logits differ from the plain reference by "
                  f"{check['logit_max_abs_err']} (prefill, then decode steps)")
     return {"platform": "tpu", "kind": kinds.pop(), "count": chips}
 
@@ -652,6 +724,7 @@ def run_phases(chips: int, seed: int, sizes: dict = SIZES) -> list[dict]:
         phase_train("train", sizes["train"], seed, chips=1),
         *phase_serve(sizes["serve"], seed),
         phase_hybrid(sizes["hybrid"], seed),
+        phase_latent(sizes["latent"], seed),
     ]
 
 

@@ -9,8 +9,9 @@ chips changes a sharding annotation, not the orchestration.
 
 One cache and three programs over it, which the MODEL supplies
 (`self.serving`: `paged_kv.LlamaServing` for a `LlamaConfig`, or what a
-config's own `serving()` returns, `hybrid_kv.HybridServing` for
-`models/nemotron_h.py`). For a Llama-shaped model the cache is the page
+config's own `serving()` returns: `hybrid_kv.HybridServing` for
+`models/nemotron_h.py`, `latent_kv.LatentServing` for
+`models/pangu_ultra_moe.py`). For a Llama-shaped model the cache is the page
 pool (`paged_kv.init_paged_kv`) and the programs are `paged_prefill` (a
 whole prompt, bucketed to power-of-two lengths to bound compile count),
 `paged_prefill_chunk` (one chunk of a long prompt) and `paged_verify`
@@ -174,12 +175,8 @@ class LLMEngine:
         # its three programs.
         serving = cfg.serving() if hasattr(cfg, "serving") else LlamaServing(cfg)
         self.serving = serving
-        if serving.recurrent and speculate:
-            raise ValueError(
-                "speculate > 0 with recurrent blocks: a rejected draft "
-                "would need the slot's state rolled back, which is not "
-                "written"
-            )
+        if speculate and serving.no_speculation:
+            raise ValueError(f"speculate > 0 {serving.no_speculation}")
         self.max_batch = max_batch
         self.max_seq = max_seq or cfg.max_seq
         self.mesh = mesh
@@ -256,8 +253,12 @@ class LLMEngine:
             )
         self.prefill_chunk = prefill_chunk
         self._prefilling: dict | None = None
-        self._prefill_chunk_fn = serving.prefill_chunk
-        self._prefill_paged = serving.prefill
+        # Every program is told which attention path it is compiled
+        # with (a prefill that has no kernel takes no notice).
+        self._prefill_chunk_fn = partial(
+            serving.prefill_chunk, use_kernel=use_kernel
+        )
+        self._prefill_paged = partial(serving.prefill, use_kernel=use_kernel)
         # The one decode program, K = 1 + speculate tokens a slot.
         self._decode_paged = partial(serving.decode, use_kernel=use_kernel)
         # Called, where set, with every program's logits as the program
@@ -300,6 +301,14 @@ class LLMEngine:
         # tokens nobody drains.
         self._deltas: dict[str, list[int]] = {}
         self._stream_ids: set[str] = set()
+        # Ids of the requests that have not ended (queued, prefilling,
+        # decoding, preempted): what `abort_request` looks at before it
+        # waits for `_lock`.
+        self._live: set[str] = set()
+        # The cache's two kinds of per-sequence state, as the model that
+        # made it counts them: pages, and what recurrent blocks keep per
+        # slot (0 without any).
+        pool_bytes, state_bytes = serving.cache_bytes(self.cache)
         # Serving observability counters (reference: the vLLM stats
         # ray.llm surfaces — requests, tokens, acceptance, preemption).
         self._stats = {
@@ -342,13 +351,8 @@ class LLMEngine:
             "param_bytes": sum(
                 x.nbytes for x in jax.tree.leaves(self.params)
             ),
-            # The cache's two kinds of per-sequence state: pages, and
-            # what recurrent blocks keep per slot (0 without any).
-            "pool_bytes": int(self.cache["k"].nbytes + self.cache["v"].nbytes),
-            "state_bytes": sum(
-                int(v.nbytes) for k, v in self.cache.items()
-                if k not in ("k", "v")
-            ),
+            "pool_bytes": pool_bytes,
+            "state_bytes": state_bytes,
             # Expert blocks (0 without any): pairs the live tokens were
             # routed to, the pairs among them whose expert is held here,
             # and held experts that got a row, summed over expert blocks
@@ -412,6 +416,7 @@ class LLMEngine:
                 self._stats["requests_submitted"] += 1
                 if stream:
                     self._stream_ids.add(rid)
+                self._live.add(rid)
                 self._queue.append(req)
         return rid
 
@@ -471,6 +476,7 @@ class LLMEngine:
         req.finish_ts = time.time()
         self._stats["requests_finished"] += 1
         self._stream_ids.discard(req.request_id)
+        self._live.discard(req.request_id)
         finished.append(
             {
                 "request_id": req.request_id,
@@ -1065,34 +1071,40 @@ class LLMEngine:
     def abort_request(self, request_id: str) -> bool:
         """Drop a request (queued or active), freeing its slot — the
         client-disconnect path for streaming (reference: vLLM engine
-        abort_request). Safe to call after completion (returns False)."""
-        with TraceAnnotation("engine:abort_request") as span, self._locked(
-            span, request_id
-        ):
-            self._stream_ids.discard(request_id)
-            self._deltas.pop(request_id, None)
-            st = self._prefilling
-            if st is not None and st["req"].request_id == request_id:
-                # Mid-chunked-prefill abort: free the held slot + pages
-                # and drop the chunk state.
-                self._prefilling = None
-                self._free.append(st["slot"])
-                self._release_pages(st["req"])
-                self._stats["requests_aborted"] += 1
-                return True
-            for i, r in enumerate(self._queue):
-                if r.request_id == request_id:
-                    del self._queue[i]
+        abort_request). Safe to call after completion (returns False),
+        and then without a wait for `_lock`: every stream ends with this
+        call on the replica's event loop, and a step that ends a prefill
+        holds `_lock` for the prefill's length."""
+        with TraceAnnotation("engine:abort_request") as span:
+            if request_id not in self._live:
+                span.set_metadata(rid=request_id, lock_wait_ms=0.0)
+                return False
+            with self._locked(span, request_id):
+                self._live.discard(request_id)
+                self._stream_ids.discard(request_id)
+                self._deltas.pop(request_id, None)
+                st = self._prefilling
+                if st is not None and st["req"].request_id == request_id:
+                    # Mid-chunked-prefill abort: free the held slot + pages
+                    # and drop the chunk state.
+                    self._prefilling = None
+                    self._free.append(st["slot"])
+                    self._release_pages(st["req"])
                     self._stats["requests_aborted"] += 1
                     return True
-            for slot, r in list(self._active.items()):
-                if r.request_id == request_id:
-                    r.done = True
-                    self._vacate(slot)
-                    self._release_pages(r)
-                    self._stats["requests_aborted"] += 1
-                    return True
-        return False
+                for i, r in enumerate(self._queue):
+                    if r.request_id == request_id:
+                        del self._queue[i]
+                        self._stats["requests_aborted"] += 1
+                        return True
+                for slot, r in list(self._active.items()):
+                    if r.request_id == request_id:
+                        r.done = True
+                        self._vacate(slot)
+                        self._release_pages(r)
+                        self._stats["requests_aborted"] += 1
+                        return True
+            return False
 
     def slot_of(self, request_id: str) -> int | None:
         """The decode slot a request holds (decoding, or mid-prefill), or
@@ -1132,7 +1144,9 @@ class LLMEngine:
         `kv_write_kernel`) and the pool/slot occupancy."""
         with self._lock:
             self._fold_moe_counts()
-            out = dict(self._stats)
+            # The model's own counters (its cache's, where it keeps any)
+            # beside the engine's.
+            out = {**self._stats, **self.serving.counters()}
             out["pipeline_drains"] = dict(out["pipeline_drains"])
             # How often a decode step ran under the host's work on the
             # step before it.

@@ -47,6 +47,7 @@ from ray_tpu.llm.paged_kv import (
     _sample_tokens,
     _write_pages,
     init_paged_kv,
+    kv_cache_bytes,
 )
 from ray_tpu.models.moe import moe_ffn
 from ray_tpu.models.nemotron_h import (
@@ -264,7 +265,10 @@ class HybridServing:
     """What `LLMEngine` serves a `NemotronHConfig` through (see
     `paged_kv.LlamaServing` for the convention)."""
 
-    recurrent = True  # per-slot state: no rollback, so no speculation
+    no_speculation = (
+        "with recurrent blocks: a rejected draft would need the slot's "
+        "state rolled back, which is not written"
+    )
     logits_last_only = True  # prefill returns the last real token's logits
     # A prompt's last chunk is padded to the chunk's length (the program
     # takes the true length), so that chunks compile to one shape.
@@ -290,15 +294,21 @@ class HybridServing:
                    shardings=None):
         return init_hybrid_cache(self.cfg, num_pages, page_size, max_batch)
 
+    cache_bytes = staticmethod(kv_cache_bytes)
+
+    def counters(self) -> dict:
+        return {}
+
     def prefill(self, params, tokens, cache, pages, *, n_write_pages, slot,
-                length):
+                length, use_kernel=None):
         return prefill_program(self.cfg, n_write_pages, n_write_pages)(
             params, tokens, cache, pages, np.int32(0), np.int32(slot),
             np.int32(length),
         )
 
     def prefill_chunk(self, params, tokens, cache, pages, start, *,
-                      n_write_pages, chunk_pages, slot, length):
+                      n_write_pages, chunk_pages, slot, length,
+                      use_kernel=None):
         return prefill_program(self.cfg, n_write_pages, chunk_pages)(
             params, tokens, cache, pages, start, np.int32(slot),
             np.int32(length),

@@ -638,6 +638,13 @@ def paged_verify(
     return sampled, logits[:, 0], pool, accept, rej
 
 
+def kv_cache_bytes(cache) -> tuple[int, int]:
+    """(bytes of the K and V page pools, bytes of whatever else the
+    cache holds: per-slot state) of a cache with ``k`` and ``v``."""
+    pool = int(cache["k"].nbytes + cache["v"].nbytes)
+    return pool, sum(int(v.nbytes) for v in cache.values()) - pool
+
+
 class LlamaServing:
     """What `LLMEngine` serves a Llama-shaped model through: the page
     pool and the three programs above, under the calling convention the
@@ -647,7 +654,7 @@ class LlamaServing:
     a padded tail or a free slot writes cells nobody attends), so it is
     dropped here and the programs compile as they always did."""
 
-    recurrent = False  # no per-slot state beside the pages
+    no_speculation = None  # `paged_verify` accepts drafts
     logits_last_only = False  # prefill returns every position's logits
     fixed_chunks = False  # the last chunk of a prompt is as long as it is
     pairs_per_token = 0  # no expert blocks: its programs keep no record
@@ -680,15 +687,21 @@ class LlamaServing:
             return make()
         return jax.jit(make, out_shardings=shardings)()
 
+    cache_bytes = staticmethod(kv_cache_bytes)
+
+    def counters(self) -> dict:
+        return {}
+
     def prefill(self, params, tokens, pool, pages, *, n_write_pages,
-                slot=None, length=None):
+                slot=None, length=None, use_kernel=None):
         return paged_prefill(
             params, tokens, pool, pages, cfg=self.cfg,
             n_write_pages=n_write_pages,
         )
 
     def prefill_chunk(self, params, tokens, pool, pages, start, *,
-                      n_write_pages, chunk_pages, slot=None, length=None):
+                      n_write_pages, chunk_pages, slot=None, length=None,
+                      use_kernel=None):
         return paged_prefill_chunk(
             params, tokens, pool, pages, start, cfg=self.cfg,
             n_write_pages=n_write_pages, chunk_pages=chunk_pages,

@@ -239,6 +239,12 @@ TINY = {
                    "prefill_chunk": 32},
         "check_prompt": 40, "check_decode": 2,
     },
+    "latent": {
+        "cfg": {}, "reduced": {},
+        "engine": {"max_batch": 2, "max_seq": 128, "page_size": 16,
+                   "prefill_chunk": 32},
+        "check_prompt": 70, "check_decode": 2,
+    },
 }
 
 
@@ -254,7 +260,8 @@ def test_chip_smoke_phases_at_tiny_size_on_cpu(fake_chips, monkeypatch, chips):
     records = chip_smoke.run_phases(chips, seed=0, sizes=TINY)
     assert [r["phase"] for r in records] == (
         ["fsdp"] if chips == 4
-        else ["train", "serve", "engine_check", "hybrid_check"]
+        else ["train", "serve", "engine_check", "hybrid_check",
+              "latent_check"]
     )
     for run in records[0]["runs"]:
         assert run["losses"][-1] < run["losses"][0]
@@ -268,5 +275,7 @@ def test_chip_smoke_phases_at_tiny_size_on_cpu(fake_chips, monkeypatch, chips):
         assert max(records[2]["logit_max_abs_err"]) < 1e-4  # fp32 on CPU
         assert max(records[3]["logit_max_abs_err"]) < 2e-4
         assert records[3]["prefill_calls"] == 2  # 40 tokens, chunks of 32
+        assert max(records[4]["logit_max_abs_err"]) < 2e-4
+        assert records[4]["prefill_calls"] == 3  # 70 tokens, chunks of 32
     with pytest.raises(chip_smoke.SmokeFailure, match="platform 'cpu'"):
         chip_smoke.verify(records, chips)

@@ -313,6 +313,26 @@ def test_abort_releases_pages(params):
     assert engine.alloc.free_pages == engine.alloc.num_pages
 
 
+def test_abort_of_an_ended_request_does_not_wait_for_the_lock(params):
+    """Every stream ends with `abort_request` on the replica's event
+    loop; for a request that has ended it answers while a step holds
+    `_lock` (a step that ends a prefill holds it for the prefill's
+    length), and a live request's abort still takes it."""
+    engine = LLMEngine(CFG, max_batch=2, max_seq=64, params=params,
+                       kv="paged", page_size=16)
+    sampling = SamplingParams(max_tokens=3)
+    ended = engine.add_request(list(range(1, 20)), sampling, stream=True)
+    while engine.has_unfinished():
+        engine.step()
+    live = engine.add_request(list(range(1, 20)), sampling)
+    assert engine._live == {live}
+    with engine._lock:  # not re-entrant: a wait here would never end
+        assert engine.abort_request(ended) is False
+        assert engine.abort_request("never-added") is False
+    assert engine.abort_request(live)
+    assert not engine._live and not engine.has_unfinished()
+
+
 def test_on_logits_hands_over_every_programs_logits():
     """The public tap (`LLMEngine.on_logits`): each prefill's and each
     decode step's logits as the program returned them, no record for a

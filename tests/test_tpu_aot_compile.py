@@ -12,7 +12,9 @@ widths with a two-layer page pool of the benchmark's size, for what only
 the compiled text shows: that nothing copies, slices out or writes back
 a layer's pages or more. So are the hybrid model's (llm/hybrid_kv.py),
 at Nemotron-3-Nano's widths with 64 experts held, for the same of its
-pages, of a layer's per-slot state and of an expert stack.
+pages, of a layer's per-slot state and of an expert stack; and the
+latent-attention model's (llm/latent_kv.py), at openPangu-Ultra-MoE's
+widths with 16 experts held, for the same of its latent pool.
 """
 
 import math
@@ -337,3 +339,72 @@ def test_hybrid_program_moves_no_pages_state_or_expert_stack(
     assert temp < 64 * d * f * 2
     if program == "decode":
         assert temp < state * 4
+
+
+# ------------------------------------------------------ the latent programs
+@pytest.fixture(scope="module")
+def latent_programs(v5e):
+    """pangu-ultra-moe-serve1's own sizes (benchmarks/configs) at 2 of
+    its 5 layers, the dense one and an expert one, with the whole
+    configuration's pages: what `aot_fit_serve_family` lowers for the
+    whole configuration, and a whole 2,048-token bucket beside it."""
+    import json
+
+    from benchmarks import aot_fit_serve_family
+    from ray_tpu._private import chip
+
+    here = os.path.join(os.path.dirname(__file__), "..", "benchmarks")
+    with open(os.path.join(here, "configs", "pangu-ultra-moe-serve1.json")) as f:
+        whole = json.load(f)
+    conf = {**whole, "num_hidden_layers": 2}
+    traffic = {"fit_prefill_buckets": [2048, 8192]}
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(chip, "platform", lambda: "tpu")
+        lowered = aot_fit_serve_family.lowered_programs(
+            conf, traffic, next(iter(v5e.device_set))
+        )
+        return whole, {name: low.compile() for name, low in lowered.items()}
+
+
+@pytest.mark.parametrize(
+    "program", ["prefill_2048", "prefill_chunk_2048_of_8192", "decode"]
+)
+def test_latent_program_moves_no_pool_or_expert_stack_and_fits(
+    latent_programs, program
+):
+    """The latent pool is one donated array updated in place, in one
+    layout from argument to result (held 576 wide it was copied whole,
+    2.9 GiB, in every prefill program, and the decode kernel's page
+    copies were refused: 576 is 4.5 tiles of 128 lanes; the cells are
+    held 640 wide); the expert stacks are read where they lie (2,048 is
+    lane-aligned); the decode program holds the latent kernel; and the
+    program's temporaries beside the WHOLE configuration's weights and
+    pages stay under what a v5e offers a program."""
+    from benchmarks.models import pangu_ultra_moe as family
+
+    conf, programs = latent_programs
+    eng = conf["engine"]
+    d, f, cell = conf["hidden_size"], conf["moe_intermediate_size"], 640
+    layer_pages = (eng["num_pages"] + 1) * PAGE * cell
+    shapes = {
+        "pages": ((PAGE, cell), layer_pages),
+        "w_up": ((d, f), 16 * d * f),
+        "w_down": ((f, d), 16 * d * f),
+    }
+    compiled = programs[program]
+    text = compiled.as_text()
+    assert _hybrid_moves(text, shapes) == []
+    # The grouped matmul of a 2,048-row chunk (above `dense_expert_rows`);
+    # the latent kernel in the decode program.
+    assert ("ragged-dot" in text) == (program != "decode")
+    if program == "decode":
+        assert "tpu_custom_call" in text
+    memory = compiled.memory_analysis()
+    # Under the two layers' pool: no copy of it is among the temporaries.
+    assert memory.temp_size_in_bytes < 2 * layer_pages * 2
+    arguments = (
+        family.held_parameters(conf) * 2
+        + conf["num_hidden_layers"] * layer_pages * 2
+    )
+    assert arguments > 0.25 * 16 * 2**30  # the floor a new cell is held to
+    assert arguments + memory.temp_size_in_bytes < 15.75 * 2**30

@@ -16,7 +16,8 @@ weights from ``--seed``):
   its chunked prefill and decode with the plain reference; and a third
   does the same for a tiny latent-attention engine
   (``models/pangu_ultra_moe.py``: MLA over a latent page pool at
-  openPangu-Ultra-MoE's widths).
+  openPangu-Ultra-MoE's widths). Both also hold the kernel that reads
+  the touched experts against the einsum form over all of them.
 - ``--chips 4`` instead runs the sharded trainer (one worker, four
   chips, an ``{"fsdp": 4}`` mesh) against the same seed and batch on a
   one-device mesh, and no other phase.
@@ -416,6 +417,50 @@ def _check_against_reference(cfg, engine_kwargs: dict, sizes: dict, seed: int,
         "largest_route_slack": float(np.asarray(record["slack"]).max()),
         "paged_attn_kernel": bool(eng.paged_attn_kernel),
         "engine_stats": eng.stats(),
+        **_expert_kernel_check(cfg, eng.params, seed),
+    }
+
+
+def _expert_kernel_check(cfg, params, seed: int) -> dict:
+    """The touched-experts kernel (ops/pallas/expert_rows.py: what a
+    decode step's expert blocks run on the chip) against the einsum form
+    over every held expert, on the engine's first expert block at its
+    published widths: 32 rows whose pairs fall on half of the held
+    experts, so that the work list skips the other half. A tile that
+    reads the wrong expert or the wrong lanes shows here, on a machine
+    that has no benchmark."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu._private import chip
+    from ray_tpu.models import moe
+    from ray_tpu.ops.pallas.expert_rows import experts_on_rows
+
+    p = next(b for b in params["blocks"] if "router" in b)
+    first, held = cfg.experts_held or (0, cfg.num_experts)
+    rng = np.random.default_rng(seed)
+    rows = jax.random.normal(
+        jax.random.key(seed), (32, cfg.d_model), jnp.float32
+    ).astype(cfg.dtype)
+    routes = jnp.asarray(
+        first + 2 * rng.integers(0, held // 2, (32, 2)), jnp.int32
+    )
+    gates = jnp.asarray(rng.uniform(0.1, 0.5, (32, 2)), jnp.float32)
+    weight, load = moe.every_row_gates(cfg, routes, gates, None)
+    ids, count = moe.touched_first(load)
+    gated = cfg.expert_kind == "swiglu"
+    got = experts_on_rows(
+        rows, p["w_gate"] if gated else None, p["w_up"], p["w_down"],
+        weight, ids, count, chip.platform() != "tpu",
+    )
+    want = np.asarray(moe.every_row_einsum(cfg, rows, p, weight), np.float32)
+    got = np.asarray(got, np.float32)
+    return {
+        "expert_kernel_max_abs_err": float(np.abs(got - want).max()),
+        "expert_kernel_finite": bool(np.isfinite(got).all()),
+        "expert_kernel_scale": float(np.abs(want).max()),
+        "expert_kernel_touched": [int(count), held],
     }
 
 
@@ -714,6 +759,13 @@ def verify(records: list[dict], chips: int) -> dict:
         _require(max(check["logit_max_abs_err"]) <= LOGIT_TOLERANCE,
                  f"{what} engine logits differ from the plain reference by "
                  f"{check['logit_max_abs_err']} (prefill, then decode steps)")
+        _require(
+            check["expert_kernel_finite"]
+            and check["expert_kernel_max_abs_err"] <= LOGIT_TOLERANCE,
+            f"the {what} model's touched-experts kernel differs from the "
+            f"einsum form by {check['expert_kernel_max_abs_err']} at "
+            f"magnitude {check['expert_kernel_scale']}",
+        )
     return {"platform": "tpu", "kind": kinds.pop(), "count": chips}
 
 

@@ -26,6 +26,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from ray_tpu._private import chip
 from ray_tpu.models.llama import (
     LlamaConfig,
     Params,
@@ -33,6 +34,7 @@ from ray_tpu.models.llama import (
     init_params,
     param_logical_axes,
 )
+from ray_tpu.ops.pallas.expert_rows import experts_on_rows
 
 
 @dataclasses.dataclass(frozen=True)
@@ -172,20 +174,38 @@ def _expert_act(cfg, rows, w_gate, w_up, matmul):
     return jax.nn.silu(matmul(rows, w_gate)) * matmul(rows, w_up)
 
 
-def _experts_on_every_row(tokens, p, cfg, routes, gates, here):
-    """Every held expert on every row, as one batched matmul over the
-    experts, and each row's sum over ITS experts by a [n, held] matrix
-    of gates that is zero elsewhere. For few rows: every expert's
-    weights are read once whatever the routes, and nothing is sorted."""
+def every_row_gates(cfg, routes, gates, here):
+    """The every-row form's dispatch: ``weight`` float32 [n, held], a
+    row's gate for each held expert and zero where it did not choose it
+    (or the pair is not computed ``here``), and ``load`` int32 [held],
+    the pairs each held expert got."""
     first, e_here = cfg.experts_held or (0, cfg.num_experts)
+    chosen = routes - first  # [n, k]; outside [0, held) where absent
+    if here is not None:
+        chosen = jnp.where(here, chosen, e_here)
+    onehot = chosen[:, :, None] == jnp.arange(e_here)  # [n, k, held]
+    weight = (onehot * gates[:, :, None]).sum(1)
+    return weight, onehot.sum((0, 1)).astype(jnp.int32)
+
+
+def touched_first(load):
+    """The work list of ``ops/pallas/expert_rows.py`` from the pairs each
+    held expert got: the experts with any, packed to the front in
+    order with the last of them repeated behind, and their count."""
+    slot = jnp.arange(load.shape[0])
+    touched = load > 0
+    count = touched.sum().astype(jnp.int32)
+    place = jnp.cumsum(touched) - 1  # where a touched expert goes
+    ids = ((touched & (place == slot[:, None])) * slot).sum(1)
+    return jnp.where(slot < count, ids, ids.max()).astype(jnp.int32), count
+
+
+def every_row_einsum(cfg, rows, p, weight):
+    """Every held expert on every row as one batched matmul over the
+    experts, and each row's sum over them by ``weight``: every expert's
+    weights are read whatever the routes. What runs off the TPU, and
+    the oracle of the kernel that runs on it."""
     dt = cfg.dtype
-    with jax.named_scope("moe:dispatch"):
-        chosen = routes - first  # [n, k]; outside [0, held) where absent
-        if here is not None:
-            chosen = jnp.where(here, chosen, e_here)
-        onehot = chosen[:, :, None] == jnp.arange(e_here)  # [n, k, held]
-        weight = (onehot * gates[:, :, None]).sum(1)  # [n, held] float32
-        load = onehot.sum((0, 1)).astype(jnp.int32)
     with jax.named_scope("moe:experts"):
         batched = lambda a, w: jnp.einsum(  # noqa: E731
             "...nd,edf->enf", a, w.astype(dt)
@@ -193,13 +213,35 @@ def _experts_on_every_row(tokens, p, cfg, routes, gates, here):
         per_expert = lambda a, w: jnp.einsum(  # noqa: E731
             "enf,efd->end", a, w.astype(dt)
         )
-        act = _expert_act(cfg, tokens.astype(dt), p.get("w_gate"), p["w_up"],
+        act = _expert_act(cfg, rows.astype(dt), p.get("w_gate"), p["w_up"],
                           batched)
         outs = per_expert(act, p["w_down"])  # [held, n, d]
     with jax.named_scope("moe:combine"):
-        out = jnp.einsum(
+        return jnp.einsum(
             "end,ne->nd", outs.astype(jnp.float32), weight
         ).astype(dt)
+
+
+def _experts_on_every_row(tokens, p, cfg, routes, gates, here):
+    """Every held expert on every row, and each row's sum over ITS
+    experts by a [n, held] matrix of gates that is zero elsewhere. For
+    few rows: nothing is sorted. On a TPU one kernel does all of it and
+    reads the weights of the experts that got a row, no others
+    (``ops/pallas/expert_rows.py``: forward only, as is every caller of
+    this form); elsewhere `every_row_einsum`."""
+    dt = cfg.dtype
+    with jax.named_scope("moe:dispatch"):
+        weight, load = every_row_gates(cfg, routes, gates, here)
+    if chip.platform() != "tpu":
+        return every_row_einsum(cfg, tokens, p, weight), load
+    with jax.named_scope("moe:dispatch"):
+        ids, count = touched_first(load)
+    with jax.named_scope("moe:experts"):
+        gated = cfg.expert_kind == "swiglu"
+        out = experts_on_rows(
+            tokens.astype(dt), p["w_gate"].astype(dt) if gated else None,
+            p["w_up"].astype(dt), p["w_down"].astype(dt), weight, ids, count,
+        )
     return out, load
 
 

@@ -80,6 +80,10 @@ class PanguUltraMoEConfig:
     # PR 33), sorted / every row: 3.34 / 3.06 ms at 32 rows (a decode
     # step), 6.08 / 3.38 at 256, 9.96 / 21.55 at 2,048 (a prefill chunk,
     # where every row does 32 times the arithmetic its routes need).
+    # Since PR 34 the every-row form reads only the experts that got a
+    # row (`ops/pallas/expert_rows.py`): 12 / 16 of 16 touched take
+    # 1.55-1.62 / 2.05-2.13 ms at 32 rows and 1.62-1.66 / 2.12-2.17 at
+    # 256 (my chip run, PR 34), and a decode step here touches 4 to 5.
     dense_expert_rows: int = 256
     # A cache cell is held this many lanes wide, a multiple of:
     # `[c; kpe]` (576) padded with zeros to 640. A TPU lays a row of 576

@@ -277,5 +277,10 @@ def test_chip_smoke_phases_at_tiny_size_on_cpu(fake_chips, monkeypatch, chips):
         assert records[3]["prefill_calls"] == 2  # 40 tokens, chunks of 32
         assert max(records[4]["logit_max_abs_err"]) < 2e-4
         assert records[4]["prefill_calls"] == 3  # 70 tokens, chunks of 32
+        for check in records[3:]:
+            # The touched-experts kernel, interpreted, on half the experts.
+            assert check["expert_kernel_max_abs_err"] < 1e-4
+            count, held = check["expert_kernel_touched"]
+            assert 0 < count <= held // 2
     with pytest.raises(chip_smoke.SmokeFailure, match="platform 'cpu'"):
         chip_smoke.verify(records, chips)

@@ -14,7 +14,10 @@ a layer's pages or more. So are the hybrid model's (llm/hybrid_kv.py),
 at Nemotron-3-Nano's widths with 64 experts held, for the same of its
 pages, of a layer's per-slot state and of an expert stack; and the
 latent-attention model's (llm/latent_kv.py), at openPangu-Ultra-MoE's
-widths with 16 experts held, for the same of its latent pool.
+widths with 16 experts held, for the same of its latent pool. Both hold
+the kernel that reads the touched experts (ops/pallas/expert_rows.py)
+wherever `moe_ffn` takes its every-row form, and the compiler's grouped
+matmul above that.
 """
 
 import math
@@ -137,6 +140,54 @@ def test_kernel_compiles_for_v5e_at_llama3_8b_widths(v5e, case):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _expert_rows_args(on, n: int, held: int, d: int, f: int, gated: bool):
+    def on_chip(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=on)
+
+    return (
+        on_chip((n, d)), on_chip((held, d, f)) if gated else None,
+        on_chip((held, d, f)), on_chip((held, f, d)),
+        on_chip((n, held), jnp.float32), on_chip((held,), jnp.int32),
+        on_chip((), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize(
+    "case",
+    {
+        # Nemotron-3-Nano: 64 of 128 experts held 1920 wide, relu^2; a
+        # decode step's rows and a prefill chunk's.
+        "nemotron_32_rows": (32, 64, 2688, 1920, False),
+        "nemotron_512_rows": (512, 64, 2688, 1920, False),
+        # openPangu-Ultra-MoE: 16 of 256 held, SwiGLU.
+        "pangu_32_rows": (32, 16, 7680, 2048, True),
+    }.items(),
+    ids=lambda case: case[0],
+)
+def test_expert_rows_kernel_compiles_for_v5e_at_served_widths(v5e, case):
+    """The tile that `_width_tile` picks, the rows, the accumulator and
+    a row block's temporaries fit the VMEM the call asks for, and the
+    stacks are taken as they lie: nothing beside the arguments."""
+    from ray_tpu.ops.pallas.expert_rows import experts_on_rows
+
+    compiled = jax.jit(experts_on_rows).lower(
+        *_expert_rows_args(v5e, *case[1])
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+def _expert_kernel_calls(text: str) -> list[str]:
+    """A compiled program's calls of the touched-experts kernel: Mosaic
+    calls whose ``op_name`` lies under ``moe:experts``, which is where
+    the benchmark's reducers look for the experts' operation."""
+    return [
+        line for line in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line
+        and "moe:experts" in line
+    ]
+
+
 # ------------------------------------------------- the serving programs
 # At mistral7b-serve1's shapes (above) with prefill_chunk 2048; 2 of its
 # 6 layers, enough for a loop.
@@ -240,6 +291,8 @@ def test_serving_program_moves_no_layer_of_pages(v5e, case, monkeypatch):
     assert _pool_moves(text) == []
     if case.startswith("verify"):
         assert "tpu_custom_call" in text
+    # A dense model's programs hold nothing of the sparse-expert layer.
+    assert "moe:" not in text and _expert_kernel_calls(text) == []
 
 
 # ------------------------------------------------------ the hybrid programs
@@ -328,11 +381,14 @@ def test_hybrid_program_moves_no_pages_state_or_expert_stack(
     compiled = programs[program]
     text = compiled.as_text()
     assert _hybrid_moves(text, shapes) == []
-    # The grouped matmul above `dense_expert_rows`; the paged-attention
-    # and cell-write kernels in the decode program.
-    assert ("ragged-dot" in text) == (program == "prefill_chunk_1024_of_2048")
+    # The grouped matmul above `dense_expert_rows`, and up to it the
+    # kernel that reads the touched experts, once an expert block; the
+    # paged-attention and cell-write kernels in the decode program.
+    sorted_form = program == "prefill_chunk_1024_of_2048"
+    assert ("ragged-dot" in text) == sorted_form
+    assert len(_expert_kernel_calls(text)) == (0 if sorted_form else 2)
     if program == "decode":
-        assert "tpu_custom_call" in text
+        assert "paged_attention" in text and "write_kv_cells" in text
     # Nothing the size of an expert stack is made beside the arguments
     # (the decode program's temporaries are a few MB).
     temp = compiled.memory_analysis().temp_size_in_bytes
@@ -395,10 +451,12 @@ def test_latent_program_moves_no_pool_or_expert_stack_and_fits(
     text = compiled.as_text()
     assert _hybrid_moves(text, shapes) == []
     # The grouped matmul of a 2,048-row chunk (above `dense_expert_rows`);
-    # the latent kernel in the decode program.
+    # in the decode program the latent kernel and, in its one expert
+    # layer, the kernel that reads the touched experts.
     assert ("ragged-dot" in text) == (program != "decode")
+    assert len(_expert_kernel_calls(text)) == (program == "decode")
     if program == "decode":
-        assert "tpu_custom_call" in text
+        assert "latent_paged_attention" in text
     memory = compiled.memory_analysis()
     # Under the two layers' pool: no copy of it is among the temporaries.
     assert memory.temp_size_in_bytes < 2 * layer_pages * 2

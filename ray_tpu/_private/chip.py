@@ -3,12 +3,15 @@
 One rule: a worker whose lease holds ``TPU > 0`` on real chips runs JAX
 on the TPU; every other worker, the node daemon, the head and the
 driver stay on the CPU. The node applies the rule at lease grant
-(:func:`lease_platform`) and starts such a worker as a process of its
-own; that process calls :func:`hold_chip` before any of its code can
-create a backend, and exits when the lease ends, because a process that
-has opened the chip keeps it until it dies. Single-process scripts that
+(:func:`lease_platform`) and starts a worker whose lease holds chips,
+real or fake, as a process of its own; where they are real that process
+calls :func:`hold_chip` before any of its code can create a backend,
+and either way it exits when the lease ends, because a process that has
+opened the chip keeps it until it dies. Single-process scripts that
 take the chip themselves (``bench.py`` and friends) call
-:func:`hold_chip` the same way.
+:func:`hold_chip` the same way. Such a process also says how it became
+useful (:func:`watch_startup`): a span around the backend's creation
+and one a compile request.
 
 The module also keeps the one table of published per-chip peaks, keyed
 by the ``device_kind`` JAX reports.
@@ -19,6 +22,10 @@ from __future__ import annotations
 import dataclasses
 import os
 import sys
+import threading
+import time
+
+from ray_tpu.util import tracing
 
 # Set by hold_chip(): this process was promised a chip, so finding none
 # (or another platform) is an error, never a CPU run.
@@ -81,6 +88,95 @@ def hold_chip() -> None:
         platform()  # raises unless the backend that exists is the TPU
     elif "jax" in sys.modules:
         sys.modules["jax"].config.update("jax_platforms", "tpu")
+    watch_startup()
+
+
+# ------------------------------------------------- start-up, by phase
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_watching = False
+# What JAX has said of the compile request this thread is in the middle
+# of: its trace and lowering intervals and whether the cache answered.
+_request = threading.local()
+
+
+def _on_compile_phase(event: str, start: float, end: float, **kw) -> None:
+    """One ``compile:<fun_name>`` span a compile request. JAX reports a
+    request's parts in order on the thread that makes it: the trace
+    (an outer function's after, and around, those of the functions it
+    calls, so the last one stands), the lowering, whether the
+    persistent cache answered, and the backend compile (a cache read
+    where it did)."""
+    if event == _TRACE_EVENT:
+        _request.trace = (start, end)
+    elif event == _LOWER_EVENT:
+        _request.lower = (start, end)
+    elif event == _BACKEND_EVENT:
+        parts = vars(_request)
+        trace = parts.pop("trace", (start, start))
+        lower = parts.pop("lower", (start, start))
+        name = str(kw.get("fun_name", "?"))
+        if name.startswith("jit(") and name.endswith(")"):
+            name = name[4:-1]
+        begin = min(trace[0], lower[0], start)
+        tracing.emit_worker_span(
+            f"compile:{name}", begin, end - begin,
+            trace_s=trace[1] - trace[0], lower_s=lower[1] - lower[0],
+            backend_s=end - start, cache_hit=parts.pop("hit", False),
+        )
+
+
+def _on_cache_event(event: str, **kw) -> None:
+    if event == _CACHE_HIT_EVENT:
+        _request.hit = True
+
+
+def watch_startup() -> None:
+    """Make this process say how it became useful: a
+    ``startup:chip_open`` span around the creation of the JAX backend,
+    wherever that is triggered, and a ``compile:*`` span for every
+    compile request of its life. For a process whose lease holds
+    ``TPU > 0``: :func:`hold_chip` calls it where the chips are real,
+    ``worker_main`` where they are fake and the backend that opens is
+    the CPU's. Imports ``jax`` (which such a process exists to run)
+    but creates no backend."""
+    global _watching
+    if _watching:
+        return
+    _watching = True
+    import jax.monitoring
+    from jax._src import xla_bridge
+
+    jax.monitoring.register_event_time_span_listener(_on_compile_phase)
+    jax.monitoring.register_event_listener(_on_cache_event)
+    if xla_bridge.backends_are_initialized():
+        return
+    # JAX reports nothing of its own about opening a backend; every
+    # path to one (jax.devices, default_backend, the first array) goes
+    # through xla_bridge.backends(), which this wraps until a backend
+    # exists. A second thread that arrives meanwhile waits in
+    # backends_are_initialized() for the first one's lock.
+    unreported = threading.Lock()
+    create = xla_bridge.backends
+
+    def backends():
+        if xla_bridge.backends_are_initialized():
+            return create()
+        start = time.time()
+        found = create()
+        if unreported.acquire(blocking=False):
+            xla_bridge.backends = create
+            devices = sys.modules["jax"].devices()
+            tracing.emit_worker_span(
+                "startup:chip_open", start, time.time() - start,
+                platform=devices[0].platform,
+                device_kind=devices[0].device_kind, count=len(devices),
+            )
+        return found
+
+    xla_bridge.backends = backends
 
 
 def holds_backend() -> bool:

@@ -15,6 +15,8 @@ import atexit
 import functools
 import os
 import threading
+import time
+import uuid
 from typing import Any, Sequence
 
 from ray_tpu._private.ids import JobID
@@ -92,6 +94,10 @@ def init(
     """
     if _runtime.ready:
         raise RayTpuError("ray_tpu is already initialized")
+    called_at = time.time()
+    # (name, start, seconds, attributes) of init's parts, recorded as
+    # spans once there is a core worker to carry them.
+    phases: list[tuple] = []
     if _system_config:
         # Typed overrides of the config registry (reference:
         # ray.init(_system_config=...) threaded through the GCS); the
@@ -137,8 +143,10 @@ def init(
             # shutdown, so a journal there would cost a write per
             # mutation and never be replayable. CLI/daemon heads (whose
             # session dir persists) journal by default (daemon.py).
+            began = time.time()
             head = HeadService()
             head_addr = await head.start()
+            phases.append(("startup:head", began, time.time() - began, {}))
         else:
             head = None
             head_addr = address
@@ -160,6 +168,7 @@ def init(
             # attaches without adding a raylet; Ray Client drivers).
             node = None
         else:
+            began = time.time()
             total = detect_resources()
             if num_cpus is not None:
                 total["CPU"] = float(num_cpus)
@@ -168,7 +177,13 @@ def init(
                 head_addr, store_dir, resources=total, labels=labels
             )
             await node.start()
+            phases.append((
+                "startup:node", began, time.time() - began,
+                {"chips_found": int(total.get("TPU", 0)),
+                 "node_id": node.node_id},
+            ))
 
+        began = time.time()
         core = CoreWorker(
             mode="client" if client else "driver",
             head_addr=head_addr,
@@ -184,6 +199,7 @@ def init(
                 # print_worker_logs worker.py:2295 — the log monitor
                 # publishes, every driver prints).
                 await core.subscribe("logs", _print_worker_log)
+        phases.append(("startup:driver_core", began, time.time() - began, {}))
         return head, node, core, session, head_addr
 
     head, node, core, session, head_addr = _runtime.run(_bootstrap())
@@ -193,6 +209,18 @@ def init(
     _runtime.mode = "client" if client else "driver"
     _runtime.session = session
     atexit.register(shutdown)
+    from ray_tpu.util import tracing
+
+    trace_id, init_id = uuid.uuid4().hex[:16], uuid.uuid4().hex[:16]
+    for name, start, dur, attrs in phases:
+        tracing.record_span(
+            trace_id, uuid.uuid4().hex[:16], init_id, name, start, dur,
+            **attrs,
+        )
+    tracing.record_span(
+        trace_id, init_id, "", "startup:init", called_at,
+        time.time() - called_at, mode=_runtime.mode,
+    )
     # tpulint: allow(TPU703 reason=opt-in telemetry gate is deliberately env-only — unset means provably nothing leaves the machine, no config layer can flip it)
     if os.environ.get("RAY_TPU_USAGE_REPORT_URL"):
         # Opt-in usage POST (reference: usage_lib report on init) —
@@ -212,6 +240,13 @@ def init(
 def shutdown() -> None:
     if not _runtime.ready:
         return
+    if _runtime.mode in ("driver", "client"):
+        # What every process said of its start-up, while there is a
+        # head to ask: state.last_startup_report() answers from it
+        # once the cluster is gone.
+        from ray_tpu.util import state
+
+        state.keep_startup_report()
 
     async def _teardown():
         await _runtime.core.stop()

@@ -218,6 +218,10 @@ class CoreWorker:
         # GcsTaskManager). Bounded: observability must not OOM the worker.
         self._task_events: list[dict] = []
         self._event_flusher: asyncio.Task | None = None
+        # When the node acknowledged this worker process's registration
+        # (worker_main); cleared by the first task it is given, whose
+        # startup:first_task span begins there.
+        self.registered_at: float | None = None
 
         # Extension RPC handlers (collective groups, channels, ...):
         # name → async fn(conn=..., **kw). Checked before built-ins.
@@ -2441,6 +2445,18 @@ class CoreWorker:
         await self._exec_queue.put(("task", spec, actor_id, fut))
         return await fut
 
+    def _first_task_span(self, task: str, actor_id: str | None) -> None:
+        """``startup:first_task``: from this worker's registration to
+        the instant its first task's callable is entered, the fetch and
+        unpickling of the callable and its arguments inside it."""
+        from ray_tpu.util import tracing
+
+        start, self.registered_at = self.registered_at, None
+        tracing.emit_worker_span(
+            "startup:first_task", start, time.time() - start,
+            task=task[:80], actor=actor_id or "",
+        )
+
     async def _on_create_actor(
         self, conn, actor_id: str, fn_id: str, args, max_concurrency=None
     ):
@@ -2450,6 +2466,10 @@ class CoreWorker:
             cls = await self._fetch_function(fn_id)
             a, kw = await self._decode_args(args)
             loop = asyncio.get_running_loop()
+            if self.registered_at is not None:
+                self._first_task_span(
+                    getattr(cls, "__name__", fn_id), actor_id
+                )
             self._actor_instance = await loop.run_in_executor(
                 self._exec_pool, lambda: cls(*a, **kw)
             )
@@ -2601,6 +2621,10 @@ class CoreWorker:
                     fn = getattr(instance, method_name)
             else:
                 fn = await self._fetch_function(spec["fn_id"])
+            if self.registered_at is not None:
+                self._first_task_span(
+                    spec.get("name") or spec["fn_id"], actor_id
+                )
             if inspect.isasyncgenfunction(fn):
                 # Async generator: the object itself is the stream; it is
                 # driven on the loop by _stream_generator below.

@@ -66,6 +66,13 @@ class HeadService:
         # and `ray timeline`). Ring-bounded; per-task latest state capped.
         self.task_events: collections.deque = collections.deque(maxlen=20000)
         self.task_latest: collections.OrderedDict = collections.OrderedDict()
+        # Start-up by phase: the startup:* and compile:* spans, folded
+        # out of the stream above (where request traffic would push
+        # them out) into a table keyed by the worker they are about,
+        # or by the address of the driver or daemon that emitted them:
+        # key → {"spans": {slot: event}, "compiles": deque of events,
+        # "compile_totals": {...}}. Bounded by processes, newest kept.
+        self.startup: collections.OrderedDict = collections.OrderedDict()
         # worker addr → latest metrics snapshot {name: record}
         self.metrics: dict[str, dict] = {}
         # Per-train-job goodput accounting, folded from rank-0
@@ -2748,25 +2755,22 @@ class HeadService:
             # Spans live in the raw stream only, not the merged task
             # table (they would evict real task states). Rank-0 train
             # step spans additionally drive per-job goodput.
-            if ev.get("name") == "train:step" and ev.get("train_job"):
+            name = ev.get("name") or ""
+            if name.startswith(("startup:", "compile:")):
+                self._startup_event(ev, name)
+            elif name == "train:step" and ev.get("train_job"):
                 self._train_step_event(ev)
             # Ingress spans additionally drive the per-deployment
             # serve SLO ledger.
-            elif (
-                ev.get("name") == "serve:ingress"
-                and ev.get("deployment")
-            ):
+            elif name == "serve:ingress" and ev.get("deployment"):
                 self._serve_request_event(ev)
             # Per-node memory samples additionally drive the head
             # memory ledger.
-            elif ev.get("name") == "mem:sample" and ev.get("mem_node"):
+            elif name == "mem:sample" and ev.get("mem_node"):
                 self._mem_event(ev)
             # Capture reports additionally drive the MFU-decomposition
             # ledger and the profile regression sentinel.
-            elif (
-                ev.get("name") == "profile:step"
-                and ev.get("train_job")
-            ):
+            elif name == "profile:step" and ev.get("train_job"):
                 self._profile_step_event(ev)
             return
         if tid:
@@ -2784,6 +2788,55 @@ class HeadService:
             self.task_latest[tid] = merged
             while len(self.task_latest) > 20000:
                 self.task_latest.popitem(last=False)
+
+    # -------------------------------------------- start-up by phase
+    _STARTUP_PROCESSES = 1000
+    _STARTUP_COMPILES = 1024  # spans kept a process; totals count all
+
+    def _startup_event(self, ev: dict, name: str) -> None:
+        """Fold one startup:* or compile:* span into the row of the
+        process it is about. A start-up span replaces an earlier one of
+        its name (a pooled worker's next lease; a driver's next
+        ``startup:entry`` of the same ``entry``); compile spans queue
+        up, and their totals outlive the queue."""
+        key = ev.get("worker_id") or ev.get("worker") or "?"
+        row = self.startup.get(key)
+        if row is None:
+            row = self.startup[key] = {
+                "spans": {},
+                "compiles": collections.deque(maxlen=self._STARTUP_COMPILES),
+                "compile_totals": {
+                    "requests": 0, "cache_hits": 0, "trace_s": 0.0,
+                    "lower_s": 0.0, "backend_s": 0.0,
+                },
+            }
+            while len(self.startup) > self._STARTUP_PROCESSES:
+                self.startup.popitem(last=False)
+        if name.startswith("compile:"):
+            row["compiles"].append(ev)
+            totals = row["compile_totals"]
+            totals["requests"] += 1
+            totals["cache_hits"] += bool(ev.get("cache_hit"))
+            for part in ("trace_s", "lower_s", "backend_s"):
+                totals[part] += ev.get(part) or 0.0
+        else:
+            slot = f"{name}/{ev['entry']}" if "entry" in ev else name
+            row["spans"][slot] = ev
+
+    async def _on_startup_table(self, conn):
+        """Every process's start-up and compile spans (reader:
+        ``ray_tpu.util.state.startup_report``)."""
+        self._drain_folds()  # read-your-writes past the fold queue
+        return {
+            "processes": {
+                key: {
+                    "spans": row["spans"],
+                    "compiles": list(row["compiles"]),
+                    "compile_totals": row["compile_totals"],
+                }
+                for key, row in self.startup.items()
+            }
+        }
 
     async def _on_list_task_events(
         self,

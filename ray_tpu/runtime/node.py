@@ -23,6 +23,7 @@ import subprocess
 import sys
 import tempfile
 import time
+import uuid
 from pathlib import Path
 from typing import Any
 
@@ -361,10 +362,13 @@ def detect_resources() -> dict[str, float]:
 class Lease:
     __slots__ = (
         "lease_id", "worker", "resources", "actor", "bundle",
-        "bundle_resources", "granted_at",
+        "bundle_resources", "granted_at", "began_at",
     )
 
-    def __init__(self, lease_id: str, worker: dict, resources: dict, actor: bool):
+    def __init__(
+        self, lease_id: str, worker: dict, resources: dict, actor: bool,
+        began_at: float | None = None,
+    ):
         self.lease_id = lease_id
         self.worker = worker
         self.resources = resources
@@ -372,6 +376,9 @@ class Lease:
         self.bundle: tuple | None = None  # (pg_id, index) if bundle-backed
         self.bundle_resources: dict | None = None
         self.granted_at = time.monotonic()
+        # time.time() as the grant began (_grant_lease): after any wait
+        # in the queue, before the worker was found or started.
+        self.began_at = time.time() if began_at is None else began_at
 
 
 class NodeManager:
@@ -418,6 +425,10 @@ class NodeManager:
         self._real_chips = TPUAcceleratorManager().real_chips()
         # Killed chip-holding workers that may not have exited yet.
         self._dying_chip_procs: list[subprocess.Popen] = []
+        # startup:* spans of this node, sent to the head in batches over
+        # the connection it already holds (_emit_span).
+        self._spans: list[dict] = []
+        self._span_flusher: asyncio.Task | None = None
         self._tasks: list[asyncio.Task] = []
         # Worker log capture (reference: workers write to
         # /tmp/ray/session_*/logs and log_monitor.py:116 tails + streams
@@ -507,6 +518,9 @@ class NodeManager:
     async def stop(self):
         for t in self._tasks:
             t.cancel()
+        if self._span_flusher is not None:
+            self._span_flusher.cancel()
+        await self.flush_spans()
         if self.agent is not None:
             await self.agent.stop()
         for w in self.workers.values():
@@ -542,10 +556,12 @@ class NodeManager:
         runtime_env: dict | None = None,
         ehash: str | None = None,
         platform: str = "cpu",
+        chips: float = 0,
     ) -> str:
         """Start a worker process. ``platform`` is what
-        chip.lease_platform decided for the lease it is started for;
-        pooled workers are always "cpu"."""
+        chip.lease_platform decided for the lease it is started for and
+        ``chips`` the TPU that lease holds; pooled workers are always
+        "cpu" and 0."""
         worker_id = WorkerID.random().hex()
         if ehash is None:
             ehash = env_hash(runtime_env)
@@ -594,6 +610,10 @@ class NodeManager:
         built = _built_envs.get(ehash, {})
         python_exe = built.get("python") or sys.executable
         argv = [python_exe, "-m", "ray_tpu.runtime.worker_main"]
+        if chips:
+            from ray_tpu.runtime.worker_main import CHIP_LEASE_ARG
+
+            argv.append(CHIP_LEASE_ARG)
         # py_modules: local dirs importable in the worker (single-host or
         # shared-FS; the reference ships them via the runtime_env agent).
         for mod_path in renv.get("py_modules", ()):
@@ -655,6 +675,7 @@ class NodeManager:
             # them).
             self.log_dir.mkdir(parents=True, exist_ok=True)
             log_path = self.log_dir / f"worker-{worker_id}.log"
+            spawned_at = time.time()
             with open(log_path, "ab") as log_f:
                 proc = subprocess.Popen(
                     argv,
@@ -676,6 +697,8 @@ class NodeManager:
             "runtime_env": runtime_env,
             "log_path": str(log_path),
             "platform": platform,
+            "chips": chips,
+            "spawned_at": spawned_at,
         }
         return worker_id
 
@@ -833,16 +856,19 @@ class NodeManager:
         self._bump_resources()
 
     async def _get_worker(
-        self, runtime_env: dict | None = None, platform: str = "cpu"
+        self,
+        runtime_env: dict | None = None,
+        platform: str = "cpu",
+        chips: float = 0,
     ) -> str:
         """Pop an idle worker of the matching runtime_env, else wait for
         a spawning one; only spawn a fresh process when demand exceeds
         the number already spawning (avoids a thundering herd of Python
-        interpreters on cold bursts). A "tpu" lease never takes a pooled
-        worker: see _get_chip_worker."""
+        interpreters on cold bursts). A lease that holds ``chips`` never
+        takes a pooled worker: see _get_chip_worker."""
         ehash = env_hash(runtime_env)
         bucket = self.idle[ehash]
-        if bucket and platform != "tpu":
+        if bucket and not chips:
             return bucket.pop()
         if runtime_env and (
             runtime_env.get("pip")
@@ -863,8 +889,10 @@ class NodeManager:
             await asyncio.get_running_loop().run_in_executor(
                 None, build_runtime_env, runtime_env, ehash
             )
-        if platform == "tpu":
-            return await self._get_chip_worker(runtime_env, ehash)
+        if chips:
+            return await self._get_chip_worker(
+                runtime_env, ehash, platform, chips
+            )
         n_spawning = sum(
             1
             for w in self.workers.values()
@@ -879,23 +907,62 @@ class NodeManager:
         return await asyncio.wait_for(fut, SPAWN_TIMEOUT_S)
 
     async def _get_chip_worker(
-        self, runtime_env: dict | None, ehash: str
+        self, runtime_env: dict | None, ehash: str, platform: str,
+        chips: float,
     ) -> str:
-        """A process of its own for a lease of real chips. A pooled
+        """A process of its own for a lease that holds chips. A pooled
         worker may already have created a CPU backend and cannot switch;
         and a process that has opened the chip keeps it until it dies,
         so the worker is started for this lease, killed when the lease
         ends (_on_return_lease), and not started before the chip
-        workers this node killed earlier are gone."""
+        workers this node killed earlier are gone. Fake chips
+        (``platform`` "cpu") take the same road, so that what a test or
+        a rehearsal starts and measures is what the chip's lease does."""
+        wait_began = time.time()
         dying, self._dying_chip_procs = self._dying_chip_procs, []
         for proc in dying:
             await asyncio.to_thread(proc.wait)
+        waited = time.time() - wait_began
         worker_id = self._spawn_worker(
-            runtime_env, ehash=ehash, platform="tpu"
+            runtime_env, ehash=ehash, platform=platform, chips=chips
+        )
+        self._emit_span(
+            "startup:chip_free_wait", wait_began, waited,
+            worker_id=worker_id, procs=len(dying),
         )
         fut = asyncio.get_running_loop().create_future()
         self.workers[worker_id]["waiter"] = fut
         return await asyncio.wait_for(fut, SPAWN_TIMEOUT_S)
+
+    def _emit_span(self, name: str, start: float, dur: float, **attrs):
+        """A completed ``startup:*`` span of this node, in the shape of
+        ``tracing.record_span``'s events. A node daemon has no core
+        worker of its own to carry spans, and an embedded one would
+        have to find its driver's: both send them over the head
+        connection the node holds, a fraction of a second's worth at a
+        time."""
+        span_id = uuid.uuid4().hex[:16]
+        self._spans.append({
+            "task_id": f"span:{span_id}", "name": name, "state": "SPAN",
+            "ts": start, "dur": dur, "worker": self.addr,
+            "trace_id": uuid.uuid4().hex[:16], "span_id": span_id,
+            "parent_id": "", "node_id": self.node_id, **attrs,
+        })
+        if self._span_flusher is None or self._span_flusher.done():
+            self._span_flusher = asyncio.ensure_future(self._flush_soon())
+
+    async def _flush_soon(self):
+        await asyncio.sleep(0.2)
+        await self.flush_spans()
+
+    async def flush_spans(self):
+        if not self._spans or self.head is None:
+            return
+        batch, self._spans = self._spans, []
+        try:
+            await self.head.call("add_task_events", events=batch)
+        except rpc.RpcError:
+            pass  # a head that is away: telemetry, not worth a retry
 
     async def _grant_lease(
         self,
@@ -910,18 +977,21 @@ class NodeManager:
         bundle). The worker's JAX platform follows from ``held``."""
         from ray_tpu._private import chip
 
-        platform = chip.lease_platform(
-            resources if held is None else held, self._real_chips
-        )
+        began_at = time.time()
+        held = resources if held is None else held
+        platform = chip.lease_platform(held, self._real_chips)
         self._acquire(resources)
         try:
-            worker_id = await self._get_worker(runtime_env, platform)
+            worker_id = await self._get_worker(
+                runtime_env, platform, held.get("TPU", 0)
+            )
             w = self.workers[worker_id]
             w["state"] = "leased"
             self._next_lease += 1
             lease_id = f"{self.node_id[:8]}-{self._next_lease}"
             self.leases[lease_id] = Lease(
-                lease_id, {**w, "worker_id": worker_id}, resources, actor
+                lease_id, {**w, "worker_id": worker_id}, resources, actor,
+                began_at,
             )
             return {
                 "ok": True,
@@ -1450,6 +1520,13 @@ class NodeManager:
         w.update(conn=conn, addr=addr, pid=pid, state="idle")
         conn.state["worker_id"] = worker_id
         self._offer_worker(worker_id)
+        spawned_at = w.pop("spawned_at", None)
+        if spawned_at is not None:
+            self._emit_span(
+                "startup:spawn", spawned_at, time.time() - spawned_at,
+                worker_id=worker_id, pid=pid,
+                platform=w.get("platform", "cpu"),
+            )
         return {"ok": True, "node_id": self.node_id}
 
     def _offer_worker(self, worker_id: str):
@@ -1478,6 +1555,30 @@ class NodeManager:
         bundle: tuple | list | None = None,
         runtime_env: dict | None = None,
     ):
+        received_at = time.time()
+        grant = await self._lease_worker(
+            resources, actor, bundle, runtime_env
+        )
+        lease = self.leases.get(grant.get("lease_id"))
+        if lease is not None:
+            held = lease.bundle_resources or lease.resources
+            self._emit_span(
+                "startup:lease", received_at, time.time() - received_at,
+                lease_id=lease.lease_id,
+                worker_id=lease.worker["worker_id"],
+                platform=lease.worker.get("platform", "cpu"),
+                tpu=held.get("TPU", 0),
+                queued_s=lease.began_at - received_at,
+            )
+        return grant
+
+    async def _lease_worker(
+        self,
+        resources: dict | None,
+        actor: bool,
+        bundle: tuple | list | None,
+        runtime_env: dict | None,
+    ) -> dict:
         """Grant a worker lease (reference: NodeManager::
         HandleRequestWorkerLease node_manager.h:290). Infeasible requests
         fail fast; unavailable ones queue until resources free up. With
@@ -1554,7 +1655,7 @@ class NodeManager:
         self._credit_bundle(lease)
         worker_id = lease.worker["worker_id"]
         w = self.workers.get(worker_id)
-        if w and w.get("platform") == "tpu":
+        if w and w.get("chips"):
             # It has opened the chip and keeps it while it lives: the
             # next chip lease gets a new process once this one is gone.
             self._kill_worker(worker_id)
@@ -1692,7 +1793,7 @@ class NodeManager:
         proc = w.get("proc")
         if proc and proc.poll() is None:
             proc.kill()
-            if w.get("platform") == "tpu":
+            if w.get("chips"):
                 self._dying_chip_procs.append(proc)
         core = w.get("core")
         if core is not None:  # inproc worker: stop its rpc endpoints

@@ -15,6 +15,7 @@ from ray_tpu.serve.config import DeploymentConfig
 from ray_tpu.serve.controller import ServeController
 from ray_tpu.serve.deployment import Application, Deployment
 from ray_tpu.serve.handle import CONTROLLER_NAME, DeploymentHandle
+from ray_tpu.util import tracing
 
 logger = logging.getLogger("ray_tpu.serve")
 
@@ -72,6 +73,7 @@ def run(
     """Deploy an application graph and return the ingress handle."""
     if not isinstance(app, Application):
         raise TypeError("serve.run takes an Application (deployment.bind())")
+    called_at = time.time()
     controller = _get_or_create_controller()
 
     # Flatten the bind graph; de-dupe deployments by name; replace child
@@ -122,6 +124,10 @@ def run(
             time.sleep(0.1)
         else:
             raise TimeoutError(f"application {name!r} not healthy in time")
+    tracing.emit_span(
+        "startup:entry", called_at, time.time() - called_at,
+        kind="serve", entry=name, blocking=_blocking,
+    )
     return DeploymentHandle(app.deployment.name, name)
 
 
@@ -206,6 +212,7 @@ def start_http(host: str = "127.0.0.1", port: int = 0) -> int:
     here a single proxy actor is enough for one host.)"""
     from ray_tpu.serve.proxy import ProxyActor
 
+    called_at = time.time()
     try:
         proxy = ray_tpu.get_actor(PROXY_NAME)
     except ValueError:
@@ -219,7 +226,11 @@ def start_http(host: str = "127.0.0.1", port: int = 0) -> int:
             )
             .remote(host, port)
         )
-    return ray_tpu.get(proxy.get_port.remote())
+    bound = ray_tpu.get(proxy.get_port.remote())
+    tracing.emit_span(
+        "startup:http", called_at, time.time() - called_at, port=bound
+    )
+    return bound
 
 
 def start_grpc(

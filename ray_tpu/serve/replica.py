@@ -16,8 +16,10 @@ import asyncio
 import contextlib
 import contextvars
 import inspect
+import time
 
 from ray_tpu.serve.context import RequestContext, set_request_context
+from ray_tpu.util import tracing
 
 
 def _replica_scope(deployment_name: str, request_context: dict | None):
@@ -31,8 +33,6 @@ def _replica_scope(deployment_name: str, request_context: dict | None):
     trace = ctx.pop("trace", None)
     if not trace:
         return contextlib.nullcontext(), ctx
-    from ray_tpu.util import tracing
-
     return (
         tracing.linked_span(
             "serve:replica",
@@ -59,7 +59,12 @@ class ReplicaActor:
         self._num_served = 0
         self._draining = False
         if isinstance(user_callable, type):
+            began = time.time()
             self._callable = user_callable(*init_args, **init_kwargs)
+            tracing.emit_worker_span(
+                "startup:replica_init", began, time.time() - began,
+                deployment=deployment_name,
+            )
         else:
             self._callable = user_callable
         if user_config is not None:

@@ -29,6 +29,7 @@ import ray_tpu
 from ray_tpu.exceptions import RayTpuError
 from ray_tpu.placement import placement_group, remove_placement_group
 from ray_tpu.train.session import TrainContext, _set_context
+from ray_tpu.util import tracing
 
 logger = logging.getLogger("ray_tpu.train")
 
@@ -334,8 +335,6 @@ class TrainWorker:
         return True
 
     def run_loop(self, train_loop: Callable, use_context_arg: bool):
-        from ray_tpu.util import tracing
-
         _set_context(self.ctx)
         # Anchor for the first implicit step (report() with no explicit
         # step_span) and for the attempt span below.
@@ -776,6 +775,7 @@ class JaxTrainer:
         n_workers: int | None = None,
     ) -> Result:
         n = n_workers or self.scaling.num_workers
+        called_at = time.time()
         pg = placement_group(
             [self.scaling.bundle() for _ in range(n)],
             strategy=self.scaling.placement_strategy,
@@ -809,6 +809,11 @@ class JaxTrainer:
                     for i, w in enumerate(workers)
                 ],
                 timeout=60,
+            )
+            tracing.emit_span(
+                "startup:entry", called_at, time.time() - called_at,
+                kind="train", entry=self.run_config.name,
+                attempt=attempt, workers=n,
             )
             import inspect
 

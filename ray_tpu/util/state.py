@@ -13,7 +13,7 @@ from typing import Any
 from ray_tpu import api as core_api
 
 
-def _call_head(method: str, **kw) -> dict:
+def _call_head(method: str, _timeout: float | None = None, **kw) -> dict:
     rt = core_api._runtime
     if rt.core is None:
         raise RuntimeError("ray_tpu.init() has not been called")
@@ -21,7 +21,7 @@ def _call_head(method: str, **kw) -> dict:
     async def go():
         return await rt.core.head.call(method, **kw)
 
-    return rt.run(go())
+    return rt.run(go(), _timeout)
 
 
 def list_worker_logs() -> list[dict]:
@@ -237,20 +237,146 @@ def verify_checkpoints(run: str | None = None) -> dict:
     return _call_head("ckpt_verify", run=run)
 
 
-_SPAN_ARG_KEYS = (
-    "trace_id", "span_id", "parent_id", "group", "verb", "backend",
-    "bytes", "dtype", "bus_bytes_per_s", "train_job", "train_attempt",
-    "train_rank", "train_step", "phases", "mfu",
-    "comm_exposed_s", "comm_overlapped_s", "degraded_frac",
-    # serve request-path spans: the ids/attrs that make one request's
-    # span tree reconstructable from the chrome trace
-    "app", "deployment", "route", "status", "ttft_s", "request_id",
-    "streamed", "items", "tokens", "batch_size", "occupancy",
-    "queue_s", "sample_rate",
-    # compiled-program profiler spans (profile:step / profile:capture)
-    "profile_sig", "profile_shares", "profile_step_s", "profile_steps",
-    "profile_dominant", "path",
+# What an event is made of; everything else on a SPAN event is one of
+# the span's attributes.
+_EVENT_FIELDS = frozenset(
+    ("task_id", "name", "state", "ts", "dur", "worker")
 )
+
+
+def _span_attrs(ev: dict) -> dict:
+    return {k: v for k, v in ev.items() if k not in _EVENT_FIELDS}
+
+
+# ------------------------------------------------- start-up by phase
+_STARTUP_COLUMNS = (
+    "lease", "chip_free_wait", "spawn", "boot", "first_task", "chip_open",
+    "replica_init",
+)
+_last_startup_report: dict | None = None
+
+
+def _span_row(ev: dict) -> dict:
+    row = _span_attrs(ev)
+    for k in ("trace_id", "span_id", "parent_id"):
+        row.pop(k, None)
+    return {"ts": ev["ts"], "dur": ev["dur"], **row}
+
+
+def _host(addr: str) -> str:
+    return str(addr).rsplit(":", 1)[0]
+
+
+def startup_report(timeout: float | None = None) -> dict:
+    """How every process became useful, from the head's table of
+    ``startup:*`` and ``compile:*`` spans: ``driver`` holds this
+    driver's own phases (``startup:init`` and its parts, each
+    ``startup:entry``, ``startup:http``); ``workers`` one row a worker
+    process, oldest first, with its node, pid, platform and lease, each
+    phase's seconds (``phases``), the spans themselves with both ends on
+    ``time.time()`` (``spans``), and its compiles: requests, cache
+    hits and seconds by part (``compiles``), and the newest of them as
+    spans (``compile_spans``). A worker that died and was replaced
+    keeps its row beside its successor's, so the rows are also the
+    record of how long a recovery took. ``same_host`` is False on a row
+    whose host is not this driver's: the time BETWEEN spans of two
+    processes means something only on one host's clock."""
+    processes = _call_head("startup_table", _timeout=timeout)["processes"]
+    own_addr = core_api._runtime.core.addr
+    report: dict = {"driver": None, "workers": []}
+    for key, proc in processes.items():
+        spans = proc["spans"]  # by name, or name/entry for an entry
+        if key == own_addr:
+            report["driver"] = {
+                "addr": key,
+                "spans": {k: _span_row(ev) for k, ev in spans.items()},
+            }
+        if ":" in key:  # a driver or a daemon, by address
+            continue
+        any_ev = next(iter(spans.values()), None) or proc["compiles"][0]
+        said = {}  # what the spans say of the process itself
+        for ev in spans.values():
+            for k in ("node_id", "pid", "platform", "lease_id", "tpu"):
+                if k in ev:
+                    said[k] = ev[k]
+        host = _host(any_ev["worker"])
+        report["workers"].append({
+            "worker_id": key,
+            **said,
+            "host": host,
+            "same_host": host == _host(own_addr),
+            "phases": {
+                name.split(":", 1)[1]: ev["dur"]
+                for name, ev in spans.items()
+            },
+            "spans": {k: _span_row(ev) for k, ev in spans.items()},
+            "compiles": proc["compile_totals"],
+            "compile_spans": [
+                {"name": ev["name"], **_span_row(ev)}
+                for ev in proc["compiles"]
+            ],
+        })
+    return report
+
+
+def keep_startup_report() -> None:
+    """Take one last :func:`startup_report` while the head can still be
+    asked (``ray_tpu.shutdown()`` calls this first) and keep it for
+    :func:`last_startup_report`. Best effort: a cluster that is
+    already half gone keeps the report before it."""
+    global _last_startup_report
+    rt = core_api._runtime
+    try:
+        rt.run(rt.core.flush_observability(), timeout=5)
+        if rt.node is not None:
+            rt.run(rt.node.flush_spans(), timeout=5)
+        _last_startup_report = startup_report(timeout=5)
+    # tpulint: allow(broad-except reason=shutdown is best-effort by contract and this is telemetry: a dead head or a stopped loop must not keep the process from exiting)
+    except Exception:  # noqa: BLE001
+        pass
+
+
+def last_startup_report() -> dict | None:
+    """The :func:`startup_report` taken as this process's last cluster
+    shut down; None before any has. For code that runs after the
+    cluster is gone (the benchmark's reducers)."""
+    return _last_startup_report
+
+
+def startup_table(report: dict | None = None) -> str:
+    """:func:`startup_report` as text: one line for the driver, one a
+    worker, seconds by phase; ``*`` marks a row from another host."""
+
+    def secs(v):
+        return "-" if v is None else f"{v:.2f}"
+
+    report = startup_report() if report is None else report
+    lines = []
+    driver = report.get("driver")
+    if driver:
+        lines.append("driver " + driver["addr"] + "  " + "  ".join(
+            f"{name.split(':', 1)[1]} {secs(sp['dur'])}"
+            for name, sp in driver["spans"].items()
+        ))
+    header = ("worker", "node", "pid", "platform", "tpu", "lease_id",
+              *_STARTUP_COLUMNS, "compiles", "hits", "trace+lower",
+              "backend")
+    rows = [header]
+    for w in report["workers"]:
+        c = w["compiles"]
+        rows.append((
+            w["worker_id"][:8] + ("" if w["same_host"] else "*"),
+            str(w.get("node_id", "?"))[:8], str(w.get("pid", "?")),
+            w.get("platform", "?"), f"{w.get('tpu', 0):g}",
+            w.get("lease_id", "-"),
+            *(secs(w["phases"].get(col)) for col in _STARTUP_COLUMNS),
+            str(c["requests"]), str(c["cache_hits"]),
+            secs(c["trace_s"] + c["lower_s"]), secs(c["backend_s"]),
+        ))
+    widths = [max(len(r[i]) for r in rows) for i in range(len(header))]
+    lines += ["  ".join(v.ljust(n) for v, n in zip(r, widths)).rstrip()
+              for r in rows]
+    return "\n".join(lines)
 
 
 def timeline(path: str | None = None) -> list[dict] | str:
@@ -272,9 +398,7 @@ def timeline(path: str | None = None) -> list[dict] | str:
                     # Separate track per worker so span slices don't
                     # overlap the task slices they ran inside.
                     "tid": "spans",
-                    "args": {
-                        k: ev[k] for k in _SPAN_ARG_KEYS if k in ev
-                    },
+                    "args": _span_attrs(ev),
                 }
             )
             continue

@@ -227,6 +227,19 @@ def emit_span(name: str, start: float, dur: float, **attrs) -> None:
     )
 
 
+def emit_worker_span(name: str, start: float, dur: float, **attrs) -> None:
+    """:func:`emit_span` for a span of this process's own life (its
+    ``startup:*`` phases, its ``compile:*`` requests), with the worker
+    id that the head's start-up table is keyed by. A driver has none
+    and is keyed by its address; a process with no runtime records
+    nothing, like every span."""
+    import ray_tpu.api as api
+
+    core = api._runtime.core
+    worker_id = (core.worker_id if core is not None else None) or ""
+    emit_span(name, start, dur, worker_id=worker_id, **attrs)
+
+
 async def carry_context(coro, ctx: tuple[str, str]):
     """Await `coro` with `ctx` installed as its trace context. The
     collective dispatch layer hops from the caller's thread onto the

@@ -173,19 +173,27 @@ def test_chip_lease_gets_its_own_process_and_the_process_ends_with_it(
         ray_tpu.get(open_chip.remote())
 
 
-def test_fake_chip_lease_stays_on_a_pooled_cpu_worker(fake_chips):
+def test_fake_chip_lease_stays_on_the_cpu_in_a_process_of_its_own(fake_chips):
     ray_tpu.init(num_cpus=2)
 
     @ray_tpu.remote(num_tpus=1)
     def where():
         import jax
 
-        return os.environ["JAX_PLATFORMS"], jax.default_backend()
+        return os.environ["JAX_PLATFORMS"], jax.default_backend(), os.getpid()
 
-    assert ray_tpu.get(where.remote()) == ("cpu", "cpu")
+    @ray_tpu.remote
+    def pooled_pid():
+        return os.getpid()
+
+    *ran_on, pid = ray_tpu.get(where.remote())
+    assert ran_on == ["cpu", "cpu"]
     rt = ray_tpu.api._runtime
     workers = rt.run(rt.core.node.call("list_workers"))["workers"]
     assert {w["platform"] for w in workers} == {"cpu"}
+    # Scheduled like a lease of real chips: started for it, ended with it.
+    assert ray_tpu.get(pooled_pid.remote()) != pid
+    assert _pid_gone(pid), "the fake-chip worker outlived its lease"
 
 
 def test_engine_stats_say_where_it_ran():

@@ -38,7 +38,14 @@ max_batch × max_seq slots would cost HBM whatever the sequences' lengths:
   mask — static shapes, gather and attention fused, the layout folded
   into the einsums (_gather_page_attention).
 - **Prefill** computes K/V with the normal dense program and scatters
-  them into freshly-allocated pages.
+  them, whole pages at a time, into freshly-allocated pages (XLA's
+  scatter keeps the pool's layout when it writes whole pages). Kernel
+  path: a chunk's queries attend the context so far by
+  ``prefill_attention`` (ops/pallas/prefill_attention.py), whose keys
+  are the request's own pages gathered as they lie (``paged_prefill``:
+  the prompt's fresh cells): no scores in HBM and no key past ``start +
+  C`` read. XLA path: dense float32 scores, over the whole gathered
+  table under a mask in ``paged_prefill_chunk``.
 - **Prefix sharing**: full pages whose token prefix hashes equal an
   existing page's are refcounted and reused instead of re-written —
   identical prompt heads across requests occupy one set of pages
@@ -248,21 +255,36 @@ def _gather_page_attention(q, k_pool, v_pool, page_index, mask, cfg):
     return attn.reshape(b, q_len, cfg.n_heads, dh)
 
 
+def _page_cells(a, page_size: int, cfg):
+    """Keys or values ``[1, S, Hkv, Dh]`` as the ``S / page_size`` pages
+    they fill, ``[n, Hkv, P, Dh]`` in ``cfg.dtype``: the pool's cells."""
+    return a.astype(cfg.dtype).reshape(
+        -1, page_size, cfg.n_kv_heads, cfg.head_dim
+    ).transpose(0, 2, 1, 3)
+
+
 def _write_pages(k_pages, v_pages, k, v, page_ids, cfg):
     """A prompt's (or a chunk's) keys and values, ``[1, S, Hkv, Dh]``
     with S = len(page_ids) * page_size, scattered into the pages
     ``page_ids`` of the flat pool as ``[n, Hkv, P, Dh]`` cells."""
-    n, page_size = page_ids.shape[0], k_pages.shape[2]
-
-    def cells(a):
-        return a.astype(cfg.dtype).reshape(
-            n, page_size, cfg.n_kv_heads, cfg.head_dim
-        ).transpose(0, 2, 1, 3)
-
+    page_size = k_pages.shape[2]
     return (
-        k_pages.at[page_ids].set(cells(k)),
-        v_pages.at[page_ids].set(cells(v)),
+        k_pages.at[page_ids].set(_page_cells(k, page_size, cfg)),
+        v_pages.at[page_ids].set(_page_cells(v, page_size, cfg)),
     )
+
+
+def _prefill_kernel_attention(q, k_cells, v_cells, start):
+    """A chunk's attention by the prefill kernel (a bare TPU; interpreted
+    elsewhere): q ``[1, C, H, Dh]`` at ``start ..`` over the context's
+    pages in order, ``[n, Hkv, P, Dh]``. No scores in HBM, no key past
+    ``start + C`` read (ops/pallas/prefill_attention.py)."""
+    from ray_tpu.ops.pallas.prefill_attention import prefill_attention
+
+    return prefill_attention(
+        q[0], k_cells, v_cells, start,
+        interpret=chip.platform() != "tpu",
+    )[None]
 
 
 def _decode_geometry(block_tables, positions, kk_w: int, page_size: int):
@@ -394,7 +416,7 @@ def _scan_layers(body, x, params, pool: PagedKV):
 
 @partial(
     jax.jit,
-    static_argnames=("cfg", "n_write_pages"),
+    static_argnames=("cfg", "n_write_pages", "use_kernel"),
     donate_argnames=("pool",),
 )
 def paged_prefill(
@@ -404,6 +426,7 @@ def paged_prefill(
     pages: jnp.ndarray,  # [n_write_pages] int32 page ids for this prompt
     cfg: LlamaConfig,
     n_write_pages: int,
+    use_kernel: bool = False,
 ):
     """Dense prompt pass; K/V scattered into `pages` of the pool.
 
@@ -411,6 +434,8 @@ def paged_prefill(
     covers the WHOLE padded prompt including shared-prefix pages: their
     content is rewritten with byte-identical values (K/V at position i
     depend only on tokens <= i), so sharing needs no scatter mask.
+    ``use_kernel``: attend by the prefill kernel, the prompt's fresh
+    cells its keys (``start`` 0), in place of dense ``[H, S, S]`` scores.
     Returns (logits [1, S_pad, V] fp32, pool).
     """
     params = matmul_weights(params, cfg)
@@ -423,7 +448,13 @@ def paged_prefill(
         q, k, v = _project_qkv(x, p, cfg)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
-        attn = causal_attention(q, k, v)
+        if use_kernel:
+            attn = _prefill_kernel_attention(
+                q, _page_cells(k, page_size, cfg),
+                _page_cells(v, page_size, cfg), jnp.int32(0),
+            )
+        else:
+            attn = causal_attention(q, k, v)
         x = x + attn.reshape(x.shape) @ p["wo"]
         x = _mlp(x, p, cfg)
         # [1, S, Hkv, Dh] → [n_pages, Hkv, P, Dh] scatter at page ids.
@@ -440,7 +471,7 @@ def paged_prefill(
 
 @partial(
     jax.jit,
-    static_argnames=("cfg", "n_write_pages", "chunk_pages"),
+    static_argnames=("cfg", "n_write_pages", "chunk_pages", "use_kernel"),
     donate_argnames=("pool",),
 )
 def paged_prefill_chunk(
@@ -452,6 +483,7 @@ def paged_prefill_chunk(
     cfg: LlamaConfig,
     n_write_pages: int,
     chunk_pages: int,
+    use_kernel: bool = False,
 ):
     """One prefill CHUNK: compute K/V for ``tokens`` at positions
     ``start .. start+C-1``, scatter them into the chunk's slice of
@@ -464,6 +496,11 @@ def paged_prefill_chunk(
     vLLM's chunked prefill, which ray.llm buys via engine_kwargs).
     ``start`` must be page-aligned; K/V of a position depend only on
     tokens <= it, so chunking is mathematically exact.
+
+    ``use_kernel``: the request's pages are gathered as they lie (33 MB
+    a layer at 8,192 tokens) and the prefill kernel attends the context
+    so far; without it, dense float32 scores over the whole table under
+    a mask (``_gather_page_attention``).
 
     Returns (logits [1, C, V] fp32, pool).
     """
@@ -487,9 +524,15 @@ def paged_prefill_chunk(
         k_pages, v_pages = _write_pages(
             k_pages, v_pages, k, v, base + chunk_slice, cfg
         )
-        attn = _gather_page_attention(
-            q, k_pages, v_pages, base + pages[None, :], mask, cfg
-        )
+        if use_kernel:
+            attn = _prefill_kernel_attention(
+                q, jnp.take(k_pages, base + pages, axis=0, mode="clip"),
+                jnp.take(v_pages, base + pages, axis=0, mode="clip"), start,
+            )
+        else:
+            attn = _gather_page_attention(
+                q, k_pages, v_pages, base + pages[None, :], mask, cfg
+            )
         x = x + attn.reshape(1, c, -1) @ p["wo"]
         x = _mlp(x, p, cfg)
         return x, k_pages, v_pages
@@ -652,7 +695,7 @@ class LlamaServing:
     slot a prompt is for, its true length and which slots are decoding;
     these programs need none of that (pages hold all their state, and
     a padded tail or a free slot writes cells nobody attends), so it is
-    dropped here and the programs compile as they always did."""
+    dropped here but for the count of what prefill attends."""
 
     no_speculation = None  # `paged_verify` accepts drafts
     logits_last_only = False  # prefill returns every position's logits
@@ -661,6 +704,7 @@ class LlamaServing:
 
     def __init__(self, cfg: LlamaConfig):
         self.cfg = cfg
+        self._prefill_pairs = 0
 
     def init_weights(self, key):
         return _init_weights(key, cfg=self.cfg)
@@ -690,21 +734,34 @@ class LlamaServing:
     cache_bytes = staticmethod(kv_cache_bytes)
 
     def counters(self) -> dict:
-        return {}
+        # The causal (query, key) pairs the prefill calls' arithmetic
+        # needed, summed over layers (heads not among them): a prompt's
+        # own tokens against what lies at or before each, whatever the
+        # padding, the table's width or the path computed besides.
+        return {"prefill_attn_pairs": self._prefill_pairs}
+
+    def _count_pairs(self, start: int, width: int, length) -> None:
+        n = width if length is None else max(min(width, length - start), 0)
+        self._prefill_pairs += self.cfg.n_layers * (
+            n * start + n * (n + 1) // 2
+        )
 
     def prefill(self, params, tokens, pool, pages, *, n_write_pages,
-                slot=None, length=None, use_kernel=None):
+                slot=None, length=None, use_kernel=False):
+        self._count_pairs(0, tokens.shape[1], length)
         return paged_prefill(
             params, tokens, pool, pages, cfg=self.cfg,
-            n_write_pages=n_write_pages,
+            n_write_pages=n_write_pages, use_kernel=use_kernel,
         )
 
     def prefill_chunk(self, params, tokens, pool, pages, start, *,
                       n_write_pages, chunk_pages, slot=None, length=None,
-                      use_kernel=None):
+                      use_kernel=False):
+        self._count_pairs(int(start), tokens.shape[1], length)
         return paged_prefill_chunk(
             params, tokens, pool, pages, start, cfg=self.cfg,
             n_write_pages=n_write_pages, chunk_pages=chunk_pages,
+            use_kernel=use_kernel,
         )
 
     def decode(self, params, tokens, pool, block_tables, positions,

@@ -86,6 +86,26 @@ def _paged(q, k_pool, v_pool, tables, positions):
     )
 
 
+def _prefill_attn(q, k_pages, v_pages, start):
+    from ray_tpu.ops.pallas.prefill_attention import prefill_attention
+
+    return prefill_attention(q, k_pages, v_pages, start)
+
+
+def _prefill_attn_args(on, queries: int, keys: int):
+    # mistral7b-serve1's prefill: a chunk's (or a prompt's) queries over
+    # the request's pages, gathered (or fresh) in the pool's cell layout.
+    pages = jax.ShapeDtypeStruct(
+        (keys // PAGE, HKV, PAGE, DH), jnp.bfloat16, sharding=on
+    )
+    return (
+        jax.ShapeDtypeStruct((queries, H, DH), jnp.bfloat16, sharding=on),
+        pages,
+        pages,
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=on),
+    )
+
+
 def _flash_args(on):
     # chip_smoke's train phase: batch 2, seq 4096.
     def bf16(*shape):
@@ -123,6 +143,8 @@ def _paged_serve_args(on, k: int):
     [
         "flash_fwd", "flash_fwd_bwd", "paged_k1", "paged_k4",
         "paged_serve_k1", "paged_serve_k5",
+        "prefill_attn_2048_of_8192", "prefill_attn_2048_of_8448",
+        "prefill_attn_1024", "prefill_attn_64",
     ],
 )
 def test_kernel_compiles_for_v5e_at_llama3_8b_widths(v5e, case):
@@ -135,6 +157,18 @@ def test_kernel_compiles_for_v5e_at_llama3_8b_widths(v5e, case):
         "paged_k4": (_paged, _paged_args(v5e, 4)),
         "paged_serve_k1": (_paged, _paged_serve_args(v5e, 1)),
         "paged_serve_k5": (_paged, _paged_serve_args(v5e, 5)),
+        # A chunk over the 8,192 bucket's table and over max_seq's (132
+        # pages: no power of two), a whole prompt, one page.
+        "prefill_attn_2048_of_8192": (
+            _prefill_attn, _prefill_attn_args(v5e, 2048, 8192)
+        ),
+        "prefill_attn_2048_of_8448": (
+            _prefill_attn, _prefill_attn_args(v5e, 2048, MAX_PAGES * PAGE)
+        ),
+        "prefill_attn_1024": (
+            _prefill_attn, _prefill_attn_args(v5e, 1024, 1024)
+        ),
+        "prefill_attn_64": (_prefill_attn, _prefill_attn_args(v5e, 64, 64)),
     }[case]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
@@ -260,20 +294,32 @@ def _serving_program(case: str, on):
             shaped(jax.eval_shape(partial(jax.random.key, 0))),
             cfg=cfg, use_kernel=True, stochastic=False,
         )
-    if case == "prefill_1024":
+    # "<program>" as a `tp` mesh or a CPU compiles it, "<program>_kernel"
+    # as LLMEngine does on a bare TPU.
+    use_kernel = case.endswith("_kernel")
+    if case.startswith("prefill_1024"):
         return paged_kv.paged_prefill.lower(
             params, i32(1, 1024), pool, i32(1024 // PAGE), cfg=cfg,
-            n_write_pages=1024 // PAGE,
+            n_write_pages=1024 // PAGE, use_kernel=use_kernel,
         )
     return paged_kv.paged_prefill_chunk.lower(
         params, i32(1, 2048), pool, i32(8192 // PAGE), i32(), cfg=cfg,
         n_write_pages=8192 // PAGE, chunk_pages=2048 // PAGE,
+        use_kernel=use_kernel,
     )
+
+
+_DENSE_SCORES = re.compile(
+    r"=\s+\(?[^=]*f32\[[\d,]*(2048,8192|2048,128,64|1024,1024)\]"
+)
 
 
 @pytest.mark.parametrize(
     "case",
-    ["verify_k1", "verify_k4", "prefill_1024", "prefill_chunk_2048_of_8192"],
+    [
+        "verify_k1", "verify_k4", "prefill_1024", "prefill_chunk_2048_of_8192",
+        "prefill_1024_kernel", "prefill_chunk_2048_of_8192_kernel",
+    ],
 )
 def test_serving_program_moves_no_layer_of_pages(v5e, case, monkeypatch):
     """The pool is one buffer in one layout from argument to result
@@ -287,10 +333,21 @@ def test_serving_program_moves_no_layer_of_pages(v5e, case, monkeypatch):
     # Mosaic kernels and their interpreter: here it is compiled for the
     # chip, from a CPU host.
     monkeypatch.setattr(chip, "platform", lambda: "tpu")
-    text = _serving_program(case, v5e).compile().as_text()
+    compiled = _serving_program(case, v5e).compile()
+    text = compiled.as_text()
     assert _pool_moves(text) == []
     if case.startswith("verify"):
         assert "tpu_custom_call" in text
+    if case.startswith("prefill"):
+        # The prefill kernel (ops/pallas/prefill_attention.py) where the
+        # engine asks for it, and then no float32 scores over the table
+        # (`[.., 2048, 8192]`, or `[.., 2048, 128, 64]` by pages) or the
+        # prompt: the chunk program's 3.24 GB of temporaries were those.
+        kernel = case.endswith("_kernel")
+        assert ("tpu_custom_call" in text) == kernel
+        assert bool(_DENSE_SCORES.search(text)) == (not kernel)
+        if kernel:
+            assert compiled.memory_analysis().temp_size_in_bytes < 2**30
     # A dense model's programs hold nothing of the sparse-expert layer.
     assert "moe:" not in text and _expert_kernel_calls(text) == []
 

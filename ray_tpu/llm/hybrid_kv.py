@@ -1,6 +1,14 @@
 """Serving programs for a model whose blocks differ in kind
-(``models/nemotron_h.py``): pages for the blocks that attend, per-slot
-recurrent state for the blocks that carry one.
+(``models/nemotron_h.py``, ``models/granite_hybrid.py``): pages for the
+blocks that attend, per-slot recurrent state for the blocks that carry
+one.
+
+A block here is one SUBLAYER, a letter of ``cfg.pattern``: a mixer or
+an expert FFN behind its own norm and a residual add. Nemotron-H's
+layers are one each; a Granite 4.0-H layer is two (its mixer, then
+``E``) and multiplies each sublayer's output, the embedding, the
+attention scores and the logits by numbers of its config, which the
+programs skip where they are 1 (``_embed``, ``_residual``, ``_head``).
 
 The cache is ONE donated tree with two kinds of per-sequence state:
 
@@ -25,8 +33,12 @@ inputs, their expert pairs are left out, and the logits returned are
 the last real token's alone. ``hybrid_decode`` advances every slot by
 one token and takes the slots that are decoding: a slot that is free or
 mid-prefill keeps its state. Attention is `paged_kv.py`'s own
-definitions (projections, page writes, the gather path and the Pallas
-kernel path), the expert mixer is ``moe_ffn``.
+definitions (projections, page writes, the gather path and the two
+Pallas kernel paths: a prefill program compiled with ``use_kernel``
+attends a table of more than 1,024 keys by
+``ops/pallas/prefill_attention.py`` over the request's pages as they
+lie, with no scores over the table in HBM), the expert mixer is
+``moe_ffn``.
 """
 
 from __future__ import annotations
@@ -38,11 +50,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu._private import chip
 from ray_tpu.llm.paged_kv import (
     _decode_attention,
     _decode_geometry,
     _flat_pool,
     _gather_page_attention,
+    _prefill_kernel_attention,
     _project_qkv,
     _sample_tokens,
     _write_pages,
@@ -59,6 +73,18 @@ from ray_tpu.models.nemotron_h import (
 from ray_tpu.ops.norms import rms_norm
 
 HybridCache = dict[str, jnp.ndarray]
+
+# A prefill program compiled with ``use_kernel`` attends by the prefill
+# kernel where its table holds more keys than this, and by dense scores
+# under a mask up to it: there the scores are small (32 heads x 512
+# queries x 1,024 keys in float32: 67 MB) and take what the kernel's
+# launch and the gather of its pages take (ops/pallas/
+# prefill_attention.py has the chip's readings), and every program of
+# `nemotron-reason-32` (tables of 64 to 1,024 tokens) lowers as it did
+# before the kernel came: in two pairs on the chip that cell read 0.9%
+# and 0.2% lower with the kernel in them (PR 39). Above it the scores
+# grow with the table (4.3 GB at 2,048 queries x 16,384 keys).
+_DENSE_ATTENTION_KEYS = 1024
 
 
 def init_hybrid_cache(
@@ -77,13 +103,27 @@ def init_hybrid_cache(
     return cache
 
 
+def _embed(params, tokens, cfg):
+    x = params["tok_emb"][tokens]
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
+    return x
+
+
+def _residual(x, out, cfg):
+    """``x + residual_multiplier * out``: a sublayer's output added on."""
+    if cfg.residual_multiplier != 1.0:
+        out = out * cfg.residual_multiplier
+    return x + out
+
+
 def _experts(x, p, cfg, rows_live, record):
     """An expert block on x [B, S, d], and its counters onto ``record``."""
     out, aux = moe_ffn(rms_norm(x, p["norm"]), p, cfg, rows_live=rows_live)
     record["routes"].append(aux["routes"])
     record["pairs_here"].append(aux["expert_load"].sum())
     record["experts_touched"].append((aux["expert_load"] > 0).sum())
-    return x + out
+    return _residual(x, out, cfg)
 
 
 def _record(record):
@@ -110,9 +150,19 @@ def _carried(cache, k_pages, v_pages, ssm, conv) -> HybridCache:
     }
 
 
-def _head(x, params):
+def _head(x, params, cfg=None):
+    """Final norm and the head; ``cfg`` where the model ties the head to
+    the embedding or divides its logits (`llm/latent_kv.py`'s does
+    neither and passes none)."""
     x = rms_norm(x, params["final_norm"])
-    return (x @ params["lm_head"]).astype(jnp.float32)
+    if cfg is not None and cfg.tie_word_embeddings:
+        logits = jnp.einsum("...d,vd->...v", x, params["tok_emb"])
+    else:
+        logits = x @ params["lm_head"]
+    logits = logits.astype(jnp.float32)
+    if cfg is not None and cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
 
 
 def _hybrid_prefill(
@@ -126,11 +176,16 @@ def _hybrid_prefill(
     cfg: NemotronHConfig,
     n_write_pages: int,
     chunk_pages: int,
+    use_kernel: bool,
 ):
     """A prompt (``start`` 0, ``chunk_pages == n_write_pages``) or one
-    chunk of it. Returns (logits [1, 1, V] float32 of position
-    ``length - 1`` — meaningful in the chunk that holds it —, cache,
-    record)."""
+    chunk of it. ``use_kernel``: where the table holds more than
+    ``_DENSE_ATTENTION_KEYS`` keys the chunk's queries attend the
+    context's pages, gathered as they lie, by the prefill kernel;
+    otherwise dense float32 scores over the whole table under a mask
+    (at a 256-page table and a 2,048-token chunk 4.3 GB of them).
+    Returns (logits [1, 1, V] float32 of position ``length - 1`` —
+    meaningful in the chunk that holds it —, cache, record)."""
     c = tokens.shape[1]
     page_size = cache["k"].shape[3]
     num_pages = cache["k"].shape[1]
@@ -140,10 +195,12 @@ def _hybrid_prefill(
     chunk_slice = jax.lax.dynamic_slice(
         pages, [start // page_size], [chunk_pages]
     )
-    mask = jnp.arange(window)[None, None, :] > pos[:, :, None]
+    by_kernel = use_kernel and window > _DENSE_ATTENTION_KEYS
+    if not by_kernel:
+        mask = jnp.arange(window)[None, None, :] > pos[:, :, None]
     k_pages, v_pages = _flat_pool(cache)
     ssm, conv = cache["ssm"], cache["conv"]
-    x = params["tok_emb"][tokens]
+    x = _embed(params, tokens, cfg)
     record = {"routes": [], "pairs_here": [], "experts_touched": []}
     n_attn = n_mamba = 0
     for kind, p in zip(cfg.pattern, params["blocks"], strict=True):
@@ -158,7 +215,7 @@ def _hybrid_prefill(
             with jax.named_scope("ssm:scan"):
                 ssm = ssm.at[n_mamba, slot].set(ssm_end)
                 conv = conv.at[n_mamba, slot].set(conv_end)
-            x = x + out[None]
+            x = _residual(x, out[None], cfg)
             n_mamba += 1
         elif kind == "E":
             x = _experts(x, p, cfg, live, record)
@@ -168,27 +225,45 @@ def _hybrid_prefill(
             k_pages, v_pages = _write_pages(
                 k_pages, v_pages, k, v, base + chunk_slice, cfg
             )
-            attn = _gather_page_attention(
-                q, k_pages, v_pages, base + pages[None, :], mask, cfg
-            )
-            x = x + attn.reshape(1, c, -1) @ p["wo"]
+            if by_kernel:
+                attn = _prefill_kernel_attention(
+                    q, jnp.take(k_pages, base + pages, axis=0, mode="clip"),
+                    jnp.take(v_pages, base + pages, axis=0, mode="clip"),
+                    start, cfg.attention_scale,
+                )
+            else:
+                attn = _gather_page_attention(
+                    q, k_pages, v_pages, base + pages[None, :], mask, cfg,
+                    cfg.attention_scale,
+                )
+            x = _residual(x, attn.reshape(1, c, -1) @ p["wo"], cfg)
             n_attn += 1
     last = jax.lax.dynamic_slice_in_dim(x, length - 1 - start, 1, axis=1)
     carried = _carried(cache, k_pages, v_pages, ssm, conv)
-    return _head(last, params), carried, _record(record)
+    return _head(last, params, cfg), carried, _record(record)
 
 
-@functools.lru_cache(maxsize=None)
-def prefill_program(cfg: NemotronHConfig, n_write_pages: int, chunk_pages: int):
+def prefill_program(cfg: NemotronHConfig, n_write_pages: int,
+                    chunk_pages: int, use_kernel: bool | None = None):
     """`_hybrid_prefill` jitted for one shape, under a name that says
     which (``hybrid_prefill_<chunk pages>_of_<table pages>``): a trace
     then names each bucket's program, and an instruction name is looked
-    up in the text of the program it ran in."""
+    up in the text of the program it ran in. ``use_kernel`` None: as an
+    engine on this platform says without being told (a bare TPU: the
+    kernel), which is what a caller that lowers the programs for their
+    text or their fit wants."""
+    if use_kernel is None:
+        use_kernel = chip.platform() == "tpu"
+    return _prefill_program(cfg, n_write_pages, chunk_pages, bool(use_kernel))
 
+
+@functools.lru_cache(maxsize=None)
+def _prefill_program(cfg, n_write_pages: int, chunk_pages: int,
+                     use_kernel: bool):
     def program(params, tokens, cache, pages, start, slot, length):
         return _hybrid_prefill(
             params, tokens, cache, pages, start, slot, length, cfg,
-            n_write_pages, chunk_pages,
+            n_write_pages, chunk_pages, use_kernel,
         )
 
     program.__name__ = f"hybrid_prefill_{chunk_pages}_of_{n_write_pages}"
@@ -224,7 +299,7 @@ def hybrid_decode(
     geometry = _decode_geometry(block_tables, positions, 1, page_size)
     k_pages, v_pages = _flat_pool(cache)
     ssm, conv = cache["ssm"], cache["conv"]
-    x = params["tok_emb"][tokens]  # [B, 1, d]
+    x = _embed(params, tokens, cfg)  # [B, 1, d]
     record = {"routes": [], "pairs_here": [], "experts_touched": []}
     n_attn = n_mamba = 0
     for kind, p in zip(cfg.pattern, params["blocks"], strict=True):
@@ -242,7 +317,7 @@ def hybrid_decode(
                 conv = conv.at[n_mamba].set(
                     jnp.where(active[:, None, None], new_conv, old_conv)
                 )
-            x = x + out[:, None]
+            x = _residual(x, out[:, None], cfg)
             n_mamba += 1
         elif kind == "E":
             x = _experts(x, p, cfg, active, record)
@@ -251,18 +326,19 @@ def hybrid_decode(
             attn, k_pages, v_pages = _decode_attention(
                 q, k.astype(cfg.dtype), v.astype(cfg.dtype), k_pages,
                 v_pages, n_attn * num_pages, geometry, positions, cfg,
-                use_kernel,
+                use_kernel, cfg.attention_scale,
             )
-            x = x + attn.reshape(b, 1, -1) @ p["wo"]
+            x = _residual(x, attn.reshape(b, 1, -1) @ p["wo"], cfg)
             n_attn += 1
-    logits = _head(x, params)  # [B, 1, V]
+    logits = _head(x, params, cfg)  # [B, 1, V]
     sampled = _sample_tokens(logits, temperature, rng_key)
     carried = _carried(cache, k_pages, v_pages, ssm, conv)
     return sampled, logits[:, 0], carried, _record(record)
 
 
 class HybridServing:
-    """What `LLMEngine` serves a `NemotronHConfig` through (see
+    """What `LLMEngine` serves a `NemotronHConfig` (or a family that
+    subclasses it and brings its own ``init_weights``) through (see
     `paged_kv.LlamaServing` for the convention)."""
 
     no_speculation = (
@@ -274,12 +350,14 @@ class HybridServing:
     # takes the true length), so that chunks compile to one shape.
     fixed_chunks = True
 
-    def __init__(self, cfg: NemotronHConfig):
+    def __init__(self, cfg: NemotronHConfig, init_weights=init_params):
         self.cfg = cfg
         self.pairs_per_token = cfg.top_k * cfg.count("E")
+        self._init_weights = init_weights
+        self._prefill_programs = self._scan_tokens = self._prefill_pairs = 0
 
     def init_weights(self, key):
-        return init_params(key, self.cfg)
+        return self._init_weights(key, self.cfg)
 
     def logical_axes(self):
         raise NotImplementedError(
@@ -297,19 +375,41 @@ class HybridServing:
     cache_bytes = staticmethod(kv_cache_bytes)
 
     def counters(self) -> dict:
-        return {}
+        # Over the prefill programs run: how many; the live tokens the
+        # chunked scan took, summed over the Mamba blocks; the causal
+        # (query, key) pairs the attention's arithmetic needed, summed
+        # over the attention blocks (`LlamaServing` counts the same).
+        return {
+            "prefill_programs": self._prefill_programs,
+            "ssm_scan_tokens": self._scan_tokens,
+            "prefill_attn_pairs": self._prefill_pairs,
+        }
+
+    def _count(self, start: int, width: int, length: int) -> None:
+        n = max(min(width, length - start), 0)
+        self._prefill_programs += 1
+        self._scan_tokens += self.cfg.count("M") * n
+        self._prefill_pairs += self.cfg.count("*") * (
+            n * start + n * (n + 1) // 2
+        )
 
     def prefill(self, params, tokens, cache, pages, *, n_write_pages, slot,
-                length, use_kernel=None):
-        return prefill_program(self.cfg, n_write_pages, n_write_pages)(
+                length, use_kernel=False):
+        self._count(0, tokens.shape[1], length)
+        return prefill_program(
+            self.cfg, n_write_pages, n_write_pages, use_kernel
+        )(
             params, tokens, cache, pages, np.int32(0), np.int32(slot),
             np.int32(length),
         )
 
     def prefill_chunk(self, params, tokens, cache, pages, start, *,
                       n_write_pages, chunk_pages, slot, length,
-                      use_kernel=None):
-        return prefill_program(self.cfg, n_write_pages, chunk_pages)(
+                      use_kernel=False):
+        self._count(int(start), tokens.shape[1], length)
+        return prefill_program(
+            self.cfg, n_write_pages, chunk_pages, use_kernel
+        )(
             params, tokens, cache, pages, start, np.int32(slot),
             np.int32(length),
         )

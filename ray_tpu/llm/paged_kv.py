@@ -213,13 +213,15 @@ def _mlp(x, p, cfg):
     return x + (gate * up) @ p["w_down"]
 
 
-def _gather_page_attention(q, k_pool, v_pool, page_index, mask, cfg):
+def _gather_page_attention(q, k_pool, v_pool, page_index, mask, cfg,
+                           scale=None):
     """Dense masked attention over gathered pool pages — the XLA
     fallback shared by decode/verify and chunked prefill (one body: a
     numerics change here changes every gather-path caller at once).
 
     q: [B, Q, H, Dh]; page_index: [B, n_pages] int32 (>= 0);
-    mask: [B, Q, window] bool, True = hidden. Returns [B, Q, H, Dh].
+    mask: [B, Q, window] bool, True = hidden; ``scale`` multiplies the
+    scores (``Dh**-0.5`` where None). Returns [B, Q, H, Dh].
     """
     b, q_len = q.shape[0], q.shape[1]
     hkv = cfg.n_kv_heads
@@ -235,7 +237,8 @@ def _gather_page_attention(q, k_pool, v_pool, page_index, mask, cfg):
     kk = jnp.take(k_pool, page_index, axis=0)
     vv = jnp.take(v_pool, page_index, axis=0)
     qg = q.reshape(b, q_len, hkv, n_rep, dh)
-    scale = dh**-0.5
+    if scale is None:
+        scale = dh**-0.5
     logits = (
         jnp.einsum(
             "bqgrd,bngpd->bgrqnp", qg, kk,
@@ -274,7 +277,7 @@ def _write_pages(k_pages, v_pages, k, v, page_ids, cfg):
     )
 
 
-def _prefill_kernel_attention(q, k_cells, v_cells, start):
+def _prefill_kernel_attention(q, k_cells, v_cells, start, scale=None):
     """A chunk's attention by the prefill kernel (a bare TPU; interpreted
     elsewhere): q ``[1, C, H, Dh]`` at ``start ..`` over the context's
     pages in order, ``[n, Hkv, P, Dh]``. No scores in HBM, no key past
@@ -283,7 +286,7 @@ def _prefill_kernel_attention(q, k_cells, v_cells, start):
 
     return prefill_attention(
         q[0], k_cells, v_cells, start,
-        interpret=chip.platform() != "tpu",
+        interpret=chip.platform() != "tpu", scale=scale,
     )[None]
 
 
@@ -314,13 +317,14 @@ def _decode_geometry(block_tables, positions, kk_w: int, page_size: int):
 
 
 def _decode_attention(q, k, v, k_pages, v_pages, base, geometry, positions,
-                      cfg, use_kernel: bool):
+                      cfg, use_kernel: bool, scale=None):
     """A decode step's attention for one layer whose pages start at
     ``base`` of the flat pool: write all K cells per slot (drafts may
     span a page boundary — each position indexes its own physical
     page), then attend. q [B, K, H, Dh], k and v [B, K, Hkv, Dh] in
     ``cfg.dtype``. The write follows the attention's path, so that one
-    party fixes the pool's layout (module docstring). Returns (attn
+    party fixes the pool's layout (module docstring). ``scale``
+    multiplies the scores (``Dh**-0.5`` where None). Returns (attn
     [B, K, H, Dh], k_pages, v_pages)."""
     _, mask, write_pages, off_of, tables = geometry
     b, kk_w = q.shape[:2]
@@ -343,7 +347,7 @@ def _decode_attention(q, k, v, k_pages, v_pages, base, geometry, positions,
         )
         attn = paged_attention(
             q, k_pages, v_pages, base + tables, positions,
-            n_kv_heads=cfg.n_kv_heads, interpret=interpret,
+            n_kv_heads=cfg.n_kv_heads, interpret=interpret, scale=scale,
         )
     else:
         # Advanced indices at dims 0 and 2 with the Hkv slice
@@ -351,7 +355,7 @@ def _decode_attention(q, k, v, k_pages, v_pages, base, geometry, positions,
         k_pages = k_pages.at[base + write_pages, :, off_of, :].set(k)
         v_pages = v_pages.at[base + write_pages, :, off_of, :].set(v)
         attn = _gather_page_attention(
-            q, k_pages, v_pages, base + tables, mask, cfg
+            q, k_pages, v_pages, base + tables, mask, cfg, scale
         )
     return attn, k_pages, v_pages
 

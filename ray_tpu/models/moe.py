@@ -359,7 +359,11 @@ def moe_ffn(x: jnp.ndarray, p: Params, cfg, rows_live=None):
             # Load balance: e * sum_e (share of pairs routed to e) *
             # (mean probability of e) (Switch section 2.2); z-loss: the
             # mean squared logsumexp of the router's logits.
-            aux["balance_loss"] = e * (probs.mean(0) * (load / n)).sum()
+            # (Under a share: the held experts' part of that sum.)
+            mean_prob = probs.mean(0)
+            if cfg.experts_held is not None:
+                mean_prob = mean_prob[first: first + e_here]
+            aux["balance_loss"] = e * (mean_prob * (load / n)).sum()
             aux["z_loss"] = jnp.square(
                 jax.nn.logsumexp(logits, axis=-1)
             ).mean()

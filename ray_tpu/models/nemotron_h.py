@@ -98,6 +98,17 @@ class NemotronHConfig:
     dense_expert_rows: int = 512
     max_seq: int = 262144
     dtype: Any = jnp.bfloat16
+    # What a family whose layers are two of these sublayers multiplies
+    # in (`models/granite_hybrid.py`): the embedding, each sublayer's
+    # output before the residual add, the attention scores (None:
+    # head_dim**-0.5) and a divisor of the logits; and whether the head
+    # is the embedding's transpose. Python numbers: the serving programs
+    # skip each at these values, so this family's programs hold none.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_scale: float | None = None
+    logits_scaling: float = 1.0
+    tie_word_embeddings: bool = False
 
     def __post_init__(self):
         if set(self.pattern) - set("ME*"):
@@ -292,7 +303,9 @@ def _project_out(y, z, p, cfg):
 
 
 def mamba_chunked(u, p, cfg: NemotronHConfig, ssm0, conv0, length):
-    """The Mamba-2 mixer over many tokens of one sequence.
+    """The Mamba-2 mixer over many tokens of one sequence (``cfg``: this
+    family's config or one with its Mamba fields, as
+    ``models/granite_hybrid.py``'s).
 
     u [T, d] (normed input); ssm0 [H, P, N] float32 and conv0
     [K - 1, conv_dim] the state before u[0]; ``length`` (traced) how many

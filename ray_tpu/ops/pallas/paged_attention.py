@@ -218,7 +218,9 @@ def _make_kernel(
     return _kernel
 
 
-@functools.partial(jax.jit, static_argnames=("n_kv_heads", "interpret"))
+@functools.partial(
+    jax.jit, static_argnames=("n_kv_heads", "interpret", "scale")
+)
 def paged_attention(
     q: jnp.ndarray,  # [B, K, H, Dh] (rope applied)
     k_pool: jnp.ndarray,  # [num_pages, Hkv, P, Dh] (head-major)
@@ -228,13 +230,14 @@ def paged_attention(
     *,
     n_kv_heads: int,
     interpret: bool = False,
+    scale: float | None = None,
 ) -> jnp.ndarray:
     """Decode/verify attention over the page pool; returns [B, K, H, Dh].
 
     Query token k of slot b attends to key positions <= positions[b]+k
     within the slot's block table (the K=1 case is plain decode). The
     pool is read in place, each slot's live pages only: see the module
-    docstring.
+    docstring. ``scale`` multiplies the scores (``Dh**-0.5`` where None).
     """
     b, kk, n_heads, head_dim = q.shape
     num_pages, hkv, page_size, _ = k_pool.shape
@@ -265,7 +268,7 @@ def paged_attention(
             page_size=page_size,
             n_queries=kk,
             max_pages=max_pages,
-            scale=head_dim**-0.5,
+            scale=head_dim**-0.5 if scale is None else scale,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,

@@ -170,7 +170,7 @@ def _kernel(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block_q", "block_kv", "interpret")
+    jax.jit, static_argnames=("block_q", "block_kv", "interpret", "scale")
 )
 def prefill_attention(
     q: jnp.ndarray,  # [C, H, Dh], rope applied
@@ -181,22 +181,31 @@ def prefill_attention(
     block_q: int = _BLOCK_Q,
     block_kv: int = _BLOCK_KV,
     interpret: bool = False,
+    scale: float | None = None,
 ) -> jnp.ndarray:
     """Causal attention of C queries at ``start .. start + C - 1`` over
     the keys at ``0 .. n_pages * P - 1`` (query i sees keys <= start +
     i); returns ``[C, H, Dh]``. ``start + C`` may not pass the pages
-    given; what the pages hold past it does not reach the result."""
+    given; what the pages hold past it does not reach the result.
+    ``scale`` multiplies the scores (``Dh**-0.5`` where None; a model
+    states its own: ``models/granite_hybrid.py``)."""
     c, n_heads, head_dim = q.shape
     n_pages, n_kv, page_size, _ = k_pages.shape
     n_rep = n_heads // n_kv
     dt = k_pages.dtype
+    # The statistics, the accumulator and the unrolled heads' scores are
+    # kept for each of the group's n_rep query heads, so the query block
+    # shrinks as the group grows: n_rep x block_q rows at most what the
+    # sweep above ran (4 x 512). At 16 heads a KV head (Nemotron-3-Nano)
+    # a 512-row block asks 66 MB of VMEM and the compiler refuses it.
+    block_q = min(block_q, max(4 * _BLOCK_Q // n_rep, _SUBLANES_BF16))
     block_q = _fit_rows(block_q, c, _SUBLANES_BF16)
     block_pages = _fit_rows(max(block_kv // page_size, 1), n_pages, 1)
     block_kv = block_pages * page_size
     num_q, num_kv = c // block_q, n_pages // block_pages
     group = n_rep * head_dim
     rows = (
-        q.astype(jnp.float32) * head_dim**-0.5
+        q.astype(jnp.float32) * (head_dim**-0.5 if scale is None else scale)
     ).astype(dt).reshape(c, n_heads * head_dim)
 
     def last_needed(qi, ki, start):

@@ -21,7 +21,7 @@ from ray_tpu.ops.pallas.paged_attention import (
 )
 
 
-def _reference(q, kp, vp, tables, positions):
+def _reference(q, kp, vp, tables, positions, scale=None):
     """The gather+repeat+dense-softmax math from paged_kv.paged_verify
     (pools are head-major: [pages, Hkv, P, Dh])."""
     b, k, h, dh = q.shape
@@ -43,7 +43,7 @@ def _reference(q, kp, vp, tables, positions):
         jnp.einsum(
             "bqhd,bkhd->bhqk", q, kk, preferred_element_type=jnp.float32
         )
-        * dh**-0.5
+        * (dh**-0.5 if scale is None else scale)
     )
     s = jnp.where(mask[:, None, :, :], -2.0e38, s)
     probs = jax.nn.softmax(s, axis=-1).astype(q.dtype)
@@ -101,8 +101,15 @@ def _serve_block(k):
 
 
 @pytest.mark.parametrize(
-    "b,k,h,hkv,dh,p,maxp,positions",
+    "b,k,h,hkv,dh,p,maxp,positions,scale",
     [
+        # A model's own score scale (models/granite_hybrid.py: 1/128).
+        (3, 1, 8, 2, 64, 16, 4, [17, 50, 3], 1 / 128),
+        (3, 3, 8, 2, 64, 8, 32, [6, 190, 253], 1 / 128),
+    ] + [
+        pytest.param(*getattr(case, "values", case), None,
+                     id=getattr(case, "id", None))
+        for case in [
         (3, 1, 8, 2, 64, 16, 4, [17, 50, 3]),          # GQA decode
         (2, 1, 4, 4, 32, 8, 3, [0, 20]),               # MHA, pos 0
         (3, 4, 8, 2, 64, 16, 4, [15, 47, 60]),         # verify K=4,
@@ -122,18 +129,21 @@ def _serve_block(k):
         _serve_case(5, [
             67, _serve_block(5) + 2, 132 * 64, 132 * 64 + 3,
         ]),
+        ]
     ],
 )
-def test_kernel_matches_gather_reference(b, k, h, hkv, dh, p, maxp, positions):
+def test_kernel_matches_gather_reference(
+    b, k, h, hkv, dh, p, maxp, positions, scale
+):
     q, kp, vp, tables, pos = _case(7, b, k, h, hkv, dh, p, maxp, positions)
     out = paged_attention(
-        q, kp, vp, tables, pos, n_kv_heads=hkv, interpret=True
+        q, kp, vp, tables, pos, n_kv_heads=hkv, interpret=True, scale=scale
     )
     # Slot by slot: the reference repeats a slot's whole window to all
     # query heads. A dead slot's output is nobody's.
     for i in [i for i, at in enumerate(positions) if at is not None]:
         one = slice(i, i + 1)
-        ref = _reference(q[one], kp, vp, tables[one], pos[one])
+        ref = _reference(q[one], kp, vp, tables[one], pos[one], scale)
         np.testing.assert_allclose(
             np.asarray(out[one]), np.asarray(ref), atol=2e-5, rtol=2e-5
         )

@@ -79,20 +79,31 @@ KERNEL_CASES = {
     "mha_several_blocks_in": (48, 80, 8, 2, 2, 16, 32),
     "blocks_that_do_not_divide": (48, 32, 5, 4, 1, 32, 32),
     "float32_pool": (32, 32, 4, 4, 2, 32, 32),
+    # A model's own score scale (models/granite_hybrid.py: 1/128, not
+    # Dh**-0.5), float32 so that scaling the oracle's queries is exact.
+    "scale_1_128_start0": (32, 0, 2, 4, 1, 32, 32),
+    "scale_1_128_several_blocks_in": (48, 80, 8, 4, 2, 16, 32),
 }
 
 
 @pytest.mark.parametrize("case", sorted(KERNEL_CASES))
 def test_kernel_matches_causal_attention_at_an_offset(case):
     c, start, n_pages, h, hkv, bq, bkv = KERNEL_CASES[case]
-    dtype = jnp.float32 if case == "float32_pool" else jnp.bfloat16
+    scale = 1 / 128 if case.startswith("scale_1_128") else None
+    in_f32 = case == "float32_pool" or scale is not None
+    dtype = jnp.float32 if in_f32 else jnp.bfloat16
     q, k, v = _inputs(1, c, n_pages, h, hkv, dtype)
     got = prefill_attention(
         q, _cells(k), _cells(v), jnp.int32(start),
-        block_q=bq, block_kv=bkv, interpret=True,
+        block_q=bq, block_kv=bkv, interpret=True, scale=scale,
     )
     assert got.shape == q.shape and got.dtype == k.dtype
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    if scale is not None:
+        # The oracle scales by Dh**-0.5: hand it queries that make up
+        # the difference, and see that the scale moved the result.
+        assert _max_err(got, _dense(q, k, v, start)) > 1e-2
+        q = q * (scale * DH**0.5)
     assert _max_err(got, _dense(q, k, v, start)) < tol
 
 

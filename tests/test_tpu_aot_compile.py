@@ -12,7 +12,9 @@ widths with a two-layer page pool of the benchmark's size, for what only
 the compiled text shows: that nothing copies, slices out or writes back
 a layer's pages or more. So are the hybrid model's (llm/hybrid_kv.py),
 at Nemotron-3-Nano's widths with 64 experts held, for the same of its
-pages, of a layer's per-slot state and of an expert stack; and the
+pages, of a layer's per-slot state and of an expert stack, and at
+granite-4.0-h-small's with 36 held, where a chunk also attends a
+16,384-token table without a score over it in HBM; and the
 latent-attention model's (llm/latent_kv.py), at openPangu-Ultra-MoE's
 widths with 16 experts held, for the same of its latent pool. Both hold
 the kernel that reads the touched experts (ops/pallas/expert_rows.py)
@@ -443,6 +445,10 @@ def test_hybrid_program_moves_no_pages_state_or_expert_stack(
     # paged-attention and cell-write kernels in the decode program.
     sorted_form = program == "prefill_chunk_1024_of_2048"
     assert ("ragged-dot" in text) == sorted_form
+    # The prefill kernel where the table holds more than 1,024 keys,
+    # dense scores up to it: the benchmark's Nemotron programs, all at
+    # or under it, attend as they did before the kernel came.
+    assert ("prefill_attention" in text) == sorted_form
     assert len(_expert_kernel_calls(text)) == (0 if sorted_form else 2)
     if program == "decode":
         assert "paged_attention" in text and "write_kv_cells" in text
@@ -520,6 +526,85 @@ def test_latent_program_moves_no_pool_or_expert_stack_and_fits(
     arguments = (
         family.held_parameters(conf) * 2
         + conf["num_hidden_layers"] * layer_pages * 2
+    )
+    assert arguments > 0.25 * 16 * 2**30  # the floor a new cell is held to
+    assert arguments + memory.temp_size_in_bytes < 15.75 * 2**30
+
+
+# ------------------------------------------- the hybrid programs, two sublayers
+@pytest.fixture(scope="module")
+def granite_programs(v5e):
+    """granite4hsmall-serve1's own sizes (benchmarks/configs) at 2 of its
+    10 layers, a Mamba-2 and the attention layer, each with its expert
+    FFN, with the whole configuration's pages and slots: what
+    `aot_fit_serve_family` lowers for the whole configuration, at the
+    widest table of the mix (256 pages)."""
+    import json
+
+    from benchmarks import aot_fit_serve_family
+    from ray_tpu._private import chip
+
+    here = os.path.join(os.path.dirname(__file__), "..", "benchmarks")
+    with open(os.path.join(here, "configs", "granite4hsmall-serve1.json")) as f:
+        whole = json.load(f)
+    conf = {**whole, "num_hidden_layers": 2,
+            "layer_types": ["mamba", "attention"]}
+    traffic = {"fit_prefill_buckets": [16384]}
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(chip, "platform", lambda: "tpu")
+        lowered = aot_fit_serve_family.lowered_programs(
+            conf, traffic, next(iter(v5e.device_set))
+        )
+        return whole, {name: low.compile() for name, low in lowered.items()}
+
+
+@pytest.mark.parametrize("program", ["prefill_chunk_2048_of_16384", "decode"])
+def test_granite_program_moves_no_pages_state_or_stack_and_fits(
+    granite_programs, program
+):
+    """The same one donated cache updated in place and expert stacks
+    read where they lie (768 is six tiles of 128 lanes) as Nemotron's
+    programs above, through the same `llm/hybrid_kv.py`; and a
+    2,048-token chunk at a 256-page table attends by the prefill kernel:
+    no `[heads, chunk, table]` float32 scores (4.29 GB: with them the
+    program cannot fit beside 12.9 GB of arguments), and the program's
+    temporaries beside the WHOLE configuration's arguments stay under
+    what a v5e offers a program."""
+    from benchmarks.models import granite_hybrid as family
+
+    conf, programs = granite_programs
+    eng = conf["engine"]
+    d, f = conf["hidden_size"], conf["intermediate_size"]
+    held, hkv = conf["num_local_experts"], conf["num_key_value_heads"]
+    layer_pages = (eng["num_pages"] + 1) * hkv * PAGE * DH
+    state = eng["max_batch"] * 128 * 64 * 128
+    shapes = {
+        "pages": ((hkv, PAGE, DH), layer_pages),
+        "state": ((128, 64, 128), state),
+        "w_up": ((d, f), held * d * f),
+        "w_down": ((f, d), held * d * f),
+    }
+    compiled = programs[program]
+    text = compiled.as_text()
+    assert _hybrid_moves(text, shapes) == []
+    memory = compiled.memory_analysis()
+    chunk, table = eng["prefill_chunk"], 16384
+    if program == "decode":
+        assert "paged_attention" in text and "write_kv_cells" in text
+        assert "ragged-dot" not in text
+        assert len(_expert_kernel_calls(text)) == 2  # one a layer's FFN
+        assert memory.temp_size_in_bytes < state * 4
+    else:
+        assert "prefill_attention" in text and "ragged-dot" in text
+        assert _expert_kernel_calls(text) == []
+        # No array with the chunk's queries against the table's keys,
+        # whatever the leading dimensions and the dtype.
+        assert not re.search(rf"\[[\d,]*{chunk},{table}\]", text)
+        assert memory.temp_size_in_bytes < 32 * chunk * table * 4 // 2
+    pool = 2 * layer_pages * 2  # K and V of the one attention layer
+    arguments = (
+        family.held_parameters(conf) * 2 + pool
+        + 9 * eng["max_batch"] * (128 * 64 * 128 * 4 + 3 * 8448 * 2)
     )
     assert arguments > 0.25 * 16 * 2**30  # the floor a new cell is held to
     assert arguments + memory.temp_size_in_bytes < 15.75 * 2**30

@@ -270,7 +270,7 @@ class LLMEngine:
         # (token, expert) pairs a token is routed to over the model's
         # expert blocks, where its programs keep a record of them.
         self._pairs_per_token = serving.pairs_per_token
-        self._moe_counts: list = []  # (phase, device int32[2]), unfolded
+        self._moe_counts: list = []  # (phase, device int32[4]), unfolded
         # The chain of keys, ``key, sub = split(key)`` a decode step:
         # its head, and the links made ahead (`_key_block`).
         self._step_key = jax.random.key(seed)
@@ -356,10 +356,13 @@ class LLMEngine:
             # Expert blocks (0 without any): pairs the live tokens were
             # routed to, the pairs among them whose expert is held here,
             # and held experts that got a row, summed over expert blocks
-            # and decode steps.
+            # and decode steps; the rows the sorted expert form ran its
+            # grouped matmuls over, and the pair rows it was given.
             "moe_pairs_routed": 0,
             "moe_pairs_here": 0,
             "experts_touched": 0,
+            "moe_rows_computed": 0,
+            "moe_rows_sorted": 0,
         }
 
     # ------------------------------------------------------ request API
@@ -730,9 +733,11 @@ class LLMEngine:
 
     def _fold_moe_counts(self) -> None:
         counts, self._moe_counts = self._moe_counts, []
-        for phase, pair in counts:
-            here, touched = (int(v) for v in np.asarray(pair))
+        for phase, row in counts:
+            here, touched, computed, given = (int(v) for v in np.asarray(row))
             self._stats["moe_pairs_here"] += here
+            self._stats["moe_rows_computed"] += computed
+            self._stats["moe_rows_sorted"] += given
             if phase == "decode":
                 self._stats["experts_touched"] += touched
 
@@ -1153,6 +1158,12 @@ class LLMEngine:
             out["decode_in_flight_pct"] = (
                 100.0 * out["decode_steps_in_flight"] / out["decode_steps"]
                 if out["decode_steps"] else 0.0
+            )
+            # How tight the sorted expert form's row bound is: beside
+            # moe_pairs_here / moe_pairs_routed, the share it must do.
+            out["moe_sorted_rows_pct"] = (
+                100.0 * out["moe_rows_computed"] / out["moe_rows_sorted"]
+                if out["moe_rows_sorted"] else 0.0
             )
             out["platform"] = self.platform
             out["device_kind"] = jax.devices()[0].device_kind

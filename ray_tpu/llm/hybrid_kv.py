@@ -120,22 +120,40 @@ def _residual(x, out, cfg):
 def _experts(x, p, cfg, rows_live, record):
     """An expert block on x [B, S, d], and its counters onto ``record``."""
     out, aux = moe_ffn(rms_norm(x, p["norm"]), p, cfg, rows_live=rows_live)
+    _note(record, aux)
+    return _residual(x, out, cfg)
+
+
+def _new_record():
+    """What a program's expert blocks leave: `_note` fills, `_record`
+    sums."""
+    return {"routes": [], "pairs_here": [], "experts_touched": [],
+            "sorted_rows": []}
+
+
+def _note(record, aux):
+    """An expert block's counters, from `moe_ffn`'s ``aux``."""
     record["routes"].append(aux["routes"])
     record["pairs_here"].append(aux["expert_load"].sum())
     record["experts_touched"].append((aux["expert_load"] > 0).sum())
-    return _residual(x, out, cfg)
+    record["sorted_rows"].append(aux["sorted_rows"])
 
 
 def _record(record):
     """Per program: ``routes`` [L_expert, T, k] (each token's experts, of
-    all the model's) and ``counts`` int32[2]: the pairs of live rows
-    whose expert is held, and the held experts that got a row, each
-    summed over the expert blocks."""
+    all the model's) and ``counts`` int32[4]: the pairs of live rows
+    whose expert is held, the held experts that got a row, the rows the
+    sorted form ran its grouped matmuls over and the pairs it was given
+    (padding's and absent experts' among them), each summed over the
+    expert blocks."""
     return {
         "routes": jnp.stack(record["routes"]),
-        "counts": jnp.stack(
-            [sum(record["pairs_here"]), sum(record["experts_touched"])]
-        ).astype(jnp.int32),
+        "counts": jnp.concatenate([
+            jnp.stack(
+                [sum(record["pairs_here"]), sum(record["experts_touched"])]
+            ),
+            sum(record["sorted_rows"]),
+        ]).astype(jnp.int32),
     }
 
 
@@ -201,7 +219,7 @@ def _hybrid_prefill(
     k_pages, v_pages = _flat_pool(cache)
     ssm, conv = cache["ssm"], cache["conv"]
     x = _embed(params, tokens, cfg)
-    record = {"routes": [], "pairs_here": [], "experts_touched": []}
+    record = _new_record()
     n_attn = n_mamba = 0
     for kind, p in zip(cfg.pattern, params["blocks"], strict=True):
         if kind == "M":
@@ -300,7 +318,7 @@ def hybrid_decode(
     k_pages, v_pages = _flat_pool(cache)
     ssm, conv = cache["ssm"], cache["conv"]
     x = _embed(params, tokens, cfg)  # [B, 1, d]
-    record = {"routes": [], "pairs_here": [], "experts_touched": []}
+    record = _new_record()
     n_attn = n_mamba = 0
     for kind, p in zip(cfg.pattern, params["blocks"], strict=True):
         if kind == "M":

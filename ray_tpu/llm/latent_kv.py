@@ -54,7 +54,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu._private import chip
-from ray_tpu.llm.hybrid_kv import _head, _record
+from ray_tpu.llm.hybrid_kv import _head, _new_record, _note, _record
 from ray_tpu.llm.paged_kv import _NEG_INF, _decode_geometry, _sample_tokens
 from ray_tpu.models.moe import moe_ffn
 from ray_tpu.models.pangu_ultra_moe import (
@@ -101,9 +101,7 @@ def _ffn(x, kind, p, cfg, rows_live, record):
         out = dense_mlp(h, p)
     else:
         out, aux = moe_ffn(h, p, cfg, rows_live=rows_live)
-        record["routes"].append(aux["routes"])
-        record["pairs_here"].append(aux["expert_load"].sum())
-        record["experts_touched"].append((aux["expert_load"] > 0).sum())
+        _note(record, aux)
     return x + rms_norm(out, p["norm4"])
 
 
@@ -249,7 +247,7 @@ def _latent_prefill(
     )
     pool = _flat(cache)
     x = params["tok_emb"][tokens]
-    record = {"routes": [], "pairs_here": [], "experts_touched": []}
+    record = _new_record()
     for i, (kind, p) in enumerate(zip(cfg.pattern, params["blocks"], strict=True)):
         base = i * num_pages
         h = rms_norm(x, p["norm1"])
@@ -318,7 +316,7 @@ def latent_decode(
     )
     pool = _flat(cache)
     x = params["tok_emb"][tokens]  # [B, K, d]
-    record = {"routes": [], "pairs_here": [], "experts_touched": []}
+    record = _new_record()
     for i, (kind, p) in enumerate(zip(cfg.pattern, params["blocks"], strict=True)):
         base = i * num_pages
         h = rms_norm(x, p["norm1"])

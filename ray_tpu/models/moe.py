@@ -8,7 +8,9 @@ are the tokens each expert received (``jax.lax.ragged_dot``), the
 results weighted by the gates and added back per token. There is no
 per-expert slot limit and no one-hot dispatch tensor, so the work is
 tokens x top_k whatever the skew. Experts carry the "expert" logical
-axis, sharded over the mesh's ep axis.
+axis, sharded over the mesh's ep axis. A serving program that holds a
+share of the experts does that work on the pairs whose expert it holds,
+a block of the sorted order at a time (`_experts_on_pairs_here`).
 
 The attention sublayer, scan scaffolding, and non-expert parameters are
 the flagship Llama's (ray_tpu.models.llama — this module only swaps the
@@ -25,6 +27,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.xla_metadata import set_xla_metadata
 
 from ray_tpu._private import chip
 from ray_tpu.models.llama import (
@@ -245,29 +248,24 @@ def _experts_on_every_row(tokens, p, cfg, routes, gates, here):
     return out, load
 
 
-def _experts_on_sorted_pairs(tokens, p, cfg, routes, gates, here):
+def _experts_on_sorted_pairs(tokens, p, cfg, routes, gates):
     """Pairs sorted by expert, the rows gathered in that order, the
     experts applied as grouped matmuls whose groups are each expert's
-    rows, the results gathered back and summed per token."""
+    rows, the results gathered back and summed per token. Every pair
+    is computed (every expert held, every row a token): the train
+    step's form, with a backward pass."""
     n, k = routes.shape
     d = tokens.shape[-1]
-    first, e_here = cfg.experts_held or (0, cfg.num_experts)
     dt = cfg.dtype
     with jax.named_scope("moe:dispatch"):
         # Pairs in expert order; a stable sort keeps each expert's rows
         # in token order.
         pair_expert = routes.reshape(n * k)
-        if here is not None:
-            # Held experts are numbered from 0; a pair that is not
-            # computed here gets the number after the last, sorts behind
-            # every group and is in no group's size.
-            pair_expert = jnp.where(
-                here.reshape(n * k), pair_expert - first, e_here
-            )
         order = jnp.argsort(pair_expert, stable=True)
         inverse = jnp.argsort(order)
-        # (bincount drops the number after the last.)
-        load = jnp.bincount(pair_expert, length=e_here).astype(jnp.int32)
+        load = jnp.bincount(
+            pair_expert, length=cfg.num_experts
+        ).astype(jnp.int32)
         rows = _take_rows(tokens, order, inverse, k)  # [n * k, d]
 
     with jax.named_scope("moe:experts"):
@@ -281,11 +279,122 @@ def _experts_on_sorted_pairs(tokens, p, cfg, routes, gates, here):
         # Back to token order: pair j of token t sits at row t * k + j.
         pairs = _take_rows(rows_out, inverse, order, 1).reshape(n, k, d)
         weighted = pairs.astype(jnp.float32) * gates[..., None]
-        if here is not None:
-            # Rows behind the last group are no expert's output.
-            weighted = jnp.where(here[..., None], weighted, 0.0)
         out = weighted.sum(1).astype(dt)
     return out, load
+
+
+# Rows of the sorted order that one step of `_experts_on_pairs_here`
+# takes: the work is the pairs computed here rounded up to this.
+_PAIR_BLOCK = 1024
+# Columns of one float32 sum of `_experts_on_pairs_here`. XLA adds
+# 1,024 rows onto a [2048, 4096] sum in 0.27 ms on a v5e, and onto
+# 3,840 / 5,120 / 7,168 / 7,680 columns in 1.3 / 3.8 / 1.7 / 5.6 ms: a
+# wider layer's sum is kept in pieces this wide, the last one padded
+# with zero columns to a power of two.
+_SUM_LANES = 4096
+
+
+# (rows, contraction) of a tile of the compiler's grouped matmul in
+# `_experts_on_pairs_here`, where its own are (512, 512). On a v5e an
+# expert layer alone, 2,048 rows (PERF.md section 6, PR 41): at
+# granite's widths 6.64 ms with the compiler's tiles, 5.74 with these;
+# at openPangu's 6.35 and 4.55.
+_GROUP_TILE = (256, 1024)
+
+
+def _grouped_matmul(a, w, sizes):
+    """``jax.lax.ragged_dot`` with `_GROUP_TILE`: on a TPU the compiler's
+    kernel visits every (group, row tile) pair that holds a row and pays
+    a whole tile of arithmetic a visit, so a block of few-row groups
+    wants fewer rows a tile than the compiler's 512, and then a deeper
+    contraction a step. The tile over the output's columns is the one
+    the compiler picks itself (512, or 256 where 512 does not divide).
+    Elsewhere the attribute says nothing."""
+    rows, depth = _GROUP_TILE
+    columns = 512 if w.shape[2] % 512 == 0 else 256
+    with set_xla_metadata(ragged_dot_tiling=f"{rows},{depth},{columns}"):
+        return jax.lax.ragged_dot(a, w, sizes)
+
+
+def _experts_on_pairs_here(tokens, p, cfg, routes, gates, here):
+    """The sorted form where only the pairs under ``here`` are computed
+    (a share of the experts held, rows that carry no token): a serving
+    program's, forward only. Those pairs sort to the front, ``m`` of
+    them, and the work is ``m`` rounded up to `_PAIR_BLOCK`, not the
+    ``n * k`` pairs routed: a loop over blocks of the sorted order, as
+    many as hold a pair, each gathering its rows, applying the experts
+    to them (the block's part of each expert's group), weighting each
+    row by its gate where it lies and adding it onto its token. Any
+    routing gives the same sums as every pair computed and the dead
+    ones masked, all ``n * k`` live included. Returns the rows the
+    grouped matmuls ran over beside the output and the load."""
+    n, k = routes.shape
+    d = tokens.shape[-1]
+    first, e_here = cfg.experts_held or (0, cfg.num_experts)
+    dt = cfg.dtype
+    block = min(_PAIR_BLOCK, n * k)
+    with jax.named_scope("moe:dispatch"):
+        # Held experts are numbered from 0; a pair that is not computed
+        # here gets the number after the last and sorts behind every
+        # group. (bincount drops that number.)
+        pair_expert = jnp.where(
+            here.reshape(n * k), routes.reshape(n * k) - first, e_here
+        )
+        order = jnp.argsort(pair_expert, stable=True)
+        load = jnp.bincount(pair_expert, length=e_here).astype(jnp.int32)
+        ends = jnp.cumsum(load)  # where each expert's rows end
+        m = ends[-1]
+        blocks = (m + block - 1) // block
+        # The last block may reach past the pairs.
+        order = jnp.pad(order, (0, -(n * k) % block))
+        gate = gates.reshape(n * k)[order]  # as the rows lie
+    # The tokens' sums, float32, in column pieces: (first column,
+    # columns, columns held: the next power of two).
+    pieces = []
+    for a in range(0, d, _SUM_LANES):
+        w = min(_SUM_LANES, d - a)
+        pieces.append((a, w, 1 << (w - 1).bit_length()))
+
+    def step(i, sums):
+        lo = i * block
+        with jax.named_scope("moe:dispatch"):
+            token = jax.lax.dynamic_slice(order, (lo,), (block,)) // k
+            rows = tokens[token]  # [block, d]
+            # Each expert's rows that lie in this block.
+            sizes = (
+                jnp.clip(ends - lo, 0, block)
+                - jnp.clip(ends - load - lo, 0, block)
+            )
+        with jax.named_scope("moe:experts"):
+            grouped = lambda a, w: _grouped_matmul(a, w.astype(dt), sizes)  # noqa: E731
+            rows_out = grouped(
+                _expert_act(cfg, rows, p.get("w_gate"), p["w_up"], grouped),
+                p["w_down"],
+            )
+        with jax.named_scope("moe:combine"):
+            g = jax.lax.dynamic_slice(gate, (lo,), (block,))
+            weighted = rows_out.astype(jnp.float32) * g[:, None]
+            # Rows behind the last group are no expert's output.
+            live = lo + jnp.arange(block) < m
+            weighted = jnp.where(live[:, None], weighted, 0.0)
+            return tuple(
+                s.at[token].add(
+                    jnp.pad(weighted[:, a: a + w], ((0, 0), (0, held - w)))
+                )
+                for s, (a, w, held) in zip(sums, pieces)
+            )
+
+    # (The loop's own time goes under the experts' scope; a step's parts
+    # are named inside it.)
+    with jax.named_scope("moe:experts"):
+        sums = jax.lax.fori_loop(0, blocks, step, tuple(
+            jnp.zeros((n, held), jnp.float32) for _, _, held in pieces
+        ))
+    with jax.named_scope("moe:combine"):
+        out = jnp.concatenate(
+            [s[:, :w] for s, (_, w, _) in zip(sums, pieces)], axis=1
+        ).astype(dt)
+    return out, load, blocks * block
 
 
 def moe_ffn(x: jnp.ndarray, p: Params, cfg, rows_live=None):
@@ -303,8 +412,11 @@ def moe_ffn(x: jnp.ndarray, p: Params, cfg, rows_live=None):
     ``aux`` is the layer's router record: ``balance_loss`` and
     ``z_loss`` (unweighted; softmax routers), ``expert_load`` (pairs each
     held expert computed, int32[held]: with every expert held and every
-    row live their sum is tokens x top_k, nothing is dropped) and
-    ``routes`` (the experts of each token, int32[T, k])."""
+    row live their sum is tokens x top_k, nothing is dropped),
+    ``routes`` (the experts of each token, int32[T, k]) and, where only
+    some pairs are computed here, ``sorted_rows`` (int32[2]: the rows
+    the sorted form ran its grouped matmuls over and the ``T x k`` pairs
+    it was given; zeros where the every-row form ran)."""
     b, s, d = x.shape
     e, k = cfg.num_experts, cfg.top_k
     n = b * s
@@ -339,10 +451,16 @@ def moe_ffn(x: jnp.ndarray, p: Params, cfg, rows_live=None):
         if rows_live is not None:
             here &= rows_live[:, None]
 
+    sorted_rows = (0, 0)
     if n <= cfg.dense_expert_rows:
         out, load = _experts_on_every_row(tokens, p, cfg, routes, gates, here)
+    elif here is None:
+        out, load = _experts_on_sorted_pairs(tokens, p, cfg, routes, gates)
     else:
-        out, load = _experts_on_sorted_pairs(tokens, p, cfg, routes, gates, here)
+        out, load, computed = _experts_on_pairs_here(
+            tokens, p, cfg, routes, gates, here
+        )
+        sorted_rows = (computed, n * k)
 
     if "shared_up" in p:
         with jax.named_scope("moe:shared"):
@@ -354,6 +472,8 @@ def moe_ffn(x: jnp.ndarray, p: Params, cfg, rows_live=None):
             )
 
     aux = {"expert_load": load, "routes": routes}
+    if here is not None:
+        aux["sorted_rows"] = jnp.stack(sorted_rows).astype(jnp.int32)
     if cfg.router_kind == "softmax":
         with jax.named_scope("moe:route"):
             # Load balance: e * sum_e (share of pairs routed to e) *

@@ -23,6 +23,7 @@ import pytest
 from benchmarks import reference_granite_hybrid as reference
 from benchmarks.models import granite_hybrid as bench_model
 from ray_tpu.llm import hybrid_kv
+from ray_tpu.models import moe
 from ray_tpu.llm.engine import LLMEngine, SamplingParams
 from ray_tpu.models.granite_hybrid import GraniteHybridConfig, init_params
 from ray_tpu.models.moe import moe_ffn
@@ -137,6 +138,63 @@ def test_prefill_then_decode_equals_the_reference_pass(params, chunk, calls):
     assert stats["prefill_programs"] == calls
     assert stats["ssm_scan_tokens"] == 9 * 75
     assert stats["prefill_attn_pairs"] == 75 * 76 // 2
+
+
+def test_a_chunked_prefill_with_a_share_held_equals_the_reference_pass(
+    monkeypatch,
+):
+    """Experts 2-5 of the 8 held, as a chip of an expert-parallel pair
+    holds them: a 75-token prompt in three 32-row chunks, whose expert
+    sublayers take the sorted form under its row bound (blocks of 16
+    rows here: a chunk's 96 pairs a layer are six), then 5 decode steps
+    in the every-row form. Logits against the reference's one pass with
+    the same share, and each program's ``counts``: the rows the grouped
+    matmuls ran over lie between the pairs computed here and the pairs
+    given, whole blocks of them."""
+    monkeypatch.setattr(moe, "_PAIR_BLOCK", 16)
+    tiny = {**TINY, "num_local_experts": 4, "first_expert_held": 2,
+            "published": {"num_local_experts": 8}}
+    cfg = bench_model.config(tiny, dtype=jnp.float32, dense_expert_rows=8)
+    assert cfg.experts_held == (2, 4) and cfg.num_experts == 8
+    held = init_params(jax.random.key(3), cfg)
+    eng = LLMEngine(cfg, params=held, max_batch=4, max_seq=192, page_size=16,
+                    prefill_chunk=32)
+    seen = _tapped(eng)
+    prompt = _prompt(0, 75)
+    (generated,) = eng.generate([prompt], SamplingParams(max_tokens=6))
+    tokens = prompt + generated
+    prefills = [s for s in seen if s[0].startswith("prefill")]
+    decodes = [s for s in seen if s[0] == "decode"]
+    assert len(prefills) == 3 and len(decodes) == 5
+    routes = np.concatenate([s[2]["routes"] for s in prefills], axis=1)[:, :75]
+    routes = np.concatenate(
+        [routes] + [s[2]["routes"][:, :1] for s in decodes], axis=1
+    )
+    want, record = reference.forward_with_record(
+        held, jnp.asarray(tokens[:-1], jnp.int32), routes=routes,
+        **reference.for_model(tiny),
+    )
+    assert (np.sort(routes, -1) == np.sort(record["routes"], -1)).all()
+    assert float(np.abs(want).max()) > 0.03
+    np.testing.assert_allclose(prefills[-1][1][0, 0], want[74], atol=TOL, rtol=0)
+    for i, step in enumerate(decodes):
+        np.testing.assert_allclose(step[1][0], want[75 + i], atol=TOL, rtol=0)
+
+    computed = given = 0
+    for live, (_, _, rec) in zip((32, 32, 11), prefills):
+        here, _, rows, pairs = (int(v) for v in rec["counts"])
+        in_share = (rec["routes"][:, :live] >= 2) & (rec["routes"][:, :live] < 6)
+        assert here == in_share.sum()
+        assert pairs == 32 * cfg.top_k * 10  # the padded rows' pairs too
+        assert here <= rows < pairs and rows % 16 == 0
+        assert rows - here < 16 * 10  # under a block a layer
+        computed, given = computed + rows, given + pairs
+    for _, _, rec in decodes:
+        assert rec["counts"][2:].tolist() == [0, 0]  # the every-row form
+    stats = eng.stats()
+    assert 0 < stats["moe_pairs_here"] < stats["moe_pairs_routed"]
+    assert stats["moe_rows_computed"] == computed
+    assert stats["moe_sorted_rows_pct"] == 100.0 * computed / given
 
 
 def test_kernel_and_gather_attention_paths_agree(params, monkeypatch):

@@ -16,9 +16,11 @@ import pytest
 
 from benchmarks import reference_olmoe
 from benchmarks.models import olmoe
+from ray_tpu.models import moe
 from ray_tpu.models.moe import (
     MOE_PRESETS,
     MoEConfig,
+    _experts_on_sorted_pairs,
     _take_rows,
     init_moe_params,
     moe_ffn,
@@ -396,3 +398,127 @@ def test_olmoe_path_through_generalised_moe_ffn_gives_what_it_gave(
     head, total = _BEFORE[norm_topk_prob]
     np.testing.assert_allclose(out[0, :, :3], head, atol=3e-5, rtol=1e-5)
     np.testing.assert_allclose(jnp.abs(out).sum(), total, rtol=1e-5)
+
+
+# ------------------------------------------- the sorted form's row bound
+# 16 experts, so that a sixteenth can be held; 64 rows x 2 = 128 pairs.
+BOUND_CFG = dataclasses.replace(CFG, num_experts=16, dtype=jnp.float32)
+_BOUND_CASES = {
+    # held (first, count), rows that carry a token (of 64), top_k,
+    # router forced onto the lowest ids, d_model
+    "all_pairs_live": ((0, 16), 64, 2, False, 64),
+    "a_half_held": ((4, 8), 64, 2, False, 64),
+    "a_sixteenth_held": ((5, 1), 64, 2, False, 64),
+    "no_pair_live": ((4, 8), 0, 2, False, 64),
+    "a_padded_tail": ((0, 8), 51, 2, False, 64),
+    "a_padded_tail_all_held": (None, 51, 2, False, 64),
+    "one_held_expert_takes_every_pair": ((0, 4), 64, 1, True, 64),
+    # 320 columns with sums held to 128 each: three pieces, the last one
+    # 64 columns wide, which is a power of two; a fourth case pads (48).
+    "a_sum_kept_in_pieces": ((4, 8), 64, 2, False, 320),
+    "a_last_piece_padded": ((4, 8), 64, 2, False, 176),
+}
+
+
+def _bound_layer(held, top_k, forced, d_model=64):
+    cfg = dataclasses.replace(BOUND_CFG, top_k=top_k, d_model=d_model)
+    layer = {
+        k: v[0] for k, v in init_moe_params(jax.random.key(4), cfg)["blocks"].items()
+    }
+    if forced:
+        layer["router"] = jnp.zeros_like(layer["router"])
+    x = jax.random.normal(jax.random.key(12), (1, 64, cfg.d_model))
+    first, count = held or (0, cfg.num_experts)
+    mine = {**layer, **{k: layer[k][first: first + count]
+                        for k in ("w_gate", "w_up", "w_down")}}
+    return dataclasses.replace(cfg, experts_held=held), layer, mine, x
+
+
+@pytest.mark.parametrize("case", list(_BOUND_CASES))
+def test_sorted_form_under_a_row_bound_gives_every_pairs_sums(
+    case, monkeypatch
+):
+    """Where only some pairs are computed here the sorted form works on
+    those, rounded up to a block (32 rows here, so that 128 pairs are
+    four blocks), and gives what the every-row form gives (the oracle,
+    `every_row_einsum`) and what the unbounded sorted form gives over
+    ALL experts when the gates of the pairs not computed here are zero:
+    whatever share is held, with a padded tail, with no pair at all, and
+    with every pair on one held expert (dropless: one group of 64 rows,
+    two whole blocks)."""
+    held, n_live, top_k, forced, d_model = _BOUND_CASES[case]
+    monkeypatch.setattr(moe, "_PAIR_BLOCK", 32)
+    monkeypatch.setattr(moe, "_SUM_LANES", 128)
+    cfg, layer, mine, x = _bound_layer(held, top_k, forced, d_model)
+    rows_live = jnp.arange(64) < n_live
+
+    got, aux = moe_ffn(x, mine, cfg, rows_live=rows_live)
+
+    oracle, oracle_aux = moe_ffn(
+        x, mine, dataclasses.replace(cfg, dense_expert_rows=10**6),
+        rows_live=rows_live,
+    )
+    np.testing.assert_allclose(got, oracle, atol=3e-5, rtol=1e-5)
+    assert (aux["expert_load"] == oracle_aux["expert_load"]).all()
+    assert (aux["routes"] == oracle_aux["routes"]).all()
+    assert (oracle_aux["sorted_rows"] == 0).all()
+
+    # Every pair computed, the dead ones weighted by zero.
+    routes = aux["routes"]
+    first, count = held or (0, cfg.num_experts)
+    here = (routes >= first) & (routes < first + count) & rows_live[:, None]
+    probs = jax.nn.softmax(x[0] @ layer["router"], -1)
+    gates = jnp.take_along_axis(probs, routes, -1)
+    unbounded, _ = _experts_on_sorted_pairs(
+        x[0], layer, cfg, routes, jnp.where(here, gates, 0.0)
+    )
+    np.testing.assert_allclose(got[0], unbounded, atol=3e-5, rtol=1e-5)
+
+    pairs_here = int(here.sum())
+    assert int(aux["expert_load"].sum()) == pairs_here
+    if forced:
+        assert aux["expert_load"].tolist() == [64, 0, 0, 0]
+    computed, given = (int(v) for v in aux["sorted_rows"])
+    assert given == 64 * top_k
+    assert computed == -(-pairs_here // 32) * 32 <= given
+
+
+def _primitives(jaxpr, found):
+    for eqn in jaxpr.eqns:
+        found.append(eqn.primitive.name)
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _primitives(sub, found)
+    return found
+
+
+def test_with_every_pair_computed_the_sorted_form_is_the_program_it_was():
+    """The train step's form (every expert held, every row a token)
+    shares nothing with the row bound: no loop, switch or conditional,
+    the three grouped matmuls, two sorts and the two `_take_rows`; and
+    `moe_ffn` reports no bounded rows for it. The bounded form is the
+    one with the loop."""
+    cfg, layer, mine, x = _bound_layer(None, 2, False)
+    routes = jnp.zeros((64, 2), jnp.int32)
+    gates = jnp.ones((64, 2))
+    found = _primitives(jax.make_jaxpr(
+        lambda t, g: _experts_on_sorted_pairs(t, layer, cfg, routes, g)
+    )(x[0], gates).jaxpr, [])
+    assert found.count("ragged_dot_general") == 3
+    assert found.count("sort") == 2 and found.count("custom_vjp_call") == 2
+    assert not {"while", "cond", "scan", "dynamic_slice"} & set(found)
+    whole = _primitives(
+        jax.make_jaxpr(lambda v: moe_ffn(v, layer, cfg))(x).jaxpr, []
+    )
+    assert "while" not in whole and "cond" not in whole
+    assert "sorted_rows" not in moe_ffn(x, layer, cfg)[1]
+    half = dataclasses.replace(cfg, experts_held=(0, 8))
+    bounded = _primitives(jax.make_jaxpr(
+        lambda v: moe_ffn(v, {**layer, **{
+            k: layer[k][:8] for k in ("w_gate", "w_up", "w_down")
+        }}, half)
+    )(x).jaxpr, [])
+    assert bounded.count("while") == 1
+    assert bounded.count("ragged_dot_general") == 3

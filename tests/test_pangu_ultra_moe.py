@@ -25,6 +25,7 @@ from ray_tpu.llm import latent_kv
 from ray_tpu.llm.engine import LLMEngine, SamplingParams
 from ray_tpu.llm.paged_kv import _decode_geometry
 from ray_tpu.models.moe import moe_ffn
+from ray_tpu.models import moe
 from ray_tpu.models.pangu_ultra_moe import (
     PANGU_PRESETS,
     PanguUltraMoEConfig,
@@ -157,8 +158,11 @@ def path_cfg(request):
 
 
 def _ffn(p, x, cfg, kind="E"):
-    record = {"routes": [], "pairs_here": [], "experts_touched": []}
-    return latent_kv._ffn(x[None], kind, p, cfg, None, record)[0], record
+    """The sublayer as a program calls it: with the mask of the rows
+    that carry a token (here all), so the sorted form is the bounded one."""
+    record = latent_kv._new_record()
+    live = jnp.ones(len(x), bool)
+    return latent_kv._ffn(x[None], kind, p, cfg, live, record)[0], record
 
 
 def test_expert_layer_equals_the_reference(params, path_cfg):
@@ -436,6 +440,66 @@ def test_prefill_then_decode_equals_the_reference_pass(
     assert stats["latent_prefill_pairs"] == 4 * sum(
         c * s + c * (c + 1) // 2 for s, c in chunks
     )
+
+
+def test_a_chunked_prefill_with_a_share_held_equals_the_reference_pass(
+    monkeypatch,
+):
+    """Experts 4 and 5 of the 8 held, a quarter, as one chip of four
+    holds them: a 45-token prompt in three 16-row chunks whose three
+    expert layers take the sorted form under its row bound (blocks of 8
+    rows here: a chunk's 48 pairs a layer are six), then 5 decode steps
+    in the every-row form. Logits against the reference's one pass with
+    the same share, and each program's ``counts``: the rows the grouped
+    matmuls ran over lie between the pairs computed here and the pairs
+    given, whole blocks of them."""
+    monkeypatch.setattr(moe, "_PAIR_BLOCK", 8)
+    tiny = {**TINY, "n_routed_experts": 2, "first_expert_held": 4,
+            "published": {"n_routed_experts": 8}}
+    cfg = bench_model.config(
+        tiny, dtype=jnp.float32, dense_expert_rows=8, cell_lanes=16,
+        prefill_key_block=16,
+    )
+    assert cfg.experts_held == (4, 2) and cfg.num_experts == 8
+    held = init_params(jax.random.key(3), cfg)
+    eng = LLMEngine(cfg, params=held, max_batch=4, max_seq=192, page_size=8,
+                    prefill_chunk=16)
+    seen = _tapped(eng)
+    prompt = _prompt(0, 45)
+    (generated,) = eng.generate([prompt], SamplingParams(max_tokens=6))
+    tokens = prompt + generated[:-1]
+    prefills = [s for s in seen if s[0].startswith("prefill")]
+    decodes = [s for s in seen if s[0] == "decode"]
+    assert len(prefills) == 3 and len(decodes) == 5
+    routes = np.concatenate([s[2]["routes"] for s in prefills], 1)[:, :45]
+    routes = np.concatenate(
+        [routes] + [s[2]["routes"][:, :1] for s in decodes], 1
+    )
+    want, record = reference.forward_with_record(
+        held, jnp.asarray(tokens, jnp.int32), routes=jnp.asarray(routes),
+        **reference.for_model(tiny),
+    )
+    assert (np.sort(routes, -1) == np.sort(record["routes"], -1)).all()
+    got = [prefills[-1][1][0, 0]] + [s[1][0] for s in decodes]
+    np.testing.assert_allclose(
+        np.stack(got), np.asarray(want)[44:], atol=TOL, rtol=0
+    )
+
+    computed = given = 0
+    for live, (_, _, rec) in zip((16, 16, 13), prefills):
+        here, _, rows, pairs = (int(v) for v in rec["counts"])
+        in_share = (rec["routes"][:, :live] >= 4) & (rec["routes"][:, :live] < 6)
+        assert here == in_share.sum()
+        assert pairs == 16 * cfg.top_k * 3  # the padded rows' pairs too
+        assert here <= rows < pairs and rows % 8 == 0
+        assert rows - here < 8 * 3  # under a block a layer
+        computed, given = computed + rows, given + pairs
+    for _, _, rec in decodes:
+        assert rec["counts"][2:].tolist() == [0, 0]  # the every-row form
+    stats = eng.stats()
+    assert 0 < stats["moe_pairs_here"] < stats["moe_pairs_routed"]
+    assert stats["moe_rows_computed"] == computed
+    assert stats["moe_sorted_rows_pct"] == 100.0 * computed / given
 
 
 def test_kernel_and_gather_paths_give_identical_greedy_streams(

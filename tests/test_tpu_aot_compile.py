@@ -381,6 +381,27 @@ def _hybrid_moves(text: str, shapes: dict[str, tuple]) -> list[str]:
     return found
 
 
+def _expert_arrays_of(text: str, shape: tuple) -> list[str]:
+    """Every array type of ``shape``, whatever its dtype and layout, on
+    a line of a compiled program's text that belongs to the expert
+    layer: under one of `moe_ffn`'s scopes, or the compiler's grouped
+    matmul (which carries none)."""
+    dims = ",".join(str(n) for n in shape)
+    found = set()
+    for line in text.splitlines():
+        if "moe:" in line or "ragged-dot" in line:
+            found.update(re.findall(rf"\b\w+\[{dims}\]", line))
+    return sorted(found)
+
+
+def _grouped_matmul_tiles(text: str) -> set[str]:
+    """The (rows, contraction, columns) tiles the compiler's grouped
+    matmuls were handed in a compiled program: `moe._GROUP_TILE` reaches
+    the kernel as a frontend attribute, and a compiler that dropped it
+    would leave its own 512-row tiles, silently."""
+    return set(re.findall(r'ragged_dot_tiling="([\d,]+)"', text))
+
+
 @pytest.fixture(scope="module")
 def hybrid_programs(v5e):
     """nemotron3nano-serve1's own sizes (benchmarks/configs) at 6 of its
@@ -520,6 +541,16 @@ def test_latent_program_moves_no_pool_or_expert_stack_and_fits(
     assert len(_expert_kernel_calls(text)) == (program == "decode")
     if program == "decode":
         assert "latent_paged_attention" in text
+    else:
+        # The sorted form works on the pairs computed here, a block at a
+        # time: nothing the size of all 16,384 pairs' rows is gathered,
+        # and the sum back to tokens is not made over every pair.
+        k = conf["num_experts_per_tok"]
+        assert _expert_arrays_of(text, (2048 * k, d)) == []
+        assert _expert_arrays_of(text, (2048, k, d)) == []
+        # (The helper sees the expert layer's arrays: its blocks' rows.)
+        assert _expert_arrays_of(text, (1024, d)) != []
+        assert _grouped_matmul_tiles(text) == {"256,1024,512"}
     memory = compiled.memory_analysis()
     # Under the two layers' pool: no copy of it is among the temporaries.
     assert memory.temp_size_in_bytes < 2 * layer_pages * 2
@@ -601,6 +632,14 @@ def test_granite_program_moves_no_pages_state_or_stack_and_fits(
         # whatever the leading dimensions and the dtype.
         assert not re.search(rf"\[[\d,]*{chunk},{table}\]", text)
         assert memory.temp_size_in_bytes < 32 * chunk * table * 4 // 2
+        # The sorted form works on the pairs computed here, a block at a
+        # time: nothing the size of all 20,480 pairs' rows is gathered,
+        # and the sum back to tokens is not made over every pair.
+        k = conf["num_experts_per_tok"]
+        assert _expert_arrays_of(text, (chunk * k, d)) == []
+        assert _expert_arrays_of(text, (chunk, k, d)) == []
+        # (768 columns an expert: 512 does not divide them.)
+        assert _grouped_matmul_tiles(text) == {"256,1024,256", "256,1024,512"}
     pool = 2 * layer_pages * 2  # K and V of the one attention layer
     arguments = (
         family.held_parameters(conf) * 2 + pool

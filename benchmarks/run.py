@@ -19,6 +19,24 @@ worker or replica. No chip, no number: the exit code is then not 0 and
 no result is printed. The last line of standard output is the one JSON
 object of the contract; everything else is printed before it.
 
+A run enters and leaves on free chips (``benchmarks/chipwait.py``). After
+the cell is loaded and before the runner starts, and again after the
+runner has shut the cluster down and before the result is printed, this
+process asks the chip's device nodes themselves whether they open: the
+kernel goes on closing a dead holder's chips for 14-23 s after its pid is
+gone, ``open()`` is the only thing that answers, and a four-chip run
+started into that fails at ``jax.devices()``. Each wait prints one
+``[bench] chips free after <s> s`` line. The entering wait is at most
+``ENTER_TIMEOUT_S``; when it runs out the run exits non-zero, naming the
+busy nodes, and prints no result. Those seconds are somebody else's
+chips, not this run's start-up: ``setup_s`` starts at ``T_START`` plus
+the seconds waited entering (on free chips, one probe's ~20 ms), and
+the runners get that as their ``t_start``. The leaving wait, at most
+``LEAVE_TIMEOUT_S``, is made also when the run failed; when it runs out
+a warning is printed and the result still is, since the measurement is
+whole. It keeps the next run's entering wait near zero and protects a
+next run that does not ask.
+
 ``--rehearse <file>`` runs a cell of another list (the tiny ones under
 ``benchmarks/tests/``) on fake chips on the CPU, to rehearse the harness.
 It cannot name a cell of ``BENCHMARK.json``.
@@ -42,6 +60,12 @@ ROOT = os.path.dirname(HERE)
 # shadow the standard library's in every worker that inherits the path.
 sys.path[:] = [p for p in sys.path if os.path.abspath(p or ".") != HERE]
 sys.path.insert(0, ROOT)
+
+from benchmarks.chipwait import wait_chips_free  # noqa: E402
+
+# How long a run waits for the chip's device nodes to open, at each end.
+ENTER_TIMEOUT_S = 60.0
+LEAVE_TIMEOUT_S = 60.0
 
 
 def load_json(*parts: str) -> dict:
@@ -139,21 +163,17 @@ def load_cell(workload: str, rehearse: str | None):
     return listing, cell, conf, traffic
 
 
-def main() -> None:
-    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--workload", required=True)
-    ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--seconds", type=float, required=True)
-    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
-    ap.add_argument("--rehearse", metavar="LISTING",
-                    help="a listing of tiny cells to run on the CPU")
-    args = ap.parse_args()
+def chips_free(end: str, timeout_s: float) -> tuple[float, str]:
+    """Wait until the chip's device nodes open and say how long it took.
+    The seconds waited, and the nodes still busy when the time ran out."""
+    waited, seen, still = wait_chips_free(timeout_s)
+    print(f"[bench] chips free after {waited:.1f} s ({end}; busy: "
+          f"{' '.join(seen) or 'none'})", flush=True)
+    return waited, " ".join(still)
 
-    listing, cell, conf, traffic = load_cell(args.workload, args.rehearse)
 
-    runner = importlib.import_module(f"benchmarks.runners.{conf['runner']}")
-    measured = runner.run(cell, conf, traffic, args, T_START)
-
+def result_line(listing, cell, conf, traffic, args, measured) -> dict:
+    """The one JSON object of the contract, from what the runner measured."""
     from ray_tpu._private import chip
 
     if chip.holds_backend():
@@ -182,6 +202,36 @@ def main() -> None:
               f"missed a shape: {measured['counters'].get('compiled_in_window')}")
     result["metrics"] = metrics
     result["device"] = device
+    return result
+
+
+def main() -> None:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--rehearse", metavar="LISTING",
+                    help="a listing of tiny cells to run on the CPU")
+    args = ap.parse_args()
+
+    listing, cell, conf, traffic = load_cell(args.workload, args.rehearse)
+
+    # Seconds spent waiting for somebody else's chips are not set-up.
+    waited, busy = chips_free("entering", ENTER_TIMEOUT_S)
+    if busy:
+        sys.exit(f"benchmarks/run.py: {busy} still busy after {waited:.1f} s; "
+                 "no free chip to measure on")
+    runner = importlib.import_module(f"benchmarks.runners.{conf['runner']}")
+    try:
+        measured = runner.run(cell, conf, traffic, args, T_START + waited)
+        result = result_line(listing, cell, conf, traffic, args, measured)
+    finally:
+        # The runner has shut the cluster down; a run that failed waits too.
+        _, busy = chips_free("leaving", LEAVE_TIMEOUT_S)
+        if busy:
+            print(f"[bench] WARNING: {busy} still busy; the next run will "
+                  "find them so", flush=True)
     print(json.dumps(result), flush=True)
 
 

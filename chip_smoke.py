@@ -50,8 +50,9 @@ PRESET = "llama3_8b"  # d_model 4096, d_ff 14336, 32 q / 8 kv heads x 128
 # Each phase's sizes. "cfg" replaces fields of the preset; every such
 # replacement is a cut, repeated under "reduced" in the phase's record.
 SIZES = {
-    # bench_8b.py's cut, the largest its planner says fits 16 GB: fp32
-    # params + adamw + grads are ~14 B/param before activations.
+    # The largest cut the memory planner (train/memory.py) says fits
+    # 16 GB: fp32 params + adamw + grads are ~14 B/param before
+    # activations.
     # Compiled for a described v5e: peak 15.43 of 15.75 GiB.
     "train": {
         "cfg": {"n_layers": 4, "vocab_size": 8192, "attn_impl": "flash",
@@ -510,7 +511,6 @@ def _phase_check(name: str, check, cfg, sizes: dict, seed: int) -> dict:
             cfg, {**sizes["engine"], "seed": seed}, sizes, seed
         )
     )
-    wait_chip_free()
     return _emit({
         "phase": name,
         "cfg": sizes["cfg"],
@@ -552,24 +552,6 @@ def _emit(record: dict) -> dict:
     return record
 
 
-def wait_chip_free(timeout_s: float = 60.0) -> None:
-    """Until every chip-holding worker process is gone and its chips are
-    back in the node's pool: the next phase's worker must find the chip
-    unlocked."""
-    rt = ray_tpu.api._runtime
-    total = ray_tpu.cluster_resources().get("TPU", 0)
-    deadline = time.monotonic() + timeout_s
-    while time.monotonic() < deadline:
-        workers = rt.run(rt.core.node.call("list_workers"))["workers"]
-        held = [w for w in workers if w["platform"] == "tpu"]
-        if not held and ray_tpu.available_resources().get("TPU", 0) == total:
-            return
-        time.sleep(0.2)
-    raise SmokeFailure(
-        f"chip-holding workers still alive after {timeout_s:.0f} s: {held}"
-    )
-
-
 def phase_train(name: str, sizes: dict, seed: int, chips: int) -> dict:
     from ray_tpu.train import JaxTrainer, RunConfig, ScalingConfig
 
@@ -586,7 +568,6 @@ def phase_train(name: str, sizes: dict, seed: int, chips: int) -> dict:
         ).fit()
     if result.error is not None:
         raise result.error
-    wait_chip_free()
     return _emit({
         "phase": name,
         "preset": PRESET,
@@ -660,7 +641,6 @@ def phase_serve(sizes: dict, seed: int) -> list[dict]:
         stats = _post(port, {"method": "stats"})
     finally:
         serve.shutdown()
-    wait_chip_free()
     served = _emit({
         "phase": "serve",
         "preset": PRESET,
@@ -682,7 +662,6 @@ def phase_serve(sizes: dict, seed: int) -> list[dict]:
             cfg, engine_kwargs, sizes, seed
         )
     )
-    wait_chip_free()
     checked = _emit({
         "phase": "engine_check",
         "preset": PRESET,

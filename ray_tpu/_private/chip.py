@@ -7,11 +7,9 @@ driver stay on the CPU. The node applies the rule at lease grant
 real or fake, as a process of its own; where they are real that process
 calls :func:`hold_chip` before any of its code can create a backend,
 and either way it exits when the lease ends, because a process that has
-opened the chip keeps it until it dies. Single-process scripts that
-take the chip themselves (``bench.py`` and friends) call
-:func:`hold_chip` the same way. Such a process also says how it became
-useful (:func:`watch_startup`): a span around the backend's creation
-and one a compile request.
+opened the chip keeps it until it dies. Such a process also says how it
+became useful (:func:`watch_startup`): a span around the backend's
+creation and one a compile request.
 
 The module also keeps the one table of published per-chip peaks, keyed
 by the ``device_kind`` JAX reports.
@@ -203,7 +201,9 @@ def platform() -> str:
             raise
         raise ChipUnavailableError(
             "this process holds a TPU lease but JAX could not open the "
-            f"chip (is another process holding it?): {e}"
+            "chip; its node saw the chip's device nodes open when it "
+            "granted the lease (NodeManager._chips_let_go), so another "
+            f"process has taken it since: {e}"
         ) from e
     if _promised and found != "tpu":
         raise ChipUnavailableError(

@@ -13,6 +13,7 @@ from __future__ import annotations
 import asyncio
 import atexit
 import functools
+import logging
 import os
 import threading
 import time
@@ -23,7 +24,11 @@ from ray_tpu._private.ids import JobID
 from ray_tpu.exceptions import RayTpuError
 from ray_tpu.runtime.core_worker import ActorSubmitTarget, CoreWorker
 
+logger = logging.getLogger(__name__)
+
 _DEFAULT_TIMEOUT = None
+# shutdown(): what the teardown gets beyond the node's own bounds.
+TEARDOWN_SLACK_S = 10.0
 
 
 class _Runtime:
@@ -238,6 +243,12 @@ def init(
 
 
 def shutdown() -> None:
+    """End this session. What a driver that started the cluster may
+    rely on afterwards: every process the node started has been reaped
+    and the chips this session leased open again (``NodeManager.stop``
+    has asked their device nodes), so the next job on this host may
+    take them at once. Where that outlasts the node's own bounds, a
+    warning names the pids and the device nodes."""
     if not _runtime.ready:
         return
     if _runtime.mode in ("driver", "client"):
@@ -255,11 +266,20 @@ def shutdown() -> None:
         if _runtime.head is not None:
             await _runtime.head.stop()
 
+    from ray_tpu.runtime import node as _node
+
+    # The node's own bounds and some seconds for everything else: the
+    # limit never cuts the node's reap and its look at the chips short.
+    limit = _node.STOP_TERM_S + _node.CHIP_FREE_TIMEOUT_S + TEARDOWN_SLACK_S
     try:
-        _runtime.run(_teardown(), timeout=10)
+        _runtime.run(_teardown(), timeout=limit)
     # tpulint: allow(broad-except reason=shutdown is best-effort by contract; a half-dead runtime loop must not prevent the store destroy and process exit below)
     except Exception:  # noqa: BLE001
-        pass
+        logger.warning(
+            "shutdown: the teardown did not end within %.0f s; processes "
+            "of this session may be alive and its chips held",
+            limit, exc_info=True,
+        )
     if _runtime.mode in ("driver", "client"):
         # Driver (observer, client) sessions own their store dir; worker
         # processes share their node's and must not delete it.

@@ -34,7 +34,27 @@ logger = logging.getLogger(__name__)
 
 IDLE_WORKER_CAP = 4  # idle processes kept warm per node
 SPAWN_TIMEOUT_S = 30.0
+# How long a chip lease, and stop() after its SIGKILLs, wait for a chip
+# that is being let go: the kernel goes on closing a dead holder's
+# device nodes for up to 23.5 s after its pid is gone (PERF.md section 7).
+CHIP_FREE_TIMEOUT_S = 60.0
+# stop(): what all children together get between SIGTERM and SIGKILL.
+STOP_TERM_S = 2.0
 PENDING_SPILL_S = 2.0  # queued lease age before bouncing to spillback
+
+
+def _wait_all(
+    procs: "list[subprocess.Popen]", deadline: float
+) -> "list[subprocess.Popen]":
+    """Reap ``procs`` until ``deadline`` (``time.monotonic()``); those
+    still alive then. Blocks: call it off the event loop."""
+    alive = []
+    for proc in procs:
+        try:
+            proc.wait(timeout=max(0.0, deadline - time.monotonic()))
+        except subprocess.TimeoutExpired:
+            alive.append(proc)
+    return alive
 
 
 _mem_frac_cache: "tuple[float, float]" = (-1.0, 0.0)  # (ts, value)
@@ -423,8 +443,13 @@ class NodeManager:
         from ray_tpu._private.accelerators import TPUAcceleratorManager
 
         self._real_chips = TPUAcceleratorManager().real_chips()
-        # Killed chip-holding workers that may not have exited yet.
+        # Killed workers that may not be gone yet: chip holders whose
+        # lease ended and, in stop(), whatever outlived SIGTERM. Read
+        # by _reap_dying alone.
         self._dying_chip_procs: list[subprocess.Popen] = []
+        # Whether a worker of this node has been sent to the real chips:
+        # stop() then sees them open before it returns.
+        self._leased_real_chips = False
         # startup:* spans of this node, sent to the head in batches over
         # the connection it already holds (_emit_span).
         self._spans: list[dict] = []
@@ -523,17 +548,31 @@ class NodeManager:
         await self.flush_spans()
         if self.agent is not None:
             await self.agent.stop()
-        for w in self.workers.values():
-            proc = w.get("proc")
-            if proc and proc.poll() is None:
+        # When this returns, no worker in the node's table and none
+        # that held a chip is alive or a zombie, and the chips its
+        # workers held open again for the next job on this host.
+        procs = [w["proc"] for w in self.workers.values() if w.get("proc")]
+        for proc in procs:
+            if proc.poll() is None:
                 proc.terminate()
+        term_by = time.monotonic() + STOP_TERM_S
+        for proc in await asyncio.to_thread(_wait_all, procs, term_by):
+            proc.kill()
+            self._dying_chip_procs.append(proc)
+        deadline = time.monotonic() + CHIP_FREE_TIMEOUT_S
+        _, left = await self._reap_dying(deadline)
+        if not left and self._leased_real_chips:
+            # A chip that a live process of somebody else's holds by
+            # now is not this node's to wait for.
+            _, left = await self._chips_let_go(deadline, others=False)
+        if left:
+            logger.warning(
+                "node %s stopped, and %.0f s after its SIGKILLs "
+                "something it started is not gone or a chip it leased "
+                "is not free: %s",
+                self.node_id[:8], CHIP_FREE_TIMEOUT_S, "; ".join(left),
+            )
         for w in self.workers.values():
-            proc = w.get("proc")
-            if proc:
-                try:
-                    proc.wait(timeout=2)
-                except subprocess.TimeoutExpired:
-                    proc.kill()
             core = w.get("core")
             if core is not None:
                 # Inproc workers (WORKER_MODE=inproc) have no process
@@ -914,25 +953,97 @@ class NodeManager:
         worker may already have created a CPU backend and cannot switch;
         and a process that has opened the chip keeps it until it dies,
         so the worker is started for this lease, killed when the lease
-        ends (_on_return_lease), and not started before the chip
-        workers this node killed earlier are gone. Fake chips
-        (``platform`` "cpu") take the same road, so that what a test or
-        a rehearsal starts and measures is what the chip's lease does."""
+        ends (_on_return_lease), and not granted before the chip is
+        free: the chip workers this node killed are reaped first, and
+        the device is asked while the new process boots (it opens no
+        chip before its first task). Fake chips (``platform`` "cpu")
+        have no device to ask and take the same road otherwise, so that
+        what a test or a rehearsal starts and measures is what the
+        chip's lease does."""
+        from ray_tpu._private.chip import ChipUnavailableError
+
+        def unavailable(left: list[str]) -> ChipUnavailableError:
+            return ChipUnavailableError(
+                f"the chip was not free {CHIP_FREE_TIMEOUT_S:.0f} s after "
+                f"this lease asked for it: {'; '.join(left)}"
+            )
+
         wait_began = time.time()
-        dying, self._dying_chip_procs = self._dying_chip_procs, []
-        for proc in dying:
-            await asyncio.to_thread(proc.wait)
+        deadline = time.monotonic() + CHIP_FREE_TIMEOUT_S
+        procs, left = await self._reap_dying(deadline)
+        if left:
+            raise unavailable(left)
         waited = time.time() - wait_began
         worker_id = self._spawn_worker(
             runtime_env, ehash=ehash, platform=platform, chips=chips
         )
-        self._emit_span(
-            "startup:chip_free_wait", wait_began, waited,
-            worker_id=worker_id, procs=len(dying),
-        )
         fut = asyncio.get_running_loop().create_future()
         self.workers[worker_id]["waiter"] = fut
+        waited_for: dict = {}
+        if platform == "tpu":
+            self._leased_real_chips = True
+            waited_for, left = await self._chips_let_go(deadline, others=True)
+            if left:
+                self._kill_worker(worker_id)
+                raise unavailable(left)
+            waited = time.time() - wait_began
+        self._emit_span(
+            "startup:chip_free_wait", wait_began, waited,
+            worker_id=worker_id, procs=procs, **waited_for,
+        )
         return await asyncio.wait_for(fut, SPAWN_TIMEOUT_S)
+
+    async def _reap_dying(self, deadline: float) -> tuple[int, list[str]]:
+        """Wait, off the event loop, for the workers this node killed to
+        be gone: how many there were, and which are not gone at
+        ``deadline`` (``time.monotonic()``)."""
+        dying, self._dying_chip_procs = self._dying_chip_procs, []
+        if not dying:  # a first lease: no thread to start
+            return 0, []
+        alive = await asyncio.to_thread(_wait_all, dying, deadline)
+        self._dying_chip_procs.extend(alive)
+        return len(dying), [
+            f"pid {proc.pid} (killed, not gone)" for proc in alive
+        ]
+
+    async def _chips_let_go(
+        self, deadline: float, others: bool
+    ) -> tuple[dict, list[str]]:
+        """Wait until ``tpu.busy_chips`` finds this host's chips free.
+        A device node that only this node's own live workers have open
+        is never waited for (they keep it while their leases last); one
+        that no process has open is being closed by the kernel and
+        always is; one that a live process of somebody else's holds is
+        waited for with ``others`` (a lease needs the chip) and not
+        without (stop() owes nobody that). Returns what was waited for,
+        as ``startup:chip_free_wait``'s attributes, and what is still
+        held at ``deadline`` (``time.monotonic()``); a blocked open can
+        end later than that."""
+        from ray_tpu._private.accelerators import tpu
+
+        nodes: set[str] = set()
+        holders: set[int] = set()
+        while True:
+            own = {
+                w["proc"].pid for w in self.workers.values() if w.get("proc")
+            }
+            busy = {
+                node: pids
+                for node, pids in (
+                    await asyncio.to_thread(tpu.busy_chips)
+                ).items()
+                if not pids or (others and not set(pids) <= own)
+            }
+            if not busy or time.monotonic() >= deadline:
+                break
+            nodes.update(busy)
+            holders.update(*busy.values())
+            await asyncio.sleep(0.2)
+        return {"nodes": sorted(nodes), "holders": sorted(holders)}, [
+            f"{node} (open in pids {pids})" if pids else
+            f"{node} (open in no process: the kernel is still closing it)"
+            for node, pids in sorted(busy.items())
+        ]
 
     def _emit_span(self, name: str, start: float, dur: float, **attrs):
         """A completed ``startup:*`` span of this node, in the shape of

@@ -216,8 +216,7 @@ def plan_bench8b(
     n_layers: int, batch: int, seq: int = 4096, hbm_gb: float = 16.0
 ) -> MemoryPlan:
     """The exact BENCH_8B recipe, priced: full-size llama3-8b layers,
-    8k-row vocab shard, bf16 adamw mu, remat=full, seq 4096 (see
-    bench_8b.py run())."""
+    8k-row vocab shard, bf16 adamw mu, remat=full, seq 4096."""
     import dataclasses as _dc
 
     from ray_tpu.models import PRESETS

@@ -477,10 +477,10 @@ def profile_train_step(
     cfg=None, batch_size: int = 8, seq: int | None = None,
     steps: int | None = None,
 ) -> dict:
-    """One-process convenience used by bench.py and the CPU acceptance
-    test: statically profile the flagship step, run ``steps`` of it
-    under the tracer, and return the joined attribution report (with
-    the static profile under ``"static"``)."""
+    """One-process convenience used by the CPU acceptance test:
+    statically profile the flagship step, run ``steps`` of it under the
+    tracer, and return the joined attribution report (with the static
+    profile under ``"static"``)."""
     import jax
     import jax.numpy as jnp
 

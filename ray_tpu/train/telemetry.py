@@ -9,7 +9,7 @@ completion it
 - observes per-phase durations into ``ray_tpu_train_step_phase_seconds``
   (data-wait / compute / collective / checkpoint / whole step),
 - computes per-step MFU from the step's FLOP count against the chip
-  generation's peak (the same table bench.py normalizes with) and sets
+  generation's peak (``chip.CHIP_SPECS``) and sets
   ``ray_tpu_train_mfu``,
 - emits ``train:step`` / ``train:<phase>`` SPAN events onto the
   task-event pipeline. Rank 0's step spans are what the head folds into

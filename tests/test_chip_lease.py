@@ -6,7 +6,10 @@ started for a chip lease (on a host that only *says* it has a chip), and
 chip_smoke.py's control flow at a tiny size on the CPU.
 """
 
+import asyncio
+import errno
 import json
+import logging
 import os
 import subprocess
 import sys
@@ -122,17 +125,23 @@ def test_peak_table_knows_the_chip_and_refuses_to_guess():
     assert profile.ici_bandwidth_per_chip() == 200e9
 
 
-def _pid_gone(pid: int, timeout_s: float = 10.0) -> bool:
+def _pid_gone(
+    pid: int, timeout_s: float = 10.0, zombie_ok: bool = True
+) -> bool:
+    """A zombie has let its chip go but is not reaped: enough for a
+    lease's end, not for ``shutdown()``."""
     deadline = time.monotonic() + timeout_s
-    while time.monotonic() < deadline:
+    while True:
         try:
             with open(f"/proc/{pid}/stat") as f:
-                if f.read().rsplit(")", 1)[1].split()[0] == "Z":
-                    return True
+                state = f.read().rsplit(")", 1)[1].split()[0]
+            if zombie_ok and state == "Z":
+                return True
         except FileNotFoundError:
             return True
+        if time.monotonic() >= deadline:
+            return False
         time.sleep(0.1)
-    return False
 
 
 def test_chip_lease_gets_its_own_process_and_the_process_ends_with_it(
@@ -194,6 +203,241 @@ def test_fake_chip_lease_stays_on_the_cpu_in_a_process_of_its_own(fake_chips):
     # Scheduled like a lease of real chips: started for it, ended with it.
     assert ray_tpu.get(pooled_pid.remote()) != pid
     assert _pid_gone(pid), "the fake-chip worker outlived its lease"
+
+
+@ray_tpu.remote
+def _where():
+    return os.getpid(), os.environ["JAX_PLATFORMS"]
+
+
+@ray_tpu.remote(num_tpus=1)
+class ChipHolder:
+    def pid(self):
+        return os.getpid()
+
+
+@pytest.mark.parametrize("lease", ["returned_just_before", "still_held"])
+def test_shutdown_returns_with_the_chip_worker_reaped(fake_chips, lease):
+    """After shutdown() the process that held the chip is gone: not
+    killed and left to die, and not a zombie of this process."""
+    ray_tpu.init(num_cpus=2)
+    node = ray_tpu.api._runtime.node
+    if lease == "still_held":
+        holder = ChipHolder.remote()
+        pid = ray_tpu.get(holder.pid.remote())
+    else:
+        # The submitter returns an idle lease after a second, and the
+        # node kills the worker that held it.
+        pid, _ = ray_tpu.get(_where.options(num_tpus=1).remote())
+        deadline = time.monotonic() + 10
+        while not node._dying_chip_procs and time.monotonic() < deadline:
+            time.sleep(0.005)
+        assert [p.pid for p in node._dying_chip_procs] == [pid]
+    ray_tpu.shutdown()
+    assert _pid_gone(pid, timeout_s=0, zombie_ok=False)
+    assert node._dying_chip_procs == []
+
+
+SLEEPER = (
+    "import signal, sys, time\n"
+    "signal.signal(signal.SIGTERM, signal.SIG_IGN)\n"
+    "held = [open(p) for p in sys.argv[1:]]\n"
+    "print('ready', flush=True)\n"
+    "time.sleep(120)\n"
+)
+
+
+def _sleeper(*paths) -> subprocess.Popen:
+    """A process that ignores SIGTERM and holds ``paths`` open."""
+    proc = subprocess.Popen(
+        [sys.executable, "-c", SLEEPER, *map(str, paths)],
+        stdout=subprocess.PIPE, text=True,
+    )
+    assert proc.stdout.readline() == "ready\n"
+    return proc
+
+
+def test_stop_gives_every_child_one_deadline_and_reaps_what_it_kills():
+    from ray_tpu.runtime import node as node_mod
+
+    ray_tpu.init(num_cpus=1)
+    node = ray_tpu.api._runtime.node
+    sleepers = [_sleeper() for _ in range(3)]
+    for i, proc in enumerate(sleepers):
+        node.workers[f"sleeper{i}"] = {"proc": proc, "state": "idle"}
+    began = time.monotonic()
+    ray_tpu.shutdown()
+    took = time.monotonic() - began
+    # SIGTERM, one wait for all of them, SIGKILL, reaped: not a wait each.
+    assert node_mod.STOP_TERM_S <= took < 3 * node_mod.STOP_TERM_S - 1
+    for proc in sleepers:
+        assert proc.returncode == -9
+        assert _pid_gone(proc.pid, timeout_s=0, zombie_ok=False)
+
+
+def test_shutdown_says_so_when_the_teardown_overruns(monkeypatch, caplog):
+    from ray_tpu import api
+    from ray_tpu.runtime import node as node_mod
+
+    ray_tpu.init(num_cpus=1)
+    node = api._runtime.node
+    procs = [w["proc"] for w in node.workers.values() if w.get("proc")]
+
+    async def never_ends():
+        await asyncio.sleep(60)
+
+    monkeypatch.setattr(node, "stop", never_ends)
+    monkeypatch.setattr(node_mod, "STOP_TERM_S", 0.1)
+    monkeypatch.setattr(node_mod, "CHIP_FREE_TIMEOUT_S", 0.1)
+    monkeypatch.setattr(api, "TEARDOWN_SLACK_S", 0.1)
+    try:
+        with caplog.at_level(logging.WARNING, logger="ray_tpu.api"):
+            began = time.monotonic()
+            ray_tpu.shutdown()
+            took = time.monotonic() - began
+        assert not ray_tpu.is_initialized() and took < 10
+        (said,) = [r for r in caplog.records if r.name == "ray_tpu.api"]
+        assert "did not end within 0 s" in said.getMessage()
+        assert said.exc_info[0].__name__ == "TimeoutError"
+    finally:  # what the teardown that never ran would have reaped
+        for proc in procs:
+            proc.kill()
+            proc.wait()
+
+
+@pytest.fixture
+def chip_files(tmp_path, monkeypatch):
+    """Two files where the host's chips would be, and one beside them."""
+    from ray_tpu._private.accelerators import tpu
+
+    monkeypatch.setattr(
+        tpu, "_CHIP_NODE_GLOBS",
+        (str(tmp_path / "accel*"), str(tmp_path / "vfio" / "*")),
+    )
+    (tmp_path / "vfio").mkdir()
+    chips = [str(tmp_path / "vfio" / n) for n in "01"]
+    for path in (*chips, tmp_path / "other"):
+        open(path, "w").close()
+    return chips
+
+
+def _refusing(node: str, code: int | None):
+    """An opener that refuses ``node`` with ``code`` and opens the rest."""
+    def opener(path, flags):
+        assert flags == os.O_RDWR
+        if path == node and code is not None:
+            raise OSError(code, os.strerror(code), path)
+        return os.open(path, flags)
+
+    return opener
+
+
+@pytest.mark.parametrize(
+    "code, busy",
+    [(errno.EBUSY, True), (errno.ENOENT, False), (errno.EACCES, False),
+     (errno.EPERM, False), (None, False)],
+)
+def test_a_chip_is_busy_while_its_open_says_ebusy(chip_files, code, busy):
+    from ray_tpu._private.accelerators import tpu
+
+    assert tpu.chip_nodes() == chip_files
+    fds_before = len(os.listdir("/proc/self/fd"))
+    got = tpu.busy_chips(opener=_refusing(chip_files[1], code))
+    assert got == ({chip_files[1]: []} if busy else {})
+    assert len(os.listdir("/proc/self/fd")) == fds_before  # closed at once
+
+
+def test_busy_chips_names_the_live_holder_and_nothing_once_reaped(
+    chip_files,
+):
+    """The pids tell a live holder from a group the kernel is closing;
+    the verdict is the open's either way."""
+    from ray_tpu._private.accelerators import tpu
+
+    refused = _refusing(chip_files[1], errno.EBUSY)
+    other = os.path.join(os.path.dirname(chip_files[0]), os.pardir, "other")
+    bystander = _sleeper(other)
+    holder = _sleeper(chip_files[1])
+    try:
+        assert tpu.busy_chips(opener=refused) == {
+            chip_files[1]: [holder.pid]
+        }
+        # Open in a process, and it opens: a device that more than one
+        # may hold, or a file. Not busy.
+        assert tpu.busy_chips() == {}
+    finally:
+        for proc in (holder, bystander):
+            proc.kill()
+            proc.wait()
+    assert tpu.busy_chips(opener=refused) == {chip_files[1]: []}
+    assert tpu.busy_chips(nodes=[chip_files[0]], opener=refused) == {}
+
+
+def test_no_chip_node_no_busy_chip(tmp_path, monkeypatch):
+    from ray_tpu._private.accelerators import tpu
+
+    monkeypatch.setattr(tpu, "_CHIP_NODE_GLOBS", (str(tmp_path / "accel*"),))
+    assert tpu.chip_nodes() == [] and tpu.busy_chips() == {}
+    assert TPUAcceleratorManager().real_chips() == 0
+
+
+@pytest.mark.parametrize(
+    "pids, named",
+    [([4242, 4243], r"open in pids \[4242, 4243\]"),
+     ([], "open in no process")],
+)
+def test_chip_lease_fails_typed_when_the_chip_is_never_let_go(
+    visible_chip, monkeypatch, pids, named
+):
+    from ray_tpu._private.accelerators import tpu
+    from ray_tpu.runtime import node as node_mod
+
+    monkeypatch.setattr(tpu, "busy_chips", lambda: {"/dev/vfio/0": pids})
+    monkeypatch.setattr(node_mod, "CHIP_FREE_TIMEOUT_S", 0.5)
+    ray_tpu.init(num_cpus=2)
+    node = ray_tpu.api._runtime.node
+    with pytest.raises(
+        Exception, match=rf"ChipUnavailableError.*/dev/vfio/0 \({named}"
+    ):
+        ray_tpu.get(_where.options(num_tpus=1).remote(), timeout=60)
+    # The node gave the chip back to its pool, killed the worker it had
+    # started for the lease, and serves on.
+    assert node.available["TPU"] == 1.0
+    assert not [w for w in node.workers.values() if w.get("chips")]
+    assert ray_tpu.get(_where.remote(), timeout=60)[1] == "cpu"
+
+
+def test_a_chip_this_nodes_own_worker_holds_is_not_waited_for(monkeypatch):
+    """A one-chip worker opens every group of its host: the next chip
+    lease of the same node finds them busy and held by a worker whose
+    lease lasts, which is as it should be."""
+    from ray_tpu._private.accelerators import tpu
+    from ray_tpu.runtime import node as node_mod
+    from ray_tpu.util import state
+
+    monkeypatch.setenv("TPU_VISIBLE_CHIPS", "0,1")
+    monkeypatch.setattr(node_mod, "CHIP_FREE_TIMEOUT_S", 2.0)
+    ray_tpu.init(num_cpus=2)
+    try:
+        holder = ChipHolder.remote()
+        held_by = ray_tpu.get(holder.pid.remote())
+        monkeypatch.setattr(
+            tpu, "busy_chips",
+            lambda: {"/dev/vfio/0": [held_by], "/dev/vfio/1": [held_by]},
+        )
+        pid, env = ray_tpu.get(
+            _where.options(num_tpus=1).remote(), timeout=60
+        )
+        assert env == "tpu" and pid != held_by
+        rt = ray_tpu.api._runtime
+        rt.run(rt.node.flush_spans(), timeout=10)
+        (row,) = [w for w in state.startup_report()["workers"]
+                  if w.get("pid") == pid]
+        wait = row["spans"]["startup:chip_free_wait"]
+        assert (wait["nodes"], wait["holders"]) == ([], [])
+        assert wait["dur"] < 1.0
+    finally:
+        ray_tpu.shutdown()
 
 
 def test_engine_stats_say_where_it_ran():

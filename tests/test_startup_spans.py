@@ -129,6 +129,65 @@ def test_chip_lease_actor_leaves_every_span_in_order(cluster):
     assert row["worker_id"][:8] in text and "chip_open" in text
 
 
+@ray_tpu.remote(num_tpus=1)
+def where_it_ran():
+    return os.getpid(), os.environ["JAX_PLATFORMS"]
+
+
+def test_chip_free_wait_covers_a_chip_that_is_being_let_go(monkeypatch):
+    """The job before this one ended seconds ago: a process still has
+    the chip's device node open, then nobody has and the kernel is
+    closing it, and one open of it blocks. On a host that says its chip
+    is real (it has none) the lease waits for all of that inside
+    ``startup:chip_free_wait``, beside its worker's boot and off the
+    node's event loop, and is granted once the node opens."""
+    from ray_tpu._private.accelerators import tpu
+
+    asked = []  # when the node asked, first to last
+
+    def busy_chips():
+        asked.append(time.monotonic())
+        age = asked[-1] - asked[0]
+        if len(asked) == 1:
+            time.sleep(0.6)  # an open that blocks
+        if age < 1:
+            return {"/dev/vfio/0": [4242] if age < 0.5 else []}
+        return {}
+
+    monkeypatch.setenv("TPU_VISIBLE_CHIPS", "0")
+    monkeypatch.setattr(tpu, "busy_chips", busy_chips)
+    ray_tpu.init(num_cpus=2)
+    try:
+        rt = ray_tpu.api._runtime
+        ref = where_it_ran.remote()
+        while not asked:
+            time.sleep(0.005)
+        # Inside the open that blocks: the node answers all the same.
+        t = time.monotonic()
+        workers = rt.run(rt.core.node.call("list_workers"), timeout=10)
+        assert time.monotonic() - t < 0.3 and len(asked) == 1
+        assert "tpu" in {w["platform"] for w in workers["workers"]}
+        pid, env = ray_tpu.get(ref, timeout=60)
+        assert env == "tpu"
+        (row,) = chip_rows(report(rt))
+        looks = len(asked)
+    finally:
+        ray_tpu.shutdown()
+    # stop() looked once more, the node having leased a real chip.
+    assert len(asked) == looks + 1
+    del asked[looks:]
+    wait, spawn = (row["spans"][k]
+                   for k in ("startup:chip_free_wait", "startup:spawn"))
+    assert row["pid"] == pid
+    assert (wait["procs"], wait["holders"], wait["nodes"]) == (
+        0, [4242], ["/dev/vfio/0"],
+    )
+    assert 1 <= asked[-1] - asked[0] <= wait["dur"] < 10
+    assert wait["dur"] == row["phases"]["chip_free_wait"]
+    # The process boots meanwhile: it opens no chip before its first task.
+    assert wait["ts"] <= spawn["ts"] < end(wait)
+
+
 def tiny_loop(config):
     import jax
     import jax.numpy as jnp

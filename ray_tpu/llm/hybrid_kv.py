@@ -1,7 +1,7 @@
 """Serving programs for a model whose blocks differ in kind
-(``models/nemotron_h.py``, ``models/granite_hybrid.py``): pages for the
-blocks that attend, per-slot recurrent state for the blocks that carry
-one.
+(``models/nemotron_h.py``, ``models/granite_hybrid.py``,
+``models/qwen3_next.py``): pages for the blocks that attend, per-slot
+recurrent state for the blocks that carry one.
 
 A block here is one SUBLAYER, a letter of ``cfg.pattern``: a mixer or
 an expert FFN behind its own norm and a residual add. Nemotron-H's
@@ -9,6 +9,10 @@ layers are one each; a Granite 4.0-H layer is two (its mixer, then
 ``E``) and multiplies each sublayer's output, the embedding, the
 attention scores and the logits by numbers of its config, which the
 programs skip where they are 1 (``_embed``, ``_residual``, ``_head``).
+A Qwen3-Next layer is two as well; its recurrent mixer is another
+letter (``G``, the gated delta rule) with its own leaves of the cache,
+and its attention block norms, rotates and gates (`_attention_inputs`),
+which the other families' configs hold off.
 
 The cache is ONE donated tree with two kinds of per-sequence state:
 
@@ -17,7 +21,11 @@ The cache is ONE donated tree with two kinds of per-sequence state:
   through block tables;
 - ``ssm`` ``[L_mamba, max_batch, H, P, N]`` float32 and ``conv``
   ``[L_mamba, max_batch, K - 1, conv_dim]``: each decode SLOT's Mamba-2
-  state and convolution tail. It is not paged and does not grow.
+  state and convolution tail. It is not paged and does not grow. Where
+  the pattern has ``G`` blocks, ``gdn`` ``[L_gdn, max_batch, Hv, dk, dv]``
+  float32 and ``gdn_conv`` ``[L_gdn, max_batch, K - 1, conv_dim]`` are
+  the same for them: a matrix a head. A cache holds the leaves of the
+  kinds its pattern has (`_RECURRENT`).
 
 Both are carried through the Python loop over the pattern and updated in
 place (a block writes its own layer's slot rows; nothing is sliced out
@@ -44,7 +52,9 @@ lie, with no scores over the table in HBM), the expert mixer is
 from __future__ import annotations
 
 import functools
+from collections.abc import Callable
 from functools import partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -57,7 +67,6 @@ from ray_tpu.llm.paged_kv import (
     _flat_pool,
     _gather_page_attention,
     _prefill_kernel_attention,
-    _project_qkv,
     _sample_tokens,
     _write_pages,
     init_paged_kv,
@@ -70,6 +79,7 @@ from ray_tpu.models.nemotron_h import (
     mamba_chunked,
     mamba_step,
 )
+from ray_tpu.models.qwen3_next import gdn_chunked, gdn_step
 from ray_tpu.ops.norms import rms_norm
 
 HybridCache = dict[str, jnp.ndarray]
@@ -87,19 +97,48 @@ HybridCache = dict[str, jnp.ndarray]
 _DENSE_ATTENTION_KEYS = 1024
 
 
+class _Recurrent(NamedTuple):
+    """A kind of recurrent block: its mixer over many tokens and over
+    one, the cache's leaves for its state and its convolution tail, the
+    prefix of its named scopes, and a slot's (state shape, convolution
+    channels) from a config."""
+
+    chunked: Callable
+    step: Callable
+    state: str
+    conv: str
+    scope: str
+    shapes: Callable
+
+
+_RECURRENT = {
+    "M": _Recurrent(
+        mamba_chunked, mamba_step, "ssm", "conv", "ssm",
+        lambda c: ((c.mamba_heads, c.mamba_head_dim, c.ssm_state), c.conv_dim),
+    ),
+    "G": _Recurrent(
+        gdn_chunked, gdn_step, "gdn", "gdn_conv", "gdn",
+        lambda c: (
+            (c.gdn_value_heads, c.gdn_key_dim, c.gdn_value_dim),
+            c.gdn_conv_dim,
+        ),
+    ),
+}
+
+
 def init_hybrid_cache(
     cfg: NemotronHConfig, num_pages: int, page_size: int, max_batch: int
 ) -> HybridCache:
-    n_mamba = cfg.count("M")
     cache = init_paged_kv(cfg, num_pages, page_size, n_layers=cfg.count("*"))
-    cache["ssm"] = jnp.zeros(
-        (n_mamba, max_batch, cfg.mamba_heads, cfg.mamba_head_dim,
-         cfg.ssm_state),
-        jnp.float32,
-    )
-    cache["conv"] = jnp.zeros(
-        (n_mamba, max_batch, cfg.conv_kernel - 1, cfg.conv_dim), cfg.dtype
-    )
+    for kind, block in _RECURRENT.items():
+        if n := cfg.count(kind):
+            state_shape, channels = block.shapes(cfg)
+            cache[block.state] = jnp.zeros(
+                (n, max_batch, *state_shape), jnp.float32
+            )
+            cache[block.conv] = jnp.zeros(
+                (n, max_batch, cfg.conv_kernel - 1, channels), cfg.dtype
+            )
     return cache
 
 
@@ -119,7 +158,9 @@ def _residual(x, out, cfg):
 
 def _experts(x, p, cfg, rows_live, record):
     """An expert block on x [B, S, d], and its counters onto ``record``."""
-    out, aux = moe_ffn(rms_norm(x, p["norm"]), p, cfg, rows_live=rows_live)
+    out, aux = moe_ffn(
+        rms_norm(x, p["norm"], cfg.norm_eps), p, cfg, rows_live=rows_live
+    )
     _note(record, aux)
     return _residual(x, out, cfg)
 
@@ -157,22 +198,83 @@ def _record(record):
     }
 
 
-def _carried(cache, k_pages, v_pages, ssm, conv) -> HybridCache:
+def _carried(cache, k_pages, v_pages, state) -> HybridCache:
     """The cache as a program hands it on: the flat pool back in the
-    argument's shape, the state as the loop left it."""
+    argument's shape, the recurrent leaves as the loop left them."""
     return {
         "k": k_pages.reshape(cache["k"].shape),
         "v": v_pages.reshape(cache["v"].shape),
-        "ssm": ssm,
-        "conv": conv,
+        **state,
     }
+
+
+def _recurrent_leaves(cache) -> HybridCache:
+    return {
+        name: leaf for name, leaf in cache.items() if name not in ("k", "v")
+    }
+
+
+def _rotate(x, positions, cfg):
+    """A rotary embedding on the first ``cfg.rotary_dim`` dimensions of
+    each head of x [B, S, H, Dh] at ``positions`` [B, S] (split halves
+    within those; the rest pass through), float32 inside."""
+    half = cfg.rotary_dim // 2
+    inv_freq = cfg.rope_theta ** (
+        -jnp.arange(half, dtype=jnp.float32) / half
+    )
+    angles = positions.astype(jnp.float32)[..., None, None] * inv_freq
+    cos, sin = jnp.cos(angles), jnp.sin(angles)  # [B, S, 1, half]
+    x1, x2, rest = jnp.split(
+        x.astype(jnp.float32), [half, cfg.rotary_dim], axis=-1
+    )
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1
+    ).astype(x.dtype)
+
+
+def _attention_inputs(x, p, cfg, positions):
+    """An attention block's q [B, S, H, Dh], k and v [B, S, Hkv, Dh] of
+    x [B, S, d] at ``positions`` [B, S], and the heads' output gate
+    [B, S, H, Dh] (None where the model has none). What a family adds
+    is skipped at its config's off value, so that the others' programs
+    are `paged_kv._project_qkv`'s three products and nothing else."""
+    b, s, _ = x.shape
+    dh = cfg.head_dim
+    h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
+    q = h @ p["wq"]
+    gate = None
+    if cfg.attn_output_gate:
+        # Head by head [query | gate].
+        q, gate = jnp.split(q.reshape(b, s, cfg.n_heads, 2 * dh), 2, axis=-1)
+    q = q.reshape(b, s, cfg.n_heads, dh)
+    k = (h @ p["wk"]).reshape(b, s, cfg.n_kv_heads, dh)
+    v = (h @ p["wv"]).reshape(b, s, cfg.n_kv_heads, dh)
+    if cfg.qk_norm:
+        q = rms_norm(q, p["q_norm"], cfg.norm_eps)
+        k = rms_norm(k, p["k_norm"], cfg.norm_eps)
+    if cfg.rotary_dim:
+        q, k = _rotate(q, positions, cfg), _rotate(k, positions, cfg)
+    return q, k, v, gate
+
+
+def _attention_output(attn, gate, p, cfg):
+    """attn [B, S, H, Dh] (times ``sigmoid(gate)`` where the model gates
+    its heads) through ``W_o``: [B, S, d]."""
+    if gate is not None:
+        with jax.named_scope("attn:gate"):
+            attn = (
+                attn * jax.nn.sigmoid(gate.astype(jnp.float32))
+            ).astype(cfg.dtype)
+    return attn.reshape(*attn.shape[:2], -1) @ p["wo"]
 
 
 def _head(x, params, cfg=None):
     """Final norm and the head; ``cfg`` where the model ties the head to
     the embedding or divides its logits (`llm/latent_kv.py`'s does
     neither and passes none)."""
-    x = rms_norm(x, params["final_norm"])
+    x = rms_norm(
+        x, params["final_norm"], 1e-5 if cfg is None else cfg.norm_eps
+    )
     if cfg is not None and cfg.tie_word_embeddings:
         logits = jnp.einsum("...d,vd->...v", x, params["tok_emb"])
     else:
@@ -217,29 +319,30 @@ def _hybrid_prefill(
     if not by_kernel:
         mask = jnp.arange(window)[None, None, :] > pos[:, :, None]
     k_pages, v_pages = _flat_pool(cache)
-    ssm, conv = cache["ssm"], cache["conv"]
+    state = _recurrent_leaves(cache)
     x = _embed(params, tokens, cfg)
     record = _new_record()
-    n_attn = n_mamba = 0
+    seen = dict.fromkeys(cfg.block_kinds, 0)  # blocks of each kind so far
     for kind, p in zip(cfg.pattern, params["blocks"], strict=True):
-        if kind == "M":
+        if kind in _RECURRENT:
+            block = _RECURRENT[kind]
             fresh = start == 0
-            out, ssm_end, conv_end = mamba_chunked(
-                rms_norm(x, p["norm"])[0], p, cfg,
-                jnp.where(fresh, 0.0, ssm[n_mamba, slot]),
-                jnp.where(fresh, 0, conv[n_mamba, slot]),
+            at = (seen[kind], slot)
+            out, s_end, c_end = block.chunked(
+                rms_norm(x, p["norm"], cfg.norm_eps)[0], p, cfg,
+                jnp.where(fresh, 0.0, state[block.state][at]),
+                jnp.where(fresh, 0, state[block.conv][at]),
                 jnp.clip(length - start, 0, c),
             )
-            with jax.named_scope("ssm:scan"):
-                ssm = ssm.at[n_mamba, slot].set(ssm_end)
-                conv = conv.at[n_mamba, slot].set(conv_end)
+            with jax.named_scope(f"{block.scope}:scan"):
+                state[block.state] = state[block.state].at[at].set(s_end)
+                state[block.conv] = state[block.conv].at[at].set(c_end)
             x = _residual(x, out[None], cfg)
-            n_mamba += 1
         elif kind == "E":
             x = _experts(x, p, cfg, live, record)
         else:
-            base = n_attn * num_pages
-            q, k, v = _project_qkv(x, p, cfg)  # [1, C, H, Dh]
+            base = seen[kind] * num_pages
+            q, k, v, gate = _attention_inputs(x, p, cfg, pos)  # [1, C, H, Dh]
             k_pages, v_pages = _write_pages(
                 k_pages, v_pages, k, v, base + chunk_slice, cfg
             )
@@ -254,10 +357,10 @@ def _hybrid_prefill(
                     q, k_pages, v_pages, base + pages[None, :], mask, cfg,
                     cfg.attention_scale,
                 )
-            x = _residual(x, attn.reshape(1, c, -1) @ p["wo"], cfg)
-            n_attn += 1
+            x = _residual(x, _attention_output(attn, gate, p, cfg), cfg)
+        seen[kind] += 1
     last = jax.lax.dynamic_slice_in_dim(x, length - 1 - start, 1, axis=1)
-    carried = _carried(cache, k_pages, v_pages, ssm, conv)
+    carried = _carried(cache, k_pages, v_pages, state)
     return _head(last, params, cfg), carried, _record(record)
 
 
@@ -316,41 +419,44 @@ def hybrid_decode(
     num_pages = cache["k"].shape[1]
     geometry = _decode_geometry(block_tables, positions, 1, page_size)
     k_pages, v_pages = _flat_pool(cache)
-    ssm, conv = cache["ssm"], cache["conv"]
+    state = _recurrent_leaves(cache)
     x = _embed(params, tokens, cfg)  # [B, 1, d]
     record = _new_record()
-    n_attn = n_mamba = 0
+    seen = dict.fromkeys(cfg.block_kinds, 0)  # blocks of each kind so far
     for kind, p in zip(cfg.pattern, params["blocks"], strict=True):
-        if kind == "M":
-            old_ssm, old_conv = ssm[n_mamba], conv[n_mamba]
-            out, new_ssm, new_conv = mamba_step(
-                rms_norm(x, p["norm"])[:, 0], p, cfg, old_ssm, old_conv
+        if kind in _RECURRENT:
+            block, at = _RECURRENT[kind], seen[kind]
+            old_s, old_c = state[block.state][at], state[block.conv][at]
+            out, new_s, new_c = block.step(
+                rms_norm(x, p["norm"], cfg.norm_eps)[:, 0], p, cfg, old_s,
+                old_c,
             )
             # The write back is the state update's other half: under
             # its scope, so that its time is read with it.
-            with jax.named_scope("ssm:update"):
-                ssm = ssm.at[n_mamba].set(
-                    jnp.where(active[:, None, None, None], new_ssm, old_ssm)
+            with jax.named_scope(f"{block.scope}:update"):
+                state[block.state] = state[block.state].at[at].set(
+                    jnp.where(active[:, None, None, None], new_s, old_s)
                 )
-                conv = conv.at[n_mamba].set(
-                    jnp.where(active[:, None, None], new_conv, old_conv)
+                state[block.conv] = state[block.conv].at[at].set(
+                    jnp.where(active[:, None, None], new_c, old_c)
                 )
             x = _residual(x, out[:, None], cfg)
-            n_mamba += 1
         elif kind == "E":
             x = _experts(x, p, cfg, active, record)
         else:
-            q, k, v = _project_qkv(x, p, cfg)  # [B, 1, H, Dh]
+            q, k, v, gate = _attention_inputs(
+                x, p, cfg, positions[:, None]
+            )  # [B, 1, H, Dh]
             attn, k_pages, v_pages = _decode_attention(
                 q, k.astype(cfg.dtype), v.astype(cfg.dtype), k_pages,
-                v_pages, n_attn * num_pages, geometry, positions, cfg,
+                v_pages, seen[kind] * num_pages, geometry, positions, cfg,
                 use_kernel, cfg.attention_scale,
             )
-            x = _residual(x, attn.reshape(b, 1, -1) @ p["wo"], cfg)
-            n_attn += 1
+            x = _residual(x, _attention_output(attn, gate, p, cfg), cfg)
+        seen[kind] += 1
     logits = _head(x, params, cfg)  # [B, 1, V]
     sampled = _sample_tokens(logits, temperature, rng_key)
-    carried = _carried(cache, k_pages, v_pages, ssm, conv)
+    carried = _carried(cache, k_pages, v_pages, state)
     return sampled, logits[:, 0], carried, _record(record)
 
 
@@ -372,7 +478,7 @@ class HybridServing:
         self.cfg = cfg
         self.pairs_per_token = cfg.top_k * cfg.count("E")
         self._init_weights = init_weights
-        self._prefill_programs = self._scan_tokens = self._prefill_pairs = 0
+        self._prefill_programs = self._live_tokens = self._prefill_pairs = 0
 
     def init_weights(self, key):
         return self._init_weights(key, self.cfg)
@@ -394,19 +500,21 @@ class HybridServing:
 
     def counters(self) -> dict:
         # Over the prefill programs run: how many; the live tokens the
-        # chunked scan took, summed over the Mamba blocks; the causal
-        # (query, key) pairs the attention's arithmetic needed, summed
-        # over the attention blocks (`LlamaServing` counts the same).
+        # chunked scans took, summed over the Mamba blocks and over the
+        # gated-delta-rule blocks; the causal (query, key) pairs the
+        # attention's arithmetic needed, summed over the attention
+        # blocks (`LlamaServing` counts the same).
         return {
             "prefill_programs": self._prefill_programs,
-            "ssm_scan_tokens": self._scan_tokens,
+            "ssm_scan_tokens": self.cfg.count("M") * self._live_tokens,
+            "gdn_scan_tokens": self.cfg.count("G") * self._live_tokens,
             "prefill_attn_pairs": self._prefill_pairs,
         }
 
     def _count(self, start: int, width: int, length: int) -> None:
         n = max(min(width, length - start), 0)
         self._prefill_programs += 1
-        self._scan_tokens += self.cfg.count("M") * n
+        self._live_tokens += n
         self._prefill_pairs += self.cfg.count("*") * (
             n * start + n * (n + 1) // 2
         )

@@ -405,7 +405,8 @@ def moe_ffn(x: jnp.ndarray, p: Params, cfg, rows_live=None):
     ``cfg.experts_held`` names a share, only pairs whose expert is held
     are computed: the others are left out of the sort's groups and add
     nothing, so the result is this share's part of the layer (plus the
-    shared expert, where ``p`` has one). ``rows_live`` (bool [B * S])
+    shared expert, where ``p`` has one, times its own sigmoid gate,
+    where ``p`` has that). ``rows_live`` (bool [B * S])
     leaves out the pairs of rows that carry no token (a padded tail, a
     free decode slot) in the same way.
 
@@ -465,11 +466,20 @@ def moe_ffn(x: jnp.ndarray, p: Params, cfg, rows_live=None):
     if "shared_up" in p:
         with jax.named_scope("moe:shared"):
             plain = lambda a, w: a @ w.astype(dt)  # noqa: E731
-            out = out + plain(
+            shared = plain(
                 _expert_act(cfg, tokens.astype(dt), p.get("shared_gate"),
                             p["shared_up"], plain),
                 p["shared_down"],
             )
+            if "shared_expert_gate" in p:
+                # A sigmoid scalar a token on the shared expert's output
+                # (`models/qwen3_next.py`: ``w_s: d -> 1``).
+                scalar = jnp.dot(
+                    tokens.astype(dt), p["shared_expert_gate"].astype(dt),
+                    preferred_element_type=jnp.float32,
+                )
+                shared = (shared * jax.nn.sigmoid(scalar)).astype(dt)
+            out = out + shared
 
     aux = {"expert_load": load, "routes": routes}
     if here is not None:

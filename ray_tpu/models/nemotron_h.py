@@ -28,7 +28,7 @@ from __future__ import annotations
 import dataclasses
 import math
 from functools import partial
-from typing import Any
+from typing import Any, ClassVar
 
 import jax
 import jax.numpy as jnp
@@ -109,12 +109,29 @@ class NemotronHConfig:
     attention_scale: float | None = None
     logits_scaling: float = 1.0
     tie_word_embeddings: bool = False
+    # What a family whose attention is gated and rotated adds to the
+    # attention block (`models/qwen3_next.py`), off here and in Granite,
+    # whose programs hold none of it: an RMSNorm over each head of q and
+    # k, a rotary embedding on the first `rotary_dim` dimensions of each
+    # head (0: none), and a `wq` twice as wide whose second half, head by
+    # head, gates the heads' output through a sigmoid. `norm_eps` is every
+    # RMSNorm's.
+    qk_norm: bool = False
+    rotary_dim: int = 0
+    rope_theta: float = 10000.0
+    attn_output_gate: bool = False
+    norm_eps: float = 1e-5
+
+    # The letters `pattern` may hold: a family with another recurrence
+    # adds its own (`models/qwen3_next.py`: G).
+    block_kinds: ClassVar[str] = "ME*"
 
     def __post_init__(self):
-        if set(self.pattern) - set("ME*"):
+        if set(self.pattern) - set(self.block_kinds):
             raise ValueError(
-                f"pattern {self.pattern!r}: blocks are M, E or * (a dense "
-                "MLP block, '-', is not written)"
+                f"pattern {self.pattern!r}: blocks are of "
+                f"{' '.join(self.block_kinds)} (a dense MLP block, '-', is "
+                "not written)"
             )
         if self.mamba_heads % self.ssm_groups:
             raise ValueError("ssm_groups does not divide mamba_heads")

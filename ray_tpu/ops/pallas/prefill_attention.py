@@ -68,6 +68,29 @@ three seeds). A whole prompt of 64 to 1,024 tokens (``paged_prefill``)
 takes 0.22-0.26 ms with any of them, the launch's own time, where dense
 attention takes 0.21-0.24 up to 512 tokens, 1.05 at 1,024 and 4.06 at
 2,048 (the kernel: 0.55-0.61).
+
+At a head of 256 (v5e, 16 query / 2 KV heads of 256, bf16: Qwen3-Next's;
+my chip run, PR 51; milliseconds a call, 2,048 queries at ``start`` 0 /
+the middle / the last chunk over a table of 8,192 and of 16,384 keys).
+The group of 8 heads already halves the query block to 256 (below); the
+key block has to halve with the head, since a tile's bytes are what
+spills:
+
+    block_q x block_kv   8,192: 0   2,048   6,144   16,384: 0   6,144   14,336
+    128 x 1,024            0.56    1.08    2.09        0.61    2.14    4.17
+    128 x 2,048            0.61    1.06    1.93        0.66    1.97    3.72
+    256 x 512              0.56    1.14    2.32        0.59    2.38    4.73
+    256 x 1,024            0.50    0.94    1.80        0.54    1.83    3.56
+    256 x 2,048            2.01    2.33    3.03        3.64    4.64    6.03
+    256 x 4,096            1.82    1.81    2.70        2.91    3.55    4.78
+    512 x 1,024            2.03    2.37    3.05        3.71    4.72    6.09
+    512 x 2,048            1.55    1.85    2.48        2.66    3.57    4.82
+
+256 x 1,024 reads 73% of the bf16 peak on the last chunk of a
+16,384-token table (1,024 operations a query, key and head). So the key
+block is ``_BLOCK_KV`` keys of 128 lanes and as many bytes of a wider
+head: 2,048 at 128 (the cells that were there keep their blocks), 1,024
+at 256.
 """
 
 from __future__ import annotations
@@ -179,7 +202,7 @@ def prefill_attention(
     start: jnp.ndarray,  # [] int32: position of query 0; key 0 is position 0
     *,
     block_q: int = _BLOCK_Q,
-    block_kv: int = _BLOCK_KV,
+    block_kv: int | None = None,
     interpret: bool = False,
     scale: float | None = None,
 ) -> jnp.ndarray:
@@ -200,6 +223,10 @@ def prefill_attention(
     # a 512-row block asks 66 MB of VMEM and the compiler refuses it.
     block_q = min(block_q, max(4 * _BLOCK_Q // n_rep, _SUBLANES_BF16))
     block_q = _fit_rows(block_q, c, _SUBLANES_BF16)
+    if block_kv is None:
+        # A key or value tile keeps its bytes as the head widens: 2,048
+        # keys of 128, 1,024 of 256 (the sweep at a head of 256 above).
+        block_kv = max(_BLOCK_KV * _LANES // max(head_dim, _LANES), page_size)
     block_pages = _fit_rows(max(block_kv // page_size, 1), n_pages, 1)
     block_kv = block_pages * page_size
     num_q, num_kv = c // block_q, n_pages // block_pages

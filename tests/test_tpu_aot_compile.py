@@ -19,7 +19,11 @@ latent-attention model's (llm/latent_kv.py), at openPangu-Ultra-MoE's
 widths with 16 experts held, for the same of its latent pool. Both hold
 the kernel that reads the touched experts (ops/pallas/expert_rows.py)
 wherever `moe_ffn` takes its every-row form, and the compiler's grouped
-matmul above that.
+matmul above that. The three attention kernels are also compiled at a
+head of 256 with 16 query and 2 KV heads (Qwen3-Next's), and that
+configuration's own programs (qwen3next-80b-serve1) at one Gated
+DeltaNet and the attention layer with the whole configuration's pages
+and slots.
 """
 
 import math
@@ -645,5 +649,135 @@ def test_granite_program_moves_no_pages_state_or_stack_and_fits(
         family.held_parameters(conf) * 2 + pool
         + 9 * eng["max_batch"] * (128 * 64 * 128 * 4 + 3 * 8448 * 2)
     )
+    assert arguments > 0.25 * 16 * 2**30  # the floor a new cell is held to
+    assert arguments + memory.temp_size_in_bytes < 15.75 * 2**30
+
+
+# --------------------------------------------- a head of 256 (Qwen3-Next)
+H256, HKV256, DH256 = 16, 2, 256
+
+
+def _head256_case(case: str, on):
+    """The kernel and its arguments at qwen3next-80b-serve1's shapes: 32
+    slots, 8,321 pages of 64 tokens, tables of 260 pages."""
+    def bf16(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=on)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=on)
+
+    pool = bf16(8321, HKV256, PAGE, DH256)
+    if case.startswith("prefill_attn"):
+        from ray_tpu.ops.pallas.prefill_attention import prefill_attention
+
+        queries, keys = {"prefill_attn_2048_of_16384": (2048, 16384),
+                         "prefill_attn_4096": (4096, 4096)}[case]
+        pages = bf16(keys // PAGE, HKV256, PAGE, DH256)
+        return prefill_attention, (
+            bf16(queries, H256, DH256), pages, pages, i32()
+        )
+    if case == "paged":
+        from ray_tpu.ops.pallas.paged_attention import paged_attention
+
+        return partial(paged_attention, n_kv_heads=HKV256), (
+            bf16(SLOTS, 1, H256, DH256), pool, pool, i32(SLOTS, 260),
+            i32(SLOTS),
+        )
+    from ray_tpu.ops.pallas.kv_cell_write import write_kv_cells
+
+    return write_kv_cells, (
+        pool, pool, bf16(SLOTS, HKV256, DH256), bf16(SLOTS, HKV256, DH256),
+        i32(SLOTS), i32(SLOTS),
+    )
+
+
+@pytest.mark.parametrize(
+    "case",
+    ["prefill_attn_2048_of_16384", "prefill_attn_4096", "paged", "kv_write"],
+)
+def test_kernel_compiles_for_v5e_at_a_head_of_256(v5e, case):
+    """16 query heads over 2 KV heads of 256: a group of 8 x 256 = 2,048
+    lanes in the prefill kernel, ``(1, 2, 8, 256)`` query blocks in the
+    paged kernel, 256-wide cells in the write (ROADMAP R8)."""
+    fn, args = _head256_case(case, v5e)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.fixture(scope="module")
+def qwen3next_programs(v5e):
+    """qwen3next-80b-serve1's own sizes (benchmarks/configs) at 2 of its
+    4 layers: a period of two, so that layer 0 is a Gated DeltaNet and
+    layer 1 the gated attention layer, each with its expert FFN, with
+    the whole configuration's pages and slots: what
+    `aot_fit_serve_family` lowers, at the widest table of the mix."""
+    import json
+
+    from benchmarks import aot_fit_serve_family
+    from ray_tpu._private import chip
+
+    here = os.path.join(os.path.dirname(__file__), "..", "benchmarks")
+    with open(os.path.join(here, "configs", "qwen3next-80b-serve1.json")) as f:
+        whole = json.load(f)
+    conf = {**whole, "num_hidden_layers": 2, "full_attention_interval": 2}
+    traffic = {"fit_prefill_buckets": [16384]}
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(chip, "platform", lambda: "tpu")
+        lowered = aot_fit_serve_family.lowered_programs(
+            conf, traffic, next(iter(v5e.device_set))
+        )
+        return whole, {name: low.compile() for name, low in lowered.items()}
+
+
+@pytest.mark.parametrize("program", ["prefill_chunk_2048_of_16384", "decode"])
+def test_qwen3next_program_moves_no_pages_state_or_stack_and_fits(
+    qwen3next_programs, program
+):
+    """As granite's programs above, through the same `llm/hybrid_kv.py`
+    with the fourth letter: the donated cache updated in place (pages of
+    256-wide cells, a float32 `[32, 128, 128]` matrix state a slot), the
+    256 held experts' stacks read where they lie, the three attention
+    kernels at a head of 256, and the temporaries beside the WHOLE
+    configuration's arguments under what a v5e offers a program."""
+    from benchmarks.models import qwen3_next as family
+
+    conf, programs = qwen3next_programs
+    eng = conf["engine"]
+    d, f = conf["hidden_size"], conf["moe_intermediate_size"]
+    held, hkv = conf["num_experts"], conf["num_key_value_heads"]
+    layer_pages = (eng["num_pages"] + 1) * hkv * PAGE * DH256
+    state = eng["max_batch"] * 32 * 128 * 128
+    shapes = {
+        "pages": ((hkv, PAGE, DH256), layer_pages),
+        "state": ((32, 128, 128), state),
+        "w_up": ((d, f), held * d * f),
+        "w_down": ((f, d), held * d * f),
+    }
+    compiled = programs[program]
+    text = compiled.as_text()
+    assert _hybrid_moves(text, shapes) == []
+    memory = compiled.memory_analysis()
+    chunk, table = eng["prefill_chunk"], 16384
+    if program == "decode":
+        assert "paged_attention" in text and "write_kv_cells" in text
+        assert "ragged-dot" not in text
+        assert len(_expert_kernel_calls(text)) == 2  # one a layer's FFN
+        assert memory.temp_size_in_bytes < state * 4
+    else:
+        assert "prefill_attention" in text and "ragged-dot" in text
+        assert _expert_kernel_calls(text) == []
+        assert not re.search(rf"\[[\d,]*{chunk},{table}\]", text)
+        k = conf["num_experts_per_tok"]
+        assert _expert_arrays_of(text, (chunk * k, d)) == []
+        assert _grouped_matmul_tiles(text) == {"256,1024,512"}
+        assert memory.temp_size_in_bytes < 2**30
+    arguments = conf["fit"]["argument_bytes"]
+    counted = (
+        family.held_parameters(conf) * 2 + 2 * layer_pages * 2
+        + 3 * eng["max_batch"]
+        * (family.gdn_state_bytes_per_slot(conf) + 3 * 8192 * 2)
+    )
+    # The float32 leaves (routers, norms, convolutions) are 9 MB more.
+    assert abs(arguments - counted) < 16e6
     assert arguments > 0.25 * 16 * 2**30  # the floor a new cell is held to
     assert arguments + memory.temp_size_in_bytes < 15.75 * 2**30

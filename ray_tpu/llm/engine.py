@@ -1146,7 +1146,9 @@ class LLMEngine:
         `overrun_slot_steps`), `param_bytes` (the held weights, matmul
         leaves in `cfg.dtype`), which attention and which K/V cell write the
         decode program was compiled with (`paged_attn_kernel`,
-        `kv_write_kernel`) and the pool/slot occupancy."""
+        `kv_write_kernel`), for a model with expert layers whether their
+        sorted form sums its rows by the kernel (`moe_combine_kernel`),
+        and the pool/slot occupancy."""
         with self._lock:
             self._fold_moe_counts()
             # The model's own counters (its cache's, where it keeps any)
@@ -1172,6 +1174,11 @@ class LLMEngine:
             # (paged_kv.paged_verify): Pallas and in place beside the
             # kernel, XLA's scatter beside the gather.
             out["kv_write_kernel"] = out["paged_attn_kernel"]
+            if self._pairs_per_token:
+                # The sorted expert form's combine follows the platform
+                # alone (models/moe.py): the kernel on a TPU, XLA's
+                # scatter-add elsewhere.
+                out["moe_combine_kernel"] = self.platform == "tpu"
             out["active_requests"] = len(self._active)
             out["queued_requests"] = len(self._queue)
             out["prefilling"] = self._prefilling is not None

@@ -10,7 +10,9 @@ per-expert slot limit and no one-hot dispatch tensor, so the work is
 tokens x top_k whatever the skew. Experts carry the "expert" logical
 axis, sharded over the mesh's ep axis. A serving program that holds a
 share of the experts does that work on the pairs whose expert it holds,
-a block of the sorted order at a time (`_experts_on_pairs_here`).
+a block of the sorted order at a time, and sums the blocks' rows onto
+their tokens once a layer, on a TPU by a kernel of its own
+(`_experts_on_pairs_here`, ``ops/pallas/expert_combine.py``).
 
 The attention sublayer, scan scaffolding, and non-expert parameters are
 the flagship Llama's (ray_tpu.models.llama — this module only swaps the
@@ -37,6 +39,7 @@ from ray_tpu.models.llama import (
     init_params,
     param_logical_axes,
 )
+from ray_tpu.ops.pallas.expert_combine import combine_rows
 from ray_tpu.ops.pallas.expert_rows import experts_on_rows
 
 
@@ -286,12 +289,6 @@ def _experts_on_sorted_pairs(tokens, p, cfg, routes, gates):
 # Rows of the sorted order that one step of `_experts_on_pairs_here`
 # takes: the work is the pairs computed here rounded up to this.
 _PAIR_BLOCK = 1024
-# Columns of one float32 sum of `_experts_on_pairs_here`. XLA adds
-# 1,024 rows onto a [2048, 4096] sum in 0.27 ms on a v5e, and onto
-# 3,840 / 5,120 / 7,168 / 7,680 columns in 1.3 / 3.8 / 1.7 / 5.6 ms: a
-# wider layer's sum is kept in pieces this wide, the last one padded
-# with zero columns to a power of two.
-_SUM_LANES = 4096
 
 
 # (rows, contraction) of a tile of the compiler's grouped matmul in
@@ -322,12 +319,16 @@ def _experts_on_pairs_here(tokens, p, cfg, routes, gates, here):
     program's, forward only. Those pairs sort to the front, ``m`` of
     them, and the work is ``m`` rounded up to `_PAIR_BLOCK`, not the
     ``n * k`` pairs routed: a loop over blocks of the sorted order, as
-    many as hold a pair, each gathering its rows, applying the experts
-    to them (the block's part of each expert's group), weighting each
-    row by its gate where it lies and adding it onto its token. Any
-    routing gives the same sums as every pair computed and the dead
-    ones masked, all ``n * k`` live included. Returns the rows the
-    grouped matmuls ran over beside the output and the load."""
+    many as hold a pair, each gathering its rows and applying the
+    experts to them (the block's part of each expert's group), and after
+    it ONE sum of the ``m`` live rows onto their tokens, each weighted by
+    its float32 gate where it lies. On a TPU that sum is a kernel
+    (``ops/pallas/expert_combine.py``) over the blocks' outputs as the
+    grouped matmul left them; elsewhere XLA's scatter-add, which is also
+    the kernel's oracle. Any routing gives the same sums as every pair
+    computed and the dead ones masked, all ``n * k`` live included.
+    Returns the rows the grouped matmuls ran over beside the output and
+    the load."""
     n, k = routes.shape
     d = tokens.shape[-1]
     first, e_here = cfg.experts_held or (0, cfg.num_experts)
@@ -347,19 +348,13 @@ def _experts_on_pairs_here(tokens, p, cfg, routes, gates, here):
         blocks = (m + block - 1) // block
         # The last block may reach past the pairs.
         order = jnp.pad(order, (0, -(n * k) % block))
-        gate = gates.reshape(n * k)[order]  # as the rows lie
-    # The tokens' sums, float32, in column pieces: (first column,
-    # columns, columns held: the next power of two).
-    pieces = []
-    for a in range(0, d, _SUM_LANES):
-        w = min(_SUM_LANES, d - a)
-        pieces.append((a, w, 1 << (w - 1).bit_length()))
+        token = order // k  # of each row, as the rows lie
+        gate = gates.reshape(n * k)[order]
 
-    def step(i, sums):
+    def step(i, rows_out):
         lo = i * block
         with jax.named_scope("moe:dispatch"):
-            token = jax.lax.dynamic_slice(order, (lo,), (block,)) // k
-            rows = tokens[token]  # [block, d]
+            rows = tokens[jax.lax.dynamic_slice(token, (lo,), (block,))]
             # Each expert's rows that lie in this block.
             sizes = (
                 jnp.clip(ends - lo, 0, block)
@@ -367,33 +362,33 @@ def _experts_on_pairs_here(tokens, p, cfg, routes, gates, here):
             )
         with jax.named_scope("moe:experts"):
             grouped = lambda a, w: _grouped_matmul(a, w.astype(dt), sizes)  # noqa: E731
-            rows_out = grouped(
+            out = grouped(
                 _expert_act(cfg, rows, p.get("w_gate"), p["w_up"], grouped),
                 p["w_down"],
             )
         with jax.named_scope("moe:combine"):
-            g = jax.lax.dynamic_slice(gate, (lo,), (block,))
-            weighted = rows_out.astype(jnp.float32) * g[:, None]
-            # Rows behind the last group are no expert's output.
-            live = lo + jnp.arange(block) < m
-            weighted = jnp.where(live[:, None], weighted, 0.0)
-            return tuple(
-                s.at[token].add(
-                    jnp.pad(weighted[:, a: a + w], ((0, 0), (0, held - w)))
-                )
-                for s, (a, w, held) in zip(sums, pieces)
-            )
+            # Kept as the grouped matmul left them, in place.
+            return jax.lax.dynamic_update_index_in_dim(rows_out, out, i, 0)
 
     # (The loop's own time goes under the experts' scope; a step's parts
-    # are named inside it.)
+    # are named inside it.) The buffer starts as it is found (on a TPU
+    # nothing is written to make it) and the blocks never reached stay
+    # so: rows at and past `m` are no expert's output and are never read.
     with jax.named_scope("moe:experts"):
-        sums = jax.lax.fori_loop(0, blocks, step, tuple(
-            jnp.zeros((n, held), jnp.float32) for _, _, held in pieces
-        ))
+        rows_out = jax.lax.fori_loop(
+            0, blocks, step,
+            jax.lax.empty((token.shape[0] // block, block, d), dt),
+        )
     with jax.named_scope("moe:combine"):
-        out = jnp.concatenate(
-            [s[:, :w] for s, (_, w, _) in zip(sums, pieces)], axis=1
-        ).astype(dt)
+        if chip.platform() == "tpu":
+            out = combine_rows(rows_out, token, gate, m, n)
+        else:
+            # Rows at and past `m` go to no token, whatever they hold.
+            at = jnp.where(jnp.arange(token.shape[0]) < m, token, n)
+            weighted = rows_out.reshape(-1, d).astype(jnp.float32)
+            out = jnp.zeros((n, d), jnp.float32).at[at].add(
+                weighted * gate[:, None], mode="drop"
+            ).astype(dt)
     return out, load, blocks * block
 
 

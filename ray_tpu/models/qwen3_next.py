@@ -121,6 +121,11 @@ class Qwen3NextConfig(NemotronHConfig):
     # 512, 20.07 / 6.80 at 2,048. The forms cross near 450 rows; the
     # engine's calls have 32 or 2,048 rows and more, so any boundary
     # between gives the same programs, and 256 is the other families'.
+    # (Those sorted readings summed their rows by XLA's scatter-add, ten
+    # blocks of it at 2,048 rows; since PR 52 the sum is one kernel call,
+    # `ops/pallas/expert_combine.py`, and 2,048 rows read 6.74 -> 5.12 ms
+    # on a layer of these widths with random routes: my chip run, PR 52.
+    # The shorter calls were not timed again; they can only cross lower.)
     dense_expert_rows: int = 256
     max_seq: int = 262144
 

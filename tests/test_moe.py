@@ -413,8 +413,8 @@ _BOUND_CASES = {
     "a_padded_tail": ((0, 8), 51, 2, False, 64),
     "a_padded_tail_all_held": (None, 51, 2, False, 64),
     "one_held_expert_takes_every_pair": ((0, 4), 64, 1, True, 64),
-    # 320 columns with sums held to 128 each: three pieces, the last one
-    # 64 columns wide, which is a power of two; a fourth case pads (48).
+    # Widths that are no power of two: 2.5 tiles of 128 lanes, and no
+    # whole tile.
     "a_sum_kept_in_pieces": ((4, 8), 64, 2, False, 320),
     "a_last_piece_padded": ((4, 8), 64, 2, False, 176),
 }
@@ -448,7 +448,6 @@ def test_sorted_form_under_a_row_bound_gives_every_pairs_sums(
     two whole blocks)."""
     held, n_live, top_k, forced, d_model = _BOUND_CASES[case]
     monkeypatch.setattr(moe, "_PAIR_BLOCK", 32)
-    monkeypatch.setattr(moe, "_SUM_LANES", 128)
     cfg, layer, mine, x = _bound_layer(held, top_k, forced, d_model)
     rows_live = jnp.arange(64) < n_live
 

@@ -217,15 +217,63 @@ def test_expert_rows_kernel_compiles_for_v5e_at_served_widths(v5e, case):
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20
 
 
-def _expert_kernel_calls(text: str) -> list[str]:
-    """A compiled program's calls of the touched-experts kernel: Mosaic
-    calls whose ``op_name`` lies under ``moe:experts``, which is where
-    the benchmark's reducers look for the experts' operation."""
+@pytest.mark.parametrize(
+    "case",
+    {
+        # A 2,048-token chunk's pair rows, rounded up to whole blocks of
+        # the sorted order: granite-4.0-h-small and Qwen3-Next ten a
+        # token, openPangu-Ultra-MoE eight.
+        "granite_4096": (20480, 4096),
+        "qwen3next_2048": (20480, 2048),
+        "pangu_7680": (16384, 7680),
+    }.items(),
+    ids=lambda case: case[0],
+)
+def test_expert_combine_kernel_compiles_for_v5e_at_served_widths(v5e, case):
+    """The accumulator `_column_tile` sizes, indexed by a token's row at
+    run time, and the row blocks fit the VMEM the call asks for; the
+    rows are taken as the grouped matmul left them (bfloat16, 7,680
+    columns as they are) and nothing is made beside the arguments."""
+    from ray_tpu.ops.pallas.expert_combine import combine_rows
+
+    total, d = case[1]
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    compiled = jax.jit(partial(combine_rows, n=2048)).lower(
+        on_chip((total // 1024, 1024, d), jnp.bfloat16),
+        on_chip((total,), jnp.int32),
+        on_chip((total,), jnp.float32), on_chip((), jnp.int32),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+def _combine_scatters(text: str) -> list[str]:
+    """A compiled program's lines under ``moe:combine`` that name a
+    scatter (the instruction, or the ``scatter-add`` its fusion was
+    built around): XLA's row scatter-add of the sorted expert form,
+    which ops/pallas/expert_combine.py replaces on a TPU."""
     return [
         line for line in text.splitlines()
-        if 'custom_call_target="tpu_custom_call"' in line
-        and "moe:experts" in line
+        if "moe:combine" in line and "scatter" in line
     ]
+
+
+def _kernel_calls_under(text: str, scope: str) -> list[str]:
+    """A compiled program's Mosaic calls whose ``op_name`` lies under
+    ``scope``, which is where the benchmark's reducers look for them."""
+    return [
+        line for line in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line and scope in line
+    ]
+
+
+def _expert_kernel_calls(text: str) -> list[str]:
+    """A compiled program's calls of the touched-experts kernel: the
+    experts' operation of the every-row form."""
+    return _kernel_calls_under(text, "moe:experts")
 
 
 # ------------------------------------------------- the serving programs
@@ -781,3 +829,21 @@ def test_qwen3next_program_moves_no_pages_state_or_stack_and_fits(
     assert abs(arguments - counted) < 16e6
     assert arguments > 0.25 * 16 * 2**30  # the floor a new cell is held to
     assert arguments + memory.temp_size_in_bytes < 15.75 * 2**30
+
+
+# ------------------------------------- the sorted expert form's combine
+@pytest.mark.parametrize("family", ["latent", "granite", "qwen3next"])
+def test_chunk_program_sums_expert_rows_by_the_kernel(family, request):
+    """A 2,048-token chunk's expert layers (the sorted form over the
+    pairs computed here) sum their rows onto the tokens by
+    ops/pallas/expert_combine.py: no scatter instruction is left under
+    ``moe:combine``, where the benchmark's reducers read the kernel's
+    call; the decode programs (the every-row form) hold neither."""
+    _, programs = request.getfixturevalue(f"{family}_programs")
+    chunk = next(name for name in programs if name.startswith("prefill_chunk"))
+    text = programs[chunk].as_text()
+    assert _combine_scatters(text) == []
+    assert _kernel_calls_under(text, "moe:combine") != []
+    decode = programs["decode"].as_text()
+    assert _combine_scatters(decode) == []
+    assert _kernel_calls_under(decode, "moe:combine") == []

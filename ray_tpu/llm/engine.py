@@ -1148,7 +1148,9 @@ class LLMEngine:
         decode program was compiled with (`paged_attn_kernel`,
         `kv_write_kernel`), for a model with expert layers whether their
         sorted form sums its rows by the kernel (`moe_combine_kernel`),
-        and the pool/slot occupancy."""
+        for a model with recurrent blocks whether a decode step updates
+        their state by the kernel (`state_step_kernel`), and the
+        pool/slot occupancy."""
         with self._lock:
             self._fold_moe_counts()
             # The model's own counters (its cache's, where it keeps any)
@@ -1179,6 +1181,11 @@ class LLMEngine:
                 # alone (models/moe.py): the kernel on a TPU, XLA's
                 # scatter-add elsewhere.
                 out["moe_combine_kernel"] = self.platform == "tpu"
+            if self.serving.recurrent_blocks:
+                # So does the decode step's state update
+                # (llm/hybrid_kv.py): ops/pallas/state_step.py over the
+                # decoding slots on a TPU, XLA's masked form elsewhere.
+                out["state_step_kernel"] = self.platform == "tpu"
             out["active_requests"] = len(self._active)
             out["queued_requests"] = len(self._queue)
             out["prefilling"] = self._prefilling is not None

@@ -78,9 +78,11 @@ from ray_tpu.models.nemotron_h import (
     init_params,
     mamba_chunked,
     mamba_step,
+    mamba_step_live,
 )
-from ray_tpu.models.qwen3_next import gdn_chunked, gdn_step
+from ray_tpu.models.qwen3_next import gdn_chunked, gdn_step, gdn_step_live
 from ray_tpu.ops.norms import rms_norm
+from ray_tpu.ops.pallas.state_step import live_order
 
 HybridCache = dict[str, jnp.ndarray]
 
@@ -98,13 +100,19 @@ _DENSE_ATTENTION_KEYS = 1024
 
 
 class _Recurrent(NamedTuple):
-    """A kind of recurrent block: its mixer over many tokens and over
-    one, the cache's leaves for its state and its convolution tail, the
-    prefix of its named scopes, and a slot's (state shape, convolution
-    channels) from a config."""
+    """A kind of recurrent block: its mixer over many tokens, over one
+    token of every slot (``step``: states in, states out, and
+    `hybrid_decode` writes them back under the mask of the slots that
+    decode) and, on a TPU, over one token of the slots that decode
+    (``step_live``: the cache's whole stack in and out, updated in place
+    by ``ops/pallas/state_step.py``, which owns that mask: a slot that
+    does not decode is not read), the cache's leaves for its state and
+    its convolution tail, the prefix of its named scopes, and a slot's
+    (state shape, convolution channels) from a config."""
 
     chunked: Callable
     step: Callable
+    step_live: Callable
     state: str
     conv: str
     scope: str
@@ -113,11 +121,11 @@ class _Recurrent(NamedTuple):
 
 _RECURRENT = {
     "M": _Recurrent(
-        mamba_chunked, mamba_step, "ssm", "conv", "ssm",
+        mamba_chunked, mamba_step, mamba_step_live, "ssm", "conv", "ssm",
         lambda c: ((c.mamba_heads, c.mamba_head_dim, c.ssm_state), c.conv_dim),
     ),
     "G": _Recurrent(
-        gdn_chunked, gdn_step, "gdn", "gdn_conv", "gdn",
+        gdn_chunked, gdn_step, gdn_step_live, "gdn", "gdn_conv", "gdn",
         lambda c: (
             (c.gdn_value_heads, c.gdn_key_dim, c.gdn_value_dim),
             c.gdn_conv_dim,
@@ -412,9 +420,13 @@ def hybrid_decode(
     that is not ``active`` computes like the others (static shapes) and
     changes nothing that lasts: its state stays as it is, its expert
     pairs are left out, its K/V cell goes to the dump page (its table is
-    all -1). Returns (sampled [B, 1] int32, logits [B, V] fp32, cache,
-    record)."""
-    b = tokens.shape[0]
+    all -1). Who keeps a recurrent state as it is: on a TPU the state
+    kernel (``ops/pallas/state_step.py``, through a block's
+    ``step_live``), which visits the decoding slots' state and no other,
+    in place in the cache's stack; elsewhere this loop, which writes
+    every slot's state back under the mask. The convolution tail (small)
+    is written back under the mask here on both. Returns (sampled [B, 1]
+    int32, logits [B, V] fp32, cache, record)."""
     page_size = cache["k"].shape[3]
     num_pages = cache["k"].shape[1]
     geometry = _decode_geometry(block_tables, positions, 1, page_size)
@@ -422,21 +434,28 @@ def hybrid_decode(
     state = _recurrent_leaves(cache)
     x = _embed(params, tokens, cfg)  # [B, 1, d]
     record = _new_record()
+    # Chosen by the platform alone, as `moe_ffn` chooses its kernels.
+    live = live_order(active) if chip.platform() == "tpu" else None
     seen = dict.fromkeys(cfg.block_kinds, 0)  # blocks of each kind so far
     for kind, p in zip(cfg.pattern, params["blocks"], strict=True):
         if kind in _RECURRENT:
             block, at = _RECURRENT[kind], seen[kind]
-            old_s, old_c = state[block.state][at], state[block.conv][at]
-            out, new_s, new_c = block.step(
-                rms_norm(x, p["norm"], cfg.norm_eps)[:, 0], p, cfg, old_s,
-                old_c,
-            )
+            u = rms_norm(x, p["norm"], cfg.norm_eps)[:, 0]
+            old_c = state[block.conv][at]
+            if live is not None:
+                out, state[block.state], new_c = block.step_live(
+                    u, p, cfg, state[block.state], at, old_c, *live
+                )
+            else:
+                old_s = state[block.state][at]
+                out, new_s, new_c = block.step(u, p, cfg, old_s, old_c)
+                with jax.named_scope(f"{block.scope}:update"):
+                    state[block.state] = state[block.state].at[at].set(
+                        jnp.where(active[:, None, None, None], new_s, old_s)
+                    )
             # The write back is the state update's other half: under
             # its scope, so that its time is read with it.
             with jax.named_scope(f"{block.scope}:update"):
-                state[block.state] = state[block.state].at[at].set(
-                    jnp.where(active[:, None, None, None], new_s, old_s)
-                )
                 state[block.conv] = state[block.conv].at[at].set(
                     jnp.where(active[:, None, None], new_c, old_c)
                 )
@@ -477,6 +496,7 @@ class HybridServing:
     def __init__(self, cfg: NemotronHConfig, init_weights=init_params):
         self.cfg = cfg
         self.pairs_per_token = cfg.top_k * cfg.count("E")
+        self.recurrent_blocks = sum(cfg.count(kind) for kind in _RECURRENT)
         self._init_weights = init_weights
         self._prefill_programs = self._live_tokens = self._prefill_pairs = 0
 

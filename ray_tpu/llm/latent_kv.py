@@ -362,6 +362,7 @@ class LatentServing:
     )
     logits_last_only = True  # prefill returns the last real token's logits
     fixed_chunks = True  # the program takes the true length
+    recurrent_blocks = 0  # no per-slot state beside the pages
 
     def __init__(self, cfg: PanguUltraMoEConfig):
         self.cfg = cfg

@@ -33,6 +33,8 @@ from typing import Any, ClassVar
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops.pallas.state_step import mamba_state_step
+
 Params = dict[str, Any]
 
 PATTERN_30B = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
@@ -399,16 +401,11 @@ def mamba_chunked(u, p, cfg: NemotronHConfig, ssm0, conv0, length):
     return out, end.reshape(ssm0.shape), conv_end.astype(conv0.dtype)
 
 
-def mamba_step(u, p, cfg: NemotronHConfig, ssm, conv):
-    """The mixer for ONE token of each of B sequences: u [B, d], ssm
-    [B, H, P, N] float32, conv [B, K - 1, conv_dim]. Returns (out
-    [B, d], ssm, conv) after the token. The state is read, updated and
-    read out in float32 elementwise arithmetic: no product rounds it."""
-    bsz = u.shape[0]
-    h, g = cfg.mamba_heads, cfg.ssm_groups
-    r = h // g
+def _step_operands(u, p, cfg, conv):
+    """What one token's state update takes, of u [B, d] and the tail
+    conv [B, K - 1, conv_dim]: z, x [B, H, P], B and C [B, G, N], the
+    window [B, K, conv_dim] whose last K - 1 rows are the next tail."""
     z, xbc, dt_raw = _project_in(u, p, cfg)
-
     with jax.named_scope("ssm:conv"):
         window = jnp.concatenate(
             [conv, xbc[:, None].astype(conv.dtype)], axis=1
@@ -417,6 +414,18 @@ def mamba_step(u, p, cfg: NemotronHConfig, ssm, conv):
             window.astype(jnp.float32) * p["conv_w"][None]
         ).sum(1)
         x, b_in, c_in = _split_xbc(jax.nn.silu(out), cfg)
+    return z, dt_raw, x, b_in, c_in, window
+
+
+def mamba_step(u, p, cfg: NemotronHConfig, ssm, conv):
+    """The mixer for ONE token of each of B sequences: u [B, d], ssm
+    [B, H, P, N] float32, conv [B, K - 1, conv_dim]. Returns (out
+    [B, d], ssm, conv) after the token. The state is read, updated and
+    read out in float32 elementwise arithmetic: no product rounds it."""
+    bsz = u.shape[0]
+    h, g = cfg.mamba_heads, cfg.ssm_groups
+    r = h // g
+    z, dt_raw, x, b_in, c_in, window = _step_operands(u, p, cfg, conv)
 
     with jax.named_scope("ssm:update"):
         dt, a = _steps(dt_raw, p)  # [B, H], [H]
@@ -428,3 +437,24 @@ def mamba_step(u, p, cfg: NemotronHConfig, ssm, conv):
         y = y.reshape(bsz, h, -1) + p["D"][:, None] * x
     out = _project_out(y.reshape(bsz, -1), z, p, cfg)
     return out, state.reshape(ssm.shape), window[:, 1:]
+
+
+def mamba_step_live(u, p, cfg: NemotronHConfig, stack, layer, conv, order,
+                    count):
+    """`mamba_step` on a TPU, for the slots that decode: ``stack`` [L, B,
+    H, P, N] is every layer's state, of which ``stack[layer]`` is
+    stepped IN PLACE for the first ``count`` slots of ``order``
+    (``ops/pallas/state_step.py live_order``) and no other slot's state
+    is read or written: the masked write-back is the kernel's. Returns
+    (out [B, d], the stack, conv after the token); a slot that does not
+    decode gets the ``out`` of ``y = D x`` (finite, and dropped)."""
+    z, dt_raw, x, b_in, c_in, window = _step_operands(u, p, cfg, conv)
+    with jax.named_scope("ssm:update"):
+        dt, a = _steps(dt_raw, p)  # [B, H], [H]
+        stack, y = mamba_state_step(
+            stack, layer, order, count, jnp.exp(dt * a), x * dt[..., None],
+            b_in, c_in,
+        )
+        y = y + p["D"][:, None] * x
+    out = _project_out(y.reshape(u.shape[0], -1), z, p, cfg)
+    return out, stack, window[:, 1:]

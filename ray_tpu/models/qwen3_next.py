@@ -62,6 +62,7 @@ from ray_tpu.models.nemotron_h import (
     _init_ends,
     _normal,
 )
+from ray_tpu.ops.pallas.state_step import gdn_state_step
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 _L2_EPS = 1e-6
@@ -450,6 +451,21 @@ def gdn_chunked(u, p, cfg: Qwen3NextConfig, state0, conv0, length):
     return out, end.reshape(state0.shape), conv_end.astype(conv0.dtype)
 
 
+def _step_operands(u, p, cfg, conv):
+    """What one token's state update takes, of u [B, d] and the tail
+    conv [B, K - 1, conv_dim]: z, ``[b | a]``, q and k [B, Hk, dk], v
+    [B, Hk, r, dv], the window [B, K, conv_dim] whose last K - 1 rows
+    are the next tail."""
+    qkv, z, ba = _project_in(u, p, cfg)
+    with jax.named_scope("gdn:conv"):
+        window = jnp.concatenate(
+            [conv, qkv[:, None].astype(conv.dtype)], axis=1
+        )  # [B, K, conv_dim]
+        out = (window.astype(jnp.float32) * p["conv_w"][None]).sum(1)
+        q, k, v = _split_qkv(jax.nn.silu(out), cfg)
+    return z, ba, q, k, v, window
+
+
 def gdn_step(u, p, cfg: Qwen3NextConfig, state, conv):
     """The mixer for ONE token of each of B sequences: u [B, d], state
     [B, Hv, dk, dv] float32, conv [B, K - 1, conv_dim]. Returns (out
@@ -457,14 +473,7 @@ def gdn_step(u, p, cfg: Qwen3NextConfig, state, conv):
     float32 elementwise arithmetic: no product rounds the state."""
     bsz = u.shape[0]
     hk, dk, dv = cfg.gdn_key_heads, cfg.gdn_key_dim, cfg.gdn_value_dim
-    qkv, z, ba = _project_in(u, p, cfg)
-
-    with jax.named_scope("gdn:conv"):
-        window = jnp.concatenate(
-            [conv, qkv[:, None].astype(conv.dtype)], axis=1
-        )  # [B, K, conv_dim]
-        out = (window.astype(jnp.float32) * p["conv_w"][None]).sum(1)
-        q, k, v = _split_qkv(jax.nn.silu(out), cfg)
+    z, ba, q, k, v, window = _step_operands(u, p, cfg, conv)
 
     with jax.named_scope("gdn:update"):
         beta, g = _gates(ba, p, cfg)  # [B, Hk, r]
@@ -477,3 +486,24 @@ def gdn_step(u, p, cfg: Qwen3NextConfig, state, conv):
         o = (s * q[:, :, None, :, None]).sum(-2)  # [B, Hk, r, dv]
     out = _project_out(o.reshape(bsz, -1), z, p, cfg)
     return out, s.reshape(state.shape), window[:, 1:]
+
+
+def gdn_step_live(u, p, cfg: Qwen3NextConfig, stack, layer, conv, order,
+                  count):
+    """`gdn_step` on a TPU, for the slots that decode: ``stack`` [L, B,
+    Hv, dk, dv] is every layer's state, of which ``stack[layer]`` is
+    stepped IN PLACE for the first ``count`` slots of ``order``
+    (``ops/pallas/state_step.py live_order``) and no other slot's state
+    is read or written: the masked write-back is the kernel's. Returns
+    (out [B, d], the stack, conv after the token); a slot that does not
+    decode gets the ``out`` of ``o = 0`` (finite, and dropped)."""
+    bsz = u.shape[0]
+    z, ba, q, k, v, window = _step_operands(u, p, cfg, conv)
+    with jax.named_scope("gdn:update"):
+        beta, g = _gates(ba, p, cfg)  # [B, Hk, r]
+        stack, o = gdn_state_step(
+            stack, layer, order, count, jnp.exp(g).reshape(bsz, -1),
+            beta.reshape(bsz, -1), q, k, v.reshape(bsz, -1, v.shape[-1]),
+        )
+    out = _project_out(o.reshape(bsz, -1), z, p, cfg)
+    return out, stack, window[:, 1:]

@@ -23,7 +23,9 @@ matmul above that. The three attention kernels are also compiled at a
 head of 256 with 16 query and 2 KV heads (Qwen3-Next's), and that
 configuration's own programs (qwen3next-80b-serve1) at one Gated
 DeltaNet and the attention layer with the whole configuration's pages
-and slots.
+and slots. The decode step's state kernel (ops/pallas/state_step.py) is
+compiled at the three served stacks, and the three decode programs are
+held to making no pass of XLA's own over a layer's states beside it.
 """
 
 import math
@@ -250,6 +252,55 @@ def test_expert_combine_kernel_compiles_for_v5e_at_served_widths(v5e, case):
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20
 
 
+@pytest.mark.parametrize(
+    "case",
+    {
+        # The three served stacks: [layers, slots, heads, a, b], and the
+        # heads that share a row of the slot's small operands.
+        "granite_mamba": ("mamba", (9, 32, 128, 64, 128), 128),
+        "nemotron_mamba": ("mamba", (7, 32, 64, 64, 128), 8),
+        "qwen3next_gdn": ("gdn", (3, 32, 32, 128, 128), 2),
+    }.items(),
+    ids=lambda case: case[0],
+)
+def test_state_step_kernel_compiles_for_v5e_at_served_shapes(v5e, case):
+    """The head tile `_head_tile` takes from the shapes, the rows turned
+    into columns and the sums over lanes lower for the chip, inside the
+    VMEM the call asks for; the donated stack is the result (aliased:
+    nothing the size of a state is made beside the arguments)."""
+    from ray_tpu.ops.pallas import state_step
+
+    rule, stack, per_row = case[1]
+    _, slots, heads, a, b = stack
+
+    def on_chip(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    if rule == "mamba":
+        step = state_step.mamba_state_step
+        operands = (
+            on_chip(slots, heads), on_chip(slots, heads, a),
+            on_chip(slots, heads // per_row, b),
+            on_chip(slots, heads // per_row, b),
+        )
+    else:
+        step = state_step.gdn_state_step
+        operands = (
+            on_chip(slots, heads), on_chip(slots, heads),
+            on_chip(slots, heads // per_row, a),
+            on_chip(slots, heads // per_row, a), on_chip(slots, heads, b),
+        )
+    compiled = jax.jit(step, donate_argnums=0).lower(
+        on_chip(*stack), on_chip(dtype=jnp.int32),
+        on_chip(slots, dtype=jnp.int32), on_chip(1, dtype=jnp.int32),
+        *operands,
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert _copies_of(text, stack) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
 def _combine_scatters(text: str) -> list[str]:
     """A compiled program's lines under ``moe:combine`` that name a
     scatter (the instruction, or the ``scatter-add`` its fusion was
@@ -454,6 +505,53 @@ def _grouped_matmul_tiles(text: str) -> set[str]:
     return set(re.findall(r'ragged_dot_tiling="([\d,]+)"', text))
 
 
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%(\S+) = (.*?) ([a-z][a-z\-]*)\((.*)$"
+)
+
+
+def _elements(types: str) -> list[int]:
+    """Elements of every array type in a piece of program text."""
+    return [
+        math.prod(int(d) for d in dims.split(","))
+        for dims in _SHAPE.findall(types)
+    ]
+
+
+def _state_passes(text: str, scope: str, elements: int) -> list[str]:
+    """Instructions of a compiled program under the named scope, the
+    kernel's own call apart, that produce or take an array of at least
+    ``elements`` elements: a pass of XLA's over the state of every slot
+    of a layer (the read-out's read, the update's second read and masked
+    write: what ops/pallas/state_step.py replaced)."""
+    sizes, found = {}, []
+    lines = [m for m in map(_INSTRUCTION.match, text.splitlines()) if m]
+    for m in lines:
+        sizes[m.group(1)] = max(_elements(m.group(2)), default=0)
+    for m in lines:
+        name, types, opcode, rest = m.groups()
+        if f"/{scope}/" not in rest or opcode in (
+            "custom-call", "get-tuple-element", "bitcast", "tuple",
+            "parameter",
+        ):
+            continue
+        operands = re.findall(r"%([^\s,()]+)", rest.split("metadata=")[0])
+        touched = [sizes[name], *(sizes.get(o, 0) for o in operands)]
+        if max(touched) >= elements:
+            found.append(f"{opcode} %{name} {types}")
+    return found
+
+
+def _copies_of(text: str, shape: tuple) -> list[str]:
+    """Copies of an array of exactly ``shape`` anywhere in a compiled
+    program: the donated state stack made a second time."""
+    dims = ",".join(str(n) for n in shape)
+    return [
+        line.strip()[:160] for line in text.splitlines()
+        if re.search(rf"= \w+\[{dims}\]\S* copy(-start)?\(", line)
+    ]
+
+
 @pytest.fixture(scope="module")
 def hybrid_programs(v5e):
     """nemotron3nano-serve1's own sizes (benchmarks/configs) at 6 of its
@@ -531,6 +629,13 @@ def test_hybrid_program_moves_no_pages_state_or_expert_stack(
     assert temp < 64 * d * f * 2
     if program == "decode":
         assert temp < state * 4
+        # The state update is the kernel's one pass over the decoding
+        # slots (ops/pallas/state_step.py), once a Mamba block: XLA
+        # makes no pass of its own over a layer's states, and the
+        # donated stack is the result.
+        assert len(_kernel_calls_under(text, "ssm:update")) == 2
+        assert _state_passes(text, "ssm:update", state) == []
+        assert _copies_of(text, (2, eng["max_batch"], 64, 64, 128)) == []
 
 
 # ------------------------------------------------------ the latent programs
@@ -677,6 +782,12 @@ def test_granite_program_moves_no_pages_state_or_stack_and_fits(
         assert "ragged-dot" not in text
         assert len(_expert_kernel_calls(text)) == 2  # one a layer's FFN
         assert memory.temp_size_in_bytes < state * 4
+        # The state update is the kernel's one pass over the decoding
+        # slots: no pass of XLA's over the layer's states, no copy of
+        # the donated stack.
+        assert len(_kernel_calls_under(text, "ssm:update")) == 1
+        assert _state_passes(text, "ssm:update", state) == []
+        assert _copies_of(text, (1, eng["max_batch"], 128, 64, 128)) == []
     else:
         assert "prefill_attention" in text and "ragged-dot" in text
         assert _expert_kernel_calls(text) == []
@@ -811,6 +922,12 @@ def test_qwen3next_program_moves_no_pages_state_or_stack_and_fits(
         assert "ragged-dot" not in text
         assert len(_expert_kernel_calls(text)) == 2  # one a layer's FFN
         assert memory.temp_size_in_bytes < state * 4
+        # The delta rule's step is the kernel's one pass over the
+        # decoding slots: no pass of XLA's over the layer's states, no
+        # copy of the donated stack.
+        assert len(_kernel_calls_under(text, "gdn:update")) == 1
+        assert _state_passes(text, "gdn:update", state) == []
+        assert _copies_of(text, (1, eng["max_batch"], 32, 128, 128)) == []
     else:
         assert "prefill_attention" in text and "ragged-dot" in text
         assert _expert_kernel_calls(text) == []

@@ -12,7 +12,12 @@ programs skip where they are 1 (``_embed``, ``_residual``, ``_head``).
 A Qwen3-Next layer is two as well; its recurrent mixer is another
 letter (``G``, the gated delta rule) with its own leaves of the cache,
 and its attention block norms, rotates and gates (`_attention_inputs`),
-which the other families' configs hold off.
+which the other families' configs hold off. A Laguna layer
+(``models/laguna.py``) is two as well: its attention is of two kinds,
+``*`` over the whole context from pages and ``W`` over the last
+``cfg.sliding_window`` positions from per-slot leaves of the cache, each
+kind with its own head count and rotary scheme (`_attention_kind`), and
+its first layer's FFN is dense (``D``).
 
 The cache is ONE donated tree with two kinds of per-sequence state:
 
@@ -25,7 +30,22 @@ The cache is ONE donated tree with two kinds of per-sequence state:
   the pattern has ``G`` blocks, ``gdn`` ``[L_gdn, max_batch, Hv, dk, dv]``
   float32 and ``gdn_conv`` ``[L_gdn, max_batch, K - 1, conv_dim]`` are
   the same for them: a matrix a head. A cache holds the leaves of the
-  kinds its pattern has (`_RECURRENT`).
+  kinds its pattern has (`_RECURRENT`);
+- ``win_k``, ``win_v`` ``[L_window, max_batch, W, Hkv, Dh]``: each
+  slot's last ``W = cfg.sliding_window`` keys and values in each window
+  layer, the key of position ``t`` at ring index ``t % W`` (positions
+  major over heads: a decode step writes one ``[Hkv, Dh]`` row a slot,
+  and it is the layout XLA gives the leaf in both programs when asked
+  for the other). They do not grow with the context either, and a
+  request's pages are the ``*`` layers' alone. (The other design, one pool and pages released behind
+  the window, was not taken: a slot's pages would differ by layer kind,
+  so one block table a request could not serve both, and the kernels
+  that read pages would each need a first page; the ring costs ``W``
+  cells a slot and layer whether the request is long or short, 0.1 GB of
+  13 at the benchmark's sizes.) What a ring holds that its request did
+  not write (a slot's last request's keys, or zeros) lies at positions
+  that every reader masks by the TRUE position it computes for the
+  index: nothing is cleared.
 
 Both are carried through the Python loop over the pattern and updated in
 place (a block writes its own layer's slot rows; nothing is sliced out
@@ -51,6 +71,7 @@ lie, with no scores over the table in HBM), the expert mixer is
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from collections.abc import Callable
 from functools import partial
@@ -62,6 +83,7 @@ import numpy as np
 
 from ray_tpu._private import chip
 from ray_tpu.llm.paged_kv import (
+    _NEG_INF,
     _decode_attention,
     _decode_geometry,
     _flat_pool,
@@ -83,6 +105,12 @@ from ray_tpu.models.nemotron_h import (
 from ray_tpu.models.qwen3_next import gdn_chunked, gdn_step, gdn_step_live
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.pallas.state_step import live_order
+from ray_tpu.ops.pallas.window_attention import (
+    band_blocks,
+    window_attention,
+    window_attention_dense,
+)
+from ray_tpu.ops.rope import yarn_inv_freq
 
 HybridCache = dict[str, jnp.ndarray]
 
@@ -147,6 +175,10 @@ def init_hybrid_cache(
             cache[block.conv] = jnp.zeros(
                 (n, max_batch, cfg.conv_kernel - 1, channels), cfg.dtype
             )
+    if n := cfg.count("W"):
+        ring = (n, max_batch, cfg.sliding_window, cfg.n_kv_heads, cfg.head_dim)
+        cache["win_k"] = jnp.zeros(ring, cfg.dtype)
+        cache["win_v"] = jnp.zeros(ring, cfg.dtype)
     return cache
 
 
@@ -222,46 +254,68 @@ def _recurrent_leaves(cache) -> HybridCache:
     }
 
 
-def _rotate(x, positions, cfg):
-    """A rotary embedding on the first ``cfg.rotary_dim`` dimensions of
-    each head of x [B, S, H, Dh] at ``positions`` [B, S] (split halves
-    within those; the rest pass through), float32 inside."""
-    half = cfg.rotary_dim // 2
-    inv_freq = cfg.rope_theta ** (
-        -jnp.arange(half, dtype=jnp.float32) / half
-    )
+def _attention_kind(cfg, kind: str):
+    """What differs between a model's kinds of attention block: (query
+    heads, the dimensions of a head that are rotated, theta, YaRN's
+    numbers or None) for the letter ``kind``. ``W``'s are fields of the
+    one family whose pattern holds the letter."""
+    if kind == "W":
+        return (cfg.window_heads, cfg.window_rotary_dim,
+                cfg.window_rope_theta, None)
+    return cfg.n_heads, cfg.rotary_dim, cfg.rope_theta, cfg.rope_yarn
+
+
+def _rotate(x, positions, rotary_dim, theta, yarn=None):
+    """A rotary embedding on the first ``rotary_dim`` dimensions of each
+    head of x [B, S, H, Dh] at ``positions`` [B, S] (split halves within
+    those; the rest pass through), float32 inside. ``yarn`` (factor,
+    original length, beta_fast, beta_slow, attention_factor): YaRN's
+    blended frequencies, cos and sin times the last."""
+    half = rotary_dim // 2
+    if yarn is None:
+        inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    else:
+        inv_freq = yarn_inv_freq(rotary_dim, theta, *yarn[:4])
     angles = positions.astype(jnp.float32)[..., None, None] * inv_freq
     cos, sin = jnp.cos(angles), jnp.sin(angles)  # [B, S, 1, half]
+    if yarn is not None:
+        cos, sin = cos * yarn[4], sin * yarn[4]
     x1, x2, rest = jnp.split(
-        x.astype(jnp.float32), [half, cfg.rotary_dim], axis=-1
+        x.astype(jnp.float32), [half, rotary_dim], axis=-1
     )
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1
     ).astype(x.dtype)
 
 
-def _attention_inputs(x, p, cfg, positions):
+def _attention_inputs(x, p, cfg, positions, kind: str = "*"):
     """An attention block's q [B, S, H, Dh], k and v [B, S, Hkv, Dh] of
     x [B, S, d] at ``positions`` [B, S], and the heads' output gate
-    [B, S, H, Dh] (None where the model has none). What a family adds
-    is skipped at its config's off value, so that the others' programs
-    are `paged_kv._project_qkv`'s three products and nothing else."""
+    ([B, S, H, Dh], or [B, S, H, 1] where it is one number a head; None
+    where the model has none), for a block of the letter ``kind``. What
+    a family adds is skipped at its config's off value, so that the
+    others' programs are `paged_kv._project_qkv`'s three products and
+    nothing else."""
     b, s, _ = x.shape
     dh = cfg.head_dim
+    n_heads, rotary_dim, theta, yarn = _attention_kind(cfg, kind)
     h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
     q = h @ p["wq"]
     gate = None
     if cfg.attn_output_gate:
         # Head by head [query | gate].
-        q, gate = jnp.split(q.reshape(b, s, cfg.n_heads, 2 * dh), 2, axis=-1)
-    q = q.reshape(b, s, cfg.n_heads, dh)
+        q, gate = jnp.split(q.reshape(b, s, n_heads, 2 * dh), 2, axis=-1)
+    elif cfg.head_gate:
+        gate = (h @ p["wg"])[..., None]
+    q = q.reshape(b, s, n_heads, dh)
     k = (h @ p["wk"]).reshape(b, s, cfg.n_kv_heads, dh)
     v = (h @ p["wv"]).reshape(b, s, cfg.n_kv_heads, dh)
     if cfg.qk_norm:
         q = rms_norm(q, p["q_norm"], cfg.norm_eps)
         k = rms_norm(k, p["k_norm"], cfg.norm_eps)
-    if cfg.rotary_dim:
-        q, k = _rotate(q, positions, cfg), _rotate(k, positions, cfg)
+    if rotary_dim:
+        q = _rotate(q, positions, rotary_dim, theta, yarn)
+        k = _rotate(k, positions, rotary_dim, theta, yarn)
     return q, k, v, gate
 
 
@@ -274,6 +328,102 @@ def _attention_output(attn, gate, p, cfg):
                 attn * jax.nn.sigmoid(gate.astype(jnp.float32))
             ).astype(cfg.dtype)
     return attn.reshape(*attn.shape[:2], -1) @ p["wo"]
+
+
+def _full_scope(cfg):
+    """The scope a ``*`` block's page write and attention run under where
+    the model has window layers beside it, so that a trace tells the two
+    kinds apart; none for a model with one kind, whose programs stay as
+    they were."""
+    if cfg.count("W"):
+        return jax.named_scope("attn:full")
+    return contextlib.nullcontext()
+
+
+def _window_prefill(q, k, v, win_k, win_v, at, start, n_live, cfg,
+                    use_kernel: bool):
+    """A window block's attention for one chunk of one slot, and the
+    slot's ring after it. q [C, H, Dh], k and v [C, Hkv, Dh] at positions
+    ``start ..``, of which the first ``n_live`` are real; ``win_k`` /
+    ``win_v`` the cache's leaves, ``at`` (layer, slot). The chunk attends
+    the slot's W carried keys, put in the order of their positions
+    ``start - W .. start - 1``, and then its own, by the band kernel
+    where ``use_kernel`` and blocks divide the shapes
+    (``ops/pallas/window_attention.py``), else by dense scores under the
+    same mask. Then the last W REAL positions go back to the ring, each
+    to its own index. Returns (attn [C, H, Dh], win_k, win_v)."""
+    c, w = q.shape[0], cfg.sliding_window
+    with jax.named_scope("attn:window"):
+        # Ring index of position start - W + j.
+        order = (start + jnp.arange(w, dtype=jnp.int32)) % w
+
+        def band(ring, new):
+            carried = jnp.take(ring[at], order, axis=0)  # [W, Hkv, Dh]
+            return jnp.concatenate([carried, new.astype(ring.dtype)], axis=0)
+
+        keys, values = band(win_k, k), band(win_v, v)  # [W + C, Hkv, Dh]
+        head_major = (q, keys.transpose(1, 0, 2), values.transpose(1, 0, 2))
+        if use_kernel and band_blocks(c, w) is not None:
+            attn = window_attention(
+                *head_major, start, window=w, scale=cfg.attention_scale,
+                interpret=chip.platform() != "tpu",
+            )
+        else:
+            attn = window_attention_dense(
+                *head_major, start, window=w, scale=cfg.attention_scale
+            )
+    with jax.named_scope("attn:window_write"):
+        # Positions end - W .. end - 1 lie at n_live .. n_live + W - 1 of
+        # the band; ring index r takes the one of them that is r mod W.
+        back = (jnp.arange(w, dtype=jnp.int32) - (start + n_live)) % w
+
+        def left(ring, band_):
+            last = jax.lax.dynamic_slice_in_dim(band_, n_live, w, axis=0)
+            return ring.at[at].set(jnp.take(last, back, axis=0))
+
+        return attn.astype(q.dtype), left(win_k, keys), left(win_v, values)
+
+
+def _window_decode(q, k, v, win_k, win_v, layer: int, positions, active, cfg):
+    """A window block's attention for one token of every slot: q [B, 1,
+    H, Dh] over each slot's ring after its k, v [B, 1, Hkv, Dh] went to
+    index ``position % W`` (a slot that is not decoding writes nothing:
+    it may be mid-prefill, and its ring is that prefill's). Ring index
+    ``r`` holds the newest position ``<= t`` that is ``r mod W``; where
+    that is negative the request has not written it and it is masked.
+    Plain XLA: the scores are [B, H, W]. Returns (attn [B, 1, H, Dh],
+    win_k, win_v)."""
+    b, _, n_heads, dh = q.shape
+    w, hkv = cfg.sliding_window, cfg.n_kv_heads
+    with jax.named_scope("attn:window_write"):
+        cell = jnp.where(active, positions % w, w)  # w: dropped
+        slots = jnp.arange(b)
+        win_k = win_k.at[layer, slots, cell].set(
+            k[:, 0].astype(win_k.dtype), mode="drop"
+        )
+        win_v = win_v.at[layer, slots, cell].set(
+            v[:, 0].astype(win_v.dtype), mode="drop"
+        )
+    with jax.named_scope("attn:window"):
+        t = positions[:, None]
+        held = t - (t - jnp.arange(w, dtype=positions.dtype)[None, :]) % w
+        scale = dh**-0.5 if cfg.attention_scale is None else cfg.attention_scale
+        scores = jnp.einsum(
+            "bgrd,bwgd->bgrw", q[:, 0].reshape(b, hkv, n_heads // hkv, dh),
+            win_k[layer], preferred_element_type=jnp.float32,
+        ) * scale
+        scores = jnp.where((held < 0)[:, None, None, :], _NEG_INF, scores)
+        probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+        attn = jnp.einsum("bgrw,bwgd->bgrd", probs, win_v[layer])
+    return attn.reshape(b, 1, n_heads, dh), win_k, win_v
+
+
+def _dense_ffn(x, p, cfg):
+    """A dense gated-SiLU FFN sublayer on x [B, S, d]."""
+    with jax.named_scope("ffn:dense"):
+        h = rms_norm(x, p["norm"], cfg.norm_eps)
+        out = (jax.nn.silu(h @ p["w_gate"]) * (h @ p["w_up"])) @ p["w_down"]
+    return _residual(x, out, cfg)
 
 
 def _head(x, params, cfg=None):
@@ -348,23 +498,35 @@ def _hybrid_prefill(
             x = _residual(x, out[None], cfg)
         elif kind == "E":
             x = _experts(x, p, cfg, live, record)
+        elif kind == "D":
+            x = _dense_ffn(x, p, cfg)
+        elif kind == "W":
+            q, k, v, gate = _attention_inputs(x, p, cfg, pos, kind)
+            attn, state["win_k"], state["win_v"] = _window_prefill(
+                q[0], k[0], v[0], state["win_k"], state["win_v"],
+                (seen[kind], slot), start, jnp.clip(length - start, 0, c),
+                cfg, use_kernel,
+            )
+            x = _residual(x, _attention_output(attn[None], gate, p, cfg), cfg)
         else:
             base = seen[kind] * num_pages
             q, k, v, gate = _attention_inputs(x, p, cfg, pos)  # [1, C, H, Dh]
-            k_pages, v_pages = _write_pages(
-                k_pages, v_pages, k, v, base + chunk_slice, cfg
-            )
-            if by_kernel:
-                attn = _prefill_kernel_attention(
-                    q, jnp.take(k_pages, base + pages, axis=0, mode="clip"),
-                    jnp.take(v_pages, base + pages, axis=0, mode="clip"),
-                    start, cfg.attention_scale,
+            with _full_scope(cfg):
+                k_pages, v_pages = _write_pages(
+                    k_pages, v_pages, k, v, base + chunk_slice, cfg
                 )
-            else:
-                attn = _gather_page_attention(
-                    q, k_pages, v_pages, base + pages[None, :], mask, cfg,
-                    cfg.attention_scale,
-                )
+                if by_kernel:
+                    attn = _prefill_kernel_attention(
+                        q,
+                        jnp.take(k_pages, base + pages, axis=0, mode="clip"),
+                        jnp.take(v_pages, base + pages, axis=0, mode="clip"),
+                        start, cfg.attention_scale,
+                    )
+                else:
+                    attn = _gather_page_attention(
+                        q, k_pages, v_pages, base + pages[None, :], mask, cfg,
+                        cfg.attention_scale,
+                    )
             x = _residual(x, _attention_output(attn, gate, p, cfg), cfg)
         seen[kind] += 1
     last = jax.lax.dynamic_slice_in_dim(x, length - 1 - start, 1, axis=1)
@@ -462,21 +624,39 @@ def hybrid_decode(
             x = _residual(x, out[:, None], cfg)
         elif kind == "E":
             x = _experts(x, p, cfg, active, record)
+        elif kind == "D":
+            x = _dense_ffn(x, p, cfg)
+        elif kind == "W":
+            q, k, v, gate = _attention_inputs(
+                x, p, cfg, positions[:, None], kind
+            )
+            attn, state["win_k"], state["win_v"] = _window_decode(
+                q, k, v, state["win_k"], state["win_v"], seen[kind],
+                positions, active, cfg,
+            )
+            x = _residual(x, _attention_output(attn, gate, p, cfg), cfg)
         else:
             q, k, v, gate = _attention_inputs(
                 x, p, cfg, positions[:, None]
             )  # [B, 1, H, Dh]
-            attn, k_pages, v_pages = _decode_attention(
-                q, k.astype(cfg.dtype), v.astype(cfg.dtype), k_pages,
-                v_pages, seen[kind] * num_pages, geometry, positions, cfg,
-                use_kernel, cfg.attention_scale,
-            )
+            with _full_scope(cfg):
+                attn, k_pages, v_pages = _decode_attention(
+                    q, k.astype(cfg.dtype), v.astype(cfg.dtype), k_pages,
+                    v_pages, seen[kind] * num_pages, geometry, positions, cfg,
+                    use_kernel, cfg.attention_scale,
+                )
             x = _residual(x, _attention_output(attn, gate, p, cfg), cfg)
         seen[kind] += 1
     logits = _head(x, params, cfg)  # [B, 1, V]
     sampled = _sample_tokens(logits, temperature, rng_key)
     carried = _carried(cache, k_pages, v_pages, state)
     return sampled, logits[:, 0], carried, _record(record)
+
+
+def _band_pairs_before(m: int, w: int) -> int:
+    """Sum of ``min(t + 1, w)`` over the positions ``t < m``: the (query,
+    key) pairs a window layer's arithmetic needs up to position m."""
+    return m * (m + 1) // 2 if m <= w else w * (w + 1) // 2 + (m - w) * w
 
 
 class HybridServing:
@@ -499,6 +679,7 @@ class HybridServing:
         self.recurrent_blocks = sum(cfg.count(kind) for kind in _RECURRENT)
         self._init_weights = init_weights
         self._prefill_programs = self._live_tokens = self._prefill_pairs = 0
+        self._window_pairs = self._window_bytes = 0
 
     def init_weights(self, key):
         return self._init_weights(key, self.cfg)
@@ -514,7 +695,12 @@ class HybridServing:
 
     def init_cache(self, num_pages: int, page_size: int, max_batch: int,
                    shardings=None):
-        return init_hybrid_cache(self.cfg, num_pages, page_size, max_batch)
+        cache = init_hybrid_cache(self.cfg, num_pages, page_size, max_batch)
+        self._window_bytes = sum(
+            int(cache[leaf].nbytes) for leaf in ("win_k", "win_v")
+            if leaf in cache
+        )
+        return cache
 
     cache_bytes = staticmethod(kv_cache_bytes)
 
@@ -522,13 +708,20 @@ class HybridServing:
         # Over the prefill programs run: how many; the live tokens the
         # chunked scans took, summed over the Mamba blocks and over the
         # gated-delta-rule blocks; the causal (query, key) pairs the
-        # attention's arithmetic needed, summed over the attention
-        # blocks (`LlamaServing` counts the same).
+        # attention's arithmetic needed, summed over the blocks that
+        # attend the whole context (`LlamaServing` counts the same) and,
+        # apart, over the window blocks, where a query at position t
+        # needs min(t + 1, W) keys, and the live tokens those blocks
+        # took. `window_bytes`: what of the cache's per-slot bytes (the
+        # engine's `state_bytes`) is windows.
         return {
             "prefill_programs": self._prefill_programs,
             "ssm_scan_tokens": self.cfg.count("M") * self._live_tokens,
             "gdn_scan_tokens": self.cfg.count("G") * self._live_tokens,
             "prefill_attn_pairs": self._prefill_pairs,
+            "prefill_window_pairs": self._window_pairs,
+            "window_tokens": self.cfg.count("W") * self._live_tokens,
+            "window_bytes": self._window_bytes,
         }
 
     def _count(self, start: int, width: int, length: int) -> None:
@@ -538,6 +731,11 @@ class HybridServing:
         self._prefill_pairs += self.cfg.count("*") * (
             n * start + n * (n + 1) // 2
         )
+        if layers := self.cfg.count("W"):
+            w = self.cfg.sliding_window
+            self._window_pairs += layers * (
+                _band_pairs_before(start + n, w) - _band_pairs_before(start, w)
+            )
 
     def prefill(self, params, tokens, cache, pages, *, n_write_pages, slot,
                 length, use_kernel=False):

@@ -123,6 +123,15 @@ class NemotronHConfig:
     rope_theta: float = 10000.0
     attn_output_gate: bool = False
     norm_eps: float = 1e-5
+    # What a family with two kinds of attention layer adds to the block
+    # (`models/laguna.py`), off here and in the families above: a gate of
+    # ONE number a head from its own `wg` [d, H], and YaRN's scaling of
+    # the rotary embedding, (factor, original length, beta_fast,
+    # beta_slow, attention_factor). Its second kind of attention block
+    # and its dense FFN are letters of its own pattern, whose fields are
+    # its own.
+    head_gate: bool = False
+    rope_yarn: tuple | None = None
 
     # The letters `pattern` may hold: a family with another recurrence
     # adds its own (`models/qwen3_next.py`: G).

@@ -1,0 +1,54 @@
+"""A serving family's check on the chip with the reference computed
+otherwise: the readings a limit of `benchmarks/models/<model>.py` has to
+fail (its `lower` switches), beside the reading it has to pass.
+
+    chiprun --timeout 3000 -- python scripts/family_check_lowers.py \
+        --config laguna-s21-serve1 --seed 7 --lower none weights_e4m3 ...
+
+Builds the configuration's replica object in this process (no cluster:
+the check runs alone before any request anyway), runs `check` once a
+`lower`, and prints one JSON line each: the check's record and
+`check_problems` of it. ``none`` is the check as a benchmark run makes
+it."""
+
+import argparse
+import importlib
+import json
+import sys
+import time
+
+sys.path.insert(0, ".")
+
+
+def main() -> None:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--config", required=True)
+    ap.add_argument("--seed", type=int, default=7)
+    ap.add_argument("--lower", nargs="+", default=["none"])
+    args = ap.parse_args()
+
+    from benchmarks.server_family import BenchFamilyServer
+
+    with open(f"benchmarks/configs/{args.config}.json") as f:
+        conf = json.load(f)
+    family = importlib.import_module(f"benchmarks.models.{conf['model']}")
+    server = BenchFamilyServer(conf, args.seed)
+    for lower in args.lower:
+        began = time.time()
+        record = server.check(
+            args.seed, **conf.get("check", {}),
+            lower=None if lower == "none" else lower,
+        )
+        print(json.dumps({
+            "config": args.config, "seed": args.seed, "lower": lower,
+            "seconds": round(time.time() - began, 1),
+            "problems": family.check_problems(record), "check": record,
+        }), flush=True)
+    stats = server.engine.stats()
+    print(json.dumps({key: stats[key] for key in (
+        "param_bytes", "pool_bytes", "state_bytes") if key in stats}
+        | {k: v for k, v in stats.items() if k.startswith("window")}))
+
+
+if __name__ == "__main__":
+    main()

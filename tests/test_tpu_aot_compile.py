@@ -26,6 +26,12 @@ DeltaNet and the attention layer with the whole configuration's pages
 and slots. The decode step's state kernel (ops/pallas/state_step.py) is
 compiled at the three served stacks, and the three decode programs are
 held to making no pass of XLA's own over a layer's states beside it.
+The band kernel (ops/pallas/window_attention.py) is compiled at 72 query
+heads over 8 KV heads and the prefill and paged kernels at 48 over 8
+(groups of 9 and 6), and laguna-s21-serve1's own programs at its full
+layer and one window layer with the whole configuration's pages and
+slots, at all three table widths: a window layer's part is the same in
+each.
 """
 
 import math
@@ -944,6 +950,166 @@ def test_qwen3next_program_moves_no_pages_state_or_stack_and_fits(
     )
     # The float32 leaves (routers, norms, convolutions) are 9 MB more.
     assert abs(arguments - counted) < 16e6
+    assert arguments > 0.25 * 16 * 2**30  # the floor a new cell is held to
+    assert arguments + memory.temp_size_in_bytes < 15.75 * 2**30
+
+
+# ---------------- two kinds of attention layer, groups of 9 and 6 (Laguna)
+HW, HF, WINDOW = 72, 48, 512
+
+
+def _two_kinds_case(case: str, on):
+    """The kernel and its arguments at laguna-s21-serve1's shapes: 16
+    slots, 4,161 pages of 64 tokens, tables of 260 pages, a 2,048-token
+    chunk, 8 KV heads of 128 under 72 query heads in a window layer and
+    48 in a full one."""
+    def bf16(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=on)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=on)
+
+    if case == "band_72_of_8":
+        from ray_tpu.ops.pallas.window_attention import window_attention
+
+        keys = bf16(HKV, WINDOW + 2048, DH)
+        return partial(window_attention, window=WINDOW), (
+            bf16(2048, HW, DH), keys, keys, i32()
+        )
+    if case == "prefill_attn_48_of_8":
+        from ray_tpu.ops.pallas.prefill_attention import prefill_attention
+
+        pages = bf16(16384 // PAGE, HKV, PAGE, DH)
+        return prefill_attention, (bf16(2048, HF, DH), pages, pages, i32())
+    from ray_tpu.ops.pallas.paged_attention import paged_attention
+
+    pool = bf16(2 * 4161, HKV, PAGE, DH)
+    return partial(paged_attention, n_kv_heads=HKV), (
+        bf16(16, 1, HF, DH), pool, pool, i32(16, 260), i32(16)
+    )
+
+
+@pytest.mark.parametrize(
+    "case", ["band_72_of_8", "prefill_attn_48_of_8", "paged_48_of_8"]
+)
+def test_kernel_compiles_for_v5e_at_query_groups_of_9_and_6(v5e, case):
+    """Nine query heads a KV head in the band kernel (a query block of
+    ``[256, 9 x 128]`` lanes against the band's three key tiles) and six
+    in the prefill and the paged kernel (``(1, 8, 6, 128)`` query blocks):
+    neither group size had been lowered (ROADMAP R8)."""
+    fn, args = _two_kinds_case(case, v5e)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.fixture(scope="module")
+def laguna_programs(v5e):
+    """laguna-s21-serve1's own sizes (benchmarks/configs) at 2 of its 5
+    layers, so that layer 0 is the full layer with the dense FFN and
+    layer 1 a window layer with its expert FFN, with the whole
+    configuration's pages and slots: what `aot_fit_serve_family` lowers,
+    at every table of the mix. Compiled when first asked for."""
+    import json
+
+    from benchmarks import aot_fit_serve_family
+    from ray_tpu._private import chip
+
+    here = os.path.join(os.path.dirname(__file__), "..", "benchmarks")
+    with open(os.path.join(here, "configs", "laguna-s21-serve1.json")) as f:
+        whole = json.load(f)
+    conf = {**whole, "num_hidden_layers": 2}
+    traffic = {"fit_prefill_buckets": [4096, 8192, 16384]}
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(chip, "platform", lambda: "tpu")
+        lowered = aot_fit_serve_family.lowered_programs(
+            conf, traffic, next(iter(v5e.device_set))
+        )
+    compiled = {}
+
+    def program(name):
+        if name not in compiled:
+            compiled[name] = lowered[name].compile()
+        return compiled[name]
+
+    return whole, program
+
+
+def _arrays_under(text: str, scope: str) -> set[str]:
+    """Every array type on the lines of a compiled program's text that
+    carry the named scope."""
+    found = set()
+    for line in text.splitlines():
+        if f"/{scope}/" in line:
+            found.update(re.findall(r"\b[a-z]+\d*\[[\d,]+\]", line))
+    return found
+
+
+@pytest.mark.parametrize(
+    "program",
+    ["prefill_chunk_2048_of_4096", "prefill_chunk_2048_of_8192",
+     "prefill_chunk_2048_of_16384", "decode"],
+)
+def test_laguna_program_moves_no_pages_or_stack_and_fits(
+    laguna_programs, program
+):
+    """Through the same `llm/hybrid_kv.py` with the letters `W` and `D`:
+    the donated cache updated in place (pages for the full layer, a
+    `[512, 8, 128]` ring a slot for the window layer), the 128 held
+    experts' stacks read where they lie, the band kernel at 72 heads and
+    the prefill and paged kernels at 48, and the temporaries beside the
+    WHOLE configuration's arguments under what a v5e offers a program.
+    A window layer's part of a chunk program is the same at every table
+    width: it holds no array that grows with the context."""
+    from benchmarks.models import laguna as family
+
+    conf, compiled_program = laguna_programs
+    eng = conf["engine"]
+    d, f = conf["hidden_size"], conf["moe_intermediate_size"]
+    held, hkv = conf["num_experts"], conf["num_key_value_heads"]
+    layer_pages = (eng["num_pages"] + 1) * hkv * PAGE * DH
+    shapes = {
+        "pages": ((hkv, PAGE, DH), layer_pages),
+        "w_up": ((d, f), held * d * f),
+        "w_down": ((f, d), held * d * f),
+    }
+    compiled = compiled_program(program)
+    text = compiled.as_text()
+    assert _hybrid_moves(text, shapes) == []
+    memory = compiled.memory_analysis()
+    chunk = eng["prefill_chunk"]
+    if program == "decode":
+        assert "paged_attention" in text and "write_kv_cells" in text
+        assert "window_attention" not in text and "ragged-dot" not in text
+        assert len(_expert_kernel_calls(text)) == 1  # the one sparse FFN
+        # A window layer's decode is XLA's: scores [slots, 8, 9, 512].
+        assert f"f32[{eng['max_batch']},{hkv},{HW // hkv},{WINDOW}]" in text
+        assert memory.temp_size_in_bytes < 2**28
+    else:
+        table = int(program.rsplit("_", 1)[1])
+        assert "prefill_attention" in text and "window_attention" in text
+        assert "ragged-dot" in text and _expert_kernel_calls(text) == []
+        # No score over the table or over the band in HBM.
+        assert not re.search(rf"\[[\d,]*{chunk},{table}\]", text)
+        assert not re.search(rf"\[[\d,]*{chunk},{WINDOW + chunk}\]", text)
+        k = conf["num_experts_per_tok"]
+        assert _expert_arrays_of(text, (chunk * k, d)) == []
+        assert memory.temp_size_in_bytes < 2**30
+        # The window layer's operations and the ring's write are the
+        # narrowest table's, array for array.
+        narrow = compiled_program("prefill_chunk_2048_of_4096").as_text()
+        for scope in ("attn:window", "attn:window_write"):
+            mine = _arrays_under(text, scope)
+            assert mine and mine == _arrays_under(narrow, scope)
+            assert f"bf16[{hkv},{WINDOW + chunk},{DH}]" in _arrays_under(
+                text, "attn:window")
+    arguments = conf["fit"]["argument_bytes"]
+    counted = (
+        family.held_parameters(conf) * 2 + 2 * 2 * layer_pages * 2
+        + family.window_layers(conf) * eng["max_batch"]
+        * family.window_bytes_per_slot(conf)
+    )
+    # The float32 leaves (routers, norms) are 4 MB more.
+    assert abs(arguments - counted) < 8e6
     assert arguments > 0.25 * 16 * 2**30  # the floor a new cell is held to
     assert arguments + memory.temp_size_in_bytes < 15.75 * 2**30
 

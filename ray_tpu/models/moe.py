@@ -10,9 +10,10 @@ per-expert slot limit and no one-hot dispatch tensor, so the work is
 tokens x top_k whatever the skew. Experts carry the "expert" logical
 axis, sharded over the mesh's ep axis. A serving program that holds a
 share of the experts does that work on the pairs whose expert it holds,
-a block of the sorted order at a time, and sums the blocks' rows onto
-their tokens once a layer, on a TPU by a kernel of its own
-(`_experts_on_pairs_here`, ``ops/pallas/expert_combine.py``).
+gathered a block of the sorted order at a time, and sums the rows onto
+their tokens once a layer; on a TPU the grouped matmuls and the sum are
+kernels of their own (`_experts_on_pairs_here`,
+``ops/pallas/grouped_rows.py``, ``ops/pallas/expert_combine.py``).
 
 The attention sublayer, scan scaffolding, and non-expert parameters are
 the flagship Llama's (ray_tpu.models.llama — this module only swaps the
@@ -29,7 +30,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.xla_metadata import set_xla_metadata
 
 from ray_tpu._private import chip
 from ray_tpu.models.llama import (
@@ -41,6 +41,7 @@ from ray_tpu.models.llama import (
 )
 from ray_tpu.ops.pallas.expert_combine import combine_rows
 from ray_tpu.ops.pallas.expert_rows import experts_on_rows
+from ray_tpu.ops.pallas.grouped_rows import grouped_rows
 
 
 @dataclasses.dataclass(frozen=True)
@@ -291,42 +292,23 @@ def _experts_on_sorted_pairs(tokens, p, cfg, routes, gates):
 _PAIR_BLOCK = 1024
 
 
-# (rows, contraction) of a tile of the compiler's grouped matmul in
-# `_experts_on_pairs_here`, where its own are (512, 512). On a v5e an
-# expert layer alone, 2,048 rows (PERF.md section 6, PR 41): at
-# granite's widths 6.64 ms with the compiler's tiles, 5.74 with these;
-# at openPangu's 6.35 and 4.55.
-_GROUP_TILE = (256, 1024)
-
-
-def _grouped_matmul(a, w, sizes):
-    """``jax.lax.ragged_dot`` with `_GROUP_TILE`: on a TPU the compiler's
-    kernel visits every (group, row tile) pair that holds a row and pays
-    a whole tile of arithmetic a visit, so a block of few-row groups
-    wants fewer rows a tile than the compiler's 512, and then a deeper
-    contraction a step. The tile over the output's columns is the one
-    the compiler picks itself (512, or 256 where 512 does not divide).
-    Elsewhere the attribute says nothing."""
-    rows, depth = _GROUP_TILE
-    columns = 512 if w.shape[2] % 512 == 0 else 256
-    with set_xla_metadata(ragged_dot_tiling=f"{rows},{depth},{columns}"):
-        return jax.lax.ragged_dot(a, w, sizes)
-
-
 def _experts_on_pairs_here(tokens, p, cfg, routes, gates, here):
     """The sorted form where only the pairs under ``here`` are computed
     (a share of the experts held, rows that carry no token): a serving
     program's, forward only. Those pairs sort to the front, ``m`` of
     them, and the work is ``m`` rounded up to `_PAIR_BLOCK`, not the
     ``n * k`` pairs routed: a loop over blocks of the sorted order, as
-    many as hold a pair, each gathering its rows and applying the
-    experts to them (the block's part of each expert's group), and after
-    it ONE sum of the ``m`` live rows onto their tokens, each weighted by
-    its float32 gate where it lies. On a TPU that sum is a kernel
-    (``ops/pallas/expert_combine.py``) over the blocks' outputs as the
-    grouped matmul left them; elsewhere XLA's scatter-add, which is also
-    the kernel's oracle. Any routing gives the same sums as every pair
-    computed and the dead ones masked, all ``n * k`` live included.
+    many as hold a pair, each gathering its rows; the experts applied to
+    the rows gathered (each expert to its group); and ONE sum of the
+    ``m`` live rows onto their tokens, each weighted by its float32 gate
+    where it lies. On a TPU the experts are two calls a layer of a
+    kernel that reads each expert's weights once however its rows fall
+    into tiles (``ops/pallas/grouped_rows.py``) and the sum is a kernel
+    too (``ops/pallas/expert_combine.py``), over the rows as the first
+    left them; elsewhere the loop applies ``jax.lax.ragged_dot`` to each
+    block's part of the groups and XLA's scatter-add sums, which are
+    also the kernels' oracles. Any routing gives the same sums as every
+    pair computed and the dead ones masked, all ``n * k`` live included.
     Returns the rows the grouped matmuls ran over beside the output and
     the load."""
     n, k = routes.shape
@@ -334,6 +316,7 @@ def _experts_on_pairs_here(tokens, p, cfg, routes, gates, here):
     first, e_here = cfg.experts_held or (0, cfg.num_experts)
     dt = cfg.dtype
     block = min(_PAIR_BLOCK, n * k)
+    on_tpu = chip.platform() == "tpu"
     with jax.named_scope("moe:dispatch"):
         # Held experts are numbered from 0; a pair that is not computed
         # here gets the number after the last and sorts behind every
@@ -351,24 +334,29 @@ def _experts_on_pairs_here(tokens, p, cfg, routes, gates, here):
         token = order // k  # of each row, as the rows lie
         gate = gates.reshape(n * k)[order]
 
-    def step(i, rows_out):
+    def step(i, staged):
         lo = i * block
         with jax.named_scope("moe:dispatch"):
             rows = tokens[jax.lax.dynamic_slice(token, (lo,), (block,))]
+            if on_tpu:  # the experts come after the loop
+                return jax.lax.dynamic_update_index_in_dim(
+                    staged, rows.astype(dt), i, 0
+                )
             # Each expert's rows that lie in this block.
             sizes = (
                 jnp.clip(ends - lo, 0, block)
                 - jnp.clip(ends - load - lo, 0, block)
             )
         with jax.named_scope("moe:experts"):
-            grouped = lambda a, w: _grouped_matmul(a, w.astype(dt), sizes)  # noqa: E731
+            grouped = lambda a, w: jax.lax.ragged_dot(  # noqa: E731
+                a, w.astype(dt), sizes
+            )
             out = grouped(
                 _expert_act(cfg, rows, p.get("w_gate"), p["w_up"], grouped),
                 p["w_down"],
             )
         with jax.named_scope("moe:combine"):
-            # Kept as the grouped matmul left them, in place.
-            return jax.lax.dynamic_update_index_in_dim(rows_out, out, i, 0)
+            return jax.lax.dynamic_update_index_in_dim(staged, out, i, 0)
 
     # (The loop's own time goes under the experts' scope; a step's parts
     # are named inside it.) The buffer starts as it is found (on a TPU
@@ -379,8 +367,21 @@ def _experts_on_pairs_here(tokens, p, cfg, routes, gates, here):
             0, blocks, step,
             jax.lax.empty((token.shape[0] // block, block, d), dt),
         )
+        if on_tpu:
+            # The tile follows the rows an expert gets if the router
+            # spreads the pairs evenly.
+            mean = n * k // cfg.num_experts
+            gated = cfg.expert_kind == "swiglu"
+            hidden = grouped_rows(
+                rows_out.reshape(-1, d),
+                [p[w].astype(dt) for w in (["w_gate"] * gated + ["w_up"])],
+                load, cfg.expert_kind, mean,
+            )
+            rows_out = grouped_rows(
+                hidden, [p["w_down"].astype(dt)], load, None, mean
+            ).reshape(rows_out.shape)
     with jax.named_scope("moe:combine"):
-        if chip.platform() == "tpu":
+        if on_tpu:
             out = combine_rows(rows_out, token, gate, m, n)
         else:
             # Rows at and past `m` go to no token, whatever they hold.

@@ -126,7 +126,9 @@ class Qwen3NextConfig(NemotronHConfig):
     # blocks of it at 2,048 rows; since PR 52 the sum is one kernel call,
     # `ops/pallas/expert_combine.py`, and 2,048 rows read 6.74 -> 5.12 ms
     # on a layer of these widths with random routes: my chip run, PR 52.
-    # The shorter calls were not timed again; they can only cross lower.)
+    # The shorter calls were not timed again; they can only cross lower.
+    # Since PR 56 the sorted form's matmuls are `ops/pallas/grouped_rows.py`
+    # on a TPU, whose tile divides nothing: 320 pair rows run.)
     dense_expert_rows: int = 256
     max_seq: int = 262144
 

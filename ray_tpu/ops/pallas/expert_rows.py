@@ -38,8 +38,8 @@ experts:
 
 The stacks are read where they lie, row-major as every Mosaic operand:
 ``[held, d, f]`` and ``[held, f, d]`` with ``f`` a multiple of 128, the
-layout ``jax.lax.ragged_dot`` takes them in too (the sorted form of
-larger calls), so neither form copies a stack
+layout ``ops/pallas/grouped_rows.py`` takes them in too (the sorted
+form of larger calls), so neither form copies a stack
 (tests/test_tpu_aot_compile.py).
 
 **No backward pass.** Nothing differentiates the every-row form: it

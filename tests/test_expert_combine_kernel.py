@@ -17,7 +17,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import moe
-from ray_tpu.ops.pallas import expert_combine
+from ray_tpu.ops.pallas import expert_combine, grouped_rows
 from test_moe import _BOUND_CASES, _bound_layer
 
 N = 48  # tokens
@@ -37,13 +37,18 @@ def small_blocks(monkeypatch):
 
 @pytest.fixture
 def as_on_a_tpu(monkeypatch):
-    """`moe_ffn` takes the kernel's branch, with the kernel interpreted."""
+    """`moe_ffn` takes the kernels' branch, with this kernel and the
+    grouped matmuls' (tests/test_grouped_rows_kernel.py) interpreted."""
 
     def switch():
         monkeypatch.setattr(moe.chip, "platform", lambda: "tpu")
         monkeypatch.setattr(
             moe, "combine_rows",
             functools.partial(expert_combine.combine_rows, interpret=True),
+        )
+        monkeypatch.setattr(
+            moe, "grouped_rows",
+            functools.partial(grouped_rows.grouped_rows, interpret=True),
         )
 
     return switch

@@ -258,6 +258,58 @@ def test_expert_combine_kernel_compiles_for_v5e_at_served_widths(v5e, case):
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20
 
 
+@pytest.mark.parametrize("call", ["up_projections", "down_projection"])
+@pytest.mark.parametrize(
+    "case",
+    {
+        # A 2,048-token chunk's pair rows, model width, expert width,
+        # experts held and of the router: the four served families.
+        "granite": (20480, 4096, 768, 36, 72),
+        "qwen3next": (20480, 2048, 512, 256, 512),
+        "laguna": (20480, 3072, 1024, 128, 256),
+        "pangu": (16384, 7680, 2048, 16, 256),
+    }.items(),
+    ids=lambda case: case[0],
+)
+def test_grouped_rows_kernel_compiles_for_v5e_at_served_widths(
+    v5e, case, call
+):
+    """The copies of a group's matrices out of the stacks in HBM, the
+    row tiles at a run-time start (64, 48, 64 and 64 rows by
+    `_tile_rows`) and the column passes `_column_tile` sizes (openPangu's
+    up projections in four, its down projection in two, the others
+    whole) lower for the chip inside the VMEM the call asks for; the
+    stacks are read where they lie and nothing is made beside the
+    arguments."""
+    from ray_tpu.ops.pallas import grouped_rows
+
+    name, (total, d, f, held, experts) = case
+    mean = total // experts
+    assert grouped_rows._tile_rows(mean, 16, 512) == {
+        "granite": 64, "qwen3next": 48, "laguna": 64, "pangu": 64}[name]
+    up = call == "up_projections"
+    k, n = (d, f) if up else (f, d)
+    assert n // grouped_rows._column_tile(k, n, 1 + up, 2) == (
+        (4 if up else 2) if name == "pangu" else 1)
+
+    def on_chip(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    def experts_on(rows, sizes, *stacks):
+        return grouped_rows.grouped_rows(
+            rows, stacks, sizes, "swiglu" if up else None, mean
+        )
+
+    compiled = jax.jit(experts_on).lower(
+        on_chip((total, k)), on_chip((held,), jnp.int32),
+        *[on_chip((held, k, n))] * (1 + up),
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert _copies_of(text, (held, k, n)) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
 @pytest.mark.parametrize(
     "case",
     {
@@ -330,7 +382,21 @@ def _kernel_calls_under(text: str, scope: str) -> list[str]:
 def _expert_kernel_calls(text: str) -> list[str]:
     """A compiled program's calls of the touched-experts kernel: the
     experts' operation of the every-row form."""
-    return _kernel_calls_under(text, "moe:experts")
+    return [
+        line for line in _kernel_calls_under(text, "moe:experts")
+        if "jit(_experts_on_rows)" in line
+    ]
+
+
+def _grouped_kernel_calls(text: str) -> list[str]:
+    """A compiled program's calls of ops/pallas/grouped_rows.py: the
+    experts' operation of the sorted form over the pairs computed here,
+    two a layer (the activation of the up projections, the down
+    projection), under the scope the benchmark's reducers read."""
+    return [
+        line for line in _kernel_calls_under(text, "moe:experts")
+        if "jit(_grouped_rows)" in line
+    ]
 
 
 # ------------------------------------------------- the serving programs
@@ -503,12 +569,18 @@ def _expert_arrays_of(text: str, shape: tuple) -> list[str]:
     return sorted(found)
 
 
-def _grouped_matmul_tiles(text: str) -> set[str]:
-    """The (rows, contraction, columns) tiles the compiler's grouped
-    matmuls were handed in a compiled program: `moe._GROUP_TILE` reaches
-    the kernel as a frontend attribute, and a compiler that dropped it
-    would leave its own 512-row tiles, silently."""
-    return set(re.findall(r'ragged_dot_tiling="([\d,]+)"', text))
+def _expert_makers_of(text: str, shape: tuple) -> set[str]:
+    """The opcodes of the expert layer's instructions whose result is an
+    array of ``shape``: every pair's rows at once are the staged
+    buffer seen flat (a bitcast) and the grouped-matmul kernel's
+    results, never a gather or a fusion over all of them."""
+    dims = ",".join(str(n) for n in shape)
+    found = set()
+    for line in text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m and "moe:" in line and re.search(rf"\b\w+\[{dims}\]", m.group(2)):
+            found.add(m.group(3))
+    return found
 
 
 _INSTRUCTION = re.compile(
@@ -617,11 +689,14 @@ def test_hybrid_program_moves_no_pages_state_or_expert_stack(
     compiled = programs[program]
     text = compiled.as_text()
     assert _hybrid_moves(text, shapes) == []
-    # The grouped matmul above `dense_expert_rows`, and up to it the
-    # kernel that reads the touched experts, once an expert block; the
-    # paged-attention and cell-write kernels in the decode program.
+    # The grouped-matmul kernel above `dense_expert_rows`, twice an
+    # expert block, and up to it the kernel that reads the touched
+    # experts, once an expert block; the paged-attention and cell-write
+    # kernels in the decode program.
     sorted_form = program == "prefill_chunk_1024_of_2048"
-    assert ("ragged-dot" in text) == sorted_form
+    assert "ragged-dot" not in text
+    # (Two expert blocks; relu^2 experts: one matrix in the first call.)
+    assert len(_grouped_kernel_calls(text)) == (4 if sorted_form else 0)
     # The prefill kernel where the table holds more than 1,024 keys,
     # dense scores up to it: the benchmark's Nemotron programs, all at
     # or under it, attend as they did before the kernel came.
@@ -697,23 +772,29 @@ def test_latent_program_moves_no_pool_or_expert_stack_and_fits(
     compiled = programs[program]
     text = compiled.as_text()
     assert _hybrid_moves(text, shapes) == []
-    # The grouped matmul of a 2,048-row chunk (above `dense_expert_rows`);
-    # in the decode program the latent kernel and, in its one expert
-    # layer, the kernel that reads the touched experts.
-    assert ("ragged-dot" in text) == (program != "decode")
+    # The grouped-matmul kernel over a 2,048-row chunk (above
+    # `dense_expert_rows`), twice in the one expert layer; in the decode
+    # program the latent kernel and, in its expert layer, the kernel
+    # that reads the touched experts. The compiler's grouped matmul in
+    # neither.
+    assert "ragged-dot" not in text
+    assert len(_grouped_kernel_calls(text)) == 2 * (program != "decode")
     assert len(_expert_kernel_calls(text)) == (program == "decode")
     if program == "decode":
         assert "latent_paged_attention" in text
     else:
-        # The sorted form works on the pairs computed here, a block at a
+        # The sorted form gathers the pairs computed here, a block at a
         # time: nothing the size of all 16,384 pairs' rows is gathered,
-        # and the sum back to tokens is not made over every pair.
+        # the kernel's result is read by the combine where it was
+        # written, and the sum back to tokens is not made over every pair.
         k = conf["num_experts_per_tok"]
-        assert _expert_arrays_of(text, (2048 * k, d)) == []
+        assert _expert_makers_of(text, (2048 * k, d)) <= {
+            "bitcast", "custom-call"}
+        assert _copies_of(text, (2048 * k, d)) == []
+        assert _copies_of(text, (2048 * k // 1024, 1024, d)) == []
         assert _expert_arrays_of(text, (2048, k, d)) == []
         # (The helper sees the expert layer's arrays: its blocks' rows.)
         assert _expert_arrays_of(text, (1024, d)) != []
-        assert _grouped_matmul_tiles(text) == {"256,1024,512"}
     memory = compiled.memory_analysis()
     # Under the two layers' pool: no copy of it is among the temporaries.
     assert memory.temp_size_in_bytes < 2 * layer_pages * 2
@@ -795,20 +876,23 @@ def test_granite_program_moves_no_pages_state_or_stack_and_fits(
         assert _state_passes(text, "ssm:update", state) == []
         assert _copies_of(text, (1, eng["max_batch"], 128, 64, 128)) == []
     else:
-        assert "prefill_attention" in text and "ragged-dot" in text
+        assert "prefill_attention" in text and "ragged-dot" not in text
         assert _expert_kernel_calls(text) == []
+        assert len(_grouped_kernel_calls(text)) == 4  # two a layer's FFN
         # No array with the chunk's queries against the table's keys,
         # whatever the leading dimensions and the dtype.
         assert not re.search(rf"\[[\d,]*{chunk},{table}\]", text)
         assert memory.temp_size_in_bytes < 32 * chunk * table * 4 // 2
-        # The sorted form works on the pairs computed here, a block at a
+        # The sorted form gathers the pairs computed here, a block at a
         # time: nothing the size of all 20,480 pairs' rows is gathered,
-        # and the sum back to tokens is not made over every pair.
+        # the kernel's result is read by the combine where it was
+        # written, and the sum back to tokens is not made over every pair.
         k = conf["num_experts_per_tok"]
-        assert _expert_arrays_of(text, (chunk * k, d)) == []
+        assert _expert_makers_of(text, (chunk * k, d)) <= {
+            "bitcast", "custom-call"}
+        assert _copies_of(text, (chunk * k, d)) == []
+        assert _copies_of(text, (chunk * k // 1024, 1024, d)) == []
         assert _expert_arrays_of(text, (chunk, k, d)) == []
-        # (768 columns an expert: 512 does not divide them.)
-        assert _grouped_matmul_tiles(text) == {"256,1024,256", "256,1024,512"}
     pool = 2 * layer_pages * 2  # K and V of the one attention layer
     arguments = (
         family.held_parameters(conf) * 2 + pool
@@ -935,12 +1019,15 @@ def test_qwen3next_program_moves_no_pages_state_or_stack_and_fits(
         assert _state_passes(text, "gdn:update", state) == []
         assert _copies_of(text, (1, eng["max_batch"], 32, 128, 128)) == []
     else:
-        assert "prefill_attention" in text and "ragged-dot" in text
+        assert "prefill_attention" in text and "ragged-dot" not in text
         assert _expert_kernel_calls(text) == []
+        assert len(_grouped_kernel_calls(text)) == 4  # two a layer's FFN
         assert not re.search(rf"\[[\d,]*{chunk},{table}\]", text)
         k = conf["num_experts_per_tok"]
-        assert _expert_arrays_of(text, (chunk * k, d)) == []
-        assert _grouped_matmul_tiles(text) == {"256,1024,512"}
+        assert _expert_makers_of(text, (chunk * k, d)) <= {
+            "bitcast", "custom-call"}
+        assert _copies_of(text, (chunk * k, d)) == []
+        assert _copies_of(text, (chunk * k // 1024, 1024, d)) == []
         assert memory.temp_size_in_bytes < 2**30
     arguments = conf["fit"]["argument_bytes"]
     counted = (
@@ -1087,12 +1174,16 @@ def test_laguna_program_moves_no_pages_or_stack_and_fits(
     else:
         table = int(program.rsplit("_", 1)[1])
         assert "prefill_attention" in text and "window_attention" in text
-        assert "ragged-dot" in text and _expert_kernel_calls(text) == []
+        assert "ragged-dot" not in text and _expert_kernel_calls(text) == []
+        assert len(_grouped_kernel_calls(text)) == 2  # the one sparse FFN
         # No score over the table or over the band in HBM.
         assert not re.search(rf"\[[\d,]*{chunk},{table}\]", text)
         assert not re.search(rf"\[[\d,]*{chunk},{WINDOW + chunk}\]", text)
         k = conf["num_experts_per_tok"]
-        assert _expert_arrays_of(text, (chunk * k, d)) == []
+        assert _expert_makers_of(text, (chunk * k, d)) <= {
+            "bitcast", "custom-call"}
+        assert _copies_of(text, (chunk * k, d)) == []
+        assert _copies_of(text, (chunk * k // 1024, 1024, d)) == []
         assert memory.temp_size_in_bytes < 2**30
         # The window layer's operations and the ring's write are the
         # narrowest table's, array for array.

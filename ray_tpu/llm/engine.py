@@ -44,7 +44,12 @@ caller's, untouched.
 Host phases are `jax.profiler.TraceAnnotation` spans (`engine:step` and
 its children, `engine:add_request`, `engine:abort_request`): with a
 profiler session open they land in the trace's host plane, on the
-device events' clock; with none each is one flag test. README "Serve
+device events' clock; with none each is one flag test. Every call that
+puts a program on the device is a span of its own, `launch:*`, around
+the call and nothing else: programs run in the order they were launched,
+so the k-th launch of a trace is its k-th program. The phases of
+`step()` also book their own seconds (a phase's duration less its
+children's) into `stats()`, profiler or none (`_Phase`). README "Serve
 observability" lists them.
 """
 
@@ -56,7 +61,7 @@ import threading
 import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
-from functools import partial
+from functools import cache, partial
 from typing import Any
 
 import jax
@@ -132,6 +137,63 @@ def _key_block(key):
 
     key, subs = jax.lax.scan(link, key, length=_KEY_BLOCK)
     return key, [subs[i] for i in range(_KEY_BLOCK)]
+
+
+@jax.jit
+def _logits_row(logits, idx):
+    """Row `idx` of a prefill's `[1, T, V]` logits as ONE program: the
+    eager `logits[0, idx]` is two (a slice, a squeeze), and a launch is
+    one program."""
+    return logits[0, idx]
+
+
+# The phases of `step()` whose own seconds `stats()` carries as
+# `host_s_sum.<phase>`: the `engine:*` children of a step by their own
+# name, every `launch:*` under `launch`, every `readback:*` under
+# `readback`, and the step's remainder under `step`.
+_HOST_PHASES = (
+    "step", "admit", "prefill_chunk", "first_token", "grow_tables",
+    "decode_dispatch", "decode_sync", "emit", "launch", "readback",
+)
+
+
+@cache
+def _host_key(name: str) -> str:
+    """A span's name -> the `stats()` key of its phase."""
+    prefix, _, rest = name.partition(":")
+    return f"host_s_sum.{rest if prefix == 'engine' else prefix}"
+
+
+class _Phase:
+    """One host phase of `step()`: its `TraceAnnotation`, entered and
+    left with it, and its OWN seconds (its duration less that of the
+    phases opened inside it) added to `stats()["host_s_sum.<phase>"]`,
+    so that the phases of a step add up to the step with the profiler
+    off. Only `step()`'s thread opens one; `with` gives the annotation,
+    for `set_metadata`."""
+
+    __slots__ = ("open", "stats", "key", "span", "began", "inner")
+
+    def __init__(self, engine: "LLMEngine", name: str, attrs: dict):
+        self.open = engine._open_phases
+        self.stats = engine._stats
+        self.key = _host_key(name)
+        self.span = TraceAnnotation(name, **attrs)
+        self.inner = 0.0
+
+    def __enter__(self):
+        self.span.__enter__()
+        self.open.append(self)
+        self.began = time.perf_counter()
+        return self.span
+
+    def __exit__(self, *exc):
+        spent = time.perf_counter() - self.began
+        self.open.pop()
+        if self.open:
+            self.open[-1].inner += spent
+        self.stats[self.key] += spent - self.inner
+        return self.span.__exit__(*exc)
 
 
 def _bucket(n: int, lo: int = 16) -> int:
@@ -279,6 +341,12 @@ class LLMEngine:
         # this step() read one back before its own dispatch, if it did.
         self._in_flight: _DecodeStep | None = None
         self._drained_for: str | None = None
+        # The `_Phase`s `step()` has open, innermost last; when the last
+        # `step()` returned (`between_s_sum`); whether this `step()` has
+        # launched a prefill program (`decode_steps_alone`).
+        self._open_phases: list[_Phase] = []
+        self._left_step_at: float | None = None
+        self._launched_prefill = False
         self._temps = np.zeros((max_batch,), np.float32)
         self._queue: list[_Request] = []
         self._active: dict[int, _Request] = {}  # slot → request
@@ -339,6 +407,24 @@ class LLMEngine:
                 "admit": 0, "host_sampled": 0, "speculate": 0, "preempt": 0,
             },
             "overrun_slot_steps": 0,
+            # Who sets the pace (PERF.md's table again): of the decode
+            # steps dispatched with a step in flight by a `step()` that
+            # launched no prefill program first (`alone`), those that
+            # found that step already complete (`starved`: the device
+            # had finished t before the host launched t+1).
+            "decode_steps_alone": 0,
+            "decode_steps_starved": 0,
+            # Seconds in `step()`, on its thread's CPU, and waiting for
+            # `_lock` at its entry; each phase's own seconds
+            # (`_HOST_PHASES`: with the lock wait they add up to
+            # `step_s_sum`); and the seconds between one step's return
+            # and the next one's entry where the later step found work
+            # in hand (the executor hop and the pump's delivery).
+            "step_s_sum": 0.0,
+            "step_cpu_s_sum": 0.0,
+            "step_lock_wait_s_sum": 0.0,
+            **{f"host_s_sum.{phase}": 0.0 for phase in _HOST_PHASES},
+            "between_s_sum": 0.0,
             "slot_steps": 0,
             "attn_pages_live": 0,
             "attn_pages_table": 0,
@@ -380,6 +466,11 @@ class LLMEngine:
                 rid=request_id, lock_wait_ms=round(waited * 1e3, 3)
             )
             yield
+
+    def _phase(self, name: str, **attrs) -> _Phase:
+        """A host phase of `step()`, `engine:*`, `launch:*` or
+        `readback:*`: the span, and its own seconds in `stats()`."""
+        return _Phase(self, name, attrs)
 
     def add_request(
         self,
@@ -546,19 +637,21 @@ class LLMEngine:
         the row to sample from (chunked prefill: the last token's index
         LOCAL to the final chunk); a model whose prefill returns the
         last real token's logits alone has them in row 0."""
-        with TraceAnnotation("engine:first_token", rid=req.request_id):
+        with self._phase("engine:first_token", rid=req.request_id):
             if self.serving.logits_last_only:
                 logit_idx = 0
+            if logit_idx is None:
+                logit_idx = ctx_len - 1
             # The first token is sampled on the host: the decode step in
             # flight, which the device ran before this prefill, is read
             # back first (its tokens are due), and the next step is
             # dispatched from the host's tokens. Under `_lock`: this
             # request is in no list an abort would find it in.
             self._drain(finished, "admit", unlock=False)
+            with self._phase("launch:first_token_row", rid=req.request_id):
+                row = _logits_row(logits, np.int32(logit_idx))
             # The host waits here for the prefill program.
-            last = np.asarray(
-                logits[0, ctx_len - 1 if logit_idx is None else logit_idx]
-            )
+            last = np.asarray(row)
             req.slot = slot
             req.position = ctx_len
             if req.first_token_ts == 0.0:
@@ -590,7 +683,7 @@ class LLMEngine:
         req = self._queue[0]
         # An attempt the pool turns away is a span too (the prefix
         # lookup is host time), without the attributes set below.
-        with TraceAnnotation(
+        with self._phase(
             "engine:admit", rid=req.request_id, prompt_len=len(req.prompt)
         ) as span:
             # Full context: the prompt plus anything generated before a
@@ -655,19 +748,22 @@ class LLMEngine:
                 return True
             tokens = np.zeros((1, pad), np.int32)
             tokens[0, : len(context)] = context
+            table = np.asarray(pages, np.int32)
             # Prefill rewrites shared pages with byte-identical values
             # (K/V at position i depend only on tokens <= i) —
             # idempotent, so no write mask is needed.
             # Host arrays go in as they are: the call transfers them.
-            logits, self.cache, *record = self._prefill_paged(
-                self.params,
-                tokens,
-                self.cache,
-                np.asarray(pages, np.int32),
-                n_write_pages=need_pages,
-                slot=slot,
-                length=len(context),
-            )
+            self._launched_prefill = True
+            with self._phase("launch:prefill", rid=req.request_id):
+                logits, self.cache, *record = self._prefill_paged(
+                    self.params,
+                    tokens,
+                    self.cache,
+                    table,
+                    n_write_pages=need_pages,
+                    slot=slot,
+                    length=len(context),
+                )
             self._account("prefill", logits, record, len(context))
             self._post_prefill(req, slot, logits, len(context), finished)
             return True
@@ -687,24 +783,26 @@ class LLMEngine:
             # own length instead (one compiled shape), where the
             # bucket's pages reach that far.
             end = min(end, st["ctx_pad"])
-        with TraceAnnotation(
-            "engine:prefill_chunk", rid=st["req"].request_id,
-            start=start, tokens=end - start,
+        rid = st["req"].request_id
+        with self._phase(
+            "engine:prefill_chunk", rid=rid, start=start, tokens=end - start
         ):
             tokens = np.zeros((1, end - start), np.int32)
             valid = context[start: min(end, len(context))]
             tokens[0, : len(valid)] = valid
-            logits, self.cache, *record = self._prefill_chunk_fn(
-                self.params,
-                tokens,
-                self.cache,
-                st["pages"],
-                np.int32(start),
-                n_write_pages=st["need_pages"],
-                chunk_pages=(end - start) // P,
-                slot=st["slot"],
-                length=len(context),
-            )
+            self._launched_prefill = True
+            with self._phase("launch:prefill_chunk", rid=rid):
+                logits, self.cache, *record = self._prefill_chunk_fn(
+                    self.params,
+                    tokens,
+                    self.cache,
+                    st["pages"],
+                    np.int32(start),
+                    n_write_pages=st["need_pages"],
+                    chunk_pages=(end - start) // P,
+                    slot=st["slot"],
+                    length=len(context),
+                )
             self._account("prefill_chunk", logits, record, len(valid))
             st["next_start"] = end
             self._stats["prefill_chunks"] += 1
@@ -721,7 +819,8 @@ class LLMEngine:
         """After every program: hand its logits to `on_logits`, and keep
         the expert blocks' counters of a program that records them
         (`tokens` live tokens went through it). The counters stay on the
-        device until `stats()` asks: no program is waited for here."""
+        device until `stats()` asks or 512 programs' have gathered: only
+        then is a program waited for here (`readback:moe_counts`)."""
         record = record[0] if record else None
         if self.on_logits is not None:
             self.on_logits(phase, logits, record)
@@ -729,7 +828,10 @@ class LLMEngine:
             self._stats["moe_pairs_routed"] += tokens * self._pairs_per_token
             self._moe_counts.append((phase, record["counts"]))
             if len(self._moe_counts) >= 512:
-                self._fold_moe_counts()
+                with self._phase(
+                    "readback:moe_counts", programs=len(self._moe_counts)
+                ):
+                    self._fold_moe_counts()
 
     def _fold_moe_counts(self) -> None:
         counts, self._moe_counts = self._moe_counts, []
@@ -745,25 +847,49 @@ class LLMEngine:
         """Admit + one decode step dispatched + the step before it read
         back. Returns the request dicts that read-back finished."""
         finished: list[dict] = []
+        stats = self._stats
+        entered, cpu_at = time.perf_counter(), time.thread_time()
+        # What lay between the step before and this one was the pump's
+        # (the executor hop, the delivery) if this one finds work in
+        # hand; with none the engine had drained, and nobody's.
+        in_hand = len(self._active), int(self._prefilling is not None)
+        if self._left_step_at is not None and any(in_hand):
+            stats["between_s_sum"] += entered - self._left_step_at
         # The span begins before the wait for `_lock`; its attributes are
-        # the engine's state as the step found it.
-        with TraceAnnotation(
+        # the engine's state as the step found it, and at its end how
+        # long that wait was and how long its thread ran: a step of
+        # seconds with neither a wait nor CPU time to show stood still.
+        with self._phase(
             "engine:step",
-            step=self._stats["steps"],
-            active=len(self._active),
+            step=stats["steps"],
+            active=in_hand[0],
             queued=len(self._queue),
-            prefilling=int(self._prefilling is not None),
+            prefilling=in_hand[1],
             max_batch=self.max_batch,
-        ), self._lock:
-            self._stats["steps"] += 1
-            self._drained_for = None
-            if self._prefilling is not None:
-                # Continue the in-flight chunked prefill: one chunk per
-                # step bounds the stall it adds to this step's decodes.
-                self._prefill_step(finished)
-            self._admit(finished)
-            if self._active or self._in_flight is not None:
-                self._step_paged(finished)
+        ) as span:
+            with self._lock:
+                waited = time.perf_counter() - entered
+                stats["steps"] += 1
+                self._drained_for = None
+                self._launched_prefill = False
+                if self._prefilling is not None:
+                    # Continue the in-flight chunked prefill: one chunk
+                    # per step bounds the stall it adds to this step's
+                    # decodes.
+                    self._prefill_step(finished)
+                self._admit(finished)
+                if self._active or self._in_flight is not None:
+                    self._step_paged(finished)
+            cpu = time.thread_time() - cpu_at
+            span.set_metadata(
+                lock_wait_ms=round(waited * 1e3, 3),
+                cpu_ms=round(cpu * 1e3, 3),
+            )
+        self._left_step_at = time.perf_counter()
+        stats["step_s_sum"] += self._left_step_at - entered
+        stats["step_cpu_s_sum"] += cpu
+        stats["step_lock_wait_s_sum"] += waited
+        stats["host_s_sum.step"] -= waited
         return finished
 
     def _record_token(self, req, tok: int, finished: list[dict]) -> None:
@@ -819,7 +945,8 @@ class LLMEngine:
         """The decode step's link of the chain of keys. A block of links
         is one program, dispatched behind what the device is running."""
         if not self._keys:
-            self._step_key, self._keys = _key_block(self._step_key)
+            with self._phase("launch:key_block"):
+                self._step_key, self._keys = _key_block(self._step_key)
             self._keys.reverse()
         return self._keys.pop()
 
@@ -876,7 +1003,7 @@ class LLMEngine:
                         self._preempt(victims[-1] if victims else req)
             return True
 
-        with TraceAnnotation("engine:grow_tables") as span:
+        with self._phase("engine:grow_tables") as span:
             preempted = self._stats["preemptions"]
             while True:
                 # A slot with a token in flight (K = 1 then, and every
@@ -932,24 +1059,35 @@ class LLMEngine:
         # state of the others (free, ended, or mid-prefill) as it is.
         mask = np.zeros((self.max_batch,), bool)
         mask[list(decoding)] = True
-        with TraceAnnotation("engine:decode_dispatch"):
-            (
-                sampled, logits, self.cache, accept, rej, *record
-            ) = self._decode_paged(
-                self.params,
-                # The step in flight's ids as they lie on the device:
-                # the same [B, 1] int32 the host's matrix is.
-                self._in_flight.sampled if ahead else toks,
-                self.cache,
-                tables,
-                # A copy: the mirror changes while the program, which
-                # may read the host's buffer in place, is in flight.
-                self._positions.copy(),
-                self._temps,
-                self._next_key(),
-                stochastic=stochastic,
-                active=mask,
-            )
+        with self._phase("engine:decode_dispatch"):
+            # The step in flight's ids as they lie on the device: the
+            # same [B, 1] int32 the host's matrix is.
+            tokens = self._in_flight.sampled if ahead else toks
+            # A copy: the mirror changes while the program, which may
+            # read the host's buffer in place, is in flight.
+            positions = self._positions.copy()
+            key = self._next_key()
+            # Whether the device had already finished the step in flight
+            # and stood idle for this launch: counted where no prefill
+            # program of this step() was keeping it busy meanwhile.
+            starved = int(bool(ahead) and tokens.is_ready())
+            if not self._launched_prefill:
+                self._stats["decode_steps_alone"] += ahead
+                self._stats["decode_steps_starved"] += starved
+            with self._phase("launch:decode", ahead=ahead, starved=starved):
+                (
+                    sampled, logits, self.cache, accept, rej, *record
+                ) = self._decode_paged(
+                    self.params,
+                    tokens,
+                    self.cache,
+                    tables,
+                    positions,
+                    self._temps,
+                    key,
+                    stochastic=stochastic,
+                    active=mask,
+                )
             self._account("decode", logits, record, len(decoding))
             sampled.copy_to_host_async()
         before, self._in_flight = self._in_flight, _DecodeStep(
@@ -1002,7 +1140,7 @@ class LLMEngine:
         """Wait for `step`, emit its tokens and run the stop checks, by
         the slot -> request map of its dispatch: a request that ended or
         was aborted since is skipped."""
-        with TraceAnnotation("engine:decode_sync"):
+        with self._phase("engine:decode_sync"):
             # add_request and abort_request, on the replica's event
             # loop, do not wait out a device step.
             if unlock:
@@ -1012,7 +1150,7 @@ class LLMEngine:
             finally:
                 if unlock:
                     self._lock.acquire()
-        with TraceAnnotation("engine:emit") as span:
+        with self._phase("engine:emit") as span:
             n_tokens, n_done = self._stats["tokens_generated"], len(finished)
             for slot, req in step.slots.items():
                 if req.done:
@@ -1143,7 +1281,12 @@ class LLMEngine:
         `init_s`; decode steps dispatched with the step before them
         still in flight, as a count and as `decode_in_flight_pct`, the
         reads that had to come first by cause, `pipeline_drains`, and
-        `overrun_slot_steps`), `param_bytes` (the held weights, matmul
+        `overrun_slot_steps`; who sets the pace: `decode_starved_pct`,
+        the seconds in `step()` and of its thread's CPU, each host
+        phase's own seconds `host_s_sum.<phase>`, `between_s_sum`: all
+        sums over the engine's life, a first call's compile among a
+        `launch`'s, so take rates from two polls), `param_bytes` (the
+        held weights, matmul
         leaves in `cfg.dtype`), which attention and which K/V cell write the
         decode program was compiled with (`paged_attn_kernel`,
         `kv_write_kernel`), for a model with expert layers whether their
@@ -1162,6 +1305,12 @@ class LLMEngine:
             out["decode_in_flight_pct"] = (
                 100.0 * out["decode_steps_in_flight"] / out["decode_steps"]
                 if out["decode_steps"] else 0.0
+            )
+            # How often such a step, alone in its step(), found the device
+            # done with the step before it: the host sets the pace.
+            out["decode_starved_pct"] = (
+                100.0 * out["decode_steps_starved"] / out["decode_steps_alone"]
+                if out["decode_steps_alone"] else 0.0
             )
             # How tight the sorted expert form's row bound is: beside
             # moe_pairs_here / moe_pairs_routed, the share it must do.

@@ -29,6 +29,14 @@ SPAN_NAMES = [
     "engine:decode_sync", "engine:emit", "engine:add_request",
     "engine:abort_request", "pump:deliver",
 ]
+# Each call that puts a program on the device, and the span it lies in.
+LAUNCH_PARENTS = {
+    "launch:decode": "engine:decode_dispatch",
+    "launch:prefill": "engine:admit",
+    "launch:prefill_chunk": "engine:prefill_chunk",
+    "launch:key_block": "engine:decode_dispatch",
+    "launch:first_token_row": "engine:first_token",
+}
 
 
 @pytest.fixture(autouse=True)
@@ -52,13 +60,17 @@ def timeout():
 
 @pytest.fixture(scope="module")
 def traced(tmp_path_factory):
-    """(spans, stats): the host spans of one profiler session around two
-    concurrent streams through `LLMServer`, and the engine's counters
-    afterwards. The same traffic runs once before the session, so that
-    nothing compiles inside it."""
+    """(spans, stats, launches, calls): the host spans of one profiler
+    session around two concurrent streams through `LLMServer`, the
+    engine's counters afterwards, the session's `launch:*` spans, and
+    how often each program was called inside it (a tap on each). The
+    same traffic runs once before the session, so that nothing compiles
+    inside it."""
     import jax
 
     from benchmarks import hostspans, traceread
+    from benchmarks.reducers import idle_by_enqueue
+    from ray_tpu.llm import engine as engine_mod
     from ray_tpu.llm.serve_integration import LLMServer
 
     server = LLMServer("tiny", {
@@ -74,18 +86,41 @@ def traced(tmp_path_factory):
         await asyncio.sleep(0)  # the long request is ahead in the queue
         return await asyncio.gather(long, one(SHORT))
 
+    calls = dict.fromkeys(LAUNCH_PARENTS, 0)
+
+    def tap(holder, attr, name):
+        fn = getattr(holder, attr)
+
+        def tapped(*args, **kw):
+            calls[name] += 1
+            return fn(*args, **kw)
+
+        setattr(holder, attr, tapped)
+        return fn
+
     async def main(trace_dir):
         await both()
+        eng = server.engine
+        eng._keys.clear()  # the session's first decode step makes a block
+        tap(eng, "_decode_paged", "launch:decode")
+        tap(eng, "_prefill_paged", "launch:prefill")
+        tap(eng, "_prefill_chunk_fn", "launch:prefill_chunk")
+        key_block = tap(engine_mod, "_key_block", "launch:key_block")
+        logits_row = tap(engine_mod, "_logits_row", "launch:first_token_row")
         jax.profiler.start_trace(trace_dir)
         try:
             await both()
         finally:
             jax.profiler.stop_trace()
+            engine_mod._key_block = key_block
+            engine_mod._logits_row = logits_row
 
     trace_dir = str(tmp_path_factory.mktemp("trace"))
     asyncio.run(asyncio.wait_for(main(trace_dir), TIMEOUT_S))
-    spans = hostspans.read_spans(traceread.find_trace_file(trace_dir))
-    return spans, server.engine.stats()
+    path = traceread.find_trace_file(trace_dir)
+    spans = hostspans.read_spans(path)
+    launches = idle_by_enqueue.read_launches(path)
+    return spans, server.engine.stats(), launches, calls
 
 
 def by_name(spans, name):
@@ -95,6 +130,42 @@ def by_name(spans, name):
 @pytest.mark.parametrize("name", SPAN_NAMES)
 def test_a_session_records_every_span(traced, name):
     assert by_name(traced[0], name), f"no {name} span in the trace"
+
+
+@pytest.mark.parametrize("name", sorted(LAUNCH_PARENTS))
+def test_a_launch_is_a_span_inside_its_parent(traced, name):
+    """Every call that puts a program on the device lies in one
+    `launch:*` span, as many spans as calls, each inside the phase that
+    makes the call and on its thread."""
+    spans, _, launches, calls = traced
+    mine = by_name(launches, name)
+    assert mine, f"no {name} span in the trace"
+    assert len(mine) == calls[name]
+    parents = by_name(spans, LAUNCH_PARENTS[name])
+    for launch in mine:
+        assert any(
+            p.thread == launch.thread
+            and p.start <= launch.start and launch.end <= p.end
+            for p in parents
+        ), launch
+    if name == "launch:decode":
+        assert {s.attrs["ahead"] for s in mine} == {0, 1}
+        assert {s.attrs["starved"] for s in mine} <= {0, 1}
+    if name in ("launch:prefill", "launch:prefill_chunk",
+                "launch:first_token_row"):
+        rids = {a.attrs["rid"] for a in by_name(spans, "engine:add_request")}
+        assert {s.attrs["rid"] for s in mine} <= rids
+
+
+def test_launches_are_the_programs_called(traced):
+    _, _, launches, calls = traced
+    assert {s.name for s in launches} == set(LAUNCH_PARENTS)
+    assert len(launches) == sum(calls.values())
+    # 3 chunks of the long prompt, the short one whole, a row each.
+    assert calls["launch:prefill_chunk"] == 3
+    assert calls["launch:prefill"] == 1
+    assert calls["launch:first_token_row"] == 2
+    assert calls["launch:key_block"] == 1
 
 
 def test_children_lie_inside_their_step(traced):
@@ -117,6 +188,12 @@ def test_step_attributes(traced):
     counts = [s.attrs["step"] for s in steps]
     assert counts == list(range(counts[0], counts[0] + len(steps)))
     assert {s.attrs["max_batch"] for s in steps} == {2}
+    # How long the step waited for `_lock` and how long its thread ran:
+    # on every step, neither more than the step.
+    for s in steps:
+        assert 0 <= s.attrs["lock_wait_ms"] <= s.dur * 1e3 + 1e-3
+        assert 0 <= s.attrs["cpu_ms"]
+    assert sum(s.attrs["cpu_ms"] for s in steps) > 0
     # The first step's thread starts while the event loop adds the short
     # request: under load it may find only the long one.
     assert steps[0].attrs["queued"] in (1, 2) and steps[0].attrs["active"] == 0
@@ -275,3 +352,125 @@ def test_submit_is_stamped_before_the_wait_for_the_lock():
     assert engine.stats()["queue_wait_s_sum"] == pytest.approx(
         timing["queue_s"]
     )
+
+
+def host_seconds(stats):
+    return {k: v for k, v in stats.items() if k.startswith("host_s_sum.")}
+
+
+def test_host_phases_add_up_to_the_step():
+    """`host_s_sum.*` are OWN seconds: with the step's wait for `_lock`
+    they are `step_s_sum`, after whole and chunked prefills, decode
+    steps and a preemption, with no profiler session."""
+    engine = LLMEngine(
+        "tiny", max_batch=2, page_size=16, num_pages=4, prefill_chunk=16
+    )
+    stats = run_to_the_end(
+        engine, [list(range(1, 21)), list(range(2, 16))], max_tokens=14
+    )
+    assert stats["preemptions"] >= 1 and stats["prefill_chunks"] >= 2
+    own = host_seconds(stats)
+    assert set(own) == {
+        f"host_s_sum.{phase}" for phase in (
+            "step", "admit", "prefill_chunk", "first_token", "grow_tables",
+            "decode_dispatch", "decode_sync", "emit", "launch", "readback",
+        )
+    }
+    assert all(v >= 0 for v in own.values()), own
+    for phase in ("admit", "prefill_chunk", "first_token", "grow_tables",
+                  "decode_dispatch", "decode_sync", "emit", "launch"):
+        assert own[f"host_s_sum.{phase}"] > 0, phase
+    assert sum(own.values()) + stats["step_lock_wait_s_sum"] == pytest.approx(
+        stats["step_s_sum"], rel=0.01
+    )
+    assert 0 < stats["step_cpu_s_sum"]
+    assert stats["step_lock_wait_s_sum"] < 0.01 * stats["step_s_sum"]
+    assert not engine._open_phases
+
+
+def test_a_fold_of_the_expert_counters_is_a_readback():
+    """`_fold_moe_counts` from `_account` (every 512 programs) lies in
+    `readback:moe_counts`, whose own seconds go to `host_s_sum.readback`;
+    from `stats()` it is nobody's phase."""
+    from ray_tpu.models.nemotron_h import NEMOTRON_H_PRESETS
+
+    engine = LLMEngine(
+        NEMOTRON_H_PRESETS["nemotron_h_tiny"], max_batch=2, page_size=16
+    )
+    engine.add_request(SHORT, SamplingParams(max_tokens=3))
+    engine.step()
+    assert engine._moe_counts
+    engine._moe_counts *= 512
+    engine.step()
+    assert len(engine._moe_counts) < 512
+    assert engine._stats["host_s_sum.readback"] > 0
+    booked = engine._stats["host_s_sum.readback"]
+    while engine.has_unfinished():
+        engine.step()
+    assert engine.stats()["host_s_sum.readback"] == booked
+
+
+@pytest.mark.parametrize("ready", [False, True], ids=["never", "always"])
+def test_starved_counts_the_steps_that_found_the_device_done(
+    monkeypatch, ready
+):
+    """`decode_steps_starved`: of the in-flight decode dispatches of
+    steps that launched no prefill first (`decode_steps_alone`), those
+    that found the step in flight complete. A step that launched a chunk
+    counts in neither, whatever `is_ready()` says."""
+    import jax.numpy as jnp
+
+    monkeypatch.setattr(
+        type(jnp.zeros(1)), "is_ready", lambda self: ready, raising=True
+    )
+    engine = LLMEngine("tiny", max_batch=2, page_size=16, prefill_chunk=16)
+    seen = {"launched": False, "alone": 0, "with_prefill": 0}
+
+    def tap(attr, prefill):
+        fn = getattr(engine, attr)
+
+        def tapped(*args, **kw):
+            if prefill:
+                seen["launched"] = True
+            elif engine._in_flight is not None:
+                seen["with_prefill" if seen["launched"] else "alone"] += 1
+            return fn(*args, **kw)
+
+        setattr(engine, attr, tapped)
+
+    tap("_prefill_paged", True)
+    tap("_prefill_chunk_fn", True)
+    tap("_decode_paged", False)
+    engine.add_request(SHORT, SamplingParams(max_tokens=24))
+    steps = 0
+    while engine.has_unfinished():
+        seen["launched"] = False
+        engine.step()
+        steps += 1
+        if steps == 4:  # in chunks, under the short request's decode
+            engine.add_request(LONG, SamplingParams(max_tokens=4))
+    stats = engine.stats()
+    assert seen["with_prefill"] >= 2 and seen["alone"] >= 8
+    assert stats["decode_steps_alone"] == seen["alone"]
+    assert stats["decode_steps_in_flight"] == (
+        seen["alone"] + seen["with_prefill"]
+    )
+    assert stats["decode_steps_starved"] == (seen["alone"] if ready else 0)
+    assert stats["decode_starved_pct"] == (100.0 if ready else 0.0)
+
+
+def test_between_counts_only_a_running_pump():
+    """`between_s_sum`: from one step's return to the next one's entry,
+    where the later step found work in hand. Across a drained engine it
+    does not grow."""
+    engine = LLMEngine("tiny", max_batch=2, page_size=16)
+    engine.add_request(SHORT, SamplingParams(max_tokens=4))
+    engine.step()
+    time.sleep(0.2)  # a slow pump: the request is in hand
+    while engine.has_unfinished():
+        engine.step()
+    running = engine.stats()["between_s_sum"]
+    assert running >= 0.2
+    time.sleep(1.0)  # drained: nobody's
+    stats = run_to_the_end(engine, [SHORT], max_tokens=4)
+    assert stats["between_s_sum"] - running < 0.5

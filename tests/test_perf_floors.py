@@ -154,17 +154,23 @@ def test_step_telemetry_disabled_overhead():
 
 
 # The engine's host spans are jax.profiler.TraceAnnotation, always in
-# the code: with no profiler session each is one flag test. Measured
-# ~5µs on the CPU for a paged decode step's five annotations
-# (constructor, the keyword arguments, set_metadata, the generator of
-# `engine:emit`); 50µs is 0.1% of a 48 ms decode step on the chip.
+# the code: with no profiler session each is one flag test, and each
+# phase of a step books its own seconds besides (`engine._Phase`: two
+# clock reads, a stack of the open phases). Measured ~11µs on the CPU
+# for a paged decode step's six phases (constructor, the keyword
+# arguments, set_metadata, the clock reads and the booking: the
+# annotations alone ~2µs of it), the step's two reads of its thread's
+# CPU time and the one `is_ready()` in front of `launch:decode`; 50µs is
+# 1.3% of the shortest decode step on the chip (3.84 ms,
+# `qwen3next-longdoc-16`).
 ENGINE_STEP_ANNOTATIONS_CEILING_S = 50e-6
 
 
 def test_engine_step_annotations_cost_without_a_session(monkeypatch):
-    """What one `LLMEngine.step()` spends on its annotations when nobody
+    """What one `LLMEngine.step()` spends on its phases when nobody
     traces: the step's own annotation calls are recorded once, then
-    replayed on the real class."""
+    replayed on the real class through the engine's own helper, with the
+    clock reads and the `is_ready()` a step makes beside them."""
     import time
 
     from jax.profiler import TraceAnnotation
@@ -192,15 +198,31 @@ def test_engine_step_annotations_cost_without_a_session(monkeypatch):
     monkeypatch.undo()
     names = [c[0] for c in calls]
     assert names == ["engine:step", "engine:grow_tables",
-                     "engine:decode_dispatch", "engine:decode_sync",
-                     "engine:emit"], names
+                     "engine:decode_dispatch", "launch:decode",
+                     "engine:decode_sync", "engine:emit"], names
     assert not TraceAnnotation.is_enabled()
+    in_flight = eng._in_flight.sampled
 
     def replay():
-        for name, kw, metadata in calls:
-            with TraceAnnotation(name, **kw) as span:
-                for more in metadata:
-                    span.set_metadata(**more)
+        # Nested as in the step: every phase inside `engine:step`, the
+        # launch inside the dispatch.
+        time.perf_counter(), time.thread_time()
+        (name, kw, metadata), *children = calls
+        with eng._phase(name, **kw) as step:
+            for name, kw, metadata_of in children:
+                if name == "launch:decode":
+                    continue
+                with eng._phase(name, **kw) as span:
+                    if name == "engine:decode_dispatch":
+                        in_flight.is_ready()
+                        with eng._phase("launch:decode", ahead=1, starved=0):
+                            pass
+                    for more in metadata_of:
+                        span.set_metadata(**more)
+            time.thread_time()
+            for more in metadata:
+                step.set_metadata(**more)
+        time.perf_counter()
 
     n = 2000
     for _ in range(100):

@@ -707,17 +707,21 @@ class HybridServing:
     def counters(self) -> dict:
         # Over the prefill programs run: how many; the live tokens the
         # chunked scans took, summed over the Mamba blocks and over the
-        # gated-delta-rule blocks; the causal (query, key) pairs the
-        # attention's arithmetic needed, summed over the blocks that
+        # gated-delta-rule blocks, and of the latter those whose rule
+        # ran in `ops/pallas/gdn_chunk.py` (all of them on a TPU, none
+        # off it: `gdn_chunked` asks the platform and nothing else);
+        # the causal (query, key) pairs the attention's arithmetic needed, summed over the blocks that
         # attend the whole context (`LlamaServing` counts the same) and,
         # apart, over the window blocks, where a query at position t
         # needs min(t + 1, W) keys, and the live tokens those blocks
         # took. `window_bytes`: what of the cache's per-slot bytes (the
         # engine's `state_bytes`) is windows.
+        gdn_tokens = self.cfg.count("G") * self._live_tokens
         return {
             "prefill_programs": self._prefill_programs,
             "ssm_scan_tokens": self.cfg.count("M") * self._live_tokens,
-            "gdn_scan_tokens": self.cfg.count("G") * self._live_tokens,
+            "gdn_scan_tokens": gdn_tokens,
+            "gdn_kernel_tokens": gdn_tokens if chip.platform() == "tpu" else 0,
             "prefill_attn_pairs": self._prefill_pairs,
             "prefill_window_pairs": self._window_pairs,
             "window_tokens": self.cfg.count("W") * self._live_tokens,

@@ -54,6 +54,7 @@ from typing import ClassVar
 import jax
 import jax.numpy as jnp
 
+from ray_tpu._private import chip
 from ray_tpu.models import granite_hybrid
 from ray_tpu.models.nemotron_h import (
     NemotronHConfig,
@@ -62,6 +63,7 @@ from ray_tpu.models.nemotron_h import (
     _init_ends,
     _normal,
 )
+from ray_tpu.ops.pallas.gdn_chunk import gdn_chunk_rule
 from ray_tpu.ops.pallas.state_step import gdn_state_step
 
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -99,10 +101,12 @@ class Qwen3NextConfig(NemotronHConfig):
     # Tokens the chunked form takes at once: within a chunk the rule is
     # matrix products and one triangular inverse of this size, between
     # chunks the state. The mixer alone over 2,048 tokens at these
-    # widths, on a v5e (my chip run, PR 51): 4.94 / 4.68 / 5.49 ms at
-    # chunks of 16 / 32 / 64 (at 128, with one-pass products, 8.15 for
-    # 4.47 at 64): longer chunks pay the inverse and the C x C scores,
-    # shorter ones more steps of the scan.
+    # widths, on a v5e, XLA's form (my chip run, PR 51): 4.94 / 4.68 /
+    # 5.49 ms at chunks of 16 / 32 / 64 (at 128, with one-pass products,
+    # 8.15 for 4.47 at 64): longer chunks pay the inverse and the C x C
+    # scores, shorter ones more steps of the scan. On a TPU the rule is
+    # `ops/pallas/gdn_chunk.py` since PR 58 (the mixer 4.49 -> 2.35 ms
+    # at 32: my chip run, PR 58; not timed at the other chunks).
     gdn_chunk: int = 32
     num_experts: int = 512
     top_k: int = 10
@@ -342,6 +346,67 @@ def _unit_lower_inverse(lower):
     return inv.reshape(*lead, c, c)
 
 
+def _chunked_rule(q, k, v, beta, g, state0, size):
+    """`gdn_chunked`'s rule in XLA's own operations, between the gates
+    and ``o``: q, k [T, Hk, dk], v [T, Hk, r, dv], beta and g [T, Hk, r]
+    (0 where a token takes no step), state0 [Hk, r, dk, dv], chunks of
+    ``size``. Returns (o [T, value width], the state after the last
+    token). The path off the TPU and the oracle of
+    ``ops/pallas/gdn_chunk.py``, which is the same rule on one."""
+    t = q.shape[0]
+    n = -(-t // size)
+    padded = n * size
+
+    def chunks(a):
+        """[T, Hk, ..] -> [n, Hk, .., C, last]: chunked, head-major,
+        time and the head's own dimension minor."""
+        a = jnp.pad(a, ((0, padded - t),) + ((0, 0),) * (a.ndim - 1))
+        a = a.reshape(n, size, *a.shape[1:])
+        return jnp.moveaxis(a, 1, -2)
+
+    q_c, k_c = chunks(q), chunks(k)  # [n, Hk, C, dk]
+    v_c = chunks(v)  # [n, Hk, r, C, dv]
+    beta_c = chunks(beta[..., None])  # [n, Hk, r, C, 1]
+    gamma = jnp.cumsum(chunks(g[..., None])[..., 0], axis=-1)  # [n,Hk,r,C]
+    diff = gamma[..., :, None] - gamma[..., None, :]  # [n, Hk, r, C, C]
+    causal = jnp.tril(jnp.ones((size, size), bool))
+    decay = jnp.exp(jnp.where(causal, diff, -jnp.inf))
+    grow = jnp.exp(gamma)[..., None]  # [n, Hk, r, C, 1]
+    to_end = jnp.exp(gamma[..., -1:] - gamma)[..., None]
+    whole = jnp.exp(gamma[..., -1])  # [n, Hk, r]
+
+    kk = jnp.einsum("nhik,nhjk->nhij", k_c, k_c, precision=_HIGHEST)
+    strict = jnp.tril(jnp.ones((size, size), bool), -1)
+    lower = jnp.where(strict, beta_c * kk[:, :, None] * decay, 0.0)
+    solve = _unit_lower_inverse(lower)  # [n, Hk, r, C, C]
+    k_r = k_c[:, :, None]  # [n, Hk, 1, C, dk]: a key head's value heads
+    # [n, Hk, r, C, dv] and [n, Hk, r, C, dk]
+    u_c = jnp.matmul(solve, beta_c * v_c, precision=_HIGHEST)
+    w_c = jnp.matmul(solve, beta_c * grow * k_r, precision=_HIGHEST)
+    qk = jnp.einsum("nhik,nhjk->nhij", q_c, k_c, precision=_HIGHEST)
+    within = qk[:, :, None] * decay  # [n, Hk, r, C, C]
+    q_grown = q_c[:, :, None] * grow
+    k_end = k_r * to_end
+
+    def carry(state, chunk):
+        u_i, w_i, within_i, q_i, k_i, whole_i = chunk
+        v_new = u_i - jnp.matmul(w_i, state, precision=_HIGHEST)
+        out = jnp.matmul(q_i, state, precision=_HIGHEST) + jnp.matmul(
+            within_i, v_new, precision=_HIGHEST
+        )  # [Hk, r, C, dv]
+        state = state * whole_i[..., None, None] + jnp.einsum(
+            "hrck,hrcv->hrkv", k_i, v_new, precision=_HIGHEST
+        )
+        return state, out
+
+    end, o = jax.lax.scan(
+        carry, state0, (u_c, w_c, within, q_grown, k_end, whole)
+    )
+    # [n, Hk, r, C, dv] -> [T, value width]
+    o = jnp.moveaxis(o, -2, 1).reshape(padded, -1)[:t]
+    return o, end
+
+
 def gdn_chunked(u, p, cfg: Qwen3NextConfig, state0, conv0, length):
     """The Gated DeltaNet mixer over many tokens of one sequence, with
     `nemotron_h.mamba_chunked`'s contract.
@@ -375,13 +440,15 @@ def gdn_chunked(u, p, cfg: Qwen3NextConfig, state0, conv0, length):
     alone takes 4.68 ms for 3.39 at 2,048 tokens, a tenth of a chunk
     program (v5e, my chip run, PR 51; `benchmarks/models/qwen3_next.py`
     FIRST_STATE_TOLERANCE is the limit that rests on it).
+
+    On a TPU the rule, between the gates and ``o``, is one call of
+    ``ops/pallas/gdn_chunk.py`` (the same arithmetic with nothing of it
+    in HBM: 2.35 ms the mixer, PR 58); elsewhere `_chunked_rule`, XLA's
+    form. The platform decides, as it does for ``moe_ffn``'s kernels.
     """
     t = u.shape[0]
     hk, dk, dv = cfg.gdn_key_heads, cfg.gdn_key_dim, cfg.gdn_value_dim
-    rep = cfg.gdn_value_heads // hk
     size = min(cfg.gdn_chunk, 1 << (t - 1).bit_length())
-    n = -(-t // size)
-    padded = n * size
     qkv, z, ba = _project_in(u, p, cfg)
 
     with jax.named_scope("gdn:conv"):
@@ -400,55 +467,14 @@ def gdn_chunked(u, p, cfg: Qwen3NextConfig, state0, conv0, length):
         live = (jnp.arange(t) < length)[:, None, None]
         beta = jnp.where(live, beta, 0.0)
         g = jnp.where(live, g, 0.0)
-
-        def chunks(a):
-            """[T, Hk, ..] -> [n, Hk, .., C, last]: chunked, head-major,
-            time and the head's own dimension minor."""
-            a = jnp.pad(a, ((0, padded - t),) + ((0, 0),) * (a.ndim - 1))
-            a = a.reshape(n, size, *a.shape[1:])
-            return jnp.moveaxis(a, 1, -2)
-
-        q_c, k_c = chunks(q), chunks(k)  # [n, Hk, C, dk]
-        v_c = chunks(v)  # [n, Hk, r, C, dv]
-        beta_c = chunks(beta[..., None])  # [n, Hk, r, C, 1]
-        gamma = jnp.cumsum(chunks(g[..., None])[..., 0], axis=-1)  # [n,Hk,r,C]
-        diff = gamma[..., :, None] - gamma[..., None, :]  # [n, Hk, r, C, C]
-        causal = jnp.tril(jnp.ones((size, size), bool))
-        decay = jnp.exp(jnp.where(causal, diff, -jnp.inf))
-        grow = jnp.exp(gamma)[..., None]  # [n, Hk, r, C, 1]
-        to_end = jnp.exp(gamma[..., -1:] - gamma)[..., None]
-        whole = jnp.exp(gamma[..., -1])  # [n, Hk, r]
-
-        kk = jnp.einsum("nhik,nhjk->nhij", k_c, k_c, precision=_HIGHEST)
-        strict = jnp.tril(jnp.ones((size, size), bool), -1)
-        lower = jnp.where(strict, beta_c * kk[:, :, None] * decay, 0.0)
-        solve = _unit_lower_inverse(lower)  # [n, Hk, r, C, C]
-        k_r = k_c[:, :, None]  # [n, Hk, 1, C, dk]: a key head's value heads
-        # [n, Hk, r, C, dv] and [n, Hk, r, C, dk]
-        u_c = jnp.matmul(solve, beta_c * v_c, precision=_HIGHEST)
-        w_c = jnp.matmul(solve, beta_c * grow * k_r, precision=_HIGHEST)
-        qk = jnp.einsum("nhik,nhjk->nhij", q_c, k_c, precision=_HIGHEST)
-        within = qk[:, :, None] * decay  # [n, Hk, r, C, C]
-        q_grown = q_c[:, :, None] * grow
-        k_end = k_r * to_end
-
-        def carry(state, chunk):
-            u_i, w_i, within_i, q_i, k_i, whole_i = chunk
-            v_new = u_i - jnp.matmul(w_i, state, precision=_HIGHEST)
-            out = jnp.matmul(q_i, state, precision=_HIGHEST) + jnp.matmul(
-                within_i, v_new, precision=_HIGHEST
-            )  # [Hk, r, C, dv]
-            state = state * whole_i[..., None, None] + jnp.einsum(
-                "hrck,hrcv->hrkv", k_i, v_new, precision=_HIGHEST
+        start = state0.reshape(hk, -1, dk, dv)
+        # Chosen by the platform alone, as `moe_ffn` chooses its kernels.
+        if chip.platform() == "tpu":
+            o, end = gdn_chunk_rule(
+                q, k, v, beta, g, start, length, chunk=size
             )
-            return state, out
-
-        end, o = jax.lax.scan(
-            carry, state0.reshape(hk, rep, dk, dv),
-            (u_c, w_c, within, q_grown, k_end, whole),
-        )
-        # [n, Hk, r, C, dv] -> [T, value width]
-        o = jnp.moveaxis(o, -2, 1).reshape(padded, -1)[:t]
+        else:
+            o, end = _chunked_rule(q, k, v, beta, g, start, size)
     out = _project_out(o, z, p, cfg)
     return out, end.reshape(state0.shape), conv_end.astype(conv0.dtype)
 

@@ -359,6 +359,31 @@ def test_state_step_kernel_compiles_for_v5e_at_served_shapes(v5e, case):
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20
 
 
+def test_gdn_chunk_kernel_compiles_for_v5e_at_the_served_shape(v5e):
+    """qwen3next-80b-serve1's prefill chunk: 2,048 tokens, 16 key heads
+    and 32 value heads of 128 x 128, rule chunks of 32. The masked
+    inverse by halves, the columns and the row taken out under a mask and
+    the product that contracts the tokens of a chunk lower for the chip,
+    inside the VMEM the call asks for; none of XLA's `[n, Hk, r, C, 128]`
+    intermediates is made beside the arguments (cols, rows and the
+    results: 1.3 MB and what leaves)."""
+    from ray_tpu.ops.pallas import gdn_chunk
+
+    t, hk, rep, dk, dv = 2048, 16, 2, 128, 128
+
+    def on_chip(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    compiled = jax.jit(partial(gdn_chunk.gdn_chunk_rule, chunk=32)).lower(
+        on_chip(t, hk, dk), on_chip(t, hk, dk), on_chip(t, hk, rep, dv),
+        on_chip(t, hk, rep), on_chip(t, hk, rep), on_chip(hk, rep, dk, dv),
+        on_chip(dtype=jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert len(_kernel_calls_under(text, "")) == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**20
+
+
 def _combine_scatters(text: str) -> list[str]:
     """A compiled program's lines under ``moe:combine`` that name a
     scatter (the instruction, or the ``scatter-add`` its fusion was
@@ -1023,6 +1048,15 @@ def test_qwen3next_program_moves_no_pages_state_or_stack_and_fits(
         assert _expert_kernel_calls(text) == []
         assert len(_grouped_kernel_calls(text)) == 4  # two a layer's FFN
         assert not re.search(rf"\[[\d,]*{chunk},{table}\]", text)
+        # The delta rule is ONE kernel call a layer under its scope: no
+        # scan over the rule chunks, none of its per-chunk float32
+        # intermediates ([64 chunks, 16 key heads, 2, 32, ..]) in HBM.
+        assert len(_kernel_calls_under(text, "gdn:scan")) == 1
+        assert not [
+            line for line in text.splitlines()
+            if "gdn:scan" in line and " while(" in line
+        ]
+        assert not re.search(r"f32\[64,16,2,32,\d+\]", text)
         k = conf["num_experts_per_tok"]
         assert _expert_makers_of(text, (chunk * k, d)) <= {
             "bitcast", "custom-call"}

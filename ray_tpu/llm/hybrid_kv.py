@@ -17,7 +17,14 @@ which the other families' configs hold off. A Laguna layer
 ``*`` over the whole context from pages and ``W`` over the last
 ``cfg.sliding_window`` positions from per-slot leaves of the cache, each
 kind with its own head count and rotary scheme (`_attention_kind`), and
-its first layer's FFN is dense (``D``).
+its first layer's FFN is dense (``D``). A GLM-5.3-Flash layer
+(``models/glm5_next.py``) is two as well: a third recurrence (``K``, the
+delta rule with a decay a key channel) or latent attention over the keys
+an indexer picks (``L``), whose cells and pooled index keys are two more
+page pools on the SAME block tables; its FFNs clamp their SwiGLU, and
+what its programs carry between sublayers is ``cfg.hc_mult`` residual
+streams that each sublayer mixes on the way in and out (`_read`,
+`_residual`), which the other families' configs hold off.
 
 The cache is ONE donated tree with two kinds of per-sequence state:
 
@@ -45,7 +52,19 @@ The cache is ONE donated tree with two kinds of per-sequence state:
   13 at the benchmark's sizes.) What a ring holds that its request did
   not write (a slot's last request's keys, or zeros) lies at positions
   that every reader masks by the TRUE position it computes for the
-  index: nothing is cleared.
+  index: nothing is cleared;
+- ``latent`` ``[L_latent, num_pages, P, rank]`` and ``index``
+  ``[L_latent, num_pages, P / pool, Di]``: a token's latent cell and, a
+  block of ``pool = cfg.index_kpool`` positions, the indexer's pooled
+  key, for the ``L`` blocks only, reached through the request's block
+  table like ``k`` and ``v`` (a request's pages are counted once: every
+  paged leaf has ``num_pages`` pages a layer). ``index_tail``
+  ``[L_latent, max_batch, pool - 1, Di]`` float32 is each slot's last
+  ``pool - 1`` indexer keys: a block's pooled key is complete only with
+  its last token, which may come in a decode step, so the open block's
+  keys wait here, the block is no query's candidate until it is whole
+  (`glm5_next._select`), and the step that completes it writes the mean
+  of the tail and its own key (`glm5_next.dsa_decode`).
 
 Both are carried through the Python loop over the pattern and updated in
 place (a block writes its own layer's slot rows; nothing is sliced out
@@ -92,9 +111,17 @@ from ray_tpu.llm.paged_kv import (
     _sample_tokens,
     _write_pages,
     init_paged_kv,
-    kv_cache_bytes,
 )
-from ray_tpu.models.moe import moe_ffn
+from ray_tpu.models.glm5_next import (
+    dsa_decode,
+    dsa_prefill,
+    kda_chunked,
+    kda_step,
+    kda_step_live,
+    mhc_mix,
+    mhc_spread,
+)
+from ray_tpu.models.moe import clamped_swiglu, moe_ffn
 from ray_tpu.models.nemotron_h import (
     NemotronHConfig,
     init_params,
@@ -159,7 +186,19 @@ _RECURRENT = {
             c.gdn_conv_dim,
         ),
     ),
+    "K": _Recurrent(
+        kda_chunked, kda_step, kda_step_live, "kda", "kda_conv", "kda",
+        lambda c: (
+            (c.kda_heads, c.kda_head_dim, c.kda_head_dim), c.kda_conv_dim
+        ),
+    ),
 }
+
+# The leaves that are page pools: a request's block table reaches each.
+_PAGED = ("k", "v", "latent", "index")
+# What an ``L`` block reads and writes of the cache, in `dsa_prefill`'s
+# and `dsa_decode`'s order.
+_LATENT_LEAVES = ("latent", "index", "index_tail")
 
 
 def init_hybrid_cache(
@@ -179,18 +218,58 @@ def init_hybrid_cache(
         ring = (n, max_batch, cfg.sliding_window, cfg.n_kv_heads, cfg.head_dim)
         cache["win_k"] = jnp.zeros(ring, cfg.dtype)
         cache["win_v"] = jnp.zeros(ring, cfg.dtype)
+    if n := cfg.count("L"):
+        pool, width = cfg.index_kpool, cfg.index_head_dim
+        if page_size % pool:
+            raise ValueError("index_kpool does not divide the page size")
+        cache["latent"] = jnp.zeros(
+            (n, num_pages, page_size, cfg.kv_lora_rank), cfg.dtype
+        )
+        cache["index"] = jnp.zeros(
+            (n, num_pages, page_size // pool, width), cfg.dtype
+        )
+        cache["index_tail"] = jnp.zeros(
+            (n, max_batch, pool - 1, width), jnp.float32
+        )
     return cache
+
+
+def hybrid_cache_bytes(cache) -> tuple[int, int]:
+    """(bytes of the page pools, bytes of whatever else the cache holds:
+    per-slot state), `paged_kv.kv_cache_bytes` with this cache's other
+    pools counted as pools."""
+    pool = sum(int(cache[leaf].nbytes) for leaf in _PAGED if leaf in cache)
+    return pool, sum(int(v.nbytes) for v in cache.values()) - pool
 
 
 def _embed(params, tokens, cfg):
     x = params["tok_emb"][tokens]
     if cfg.embedding_multiplier != 1.0:
         x = x * cfg.embedding_multiplier
+    if cfg.hc_mult:
+        # A copy a residual stream: [.., n, d] from here to `_head`.
+        x = jnp.broadcast_to(
+            x[..., None, :], (*x.shape[:-1], cfg.hc_mult, x.shape[-1])
+        )
     return x
 
 
-def _residual(x, out, cfg):
-    """``x + residual_multiplier * out``: a sublayer's output added on."""
+def _read(x, p, cfg):
+    """A sublayer's input from what the programs carry, and what
+    `_residual` needs to write its output back: ``x`` itself and nothing
+    for a model of one residual stream; `mhc_mix`'s learned mix of the
+    streams [.., n, d] and its two write-back matrices for a model with
+    ``cfg.hc_mult`` of them."""
+    if not cfg.hc_mult:
+        return x, None
+    return mhc_mix(x, p["hc"], cfg)
+
+
+def _residual(x, out, cfg, mix=None):
+    """``x + residual_multiplier * out``: a sublayer's output added on
+    (``mix``, `_read`'s: spread over the streams by `mhc_spread`)."""
+    if mix is not None:
+        return mhc_spread(x, out, *mix)
     if cfg.residual_multiplier != 1.0:
         out = out * cfg.residual_multiplier
     return x + out
@@ -198,18 +277,19 @@ def _residual(x, out, cfg):
 
 def _experts(x, p, cfg, rows_live, record):
     """An expert block on x [B, S, d], and its counters onto ``record``."""
+    h, mix = _read(x, p, cfg)
     out, aux = moe_ffn(
-        rms_norm(x, p["norm"], cfg.norm_eps), p, cfg, rows_live=rows_live
+        rms_norm(h, p["norm"], cfg.norm_eps), p, cfg, rows_live=rows_live
     )
     _note(record, aux)
-    return _residual(x, out, cfg)
+    return _residual(x, out, cfg, mix)
 
 
 def _new_record():
     """What a program's expert blocks leave: `_note` fills, `_record`
     sums."""
     return {"routes": [], "pairs_here": [], "experts_touched": [],
-            "sorted_rows": []}
+            "sorted_rows": [], "selected": []}
 
 
 def _note(record, aux):
@@ -227,7 +307,14 @@ def _record(record):
     sorted form ran its grouped matmuls over and the pairs it was given
     (padding's and absent experts' among them), each summed over the
     expert blocks."""
+    selected = (
+        # [L_latent, T, index_blocks]: the blocks each query attended
+        # beside its own (-1: fewer candidates), where the model selects.
+        {"selected": jnp.stack(record["selected"])} if record["selected"]
+        else {}
+    )
     return {
+        **selected,
         "routes": jnp.stack(record["routes"]),
         "counts": jnp.concatenate([
             jnp.stack(
@@ -244,13 +331,19 @@ def _carried(cache, k_pages, v_pages, state) -> HybridCache:
     return {
         "k": k_pages.reshape(cache["k"].shape),
         "v": v_pages.reshape(cache["v"].shape),
-        **state,
+        **{name: leaf.reshape(cache[name].shape) if name in _PAGED else leaf
+           for name, leaf in state.items()},
     }
 
 
 def _recurrent_leaves(cache) -> HybridCache:
+    """The leaves the loop carries as they are; the latent blocks' two
+    pools in a flat view over the layers, as `_flat_pool`'s."""
     return {
-        name: leaf for name, leaf in cache.items() if name not in ("k", "v")
+        name: (
+            leaf.reshape(-1, *leaf.shape[2:]) if name in _PAGED else leaf
+        )
+        for name, leaf in cache.items() if name not in ("k", "v")
     }
 
 
@@ -420,16 +513,21 @@ def _window_decode(q, k, v, win_k, win_v, layer: int, positions, active, cfg):
 
 def _dense_ffn(x, p, cfg):
     """A dense gated-SiLU FFN sublayer on x [B, S, d]."""
+    h, mix = _read(x, p, cfg)
     with jax.named_scope("ffn:dense"):
-        h = rms_norm(x, p["norm"], cfg.norm_eps)
-        out = (jax.nn.silu(h @ p["w_gate"]) * (h @ p["w_up"])) @ p["w_down"]
-    return _residual(x, out, cfg)
+        h = rms_norm(h, p["norm"], cfg.norm_eps)
+        act = clamped_swiglu(h, p["w_gate"], p["w_up"], cfg.swiglu_limit)
+        out = act @ p["w_down"]
+    return _residual(x, out, cfg, mix)
 
 
 def _head(x, params, cfg=None):
     """Final norm and the head; ``cfg`` where the model ties the head to
     the embedding or divides its logits (`llm/latent_kv.py`'s does
-    neither and passes none)."""
+    neither and passes none). The sum of the residual streams where
+    the programs carry more than one."""
+    if cfg is not None and cfg.hc_mult:
+        x = x.astype(jnp.float32).sum(-2).astype(x.dtype)
     x = rms_norm(
         x, params["final_norm"], 1e-5 if cfg is None else cfg.norm_eps
     )
@@ -486,8 +584,9 @@ def _hybrid_prefill(
             block = _RECURRENT[kind]
             fresh = start == 0
             at = (seen[kind], slot)
+            h, mix = _read(x, p, cfg)
             out, s_end, c_end = block.chunked(
-                rms_norm(x, p["norm"], cfg.norm_eps)[0], p, cfg,
+                rms_norm(h, p["norm"], cfg.norm_eps)[0], p, cfg,
                 jnp.where(fresh, 0.0, state[block.state][at]),
                 jnp.where(fresh, 0, state[block.conv][at]),
                 jnp.clip(length - start, 0, c),
@@ -495,11 +594,21 @@ def _hybrid_prefill(
             with jax.named_scope(f"{block.scope}:scan"):
                 state[block.state] = state[block.state].at[at].set(s_end)
                 state[block.conv] = state[block.conv].at[at].set(c_end)
-            x = _residual(x, out[None], cfg)
+            x = _residual(x, out[None], cfg, mix)
         elif kind == "E":
             x = _experts(x, p, cfg, live, record)
         elif kind == "D":
             x = _dense_ffn(x, p, cfg)
+        elif kind == "L":
+            h, mix = _read(x, p, cfg)
+            out, left, picked = dsa_prefill(
+                h[0], p, cfg, tuple(state[leaf] for leaf in _LATENT_LEAVES),
+                (seen[kind], slot), seen[kind] * num_pages, pages,
+                chunk_slice, start, jnp.clip(length - start, 0, c),
+            )
+            state.update(zip(_LATENT_LEAVES, left))
+            record["selected"].append(picked)
+            x = _residual(x, out[None], cfg, mix)
         elif kind == "W":
             q, k, v, gate = _attention_inputs(x, p, cfg, pos, kind)
             attn, state["win_k"], state["win_v"] = _window_prefill(
@@ -602,7 +711,8 @@ def hybrid_decode(
     for kind, p in zip(cfg.pattern, params["blocks"], strict=True):
         if kind in _RECURRENT:
             block, at = _RECURRENT[kind], seen[kind]
-            u = rms_norm(x, p["norm"], cfg.norm_eps)[:, 0]
+            h, mix = _read(x, p, cfg)
+            u = rms_norm(h, p["norm"], cfg.norm_eps)[:, 0]
             old_c = state[block.conv][at]
             if live is not None:
                 out, state[block.state], new_c = block.step_live(
@@ -621,11 +731,22 @@ def hybrid_decode(
                 state[block.conv] = state[block.conv].at[at].set(
                     jnp.where(active[:, None, None], new_c, old_c)
                 )
-            x = _residual(x, out[:, None], cfg)
+            x = _residual(x, out[:, None], cfg, mix)
         elif kind == "E":
             x = _experts(x, p, cfg, active, record)
         elif kind == "D":
             x = _dense_ffn(x, p, cfg)
+        elif kind == "L":
+            h, mix = _read(x, p, cfg)
+            out, left, picked = dsa_decode(
+                h[:, 0], p, cfg,
+                tuple(state[leaf] for leaf in _LATENT_LEAVES),
+                seen[kind], seen[kind] * num_pages, block_tables, positions,
+                active,
+            )
+            state.update(zip(_LATENT_LEAVES, left))
+            record["selected"].append(picked)
+            x = _residual(x, out[:, None], cfg, mix)
         elif kind == "W":
             q, k, v, gate = _attention_inputs(
                 x, p, cfg, positions[:, None], kind
@@ -680,6 +801,8 @@ class HybridServing:
         self._init_weights = init_weights
         self._prefill_programs = self._live_tokens = self._prefill_pairs = 0
         self._window_pairs = self._window_bytes = 0
+        self._index_pairs = self._selected_pairs = self._causal_pairs = 0
+        self._latent_bytes = self._index_bytes = 0
 
     def init_weights(self, key):
         return self._init_weights(key, self.cfg)
@@ -700,9 +823,14 @@ class HybridServing:
             int(cache[leaf].nbytes) for leaf in ("win_k", "win_v")
             if leaf in cache
         )
+        self._latent_bytes = int(cache["latent"].nbytes) if "latent" in cache else 0
+        self._index_bytes = sum(
+            int(cache[leaf].nbytes) for leaf in ("index", "index_tail")
+            if leaf in cache
+        )
         return cache
 
-    cache_bytes = staticmethod(kv_cache_bytes)
+    cache_bytes = staticmethod(hybrid_cache_bytes)
 
     def counters(self) -> dict:
         # Over the prefill programs run: how many; the live tokens the
@@ -716,8 +844,28 @@ class HybridServing:
         # needs min(t + 1, W) keys, and the live tokens those blocks
         # took. `window_bytes`: what of the cache's per-slot bytes (the
         # engine's `state_bytes`) is windows.
+        # Where the model has them: the tokens the per-channel rule
+        # (`K`) took; over the latent blocks (`L`) their tokens, the (query, pooled
+        # key) pairs the indexer's arithmetic needed (a query scores the
+        # complete blocks before its own), the (query, key) pairs the
+        # attention needed after the selection and the causal pairs it
+        # would have needed without one; what of the cache is latent
+        # cells and what is the indexer's (pooled keys and tails); and
+        # the tokens whose streams a sublayer mixed, summed over the
+        # sublayers, where the model carries more than one.
         gdn_tokens = self.cfg.count("G") * self._live_tokens
         return {
+            "kda_scan_tokens": self.cfg.count("K") * self._live_tokens,
+            "dsa_tokens": self.cfg.count("L") * self._live_tokens,
+            "dsa_index_pairs": self._index_pairs,
+            "dsa_selected_pairs": self._selected_pairs,
+            "dsa_causal_pairs": self._causal_pairs,
+            "latent_bytes": self._latent_bytes,
+            "index_bytes": self._index_bytes,
+            "mhc_tokens": (
+                len(self.cfg.pattern) * self._live_tokens
+                if self.cfg.hc_mult else 0
+            ),
             "prefill_programs": self._prefill_programs,
             "ssm_scan_tokens": self.cfg.count("M") * self._live_tokens,
             "gdn_scan_tokens": gdn_tokens,
@@ -740,6 +888,15 @@ class HybridServing:
             self._window_pairs += layers * (
                 _band_pairs_before(start + n, w) - _band_pairs_before(start, w)
             )
+        if layers := self.cfg.count("L"):
+            pool, top = self.cfg.index_kpool, self.cfg.index_blocks
+            t = np.arange(start, start + n, dtype=np.int64)
+            before = t // pool  # complete blocks before the query's own
+            self._index_pairs += layers * int(before.sum())
+            self._selected_pairs += layers * int(
+                (np.minimum(before, top) * pool + t % pool + 1).sum()
+            )
+            self._causal_pairs += layers * int((t + 1).sum())
 
     def prefill(self, params, tokens, cache, pages, *, n_write_pages, slot,
                 length, use_kernel=False):

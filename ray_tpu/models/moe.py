@@ -68,6 +68,11 @@ class MoEConfig(LlamaConfig):
     # "swiglu": silu(h W_gate) * (h W_up) W_down. "relu2": relu(h W_up)^2
     # W_down, no gate matrix.
     expert_kind: str = "swiglu"
+    # A gated expert's two products clamped before they are multiplied
+    # (``swiglu_limit`` of a published config): ``silu(min(h W_gate, l))
+    # * clip(h W_up, -l, l)``. None, here and in every family but
+    # `models/glm5_next.py`: no clamp, and nothing of it in a program.
+    swiglu_limit: float | None = None
     # (first, count) of the experts whose weights are held here, where
     # that is a share of `num_experts` (expert parallelism: the router
     # stays `num_experts` wide); None where all are.
@@ -178,7 +183,22 @@ def _expert_act(cfg, rows, w_gate, w_up, matmul):
     ``matmul(rows, w)`` is the grouped or the plain product."""
     if cfg.expert_kind == "relu2":
         return jnp.square(jax.nn.relu(matmul(rows, w_up)))
-    return jax.nn.silu(matmul(rows, w_gate)) * matmul(rows, w_up)
+    return clamped_swiglu(rows, w_gate, w_up, cfg.swiglu_limit, matmul)
+
+
+def clamped_swiglu(rows, w_gate, w_up, limit: float | None, matmul=jnp.matmul):
+    """``silu(rows W_gate) * (rows W_up)`` and, where the model has a
+    ``limit``, the gate held under it and the linear part within it on
+    both sides first. (In the order a model without one has always made
+    them: its programs' text stays as it was.)"""
+    gate = matmul(rows, w_gate)
+    if limit is not None:
+        gate = jnp.minimum(gate, limit)
+    act = jax.nn.silu(gate)
+    up = matmul(rows, w_up)
+    if limit is not None:
+        up = jnp.clip(up, -limit, limit)
+    return act * up
 
 
 def every_row_gates(cfg, routes, gates, here):
@@ -248,6 +268,7 @@ def _experts_on_every_row(tokens, p, cfg, routes, gates, here):
         out = experts_on_rows(
             tokens.astype(dt), p["w_gate"].astype(dt) if gated else None,
             p["w_up"].astype(dt), p["w_down"].astype(dt), weight, ids, count,
+            limit=cfg.swiglu_limit,
         )
     return out, load
 
@@ -375,7 +396,7 @@ def _experts_on_pairs_here(tokens, p, cfg, routes, gates, here):
             hidden = grouped_rows(
                 rows_out.reshape(-1, d),
                 [p[w].astype(dt) for w in (["w_gate"] * gated + ["w_up"])],
-                load, cfg.expert_kind, mean,
+                load, cfg.expert_kind, mean, limit=cfg.swiglu_limit,
             )
             rows_out = grouped_rows(
                 hidden, [p["w_down"].astype(dt)], load, None, mean

@@ -132,6 +132,14 @@ class NemotronHConfig:
     # its own.
     head_gate: bool = False
     rope_yarn: tuple | None = None
+    # What a family with clamped experts and more than one residual
+    # stream adds (`models/glm5_next.py`), off here and in the families
+    # above, whose programs hold none of it: `moe.clamped_swiglu`'s limit
+    # on a gated FFN's two products, and how many residual streams the
+    # programs carry (0: the one stream ``x + out``; n: `mhc_mix` reads a
+    # sublayer's input from n streams and `mhc_spread` writes it back).
+    swiglu_limit: float | None = None
+    hc_mult: int = 0
 
     # The letters `pattern` may hold: a family with another recurrence
     # adds its own (`models/qwen3_next.py`: G).

@@ -74,6 +74,7 @@ class PanguUltraMoEConfig:
     experts_held: tuple | None = None  # (first, count); None = all
     router_kind: str = "sigmoid"
     expert_kind: str = "swiglu"
+    swiglu_limit: float | None = None  # no clamp (`moe.clamped_swiglu`)
     # Up to this many rows `moe_ffn` applies every held expert to every
     # row, above it it sorts pairs into grouped matmuls. One expert layer
     # at these widths with 16 of 256 experts held, on a v5e (my chip run,

@@ -96,7 +96,7 @@ def _width_tile(d: int, f: int, n_matrices: int, itemsize: int) -> int:
     )
 
 
-def _make_kernel(gated: bool, n_rows: int):
+def _make_kernel(gated: bool, n_rows: int, limit: float | None):
     """Kernel of one (touched expert, width tile) a grid step. Refs:
     scalar prefetch (ids, count), the rows, the gate matrix, the weight
     tiles (gate's first where ``gated``), out, the accumulator."""
@@ -119,10 +119,14 @@ def _make_kernel(gated: bool, n_rows: int):
                     x, w_up_ref[0], preferred_element_type=jnp.float32
                 )
                 if gated:
-                    act = jax.nn.silu(jnp.dot(
+                    gate = jnp.dot(
                         x, w_gate_ref[0][0],
                         preferred_element_type=jnp.float32,
-                    )) * up
+                    )
+                    if limit is not None:  # a clamped SwiGLU
+                        gate = jnp.minimum(gate, limit)
+                        up = jnp.clip(up, -limit, limit)
+                    act = jax.nn.silu(gate) * up
                 else:
                     act = jnp.square(jnp.maximum(up, 0.0))
                 out = jnp.dot(
@@ -148,8 +152,9 @@ def _make_kernel(gated: bool, n_rows: int):
     return _kernel
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _experts_on_rows(x, w_gate, w_up, w_down, weight, ids, count, interpret):
+@functools.partial(jax.jit, static_argnames=("interpret", "limit"))
+def _experts_on_rows(x, w_gate, w_up, w_down, weight, ids, count, interpret,
+                     limit=None):
     n, d = x.shape
     held, _, f = w_up.shape
     gated = w_gate is not None
@@ -185,7 +190,7 @@ def _experts_on_rows(x, w_gate, w_up, w_down, weight, ids, count, interpret):
         + row_block * (4 * max(tile, _LANES) + 2 * d) * 4
     )
     out = pl.pallas_call(
-        _make_kernel(gated, n_rows),
+        _make_kernel(gated, n_rows, limit),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(held, n_tiles),
@@ -206,7 +211,7 @@ def _experts_on_rows(x, w_gate, w_up, w_down, weight, ids, count, interpret):
     return out[:n]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
 def experts_on_rows(
     x: jnp.ndarray,  # [n, d]
     w_gate: jnp.ndarray | None,  # [held, d, f]; None: relu(x w_up)^2
@@ -216,6 +221,7 @@ def experts_on_rows(
     ids: jnp.ndarray,  # [held] int32: the touched experts, packed first
     count: jnp.ndarray,  # [] int32: how many of `ids` are touched
     interpret: bool = False,
+    limit: float | None = None,  # a gated expert's clamp (`clamped_swiglu`)
 ) -> jnp.ndarray:
     """``sum_e weight[:, e] * expert_e(x)`` over the experts ``ids[:count]``,
     [n, d] in ``x``'s dtype. Every expert with a nonzero column of
@@ -223,17 +229,17 @@ def experts_on_rows(
     ``ids[count - 1]`` (0 where ``count`` is 0), so that those steps
     fetch nothing: see the module docstring. Forward only."""
     return _experts_on_rows(
-        x, w_gate, w_up, w_down, weight, ids, count, interpret
+        x, w_gate, w_up, w_down, weight, ids, count, interpret, limit
     )
 
 
-def _forward(x, w_gate, w_up, w_down, weight, ids, count, interpret):
+def _forward(x, w_gate, w_up, w_down, weight, ids, count, interpret, limit):
     return _experts_on_rows(
-        x, w_gate, w_up, w_down, weight, ids, count, interpret
+        x, w_gate, w_up, w_down, weight, ids, count, interpret, limit
     ), None
 
 
-def _backward(interpret, residuals, g):
+def _backward(interpret, limit, residuals, g):
     raise NotImplementedError(
         "ops/pallas/expert_rows.py has no backward pass: the every-row "
         "expert form serves decode steps and short prefill chunks; a "

@@ -122,7 +122,8 @@ def _column_tile(k: int, n: int, n_matrices: int, itemsize: int) -> int:
 
 
 def _make_kernel(
-    act: str | None, n_w: int, tile: int, pack: int, passes: int, depth: int
+    act: str | None, n_w: int, tile: int, pack: int, passes: int, depth: int,
+    limit: float | None = None,
 ):
     """Kernel of one (column pass, row block) a grid step. Refs: scalar
     prefetch (each block's first live group and how many it holds; the
@@ -189,10 +190,14 @@ def _make_kernel(
                     preferred_element_type=jnp.float32,
                 )
                 if act == "swiglu":
-                    y = jax.nn.silu(jnp.dot(
+                    gate = jnp.dot(
                         x, buffers[0][l % depth],
                         preferred_element_type=jnp.float32,
-                    )) * y
+                    )
+                    if limit is not None:  # a clamped SwiGLU
+                        gate = jnp.minimum(gate, limit)
+                        y = jnp.clip(y, -limit, limit)
+                    y = jax.nn.silu(gate) * y
                 elif act == "relu2":
                     y = jnp.square(jnp.maximum(y, 0.0))
                 row = at + jax.lax.broadcasted_iota(jnp.int32, y.shape, 0)
@@ -235,9 +240,10 @@ def _work_list(sizes, blocks: int, block: int):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("act", "group_rows", "interpret")
+    jax.jit, static_argnames=("act", "group_rows", "interpret", "limit")
 )
-def _grouped_rows(rows, weights, sizes, act, group_rows, interpret):
+def _grouped_rows(rows, weights, sizes, act, group_rows, interpret,
+                  limit=None):
     total, k = rows.shape
     n = weights[0].shape[2]
     n_w = len(weights)
@@ -264,7 +270,7 @@ def _grouped_rows(rows, weights, sizes, act, group_rows, interpret):
         + tile * (k * item + (n_w + 2) * max(width, _LANES) * 4)  # a tile's
     )
     out = pl.pallas_call(
-        _make_kernel(act, n_w, tile, pack, n // width, _BUFFERS),
+        _make_kernel(act, n_w, tile, pack, n // width, _BUFFERS, limit),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
             grid=(n // width, blocks),
@@ -290,7 +296,7 @@ def _grouped_rows(rows, weights, sizes, act, group_rows, interpret):
     return out[:total] if pad else out
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def grouped_rows(
     rows: jnp.ndarray,  # [total, k]: the rows in group order
     weights: list,  # one or two stacks [groups, k, n] in `rows`' dtype
@@ -298,6 +304,7 @@ def grouped_rows(
     act: str | None = None,  # None, "swiglu" (two stacks) or "relu2"
     group_rows: int = _MAX_TILE_ROWS,  # the groups' mean size, for the tile
     interpret: bool = False,
+    limit: float | None = None,  # "swiglu"'s clamp (`moe.clamped_swiglu`)
 ) -> jnp.ndarray:
     """Rows ``[sum(sizes[:g]), sum(sizes[:g + 1]))`` times
     ``weights[-1][g]`` for every group ``g``, [total, n] in ``rows``'
@@ -305,16 +312,18 @@ def grouped_rows(
     that product (and of the rows times ``weights[0][g]``: see the module
     docstring). Rows at and past ``sum(sizes)`` are never read and what
     comes back in their place is not defined. Forward only."""
-    return _grouped_rows(rows, weights, sizes, act, group_rows, interpret)
-
-
-def _forward(rows, weights, sizes, act, group_rows, interpret):
     return _grouped_rows(
-        rows, weights, sizes, act, group_rows, interpret
+        rows, weights, sizes, act, group_rows, interpret, limit
+    )
+
+
+def _forward(rows, weights, sizes, act, group_rows, interpret, limit):
+    return _grouped_rows(
+        rows, weights, sizes, act, group_rows, interpret, limit
     ), None
 
 
-def _backward(act, group_rows, interpret, residuals, g):
+def _backward(act, group_rows, interpret, limit, residuals, g):
     raise NotImplementedError(
         "ops/pallas/grouped_rows.py has no backward pass: the sorted "
         "expert form over the pairs computed here is a serving program's; "

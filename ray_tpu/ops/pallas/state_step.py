@@ -140,6 +140,20 @@ def _gdn_body(at_once: int):
     return body
 
 
+def _kda_body(at_once: int):
+    def body(s, h, rows):
+        """s [n, dk, dv] of heads h..; `_gdn_body` with a decay a key
+        channel (``decay`` [n, dk]) in place of one a head."""
+        decay, beta, q, k, v = (row[pl.ds(h, at_once), :] for row in rows)
+        k_col = k[:, :, None]
+        s = s * decay[:, :, None]
+        read = jnp.sum(s * k_col, axis=1)  # S^T k: [n, dv]
+        s = s + k_col * (beta * (v - read))[:, None, :]
+        return s, jnp.sum(s * q[:, :, None], axis=1)
+
+    return body
+
+
 def _step(body, stack, layer, order, count, rows, width, share,
           block_bytes, interpret):
     """The skeleton: ``stack`` [L, B, H, a, b] float32 aliased to the
@@ -267,4 +281,31 @@ def gdn_state_step(
         _gdn_body, stack, layer, order, count,
         [_along(decay, dv), _along(beta, dv), q, k, v], dv, heads,
         block_bytes, interpret,
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("block_bytes", "interpret"))
+def kda_state_step(
+    stack: jnp.ndarray,  # [L, B, H, dk, dv] float32: every layer's state
+    layer: jnp.ndarray,  # [] int32: the layer stepped
+    order: jnp.ndarray,  # [B] int32: `live_order`
+    count: jnp.ndarray,  # [1] int32
+    decay: jnp.ndarray,  # [B, H, dk] float32: exp(g), a key channel each
+    beta: jnp.ndarray,  # [B, H] float32
+    q: jnp.ndarray,  # [B, H, dk] float32
+    k: jnp.ndarray,  # [B, H, dk] float32
+    v: jnp.ndarray,  # [B, H, dv] float32
+    *,
+    block_bytes: int | None = None,
+    interpret: bool = False,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Kimi Delta Attention's step (`models/glm5_next.py kda_step`), ``S
+    = diag(decay) S``, ``S += k (beta (v - S^T k))^T``, ``o = S^T q``,
+    for the first ``count`` slots of ``order`` in ``stack[layer]``, in
+    place. Returns (the stack, o [B, H, dv] float32: zeros for the other
+    slots)."""
+    heads, dv = stack.shape[2], stack.shape[4]
+    return _step(
+        _kda_body, stack, layer, order, count,
+        [decay, _along(beta, dv), q, k, v], dv, heads, block_bytes, interpret,
     )

@@ -9,7 +9,14 @@ Builds the configuration's replica object in this process (no cluster:
 the check runs alone before any request anyway), runs `check` once a
 `lower`, and prints one JSON line each: the check's record and
 `check_problems` of it. ``none`` is the check as a benchmark run makes
-it."""
+it. GLM-5.3-Flash's switches (`benchmarks/reference_glm5_next.py`):
+
+    chiprun --timeout 3000 -- python scripts/family_check_lowers.py \
+        --config glm53flash-serve1 --seed 7 --skip-whole --skip-long --lower none \
+        weights_e4m3 state_bf16 one_decay_a_head unbounded_gate attend_all \
+        recent_keys no_pooling no_tail static_h no_sinkhorn one_stream \
+        no_clamp no_routed_scaling router_bf16
+"""
 
 import argparse
 import importlib
@@ -25,6 +32,15 @@ def main() -> None:
     ap.add_argument("--config", required=True)
     ap.add_argument("--seed", type=int, default=7)
     ap.add_argument("--lower", nargs="+", default=["none"])
+    ap.add_argument(
+        "--skip-whole", action="store_true",
+        help="leave out the prompt that is prefilled whole (a family whose "
+             "check takes whole_prompt_len 0 for that: glm5_next)",
+    )
+    ap.add_argument(
+        "--skip-long", action="store_true",
+        help="leave out the prompt past 32,768 tokens (long_prompt_len 0)",
+    )
     args = ap.parse_args()
 
     from benchmarks.server_family import BenchFamilyServer
@@ -33,11 +49,15 @@ def main() -> None:
         conf = json.load(f)
     family = importlib.import_module(f"benchmarks.models.{conf['model']}")
     server = BenchFamilyServer(conf, args.seed)
+    sizes = conf.get("check", {})
+    if args.skip_whole:
+        sizes = {**sizes, "whole_prompt_len": 0}
+    if args.skip_long:
+        sizes = {**sizes, "long_prompt_len": 0}
     for lower in args.lower:
         began = time.time()
         record = server.check(
-            args.seed, **conf.get("check", {}),
-            lower=None if lower == "none" else lower,
+            args.seed, **sizes, lower=None if lower == "none" else lower,
         )
         print(json.dumps({
             "config": args.config, "seed": args.seed, "lower": lower,
@@ -47,7 +67,8 @@ def main() -> None:
     stats = server.engine.stats()
     print(json.dumps({key: stats[key] for key in (
         "param_bytes", "pool_bytes", "state_bytes") if key in stats}
-        | {k: v for k, v in stats.items() if k.startswith("window")}))
+        | {k: v for k, v in stats.items()
+           if k.startswith(("window", "latent", "index"))}))
 
 
 if __name__ == "__main__":

@@ -31,7 +31,13 @@ heads over 8 KV heads and the prefill and paged kernels at 48 over 8
 (groups of 9 and 6), and laguna-s21-serve1's own programs at its full
 layer and one window layer with the whole configuration's pages and
 slots, at all three table widths: a window layer's part is the same in
-each.
+each. For GLM-5.3-Flash (models/glm5_next.py): the state kernel's third
+body at its stack, the two expert kernels with the clamp, the
+sub-chunked per-channel rule, the exact top-k and the row gather of
+selected cells (all XLA) at the served shapes, and glm53flash-serve1's
+own programs at its KDA + dense and sparse-attention + expert layers
+with the whole configuration's pages and slots, at all four table
+widths: no score over the table, at 65,536 keys either.
 """
 
 import math
@@ -1255,3 +1261,199 @@ def test_chunk_program_sums_expert_rows_by_the_kernel(family, request):
     decode = programs["decode"].as_text()
     assert _combine_scatters(decode) == []
     assert _kernel_calls_under(decode, "moe:combine") == []
+
+
+# --------------------------- GLM-5.3-Flash: KDA, sparse latent, clamp
+def test_kda_state_kernel_compiles_for_v5e_at_the_served_stack(v5e):
+    """`kda_state_step` at glm53flash-serve1's stack ([4, 16, 64, 128,
+    128]): the decay a key channel turned into a column lowers for the
+    chip, and the donated stack is the result."""
+    from ray_tpu.ops.pallas import state_step
+
+    stack = (4, 16, 64, 128, 128)
+
+    def on_chip(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    compiled = jax.jit(state_step.kda_state_step, donate_argnums=0).lower(
+        on_chip(*stack), on_chip(dtype=jnp.int32),
+        on_chip(16, dtype=jnp.int32), on_chip(1, dtype=jnp.int32),
+        on_chip(16, 64, 128), on_chip(16, 64), on_chip(16, 64, 128),
+        on_chip(16, 64, 128), on_chip(16, 64, 128),
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert _copies_of(text, stack) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+@pytest.mark.parametrize("call", ["every_row", "up_projections"])
+def test_expert_kernels_compile_for_v5e_with_the_clamp(v5e, call):
+    """`expert_rows` and `grouped_rows` at glm53flash-serve1's 36 held
+    experts of 2,048 behind a model width of 4,096 with ``limit`` 10: the
+    clamp is two more vector operations on the float32 products, inside
+    the VMEM the calls ask for."""
+    from ray_tpu.ops.pallas import grouped_rows
+    from ray_tpu.ops.pallas.expert_rows import experts_on_rows
+
+    d, f, held = 4096, 2048, 36
+
+    def on_chip(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    if call == "every_row":
+        compiled = jax.jit(partial(experts_on_rows, limit=10.0)).lower(
+            *_expert_rows_args(v5e, 16, held, d, f, True)
+        ).compile()
+    else:
+        total = 2048 * 8
+
+        def experts_on(rows, sizes, *stacks):
+            return grouped_rows.grouped_rows(
+                rows, stacks, sizes, "swiglu", total // 288, limit=10.0
+            )
+
+        compiled = jax.jit(experts_on).lower(
+            on_chip((total, d)), on_chip((held,), jnp.int32),
+            on_chip((held, d, f)), on_chip((held, d, f)),
+        ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert _copies_of(text, (held, d, f)) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+def test_the_sub_chunked_rule_and_the_selection_lower_for_v5e(v5e):
+    """What no kernel computes, at the served shapes: KDA's chunked rule
+    over 2,048 tokens of 64 heads (chunk 64 in sub-chunks of 16: the
+    decays inside the products, the triangular inverse, the scan over
+    chunks), the exact top-k of 512 of 16,384 blocks for 2,048 queries,
+    and the gather of a query block's selected cells, a row a position,
+    from a 64k context's cells."""
+    from ray_tpu.models import glm5_next
+
+    def on_chip(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    rule = jax.jit(
+        partial(glm5_next._kda_rule, size=64, sub=16)
+    ).lower(
+        on_chip(2048, 64, 128), on_chip(2048, 64, 128),
+        on_chip(2048, 64, 128), on_chip(2048, 64), on_chip(2048, 64, 128),
+        on_chip(64, 128, 128),
+    ).compile()
+    assert rule.memory_analysis().temp_size_in_bytes < 3 * 2**30
+    cfg = glm5_next.Glm5NextConfig()
+    select = jax.jit(partial(glm5_next._select, cfg=cfg)).lower(
+        on_chip(2048, 16384), on_chip(2048, dtype=jnp.int32)
+    ).compile()
+    assert "s32[2048,512]" in select.as_text()
+    def selected_cells(context, ids):
+        rows, hidden = glm5_next._block_rows(ids, cfg.index_kpool)
+        return jnp.take(context, rows, axis=0, mode="clip"), hidden
+
+    gather = jax.jit(selected_cells).lower(
+        on_chip(65536, 512, dtype=jnp.bfloat16),
+        on_chip(128, 512, dtype=jnp.int32),
+    ).compile()
+    assert "bf16[128,2048,512]" in gather.as_text()
+    assert _copies_of(gather.as_text(), (65536, 512)) == []
+
+
+@pytest.fixture(scope="module")
+def glm5_next_programs(v5e):
+    """glm53flash-serve1's own sizes (benchmarks/configs) at 2 of its 5
+    layers (KDA + dense FFN, sparse latent attention + experts: all four
+    kinds of sublayer), with the whole configuration's pages and slots:
+    what `aot_fit_serve_family` lowers, at every table of the mix.
+    Compiled when first asked for."""
+    import json
+
+    from benchmarks import aot_fit_serve_family
+    from ray_tpu._private import chip
+
+    here = os.path.join(os.path.dirname(__file__), "..", "benchmarks")
+    with open(os.path.join(here, "configs", "glm53flash-serve1.json")) as f:
+        whole = json.load(f)
+    conf = {**whole, "num_hidden_layers": 2, **{
+        key: whole[key][:2]
+        for key in ("layer_types", "mlp_layer_types", "indexer_types")
+    }}
+    traffic = {"fit_prefill_buckets": [8192, 16384, 32768, 65536]}
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(chip, "platform", lambda: "tpu")
+        lowered = aot_fit_serve_family.lowered_programs(
+            conf, traffic, next(iter(v5e.device_set))
+        )
+    compiled = {}
+
+    def program(name):
+        if name not in compiled:
+            compiled[name] = lowered[name].compile()
+        return compiled[name]
+
+    return whole, program
+
+
+@pytest.mark.parametrize(
+    "program",
+    ["prefill_chunk_2048_of_8192", "prefill_chunk_2048_of_16384",
+     "prefill_chunk_2048_of_32768", "prefill_chunk_2048_of_65536", "decode"],
+)
+def test_glm5_next_program_moves_no_pool_or_stack_and_fits(
+    glm5_next_programs, program
+):
+    """Through the same `llm/hybrid_kv.py` with the letters `K` and `L`
+    and four residual streams: the donated cache updated in place (the
+    latent and index pools, the matrix state a slot), the 36 held
+    experts' stacks read where they lie, a chunk's attention following
+    the selection (no score over the table in HBM, at 65,536 keys
+    either), and the temporaries beside the WHOLE configuration's
+    arguments under what a v5e offers a program."""
+    from benchmarks.models import glm5_next as family
+
+    conf, compiled_program = glm5_next_programs
+    eng = conf["engine"]
+    d, f = conf["hidden_size"], conf["moe_intermediate_size"]
+    held, rank = conf["n_routed_experts"], conf["kv_lora_rank"]
+    pages = eng["num_pages"] + 1
+    shapes = {
+        "latent": ((PAGE, rank), pages * PAGE * rank),
+        "state": ((64, 128, 128), eng["max_batch"] * 64 * 128 * 128),
+        "w_up": ((d, f), held * d * f),
+        "w_down": ((f, d), held * d * f),
+    }
+    compiled = compiled_program(program)
+    text = compiled.as_text()
+    assert _hybrid_moves(text, shapes) == []
+    memory = compiled.memory_analysis()
+    chunk = eng["prefill_chunk"]
+    if program == "decode":
+        assert "jit(kda_state_step)" in text
+        assert len(_expert_kernel_calls(text)) == 1  # the one sparse FFN
+        assert memory.temp_size_in_bytes < 2**30
+    else:
+        table = int(program.rsplit("_", 1)[1])
+        assert len(_grouped_kernel_calls(text)) == 2  # the one sparse FFN
+        # No score of the attention over the table in HBM: the indexer's
+        # [chunk, blocks] float32 is the one array as wide as the context.
+        # (A bare [2048, 16384] is the heads' width, 64 x 256.)
+        heads = conf["num_attention_heads"]
+        for keys in (table, table // 4):
+            assert f"[{heads},{chunk},{keys}]" not in text
+            assert f"[{chunk},{heads},{keys}]" not in text
+        assert f"f32[{chunk},{table // 4}]" in text
+        assert f"s32[{chunk},512]" in text  # the selection
+        assert memory.temp_size_in_bytes < 3 * 2**30
+    arguments = conf["fit"]["argument_bytes"]
+    counted = (
+        family.held_parameters(conf) * 2
+        + pages * PAGE * (rank + conf["index_head_dim"] // 4) * 2
+        + family.kda_layers(conf) * eng["max_batch"] * (
+            family.kda_state_bytes_per_slot(conf) + 3 * 24576 * 2)
+    )
+    # The float32 leaves (routers, norms, the residual mixing's P) are
+    # 15.7 MB more, the indexer's tails 25 KB.
+    assert abs(arguments - counted) < 3.2e7
+    assert arguments > 0.25 * 16 * 2**30  # the floor a new cell is held to
+    assert arguments + memory.temp_size_in_bytes < 15.75 * 2**30

@@ -30,7 +30,8 @@ activation is ``X`` ``[n, d]`` a token, ``n = hc_mult`` streams:
   Output ``W_o (RMSNorm_head(o) * sigmoid(W_gb (W_ga h)))``. One decay a
   head (``models/qwen3_next.py``'s rule) factors out of a chunk's ``Q
   K^T``; 128 a head do not, so the decays go INSIDE the products
-  (`_kda_rule`).
+  (`_kda_rule`; on a TPU ``ops/pallas/kda_chunk.py``, the same
+  arithmetic with nothing of it in HBM).
 - ``L``, **a sparse latent mixer** (DeepSeek Sparse Attention over
   multi-head latent attention without a rotary part; `dsa_prefill`,
   `dsa_decode`): ``cq = RMSNorm(h W_qa)``, ``q_j = cq W_qb``; ``c =
@@ -83,6 +84,7 @@ from typing import ClassVar
 import jax
 import jax.numpy as jnp
 
+from ray_tpu._private import chip
 from ray_tpu.models import granite_hybrid, laguna
 from ray_tpu.models.nemotron_h import (
     NemotronHConfig,
@@ -92,6 +94,7 @@ from ray_tpu.models.nemotron_h import (
 )
 from ray_tpu.models.qwen3_next import _unit, _unit_lower_inverse
 from ray_tpu.ops.norms import rms_norm
+from ray_tpu.ops.pallas.kda_chunk import kda_chunk_rule
 from ray_tpu.ops.pallas.state_step import kda_state_step
 
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -578,7 +581,12 @@ def kda_chunked(u, p, cfg: Glm5NextConfig, state0, conv0, length):
     state0 [H, dk, dv] float32 and conv0 [K - 1, conv_dim] the state
     before u[0]; ``length`` (traced) how many of the T tokens are real.
     Returns (out [T, d], the state and the convolution tail after token
-    ``length - 1``). Positions from ``length`` on take no step."""
+    ``length - 1``). Positions from ``length`` on take no step.
+
+    On a TPU the rule, between the gates and ``o``, is one call of
+    ``ops/pallas/kda_chunk.py`` (PR 60); elsewhere `_kda_rule`, XLA's
+    form, which is tier 1's path and the kernel's oracle. The platform
+    decides, as it does for `qwen3_next.gdn_chunked`."""
     t = u.shape[0]
     size = min(cfg.kda_chunk, 1 << (t - 1).bit_length())
     sub = min(_KDA_SUBCHUNK, size)
@@ -597,7 +605,14 @@ def kda_chunked(u, p, cfg: Glm5NextConfig, state0, conv0, length):
         live = jnp.arange(t) < length
         beta = jnp.where(live[:, None], beta, 0.0)
         g = jnp.where(live[:, None, None], g, 0.0)
-        o, end = _kda_rule(q, k, v, beta, g, state0, size, sub)
+        # Chosen by the platform alone, as `moe_ffn` chooses its kernels.
+        if chip.platform() == "tpu":
+            o, end = kda_chunk_rule(
+                q, k, v, beta, g, state0, length, chunk=size, sub=sub
+            )
+            o = o.reshape(v.shape)
+        else:
+            o, end = _kda_rule(q, k, v, beta, g, state0, size, sub)
     out = _kda_out(o, gate, p, cfg)
     return out, end, conv_end.astype(conv0.dtype)
 

@@ -324,6 +324,13 @@ def _kernel(chunk, group, groups, heads, rep, dk, dv, length_ref, q_ref,
                 jnp.concatenate(news[i], axis=0),
             )
 
+    _live_groups(s, groups, group, length, o_ref, one_group)
+
+
+def _live_groups(s, groups, group, length, o_ref, one_group):
+    """``one_group(rows)`` for each of token block ``s``'s groups that
+    holds a token before ``length``; zeros leave for the others' rows of
+    ``o_ref``, so that they stay finite."""
     for gi in range(groups):
         at = pl.ds(gi * group, group)
         first = (s * groups + gi) * group  # the group's first token
@@ -335,6 +342,13 @@ def _kernel(chunk, group, groups, heads, rep, dk, dv, length_ref, q_ref,
         @pl.when(first >= length)
         def _dead(at=at):
             o_ref[at, :] = jnp.zeros((group, o_ref.shape[1]), o_ref.dtype)
+
+
+def _live_block(s, length, block):
+    """Token block ``s`` for an index map, or, past the last block that
+    holds a token before ``length`` (the scalar-prefetch ref), that block
+    again: nothing is copied for a block named twice in a row."""
+    return jnp.minimum(s, jnp.maximum(length[0] - 1, 0) // block)
 
 
 def _blocking(t: int, chunk: int) -> tuple[int, int, int]:
@@ -384,8 +398,7 @@ def gdn_chunk_rule(
     )
 
     def tokens(s, length):
-        # Past the last live block: that block again, so no copy.
-        return jnp.minimum(s, jnp.maximum(length[0] - 1, 0) // block)
+        return _live_block(s, length, block)
 
     def token_block(width):
         return pl.BlockSpec(

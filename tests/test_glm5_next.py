@@ -461,7 +461,8 @@ def test_the_other_families_programs_hold_none_of_it(family):
     index pool, no third recurrence, no scope of the residual mixing and
     no clamp; this family's have each. (That their lowered text is the
     parent commit's, source lines apart, was compared once when this
-    came: CHANGES.md, PR 59.)"""
+    came and again when the rule became a kernel: CHANGES.md, PRs 59
+    and 60.)"""
     from ray_tpu.models import granite_hybrid, laguna, nemotron_h, qwen3_next
 
     cfg, init = {

@@ -33,8 +33,9 @@ layer and one window layer with the whole configuration's pages and
 slots, at all three table widths: a window layer's part is the same in
 each. For GLM-5.3-Flash (models/glm5_next.py): the state kernel's third
 body at its stack, the two expert kernels with the clamp, the
-sub-chunked per-channel rule, the exact top-k and the row gather of
-selected cells (all XLA) at the served shapes, and glm53flash-serve1's
+chunked per-channel rule's kernel (ops/pallas/kda_chunk.py) and XLA's
+form of it, the exact top-k and the row gather of selected cells (both
+XLA) at the served shapes, and glm53flash-serve1's
 own programs at its KDA + dense and sparse-attention + expert layers
 with the whole configuration's pages and slots, at all four table
 widths: no score over the table, at 65,536 keys either.
@@ -1323,13 +1324,48 @@ def test_expert_kernels_compile_for_v5e_with_the_clamp(v5e, call):
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20
 
 
+def test_kda_chunk_kernel_compiles_for_v5e_at_the_served_shape(v5e):
+    """glm53flash-serve1's prefill chunk: 2,048 tokens, 64 heads of 128
+    x 128, rule chunks of 32 in sub-chunks of 16. The running sum down
+    the rows, a sub-chunk's middle row spread over it, the two score
+    products under their masks, the masked inverse by halves and the
+    state transposed in and out lower for the chip, inside the VMEM the
+    call asks for; none of XLA's `[n, H, C, dk]` intermediates is made
+    beside the arguments. The operands are handed over as the program's
+    fusions leave them, `[T, H x dk]` (a `[T, H, dk]` ARGUMENT of a
+    program lies in other tiles and would be laid out again first)."""
+    from ray_tpu.ops.pallas import kda_chunk
+
+    t, h, dk, dv = 2048, 64, 128, 128
+
+    def on_chip(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    def rule(q, k, v, beta, g, state0, length):
+        return kda_chunk.kda_chunk_rule(
+            q.reshape(t, h, dk), k.reshape(t, h, dk), v.reshape(t, h, dv),
+            beta, g.reshape(t, h, dk), state0, length, chunk=32, sub=16,
+        )
+
+    compiled = jax.jit(rule).lower(
+        on_chip(t, h * dk), on_chip(t, h * dk), on_chip(t, h * dv),
+        on_chip(t, h), on_chip(t, h * dk), on_chip(h, dk, dv),
+        on_chip(dtype=jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert len(_kernel_calls_under(text, "")) == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**20
+
+
 def test_the_sub_chunked_rule_and_the_selection_lower_for_v5e(v5e):
-    """What no kernel computes, at the served shapes: KDA's chunked rule
-    over 2,048 tokens of 64 heads (chunk 64 in sub-chunks of 16: the
-    decays inside the products, the triangular inverse, the scan over
-    chunks), the exact top-k of 512 of 16,384 blocks for 2,048 queries,
-    and the gather of a query block's selected cells, a row a position,
-    from a 64k context's cells."""
+    """What no kernel computes in a program, at the served shapes: the
+    exact top-k of 512 of 16,384 blocks for 2,048 queries and the gather
+    of a query block's selected cells, a row a position, from a 64k
+    context's cells; and XLA's form of KDA's chunked rule over 2,048
+    tokens of 64 heads (chunk 64 in sub-chunks of 16: the decays inside
+    the products, the triangular inverse, the scan over chunks), which
+    since PR 60 no program on a TPU holds and `scripts/glm5_next_layer.py`
+    still times there beside the kernel."""
     from ray_tpu.models import glm5_next
 
     def on_chip(*shape, dtype=jnp.float32):
@@ -1435,6 +1471,17 @@ def test_glm5_next_program_moves_no_pool_or_stack_and_fits(
     else:
         table = int(program.rsplit("_", 1)[1])
         assert len(_grouped_kernel_calls(text)) == 2  # the one sparse FFN
+        # The per-channel delta rule is ONE kernel call a KDA layer under
+        # its scope: no scan over the rule chunks, none of its float32
+        # intermediates a rule chunk ([64 chunks, 64 heads, 32, ..] and
+        # the columns' [.., 2 sub-chunks, 32, 128]) in HBM.
+        assert len(_kernel_calls_under(text, "kda:scan")) == 1
+        assert "jit(kda_chunk_rule)" in text
+        assert not [
+            line for line in text.splitlines()
+            if "kda:scan" in line and " while(" in line
+        ]
+        assert not re.search(r"f32\[64,64,(2,)?(16|32),\d+\]", text)
         # No score of the attention over the table in HBM: the indexer's
         # [chunk, blocks] float32 is the one array as wide as the context.
         # (A bare [2048, 16384] is the heads' width, 64 x 256.)

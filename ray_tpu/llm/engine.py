@@ -332,7 +332,7 @@ class LLMEngine:
         # (token, expert) pairs a token is routed to over the model's
         # expert blocks, where its programs keep a record of them.
         self._pairs_per_token = serving.pairs_per_token
-        self._moe_counts: list = []  # (phase, device int32[4]), unfolded
+        self._moe_counts: list = []  # (phase, device int32[4 or 6]), unfolded
         # The chain of keys, ``key, sub = split(key)`` a decode step:
         # its head, and the links made ahead (`_key_block`).
         self._step_key = jax.random.key(seed)
@@ -443,13 +443,19 @@ class LLMEngine:
             # routed to, the pairs among them whose expert is held here,
             # and held experts that got a row, summed over expert blocks
             # and decode steps; the rows the sorted expert form ran its
-            # grouped matmuls over, and the pair rows it was given.
+            # grouped matmuls over, and the pair rows it was given; the
+            # routes among `moe_pairs_routed` that went to an identity
+            # output of the router, which no chip computes as a pair.
             "moe_pairs_routed": 0,
             "moe_pairs_here": 0,
             "experts_touched": 0,
             "moe_rows_computed": 0,
             "moe_rows_sorted": 0,
+            "moe_zero_pairs": 0,
         }
+        # The most real experts a live token chose in any expert block
+        # (a router with identity outputs; else `top_k`, always).
+        self._real_experts_max = 0
 
     # ------------------------------------------------------ request API
     @contextmanager
@@ -836,7 +842,12 @@ class LLMEngine:
     def _fold_moe_counts(self) -> None:
         counts, self._moe_counts = self._moe_counts, []
         for phase, row in counts:
-            here, touched, computed, given = (int(v) for v in np.asarray(row))
+            here, touched, computed, given, *zero = (
+                int(v) for v in np.asarray(row)
+            )
+            if zero:
+                self._stats["moe_zero_pairs"] += zero[0]
+                self._real_experts_max = max(self._real_experts_max, zero[1])
             self._stats["moe_pairs_here"] += here
             self._stats["moe_rows_computed"] += computed
             self._stats["moe_rows_sorted"] += given
@@ -1292,8 +1303,11 @@ class LLMEngine:
         `kv_write_kernel`), for a model with expert layers whether their
         sorted form sums its rows by the kernel (`moe_combine_kernel`),
         for a model with recurrent blocks whether a decode step updates
-        their state by the kernel (`state_step_kernel`), and the
-        pool/slot occupancy."""
+        their state by the kernel (`state_step_kernel`), for a model
+        whose router has identity outputs the share of routes that went
+        to one and the real experts a token has left
+        (`zero_expert_pairs_pct`, `real_experts_per_token_mean` /
+        `_max`), and the pool/slot occupancy."""
         with self._lock:
             self._fold_moe_counts()
             # The model's own counters (its cache's, where it keeps any)
@@ -1318,6 +1332,15 @@ class LLMEngine:
                 100.0 * out["moe_rows_computed"] / out["moe_rows_sorted"]
                 if out["moe_rows_sorted"] else 0.0
             )
+            if self.serving.zero_experts and out["moe_pairs_routed"]:
+                # How many of a token's routes cost nothing, and how many
+                # experts it has left: per live token and expert block.
+                share = out["moe_zero_pairs"] / out["moe_pairs_routed"]
+                out["zero_expert_pairs_pct"] = 100.0 * share
+                out["real_experts_per_token_mean"] = (
+                    self.cfg.top_k * (1.0 - share)
+                )
+                out["real_experts_per_token_max"] = self._real_experts_max
             out["platform"] = self.platform
             out["device_kind"] = jax.devices()[0].device_kind
             out["paged_attn_kernel"] = self.paged_attn_kernel

@@ -289,7 +289,8 @@ def _new_record():
     """What a program's expert blocks leave: `_note` fills, `_record`
     sums."""
     return {"routes": [], "pairs_here": [], "experts_touched": [],
-            "sorted_rows": [], "selected": []}
+            "sorted_rows": [], "selected": [], "zero_pairs": [],
+            "real_max": []}
 
 
 def _note(record, aux):
@@ -298,6 +299,9 @@ def _note(record, aux):
     record["pairs_here"].append(aux["expert_load"].sum())
     record["experts_touched"].append((aux["expert_load"] > 0).sum())
     record["sorted_rows"].append(aux["sorted_rows"])
+    if "zero_pairs" in aux:  # a router with identity outputs
+        record["zero_pairs"].append(aux["zero_pairs"])
+        record["real_max"].append(aux["real_max"])
 
 
 def _record(record):
@@ -306,7 +310,14 @@ def _record(record):
     whose expert is held, the held experts that got a row, the rows the
     sorted form ran its grouped matmuls over and the pairs it was given
     (padding's and absent experts' among them), each summed over the
-    expert blocks."""
+    expert blocks; int32[6] where the router has identity outputs: the
+    live rows' routes to one, summed, and the most real experts a live
+    row chose in any block."""
+    zero = (
+        [jnp.stack([sum(record["zero_pairs"]),
+                    jnp.stack(record["real_max"]).max()])]
+        if record["zero_pairs"] else []
+    )
     selected = (
         # [L_latent, T, index_blocks]: the blocks each query attended
         # beside its own (-1: fewer candidates), where the model selects.
@@ -321,6 +332,7 @@ def _record(record):
                 [sum(record["pairs_here"]), sum(record["experts_touched"])]
             ),
             sum(record["sorted_rows"]),
+            *zero,
         ]).astype(jnp.int32),
     }
 
@@ -797,6 +809,7 @@ class HybridServing:
     def __init__(self, cfg: NemotronHConfig, init_weights=init_params):
         self.cfg = cfg
         self.pairs_per_token = cfg.top_k * cfg.count("E")
+        self.zero_experts = cfg.zero_experts
         self.recurrent_blocks = sum(cfg.count(kind) for kind in _RECURRENT)
         self._init_weights = init_weights
         self._prefill_programs = self._live_tokens = self._prefill_pairs = 0

@@ -1,9 +1,12 @@
 """Serving programs for a model with multi-head latent attention
-(``models/pangu_ultra_moe.py``): ONE latent page pool, written and read
-in two forms of the same function.
+(``models/pangu_ultra_moe.py``, ``models/longcat_flash.py``): ONE latent
+page pool, written and read in two forms of the same function.
 
-The cache is one donated array, ``latent [L, num_pages, P, W]`` in
-``cfg.dtype``: per token and layer the row ``[Nkv(c); rope(kpe)]``,
+The cache is one donated array, ``latent [A, num_pages, P, W]`` in
+``cfg.dtype``, ``A`` the model's attention sublayers (one a layer for
+Pangu, two for LongCat-Flash's double layer, whose sublayer ``j`` of
+layer ``i`` has row ``2 i + j``: two cells a token a layer): per token
+and attention sublayer the row ``[Nkv(c); rope(kpe)]``,
 ``kv_lora_rank + qk_rope_head_dim`` numbers (576: 1,152 bytes in bf16)
 whatever the number of heads, where per-head keys and values would be
 2 x 128 x 128; held W = 640 wide, zeros behind the 576, which is what a
@@ -11,9 +14,16 @@ TPU's tiles of 128 lanes make of a row of 576 in any case
 (``cfg.cell_width``). Pages, block tables, prefix hashes, preemption and the
 step in flight are `LLMEngine`'s as they are: pages hold all of this
 model's per-sequence state. The pool is carried through the Python loop
-over the layers in a flat view (``[L * num_pages, P, W]``, a bitcast;
-each layer adds its page base to the ids) and updated in place: one
-buffer in one layout from a program's argument to its result.
+over the layers in a flat view (``[A * num_pages, P, W]``, a bitcast;
+each attention sublayer adds its page base to the ids) and updated in
+place: one buffer in one layout from a program's argument to its result.
+
+A program walks the pattern's kinds through one attention body (its
+closure `attention`, which takes the pool's next row each time it is
+called) and `_layer`, which says where a kind calls it: ``D`` / ``E``
+once, before the FFN behind its two sandwich norms; ``S`` twice, around
+the first dense FFN, with the expert layer read after the first and
+added after the second dense FFN.
 
 **Decode is the absorbed form** (``latent_decode``): ``qa_h = q_nope_h
 Wuk_h^T`` makes each head's query 512 + 64 wide, the scores are ``qa_h .
@@ -47,6 +57,7 @@ shape (``fixed_chunks``).
 from __future__ import annotations
 
 import functools
+import itertools
 from functools import partial
 
 import jax
@@ -58,9 +69,8 @@ from ray_tpu.llm.hybrid_kv import _head, _new_record, _note, _record
 from ray_tpu.llm.paged_kv import _NEG_INF, _decode_geometry, _sample_tokens
 from ray_tpu.models.moe import moe_ffn
 from ray_tpu.models.pangu_ultra_moe import (
-    PanguUltraMoEConfig,
+    LatentShape,
     dense_mlp,
-    init_params,
     pad_to_cell,
     project_latent,
     project_q,
@@ -68,7 +78,7 @@ from ray_tpu.models.pangu_ultra_moe import (
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.rope import rope_frequencies
 
-LatentCache = dict[str, jnp.ndarray]  # {"latent": [L, num_pages, P, W]}
+LatentCache = dict[str, jnp.ndarray]  # {"latent": [A, num_pages, P, W]}
 
 # Finite mask and initial maximum of the running soft-max, as the
 # kernels': exp(x - m) underflows to exactly 0.
@@ -77,18 +87,20 @@ _M_INIT = -1e30
 
 
 def init_latent_cache(
-    cfg: PanguUltraMoEConfig, num_pages: int, page_size: int
+    cfg: LatentShape, num_pages: int, page_size: int
 ) -> LatentCache:
     return {
         "latent": jnp.zeros(
-            (cfg.n_layers, num_pages, page_size, cfg.cell_width), cfg.dtype
+            (cfg.attn_sublayers, num_pages, page_size, cfg.cell_width),
+            cfg.dtype,
         )
     }
 
 
 def _flat(cache: LatentCache) -> jnp.ndarray:
-    """The pool's pages in a flat view ``[L * num_pages, P, W]`` (a
-    bitcast): layer ``i``'s pages start at ``i * num_pages``."""
+    """The pool's pages in a flat view ``[A * num_pages, P, W]`` (a
+    bitcast): attention sublayer ``a``'s pages start at ``a *
+    num_pages``."""
     pool = cache["latent"]
     return pool.reshape((-1,) + pool.shape[2:])
 
@@ -107,10 +119,32 @@ def _ffn(x, kind, p, cfg, rows_live, record):
 
 def _attn_out(x, heads, p):
     """Heads' outputs [B, S, H, v] through ``Wo`` and the sublayer's
-    output norm onto the residual stream."""
+    output norm, where it has one, onto the residual stream."""
     with jax.named_scope("mla:out"):
         out = heads.reshape(*x.shape[:2], -1) @ p["wo"]
-    return x + rms_norm(out, p["norm2"])
+    if "norm2" in p:
+        out = rms_norm(out, p["norm2"])
+    return x + out
+
+
+def _layer(x, kind, p, cfg, attention, rows_live, record):
+    """One layer of ``kind`` on x [B, S, d]. ``attention(x, p)`` is the
+    program's attention sublayer on the residual stream, with the tree
+    of the sublayer's own leaves; ``rows_live()`` makes the mask of the
+    rows that carry a token where the layer's experts are called (a
+    program's text then has it where it always had)."""
+    if kind != "S":
+        return _ffn(attention(x, p), kind, p, cfg, rows_live(), record)
+    first, second = p["ffn"]
+    x = attention(x, p["attn"][0])
+    u = rms_norm(x, first["norm"])
+    # The shortcut: the experts read the first dense FFN's input and
+    # their output joins the stream behind the second.
+    shortcut, aux = moe_ffn(u, p["moe"], cfg, rows_live=rows_live())
+    _note(record, aux)
+    x = x + dense_mlp(u, first)
+    x = attention(x, p["attn"][1])
+    return x + dense_mlp(rms_norm(x, second["norm"]), second) + shortcut
 
 
 def _gather_latent_attention(q, pool, page_index, mask, cfg):
@@ -227,7 +261,7 @@ def _latent_prefill(
     pages: jnp.ndarray,  # [n_write_pages] int32: the FULL context table
     start: jnp.ndarray,  # [] int32: position of tokens[0, 0], page-aligned
     length: jnp.ndarray,  # [] int32: the context's true length
-    cfg: PanguUltraMoEConfig,
+    cfg: LatentShape,
     n_write_pages: int,
     chunk_pages: int,
     use_kernel: bool,
@@ -248,8 +282,12 @@ def _latent_prefill(
     pool = _flat(cache)
     x = params["tok_emb"][tokens]
     record = _new_record()
-    for i, (kind, p) in enumerate(zip(cfg.pattern, params["blocks"], strict=True)):
-        base = i * num_pages
+    rows = itertools.count()  # of the pool, one an attention sublayer
+    attend = _attend_expanded_kernel if use_kernel else _attend_expanded
+
+    def attention(x, p):
+        nonlocal pool
+        base = next(rows) * num_pages
         h = rms_norm(x, p["norm1"])
         q_nope, q_pe = project_q(h, p, cfg, cos, sin, pos)
         with jax.named_scope("mla:latent"):
@@ -257,17 +295,18 @@ def _latent_prefill(
             pool = pool.at[base + chunk_slice].set(
                 cells.reshape(chunk_pages, page_size, -1)
             )
-        attend = _attend_expanded_kernel if use_kernel else _attend_expanded
         heads = attend(q_nope[0], q_pe[0], pool, base + pages, start, p, cfg)
-        x = _attn_out(x, heads[None], p)
-        x = _ffn(x, kind, p, cfg, live, record)
+        return _attn_out(x, heads[None], p)
+
+    for kind, p in zip(cfg.pattern, params["blocks"], strict=True):
+        x = _layer(x, kind, p, cfg, attention, lambda: live, record)
     last = jax.lax.dynamic_slice_in_dim(x, length - 1 - start, 1, axis=1)
     cache = {"latent": pool.reshape(cache["latent"].shape)}
     return _head(last, params), cache, _record(record)
 
 
 @functools.lru_cache(maxsize=None)
-def prefill_program(cfg: PanguUltraMoEConfig, n_write_pages: int,
+def prefill_program(cfg: LatentShape, n_write_pages: int,
                     chunk_pages: int, use_kernel: bool = False):
     """`_latent_prefill` jitted for one shape, under a name that says
     which (``latent_prefill_<chunk pages>_of_<table pages>``): a trace
@@ -297,7 +336,7 @@ def latent_decode(
     active: jnp.ndarray,  # [B] bool: the slots that are decoding
     temperature: jnp.ndarray,  # [B] fp32 (0 = greedy)
     rng_key: jnp.ndarray,
-    cfg: PanguUltraMoEConfig,
+    cfg: LatentShape,
     use_kernel: bool = False,
 ):
     """The decode program: one token a slot in the absorbed form, sampled
@@ -317,8 +356,11 @@ def latent_decode(
     pool = _flat(cache)
     x = params["tok_emb"][tokens]  # [B, K, d]
     record = _new_record()
-    for i, (kind, p) in enumerate(zip(cfg.pattern, params["blocks"], strict=True)):
-        base = i * num_pages
+    rows = itertools.count()  # of the pool, one an attention sublayer
+
+    def attention(x, p):
+        nonlocal pool
+        base = next(rows) * num_pages
         h = rms_norm(x, p["norm1"])
         q_nope, q_pe = project_q(h, p, cfg, cos, sin, pos2d)
         with jax.named_scope("mla:latent"):
@@ -344,8 +386,12 @@ def latent_decode(
                 )
         with jax.named_scope("mla:absorb"):
             heads = jnp.einsum("bkhc,hcd->bkhd", weighted, p["w_uv"])
-        x = _attn_out(x, heads, p)
-        x = _ffn(x, kind, p, cfg, jnp.repeat(active, kk), record)
+        return _attn_out(x, heads, p)
+
+    for kind, p in zip(cfg.pattern, params["blocks"], strict=True):
+        x = _layer(
+            x, kind, p, cfg, attention, lambda: jnp.repeat(active, kk), record
+        )
     logits = _head(x, params)  # [B, K, V]
     sampled = _sample_tokens(logits, temperature, rng_key)
     cache = {"latent": pool.reshape(cache["latent"].shape)}
@@ -353,8 +399,9 @@ def latent_decode(
 
 
 class LatentServing:
-    """What `LLMEngine` serves a `PanguUltraMoEConfig` through (see
-    `paged_kv.LlamaServing` for the convention)."""
+    """What `LLMEngine` serves a `PanguUltraMoEConfig` or a
+    `LongcatFlashConfig` through (see `paged_kv.LlamaServing` for the
+    convention); ``init_weights`` is the family's initialiser."""
 
     no_speculation = (
         "the latent decode program takes one token a slot: draft "
@@ -364,13 +411,15 @@ class LatentServing:
     fixed_chunks = True  # the program takes the true length
     recurrent_blocks = 0  # no per-slot state beside the pages
 
-    def __init__(self, cfg: PanguUltraMoEConfig):
+    def __init__(self, cfg: LatentShape, init_weights):
         self.cfg = cfg
-        self.pairs_per_token = cfg.top_k * cfg.count("E")
+        self._init_weights = init_weights
+        self.pairs_per_token = cfg.top_k * (cfg.count("E") + cfg.count("S"))
+        self.zero_experts = cfg.zero_experts
         self._tokens_expanded = self._prefill_programs = self._prefill_pairs = 0
 
     def init_weights(self, key):
-        return init_params(key, self.cfg)
+        return self._init_weights(key, self.cfg)
 
     def logical_axes(self):
         raise NotImplementedError(
@@ -392,13 +441,15 @@ class LatentServing:
     def counters(self) -> dict:
         cfg = self.cfg
         return {
-            # What the arithmetic needs of a cached token, all layers
-            # (`pool_bytes` has the cells as held, `cell_width` wide).
-            "latent_bytes_per_token": cfg.n_layers * cfg.latent_dim
+            # What the arithmetic needs of a cached token, all attention
+            # sublayers (`pool_bytes` has the cells as held,
+            # `cell_width` wide).
+            "latent_bytes_per_token": cfg.attn_sublayers * cfg.latent_dim
             * jnp.dtype(cfg.dtype).itemsize,
             "latent_tokens_expanded": self._tokens_expanded,
             # Prefill programs run, and the (query, key) pairs they
-            # attended, summed over layers (heads not among them).
+            # attended, summed over attention sublayers (heads not
+            # among them).
             "latent_prefill_programs": self._prefill_programs,
             "latent_prefill_pairs": self._prefill_pairs,
         }
@@ -409,7 +460,7 @@ class LatentServing:
         it attends under the causal mask, and the cached tokens it turns
         back into keys and values (the kernel path: the whole table; the
         XLA path: whole key blocks up to the chunk's end), in every
-        layer."""
+        attention sublayer."""
         cfg, c = self.cfg, tokens.shape[1]
         page_size = cache["latent"].shape[2]
         if use_kernel:
@@ -417,9 +468,9 @@ class LatentServing:
         else:
             block = max(cfg.prefill_key_block // page_size, 1) * page_size
             expanded = -(-(int(start) + c) // block) * block
-        self._tokens_expanded += cfg.n_layers * expanded
+        self._tokens_expanded += cfg.attn_sublayers * expanded
         self._prefill_programs += 1
-        self._prefill_pairs += cfg.n_layers * (
+        self._prefill_pairs += cfg.attn_sublayers * (
             c * int(start) + c * (c + 1) // 2
         )
         return prefill_program(cfg, n_write_pages, chunk_pages, use_kernel)(

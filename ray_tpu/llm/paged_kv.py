@@ -705,6 +705,7 @@ class LlamaServing:
     logits_last_only = False  # prefill returns every position's logits
     fixed_chunks = False  # the last chunk of a prompt is as long as it is
     pairs_per_token = 0  # no expert blocks: its programs keep no record
+    zero_experts = 0  # nor a router with identity outputs
     recurrent_blocks = 0  # no per-slot state beside the pages
 
     def __init__(self, cfg: LlamaConfig):

@@ -73,6 +73,12 @@ class MoEConfig(LlamaConfig):
     # * clip(h W_up, -l, l)``. None, here and in every family but
     # `models/glm5_next.py`: no clamp, and nothing of it in a program.
     swiglu_limit: float | None = None
+    # Router outputs behind the ``num_experts`` real ones that are
+    # identity experts (``zero_expert_num`` of a published config,
+    # ``zero_expert_type: identity``): a route to one adds ``gate * x``
+    # and costs no matmul. 0, here and in every family but
+    # `models/longcat_flash.py`: nothing of them in a program.
+    zero_experts: int = 0
     # (first, count) of the experts whose weights are held here, where
     # that is a share of `num_experts` (expert parallelism: the router
     # stays `num_experts` wide); None where all are.
@@ -390,8 +396,8 @@ def _experts_on_pairs_here(tokens, p, cfg, routes, gates, here):
         )
         if on_tpu:
             # The tile follows the rows an expert gets if the router
-            # spreads the pairs evenly.
-            mean = n * k // cfg.num_experts
+            # spreads the pairs evenly over its outputs.
+            mean = n * k // (cfg.num_experts + cfg.zero_experts)
             gated = cfg.expert_kind == "swiglu"
             hidden = grouped_rows(
                 rows_out.reshape(-1, d),
@@ -418,7 +424,13 @@ def moe_ffn(x: jnp.ndarray, p: Params, cfg, rows_live=None):
     """FFN hook for llama._block: x [B, S, d] -> (out, aux).
 
     ``cfg`` is a ``MoEConfig`` or any config with its expert-layer
-    fields. Routing is over all ``cfg.num_experts``. Where
+    fields. Routing is over all ``cfg.num_experts`` and, behind them,
+    ``cfg.zero_experts`` identity outputs: a route to one of those is
+    never a pair (it is in no group, no load and no kernel's work
+    list); a row's gates for them are summed and multiply the row
+    itself (``moe:zero``, inside ``moe:combine``). A softmax router
+    whose tree has a ``router_bias`` chooses by ``probs + bias`` and
+    gates by ``probs``, as the sigmoid kind always does. Where
     ``cfg.experts_held`` names a share, only pairs whose expert is held
     are computed: the others are left out of the sort's groups and add
     nothing, so the result is this share's part of the layer (plus the
@@ -434,9 +446,12 @@ def moe_ffn(x: jnp.ndarray, p: Params, cfg, rows_live=None):
     ``routes`` (the experts of each token, int32[T, k]) and, where only
     some pairs are computed here, ``sorted_rows`` (int32[2]: the rows
     the sorted form ran its grouped matmuls over and the ``T x k`` pairs
-    it was given; zeros where the every-row form ran)."""
+    it was given; zeros where the every-row form ran) and, where the
+    router has identity outputs, ``zero_pairs`` (the routes of live rows
+    that went to one, int32[]) and ``real_max`` (the most real experts
+    any live row chose, int32[])."""
     b, s, d = x.shape
-    e, k = cfg.num_experts, cfg.top_k
+    e, k, zero = cfg.num_experts, cfg.top_k, cfg.zero_experts
     n = b * s
     dt = cfg.dtype
     tokens = x.reshape(n, d)
@@ -449,21 +464,24 @@ def moe_ffn(x: jnp.ndarray, p: Params, cfg, rows_live=None):
             precision=jax.lax.Precision.HIGHEST,
         )
         if cfg.router_kind == "softmax":
-            probs = jax.nn.softmax(logits, axis=-1)  # [n, e]
-            gates, routes = jax.lax.top_k(probs, k)  # [n, k]
+            probs = jax.nn.softmax(logits, axis=-1)  # [n, e + zero]
         else:
             probs = jax.nn.sigmoid(logits)
+        if "router_bias" in p:  # for the choice alone
             _, routes = jax.lax.top_k(probs + p["router_bias"], k)
             gates = jnp.take_along_axis(probs, routes, axis=-1)
+        else:
+            gates, routes = jax.lax.top_k(probs, k)  # [n, k]
         if cfg.norm_topk_prob:
             gates = gates / gates.sum(-1, keepdims=True)
         if cfg.routed_scaling_factor != 1.0:
             gates = gates * cfg.routed_scaling_factor
 
-    # Which experts are computed here, and for which rows.
+    # Which experts are computed here, and for which rows: an identity
+    # output (a route at or past `e`) is no expert's and is held nowhere.
     first, e_here = cfg.experts_held or (0, e)
     here = None
-    if cfg.experts_held is not None or rows_live is not None:
+    if cfg.experts_held is not None or rows_live is not None or zero:
         local = routes - first
         here = (local >= 0) & (local < e_here)  # [n, k]
         if rows_live is not None:
@@ -499,6 +517,19 @@ def moe_ffn(x: jnp.ndarray, p: Params, cfg, rows_live=None):
             out = out + shared
 
     aux = {"expert_load": load, "routes": routes}
+    if zero:
+        with jax.named_scope("moe:combine"), jax.named_scope("moe:zero"):
+            # What every chip computes for its own rows, whichever
+            # experts it holds: nothing is sent anywhere.
+            to_zero = routes >= e  # [n, k]
+            gate = jnp.where(to_zero, gates, 0.0).sum(-1, keepdims=True)
+            out = out + (tokens.astype(jnp.float32) * gate).astype(dt)
+            real = k - to_zero.sum(-1)  # [n]: the experts a row chose
+            if rows_live is not None:
+                to_zero &= rows_live[:, None]
+                real = jnp.where(rows_live, real, 0)
+            aux["zero_pairs"] = to_zero.sum().astype(jnp.int32)
+            aux["real_max"] = real.max().astype(jnp.int32)
     if here is not None:
         aux["sorted_rows"] = jnp.stack(sorted_rows).astype(jnp.int32)
     if cfg.router_kind == "softmax":
@@ -508,7 +539,7 @@ def moe_ffn(x: jnp.ndarray, p: Params, cfg, rows_live=None):
             # mean squared logsumexp of the router's logits.
             # (Under a share: the held experts' part of that sum.)
             mean_prob = probs.mean(0)
-            if cfg.experts_held is not None:
+            if cfg.experts_held is not None or zero:
                 mean_prob = mean_prob[first: first + e_here]
             aux["balance_loss"] = e * (mean_prob * (load / n)).sum()
             aux["z_loss"] = jnp.square(

@@ -140,6 +140,9 @@ class NemotronHConfig:
     # sublayer's input from n streams and `mhc_spread` writes it back).
     swiglu_limit: float | None = None
     hc_mult: int = 0
+    # Identity outputs behind the router's experts (`moe.MoEConfig`):
+    # none in any family served through this class.
+    zero_experts: int = 0
 
     # The letters `pattern` may hold: a family with another recurrence
     # adds its own (`models/qwen3_next.py`: G).

@@ -37,7 +37,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Any
+from typing import Any, ClassVar
 
 import jax
 import jax.numpy as jnp
@@ -49,8 +49,45 @@ from ray_tpu.ops.rope import apply_rope
 Params = dict[str, Any]
 
 
+class LatentShape:
+    """What the latent programs and kernels read of a config beside its
+    fields: a cache cell's sizes, the pool's rows, the experts held.
+    (`models/longcat_flash.py`'s config is one too.)"""
+
+    @property
+    def latent_dim(self) -> int:
+        """What the cache holds of a token in one attention sublayer:
+        ``[c; kpe]``."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def cell_width(self) -> int:
+        """The width a cache cell is held at: ``latent_dim`` and zeros."""
+        return -(-self.latent_dim // self.cell_lanes) * self.cell_lanes
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def softmax_scale(self) -> float:
+        return self.qk_head_dim**-0.5
+
+    @property
+    def n_experts_held(self) -> int:
+        return self.experts_held[1] if self.experts_held else self.num_experts
+
+    @property
+    def attn_sublayers(self) -> int:
+        """Rows of the latent pool: one an attention sublayer."""
+        return self.n_layers * self.attn_per_layer
+
+    def count(self, kind: str) -> int:
+        return self.pattern.count(kind)
+
+
 @dataclasses.dataclass(frozen=True)
-class PanguUltraMoEConfig:
+class PanguUltraMoEConfig(LatentShape):
     vocab_size: int = 153600  # rows held, where the vocabulary is sliced
     d_model: int = 7680
     n_layers: int = 61
@@ -63,6 +100,11 @@ class PanguUltraMoEConfig:
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
     rope_theta: float = 25.6e6
+    # Factors on the heads' queries and on the normed key-value latent
+    # (a family that scales them by sqrt(hidden / rank):
+    # `models/longcat_flash.py`). 1.0: nothing of them in a program.
+    q_latent_scale: float = 1.0
+    kv_latent_scale: float = 1.0
     dense_d_ff: int = 18432
     # expert layers (the names `moe_ffn` reads)
     num_experts: int = 256
@@ -75,6 +117,7 @@ class PanguUltraMoEConfig:
     router_kind: str = "sigmoid"
     expert_kind: str = "swiglu"
     swiglu_limit: float | None = None  # no clamp (`moe.clamped_swiglu`)
+    zero_experts: int = 0  # no identity outputs behind the router's experts
     # Up to this many rows `moe_ffn` applies every held expert to every
     # row, above it it sorts pairs into grouped matmuls. One expert layer
     # at these widths with 16 of 256 experts held, on a v5e (my chip run,
@@ -98,6 +141,10 @@ class PanguUltraMoEConfig:
     max_seq: int = 131072
     dtype: Any = jnp.bfloat16
 
+    # Attention sublayers in a layer: each has a row of its own in the
+    # latent pool (`llm/latent_kv.py`).
+    attn_per_layer: ClassVar[int] = 1
+
     def __post_init__(self):
         if not 0 <= self.first_k_dense <= self.n_layers:
             raise ValueError("first_k_dense is not within n_layers")
@@ -111,37 +158,12 @@ class PanguUltraMoEConfig:
             self.n_layers - self.first_k_dense
         )
 
-    @property
-    def latent_dim(self) -> int:
-        """What the cache holds of a token in one layer: ``[c; kpe]``."""
-        return self.kv_lora_rank + self.qk_rope_head_dim
-
-    @property
-    def cell_width(self) -> int:
-        """The width a cache cell is held at: ``latent_dim`` and zeros."""
-        return -(-self.latent_dim // self.cell_lanes) * self.cell_lanes
-
-    @property
-    def qk_head_dim(self) -> int:
-        return self.qk_nope_head_dim + self.qk_rope_head_dim
-
-    @property
-    def softmax_scale(self) -> float:
-        return self.qk_head_dim**-0.5
-
-    @property
-    def n_experts_held(self) -> int:
-        return self.experts_held[1] if self.experts_held else self.num_experts
-
-    def count(self, kind: str) -> int:
-        return self.pattern.count(kind)
-
     def serving(self):
         """What `LLMEngine` serves this model through: its cache and its
         three programs."""
         from ray_tpu.llm.latent_kv import LatentServing
 
-        return LatentServing(self)
+        return LatentServing(self, init_params)
 
 
 PANGU_PRESETS: dict[str, PanguUltraMoEConfig] = {
@@ -159,26 +181,44 @@ PANGU_PRESETS: dict[str, PanguUltraMoEConfig] = {
 
 
 # ------------------------------------------------------------ parameters
+def _zeros(n):
+    return jnp.zeros((n,), jnp.float32)
+
+
+def init_mla(keys, cfg) -> Params:
+    """One latent-attention sublayer's leaves from six keys: its input
+    norm, the two latent norms and the six matrices. A matrix behind a
+    latent that the model scales is drawn that much smaller, so that
+    queries, keys and values have unit variance whatever the ranks:
+    the factors ``sqrt(hidden / rank)`` exist to align the variance of
+    what comes through a latent with what comes from the hidden state
+    (the rotary key), under weights of ONE deviation, ``hidden^-0.5``;
+    over weights drawn at ``rank^-0.5`` they would make the scores'
+    deviation ``q_latent_scale x kv_latent_scale`` times (6.9 times)
+    a trained model's and the soft-max nearly an arg-max."""
+    d, dt, h = cfg.d_model, cfg.dtype, cfg.n_heads
+    rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+    fan_q, fan_kv = rq * cfg.q_latent_scale**2, rkv * cfg.kv_latent_scale**2
+    return {
+        "norm1": _zeros(d), "q_norm": _zeros(rq), "kv_norm": _zeros(rkv),
+        "wq_a": _normal(keys[0], (d, rq), d, dt),
+        "wq_b": _normal(keys[1], (rq, h * cfg.qk_head_dim), fan_q, dt),
+        "wkv_a": _normal(keys[2], (d, cfg.latent_dim), d, dt),
+        "w_uk": _normal(keys[3], (h, rkv, cfg.qk_nope_head_dim), fan_kv, dt),
+        "w_uv": _normal(keys[4], (h, rkv, cfg.v_head_dim), fan_kv, dt),
+        "wo": _normal(keys[5], (h * cfg.v_head_dim, d), h * cfg.v_head_dim, dt),
+    }
+
+
 @partial(jax.jit, static_argnames=("kind", "cfg"))
 def _init_block(key, kind: str, cfg: PanguUltraMoEConfig) -> Params:
     """One layer's tree, each leaf made and rounded inside this program:
     the float32 draw of an expert stack never outlives it."""
-    d, dt, h = cfg.d_model, cfg.dtype, cfg.n_heads
-    rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+    d, dt = cfg.d_model, cfg.dtype
     keys = jax.random.split(key, 14)
-
-    def zeros(n):
-        return jnp.zeros((n,), jnp.float32)
-
     block = {
-        "norm1": zeros(d), "norm2": zeros(d), "norm3": zeros(d),
-        "norm4": zeros(d), "q_norm": zeros(rq), "kv_norm": zeros(rkv),
-        "wq_a": _normal(keys[0], (d, rq), d, dt),
-        "wq_b": _normal(keys[1], (rq, h * cfg.qk_head_dim), rq, dt),
-        "wkv_a": _normal(keys[2], (d, cfg.latent_dim), d, dt),
-        "w_uk": _normal(keys[3], (h, rkv, cfg.qk_nope_head_dim), rkv, dt),
-        "w_uv": _normal(keys[4], (h, rkv, cfg.v_head_dim), rkv, dt),
-        "wo": _normal(keys[5], (h * cfg.v_head_dim, d), h * cfg.v_head_dim, dt),
+        **init_mla(keys, cfg),
+        "norm2": _zeros(d), "norm3": _zeros(d), "norm4": _zeros(d),
     }
     if kind == "D":
         f = cfg.dense_d_ff
@@ -194,7 +234,7 @@ def _init_block(key, kind: str, cfg: PanguUltraMoEConfig) -> Params:
         router=_normal(keys[6], (d, cfg.num_experts), d, jnp.float32),
         # No selection bias is published; `moe_ffn` adds this to the
         # scores for the choice (tests use a non-zero one).
-        router_bias=zeros(cfg.num_experts),
+        router_bias=_zeros(cfg.num_experts),
         w_gate=_normal(keys[7], (held, d, f), d, dt),
         w_up=_normal(keys[8], (held, d, f), d, dt),
         w_down=_normal(keys[9], (held, f, d), f, dt),
@@ -206,7 +246,8 @@ def _init_block(key, kind: str, cfg: PanguUltraMoEConfig) -> Params:
 
 
 @partial(jax.jit, static_argnames="cfg")
-def _init_ends(key, cfg: PanguUltraMoEConfig) -> Params:
+def init_ends(key, cfg) -> Params:
+    """The embedding, the final norm and the untied head."""
     k_emb, k_head = jax.random.split(key)
     v, d = cfg.vocab_size, cfg.d_model
     return {
@@ -222,7 +263,7 @@ def init_params(key: jax.Array, cfg: PanguUltraMoEConfig) -> Params:
     """The tree as it is held: matmul weights in ``cfg.dtype``, the
     router and the norms in float32. One program per layer, so that no
     more than one layer's float32 draws exist at a time."""
-    params = _init_ends(jax.random.fold_in(key, cfg.n_layers), cfg=cfg)
+    params = init_ends(jax.random.fold_in(key, cfg.n_layers), cfg=cfg)
     params["blocks"] = tuple(
         _init_block(jax.random.fold_in(key, i), kind=kind, cfg=cfg)
         for i, kind in enumerate(cfg.pattern)
@@ -238,6 +279,8 @@ def project_q(h, p, cfg, cos, sin, positions):
         b, s, _ = h.shape
         cq = rms_norm(h @ p["wq_a"], p["q_norm"])
         q = (cq @ p["wq_b"]).reshape(b, s, cfg.n_heads, cfg.qk_head_dim)
+        if cfg.q_latent_scale != 1.0:
+            q = q * cfg.q_latent_scale
         q_nope, q_pe = jnp.split(q, [cfg.qk_nope_head_dim], axis=-1)
         return q_nope, apply_rope(q_pe, cos, sin, positions=positions)
 
@@ -252,10 +295,13 @@ def pad_to_cell(x, cfg):
 
 def project_latent(h, p, cfg, cos, sin, positions):
     """Normed input h [B, S, d] -> each token's cache cell,
-    ``[Nkv(c); rope(kpe); zeros]`` [B, S, cell_width] in ``cfg.dtype``."""
+    ``[kv_latent_scale * Nkv(c); rope(kpe); zeros]`` [B, S, cell_width]
+    in ``cfg.dtype``."""
     ckv = h @ p["wkv_a"]
     c, kpe = jnp.split(ckv, [cfg.kv_lora_rank], axis=-1)
     c = rms_norm(c, p["kv_norm"])
+    if cfg.kv_latent_scale != 1.0:
+        c = c * cfg.kv_latent_scale  # the rotary key is not scaled
     # One rotary key for all heads: a head dimension of one.
     kpe = apply_rope(kpe[:, :, None, :], cos, sin, positions=positions)
     cell = jnp.concatenate([c, kpe[:, :, 0, :]], axis=-1)

@@ -16,6 +16,13 @@ it. GLM-5.3-Flash's switches (`benchmarks/reference_glm5_next.py`):
         weights_e4m3 state_bf16 one_decay_a_head unbounded_gate attend_all \
         recent_keys no_pooling no_tail static_h no_sinkhorn one_stream \
         no_clamp no_routed_scaling router_bf16
+
+LongCat-Flash's (`benchmarks/reference_longcat_flash.py`), ~2 min a
+switch:
+
+    chiprun --timeout 3000 -- python scripts/family_check_lowers.py \
+        --config longcat-flash-omni-serve1 --seed 7 --lower none weights_e4m3 \
+        no_identity latent_unscaled shortcut_early router_bf16
 """
 
 import argparse

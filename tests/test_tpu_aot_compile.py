@@ -38,7 +38,12 @@ form of it, the exact top-k and the row gather of selected cells (both
 XLA) at the served shapes, and glm53flash-serve1's
 own programs at its KDA + dense and sparse-attention + expert layers
 with the whole configuration's pages and slots, at all four table
-widths: no score over the table, at 65,536 keys either.
+widths: no score over the table, at 65,536 keys either. For
+LongCat-Flash (models/longcat_flash.py): longcat-flash-omni-serve1's own
+programs at one double layer (two latent-attention sublayers at 64
+heads, two dense FFNs, the shortcut's experts behind a 768-wide router)
+with the whole configuration's pages: both latent kernels twice a layer,
+the identity outputs' sum no kernel's work.
 """
 
 import math
@@ -1504,3 +1509,102 @@ def test_glm5_next_program_moves_no_pool_or_stack_and_fits(
     assert abs(arguments - counted) < 3.2e7
     assert arguments > 0.25 * 16 * 2**30  # the floor a new cell is held to
     assert arguments + memory.temp_size_in_bytes < 15.75 * 2**30
+
+
+# ------------------------------------- the latent programs, a double layer
+@pytest.fixture(scope="module")
+def longcat_programs(v5e):
+    """longcat-flash-omni-serve1's own sizes (benchmarks/configs) at 1 of
+    its 4 double layers, with the whole configuration's pages: what
+    `aot_fit_serve_family` lowers, a whole prompt's program, a chunk's
+    and the decode program. Compiled when first asked for."""
+    import json
+
+    from benchmarks import aot_fit_serve_family
+    from ray_tpu._private import chip
+
+    here = os.path.join(os.path.dirname(__file__), "..", "benchmarks")
+    with open(os.path.join(here, "configs", "longcat-flash-omni-serve1.json")) as f:
+        whole = json.load(f)
+    conf = {**whole, "num_layers": 1}
+    traffic = {"fit_prefill_buckets": [2048, 8192]}
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(chip, "platform", lambda: "tpu")
+        lowered = aot_fit_serve_family.lowered_programs(
+            conf, traffic, next(iter(v5e.device_set))
+        )
+    compiled = {}
+
+    def program(name):
+        if name not in compiled:
+            compiled[name] = lowered[name].compile()
+        return compiled[name]
+
+    return whole, program
+
+
+@pytest.mark.parametrize(
+    "program", ["prefill_2048", "prefill_chunk_2048_of_8192", "decode"]
+)
+def test_longcat_program_runs_both_sublayers_through_the_kernels_and_fits(
+    longcat_programs, program
+):
+    """One double layer: each latent kernel is called twice, at 64 heads
+    (they had only ever been lowered at 128), once an attention sublayer
+    with a row of the pool each; the pool is updated in place and the
+    expert stacks read where they lie; the shortcut's experts are the
+    grouped-matmul kernel over a chunk's sorted pairs and the
+    touched-experts kernel in a decode step, and the identity outputs'
+    sum is under `moe:combine/moe:zero` and is no kernel's; and the
+    program's temporaries beside the WHOLE configuration's weights and
+    pages stay under what a v5e offers a program."""
+    from benchmarks.models import longcat_flash as family
+
+    conf, programs = longcat_programs
+    eng = conf["engine"]
+    d, f, cell = conf["hidden_size"], conf["expert_ffn_hidden_size"], 640
+    heads = conf["num_attention_heads"]
+    sublayer_pages = (eng["num_pages"] + 1) * PAGE * cell
+    shapes = {
+        "pages": ((PAGE, cell), sublayer_pages),
+        "w_up": ((d, f), 16 * d * f),
+        "w_down": ((f, d), 16 * d * f),
+    }
+    compiled = programs(program)
+    text = compiled.as_text()
+    assert _hybrid_moves(text, shapes) == []
+    assert "ragged-dot" not in text
+    assert "moe:combine/moe:zero" in text and "dense:mlp" in text
+    assert not [
+        line for line in _kernel_calls_under(text, "moe:zero")
+    ]
+    attend = _kernel_calls_under(text, "mla:attend")
+    assert len(attend) == 2
+    if program == "decode":
+        assert all("jit(latent_paged_attention)" in line for line in attend)
+        assert f"bf16[{eng['max_batch']},{heads},512]" in "".join(attend)
+        assert len(_expert_kernel_calls(text)) == 1
+        assert len(_grouped_kernel_calls(text)) == 0
+    else:
+        assert all("jit(latent_prefill_attention)" in line for line in attend)
+        assert f"bf16[{heads},2048,128]" in "".join(attend)
+        assert len(_grouped_kernel_calls(text)) == 2
+        assert len(_expert_kernel_calls(text)) == 0
+        # No score over the table in HBM, and nothing the size of all
+        # 24,576 routes' rows is gathered: a third of them are no pair.
+        k = conf["moe_topk"]
+        assert f"[{heads},2048,8192]" not in text
+        assert _copies_of(text, (2048 * k, d)) == []
+        assert _expert_arrays_of(text, (2048, k, d)) == []
+    memory = compiled.memory_analysis()
+    # Under the two sublayers' pool: no copy of it among the temporaries.
+    assert memory.temp_size_in_bytes < 2 * sublayer_pages * 2
+    counted = (
+        family.held_parameters(conf) * 2
+        + family.attention_sublayers(conf) * sublayer_pages * 2
+    )
+    # The float32 leaves, counted here at two bytes, are 38 MB more: the
+    # four routers, 6,144 x 768 each, and the norms.
+    assert abs(conf["fit"]["argument_bytes"] - counted) < 4e7
+    assert counted > 12e9  # what the issue asks the fullest device to hold
+    assert counted + memory.temp_size_in_bytes < 15.75 * 2**30

@@ -92,7 +92,7 @@ from ray_tpu.models.nemotron_h import (
     _init_ends,
     _normal,
 )
-from ray_tpu.models.qwen3_next import _unit, _unit_lower_inverse
+from ray_tpu.models.qwen3_next import _L2_EPS, _unit, _unit_lower_inverse
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.pallas.kda_chunk import kda_chunk_rule
 from ray_tpu.ops.pallas.state_step import kda_state_step
@@ -447,12 +447,12 @@ def mhc_spread(x, out, h_res, h_post):
 
 
 # -------------------------------------------------- Kimi Delta Attention
-def _kda_in(u, p, cfg):
-    """What the rule takes of u [T, d], before the convolution: ``[q | k
-    | v]`` [T, conv_dim] float32 as the matmul unit accumulates them,
-    ``g`` [T, H, dk] (the log of the decay, in ``[lower, 0]``), ``beta``
-    [T, H] and the output gate's pre-activation [T, H dk], float32."""
-    h, dk = cfg.kda_heads, cfg.kda_head_dim
+def _kda_projections(u, p, cfg):
+    """The mixer's matmuls of u [T, d] before the rule: ``[q | k | v]``
+    [T, conv_dim] float32 as the matmul unit accumulates them, before
+    the convolution; the decay's pre-activation ``low`` [T, H dk]
+    (``W_fb (W_fa u)``, without ``dt_bias``); ``beta`` [T, H]; and the
+    output gate's pre-activation [T, H dk], float32."""
     with jax.named_scope("kda:in"):
         f32 = partial(jnp.dot, preferred_element_type=jnp.float32)
         qkv = f32(u, p["in_proj"])
@@ -461,13 +461,26 @@ def _kda_in(u, p, cfg):
         low = jnp.dot(
             f32(u, p["f_a"]), p["f_b"].astype(jnp.float32), precision=_HIGHEST
         )
-        raw = low.reshape(-1, h, dk) + p["dt_bias"]
-        g = cfg.kda_lower * jax.nn.sigmoid(
-            jnp.exp(p["A_log"])[:, None] * raw
-        )
         beta = jax.nn.sigmoid(f32(u, p["b_proj"]))
         gate = f32(f32(u, p["g_a"]).astype(u.dtype), p["g_b"])
+    return qkv, low, beta, gate
+
+
+def _kda_in(u, p, cfg):
+    """What the rule takes of u [T, d], before the convolution:
+    `_kda_projections`'s with ``g`` [T, H, dk] (the log of the decay, in
+    ``[lower, 0]``) in the place of its pre-activation."""
+    qkv, low, beta, gate = _kda_projections(u, p, cfg)
+    with jax.named_scope("kda:in"):
+        g = _kda_decay(low, p, cfg)
     return qkv, g, beta, gate
+
+
+def _kda_decay(low, p, cfg):
+    """The log of the decay [T, H, dk], in ``[lower, 0]``, of its
+    pre-activation low [T, H dk]."""
+    raw = low.reshape(-1, cfg.kda_heads, cfg.kda_head_dim) + p["dt_bias"]
+    return cfg.kda_lower * jax.nn.sigmoid(jnp.exp(p["A_log"])[:, None] * raw)
 
 
 def _kda_split(act, cfg):
@@ -480,14 +493,19 @@ def _kda_split(act, cfg):
             v.reshape(shape))
 
 
+def _kda_gated(o, gate, p, cfg):
+    """``RMSNorm_head(o) * w * sigmoid(gate)`` over each head: o [.., H,
+    dk], gate [.., H dk] float32 -> [.., H dk] in ``cfg.dtype``."""
+    var = jnp.mean(o * o, axis=-1, keepdims=True)
+    normed = o * jax.lax.rsqrt(var + cfg.norm_eps) * p["gate_norm"]
+    gated = normed.reshape(gate.shape) * jax.nn.sigmoid(gate)
+    return gated.astype(cfg.dtype)
+
+
 def _kda_out(o, gate, p, cfg):
-    """``RMSNorm_head(o) * w * sigmoid(gate)`` over each head, then
-    ``W_o``. o [.., H, dk], gate [.., H dk]."""
+    """`_kda_gated`, then ``W_o``."""
     with jax.named_scope("kda:out"):
-        var = jnp.mean(o * o, axis=-1, keepdims=True)
-        normed = o * jax.lax.rsqrt(var + cfg.norm_eps) * p["gate_norm"]
-        gated = normed.reshape(gate.shape) * jax.nn.sigmoid(gate)
-        return gated.astype(cfg.dtype) @ p["out_proj"]
+        return _kda_gated(o, gate, p, cfg) @ p["out_proj"]
 
 
 def _kda_rule(q, k, v, beta, g, state0, size: int, sub: int):
@@ -575,6 +593,59 @@ def _kda_rule(q, k, v, beta, g, state0, size: int, sub: int):
     return jnp.moveaxis(o, 2, 1).reshape(padded, heads, -1)[:t], end
 
 
+def _kda_conv(qkv, conv0, p, cfg, length):
+    """The causal depthwise convolution of qkv [T, conv_dim] behind the
+    K - 1 rows conv0 before it, ``silu`` and `_kda_split`: (q, k, v [T,
+    H, dk], the K - 1 rows of input before position ``length``)."""
+    t, kernel = qkv.shape[0], cfg.conv_kernel
+    seq = jnp.concatenate([conv0.astype(qkv.dtype), qkv], axis=0)
+    conv = sum(
+        seq[j: j + t].astype(jnp.float32) * p["conv_w"][j]
+        for j in range(kernel)
+    )
+    # Row i of `seq` is the input at position i - (K - 1).
+    conv_end = jax.lax.dynamic_slice_in_dim(seq, length, kernel - 1, axis=0)
+    return _kda_split(jax.nn.silu(conv), cfg), conv_end
+
+
+def _conv_tail(qkv, conv0, length):
+    """`_kda_conv`'s tail without its ``[T + K - 1, conv_dim]`` array:
+    the K - 1 rows from row ``length`` on of ``[conv0; qkv]`` are among
+    conv0 and the rows of qkv right before ``length`` (all of them under
+    K - 1 tokens, conv0's last ones beside them)."""
+    tail = conv0.shape[0]
+    reach = min(tail, qkv.shape[0])
+    window = jax.lax.dynamic_slice_in_dim(
+        qkv, jnp.maximum(length - reach, 0), reach, axis=0
+    )
+    return jax.lax.dynamic_slice_in_dim(
+        jnp.concatenate([conv0.astype(qkv.dtype), window], axis=0),
+        jnp.minimum(length, reach), tail, axis=0,
+    )
+
+
+def _kda_chunked_fused(u, p, cfg, state0, conv0, length, size, sub):
+    """`kda_chunked` on a TPU: everything between the in-projections'
+    matmuls and the out-projection's is ONE call of
+    ``ops/pallas/kda_chunk.py``, which reads the matmuls' float32
+    results where they lie (the convolution, ``silu``, the unit lengths
+    and the decay's sigmoid in its prologue, on the tiles the rule loads
+    anyway) and writes the normed, gated output in ``cfg.dtype``."""
+    qkv, low, beta, gate = _kda_projections(u, p, cfg)
+    with jax.named_scope("kda:conv"):
+        conv_end = _conv_tail(qkv, conv0, length)
+    with jax.named_scope("kda:scan"):
+        gated, end = kda_chunk_rule(
+            qkv, conv0, p["conv_w"], low, p["dt_bias"], p["A_log"], beta,
+            gate, p["gate_norm"], state0, length, chunk=size, sub=sub,
+            lower=cfg.kda_lower, l2_eps=_L2_EPS, norm_eps=cfg.norm_eps,
+            dtype=cfg.dtype,
+        )
+    with jax.named_scope("kda:out"):
+        out = gated @ p["out_proj"]
+    return out, end, conv_end.astype(conv0.dtype)
+
+
 def kda_chunked(u, p, cfg: Glm5NextConfig, state0, conv0, length):
     """The KDA mixer over many tokens of one sequence, with
     `nemotron_h.mamba_chunked`'s contract: u [T, d] (normed input);
@@ -583,36 +654,25 @@ def kda_chunked(u, p, cfg: Glm5NextConfig, state0, conv0, length):
     Returns (out [T, d], the state and the convolution tail after token
     ``length - 1``). Positions from ``length`` on take no step.
 
-    On a TPU the rule, between the gates and ``o``, is one call of
-    ``ops/pallas/kda_chunk.py`` (PR 60); elsewhere `_kda_rule`, XLA's
-    form, which is tier 1's path and the kernel's oracle. The platform
-    decides, as it does for `qwen3_next.gdn_chunked`."""
+    On a TPU everything between the matmuls is one call of
+    ``ops/pallas/kda_chunk.py`` (the rule PR 60, the float32 passes
+    around it PR 62: `_kda_chunked_fused`); elsewhere the passes are
+    XLA's and the rule `_kda_rule`, which is tier 1's path and the
+    kernel's oracle. The platform decides, as it does for
+    `qwen3_next.gdn_chunked` and as `moe_ffn` chooses its kernels."""
     t = u.shape[0]
     size = min(cfg.kda_chunk, 1 << (t - 1).bit_length())
     sub = min(_KDA_SUBCHUNK, size)
+    if chip.platform() == "tpu":
+        return _kda_chunked_fused(u, p, cfg, state0, conv0, length, size, sub)
     qkv, g, beta, gate = _kda_in(u, p, cfg)
     with jax.named_scope("kda:conv"):
-        kernel = cfg.conv_kernel
-        seq = jnp.concatenate([conv0.astype(qkv.dtype), qkv], axis=0)
-        conv = sum(
-            seq[j: j + t].astype(jnp.float32) * p["conv_w"][j]
-            for j in range(kernel)
-        )
-        # Row i of `seq` is the input at position i - (K - 1).
-        conv_end = jax.lax.dynamic_slice_in_dim(seq, length, kernel - 1, axis=0)
-        q, k, v = _kda_split(jax.nn.silu(conv), cfg)
+        (q, k, v), conv_end = _kda_conv(qkv, conv0, p, cfg, length)
     with jax.named_scope("kda:scan"):
         live = jnp.arange(t) < length
         beta = jnp.where(live[:, None], beta, 0.0)
         g = jnp.where(live[:, None, None], g, 0.0)
-        # Chosen by the platform alone, as `moe_ffn` chooses its kernels.
-        if chip.platform() == "tpu":
-            o, end = kda_chunk_rule(
-                q, k, v, beta, g, state0, length, chunk=size, sub=sub
-            )
-            o = o.reshape(v.shape)
-        else:
-            o, end = _kda_rule(q, k, v, beta, g, state0, size, sub)
+        o, end = _kda_rule(q, k, v, beta, g, state0, size, sub)
     out = _kda_out(o, gate, p, cfg)
     return out, end, conv_end.astype(conv0.dtype)
 

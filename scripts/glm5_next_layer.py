@@ -5,16 +5,20 @@ experts), 2,048 tokens a call: what one prefill chunk's parts take.
 
     chiprun -- python scripts/glm5_next_layer.py [kda]
 
-Prints a JSON line a variant: the KDA mixer and its rule alone, XLA's
-form (`glm5_next._kda_rule`, what `kda_chunked` was on a TPU up to PR
-59 and still is off it) at several (chunk, sub-chunk) pairs and
-`ops/pallas/kda_chunk.py` at several (heads, groups) a grid step, every
-token live and with the last tenth padding, with the distance of the
-state from the token-a-step recurrence's (``kda`` alone stops there);
+Prints a JSON line a variant: the KDA mixer in XLA's form
+(`glm5_next._kda_rule` between XLA's passes: what `kda_chunked` was on
+a TPU up to PR 59 and still is off it), then with
+`ops/pallas/kda_chunk.py` between the matmuls as it is called on a TPU
+(and that call alone), with the convolution, silu, unit lengths and
+decay (the prologue) or the head norm and output gate (the epilogue) or
+both left to XLA as PR 60 left them, and at several (heads, groups) a
+grid step; every token live and with the last tenth padding, with the
+distance of the output from XLA's form's and of the state from the
+token-a-step recurrence's (``kda`` alone stops there);
 the sparse latent mixer as the last chunk
 of a 16k and of a 64k context at several query blocks; one residual mix
 and spread; the expert FFN; the dense FFN. PERF.md section 6, PRs 59
-and 60, has the tables this made.
+60 and 62, has the tables this made.
 """
 
 import dataclasses
@@ -78,70 +82,131 @@ def main():
     conv0 = jnp.zeros((CFG.conv_kernel - 1, CFG.kda_conv_dim), CFG.dtype)
 
     # ------------------------------------------------------------- KDA
-    def operands(u, p, length):
-        """What `kda_chunked` hands its rule, [T, H x dk] as they lie."""
+    def rule_operands(u, p, length):
+        """q, k, v, beta, g [T, H, dk] as XLA's passes make them of the
+        matmuls' results (`kda_chunked` off the TPU): what `_kda_rule`
+        and the recurrence take."""
         qkv, g, beta, _ = glm5_next._kda_in(u, p, CFG)
-        seq = jnp.concatenate([jnp.zeros((3, qkv.shape[1])), qkv])
-        conv = sum(seq[j: j + len(u)] * p["conv_w"][j] for j in range(4))
         live = jnp.arange(len(u)) < length
-        flat = [a.reshape(len(u), -1) for a in (
-            *glm5_next._kda_split(jax.nn.silu(conv), CFG),
-            jnp.where(live[:, None, None], g, 0.0),
-        )]
-        return (*flat[:3], jnp.where(live[:, None], beta, 0.0), flat[3])
+        return (*glm5_next._kda_conv(qkv, conv0, p, CFG, length)[0],
+                jnp.where(live[:, None], beta, 0.0),
+                jnp.where(live[:, None, None], g, 0.0))
 
-    def heads_of(q, k, v, beta, g):
-        return (*(a.reshape(TOKENS, h, dk) for a in (q, k, v)), beta,
-                g.reshape(TOKENS, h, dk))
+    def kernel_call(p, qkv, low, beta, gate, state, length, dtype):
+        return kda_chunk.kda_chunk_rule(
+            qkv, conv0, p["conv_w"], low, p["dt_bias"], p["A_log"], beta,
+            gate, p["gate_norm"], state, length, chunk=CFG.kda_chunk,
+            sub=glm5_next._KDA_SUBCHUNK, lower=CFG.kda_lower,
+            l2_eps=glm5_next._L2_EPS, norm_eps=CFG.norm_eps, dtype=dtype,
+        )
 
-    def kda_line(name, length, rule, cfg, platform):
-        """A rule (flat operands, so that no call pays for laying them
-        out again: in the program they come from fusions as they are
-        wanted) and the mixer around it, timed."""
-        glm5_next.chip = types.SimpleNamespace(platform=lambda: platform)
-        want = wants[length]
+    def split_mixer(prologue_in, epilogue_in):
+        """`kda_chunked` on a TPU with the passes of either end made by
+        XLA as the parent (PR 60) made them and the kernel's own stage
+        passed through (`kda_chunk._prologue` / `_epilogue` replaced
+        while this is traced): q, k, v come as one [T, 3 H dk] array in
+        the in-projection's place and ``g`` in ``low``'s, ``o`` leaves
+        float32. The kernel still fetches the operands its stage would
+        have read (``gate``'s 67 MB where the epilogue is out)."""
+        def mixer(p, u, state, conv, length):
+            if prologue_in:
+                qkv, low, beta, gate = glm5_next._kda_projections(u, p, CFG)
+            else:
+                q, k, v, beta, g = rule_operands(u, p, length)
+                gate = glm5_next._kda_in(u, p, CFG)[3]
+                qkv = jnp.concatenate(
+                    [a.reshape(TOKENS, -1) for a in (q, k, v)], axis=1)
+                low = g.reshape(TOKENS, -1)
+            o, end = kernel_call(
+                p, qkv, low, beta, gate, state, length,
+                CFG.dtype if epilogue_in else jnp.float32)
+            if epilogue_in:
+                return o @ p["out_proj"], end
+            return glm5_next._kda_out(o.reshape(TOKENS, h, dk), gate, p, CFG), end
+        return mixer
+
+    stages = (kda_chunk._prologue, kda_chunk._epilogue)
+    passed = (
+        lambda raw, before, w, low, *_: (*raw, low),
+        lambda o, *_: o,
+    )
+
+    def kda_line(name, length, mixer, rule=None):
+        """A mixer (and what of it is the rule's call alone) timed, with
+        the distance of its output over the live tokens from XLA's form's
+        and of its state from the token-a-step recurrence's."""
         # (The weights go in as arguments: closed over, they would be
         # constants of the program, 0.3 GB of them in its executable.)
-        mixer = jax.jit(lambda p, u, s, c, n: glm5_next.kda_chunked(
-            u, p, cfg, s, c, n))
         line = {"kda": name, "length": length}
         try:
-            rule_ms, (o, end) = timed(rule, *ops[length], state0)
-            mixer_ms, _ = timed(mixer, kda, u, state0, conv0, jnp.int32(length))
+            if rule is not None:
+                line["rule_ms"] = timed(rule, kda, *rule_ops[length])[0]
+            ms, (out, end) = timed(
+                jax.jit(mixer), kda, u, state0, conv0, jnp.int32(length))
+            want_out, want_end = wants[length]
             line.update({
-                "rule_ms": rule_ms, "mixer_ms": mixer_ms,
+                "mixer_ms": ms,
                 "state_rel_err": float(
-                    jnp.linalg.norm(end - want) / jnp.linalg.norm(want)),
-                "o_finite": bool(jnp.isfinite(o).all()),
+                    jnp.linalg.norm(end - want_end) / jnp.linalg.norm(want_end)),
+                "out_rel_err": float(
+                    jnp.linalg.norm((out - want_out)[:length].astype(jnp.float32))
+                    / jnp.linalg.norm(want_out[:length].astype(jnp.float32))),
+                "out_finite": bool(jnp.isfinite(out).all()),
             })
         except Exception as e:  # noqa: BLE001 - a variant the chip refuses
             line["refused"] = repr(e)[-400:]
         print(json.dumps(line), flush=True)
 
+    def as_on(platform, cfg=CFG):
+        def mixer(p, u, s, c, n):
+            glm5_next.chip = types.SimpleNamespace(platform=lambda: platform)
+            return glm5_next.kda_chunked(u, p, cfg, s, c, n)[:2]
+        return mixer
+
     if "kda" in parts:
         lengths = (TOKENS, TOKENS * 9 // 10)
-        ops = {n: jax.jit(operands)(u, kda, n) for n in lengths}
-        wants = {n: recurrence(*heads_of(*ops[n]), state0) for n in lengths}
         on_the_chip = glm5_next.chip
-        # XLA's form (`_kda_rule`), on the chip: the program's own
-        # sub-chunk of 16 in the mixer, whatever the rule alone is given.
-        for size, sub in ((32, 16), (64, 16), (64, 32), (128, 16)):
+        # The oracle: XLA's form's output, and the recurrence's state.
+        wants, rule_ops = {}, {}
+        for n in lengths:
+            out = jax.jit(as_on("cpu"))(kda, u, state0, conv0, jnp.int32(n))[0]
+            ops = jax.jit(rule_operands)(u, kda, n)
+            wants[n] = (out, recurrence(*ops, state0))
+            rule_ops[n] = (
+                *jax.jit(lambda u, p: glm5_next._kda_projections(u, p, CFG))(u, kda),
+                state0, jnp.int32(n),
+            )
+        # XLA's form (`_kda_rule` between XLA's passes), on the chip.
+        for size in (32, 64):
             cfg = dataclasses.replace(CFG, kda_chunk=size)
-            rule = jax.jit(lambda *a, size=size, sub=sub: glm5_next._kda_rule(
-                *heads_of(*a[:5]), a[5], size, sub))
             for n in lengths if size == CFG.kda_chunk else lengths[:1]:
-                kda_line(f"xla, chunk {size} sub {sub}", n, rule, cfg, "cpu")
-        # The kernel at (heads, groups of 128 tokens) a grid step.
+                kda_line(f"xla, chunk {size}", n, as_on("cpu", cfg))
+        # The kernel as `kda_chunked` calls it on a TPU (its call alone:
+        # the matmuls' results to the gated output), then with either
+        # stage, and both, left to XLA as the parent left them.
+        whole = jax.jit(lambda p, *a: kernel_call(p, *a, CFG.dtype))
+        for n in lengths:
+            kda_line("kernel, both stages in", n, as_on("tpu"), whole)
+        for name, prologue_in, epilogue_in in (
+            ("kernel, the parent's form: neither stage in", False, False),
+            ("kernel, the epilogue in", False, True),
+            ("kernel, the prologue in", True, False),
+        ):
+            kda_chunk._prologue = stages[0] if prologue_in else passed[0]
+            kda_chunk._epilogue = stages[1] if epilogue_in else passed[1]
+            jax.clear_caches()
+            for n in lengths:
+                kda_line(name, n, split_mixer(prologue_in, epilogue_in))
+        kda_chunk._prologue, kda_chunk._epilogue = stages
+        # Both stages in, at (heads, groups of 128 tokens) a grid step.
         default = (kda_chunk._HEADS_A_STEP, gdn_chunk._GROUPS_A_STEP)
-        for blocking in [default] + [b for b in KDA_SWEEP if b != default]:
+        for blocking in [b for b in KDA_SWEEP if b != default]:
             kda_chunk._HEADS_A_STEP, gdn_chunk._GROUPS_A_STEP = blocking
             jax.clear_caches()
-            for n in lengths if blocking == default else lengths[:1]:
-                rule = jax.jit(lambda *a, n=n: kda_chunk.kda_chunk_rule(
-                    *heads_of(*a[:5]), a[5], jnp.int32(n),
-                    chunk=CFG.kda_chunk, sub=glm5_next._KDA_SUBCHUNK))
-                kda_line("kernel, {} heads x {} groups a step".format(*blocking),
-                         n, rule, CFG, "tpu")
+            kda_line(
+                "kernel, both stages in, {} heads x {} groups a step".format(
+                    *blocking),
+                lengths[0], as_on("tpu"), whole)
         kda_chunk._HEADS_A_STEP, gdn_chunk._GROUPS_A_STEP = default
         glm5_next.chip = on_the_chip
     if parts == {"kda"}:

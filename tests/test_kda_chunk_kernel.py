@@ -1,19 +1,25 @@
 """ops/pallas/kda_chunk.py interpreted, against what it replaces on a
-TPU: the chunked per-channel delta rule of `models/glm5_next.py
-kda_chunked` in XLA's own operations (`_kda_rule`), and the rule a token
-a step (`kda_step`'s).
+TPU: everything of `models/glm5_next.py kda_chunked` between the
+in-projections' matmuls and the out-projection's, in XLA's own
+operations (`_kda_decay`, `_kda_conv`, `_kda_rule`, `_kda_gated`), and
+the rule a token a step (`kda_step`'s).
 
-The rule is called alone, on operands as `kda_chunked` hands them
-(unit-length keys, ``q`` times ``dk^-0.5``, ``g`` in ``[lower, 0]`` a
-channel, ``beta`` and ``g`` 0 from ``length`` on): the live positions'
-outputs and the state after the last live token must agree with XLA's
-form and with the recurrence to the tolerance tests/test_glm5_next.py
-holds the XLA form to. Compiled for a described v5e at the served shape
-in tests/test_tpu_aot_compile.py.
+The kernel is called alone, on operands as the matmuls leave them (``[q
+| k | v]`` before the convolution with the rows before them and the
+taps, the decay's pre-activation with ``dt_bias`` and ``A_log``,
+``beta``, the output gate's pre-activation and the head norm's weight):
+the live positions' gated, normed outputs and the state after the last
+live token must agree with XLA's chain and with the recurrence between
+the same passes, the state to the tolerance tests/test_glm5_next.py
+holds the XLA form to and the output to that over the size of ``o``,
+which the head norm divides by. Compiled for a described v5e at the
+served shape in tests/test_tpu_aot_compile.py.
 """
 
+import dataclasses
 import functools
 import types
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -28,6 +34,7 @@ TINY = glm5_next.GLM5_NEXT_PRESETS["glm5_next_tiny"]
 LOWER = TINY.kda_lower
 TOL = 2e-5
 SUB = glm5_next._KDA_SUBCHUNK
+TAIL = TINY.conv_kernel - 1
 
 
 def _as_on_a_tpu(monkeypatch):
@@ -42,29 +49,86 @@ def _as_on_a_tpu(monkeypatch):
     )
 
 
-def _operands(tokens, heads, dim, length, seed, gates, zero_state):
-    """q, k, v, beta, g, state0 as `kda_chunked` hands them to its rule.
-    ``gates``: "random" (g uniform in [lower, 0] a channel), "lower" (g
-    at the lower bound on every channel of every live token: the case the
-    sub-chunk reference exists for) or "none" (g 0: no decay)."""
-    keys = jax.random.split(jax.random.key(seed), 6)
-    shape = (tokens, heads, dim)
-    q = glm5_next._unit(jax.random.normal(keys[0], shape)) * dim**-0.5
-    k = glm5_next._unit(jax.random.normal(keys[1], shape))
-    v = jax.random.normal(keys[2], shape)
-    beta = jax.nn.sigmoid(jax.random.normal(keys[3], (tokens, heads)))
-    g = {
-        "random": LOWER * jax.random.uniform(keys[4], shape),
-        "lower": jnp.full(shape, LOWER),
-        "none": jnp.zeros(shape),
+class Operands(NamedTuple):
+    """What `kda_chunked` hands the kernel, in its order."""
+
+    qkv: jax.Array  # [T, 3 H dk]
+    conv0: jax.Array  # [K - 1, 3 H dk]
+    conv_w: jax.Array  # [K, 3 H dk]
+    low: jax.Array  # [T, H dk]
+    dt_bias: jax.Array  # [H, dk]
+    A_log: jax.Array  # [H]
+    beta: jax.Array  # [T, H]
+    gate: jax.Array  # [T, H dk]
+    gate_norm: jax.Array  # [dk]
+    state0: jax.Array  # [H, dk, dk]
+
+    def rows(self, start, stop):
+        """The same sequence's tokens start..stop (not its conv0 or
+        state0, which are what lies before token 0)."""
+        return self._replace(**{
+            name: getattr(self, name)[start:stop]
+            for name in ("qkv", "low", "beta", "gate")
+        })
+
+
+def _operands(tokens, heads, dim, seed, gates, zero_state, zero_tail=False):
+    """Operands as the matmuls of `kda_chunked` leave them. ``gates``:
+    "random" (the decay's pre-activation normal: g over ``[lower, 0]`` a
+    channel), "lower" (so far up that the sigmoid is 1 and g the lower
+    bound on every channel of every live token: the case the sub-chunk
+    reference exists for) or "none" (so far down that g is 0: no
+    decay)."""
+    keys = jax.random.split(jax.random.key(seed), 10)
+    width = heads * dim
+    low = {
+        "random": 2.0 * jax.random.normal(keys[3], (tokens, width)),
+        "lower": jnp.full((tokens, width), 1e4),
+        "none": jnp.full((tokens, width), -1e4),
     }[gates]
-    live = jnp.arange(tokens) < length
-    beta = jnp.where(live[:, None], beta, 0.0)
-    g = jnp.where(live[:, None, None], g, 0.0)
-    state0 = jax.random.normal(keys[5], (heads, dim, dim))
-    if zero_state:
-        state0 = jnp.zeros_like(state0)
-    return q, k, v, beta, g, state0
+    conv0 = jax.random.normal(keys[1], (TAIL, 3 * width))
+    state0 = jax.random.normal(keys[9], (heads, dim, dim))
+    return Operands(
+        qkv=3.0 * jax.random.normal(keys[0], (tokens, 3 * width)),
+        conv0=jnp.zeros_like(conv0) if zero_tail else conv0,
+        conv_w=jax.random.uniform(
+            keys[2], (TAIL + 1, 3 * width), minval=-0.5, maxval=0.5
+        ),
+        low=low,
+        dt_bias=0.5 * jax.random.normal(keys[4], (heads, dim)),
+        A_log=jnp.log(jax.random.uniform(keys[5], (heads,), minval=1.0, maxval=16.0)),
+        beta=jax.nn.sigmoid(jax.random.normal(keys[6], (tokens, heads))),
+        gate=jax.random.normal(keys[7], (tokens, width)),
+        gate_norm=1.0 + 0.1 * jax.random.normal(keys[8], (dim,)),
+        state0=jnp.zeros_like(state0) if zero_state else state0,
+    )
+
+
+def _config(ops, dtype=jnp.float32):
+    heads, dim, _ = ops.state0.shape
+    return dataclasses.replace(
+        TINY, kda_heads=heads, kda_head_dim=dim, dtype=dtype
+    )
+
+
+def _rule_operands(ops, length):
+    """q, k, v, beta, g as XLA's passes make them for `_kda_rule` (what
+    `kda_chunked` does off the TPU), and the convolution's tail."""
+    cfg = _config(ops)
+    p = ops._asdict()
+    (q, k, v), conv_end = glm5_next._kda_conv(ops.qkv, ops.conv0, p, cfg, length)
+    live = jnp.arange(len(ops.qkv)) < length
+    beta = jnp.where(live[:, None], ops.beta, 0.0)
+    g = jnp.where(live[:, None, None], glm5_next._kda_decay(ops.low, p, cfg), 0.0)
+    return (q, k, v, beta, g), conv_end
+
+
+def _kernel(ops, length, chunk, dtype=jnp.float32):
+    return kda_chunk.kda_chunk_rule(
+        *ops, jnp.int32(length), chunk=chunk, sub=min(SUB, chunk),
+        lower=LOWER, l2_eps=glm5_next._L2_EPS, norm_eps=TINY.norm_eps,
+        dtype=dtype, interpret=True,
+    )
 
 
 @jax.jit
@@ -96,28 +160,47 @@ CALLS = {
     "every_gate_at_its_lower_bound": (128, 128, "lower", False),
     "the_lower_bound_and_padding": (64, 50, "lower", False),
     "no_decay": (128, 128, "none", False),
+    # The convolution's window: fewer live tokens than it reaches back,
+    # one token past a token block's edge (its taps meet the block
+    # before's last rows), and fewer tokens than a group may hold.
+    "a_length_under_the_window": (64, TAIL - 1, "random", False),
+    "one_live_token": (32, 1, "random", False),
+    "a_window_across_a_block_edge": (2 * 128, 128 + 1, "random", False),
+    "fewer_tokens_than_a_tile": (5, 3, "random", False),
 }
 
 
 def _check(ops, length, chunk):
-    """The kernel, interpreted, against XLA's form and the recurrence on
-    the same operands."""
+    """The kernel, interpreted, against XLA's chain and the recurrence
+    between the same passes, on the same operands."""
+    cfg = _config(ops)
+    p = ops._asdict()
     sub = min(SUB, chunk)
-    want_o, want_state = glm5_next._kda_rule(*ops, chunk, sub)
-    got_o, got_state = kda_chunk.kda_chunk_rule(
-        *ops, jnp.int32(length), chunk=chunk, sub=sub, interpret=True
-    )
-    got_o = got_o.reshape(want_o.shape)
-    assert np.isfinite(np.asarray(got_o)).all()  # the dead rows too
+    rule_ops, _ = _rule_operands(ops, length)
+    want_o, want_state = glm5_next._kda_rule(*rule_ops, ops.state0, chunk, sub)
+    want_out = glm5_next._kda_gated(want_o, ops.gate, p, cfg)
+    got_out, got_state = _kernel(ops, length, chunk)
+    assert got_out.shape == want_out.shape and got_out.dtype == want_out.dtype
+    assert np.isfinite(np.asarray(got_out)).all()  # the dead rows too
     if length == 0:
-        np.testing.assert_array_equal(got_state, ops[5])
+        np.testing.assert_array_equal(got_state, ops.state0)
         return
-    rule_o, rule_state = _recurrence(*(a[:length] for a in ops[:5]), ops[5])
+    rule_o, rule_state = _recurrence(*(a[:length] for a in rule_ops), ops.state0)
     assert float(np.abs(rule_o).max()) > 0.02  # a thousand times TOL
-    for o, state in ((want_o, want_state), (got_o, got_state)):
-        np.testing.assert_allclose(o[:length], rule_o, atol=TOL, rtol=0)
+    rule_out = glm5_next._kda_gated(rule_o, ops.gate[:length], p, cfg)
+    # The head norm divides o by its size over a head's channels: an
+    # error of o is that many times larger in the output.
+    size = jnp.repeat(
+        jnp.sqrt(jnp.mean(rule_o * rule_o, axis=-1)), cfg.kda_head_dim, axis=-1
+    )  # [length, H dk]
+
+    def as_in_o(a, b):
+        return float(jnp.max(jnp.abs(a[:length] - b[:length]) * size))
+
+    for out, state in ((want_out, want_state), (got_out, got_state)):
+        assert as_in_o(out, rule_out) < 2 * TOL
         np.testing.assert_allclose(state, rule_state, atol=TOL, rtol=0)
-    np.testing.assert_allclose(got_o[:length], want_o[:length], atol=TOL, rtol=0)
+    assert as_in_o(got_out, want_out) < 2 * TOL
     np.testing.assert_allclose(got_state, want_state, atol=TOL, rtol=0)
 
 
@@ -133,6 +216,7 @@ CASES = [
     (32, "tiny", (2048, 1843, "random", False)),
     *((32, "served", (tokens, tokens * 9 // 10, gates, False))
       for tokens in (64, 128, 2048) for gates in ("random", "lower")),
+    (32, "served", (2 * 128, 128 + 2, "random", False)),
 ]
 
 
@@ -147,10 +231,37 @@ def test_kernel_is_the_xla_form_and_the_recurrence(case):
     chunk, width, call = case
     tokens, length, gates, zero_state = CALLS.get(call, call)
     heads, dim = (TINY.kda_heads, TINY.kda_head_dim) if width == "tiny" else (1, 128)
-    ops = _operands(
-        tokens, heads, dim, length, chunk + tokens, gates, zero_state
-    )
+    ops = _operands(tokens, heads, dim, chunk + tokens, gates, zero_state)
     _check(ops, length, chunk)
+
+
+def test_no_rows_before_the_sequence_are_rows_of_zeros():
+    """A sequence's first chunk has a zero tail: the same as the taps
+    meeting nothing."""
+    ops = _operands(64, TINY.kda_heads, TINY.kda_head_dim, 11, "random", True,
+                    zero_tail=True)
+    _check(ops, 50, 32)
+
+
+def test_the_output_leaves_in_the_models_dtype():
+    """bfloat16, as `_kda_gated` casts before ``W_o``: the float32
+    result rounded once (a neighbouring value where the two float32
+    results straddle a rounding edge)."""
+    ops = _operands(128, TINY.kda_heads, TINY.kda_head_dim, 5, "random", False)
+    exact, state = _kernel(ops, 100, 32)
+    rounded, same = _kernel(ops, 100, 32, dtype=jnp.bfloat16)
+    assert rounded.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(rounded[:100], exact[:100].astype(jnp.bfloat16))
+    np.testing.assert_array_equal(same, state)
+    rule_ops, _ = _rule_operands(ops, 100)
+    want = glm5_next._kda_gated(
+        glm5_next._kda_rule(*rule_ops, ops.state0, 32, SUB)[0], ops.gate,
+        ops._asdict(), _config(ops, jnp.bfloat16),
+    )
+    np.testing.assert_allclose(
+        rounded[:100].astype(jnp.float32), want[:100].astype(jnp.float32),
+        rtol=2**-7, atol=1e-4,
+    )
 
 
 def test_no_exponent_passes_what_float32_holds():
@@ -161,34 +272,42 @@ def test_no_exponent_passes_what_float32_holds():
     / 2``; the outputs are finite and the recurrence's. A bound a config
     could pass is refused where the config is made."""
     assert SUB * -LOWER / 2 <= 85.0
-    ops = _operands(128, 2, 16, 128, 3, "lower", False)
-    got_o, got_state = kda_chunk.kda_chunk_rule(
-        *ops, jnp.int32(128), chunk=32, sub=SUB, interpret=True
-    )
-    rule_o, rule_state = _recurrence(*ops)
-    assert np.isfinite(np.asarray(got_o)).all()
-    np.testing.assert_allclose(
-        got_o.reshape(rule_o.shape), rule_o, atol=TOL, rtol=0
-    )
-    np.testing.assert_allclose(got_state, rule_state, atol=TOL, rtol=0)
+    ops = _operands(128, 2, 16, 3, "lower", False)
+    rule_ops, _ = _rule_operands(ops, 128)
+    np.testing.assert_array_equal(rule_ops[4], LOWER)  # g, every channel
+    _check(ops, 128, 32)
     with pytest.raises(ValueError, match="float32's exp"):
         glm5_next.Glm5NextConfig(kda_lower=-12.0)
 
 
-def test_two_calls_that_carry_the_state_are_one():
-    """Two calls of 64 tokens, the second from what the first left (of
-    its 64 the last 9 padding), are one call of 119."""
-    ops = _operands(128, TINY.kda_heads, TINY.kda_head_dim, 119, 7, "random", False)
-    rule = functools.partial(
-        kda_chunk.kda_chunk_rule, chunk=32, sub=SUB, interpret=True
+def test_two_calls_that_carry_the_state_and_the_tail_are_one():
+    """Two calls of 64 tokens, the second from the state AND the
+    convolution's tail the first left (of its 64 the last 9 padding),
+    are one call of 119."""
+    ops = _operands(128, TINY.kda_heads, TINY.kda_head_dim, 7, "random", False)
+    whole, state = _kernel(ops, 119, 32)
+    first, s1 = _kernel(ops.rows(0, 64), 64, 32)
+    tail = glm5_next._conv_tail(ops.qkv[:64], ops.conv0, jnp.int32(64))
+    second, s2 = _kernel(
+        ops.rows(64, 128)._replace(state0=s1, conv0=tail), 55, 32
     )
-    whole, state = rule(*ops, jnp.int32(119))
-    first, s1 = rule(*(a[:64] for a in ops[:5]), ops[5], jnp.int32(64))
-    second, s2 = rule(*(a[64:] for a in ops[:5]), s1, jnp.int32(55))
     np.testing.assert_allclose(
         jnp.concatenate([first, second[:55]]), whole[:119], atol=TOL, rtol=0
     )
     np.testing.assert_allclose(s2, state, atol=TOL, rtol=0)
+
+
+@pytest.mark.parametrize("tokens", [2, 3, 64])
+def test_the_tail_is_the_concatenations_without_it(tokens):
+    """`_conv_tail` (a slice of K - 1 rows of qkv beside conv0) is
+    `_kda_conv`'s slice of ``[conv0; qkv]`` at every length, under K - 1
+    tokens too."""
+    ops = _operands(tokens, 2, 16, tokens, "random", True)
+    for length in range(tokens + 1):
+        np.testing.assert_array_equal(
+            glm5_next._conv_tail(ops.qkv, ops.conv0, jnp.int32(length)),
+            _rule_operands(ops, length)[1], err_msg=str(length),
+        )
 
 
 @pytest.mark.parametrize("platform", ["cpu", "tpu"])

@@ -33,8 +33,9 @@ layer and one window layer with the whole configuration's pages and
 slots, at all three table widths: a window layer's part is the same in
 each. For GLM-5.3-Flash (models/glm5_next.py): the state kernel's third
 body at its stack, the two expert kernels with the clamp, the
-chunked per-channel rule's kernel (ops/pallas/kda_chunk.py) and XLA's
-form of it, the exact top-k and the row gather of selected cells (both
+KDA mixer's kernel between its matmuls (ops/pallas/kda_chunk.py: the
+convolution and gates, the chunked per-channel rule, the head norm) and
+XLA's form of the rule, the exact top-k and the row gather of selected cells (both
 XLA) at the served shapes, and glm53flash-serve1's
 own programs at its KDA + dense and sparse-attention + expert layers
 with the whole configuration's pages and slots, at all four table
@@ -655,6 +656,17 @@ def _state_passes(text: str, scope: str, elements: int) -> list[str]:
         if max(touched) >= elements:
             found.append(f"{opcode} %{name} {types}")
     return found
+
+
+def _entry_results(text: str, shape: str) -> list[str]:
+    """The instructions of a compiled program's entry computation whose
+    result matches ``shape`` (a pattern: ``f32\\[2048,8192\\]``): arrays
+    that lie in HBM between two of its operations."""
+    entry = text[text.index("ENTRY "):]
+    return [
+        line.strip() for line in entry.splitlines()
+        if re.match(rf"\s+(ROOT )?%\S+ = \(?{shape}", line)
+    ]
 
 
 def _copies_of(text: str, shape: tuple) -> list[str]:
@@ -1331,34 +1343,36 @@ def test_expert_kernels_compile_for_v5e_with_the_clamp(v5e, call):
 
 def test_kda_chunk_kernel_compiles_for_v5e_at_the_served_shape(v5e):
     """glm53flash-serve1's prefill chunk: 2,048 tokens, 64 heads of 128
-    x 128, rule chunks of 32 in sub-chunks of 16. The running sum down
-    the rows, a sub-chunk's middle row spread over it, the two score
-    products under their masks, the masked inverse by halves and the
-    state transposed in and out lower for the chip, inside the VMEM the
-    call asks for; none of XLA's `[n, H, C, dk]` intermediates is made
-    beside the arguments. The operands are handed over as the program's
-    fusions leave them, `[T, H x dk]` (a `[T, H, dk]` ARGUMENT of a
-    program lies in other tiles and would be laid out again first)."""
+    x 128, rule chunks of 32 in sub-chunks of 16, from the matmuls'
+    float32 results to the gated output in bfloat16 (PR 62). The three
+    views of the in-projection's result, the taps' rolls down the
+    sublanes with the eight rows before a block, silu, the unit lengths
+    and both sigmoids; the running sum down the rows, a sub-chunk's
+    middle row spread over it, the two score products under their masks,
+    the masked inverse by halves and the state transposed in and out;
+    the head norm and the cast lower for the chip, inside the VMEM the
+    call asks for; nothing is made beside the arguments (the eight rows
+    before the sequence and the decay's two rows a channel are
+    kilobytes)."""
     from ray_tpu.ops.pallas import kda_chunk
 
-    t, h, dk, dv = 2048, 64, 128, 128
+    t, h, dk = 2048, 64, 128
 
     def on_chip(*shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
 
-    def rule(q, k, v, beta, g, state0, length):
-        return kda_chunk.kda_chunk_rule(
-            q.reshape(t, h, dk), k.reshape(t, h, dk), v.reshape(t, h, dv),
-            beta, g.reshape(t, h, dk), state0, length, chunk=32, sub=16,
-        )
-
-    compiled = jax.jit(rule).lower(
-        on_chip(t, h * dk), on_chip(t, h * dk), on_chip(t, h * dv),
-        on_chip(t, h), on_chip(t, h * dk), on_chip(h, dk, dv),
-        on_chip(dtype=jnp.int32),
+    compiled = jax.jit(partial(
+        kda_chunk.kda_chunk_rule, chunk=32, sub=16, lower=-5.0, l2_eps=1e-6,
+        norm_eps=1e-5, dtype=jnp.bfloat16,
+    )).lower(
+        on_chip(t, 3 * h * dk), on_chip(3, 3 * h * dk, dtype=jnp.bfloat16),
+        on_chip(4, 3 * h * dk), on_chip(t, h * dk), on_chip(h, dk),
+        on_chip(h), on_chip(t, h), on_chip(t, h * dk), on_chip(dk),
+        on_chip(h, dk, dk), on_chip(dtype=jnp.int32),
     ).compile()
     text = compiled.as_text()
     assert len(_kernel_calls_under(text, "")) == 1
+    assert f"bf16[{t},{h * dk}]" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**20
 
 
@@ -1487,6 +1501,27 @@ def test_glm5_next_program_moves_no_pool_or_stack_and_fits(
             if "kda:scan" in line and " while(" in line
         ]
         assert not re.search(r"f32\[64,64,(2,)?(16|32),\d+\]", text)
+        # Between the in-projections' matmuls and the out-projection's
+        # nothing is XLA's (PR 62). The one float32 [chunk, 3 H dk] in HBM
+        # is the in-projection's result: none behind the convolution, and
+        # no [chunk + K - 1, ..] of the tail's rows before it. The two
+        # float32 [chunk, H dk] are the decay's and the output gate's
+        # pre-activations: no q, k, v, g, and `o` leaves in bfloat16.
+        lin = conf["linear_attn_config"]
+        kda_heads, dk = lin["num_heads"], lin["head_dim"]
+        rows = f"({chunk}|{chunk + lin['short_conv_kernel_size'] - 1})"
+        wide = _entry_results(text, rf"f32\[{rows},{3 * kda_heads * dk}\]")
+        assert len(wide) == 1 and "kda:in/dot_general" in wide[0]
+        # (The indexer's scores over a 32,768-token table are as wide.)
+        tall = [
+            line for line in _entry_results(
+                text, rf"f32\[{rows},({kda_heads * dk}|{kda_heads},{dk})\]"
+            ) if "/dsa:" not in line
+        ]
+        assert len(tall) == 2 and all("kda:in/dot_general" in t for t in tall)
+        assert f"bf16[{chunk},{kda_heads * dk}]" in _kernel_calls_under(
+            text, "kda:scan"
+        )[0]
         # No score of the attention over the table in HBM: the indexer's
         # [chunk, blocks] float32 is the one array as wide as the context.
         # (A bare [2048, 16384] is the heads' width, 64 x 256.)

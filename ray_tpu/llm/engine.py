@@ -8,20 +8,14 @@ same XLA programs pjit-sharded over a mesh's 'tp' axis, so adding
 chips changes a sharding annotation, not the orchestration.
 
 One cache and three programs over it, which the MODEL supplies
-(`self.serving`: `paged_kv.LlamaServing` for a `LlamaConfig`, or what a
-config's own `serving()` returns: `hybrid_kv.HybridServing` for
-`models/nemotron_h.py`, `latent_kv.LatentServing` for
-`models/pangu_ultra_moe.py`). For a Llama-shaped model the cache is the page
-pool (`paged_kv.init_paged_kv`) and the programs are `paged_prefill` (a
-whole prompt, bucketed to power-of-two lengths to bound compile count),
-`paged_prefill_chunk` (one chunk of a long prompt) and `paged_verify`
-(the decode program: K = 1 + `speculate` tokens a slot); a model with
-recurrent blocks keeps per-slot state beside the pages, and its
-programs are told the slot, the context's true length and which slots
-are decoding. add_request() parks requests in a FIFO; step() admits
-queued requests into free slots while their pages fit the pool, runs at
-most one prefill chunk, and then advances all `max_batch` slots with
-one decode program.
+(`self.serving`, a `serving.Serving`, whose docstring is the contract:
+`paged_kv.LlamaServing` for a `LlamaConfig`, or what a config's own
+`serving()` returns): a whole prompt's prefill (bucketed to power-of-two
+lengths to bound compile count), one chunk's of a long prompt, and the
+decode program (K = 1 + `speculate` tokens a slot). add_request() parks
+requests in a FIFO; step() admits queued requests into free slots while
+their pages fit the pool, runs at most one prefill chunk, and then
+advances all `max_batch` slots with one decode program.
 
 One decode step is in flight while the host works. step() dispatches
 step t+1 with step t's sampled ids as they lie on the device, and only
@@ -315,8 +309,6 @@ class LLMEngine:
             )
         self.prefill_chunk = prefill_chunk
         self._prefilling: dict | None = None
-        # Every program is told which attention path it is compiled
-        # with (a prefill that has no kernel takes no notice).
         self._prefill_chunk_fn = partial(
             serving.prefill_chunk, use_kernel=use_kernel
         )
@@ -329,10 +321,6 @@ class LLMEngine:
         # "prefill_chunk" or "decode". For checks of the timed programs'
         # own output against a reference.
         self.on_logits = None
-        # (token, expert) pairs a token is routed to over the model's
-        # expert blocks, where its programs keep a record of them.
-        self._pairs_per_token = serving.pairs_per_token
-        self._moe_counts: list = []  # (phase, device int32[4 or 6]), unfolded
         # The chain of keys, ``key, sub = split(key)`` a decode step:
         # its head, and the links made ahead (`_key_block`).
         self._step_key = jax.random.key(seed)
@@ -373,9 +361,8 @@ class LLMEngine:
         # decoding, preempted): what `abort_request` looks at before it
         # waits for `_lock`.
         self._live: set[str] = set()
-        # The cache's two kinds of per-sequence state, as the model that
-        # made it counts them: pages, and what recurrent blocks keep per
-        # slot (0 without any).
+        # The cache's two kinds of per-sequence state, as the model
+        # counts them: pages, and what it keeps per slot beside them.
         pool_bytes, state_bytes = serving.cache_bytes(self.cache)
         # Serving observability counters (reference: the vLLM stats
         # ray.llm surfaces — requests, tokens, acceptance, preemption).
@@ -439,23 +426,7 @@ class LLMEngine:
             ),
             "pool_bytes": pool_bytes,
             "state_bytes": state_bytes,
-            # Expert blocks (0 without any): pairs the live tokens were
-            # routed to, the pairs among them whose expert is held here,
-            # and held experts that got a row, summed over expert blocks
-            # and decode steps; the rows the sorted expert form ran its
-            # grouped matmuls over, and the pair rows it was given; the
-            # routes among `moe_pairs_routed` that went to an identity
-            # output of the router, which no chip computes as a pair.
-            "moe_pairs_routed": 0,
-            "moe_pairs_here": 0,
-            "experts_touched": 0,
-            "moe_rows_computed": 0,
-            "moe_rows_sorted": 0,
-            "moe_zero_pairs": 0,
         }
-        # The most real experts a live token chose in any expert block
-        # (a router with identity outputs; else `top_k`, always).
-        self._real_experts_max = 0
 
     # ------------------------------------------------------ request API
     @contextmanager
@@ -822,37 +793,18 @@ class LLMEngine:
                 )
 
     def _account(self, phase: str, logits, record: list, tokens: int) -> None:
-        """After every program: hand its logits to `on_logits`, and keep
-        the expert blocks' counters of a program that records them
-        (`tokens` live tokens went through it). The counters stay on the
-        device until `stats()` asks or 512 programs' have gathered: only
-        then is a program waited for here (`readback:moe_counts`)."""
+        """After every program: hand its logits to `on_logits`, and its
+        record, where it makes one, to the model (`tokens` live tokens
+        went through the program). A record stays on the device until
+        `stats()` asks or the model says a fold is due: only then is a
+        program waited for here (`readback:moe_counts`)."""
         record = record[0] if record else None
         if self.on_logits is not None:
             self.on_logits(phase, logits, record)
         if record is not None:
-            self._stats["moe_pairs_routed"] += tokens * self._pairs_per_token
-            self._moe_counts.append((phase, record["counts"]))
-            if len(self._moe_counts) >= 512:
-                with self._phase(
-                    "readback:moe_counts", programs=len(self._moe_counts)
-                ):
-                    self._fold_moe_counts()
-
-    def _fold_moe_counts(self) -> None:
-        counts, self._moe_counts = self._moe_counts, []
-        for phase, row in counts:
-            here, touched, computed, given, *zero = (
-                int(v) for v in np.asarray(row)
-            )
-            if zero:
-                self._stats["moe_zero_pairs"] += zero[0]
-                self._real_experts_max = max(self._real_experts_max, zero[1])
-            self._stats["moe_pairs_here"] += here
-            self._stats["moe_rows_computed"] += computed
-            self._stats["moe_rows_sorted"] += given
-            if phase == "decode":
-                self._stats["experts_touched"] += touched
+            if due := self.serving.note(phase, record, tokens):
+                with self._phase("readback:moe_counts", programs=due):
+                    self.serving.fold()
 
     def step(self) -> list[dict]:
         """Admit + one decode step dispatched + the step before it read
@@ -1297,21 +1249,12 @@ class LLMEngine:
         phase's own seconds `host_s_sum.<phase>`, `between_s_sum`: all
         sums over the engine's life, a first call's compile among a
         `launch`'s, so take rates from two polls), `param_bytes` (the
-        held weights, matmul
-        leaves in `cfg.dtype`), which attention and which K/V cell write the
-        decode program was compiled with (`paged_attn_kernel`,
-        `kv_write_kernel`), for a model with expert layers whether their
-        sorted form sums its rows by the kernel (`moe_combine_kernel`),
-        for a model with recurrent blocks whether a decode step updates
-        their state by the kernel (`state_step_kernel`), for a model
-        whose router has identity outputs the share of routes that went
-        to one and the real experts a token has left
-        (`zero_expert_pairs_pct`, `real_experts_per_token_mean` /
-        `_max`), and the pool/slot occupancy."""
+        held weights, matmul leaves in `cfg.dtype`), which attention and
+        which K/V cell write the decode program was compiled with
+        (`paged_attn_kernel`, `kv_write_kernel`), the pool/slot
+        occupancy, and what the model counts (`Serving.counters`)."""
         with self._lock:
-            self._fold_moe_counts()
-            # The model's own counters (its cache's, where it keeps any)
-            # beside the engine's.
+            # The model's own counters beside the engine's.
             out = {**self._stats, **self.serving.counters()}
             out["pipeline_drains"] = dict(out["pipeline_drains"])
             # How often a decode step ran under the host's work on the
@@ -1326,21 +1269,6 @@ class LLMEngine:
                 100.0 * out["decode_steps_starved"] / out["decode_steps_alone"]
                 if out["decode_steps_alone"] else 0.0
             )
-            # How tight the sorted expert form's row bound is: beside
-            # moe_pairs_here / moe_pairs_routed, the share it must do.
-            out["moe_sorted_rows_pct"] = (
-                100.0 * out["moe_rows_computed"] / out["moe_rows_sorted"]
-                if out["moe_rows_sorted"] else 0.0
-            )
-            if self.serving.zero_experts and out["moe_pairs_routed"]:
-                # How many of a token's routes cost nothing, and how many
-                # experts it has left: per live token and expert block.
-                share = out["moe_zero_pairs"] / out["moe_pairs_routed"]
-                out["zero_expert_pairs_pct"] = 100.0 * share
-                out["real_experts_per_token_mean"] = (
-                    self.cfg.top_k * (1.0 - share)
-                )
-                out["real_experts_per_token_max"] = self._real_experts_max
             out["platform"] = self.platform
             out["device_kind"] = jax.devices()[0].device_kind
             out["paged_attn_kernel"] = self.paged_attn_kernel
@@ -1348,16 +1276,6 @@ class LLMEngine:
             # (paged_kv.paged_verify): Pallas and in place beside the
             # kernel, XLA's scatter beside the gather.
             out["kv_write_kernel"] = out["paged_attn_kernel"]
-            if self._pairs_per_token:
-                # The sorted expert form's combine follows the platform
-                # alone (models/moe.py): the kernel on a TPU, XLA's
-                # scatter-add elsewhere.
-                out["moe_combine_kernel"] = self.platform == "tpu"
-            if self.serving.recurrent_blocks:
-                # So does the decode step's state update
-                # (llm/hybrid_kv.py): ops/pallas/state_step.py over the
-                # decoding slots on a TPU, XLA's masked form elsewhere.
-                out["state_step_kernel"] = self.platform == "tpu"
             out["active_requests"] = len(self._active)
             out["queued_requests"] = len(self._queue)
             out["prefilling"] = self._prefilling is not None

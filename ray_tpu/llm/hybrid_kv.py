@@ -8,7 +8,7 @@ an expert FFN behind its own norm and a residual add. Nemotron-H's
 layers are one each; a Granite 4.0-H layer is two (its mixer, then
 ``E``) and multiplies each sublayer's output, the embedding, the
 attention scores and the logits by numbers of its config, which the
-programs skip where they are 1 (``_embed``, ``_residual``, ``_head``).
+programs skip where they are 1 (``_embed``, ``_residual``, ``_logits``).
 A Qwen3-Next layer is two as well; its recurrent mixer is another
 letter (``G``, the gated delta rule) with its own leaves of the cache,
 and its attention block norms, rotates and gates (`_attention_inputs`),
@@ -107,11 +107,13 @@ from ray_tpu.llm.paged_kv import (
     _decode_geometry,
     _flat_pool,
     _gather_page_attention,
+    _head,
     _prefill_kernel_attention,
     _sample_tokens,
     _write_pages,
     init_paged_kv,
 )
+from ray_tpu.llm.serving import Serving, _new_record, _note, _record, leaf_bytes
 from ray_tpu.models.glm5_next import (
     dsa_decode,
     dsa_prefill,
@@ -234,20 +236,12 @@ def init_hybrid_cache(
     return cache
 
 
-def hybrid_cache_bytes(cache) -> tuple[int, int]:
-    """(bytes of the page pools, bytes of whatever else the cache holds:
-    per-slot state), `paged_kv.kv_cache_bytes` with this cache's other
-    pools counted as pools."""
-    pool = sum(int(cache[leaf].nbytes) for leaf in _PAGED if leaf in cache)
-    return pool, sum(int(v.nbytes) for v in cache.values()) - pool
-
-
 def _embed(params, tokens, cfg):
     x = params["tok_emb"][tokens]
     if cfg.embedding_multiplier != 1.0:
         x = x * cfg.embedding_multiplier
     if cfg.hc_mult:
-        # A copy a residual stream: [.., n, d] from here to `_head`.
+        # A copy a residual stream: [.., n, d] from here to `_logits`.
         x = jnp.broadcast_to(
             x[..., None, :], (*x.shape[:-1], cfg.hc_mult, x.shape[-1])
         )
@@ -283,58 +277,6 @@ def _experts(x, p, cfg, rows_live, record):
     )
     _note(record, aux)
     return _residual(x, out, cfg, mix)
-
-
-def _new_record():
-    """What a program's expert blocks leave: `_note` fills, `_record`
-    sums."""
-    return {"routes": [], "pairs_here": [], "experts_touched": [],
-            "sorted_rows": [], "selected": [], "zero_pairs": [],
-            "real_max": []}
-
-
-def _note(record, aux):
-    """An expert block's counters, from `moe_ffn`'s ``aux``."""
-    record["routes"].append(aux["routes"])
-    record["pairs_here"].append(aux["expert_load"].sum())
-    record["experts_touched"].append((aux["expert_load"] > 0).sum())
-    record["sorted_rows"].append(aux["sorted_rows"])
-    if "zero_pairs" in aux:  # a router with identity outputs
-        record["zero_pairs"].append(aux["zero_pairs"])
-        record["real_max"].append(aux["real_max"])
-
-
-def _record(record):
-    """Per program: ``routes`` [L_expert, T, k] (each token's experts, of
-    all the model's) and ``counts`` int32[4]: the pairs of live rows
-    whose expert is held, the held experts that got a row, the rows the
-    sorted form ran its grouped matmuls over and the pairs it was given
-    (padding's and absent experts' among them), each summed over the
-    expert blocks; int32[6] where the router has identity outputs: the
-    live rows' routes to one, summed, and the most real experts a live
-    row chose in any block."""
-    zero = (
-        [jnp.stack([sum(record["zero_pairs"]),
-                    jnp.stack(record["real_max"]).max()])]
-        if record["zero_pairs"] else []
-    )
-    selected = (
-        # [L_latent, T, index_blocks]: the blocks each query attended
-        # beside its own (-1: fewer candidates), where the model selects.
-        {"selected": jnp.stack(record["selected"])} if record["selected"]
-        else {}
-    )
-    return {
-        **selected,
-        "routes": jnp.stack(record["routes"]),
-        "counts": jnp.concatenate([
-            jnp.stack(
-                [sum(record["pairs_here"]), sum(record["experts_touched"])]
-            ),
-            sum(record["sorted_rows"]),
-            *zero,
-        ]).astype(jnp.int32),
-    }
 
 
 def _carried(cache, k_pages, v_pages, state) -> HybridCache:
@@ -533,24 +475,14 @@ def _dense_ffn(x, p, cfg):
     return _residual(x, out, cfg, mix)
 
 
-def _head(x, params, cfg=None):
-    """Final norm and the head; ``cfg`` where the model ties the head to
-    the embedding or divides its logits (`llm/latent_kv.py`'s does
-    neither and passes none). The sum of the residual streams where
-    the programs carry more than one."""
-    if cfg is not None and cfg.hc_mult:
+def _logits(x, params, cfg):
+    """Final norm and the head as the config has them, on the sum of
+    the residual streams where the programs carry more than one."""
+    if cfg.hc_mult:
         x = x.astype(jnp.float32).sum(-2).astype(x.dtype)
-    x = rms_norm(
-        x, params["final_norm"], 1e-5 if cfg is None else cfg.norm_eps
+    return _head(
+        x, params, cfg.norm_eps, cfg.tie_word_embeddings, cfg.logits_scaling
     )
-    if cfg is not None and cfg.tie_word_embeddings:
-        logits = jnp.einsum("...d,vd->...v", x, params["tok_emb"])
-    else:
-        logits = x @ params["lm_head"]
-    logits = logits.astype(jnp.float32)
-    if cfg is not None and cfg.logits_scaling != 1.0:
-        logits = logits / cfg.logits_scaling
-    return logits
 
 
 def _hybrid_prefill(
@@ -652,7 +584,7 @@ def _hybrid_prefill(
         seen[kind] += 1
     last = jax.lax.dynamic_slice_in_dim(x, length - 1 - start, 1, axis=1)
     carried = _carried(cache, k_pages, v_pages, state)
-    return _head(last, params, cfg), carried, _record(record)
+    return _logits(last, params, cfg), carried, _record(record)
 
 
 def prefill_program(cfg: NemotronHConfig, n_write_pages: int,
@@ -780,7 +712,7 @@ def hybrid_decode(
                 )
             x = _residual(x, _attention_output(attn, gate, p, cfg), cfg)
         seen[kind] += 1
-    logits = _head(x, params, cfg)  # [B, 1, V]
+    logits = _logits(x, params, cfg)  # [B, 1, V]
     sampled = _sample_tokens(logits, temperature, rng_key)
     carried = _carried(cache, k_pages, v_pages, state)
     return sampled, logits[:, 0], carried, _record(record)
@@ -792,58 +724,30 @@ def _band_pairs_before(m: int, w: int) -> int:
     return m * (m + 1) // 2 if m <= w else w * (w + 1) // 2 + (m - w) * w
 
 
-class HybridServing:
+class HybridServing(Serving):
     """What `LLMEngine` serves a `NemotronHConfig` (or a family that
-    subclasses it and brings its own ``init_weights``) through (see
-    `paged_kv.LlamaServing` for the convention)."""
+    subclasses it and brings its own ``init_weights``) through."""
 
     no_speculation = (
         "with recurrent blocks: a rejected draft would need the slot's "
         "state rolled back, which is not written"
     )
-    logits_last_only = True  # prefill returns the last real token's logits
-    # A prompt's last chunk is padded to the chunk's length (the program
-    # takes the true length), so that chunks compile to one shape.
-    fixed_chunks = True
+    _paged = _PAGED
 
     def __init__(self, cfg: NemotronHConfig, init_weights=init_params):
-        self.cfg = cfg
-        self.pairs_per_token = cfg.top_k * cfg.count("E")
-        self.zero_experts = cfg.zero_experts
-        self.recurrent_blocks = sum(cfg.count(kind) for kind in _RECURRENT)
-        self._init_weights = init_weights
+        super().__init__(cfg, init_weights, cfg.count("E"))
         self._prefill_programs = self._live_tokens = self._prefill_pairs = 0
         self._window_pairs = self._window_bytes = 0
         self._index_pairs = self._selected_pairs = self._causal_pairs = 0
         self._latent_bytes = self._index_bytes = 0
 
-    def init_weights(self, key):
-        return self._init_weights(key, self.cfg)
-
-    def logical_axes(self):
-        raise NotImplementedError(
-            "a mesh: the hybrid programs are written for one chip's share "
-            "(experts across chips and their exchange are not)"
-        )
-
-    def held_weights(self, params):
-        return params  # `init_params` makes the tree as it is held
-
     def init_cache(self, num_pages: int, page_size: int, max_batch: int,
                    shardings=None):
         cache = init_hybrid_cache(self.cfg, num_pages, page_size, max_batch)
-        self._window_bytes = sum(
-            int(cache[leaf].nbytes) for leaf in ("win_k", "win_v")
-            if leaf in cache
-        )
-        self._latent_bytes = int(cache["latent"].nbytes) if "latent" in cache else 0
-        self._index_bytes = sum(
-            int(cache[leaf].nbytes) for leaf in ("index", "index_tail")
-            if leaf in cache
-        )
+        self._window_bytes = leaf_bytes(cache, ("win_k", "win_v"))
+        self._latent_bytes = leaf_bytes(cache, ("latent",))
+        self._index_bytes = leaf_bytes(cache, ("index", "index_tail"))
         return cache
-
-    cache_bytes = staticmethod(hybrid_cache_bytes)
 
     def counters(self) -> dict:
         # Over the prefill programs run: how many; the live tokens the
@@ -867,7 +771,9 @@ class HybridServing:
         # the tokens whose streams a sublayer mixed, summed over the
         # sublayers, where the model carries more than one.
         gdn_tokens = self.cfg.count("G") * self._live_tokens
-        return {
+        on_tpu = chip.platform() == "tpu"
+        out = {
+            **super().counters(),
             "kda_scan_tokens": self.cfg.count("K") * self._live_tokens,
             "dsa_tokens": self.cfg.count("L") * self._live_tokens,
             "dsa_index_pairs": self._index_pairs,
@@ -882,12 +788,18 @@ class HybridServing:
             "prefill_programs": self._prefill_programs,
             "ssm_scan_tokens": self.cfg.count("M") * self._live_tokens,
             "gdn_scan_tokens": gdn_tokens,
-            "gdn_kernel_tokens": gdn_tokens if chip.platform() == "tpu" else 0,
+            "gdn_kernel_tokens": gdn_tokens if on_tpu else 0,
             "prefill_attn_pairs": self._prefill_pairs,
             "prefill_window_pairs": self._window_pairs,
             "window_tokens": self.cfg.count("W") * self._live_tokens,
             "window_bytes": self._window_bytes,
         }
+        if any(self.cfg.count(kind) for kind in _RECURRENT):
+            # A decode step's state update follows the platform alone:
+            # `ops/pallas/state_step.py` over the decoding slots on a
+            # TPU, XLA's masked form elsewhere.
+            out["state_step_kernel"] = on_tpu
+        return out
 
     def _count(self, start: int, width: int, length: int) -> None:
         n = max(min(width, length - start), 0)
@@ -911,16 +823,6 @@ class HybridServing:
             )
             self._causal_pairs += layers * int((t + 1).sum())
 
-    def prefill(self, params, tokens, cache, pages, *, n_write_pages, slot,
-                length, use_kernel=False):
-        self._count(0, tokens.shape[1], length)
-        return prefill_program(
-            self.cfg, n_write_pages, n_write_pages, use_kernel
-        )(
-            params, tokens, cache, pages, np.int32(0), np.int32(slot),
-            np.int32(length),
-        )
-
     def prefill_chunk(self, params, tokens, cache, pages, start, *,
                       n_write_pages, chunk_pages, slot, length,
                       use_kernel=False):
@@ -932,12 +834,5 @@ class HybridServing:
             np.int32(length),
         )
 
-    def decode(self, params, tokens, cache, block_tables, positions,
-               temperature, rng_key, *, use_kernel, stochastic, active):
-        sampled, logits, cache, record = hybrid_decode(
-            params, tokens, cache, block_tables, positions, active,
-            temperature, rng_key, cfg=self.cfg, use_kernel=use_kernel,
-        )
-        # No drafts: the engine's acceptance arrays are [B, 0].
-        none = np.zeros((tokens.shape[0], 0), np.int32)
-        return sampled, logits, cache, none.astype(bool), none, record
+    def _decode_one(self, *args, **kwargs):
+        return hybrid_decode(*args, **kwargs)  # the module's, as the call finds it

@@ -65,8 +65,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu._private import chip
-from ray_tpu.llm.hybrid_kv import _head, _new_record, _note, _record
-from ray_tpu.llm.paged_kv import _NEG_INF, _decode_geometry, _sample_tokens
+from ray_tpu.llm.paged_kv import (
+    _NEG_INF,
+    _decode_geometry,
+    _head,
+    _sample_tokens,
+)
+from ray_tpu.llm.serving import Serving, _new_record, _note, _record
 from ray_tpu.models.moe import moe_ffn
 from ray_tpu.models.pangu_ultra_moe import (
     LatentShape,
@@ -398,49 +403,25 @@ def latent_decode(
     return sampled, logits[:, 0], cache, _record(record)
 
 
-class LatentServing:
+class LatentServing(Serving):
     """What `LLMEngine` serves a `PanguUltraMoEConfig` or a
-    `LongcatFlashConfig` through (see `paged_kv.LlamaServing` for the
-    convention); ``init_weights`` is the family's initialiser."""
+    `LongcatFlashConfig` through; ``init_weights`` is the family's
+    initialiser."""
 
-    no_speculation = (
-        "the latent decode program takes one token a slot: draft "
-        "acceptance is `paged_verify`'s own and is not shared"
-    )
-    logits_last_only = True  # prefill returns the last real token's logits
-    fixed_chunks = True  # the program takes the true length
-    recurrent_blocks = 0  # no per-slot state beside the pages
+    _paged = ("latent",)
 
     def __init__(self, cfg: LatentShape, init_weights):
-        self.cfg = cfg
-        self._init_weights = init_weights
-        self.pairs_per_token = cfg.top_k * (cfg.count("E") + cfg.count("S"))
-        self.zero_experts = cfg.zero_experts
+        super().__init__(cfg, init_weights, cfg.count("E") + cfg.count("S"))
         self._tokens_expanded = self._prefill_programs = self._prefill_pairs = 0
-
-    def init_weights(self, key):
-        return self._init_weights(key, self.cfg)
-
-    def logical_axes(self):
-        raise NotImplementedError(
-            "a mesh: the latent programs are written for one chip's share "
-            "(experts across chips and their exchange are not)"
-        )
-
-    def held_weights(self, params):
-        return params  # `init_params` makes the tree as it is held
 
     def init_cache(self, num_pages: int, page_size: int, max_batch: int,
                    shardings=None):
         return init_latent_cache(self.cfg, num_pages, page_size)
 
-    @staticmethod
-    def cache_bytes(cache) -> tuple[int, int]:
-        return int(cache["latent"].nbytes), 0
-
     def counters(self) -> dict:
         cfg = self.cfg
         return {
+            **super().counters(),
             # What the arithmetic needs of a cached token, all attention
             # sublayers (`pool_bytes` has the cells as held,
             # `cell_width` wide).
@@ -454,8 +435,8 @@ class LatentServing:
             "latent_prefill_pairs": self._prefill_pairs,
         }
 
-    def _prefill(self, params, tokens, cache, pages, start, length,
-                 n_write_pages, chunk_pages, use_kernel):
+    def prefill_chunk(self, params, tokens, cache, pages, start, *,
+                      n_write_pages, chunk_pages, slot, length, use_kernel):
         """One prefill program, and its counters: the (query, key) pairs
         it attends under the causal mask, and the cached tokens it turns
         back into keys and values (the kernel path: the whole table; the
@@ -477,22 +458,5 @@ class LatentServing:
             params, tokens, cache, pages, start, np.int32(length)
         )
 
-    def prefill(self, params, tokens, cache, pages, *, n_write_pages, slot,
-                length, use_kernel):
-        return self._prefill(params, tokens, cache, pages, np.int32(0), length,
-                             n_write_pages, n_write_pages, use_kernel)
-
-    def prefill_chunk(self, params, tokens, cache, pages, start, *,
-                      n_write_pages, chunk_pages, slot, length, use_kernel):
-        return self._prefill(params, tokens, cache, pages, start, length,
-                             n_write_pages, chunk_pages, use_kernel)
-
-    def decode(self, params, tokens, cache, block_tables, positions,
-               temperature, rng_key, *, use_kernel, stochastic, active):
-        sampled, logits, cache, record = latent_decode(
-            params, tokens, cache, block_tables, positions, active,
-            temperature, rng_key, cfg=self.cfg, use_kernel=use_kernel,
-        )
-        # No drafts: the engine's acceptance arrays are [B, 0].
-        none = np.zeros((tokens.shape[0], 0), np.int32)
-        return sampled, logits, cache, none.astype(bool), none, record
+    def _decode_one(self, *args, **kwargs):
+        return latent_decode(*args, **kwargs)  # the module's, as the call finds it

@@ -66,6 +66,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu._private import chip
+from ray_tpu.llm.serving import Serving
 from ray_tpu.models.llama import LlamaConfig, Params
 from ray_tpu.ops.attention import causal_attention
 from ray_tpu.ops.norms import rms_norm
@@ -360,6 +361,20 @@ def _decode_attention(q, k, v, k_pages, v_pages, base, geometry, positions,
     return attn, k_pages, v_pages
 
 
+def _head(x, params, norm_eps=1e-5, tied=False, divisor=1.0):
+    """Final norm and the head on x [..., d]: float32 logits, over
+    ``divisor``; ``tied``: the head is the embedding."""
+    x = rms_norm(x, params["final_norm"], norm_eps)
+    if tied:
+        logits = jnp.einsum("...d,vd->...v", x, params["tok_emb"])
+    else:
+        logits = x @ params["lm_head"]
+    logits = logits.astype(jnp.float32)
+    if divisor != 1.0:
+        logits = logits / divisor
+    return logits
+
+
 def _sample_tokens(logits, temperature, rng_key):
     """Per-position sampling of logits [B, K, V]: greedy for temp 0,
     temperature draw otherwise (the full-p sample — used for position
@@ -468,9 +483,7 @@ def paged_prefill(
         return x, k_pages, v_pages
 
     x, pool = _scan_layers(body, x, params, pool)
-    x = rms_norm(x, params["final_norm"])
-    logits = (x @ params["lm_head"]).astype(jnp.float32)
-    return logits, pool
+    return _head(x, params), pool
 
 
 @partial(
@@ -542,9 +555,7 @@ def paged_prefill_chunk(
         return x, k_pages, v_pages
 
     x, pool = _scan_layers(body, x, params, pool)
-    x = rms_norm(x, params["final_norm"])
-    logits = (x @ params["lm_head"]).astype(jnp.float32)
-    return logits, pool
+    return _head(x, params), pool
 
 
 @partial(
@@ -625,8 +636,7 @@ def paged_verify(
         return x, k_pages, v_pages
 
     x, pool = _scan_layers(body, x, params, pool)
-    x = rms_norm(x, params["final_norm"])
-    logits = (x @ params["lm_head"]).astype(jnp.float32)
+    logits = _head(x, params)
 
     sampled = _sample_tokens(logits, temperature, rng_key)
 
@@ -685,35 +695,22 @@ def paged_verify(
     return sampled, logits[:, 0], pool, accept, rej
 
 
-def kv_cache_bytes(cache) -> tuple[int, int]:
-    """(bytes of the K and V page pools, bytes of whatever else the
-    cache holds: per-slot state) of a cache with ``k`` and ``v``."""
-    pool = int(cache["k"].nbytes + cache["v"].nbytes)
-    return pool, sum(int(v.nbytes) for v in cache.values()) - pool
-
-
-class LlamaServing:
+class LlamaServing(Serving):
     """What `LLMEngine` serves a Llama-shaped model through: the page
-    pool and the three programs above, under the calling convention the
-    engine has for every model. The engine also tells a program which
-    slot a prompt is for, its true length and which slots are decoding;
-    these programs need none of that (pages hold all their state, and
-    a padded tail or a free slot writes cells nobody attends), so it is
-    dropped here but for the count of what prefill attends."""
+    pool and the three programs above. These need nothing of the slot,
+    the true length or the decoding slots (pages hold all their state,
+    and a padded tail or a free slot writes cells nobody attends), so
+    they are dropped here but for the count of what prefill attends. No
+    expert blocks: no record."""
 
     no_speculation = None  # `paged_verify` accepts drafts
     logits_last_only = False  # prefill returns every position's logits
     fixed_chunks = False  # the last chunk of a prompt is as long as it is
-    pairs_per_token = 0  # no expert blocks: its programs keep no record
-    zero_experts = 0  # nor a router with identity outputs
-    recurrent_blocks = 0  # no per-slot state beside the pages
+    _paged = ("k", "v")
 
     def __init__(self, cfg: LlamaConfig):
-        self.cfg = cfg
+        super().__init__(cfg, _init_weights)
         self._prefill_pairs = 0
-
-    def init_weights(self, key):
-        return _init_weights(key, cfg=self.cfg)
 
     def logical_axes(self):
         from ray_tpu.models.llama import param_logical_axes
@@ -721,8 +718,7 @@ class LlamaServing:
         return param_logical_axes(self.cfg)
 
     def held_weights(self, params):
-        """`params` as the programs multiply by them; the caller's own
-        arrays where nothing is to be cast."""
+        # The caller's own arrays where nothing is to be cast.
         held = _cast_weights.eval_shape(params, cfg=self.cfg)
         if [x.dtype for x in jax.tree.leaves(held)] == [
             x.dtype for x in jax.tree.leaves(params)
@@ -737,14 +733,12 @@ class LlamaServing:
             return make()
         return jax.jit(make, out_shardings=shardings)()
 
-    cache_bytes = staticmethod(kv_cache_bytes)
-
     def counters(self) -> dict:
         # The causal (query, key) pairs the prefill calls' arithmetic
         # needed, summed over layers (heads not among them): a prompt's
         # own tokens against what lies at or before each, whatever the
         # padding, the table's width or the path computed besides.
-        return {"prefill_attn_pairs": self._prefill_pairs}
+        return {**super().counters(), "prefill_attn_pairs": self._prefill_pairs}
 
     def _count_pairs(self, start: int, width: int, length) -> None:
         n = width if length is None else max(min(width, length - start), 0)

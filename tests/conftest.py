@@ -151,3 +151,35 @@ def generate_at_lag0():
         return outs
 
     return generate
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One device of a described v5e:2x2 for `test_tpu_aot_compile.py`
+    (the kernels) and `test_tpu_aot_programs.py` (the serving programs),
+    with the persistent compile cache off: such a compile is written to
+    it but cannot be read back without a chip, and the next one would
+    warn. Not `autouse`, and describing nothing until a test asks: a
+    module that names no `v5e` never loads libtpu."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    # libtpu lets one process a host load it, to protect an attached
+    # chip. Nothing is attached here, and test processes run side by
+    # side (xdist): read when libtpu loads, which is the call below.
+    with pytest.MonkeyPatch.context() as env:
+        env.setenv("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+        try:
+            topo = topologies.get_topology_desc(
+                platform="tpu", topology_name="v5e:2x2"
+            )
+        # tpulint: allow(broad-except reason=whatever keeps the TPU compiler from describing a topology here (no libtpu, no compiler for this chip) skips these tests; they have no CPU meaning)
+        except Exception as e:  # noqa: BLE001
+            pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()

@@ -13,7 +13,7 @@ abstract value of every argument, against what those two scripts hand to
 the second kind of call compiles nothing that the first had not.
 
 (The scripts' `.lower` is stood in for, so that nothing is traced for a
-TPU here; tests/test_tpu_aot_compile.py compiles the real programs.)
+TPU here; tests/test_tpu_aot_programs.py compiles the real programs.)
 """
 
 import json

@@ -389,8 +389,8 @@ def test_host_phases_add_up_to_the_step():
 
 
 def test_a_fold_of_the_expert_counters_is_a_readback():
-    """`_fold_moe_counts` from `_account` (every 512 programs) lies in
-    `readback:moe_counts`, whose own seconds go to `host_s_sum.readback`;
+    """The serving object's `fold` from `_account` (every 512 programs)
+    lies in `readback:moe_counts`, whose own seconds go to `host_s_sum.readback`;
     from `stats()` it is nobody's phase."""
     from ray_tpu.models.nemotron_h import NEMOTRON_H_PRESETS
 
@@ -399,10 +399,10 @@ def test_a_fold_of_the_expert_counters_is_a_readback():
     )
     engine.add_request(SHORT, SamplingParams(max_tokens=3))
     engine.step()
-    assert engine._moe_counts
-    engine._moe_counts *= 512
+    assert engine.serving._backlog
+    engine.serving._backlog *= 512
     engine.step()
-    assert len(engine._moe_counts) < 512
+    assert len(engine.serving._backlog) < 512
     assert engine._stats["host_s_sum.readback"] > 0
     booked = engine._stats["host_s_sum.readback"]
     while engine.has_unfinished():

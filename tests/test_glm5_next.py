@@ -506,8 +506,8 @@ def test_the_cache_is_pools_on_one_table_and_state_a_slot(params):
     assert small["kda"].shape == (5, 2, 4, 16, 16)
     assert small["kda_conv"].shape == (5, 2, 3, 3 * 4 * 16)
     assert small["k"].shape[0] == 0  # no layer attends by `paged_kv`'s pages
-    pool_s, state_s = hybrid_kv.hybrid_cache_bytes(small)
-    pool_l, state_l = hybrid_kv.hybrid_cache_bytes(large)
+    pool_s, state_s = CFG.serving().cache_bytes(small)
+    pool_l, state_l = CFG.serving().cache_bytes(large)
     assert state_s == state_l and pool_l * 9 == pool_s * 17
     here = os.path.join(os.path.dirname(__file__), "..", "benchmarks")
     with open(os.path.join(here, "configs", "glm53flash-serve1.json")) as f:

@@ -128,7 +128,7 @@ def test_one_decode_program_whatever_the_temperatures(params):
     engine.generate([[1, 2, 3]], SamplingParams(max_tokens=4))
     # Two signatures of one compiled program: `tokens` from the host
     # (the first step) and as the step in flight left them on the device
-    # (tests/test_tpu_aot_compile.py counts the compiles).
+    # (tests/test_tpu_aot_programs.py counts the compiles).
     assert paged_verify._cache_size() == before + 2
     outs = engine.generate(
         [[4, 5, 6], [7, 8]], SamplingParams(max_tokens=4, temperature=0.8)

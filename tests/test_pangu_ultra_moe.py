@@ -21,7 +21,7 @@ import pytest
 
 from benchmarks import reference_pangu_ultra_moe as reference
 from benchmarks.models import pangu_ultra_moe as bench_model
-from ray_tpu.llm import latent_kv
+from ray_tpu.llm import latent_kv, serving
 from ray_tpu.llm.engine import LLMEngine, SamplingParams
 from ray_tpu.llm.paged_kv import _decode_geometry
 from ray_tpu.models.moe import moe_ffn
@@ -160,7 +160,7 @@ def path_cfg(request):
 def _ffn(p, x, cfg, kind="E"):
     """The sublayer as a program calls it: with the mask of the rows
     that carry a token (here all), so the sorted form is the bounded one."""
-    record = latent_kv._new_record()
+    record = serving._new_record()
     live = jnp.ones(len(x), bool)
     return latent_kv._ffn(x[None], kind, p, cfg, live, record)[0], record
 

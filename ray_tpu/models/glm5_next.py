@@ -94,6 +94,7 @@ from ray_tpu.models.nemotron_h import (
 )
 from ray_tpu.models.qwen3_next import _L2_EPS, _unit, _unit_lower_inverse
 from ray_tpu.ops.norms import rms_norm
+from ray_tpu.ops.pallas import mhc_streams
 from ray_tpu.ops.pallas.kda_chunk import kda_chunk_rule
 from ray_tpu.ops.pallas.state_step import kda_state_step
 
@@ -112,6 +113,13 @@ _KDA_SUBCHUNK = 16
 # / 64k context, on a v5e (my chip run, PR 59): 33.8 / 65.0 ms at 64,
 # 36.3 / 65.6 at 128, 41.0 / 69.2 at 256.
 _DSA_QUERY_BLOCK = 64
+# Tokens from which the residual path takes its kernels on a TPU
+# (`_mhc_by_kernels`): a tile of `ops/pallas/mhc_streams.py`. A prefill
+# chunk's 2,048 read 0.288 ms a sublayer there for 1.82 in XLA's form; a
+# decode step's slots keep XLA's form, which reads no slower alone
+# (0.064 ms a sublayer at 16 rows for the kernels' 0.065, 0.065 at 32
+# for 0.076: ten sublayers a call on a v5e, my chip run, PR 64).
+_MHC_KERNEL_ROWS = 128
 # Pooled keys a prefill chunk's indexer scores at once: the float32
 # products are chunk x index_heads x this (2,048 x 32 x 1,024: 268 MB).
 _DSA_INDEX_BLOCK = 1024
@@ -413,12 +421,32 @@ def sinkhorn(m, iters: int, eps: float):
     return jax.lax.fori_loop(0, iters, one, m)
 
 
+def _mhc_by_kernels(x) -> bool:
+    """Whether the streams x [.., n, d] take ``ops/pallas/mhc_streams.py``:
+    on a TPU, from `_MHC_KERNEL_ROWS` tokens on. The platform and the row
+    count decide, as `kda_chunked` and `_DENSE_ATTENTION_KEYS` do."""
+    return (
+        chip.platform() == "tpu"
+        and math.prod(x.shape[:-2]) >= _MHC_KERNEL_ROWS
+    )
+
+
 def mhc_mix(x, p, cfg: Glm5NextConfig):
     """A sublayer's input from the streams x [.., n, d]: ``h = Hpre X``
     [.., d] in ``x``'s dtype, and what `mhc_spread` writes back by:
-    ``Hres`` [.., n, n] and ``Hpost`` [.., n], float32."""
+    ``Hres`` [.., n, n] and ``Hpost`` [.., n], float32.
+
+    On a TPU one call of ``ops/pallas/mhc_streams.py`` (PR 64); elsewhere
+    and under `_MHC_KERNEL_ROWS` tokens XLA's form below, which is tier
+    1's path and the kernel's oracle."""
     n = cfg.hc_mult
     with jax.named_scope("mhc:mix"):
+        if _mhc_by_kernels(x):
+            h, h_res, h_post = mhc_streams.mhc_mix(
+                x, p["proj"], p["scale"], p["b_pre"], p["b_post"],
+                p["b_res"], iters=cfg.hc_sinkhorn_iters, eps=cfg.hc_eps,
+            )
+            return h, (h_res, h_post)
         flat = x.reshape(*x.shape[:-2], -1).astype(jnp.float32)
         var = jnp.mean(flat * flat, axis=-1, keepdims=True)
         unit = flat * jax.lax.rsqrt(var + cfg.hc_eps)
@@ -439,8 +467,11 @@ def mhc_mix(x, p, cfg: Glm5NextConfig):
 
 def mhc_spread(x, out, h_res, h_post):
     """``Hres X + Hpost^T y``: the streams x [.., n, d] after a sublayer
-    whose output is ``out`` [.., d]."""
+    whose output is ``out`` [.., d]. On a TPU the other call of
+    ``ops/pallas/mhc_streams.py``, by `mhc_mix`'s rule."""
     with jax.named_scope("mhc:spread"):
+        if _mhc_by_kernels(x):
+            return mhc_streams.mhc_spread(x, out, h_res, h_post)
         mixed = jnp.einsum("...ij,...jd->...id", h_res, x.astype(jnp.float32))
         wrote = h_post[..., None] * out.astype(jnp.float32)[..., None, :]
         return (mixed + wrote).astype(x.dtype)

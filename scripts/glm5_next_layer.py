@@ -3,7 +3,7 @@
 512-wide cell, 32 indexer heads, four streams of 4,096, 36 of 288
 experts), 2,048 tokens a call: what one prefill chunk's parts take.
 
-    chiprun -- python scripts/glm5_next_layer.py [kda]
+    chiprun -- python scripts/glm5_next_layer.py [kda | mhc]
 
 Prints a JSON line a variant: the KDA mixer in XLA's form
 (`glm5_next._kda_rule` between XLA's passes: what `kda_chunked` was on
@@ -15,10 +15,13 @@ both left to XLA as PR 60 left them, and at several (heads, groups) a
 grid step; every token live and with the last tenth padding, with the
 distance of the output from XLA's form's and of the state from the
 token-a-step recurrence's (``kda`` alone stops there);
-the sparse latent mixer as the last chunk
-of a 16k and of a 64k context at several query blocks; one residual mix
-and spread; the expert FFN; the dense FFN. PERF.md section 6, PRs 59
-60 and 62, has the tables this made.
+one residual mix and spread, then the residual path by variant (XLA's
+form, `ops/pallas/mhc_streams.py`'s two calls) at 2,048 rows and at a
+decode step's, with the kernels' distance from XLA's form (``mhc``
+alone is this); the sparse latent mixer as the last chunk of a 16k and
+of a 64k context at several query blocks; the dense FFN; the expert
+FFN. PERF.md section 6, PRs 59, 60, 62 and 64, has the tables this
+made.
 """
 
 import dataclasses
@@ -35,7 +38,7 @@ sys.path.insert(0, ".")
 from ray_tpu.llm import hybrid_kv  # noqa: E402
 from ray_tpu.models import glm5_next  # noqa: E402
 from ray_tpu.models.moe import moe_ffn  # noqa: E402
-from ray_tpu.ops.pallas import gdn_chunk, kda_chunk  # noqa: E402
+from ray_tpu.ops.pallas import gdn_chunk, kda_chunk, mhc_streams  # noqa: E402
 
 TOKENS = 2048
 CFG = glm5_next.Glm5NextConfig(
@@ -43,6 +46,12 @@ CFG = glm5_next.Glm5NextConfig(
 )
 # (heads, groups of 128 tokens) a grid step of `ops/pallas/kda_chunk.py`.
 KDA_SWEEP = [(1, 1), (2, 1), (4, 1), (8, 1), (2, 2)]
+# (tokens a grid step, rows, lanes between a load and a store) of
+# `ops/pallas/mhc_streams.py`.
+MHC_SWEEP = [(128, 16, 512), (256, 16, 512), (512, 16, 512), (128, 16, 1024),
+             (128, 16, 256), (128, 32, 512), (256, 32, 1024)]
+# Sublayers a timed call of the residual path: a chunk program's ten.
+MHC_CHAIN = 10
 
 
 def timed(fn, *args, calls=10):
@@ -66,6 +75,89 @@ def recurrence(q, k, v, beta, g, state):
         return s, None
 
     return jax.lax.scan(step, state, (q, k, v, beta, g))[0]
+
+
+def residual_path(hc, key, u):
+    """One residual mix and spread as the platform chooses it, the
+    streams [1, T, n, d] a call, as PR 59 timed it
+    (`mhc_mix_and_spread_ms`; a call of under ~0.6 ms is the HOST's
+    dispatch on the one-chip machine, not the device: PR 64). Then the
+    path by variant (XLA's form, `ops/pallas/mhc_streams.py`'s two
+    calls) at a prefill chunk's rows and at a decode step's, as a program
+    runs it: `MHC_CHAIN` sublayers a call, the streams handed from a
+    spread to the next mix (flat [T, n d] at the call's ends, no copy
+    into another layout inside), each a mix, the sublayer's output added
+    and a spread; ms a sublayer; the kernels' distance from XLA's form
+    after the first sublayer; and the two calls by (tile, rows, lanes)."""
+    n, d = CFG.hc_mult, CFG.d_model
+    on_the_chip = glm5_next.chip
+    x = jax.random.normal(key, (1, TOKENS, n, d)).astype(CFG.dtype)
+
+    def one_mix(p, x, y):
+        hh, mix = glm5_next.mhc_mix(x, p, CFG)
+        return glm5_next.mhc_spread(x, y + hh, *mix)
+
+    ms, _ = timed(jax.jit(one_mix), hc, x, u[None])
+    print(json.dumps({"mhc_mix_and_spread_ms": ms}), flush=True)
+
+    def path(platform, sublayers):
+        def chain(p, flat, y):
+            glm5_next.chip = types.SimpleNamespace(platform=lambda: platform)
+            x = flat.reshape(1, -1, n, d)
+            for _ in range(sublayers):
+                h, mix = glm5_next.mhc_mix(x, p, CFG)
+                x = glm5_next.mhc_spread(x, y + h, *mix)
+            return x.reshape(flat.shape), h, *mix
+        return jax.jit(chain)
+
+    def operands(tokens):
+        return (
+            jax.random.normal(key, (tokens, n * d)).astype(CFG.dtype),
+            jax.random.normal(
+                jax.random.fold_in(key, 1), (1, tokens, d)).astype(CFG.dtype),
+        )
+
+    def a_sublayer(platform, flat, y):
+        return round(
+            timed(path(platform, MHC_CHAIN), hc, flat, y)[0] / MHC_CHAIN, 4)
+
+    by_rows = glm5_next._MHC_KERNEL_ROWS
+    glm5_next._MHC_KERNEL_ROWS = 0  # the platform alone chooses, below
+    for tokens in (TOKENS, 32, 16):
+        flat, y = operands(tokens)
+        print(json.dumps({"mhc": "xla", "tokens": tokens,
+                          "ms_a_sublayer": a_sublayer("cpu", flat, y)}),
+              flush=True)
+        line = {"mhc": "kernels", "tokens": tokens}
+        try:
+            line["ms_a_sublayer"] = a_sublayer("tpu", flat, y)
+            got = path("tpu", 1)(hc, flat, y)
+            want = path("cpu", 1)(hc, flat, y)
+            line.update({
+                f"{name}_max_abs_err": float(jnp.max(jnp.abs(
+                    a.astype(jnp.float32) - b.astype(jnp.float32))))
+                for name, a, b in zip(
+                    ("streams", "h", "h_res", "h_post"), got, want)
+            })
+        except Exception as e:  # noqa: BLE001 - a shape the chip refuses
+            line["refused"] = repr(e)[-400:]
+        print(json.dumps(line), flush=True)
+    # The two calls at (tile, rows, lanes), a prefill chunk's rows.
+    default = (mhc_streams._TILE, mhc_streams._ROWS, mhc_streams._LANES)
+    flat, y = operands(TOKENS)
+    for blocking in MHC_SWEEP:
+        mhc_streams._TILE, mhc_streams._ROWS, mhc_streams._LANES = blocking
+        jax.clear_caches()
+        line = {"mhc": "kernels, {} tokens a step, {} rows x {} lanes".format(
+            *blocking)}
+        try:
+            line["ms_a_sublayer"] = a_sublayer("tpu", flat, y)
+        except Exception as e:  # noqa: BLE001
+            line["refused"] = repr(e)[-400:]
+        print(json.dumps(line), flush=True)
+    mhc_streams._TILE, mhc_streams._ROWS, mhc_streams._LANES = default
+    glm5_next._MHC_KERNEL_ROWS = by_rows
+    glm5_next.chip = on_the_chip
 
 
 def main():
@@ -209,7 +301,9 @@ def main():
                 lengths[0], as_on("tpu"), whole)
         kda_chunk._HEADS_A_STEP, gdn_chunk._GROUPS_A_STEP = default
         glm5_next.chip = on_the_chip
-    if parts == {"kda"}:
+    if parts & {"mhc", "rest"}:
+        residual_path(dense["hc"], keys[4], u)
+    if "rest" not in parts:
         return
 
     # ------------------------------------------------ sparse latent mixer
@@ -244,17 +338,10 @@ def main():
                                   "query_block": q_block,
                                   "refused": str(e)[:200]}), flush=True)
 
-    # ------------------------------------------ residual path, the FFNs
+    # ---------------------------------------------------------- the FFNs
     x = jax.random.normal(
         keys[4], (1, TOKENS, CFG.hc_mult, CFG.d_model)
     ).astype(CFG.dtype)
-
-    def one_mix(p, x, y):
-        hh, mix = glm5_next.mhc_mix(x, p, CFG)
-        return glm5_next.mhc_spread(x, y + hh, *mix)
-
-    ms, _ = timed(jax.jit(one_mix), dense["hc"], x, u[None])
-    print(json.dumps({"mhc_mix_and_spread_ms": ms}), flush=True)
     ms, _ = timed(
         jax.jit(lambda p, x: hybrid_kv._dense_ffn(x, p, CFG)), dense, x
     )

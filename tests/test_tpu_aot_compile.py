@@ -561,6 +561,61 @@ def test_kda_chunk_kernel_compiles_for_v5e_at_the_served_shape(v5e):
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**20
 
 
+@pytest.mark.parametrize("tokens", [2048, 16])
+def test_residual_stream_kernels_compile_for_v5e_at_the_served_shapes(
+    v5e, tokens
+):
+    """glm53flash-serve1's four residual streams of 4,096 in bfloat16,
+    a prefill chunk's 2,048 tokens and a decode step's 16 slots (one
+    block that hangs over the arrays' end), flat as a program carries
+    them between two sublayers. `mhc_mix`: ``P`` cut into its three
+    bf16 parts on the bits in the first step, the product against the
+    parts' rows, the lane rolls, the transposes either side of
+    Sinkhorn's sublane rolls and exact divisions, the [tokens, 16] and
+    [tokens, 4] float32 results stored under lane masks. `mhc_spread`
+    writes over the streams' buffer. Each is ONE Mosaic call and nothing
+    is made beside the arguments: ``P`` goes in as it is held
+    (transposed: a bitcast of the layout the chip keeps it in), no
+    float32 copy of the streams."""
+    from ray_tpu.ops.pallas import mhc_streams
+
+    n, d = 4, 4096
+
+    def on_chip(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    def flat_mix(flat, *p):
+        return mhc_streams.mhc_mix(
+            flat.reshape(tokens, n, d), *p, iters=20, eps=1e-6
+        )
+
+    mix = jax.jit(flat_mix).lower(
+        on_chip(tokens, n * d, dtype=jnp.bfloat16), on_chip(n * d, 24),
+        on_chip(3), on_chip(n), on_chip(n), on_chip(n, n),
+    ).compile()
+    text = mix.as_text()
+    assert len(_kernel_calls_under(text, "")) == 1
+    assert f"bf16[{tokens},{d}]" in text and f"f32[{tokens},16]" in text
+    assert not re.search(rf"f32\[{tokens},({n},{d}|{n * d})\]", text)
+    assert mix.memory_analysis().temp_size_in_bytes < 2**20
+
+    def flat_spread(flat, out, h_res, h_post):
+        return mhc_streams.mhc_spread(
+            flat.reshape(tokens, n, d), out, h_res, h_post
+        ).reshape(flat.shape)
+
+    spread = jax.jit(flat_spread, donate_argnums=0).lower(
+        on_chip(tokens, n * d, dtype=jnp.bfloat16),
+        on_chip(tokens, d, dtype=jnp.bfloat16), on_chip(tokens, n, n),
+        on_chip(tokens, n),
+    ).compile()
+    text = spread.as_text()
+    assert len(_kernel_calls_under(text, "")) == 1
+    assert _copies_of(text, (tokens, n * d)) == []
+    assert not re.search(rf"f32\[{tokens},({n},{d}|{n * d})\]", text)
+    assert spread.memory_analysis().temp_size_in_bytes < 2**20
+
+
 def test_the_sub_chunked_rule_and_the_selection_lower_for_v5e(v5e):
     """What no kernel computes in a program, at the served shapes: the
     exact top-k of 512 of 16,384 blocks for 2,048 queries and the gather

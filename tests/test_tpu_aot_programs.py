@@ -858,6 +858,7 @@ def glm5_next_programs(v5e):
             compiled[name] = lowered[name].compile()
         return compiled[name]
 
+    program.lowered = lowered
     return whole, program
 
 
@@ -898,6 +899,10 @@ def test_glm5_next_program_moves_no_pool_or_stack_and_fits(
         assert "jit(kda_state_step)" in text
         assert len(_expert_kernel_calls(text)) == 1  # the one sparse FFN
         assert memory.temp_size_in_bytes < 2**30
+        # 16 slots are under `glm5_next._MHC_KERNEL_ROWS`: the residual
+        # path keeps XLA's form here (it reads no slower alone, PR 64).
+        assert _kernel_calls_under(text, "mhc:") == []
+        assert _arrays_under(text, "mhc:mix") != set()
     else:
         table = int(program.rsplit("_", 1)[1])
         assert len(_grouped_kernel_calls(text)) == 2  # the one sparse FFN
@@ -943,6 +948,35 @@ def test_glm5_next_program_moves_no_pool_or_stack_and_fits(
         assert f"f32[{chunk},{table // 4}]" in text
         assert f"s32[{chunk},512]" in text  # the selection
         assert memory.temp_size_in_bytes < 3 * 2**30
+        # The residual path (PR 64): a sublayer's mix and its spread are
+        # each ONE call of ops/pallas/mhc_streams.py under its scope (the
+        # fixture's two layers are four sublayers), lowered once a
+        # program and called from every sublayer; the streams pass from a
+        # spread to the next mix as the [chunk, n d] bf16 array the calls
+        # take, and no float32 copy of them ([.., 4, 4096] or [.., 16384]
+        # a token) is left among the program's operations there.
+        n, sublayers = conf["hc_mult"], 4
+        lowered = compiled_program.lowered[program].as_text()
+        under = set()
+        for scope, kernel in (("mhc:mix", "mhc_mix"),
+                              ("mhc:spread", "mhc_spread")):
+            calls = _kernel_calls_under(text, scope)
+            assert len(calls) == sublayers
+            assert all(f"jit({kernel})" in call for call in calls)
+            assert len(re.findall(rf"func\.func private @{kernel}\(", lowered)) == 1
+            assert len(re.findall(rf"call @{kernel}\(", lowered)) == sublayers
+            under |= _arrays_under(text, scope)
+        assert f"bf16[{chunk},{n * d}]" in under
+        assert not [
+            a for a in under
+            if a.startswith("f32[") and max(_elements(a)) >= chunk * d
+        ]
+        # A 16k chunk program's temporaries at the fixture's two layers:
+        # 0.659 GiB, for 0.640 in XLA's form, whose ``h`` [chunk, d] (16
+        # MB) and two ``H`` (1 MB each, 128 lanes a row) were fused into
+        # their readers.
+        if table <= 16384:
+            assert memory.temp_size_in_bytes < 0.665 * 2**30
     arguments = conf["fit"]["argument_bytes"]
     counted = (
         family.held_parameters(conf) * 2
@@ -955,6 +989,27 @@ def test_glm5_next_program_moves_no_pool_or_stack_and_fits(
     assert abs(arguments - counted) < 3.2e7
     assert arguments > 0.25 * 16 * 2**30  # the floor a new cell is held to
     assert arguments + memory.temp_size_in_bytes < 15.75 * 2**30
+
+
+@pytest.mark.parametrize("family", ["hybrid", "granite", "qwen3next", "laguna"])
+def test_a_program_of_one_residual_stream_holds_no_stream_kernel(
+    family, request
+):
+    """`hybrid_kv._read` / `_residual` are every hybrid family's, and
+    reach `glm5_next.mhc_mix` / `mhc_spread` (on a TPU: the two calls of
+    ops/pallas/mhc_streams.py) only where the config carries more than
+    one residual stream, which GLM-5.3-Flash's alone does: no other
+    family's program holds either scope or either call. (Their lowered
+    texts at PR 64 are the parent's, the kernels' source paths apart:
+    CHANGES.md.)"""
+    _, programs = request.getfixturevalue(f"{family}_programs")
+    if callable(programs):  # laguna's, compiled when first asked for
+        programs = {name: programs(name) for name in (
+            "prefill_chunk_2048_of_4096", "prefill_chunk_2048_of_8192",
+            "prefill_chunk_2048_of_16384", "decode",
+        )}
+    for compiled in programs.values():
+        assert "mhc" not in compiled.as_text()
 
 
 # ------------------------------------- the latent programs, a double layer

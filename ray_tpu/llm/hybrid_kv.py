@@ -24,7 +24,15 @@ an indexer picks (``L``), whose cells and pooled index keys are two more
 page pools on the SAME block tables; its FFNs clamp their SwiGLU, and
 what its programs carry between sublayers is ``cfg.hc_mult`` residual
 streams that each sublayer mixes on the way in and out (`_read`,
-`_residual`), which the other families' configs hold off.
+`_residual`), which the other families' configs hold off. A Motif-3-Beta
+layer (``models/motif.py``) is two as well and carries the same streams:
+its mixer is latent attention WITH a rotary part whose 80 query heads
+share 16 expanded KV groups and subtract one attention map from another,
+over the whole context (``A``: cells in one more page pool on the
+request's block table, attended by ``ops/pallas/latent_attention.py``'s
+two kernels) or over the last ``cfg.sliding_window`` positions (``R``:
+a per-slot ring of latent CELLS, not of keys and values); its FFNs are
+PolyNorm's (`_dense_ffn`, ``moe_ffn``).
 
 The cache is ONE donated tree with two kinds of per-sequence state:
 
@@ -64,7 +72,15 @@ The cache is ONE donated tree with two kinds of per-sequence state:
   its last token, which may come in a decode step, so the open block's
   keys wait here, the block is no query's candidate until it is whole
   (`glm5_next._select`), and the step that completes it writes the mean
-  of the tail and its own key (`glm5_next.dsa_decode`).
+  of the tail and its own key (`glm5_next.dsa_decode`);
+- ``cells`` ``[L_full, num_pages, P, cell_width]``: the ``A`` blocks'
+  latent cells ``[c; kpe; zeros]`` (576 numbers held 640 wide), reached
+  through the request's block table like the other pools, and
+  ``win_cells`` ``[L_window, max_batch, W, cell_width]``: each slot's
+  last ``W`` cells in each ``R`` layer, position ``t`` at ring index ``t
+  % W``, masked by true position and never cleared, as ``win_k`` /
+  ``win_v``. Two kinds of latent state in the one donated tree: the
+  page pool counts the full layers only.
 
 Both are carried through the Python loop over the pattern and updated in
 place (a block writes its own layer's slot rows; nothing is sliced out
@@ -120,10 +136,15 @@ from ray_tpu.models.glm5_next import (
     kda_chunked,
     kda_step,
     kda_step_live,
-    mhc_mix,
-    mhc_spread,
 )
-from ray_tpu.models.moe import clamped_swiglu, moe_ffn
+from ray_tpu.models.mhc import mhc_mix, mhc_spread
+from ray_tpu.models.moe import clamped_swiglu, moe_ffn, poly_glu, poly_terms
+from ray_tpu.models.motif import (
+    gdla_decode_full,
+    gdla_decode_window,
+    gdla_prefill_full,
+    gdla_prefill_window,
+)
 from ray_tpu.models.nemotron_h import (
     NemotronHConfig,
     init_params,
@@ -197,7 +218,7 @@ _RECURRENT = {
 }
 
 # The leaves that are page pools: a request's block table reaches each.
-_PAGED = ("k", "v", "latent", "index")
+_PAGED = ("k", "v", "latent", "index", "cells")
 # What an ``L`` block reads and writes of the cache, in `dsa_prefill`'s
 # and `dsa_decode`'s order.
 _LATENT_LEAVES = ("latent", "index", "index_tail")
@@ -233,6 +254,14 @@ def init_hybrid_cache(
         cache["index_tail"] = jnp.zeros(
             (n, max_batch, pool - 1, width), jnp.float32
         )
+    if n := cfg.count("A"):
+        cache["cells"] = jnp.zeros(
+            (n, num_pages, page_size, cfg.cell_width), cfg.dtype
+        )
+    if n := cfg.count("R"):
+        cache["win_cells"] = jnp.zeros(
+            (n, max_batch, cfg.sliding_window, cfg.cell_width), cfg.dtype
+        )
     return cache
 
 
@@ -261,7 +290,10 @@ def _read(x, p, cfg):
 
 def _residual(x, out, cfg, mix=None):
     """``x + residual_multiplier * out``: a sublayer's output added on
-    (``mix``, `_read`'s: spread over the streams by `mhc_spread`)."""
+    (``mix``, `_read`'s: spread over the streams by `mhc_spread`), held
+    within ``+-cfg.hidden_clamp`` first where the model has one."""
+    if cfg.hidden_clamp is not None:
+        out = jnp.clip(out, -cfg.hidden_clamp, cfg.hidden_clamp)
     if mix is not None:
         return mhc_spread(x, out, *mix)
     if cfg.residual_multiplier != 1.0:
@@ -466,11 +498,18 @@ def _window_decode(q, k, v, win_k, win_v, layer: int, positions, active, cfg):
 
 
 def _dense_ffn(x, p, cfg):
-    """A dense gated-SiLU FFN sublayer on x [B, S, d]."""
+    """A dense gated FFN sublayer on x [B, S, d]: gated by SiLU, or by
+    PolyNorm where that is the model's ``expert_kind``."""
     h, mix = _read(x, p, cfg)
     with jax.named_scope("ffn:dense"):
         h = rms_norm(h, p["norm"], cfg.norm_eps)
-        act = clamped_swiglu(h, p["w_gate"], p["w_up"], cfg.swiglu_limit)
+        if cfg.expert_kind == "polynorm":
+            act = poly_glu(
+                h, p["w_gate"], p["w_up"],
+                poly_terms(cfg, p["poly_w"], p["poly_b"]), cfg.norm_eps,
+            )
+        else:
+            act = clamped_swiglu(h, p["w_gate"], p["w_up"], cfg.swiglu_limit)
         out = act @ p["w_down"]
     return _residual(x, out, cfg, mix)
 
@@ -552,6 +591,20 @@ def _hybrid_prefill(
             )
             state.update(zip(_LATENT_LEAVES, left))
             record["selected"].append(picked)
+            x = _residual(x, out[None], cfg, mix)
+        elif kind == "A":
+            h, mix = _read(x, p, cfg)
+            out, state["cells"] = gdla_prefill_full(
+                h[0], p, cfg, state["cells"], seen[kind] * num_pages, pages,
+                chunk_slice, start, use_kernel,
+            )
+            x = _residual(x, out[None], cfg, mix)
+        elif kind == "R":
+            h, mix = _read(x, p, cfg)
+            out, state["win_cells"] = gdla_prefill_window(
+                h[0], p, cfg, state["win_cells"], (seen[kind], slot), start,
+                jnp.clip(length - start, 0, c), use_kernel,
+            )
             x = _residual(x, out[None], cfg, mix)
         elif kind == "W":
             q, k, v, gate = _attention_inputs(x, p, cfg, pos, kind)
@@ -691,6 +744,20 @@ def hybrid_decode(
             state.update(zip(_LATENT_LEAVES, left))
             record["selected"].append(picked)
             x = _residual(x, out[:, None], cfg, mix)
+        elif kind == "A":
+            h, mix = _read(x, p, cfg)
+            out, state["cells"] = gdla_decode_full(
+                h[:, 0], p, cfg, state["cells"], seen[kind] * num_pages,
+                geometry, positions, use_kernel,
+            )
+            x = _residual(x, out[:, None], cfg, mix)
+        elif kind == "R":
+            h, mix = _read(x, p, cfg)
+            out, state["win_cells"] = gdla_decode_window(
+                h[:, 0], p, cfg, state["win_cells"], seen[kind], positions,
+                active,
+            )
+            x = _residual(x, out[:, None], cfg, mix)
         elif kind == "W":
             q, k, v, gate = _attention_inputs(
                 x, p, cfg, positions[:, None], kind
@@ -739,13 +806,13 @@ class HybridServing(Serving):
         self._prefill_programs = self._live_tokens = self._prefill_pairs = 0
         self._window_pairs = self._window_bytes = 0
         self._index_pairs = self._selected_pairs = self._causal_pairs = 0
-        self._latent_bytes = self._index_bytes = 0
+        self._latent_bytes = self._index_bytes = self._cells_expanded = 0
 
     def init_cache(self, num_pages: int, page_size: int, max_batch: int,
                    shardings=None):
         cache = init_hybrid_cache(self.cfg, num_pages, page_size, max_batch)
-        self._window_bytes = leaf_bytes(cache, ("win_k", "win_v"))
-        self._latent_bytes = leaf_bytes(cache, ("latent",))
+        self._window_bytes = leaf_bytes(cache, ("win_k", "win_v", "win_cells"))
+        self._latent_bytes = leaf_bytes(cache, ("latent", "cells"))
         self._index_bytes = leaf_bytes(cache, ("index", "index_tail"))
         return cache
 
@@ -769,7 +836,14 @@ class HybridServing(Serving):
         # would have needed without one; what of the cache is latent
         # cells and what is the indexer's (pooled keys and tails); and
         # the tokens whose streams a sublayer mixed, summed over the
-        # sublayers, where the model carries more than one.
+        # sublayers, where the model carries more than one. Where the
+        # model's attention is over latent cells with a rotary part (`A`
+        # over pages, `R` over per-slot rings; their causal pairs and
+        # tokens are `prefill_attn_pairs` and `prefill_window_pairs` /
+        # `window_tokens`, their bytes among `latent_bytes` and
+        # `window_bytes`): the cells its prefill programs turned back
+        # into keys and values, the whole table a full layer and the
+        # ring and the chunk a window layer.
         gdn_tokens = self.cfg.count("G") * self._live_tokens
         on_tpu = chip.platform() == "tpu"
         out = {
@@ -780,6 +854,7 @@ class HybridServing(Serving):
             "dsa_selected_pairs": self._selected_pairs,
             "dsa_causal_pairs": self._causal_pairs,
             "latent_bytes": self._latent_bytes,
+            "latent_cells_expanded": self._cells_expanded,
             "index_bytes": self._index_bytes,
             "mhc_tokens": (
                 len(self.cfg.pattern) * self._live_tokens
@@ -791,7 +866,9 @@ class HybridServing(Serving):
             "gdn_kernel_tokens": gdn_tokens if on_tpu else 0,
             "prefill_attn_pairs": self._prefill_pairs,
             "prefill_window_pairs": self._window_pairs,
-            "window_tokens": self.cfg.count("W") * self._live_tokens,
+            "window_tokens": (
+                self.cfg.count("W") + self.cfg.count("R")
+            ) * self._live_tokens,
             "window_bytes": self._window_bytes,
         }
         if any(self.cfg.count(kind) for kind in _RECURRENT):
@@ -801,14 +878,18 @@ class HybridServing(Serving):
             out["state_step_kernel"] = on_tpu
         return out
 
-    def _count(self, start: int, width: int, length: int) -> None:
+    def _count(self, start: int, width: int, length: int,
+               table: int) -> None:
         n = max(min(width, length - start), 0)
         self._prefill_programs += 1
         self._live_tokens += n
-        self._prefill_pairs += self.cfg.count("*") * (
+        self._prefill_pairs += (self.cfg.count("*") + self.cfg.count("A")) * (
             n * start + n * (n + 1) // 2
         )
-        if layers := self.cfg.count("W"):
+        if rings := self.cfg.count("R"):
+            self._cells_expanded += rings * (self.cfg.sliding_window + width)
+        self._cells_expanded += self.cfg.count("A") * table
+        if layers := self.cfg.count("W") + self.cfg.count("R"):
             w = self.cfg.sliding_window
             self._window_pairs += layers * (
                 _band_pairs_before(start + n, w) - _band_pairs_before(start, w)
@@ -826,7 +907,10 @@ class HybridServing(Serving):
     def prefill_chunk(self, params, tokens, cache, pages, start, *,
                       n_write_pages, chunk_pages, slot, length,
                       use_kernel=False):
-        self._count(int(start), tokens.shape[1], length)
+        self._count(
+            int(start), tokens.shape[1], length,
+            n_write_pages * tokens.shape[1] // chunk_pages,
+        )
         return prefill_program(
             self.cfg, n_write_pages, chunk_pages, use_kernel
         )(

@@ -40,7 +40,7 @@ from ray_tpu.models.llama import (
     param_logical_axes,
 )
 from ray_tpu.ops.pallas.expert_combine import combine_rows
-from ray_tpu.ops.pallas.expert_rows import experts_on_rows
+from ray_tpu.ops.pallas.expert_rows import experts_on_rows, poly_norm_of
 from ray_tpu.ops.pallas.grouped_rows import grouped_rows
 
 
@@ -66,7 +66,10 @@ class MoEConfig(LlamaConfig):
     router_kind: str = "softmax"
     routed_scaling_factor: float = 1.0
     # "swiglu": silu(h W_gate) * (h W_up) W_down. "relu2": relu(h W_up)^2
-    # W_down, no gate matrix.
+    # W_down, no gate matrix. "polynorm": PolyNorm(h W_gate) * (h W_up)
+    # W_down (`poly_norm`: three norms over the expert's whole width,
+    # with ``polynorm_scale`` and ``polynorm_clamp`` of the config and
+    # ``poly_w`` / ``poly_b`` an expert in the tree; `models/motif.py`).
     expert_kind: str = "swiglu"
     # A gated expert's two products clamped before they are multiplied
     # (``swiglu_limit`` of a published config): ``silu(min(h W_gate, l))
@@ -184,12 +187,43 @@ def _take_rows_bwd(fan, inverse, g):
 _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 
 
-def _expert_act(cfg, rows, w_gate, w_up, matmul):
+def _expert_act(cfg, rows, w_gate, w_up, matmul, poly=None):
     """The width-``d_ff`` activations of an expert of ``cfg.expert_kind``;
-    ``matmul(rows, w)`` is the grouped or the plain product."""
+    ``matmul(rows, w)`` is the grouped or the plain product. ``poly``:
+    `poly_terms` of each row's expert, where the kind is "polynorm"."""
     if cfg.expert_kind == "relu2":
         return jnp.square(jax.nn.relu(matmul(rows, w_up)))
+    if cfg.expert_kind == "polynorm":
+        return poly_glu(rows, w_gate, w_up, poly, cfg.norm_eps, matmul)
     return clamped_swiglu(rows, w_gate, w_up, cfg.swiglu_limit, matmul)
+
+
+def poly_terms(cfg, w, b):
+    """PolyNorm's four numbers an FFN as the arithmetic takes them, [..,
+    4] float32: ``polynorm_scale`` times the three weights ``w`` [.., 3]
+    (of the cube, the square and the first power) and the bias ``b``
+    [.., 1] held within ``+-polynorm_clamp``."""
+    c = cfg.polynorm_clamp
+    return jnp.concatenate(
+        [cfg.polynorm_scale * w, jnp.clip(b, -c, c)], axis=-1
+    ).astype(jnp.float32)
+
+
+def poly_norm(z, poly, eps: float):
+    """``s (w_0 N(z^3) + w_1 N(z^2) + w_2 N(z)) + clip(b)`` with ``N(a) =
+    a / sqrt(mean(a^2) + eps)`` over z's WHOLE last axis (PolyNorm,
+    arXiv:2411.03884): z [.., f] float32, ``poly`` `poly_terms`'s [.., 4]
+    (leading axes that broadcast against z's)."""
+    return poly_norm_of(z, *jnp.split(poly, 4, axis=-1), eps)
+
+
+def poly_glu(rows, w_gate, w_up, poly, eps: float, matmul=jnp.matmul):
+    """``PolyNorm(rows W_gate) * (rows W_up)`` in the rows' dtype, the
+    norms in float32."""
+    with jax.named_scope("ffn:polynorm"):
+        gate = poly_norm(matmul(rows, w_gate).astype(jnp.float32), poly, eps)
+        up = matmul(rows, w_up)
+        return (gate * up.astype(jnp.float32)).astype(up.dtype)
 
 
 def clamped_swiglu(rows, w_gate, w_up, limit: float | None, matmul=jnp.matmul):
@@ -233,6 +267,24 @@ def touched_first(load):
     return jnp.where(slot < count, ids, ids.max()).astype(jnp.int32), count
 
 
+def _expert_poly(cfg, p, each_row: bool = False):
+    """`poly_terms` of the held experts [held, 4] (``each_row``: [held,
+    1, 4], against activations [held, n, f]); None for another kind."""
+    if cfg.expert_kind != "polynorm":
+        return None
+    poly = poly_terms(cfg, p["poly_w"], p["poly_b"])
+    return poly[:, None] if each_row else poly
+
+
+def _poly_operands(cfg, p) -> dict:
+    """What the expert kernels take of a PolyNorm model beside the
+    stacks: the held experts' numbers and the norms' eps; nothing for
+    another kind (whose config need not have a ``norm_eps``)."""
+    if cfg.expert_kind != "polynorm":
+        return {}
+    return {"poly": _expert_poly(cfg, p), "eps": cfg.norm_eps}
+
+
 def every_row_einsum(cfg, rows, p, weight):
     """Every held expert on every row as one batched matmul over the
     experts, and each row's sum over them by ``weight``: every expert's
@@ -247,7 +299,7 @@ def every_row_einsum(cfg, rows, p, weight):
             "enf,efd->end", a, w.astype(dt)
         )
         act = _expert_act(cfg, rows.astype(dt), p.get("w_gate"), p["w_up"],
-                          batched)
+                          batched, _expert_poly(cfg, p, each_row=True))
         outs = per_expert(act, p["w_down"])  # [held, n, d]
     with jax.named_scope("moe:combine"):
         return jnp.einsum(
@@ -270,11 +322,11 @@ def _experts_on_every_row(tokens, p, cfg, routes, gates, here):
     with jax.named_scope("moe:dispatch"):
         ids, count = touched_first(load)
     with jax.named_scope("moe:experts"):
-        gated = cfg.expert_kind == "swiglu"
+        gated = cfg.expert_kind != "relu2"
         out = experts_on_rows(
             tokens.astype(dt), p["w_gate"].astype(dt) if gated else None,
             p["w_up"].astype(dt), p["w_down"].astype(dt), weight, ids, count,
-            limit=cfg.swiglu_limit,
+            limit=cfg.swiglu_limit, **_poly_operands(cfg, p),
         )
     return out, load
 
@@ -298,11 +350,14 @@ def _experts_on_sorted_pairs(tokens, p, cfg, routes, gates):
             pair_expert, length=cfg.num_experts
         ).astype(jnp.int32)
         rows = _take_rows(tokens, order, inverse, k)  # [n * k, d]
+        poly = _expert_poly(cfg, p)
+        if poly is not None:  # each row's expert's
+            poly = poly[pair_expert[order]]
 
     with jax.named_scope("moe:experts"):
         grouped = lambda a, w: jax.lax.ragged_dot(a, w.astype(dt), load)  # noqa: E731
         rows_out = grouped(
-            _expert_act(cfg, rows, p.get("w_gate"), p["w_up"], grouped),
+            _expert_act(cfg, rows, p.get("w_gate"), p["w_up"], grouped, poly),
             p["w_down"],
         )
 
@@ -360,6 +415,10 @@ def _experts_on_pairs_here(tokens, p, cfg, routes, gates, here):
         order = jnp.pad(order, (0, -(n * k) % block))
         token = order // k  # of each row, as the rows lie
         gate = gates.reshape(n * k)[order]
+        poly = None if on_tpu else _expert_poly(cfg, p)
+        if poly is not None:
+            # Each row's expert's (a row past the pairs: any expert's).
+            poly = poly[jnp.minimum(pair_expert[order], e_here - 1)]
 
     def step(i, staged):
         lo = i * block
@@ -379,7 +438,12 @@ def _experts_on_pairs_here(tokens, p, cfg, routes, gates, here):
                 a, w.astype(dt), sizes
             )
             out = grouped(
-                _expert_act(cfg, rows, p.get("w_gate"), p["w_up"], grouped),
+                _expert_act(
+                    cfg, rows, p.get("w_gate"), p["w_up"], grouped,
+                    poly if poly is None else jax.lax.dynamic_slice_in_dim(
+                        poly, lo, block
+                    ),
+                ),
                 p["w_down"],
             )
         with jax.named_scope("moe:combine"):
@@ -398,11 +462,12 @@ def _experts_on_pairs_here(tokens, p, cfg, routes, gates, here):
             # The tile follows the rows an expert gets if the router
             # spreads the pairs evenly over its outputs.
             mean = n * k // (cfg.num_experts + cfg.zero_experts)
-            gated = cfg.expert_kind == "swiglu"
+            gated = cfg.expert_kind != "relu2"
             hidden = grouped_rows(
                 rows_out.reshape(-1, d),
                 [p[w].astype(dt) for w in (["w_gate"] * gated + ["w_up"])],
                 load, cfg.expert_kind, mean, limit=cfg.swiglu_limit,
+                **_poly_operands(cfg, p),
             )
             rows_out = grouped_rows(
                 hidden, [p["w_down"].astype(dt)], load, None, mean
@@ -502,8 +567,12 @@ def moe_ffn(x: jnp.ndarray, p: Params, cfg, rows_live=None):
         with jax.named_scope("moe:shared"):
             plain = lambda a, w: a @ w.astype(dt)  # noqa: E731
             shared = plain(
-                _expert_act(cfg, tokens.astype(dt), p.get("shared_gate"),
-                            p["shared_up"], plain),
+                _expert_act(
+                    cfg, tokens.astype(dt), p.get("shared_gate"),
+                    p["shared_up"], plain,
+                    poly_terms(cfg, p["shared_poly_w"], p["shared_poly_b"])
+                    if cfg.expert_kind == "polynorm" else None,
+                ),
                 p["shared_down"],
             )
             if "shared_expert_gate" in p:

@@ -140,6 +140,10 @@ class NemotronHConfig:
     # sublayer's input from n streams and `mhc_spread` writes it back).
     swiglu_limit: float | None = None
     hc_mult: int = 0
+    # A bound every sublayer's output is held within before it joins
+    # the residual path (`models/motif.py`; None, here and in the
+    # families above: nothing of it in a program).
+    hidden_clamp: float | None = None
     # Identity outputs behind the router's experts (`moe.MoEConfig`):
     # none in any family served through this class.
     zero_experts: int = 0

@@ -35,6 +35,14 @@ experts:
 - **The tile follows from the shapes** (``_width_tile``): the widest
   multiple of 128 lanes that divides the expert width and whose double
   buffers (two or three matrices) fit ``_WEIGHT_VMEM_BYTES``.
+- **A PolyNorm expert takes its whole width in one step** (``poly``,
+  ``models/moe.py poly_norm``): its three norms run over the expert's
+  width, so the gate product of a row is held whole, in float32, before
+  the norms and the down product. At Motif-3-Beta's 4,096 x 1,280 three
+  matrices are 31.5 MB an expert, 63 MB twice buffered: over the budget
+  the other kinds tile under (they would take 640 lanes there), inside
+  the core's 128 MiB. The four numbers an expert are scalars, a row of a
+  ``[held, 4]`` float32 array in SMEM.
 
 The stacks are read where they lie, row-major as every Mosaic operand:
 ``[held, d, f]`` and ``[held, f, d]`` with ``f`` a multiple of 128, the
@@ -96,12 +104,17 @@ def _width_tile(d: int, f: int, n_matrices: int, itemsize: int) -> int:
     )
 
 
-def _make_kernel(gated: bool, n_rows: int, limit: float | None):
+def _make_kernel(gated: bool, n_rows: int, limit: float | None,
+                 poly_eps: float | None = None):
     """Kernel of one (touched expert, width tile) a grid step. Refs:
-    scalar prefetch (ids, count), the rows, the gate matrix, the weight
-    tiles (gate's first where ``gated``), out, the accumulator."""
+    scalar prefetch (ids, count), the rows, the gate matrix, PolyNorm's
+    numbers (where ``poly_eps`` is not None: the gate is then a
+    PolyNorm's and the tile the whole width), the weight tiles (gate's
+    first where ``gated``), out, the accumulator."""
 
     def _kernel(ids_ref, count_ref, x_ref, weight_ref, *refs):
+        if poly_eps is not None:
+            poly_ref, *refs = refs
         *w_gate_ref, w_up_ref, w_down_ref, o_ref, acc_ref = refs
         i, t = pl.program_id(0), pl.program_id(1)
 
@@ -126,7 +139,12 @@ def _make_kernel(gated: bool, n_rows: int, limit: float | None):
                     if limit is not None:  # a clamped SwiGLU
                         gate = jnp.minimum(gate, limit)
                         up = jnp.clip(up, -limit, limit)
-                    act = jax.nn.silu(gate) * up
+                    if poly_eps is None:
+                        act = jax.nn.silu(gate) * up
+                    else:
+                        act = poly_norm_rows(
+                            gate, poly_ref, expert, poly_eps
+                        ) * up
                 else:
                     act = jnp.square(jnp.maximum(up, 0.0))
                 out = jnp.dot(
@@ -152,14 +170,40 @@ def _make_kernel(gated: bool, n_rows: int, limit: float | None):
     return _kernel
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "limit"))
+def poly_norm_of(z, cube, square, first, bias, eps: float):
+    """``cube N(z^3) + square N(z^2) + first N(z) + bias`` with ``N(a) =
+    a / sqrt(mean(a^2) + eps)`` over z's WHOLE last axis, z [.., f]
+    float32: PolyNorm's arithmetic (arXiv:2411.03884), for the kernels
+    here and in ``grouped_rows.py`` (the four numbers scalars) and for
+    ``models/moe.py poly_norm`` (arrays that broadcast against z)."""
+    def unit(a):
+        return a * jax.lax.rsqrt(jnp.mean(a * a, axis=-1, keepdims=True) + eps)
+
+    z2 = z * z
+    return cube * unit(z2 * z) + square * unit(z2) + first * unit(z) + bias
+
+
+def poly_norm_rows(z, poly_ref, expert, eps: float):
+    """`poly_norm_of` inside a kernel, with row ``expert`` of ``poly_ref``
+    ([held, 4] float32 in SMEM: scalars)."""
+    return poly_norm_of(z, *(poly_ref[expert, i] for i in range(4)), eps)
+
+
+# PolyNorm's numbers as a kernel takes them: whole, in SMEM.
+POLY_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "limit", "eps"))
 def _experts_on_rows(x, w_gate, w_up, w_down, weight, ids, count, interpret,
-                     limit=None):
+                     limit=None, poly=None, eps=None):
     n, d = x.shape
     held, _, f = w_up.shape
     gated = w_gate is not None
     stacks = ([w_gate] if gated else []) + [w_up, w_down]
-    tile = _width_tile(d, f, len(stacks), w_up.dtype.itemsize)
+    # A PolyNorm's norms need a row's whole width of the gate product.
+    tile = f if poly is not None else _width_tile(
+        d, f, len(stacks), w_up.dtype.itemsize
+    )
     n_tiles = f // tile
     n_rows = -(-n // _ROW_TILE) * _ROW_TILE
     x = jnp.pad(x, ((0, n_rows - n), (0, 0)))
@@ -189,12 +233,14 @@ def _experts_on_rows(x, w_gate, w_up, w_down, weight, ids, count, interpret,
         # a row block's up / gate / act and its two [rows, d] results
         + row_block * (4 * max(tile, _LANES) + 2 * d) * 4
     )
+    numbers = [] if poly is None else [poly.astype(jnp.float32)]
     out = pl.pallas_call(
-        _make_kernel(gated, n_rows, limit),
+        _make_kernel(gated, n_rows, limit, None if poly is None else eps),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(held, n_tiles),
             in_specs=[resident((n_rows, d)), resident((n_rows, held))]
+            + [POLY_SPEC] * len(numbers)
             + [up_spec] * (len(stacks) - 1) + [down_spec],
             out_specs=resident((n_rows, d)),
             scratch_shapes=[pltpu.VMEM((n_rows, d), jnp.float32)],
@@ -207,11 +253,11 @@ def _experts_on_rows(x, w_gate, w_up, w_down, weight, ids, count, interpret,
         ),
         interpret=interpret,
     )(ids.astype(jnp.int32), count.astype(jnp.int32).reshape(1), x, weight,
-      *stacks)
+      *numbers, *stacks)
     return out[:n]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 10))
 def experts_on_rows(
     x: jnp.ndarray,  # [n, d]
     w_gate: jnp.ndarray | None,  # [held, d, f]; None: relu(x w_up)^2
@@ -222,6 +268,9 @@ def experts_on_rows(
     count: jnp.ndarray,  # [] int32: how many of `ids` are touched
     interpret: bool = False,
     limit: float | None = None,  # a gated expert's clamp (`clamped_swiglu`)
+    poly: jnp.ndarray | None = None,  # [held, 4]: `moe.poly_terms`; the
+    # gate is then PolyNorm's and not silu's
+    eps: float | None = None,  # of PolyNorm's three norms
 ) -> jnp.ndarray:
     """``sum_e weight[:, e] * expert_e(x)`` over the experts ``ids[:count]``,
     [n, d] in ``x``'s dtype. Every expert with a nonzero column of
@@ -229,17 +278,20 @@ def experts_on_rows(
     ``ids[count - 1]`` (0 where ``count`` is 0), so that those steps
     fetch nothing: see the module docstring. Forward only."""
     return _experts_on_rows(
-        x, w_gate, w_up, w_down, weight, ids, count, interpret, limit
+        x, w_gate, w_up, w_down, weight, ids, count, interpret, limit, poly,
+        eps,
     )
 
 
-def _forward(x, w_gate, w_up, w_down, weight, ids, count, interpret, limit):
+def _forward(x, w_gate, w_up, w_down, weight, ids, count, interpret, limit,
+             poly, eps):
     return _experts_on_rows(
-        x, w_gate, w_up, w_down, weight, ids, count, interpret, limit
+        x, w_gate, w_up, w_down, weight, ids, count, interpret, limit, poly,
+        eps,
     ), None
 
 
-def _backward(interpret, limit, residuals, g):
+def _backward(interpret, limit, eps, residuals, g):
     raise NotImplementedError(
         "ops/pallas/expert_rows.py has no backward pass: the every-row "
         "expert form serves decode steps and short prefill chunks; a "

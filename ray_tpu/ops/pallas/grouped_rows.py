@@ -39,7 +39,13 @@ This kernel unties them:
   contraction, one rounding at the store**, as ``ragged_dot``; with two
   matrices the step is a gated expert's ``silu(x Wg) * (x Wu)`` on the
   two float32 products (``act="swiglu"``), with one and ``act="relu2"``
-  ``relu(x W)^2``.
+  ``relu(x W)^2``; ``act="polynorm"`` is ``PolyNorm(x Wg) * (x Wu)``
+  (``models/moe.py poly_norm``), whose three norms run over the
+  expert's WHOLE width: a tile's gate product is held whole in float32
+  before them, so the stacks go in ONE column pass (Motif-3-Beta's two
+  4,096 x 1,280 matrices are 21 MB a group, 42 MB in two buffers: inside
+  the budget; a wider expert would be refused, not split), and the
+  group's four numbers are scalars in SMEM.
 - **The tiles follow from the shapes.** The row tile (`_tile_rows`):
   the groups' mean size rounded up to the packing, at most
   `_MAX_TILE_ROWS`. The column tile (`_column_tile`): the widest
@@ -67,6 +73,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.ops.pallas.expert_rows import POLY_SPEC, poly_norm_rows
 
 # What the buffers of a group's matrices may take of VMEM (a v5e
 # core has 128 MiB). Granite's and Laguna's gate and up matrices (2 x
@@ -123,16 +131,19 @@ def _column_tile(k: int, n: int, n_matrices: int, itemsize: int) -> int:
 
 def _make_kernel(
     act: str | None, n_w: int, tile: int, pack: int, passes: int, depth: int,
-    limit: float | None = None,
+    limit: float | None = None, eps: float | None = None,
 ):
     """Kernel of one (column pass, row block) a grid step. Refs: scalar
     prefetch (each block's first live group and how many it holds; the
     live groups' ids, first rows and ends; their count and the last live
-    block), the rows, the stacks in HBM, out, the stacks' buffers
+    block), the rows, the groups' PolyNorm numbers (``act="polynorm"``
+    alone), the stacks in HBM, out, the stacks' buffers
     ``[depth, k, width]`` and their DMA semaphores ``[matrix, buffer]``."""
 
     def _kernel(first_ref, held_ref, ids_ref, starts_ref, ends_ref, meta_ref,
                 rows_ref, *refs):
+        if act == "polynorm":
+            poly_ref, *refs = refs
         stacks, o_ref = refs[:n_w], refs[n_w]
         buffers, sems = refs[n_w + 1: 2 * n_w + 1], refs[2 * n_w + 1]
         c, i = pl.program_id(0), pl.program_id(1)
@@ -200,6 +211,12 @@ def _make_kernel(
                     y = jax.nn.silu(gate) * y
                 elif act == "relu2":
                     y = jnp.square(jnp.maximum(y, 0.0))
+                elif act == "polynorm":
+                    gate = jnp.dot(
+                        x, buffers[0][l % depth],
+                        preferred_element_type=jnp.float32,
+                    )
+                    y = poly_norm_rows(gate, poly_ref, ids_ref[l], eps) * y
                 row = at + jax.lax.broadcasted_iota(jnp.int32, y.shape, 0)
                 o_ref[here, :] = jnp.where(
                     (row >= lo) & (row < hi), y.astype(o_ref.dtype),
@@ -240,10 +257,11 @@ def _work_list(sizes, blocks: int, block: int):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("act", "group_rows", "interpret", "limit")
+    jax.jit,
+    static_argnames=("act", "group_rows", "interpret", "limit", "eps"),
 )
 def _grouped_rows(rows, weights, sizes, act, group_rows, interpret,
-                  limit=None):
+                  limit=None, poly=None, eps=None):
     total, k = rows.shape
     n = weights[0].shape[2]
     n_w = len(weights)
@@ -257,6 +275,12 @@ def _grouped_rows(rows, weights, sizes, act, group_rows, interpret,
     blocks = (total + pad) // block
     tile = _tile_rows(group_rows, pack, block)
     width = _column_tile(k, n, n_w, rows.dtype.itemsize)
+    if act == "polynorm" and width != n:
+        raise ValueError(
+            f"a PolyNorm expert's {n} columns do not fit one pass of "
+            f"{width}: its norms run over the whole width"
+        )
+    numbers = [poly.astype(jnp.float32)] if act == "polynorm" else []
     scalars = _work_list(sizes, blocks, block)
 
     def row_block(c, i, first, held, ids, starts, ends, meta):
@@ -270,13 +294,14 @@ def _grouped_rows(rows, weights, sizes, act, group_rows, interpret,
         + tile * (k * item + (n_w + 2) * max(width, _LANES) * 4)  # a tile's
     )
     out = pl.pallas_call(
-        _make_kernel(act, n_w, tile, pack, n // width, _BUFFERS, limit),
+        _make_kernel(act, n_w, tile, pack, n // width, _BUFFERS, limit, eps),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
             grid=(n // width, blocks),
             in_specs=[pl.BlockSpec(
                 (block, k), lambda c, i, *s: (row_block(c, i, *s), 0)
-            )] + [pl.BlockSpec(memory_space=pl.ANY)] * n_w,
+            )] + [POLY_SPEC] * len(numbers)
+            + [pl.BlockSpec(memory_space=pl.ANY)] * n_w,
             out_specs=pl.BlockSpec(
                 (block, width), lambda c, i, *s: (row_block(c, i, *s), c)
             ),
@@ -292,19 +317,21 @@ def _grouped_rows(rows, weights, sizes, act, group_rows, interpret,
             vmem_limit_bytes=vmem + 8 * 1024 * 1024,
         ),
         interpret=interpret,
-    )(*scalars, rows, *weights)
+    )(*scalars, rows, *numbers, *weights)
     return out[:total] if pad else out
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 8))
 def grouped_rows(
     rows: jnp.ndarray,  # [total, k]: the rows in group order
     weights: list,  # one or two stacks [groups, k, n] in `rows`' dtype
     sizes: jnp.ndarray,  # [groups] int32: each group's rows
-    act: str | None = None,  # None, "swiglu" (two stacks) or "relu2"
+    act: str | None = None,  # None, "swiglu" / "polynorm" (two stacks) or "relu2"
     group_rows: int = _MAX_TILE_ROWS,  # the groups' mean size, for the tile
     interpret: bool = False,
     limit: float | None = None,  # "swiglu"'s clamp (`moe.clamped_swiglu`)
+    poly: jnp.ndarray | None = None,  # "polynorm": [groups, 4], `moe.poly_terms`
+    eps: float | None = None,  # of "polynorm"'s three norms
 ) -> jnp.ndarray:
     """Rows ``[sum(sizes[:g]), sum(sizes[:g + 1]))`` times
     ``weights[-1][g]`` for every group ``g``, [total, n] in ``rows``'
@@ -313,17 +340,18 @@ def grouped_rows(
     docstring). Rows at and past ``sum(sizes)`` are never read and what
     comes back in their place is not defined. Forward only."""
     return _grouped_rows(
-        rows, weights, sizes, act, group_rows, interpret, limit
+        rows, weights, sizes, act, group_rows, interpret, limit, poly, eps
     )
 
 
-def _forward(rows, weights, sizes, act, group_rows, interpret, limit):
+def _forward(rows, weights, sizes, act, group_rows, interpret, limit, poly,
+             eps):
     return _grouped_rows(
-        rows, weights, sizes, act, group_rows, interpret, limit
+        rows, weights, sizes, act, group_rows, interpret, limit, poly, eps
     ), None
 
 
-def _backward(act, group_rows, interpret, limit, residuals, g):
+def _backward(act, group_rows, interpret, limit, eps, residuals, g):
     raise NotImplementedError(
         "ops/pallas/grouped_rows.py has no backward pass: the sorted "
         "expert form over the pairs computed here is a serving program's; "

@@ -49,7 +49,12 @@ concatenated with a broadcast ``kpe``; values narrower than the scores'
 contraction. The rotary parts come 128 wide, zeros behind the 64 (a
 cache cell's ``[kpe; zeros]`` as it lies): the scores' contraction is
 256 where the arithmetic needs 192, a third more of the score product
-and a fifth more of the kernel's operations, all tile-aligned.
+and a fifth more of the kernel's operations, all tile-aligned. Keys and
+values come a KV GROUP, ``[G, T, .]``: where the model shares an expanded
+group between ``H / G`` query heads (``models/motif.py``: 16 groups under
+80 heads, so a fifth of the per-head expansion) the heads of a group are
+neighbours and a head's blocks are its group's; with a key and value a
+head (``G == H``) the index maps are the plain ones.
 """
 
 from __future__ import annotations
@@ -302,9 +307,9 @@ def _prefill_kernel(
 def latent_prefill_attention(
     q_nope: jnp.ndarray,  # [H, C, nope]
     q_pe: jnp.ndarray,  # [H, C, R]: rope applied, zeros behind the rotary part
-    k_nope: jnp.ndarray,  # [H, T, nope]
+    k_nope: jnp.ndarray,  # [G, T, nope]: head h reads group h // (H / G)
     kpe: jnp.ndarray,  # [T, R]: one rotary key a token, for all heads
-    v: jnp.ndarray,  # [H, T, v]
+    v: jnp.ndarray,  # [G, T, v]
     start: jnp.ndarray,  # [] int32: position of query 0; keys start at 0
     *,
     scale: float,
@@ -320,6 +325,9 @@ def latent_prefill_attention(
     ``0 .. T - 1`` (query i sees keys <= start + i); returns [H, C, v]."""
     h, c, nope = q_nope.shape
     t, r = kpe.shape
+    n_rep = h // k_nope.shape[0]
+    # A key and a value a head: the index maps as they always were.
+    group = (lambda hi: hi) if n_rep == 1 else (lambda hi: hi // n_rep)
     v_dim = v.shape[-1]
     block_q, block_kv = _fit_block(block_q, c), _fit_block(block_kv, t)
     num_q, num_kv = c // block_q, t // block_kv
@@ -345,7 +353,7 @@ def latent_prefill_attention(
                 pl.BlockSpec((1, block_q, r), lambda hi, qi, ki, s: (hi, qi, 0)),
                 pl.BlockSpec(
                     (1, block_kv, nope),
-                    lambda hi, qi, ki, s: (hi, last_needed(qi, ki, s), 0),
+                    lambda hi, qi, ki, s: (group(hi), last_needed(qi, ki, s), 0),
                 ),
                 pl.BlockSpec(
                     (block_kv, r),
@@ -353,7 +361,7 @@ def latent_prefill_attention(
                 ),
                 pl.BlockSpec(
                     (1, block_kv, v_dim),
-                    lambda hi, qi, ki, s: (hi, last_needed(qi, ki, s), 0),
+                    lambda hi, qi, ki, s: (group(hi), last_needed(qi, ki, s), 0),
                 ),
             ],
             out_specs=pl.BlockSpec(

@@ -74,7 +74,7 @@ chunk programs a `mhc_mix` call reads 0.13 ms in the trace, a
 `mhc_spread` 0.10 and the whole residual path 0.28 ms a sublayer), the three ``H`` within 1.3e-6 of
 XLA's form's and ``h`` and the streams within a bf16 unit; at a decode
 step's 32 / 16 rows 0.076 / 0.065 for XLA's 0.065 / 0.064, which is why
-`glm5_next._MHC_KERNEL_ROWS` leaves those shapes to XLA. By (tokens a
+`mhc._MHC_KERNEL_ROWS` leaves those shapes to XLA. By (tokens a
 step, rows, lanes between a load and a store): (128, 16, 512) 0.292,
 (128, 32, 512) 0.297, (128, 16, 256) 0.299, (128, 16, 1024) 0.311,
 (256, 32, 1024) 0.364, (256, 16, 512) 0.373, (512, 16, 512) 0.399. One
@@ -89,7 +89,7 @@ not written back.
 
 The same arithmetic as XLA's form: float32 from the streams' load to
 the result's cast, exact ``exp``, sigmoids and divisions, ``eps`` and
-``iters`` the caller's. Forward only. Off the TPU ``glm5_next.mhc_mix``
+``iters`` the caller's. Forward only. Off the TPU ``mhc.mhc_mix``
 / ``mhc_spread`` keep XLA's form, which is tier 1's path and these
 kernels' oracle (tests/test_mhc_streams_kernel.py, interpreted).
 """
@@ -131,7 +131,7 @@ def _row_groups(tile: int, body) -> None:
 
 
 def _sinkhorn(m, n: int, iters: int, eps: float):
-    """`glm5_next.sinkhorn` on m [n n, tokens]: a token a lane, row ``n
+    """`mhc.sinkhorn` on m [n n, tokens]: a token a lane, row ``n
     i + j`` its matrix's entry (i, j); ``n`` a power of two."""
     size = n * n
     row = jax.lax.broadcasted_iota(jnp.int32, m.shape, 0)
@@ -303,7 +303,7 @@ def mhc_mix(
     eps: float,
     interpret: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """`glm5_next.mhc_mix`'s three results: ``h = Hpre X`` [.., d] in
+    """`mhc.mhc_mix`'s three results: ``h = Hpre X`` [.., d] in
     ``x``'s dtype, ``Hres`` [.., n, n] and ``Hpost`` [.., n] float32."""
     *lead, n, d = x.shape
     size, used = n * n, 2 * n + n * n
@@ -364,7 +364,7 @@ def mhc_spread(
     *,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """`glm5_next.mhc_spread`: ``Hres X + Hpost^T out`` [.., n, d] in
+    """`mhc.mhc_spread`: ``Hres X + Hpost^T out`` [.., n, d] in
     ``x``'s dtype, written over ``x`` where the program lets it."""
     *_, n, d = x.shape
     flat = x.reshape(-1, n * d)

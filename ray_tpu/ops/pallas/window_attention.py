@@ -45,7 +45,11 @@ that also masks by position.
 Grouped queries as in ``prefill_attention.py``: the ``n_rep`` query heads
 of a KV head side by side in the lanes of one block, each a lane-aligned
 slice attended against the step's one set of key and value tiles;
-operands in the cache's dtype, float32 scores.
+operands in the cache's dtype, float32 scores. The values' width is
+their own (``models/motif.py``: scores 192 wide, held 256 with the
+cell's zeros behind the rotary part, over values of 128, five query
+heads a group of expanded latent cells); where it is the keys' the
+blocks are the ones they were.
 
 Block sizes, found on the chip (v5e, 72 query / 8 KV heads of 128, bf16,
 2,048 queries at ``start`` 8,192 over a window of 512; my chip run, PR 55,
@@ -109,14 +113,15 @@ def band_blocks(c: int, window: int, block_q: int = _BLOCK_Q,
 
 
 def _kernel(start_ref, q_ref, *refs, block_q: int, block_kv: int, tiles: int,
-            n_rep: int, head_dim: int, window: int):
+            n_rep: int, head_dim: int, v_dim: int, window: int):
     """One (KV head, query block) step: the block's whole band at once.
     ``q_ref`` ``[block_q, n_rep * Dh]`` (pre-scaled); then ``tiles`` key
-    refs and ``tiles`` value refs ``[block_kv, Dh]``, consecutive key
-    blocks from the query block's own offset on; then ``o_ref`` as
-    ``q_ref``. Tile ``t``'s first key is ``t * block_kv`` ahead of the
-    block's first query, whatever the block: which pairs of it the band
-    holds is known when the kernel is traced."""
+    refs ``[block_kv, Dh]`` and ``tiles`` value refs ``[block_kv, Dv]``,
+    consecutive key blocks from the query block's own offset on; then
+    ``o_ref`` ``[block_q, n_rep * Dv]``. Tile ``t``'s first key is ``t *
+    block_kv`` ahead of the block's first query, whatever the block:
+    which pairs of it the band holds is known when the kernel is
+    traced."""
     k_refs, v_refs, o_ref = refs[:tiles], refs[tiles: 2 * tiles], refs[-1]
     qi = pl.program_id(1)
     k_lo = qi * block_q  # the key index of the band's first key
@@ -161,7 +166,9 @@ def _kernel(start_ref, q_ref, *refs, block_q: int, block_kv: int, tiles: int,
                             preferred_element_type=jnp.float32)
                 for p, v in zip(probs, values)
             )
-            o_ref[:, lanes] = (acc / total).astype(o_ref.dtype)
+            o_ref[:, r * v_dim: (r + 1) * v_dim] = (
+                acc / total
+            ).astype(o_ref.dtype)
 
     # Keys before position 0 exist only in a prompt's first chunks, and
     # there only in the query blocks whose band reaches back that far.
@@ -181,7 +188,7 @@ def _kernel(start_ref, q_ref, *refs, block_q: int, block_kv: int, tiles: int,
 def window_attention(
     q: jnp.ndarray,  # [C, H, Dh], rope applied
     k: jnp.ndarray,  # [Hkv, W + C, Dh]: the carried keys, then the chunk's
-    v: jnp.ndarray,  # [Hkv, W + C, Dh]
+    v: jnp.ndarray,  # [Hkv, W + C, Dv]: Dv need not be Dh
     start: jnp.ndarray,  # [] int32: position of query 0
     *,
     window: int,
@@ -192,12 +199,13 @@ def window_attention(
 ) -> jnp.ndarray:
     """Attention of C queries at ``start .. start + C - 1`` over keys at
     ``start - W .. start + C - 1`` given in that order, query ``t`` seeing
-    the keys at ``max(t - W + 1, 0) .. t``; returns ``[C, H, Dh]``. What
+    the keys at ``max(t - W + 1, 0) .. t``; returns ``[C, H, Dv]``. What
     ``k`` holds for a negative position does not reach the result; ``v``
     has to be finite there (a probability of 0 times it). ``scale``
     multiplies the scores (``Dh**-0.5`` where None)."""
     c, n_heads, head_dim = q.shape
     n_kv, total, _ = k.shape
+    v_dim = v.shape[-1]
     if total != window + c:
         raise ValueError(f"{total} keys for {c} queries and a window of {window}")
     blocks = band_blocks(c, window, block_q, block_kv)
@@ -212,25 +220,29 @@ def window_attention(
         q.astype(jnp.float32) * (head_dim**-0.5 if scale is None else scale)
     ).astype(dt).reshape(c, n_heads * head_dim)
     q_spec = pl.BlockSpec((block_q, group), lambda g, qi, s: (qi, g))
-    kv_specs = [
-        pl.BlockSpec(
-            (None, block_kv, head_dim),
-            lambda g, qi, s, t=t: (g, qi * (block_q // block_kv) + t, 0),
-        )
-        for t in range(tiles)
-    ]
+
+    def tile_specs(width):
+        return [
+            pl.BlockSpec(
+                (None, block_kv, width),
+                lambda g, qi, s, t=t: (g, qi * (block_q // block_kv) + t, 0),
+            )
+            for t in range(tiles)
+        ]
+
+    o_spec = pl.BlockSpec((block_q, n_rep * v_dim), lambda g, qi, s: (qi, g))
     out = pl.pallas_call(
         functools.partial(
             _kernel, block_q=block_q, block_kv=block_kv, tiles=tiles,
-            n_rep=n_rep, head_dim=head_dim, window=window,
+            n_rep=n_rep, head_dim=head_dim, v_dim=v_dim, window=window,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(n_kv, c // block_q),
-            in_specs=[q_spec, *kv_specs, *kv_specs],
-            out_specs=q_spec,
+            in_specs=[q_spec, *tile_specs(head_dim), *tile_specs(v_dim)],
+            out_specs=o_spec,
         ),
-        out_shape=jax.ShapeDtypeStruct((c, n_heads * head_dim), dt),
+        out_shape=jax.ShapeDtypeStruct((c, n_heads * v_dim), dt),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
             vmem_limit_bytes=_VMEM_LIMIT_BYTES,
@@ -238,7 +250,7 @@ def window_attention(
         interpret=interpret,
     )(jnp.reshape(start, (1,)).astype(jnp.int32), rows, *[k] * tiles,
       *[v] * tiles)
-    return out.reshape(c, n_heads, head_dim)
+    return out.reshape(c, n_heads, v_dim)
 
 
 def window_attention_dense(q, k, v, start, *, window: int,
@@ -259,4 +271,4 @@ def window_attention_dense(q, k, v, start, *, window: int,
     )
     probs = jax.nn.softmax(jnp.where(hidden, -jnp.inf, scores), axis=-1)
     out = jnp.einsum("grcj,gjd->cgrd", probs.astype(v.dtype), v)
-    return out.reshape(c, n_heads, head_dim)
+    return out.reshape(c, n_heads, v.shape[-1])

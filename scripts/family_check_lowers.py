@@ -23,6 +23,14 @@ switch:
     chiprun --timeout 3000 -- python scripts/family_check_lowers.py \
         --config longcat-flash-omni-serve1 --seed 7 --lower none weights_e4m3 \
         no_identity latent_unscaled shortcut_early router_bf16
+
+Motif-3-Beta's (`benchmarks/reference_motif.py`), ~20 s a switch on the
+9,000-token prompt alone:
+
+    chiprun --timeout 3000 -- python scripts/family_check_lowers.py \
+        --config motif3beta-serve1 --seed 7 --skip-whole --skip-long --lower none \
+        weights_e4m3 no_noise lambda_const window_as_full polynorm_as_silu \
+        static_h router_bf16
 """
 
 import argparse

@@ -36,7 +36,7 @@ import jax.numpy as jnp
 sys.path.insert(0, ".")
 
 from ray_tpu.llm import hybrid_kv  # noqa: E402
-from ray_tpu.models import glm5_next  # noqa: E402
+from ray_tpu.models import glm5_next, mhc  # noqa: E402
 from ray_tpu.models.moe import moe_ffn  # noqa: E402
 from ray_tpu.ops.pallas import gdn_chunk, kda_chunk, mhc_streams  # noqa: E402
 
@@ -90,23 +90,23 @@ def residual_path(hc, key, u):
     and a spread; ms a sublayer; the kernels' distance from XLA's form
     after the first sublayer; and the two calls by (tile, rows, lanes)."""
     n, d = CFG.hc_mult, CFG.d_model
-    on_the_chip = glm5_next.chip
+    on_the_chip = mhc.chip
     x = jax.random.normal(key, (1, TOKENS, n, d)).astype(CFG.dtype)
 
     def one_mix(p, x, y):
-        hh, mix = glm5_next.mhc_mix(x, p, CFG)
-        return glm5_next.mhc_spread(x, y + hh, *mix)
+        hh, mix = mhc.mhc_mix(x, p, CFG)
+        return mhc.mhc_spread(x, y + hh, *mix)
 
     ms, _ = timed(jax.jit(one_mix), hc, x, u[None])
     print(json.dumps({"mhc_mix_and_spread_ms": ms}), flush=True)
 
     def path(platform, sublayers):
         def chain(p, flat, y):
-            glm5_next.chip = types.SimpleNamespace(platform=lambda: platform)
+            mhc.chip = types.SimpleNamespace(platform=lambda: platform)
             x = flat.reshape(1, -1, n, d)
             for _ in range(sublayers):
-                h, mix = glm5_next.mhc_mix(x, p, CFG)
-                x = glm5_next.mhc_spread(x, y + h, *mix)
+                h, mix = mhc.mhc_mix(x, p, CFG)
+                x = mhc.mhc_spread(x, y + h, *mix)
             return x.reshape(flat.shape), h, *mix
         return jax.jit(chain)
 
@@ -121,8 +121,8 @@ def residual_path(hc, key, u):
         return round(
             timed(path(platform, MHC_CHAIN), hc, flat, y)[0] / MHC_CHAIN, 4)
 
-    by_rows = glm5_next._MHC_KERNEL_ROWS
-    glm5_next._MHC_KERNEL_ROWS = 0  # the platform alone chooses, below
+    by_rows = mhc._MHC_KERNEL_ROWS
+    mhc._MHC_KERNEL_ROWS = 0  # the platform alone chooses, below
     for tokens in (TOKENS, 32, 16):
         flat, y = operands(tokens)
         print(json.dumps({"mhc": "xla", "tokens": tokens,
@@ -156,8 +156,8 @@ def residual_path(hc, key, u):
             line["refused"] = repr(e)[-400:]
         print(json.dumps(line), flush=True)
     mhc_streams._TILE, mhc_streams._ROWS, mhc_streams._LANES = default
-    glm5_next._MHC_KERNEL_ROWS = by_rows
-    glm5_next.chip = on_the_chip
+    mhc._MHC_KERNEL_ROWS = by_rows
+    mhc.chip = on_the_chip
 
 
 def main():

@@ -30,7 +30,7 @@ from benchmarks import reference_glm5_next as reference
 from benchmarks.models import glm5_next as bench_model
 from ray_tpu.llm import hybrid_kv
 from ray_tpu.llm.engine import LLMEngine, SamplingParams
-from ray_tpu.models import glm5_next, moe
+from ray_tpu.models import glm5_next, mhc, moe
 from ray_tpu.models.glm5_next import Glm5NextConfig, init_params
 from ray_tpu.models.moe import moe_ffn
 from ray_tpu.ops.norms import rms_norm
@@ -541,7 +541,7 @@ def test_router_h_states_and_scores_are_float32():
     assert cache["latent"].dtype == cache["index"].dtype == jnp.bfloat16
     x = jax.ShapeDtypeStruct((5, 4, 64), jnp.bfloat16)
     h, (res, post) = jax.eval_shape(
-        lambda x, p: glm5_next.mhc_mix(x, p, cfg), x, tree["blocks"][0]["hc"]
+        lambda x, p: mhc.mhc_mix(x, p, cfg), x, tree["blocks"][0]["hc"]
     )
     assert (h.dtype, res.dtype, post.dtype) == (
         jnp.bfloat16, jnp.float32, jnp.float32)
@@ -556,7 +556,7 @@ def test_router_h_states_and_scores_are_float32():
 
 def test_sinkhorn_leaves_a_doubly_stochastic_matrix():
     m = jnp.exp(jax.random.normal(jax.random.key(2), (6, 4, 4)) * 2.0)
-    out = np.asarray(glm5_next.sinkhorn(m, 20, 1e-6))
+    out = np.asarray(mhc.sinkhorn(m, 20, 1e-6))
     np.testing.assert_allclose(out.sum(-1), 1.0, atol=1e-3)
     np.testing.assert_allclose(out.sum(-2), 1.0, atol=1e-5)
 

@@ -1,5 +1,5 @@
 """ops/pallas/mhc_streams.py interpreted, against what it replaces on a
-TPU: `models/glm5_next.py mhc_mix` / `mhc_spread` in XLA's own
+TPU: `models/mhc.py mhc_mix` / `mhc_spread` in XLA's own
 operations (the bodies those functions keep off the TPU).
 
 The two calls are made alone on streams of tiny and of the served width,
@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import glm5_next
+from ray_tpu.models import glm5_next, mhc
 from ray_tpu.ops.pallas import mhc_streams
 
 TILE = mhc_streams._TILE
@@ -38,7 +38,7 @@ class Case:
     d: int = 64
     dtype: str = "bfloat16"
     a_res: float = 1.0
-    # "calls": the two kernels alone. "model": `glm5_next.mhc_mix` /
+    # "calls": the two kernels alone. "model": `mhc.mhc_mix` /
     # `mhc_spread` as on a TPU, which take them from `_MHC_KERNEL_ROWS` on.
     through: str = "calls"
 
@@ -61,8 +61,8 @@ CASES = [
     Case((1, TILE + 70)),  # a prefill chunk's [B, S, n, d]
     Case((2, 70)),
     Case((16,), d=4096),  # a decode step's [B, n, d]
-    Case((1, glm5_next._MHC_KERNEL_ROWS), through="model"),
-    Case((glm5_next._MHC_KERNEL_ROWS - 1, 1), through="model"),
+    Case((1, mhc._MHC_KERNEL_ROWS), through="model"),
+    Case((mhc._MHC_KERNEL_ROWS - 1, 1), through="model"),
 ]
 
 
@@ -86,7 +86,7 @@ def test_the_kernels_are_xlas_form(case, monkeypatch):
         dtype=dtype,
     )
     n = cfg.hc_mult
-    p = glm5_next._init_hc(jax.random.key(1), cfg)
+    p = mhc.init_hc(jax.random.key(1), cfg)
     p["scale"] = jnp.array([0.7, 1.3, case.a_res], jnp.float32)
     p["b_pre"] = p["b_pre"] + 0.3
     p["b_post"] = p["b_post"] - 0.2
@@ -94,8 +94,8 @@ def test_the_kernels_are_xlas_form(case, monkeypatch):
     x = (2.0 * jax.random.normal(keys[0], (*case.lead, n, case.d))).astype(dtype)
     y = jax.random.normal(keys[1], (*case.lead, case.d)).astype(dtype)
     # The oracle: XLA's form, which is what runs off the TPU.
-    want_h, (want_res, want_post) = glm5_next.mhc_mix(x, p, cfg)
-    want_x = glm5_next.mhc_spread(x, y, want_res, want_post)
+    want_h, (want_res, want_post) = mhc.mhc_mix(x, p, cfg)
+    want_x = mhc.mhc_spread(x, y, want_res, want_post)
 
     calls = []
 
@@ -111,13 +111,13 @@ def test_the_kernels_are_xlas_form(case, monkeypatch):
     )
     if case.through == "model":
         monkeypatch.setattr(
-            glm5_next, "chip", types.SimpleNamespace(platform=lambda: "tpu")
+            mhc, "chip", types.SimpleNamespace(platform=lambda: "tpu")
         )
-        monkeypatch.setattr(glm5_next, "mhc_streams", interpreted)
-        h, (h_res, h_post) = glm5_next.mhc_mix(x, p, cfg)
-        wrote = glm5_next.mhc_spread(x, y, want_res, want_post)
+        monkeypatch.setattr(mhc, "mhc_streams", interpreted)
+        h, (h_res, h_post) = mhc.mhc_mix(x, p, cfg)
+        wrote = mhc.mhc_spread(x, y, want_res, want_post)
         rows = int(np.prod(case.lead))
-        by_kernels = rows >= glm5_next._MHC_KERNEL_ROWS
+        by_kernels = rows >= mhc._MHC_KERNEL_ROWS
         assert calls == (["mhc_mix", "mhc_spread"] if by_kernels else [])
     else:
         h, h_res, h_post = interpreted.mhc_mix(

@@ -27,6 +27,7 @@ from ray_tpu.models.glm5_next import GLM5_NEXT_PRESETS
 from ray_tpu.models.laguna import LAGUNA_PRESETS
 from ray_tpu.models.llama import PRESETS
 from ray_tpu.models.longcat_flash import LONGCAT_PRESETS
+from ray_tpu.models.motif import MOTIF_PRESETS
 from ray_tpu.models.nemotron_h import NEMOTRON_H_PRESETS
 from ray_tpu.models.pangu_ultra_moe import PANGU_PRESETS
 from ray_tpu.models.qwen3_next import QWEN3_NEXT_PRESETS
@@ -162,7 +163,8 @@ moe_rows_sorted moe_sorted_rows_pct moe_zero_pairs
 HYBRID_KEYS = """
 dsa_causal_pairs dsa_index_pairs dsa_selected_pairs dsa_tokens
 gdn_kernel_tokens gdn_scan_tokens index_bytes kda_scan_tokens
-latent_bytes mhc_tokens moe_combine_kernel prefill_attn_pairs
+latent_bytes latent_cells_expanded mhc_tokens moe_combine_kernel
+prefill_attn_pairs
 prefill_programs prefill_window_pairs ssm_scan_tokens window_bytes
 window_tokens
 """.split()
@@ -195,6 +197,8 @@ FAMILIES = {
         lambda: GLM5_NEXT_PRESETS["glm5_next_tiny"],
         HYBRID_KEYS + ["state_step_kernel"],
     ),
+    # No recurrence: latent cells in pages and in rings.
+    "motif": (lambda: MOTIF_PRESETS["motif_tiny"], HYBRID_KEYS),
     "pangu": (lambda: PANGU_PRESETS["pangu_tiny"], LATENT_KEYS),
     # `zero_expert_*` / `real_experts_*` come with the first routed pair.
     "longcat": (lambda: LONGCAT_PRESETS["longcat_tiny"], LATENT_KEYS),

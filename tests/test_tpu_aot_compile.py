@@ -653,3 +653,82 @@ def test_the_sub_chunked_rule_and_the_selection_lower_for_v5e(v5e):
     ).compile()
     assert "bf16[128,2048,512]" in gather.as_text()
     assert _copies_of(gather.as_text(), (65536, 512)) == []
+
+
+# ------------- Motif-3-Beta: grouped latent attention, PolyNorm experts
+def _motif_case(case: str, on):
+    """The kernel and its arguments at motif3beta-serve1's shapes: 16
+    slots, 16,449 pages of 64 cells 640 wide, tables of 1,028 pages, a
+    2,048-token chunk, 80 query heads (192 | 128) over 16 expanded KV
+    groups, a window of 128, 48 held experts of 1,280 behind a model
+    width of 4,096."""
+    from ray_tpu.ops.pallas import grouped_rows
+    from ray_tpu.ops.pallas.expert_rows import experts_on_rows
+    from ray_tpu.ops.pallas.latent_attention import (
+        latent_paged_attention,
+        latent_prefill_attention,
+    )
+    from ray_tpu.ops.pallas.window_attention import window_attention
+
+    def arr(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=on)
+
+    i32 = partial(arr, dtype=jnp.int32)
+    f32 = partial(arr, dtype=jnp.float32)
+    d, f, held, heads, groups = 4096, 1280, 48, 80, 16
+    if case == "grouped_prefill_80_of_16":
+        keys = 65536
+        return partial(latent_prefill_attention, scale=192**-0.5), (
+            arr(heads, 2048, 128), arr(heads, 2048, 128),
+            arr(groups, keys, 128), arr(keys, 128), arr(groups, keys, 128),
+            i32(),
+        )
+    if case == "paged_80_rows":
+        return partial(latent_paged_attention, v_width=512, scale=192**-0.5), (
+            arr(16, 1, heads, 640), arr(16449, 64, 640), i32(16, 1028), i32(16)
+        )
+    if case == "band_192_over_128":
+        return partial(window_attention, window=128, scale=192**-0.5), (
+            arr(2048, heads, 256), arr(groups, 128 + 2048, 256),
+            arr(groups, 128 + 2048, 128), i32(),
+        )
+    if case == "every_row_polynorm":
+        def every_row(*args):
+            return experts_on_rows(*args[:-1], poly=args[-1], eps=1e-5)
+
+        return every_row, (
+            *_expert_rows_args(on, 16, held, d, f, True), f32(held, 4)
+        )
+    total = 2048 * 8
+
+    def experts_on(rows, sizes, poly, *stacks):
+        return grouped_rows.grouped_rows(
+            rows, stacks, sizes, "polynorm", total // 384, poly=poly, eps=1e-5
+        )
+
+    return experts_on, (
+        arr(total, d), i32(held), f32(held, 4), arr(held, d, f),
+        arr(held, d, f),
+    )
+
+
+@pytest.mark.parametrize(
+    "case",
+    ["grouped_prefill_80_of_16", "paged_80_rows", "band_192_over_128",
+     "every_row_polynorm", "up_projections_polynorm"],
+)
+def test_kernel_compiles_for_v5e_at_motif3betas_shapes(v5e, case):
+    """What motif3beta-serve1 changed in four kernels lowers for the
+    chip: five query heads reading one expanded group's blocks in the
+    latent prefill kernel, 80 rows a slot in the latent decode kernel, a
+    band whose values are narrower than its keys (five heads of 256 a
+    query block against three key tiles), and a PolyNorm expert's whole
+    width of 1,280 in one step of both expert kernels (63 MB of weight
+    buffers in the every-row form, over the budget the other kinds tile
+    under), its numbers scalars in SMEM; no expert stack is copied."""
+    fn, args = _motif_case(case, v5e)
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert _copies_of(text, (48, 4096, 1280)) == []
+    assert _copies_of(text, (16449, 64, 640)) == []

@@ -899,7 +899,7 @@ def test_glm5_next_program_moves_no_pool_or_stack_and_fits(
         assert "jit(kda_state_step)" in text
         assert len(_expert_kernel_calls(text)) == 1  # the one sparse FFN
         assert memory.temp_size_in_bytes < 2**30
-        # 16 slots are under `glm5_next._MHC_KERNEL_ROWS`: the residual
+        # 16 slots are under `mhc._MHC_KERNEL_ROWS`: the residual
         # path keeps XLA's form here (it reads no slower alone, PR 64).
         assert _kernel_calls_under(text, "mhc:") == []
         assert _arrays_under(text, "mhc:mix") != set()
@@ -991,12 +991,113 @@ def test_glm5_next_program_moves_no_pool_or_stack_and_fits(
     assert arguments + memory.temp_size_in_bytes < 15.75 * 2**30
 
 
+@pytest.fixture(scope="module")
+def motif_programs(v5e):
+    """motif3beta-serve1's own sizes (benchmarks/configs) at 3 of its 5
+    layers (published layers 1-3: a window layer with the dense FFN, a
+    window layer and the full layer with experts: all four letters),
+    with the whole configuration's pages and slots: what
+    `aot_fit_serve_family` lowers, at the narrowest and the widest table
+    of the mix. Compiled when first asked for."""
+    import json
+
+    from benchmarks import aot_fit_serve_family
+    from ray_tpu._private import chip
+
+    here = os.path.join(os.path.dirname(__file__), "..", "benchmarks")
+    with open(os.path.join(here, "configs", "motif3beta-serve1.json")) as f:
+        whole = json.load(f)
+    conf = {**whole, "num_hidden_layers": 3}
+    traffic = {"fit_prefill_buckets": [8192, 65536]}
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(chip, "platform", lambda: "tpu")
+        lowered = aot_fit_serve_family.lowered_programs(
+            conf, traffic, next(iter(v5e.device_set))
+        )
+    compiled = {}
+
+    def program(name):
+        if name not in compiled:
+            compiled[name] = lowered[name].compile()
+        return compiled[name]
+
+    return whole, program
+
+
+@pytest.mark.parametrize(
+    "program",
+    ["prefill_chunk_2048_of_8192", "prefill_chunk_2048_of_65536", "decode"],
+)
+def test_motif_program_moves_no_pool_ring_or_stack_and_fits(
+    motif_programs, program
+):
+    """Through the same `llm/hybrid_kv.py` with the letters `A` and `R`:
+    the donated cache updated in place (the full layer's pool of cells,
+    the window layers' rings a slot), the 48 held experts' stacks read
+    where they lie, a chunk's attention by the two kernels (no score
+    over the table or the band in HBM), a step's full layer by the paged
+    latent kernel, and the temporaries beside the WHOLE configuration's
+    arguments under what a v5e offers a program."""
+    from benchmarks.models import motif as family
+
+    conf, compiled_program = motif_programs
+    eng = conf["engine"]
+    d, f, held = (conf["hidden_size"], conf["moe_intermediate_size"],
+                  conf["num_experts"])
+    pages, slots, w = eng["num_pages"] + 1, eng["max_batch"], conf["sliding_window"]
+    width = 640
+    shapes = {
+        "cells": ((PAGE, width), pages * PAGE * width),
+        "win_cells": ((w, width), 2 * slots * w * width),
+        "w_up": ((d, f), held * d * f),
+        "w_down": ((f, d), held * d * f),
+    }
+    compiled = compiled_program(program)
+    text = compiled.as_text()
+    # (The decode program's `copy-start` of the rings is the compiler's
+    # own prefetch of 5 MB into VMEM, `S(1)`, in the layout they have: it
+    # is no re-layout and no second buffer in HBM. A `copy`, `transpose`
+    # or slice of them is what a gather by ring index cost: `_rolled`.)
+    assert [
+        move for move in _hybrid_moves(text, shapes)
+        if not move.startswith("win_cells: copy-start")
+    ] == []
+    memory = compiled.memory_analysis()
+    heads, chunk = conf["num_attention_heads"], eng["prefill_chunk"]
+    if program == "decode":
+        assert len(_kernel_calls_under(text, "mla:attend")) == 1
+        assert "jit(latent_paged_attention)" in text
+        assert len(_expert_kernel_calls(text)) == 2  # two sparse FFNs
+        assert memory.temp_size_in_bytes < 2**28
+    else:
+        table = int(program.rsplit("_", 1)[1])
+        assert len(_kernel_calls_under(text, "mla:attend")) == 1
+        assert "jit(latent_prefill_attention)" in text
+        assert len(_kernel_calls_under(text, "attn:window")) == 2
+        assert len(_grouped_kernel_calls(text)) == 4  # two sparse FFNs
+        for keys in (table, w + chunk):
+            assert f"[{heads},{chunk},{keys}]" not in text
+            assert f"[16,5,{chunk},{keys}]" not in text
+        assert memory.temp_size_in_bytes < 2**30
+    arguments = conf["fit"]["argument_bytes"]
+    counted = (
+        family.held_parameters(conf) * 2
+        + family.full_layers(conf) * pages * PAGE * width * 2
+        + family.window_layers(conf) * slots * w * width * 2
+    )
+    # The float32 leaves (routers, norms, the residual mixing's P) are
+    # 16 MB more.
+    assert abs(arguments - counted) < 3.2e7
+    assert arguments > 0.25 * 16 * 2**30  # the floor a new cell is held to
+    assert arguments + memory.temp_size_in_bytes < 15.75 * 2**30
+
+
 @pytest.mark.parametrize("family", ["hybrid", "granite", "qwen3next", "laguna"])
 def test_a_program_of_one_residual_stream_holds_no_stream_kernel(
     family, request
 ):
     """`hybrid_kv._read` / `_residual` are every hybrid family's, and
-    reach `glm5_next.mhc_mix` / `mhc_spread` (on a TPU: the two calls of
+    reach `mhc.mhc_mix` / `mhc_spread` (on a TPU: the two calls of
     ops/pallas/mhc_streams.py) only where the config carries more than
     one residual stream, which GLM-5.3-Flash's alone does: no other
     family's program holds either scope or either call. (Their lowered

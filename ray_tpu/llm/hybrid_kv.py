@@ -154,6 +154,7 @@ from ray_tpu.models.nemotron_h import (
 )
 from ray_tpu.models.qwen3_next import gdn_chunked, gdn_step, gdn_step_live
 from ray_tpu.ops.norms import rms_norm
+from ray_tpu.ops.pallas.latent_attention import keys_expanded
 from ray_tpu.ops.pallas.state_step import live_order
 from ray_tpu.ops.pallas.window_attention import (
     band_blocks,
@@ -842,8 +843,10 @@ class HybridServing(Serving):
         # tokens are `prefill_attn_pairs` and `prefill_window_pairs` /
         # `window_tokens`, their bytes among `latent_bytes` and
         # `window_bytes`): the cells its prefill programs turned back
-        # into keys and values, the whole table a full layer and the
-        # ring and the chunk a window layer.
+        # into keys and values, a full layer the table's key blocks up
+        # to the chunk's end by the kernels (`keys_expanded`) and the
+        # whole table under dense scores, a window layer the ring and
+        # the chunk.
         gdn_tokens = self.cfg.count("G") * self._live_tokens
         on_tpu = chip.platform() == "tpu"
         out = {
@@ -878,8 +881,8 @@ class HybridServing(Serving):
             out["state_step_kernel"] = on_tpu
         return out
 
-    def _count(self, start: int, width: int, length: int,
-               table: int) -> None:
+    def _count(self, start: int, width: int, length: int, table: int,
+               use_kernel: bool) -> None:
         n = max(min(width, length - start), 0)
         self._prefill_programs += 1
         self._live_tokens += n
@@ -888,7 +891,10 @@ class HybridServing(Serving):
         )
         if rings := self.cfg.count("R"):
             self._cells_expanded += rings * (self.cfg.sliding_window + width)
-        self._cells_expanded += self.cfg.count("A") * table
+        if full := self.cfg.count("A"):
+            self._cells_expanded += full * (
+                keys_expanded(start, width, table) if use_kernel else table
+            )
         if layers := self.cfg.count("W") + self.cfg.count("R"):
             w = self.cfg.sliding_window
             self._window_pairs += layers * (
@@ -909,7 +915,7 @@ class HybridServing(Serving):
                       use_kernel=False):
         self._count(
             int(start), tokens.shape[1], length,
-            n_write_pages * tokens.shape[1] // chunk_pages,
+            n_write_pages * tokens.shape[1] // chunk_pages, use_kernel,
         )
         return prefill_program(
             self.cfg, n_write_pages, chunk_pages, use_kernel

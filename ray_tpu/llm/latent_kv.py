@@ -39,14 +39,24 @@ tested against).
 or one chunk of it): per (query, key) pair and head the expanded form
 costs 192 + 128 multiply-adds and the absorbed one 576 + 512, so with
 thousands of queries against each key it pays to turn the latents back
-into keys and values, ``c Wuk_h`` and ``c Wuv_h``. That is done a block
-of ``cfg.prefill_key_block`` keys at a time, read from the pages (this
-chunk's own cells too: they were written first), inside a loop with a
-running soft-max whose trip count is the context so far, so that no
-``[heads, chunk, context]`` scores and no whole context of expanded keys
-ever exist, and a chunk's work follows what it attends and not the
-bucket's width. Earlier chunks' latents are expanded again in every
-later chunk (``stats()["latent_tokens_expanded"]``).
+into keys and values, ``c Wuk_h`` and ``c Wuv_h``, read from the pages
+(this chunk's own cells too: they were written first). Either way a
+chunk's work follows what it attends and not the bucket's width: the
+key blocks past the chunk's end are neither expanded nor attended. Off
+the TPU (`_attend_expanded`) that is done a block of
+``cfg.prefill_key_block`` keys at a time inside a loop with a running
+soft-max whose trip count is the context so far, so that no ``[heads,
+chunk, context]`` scores and no whole context of expanded keys ever
+exist. On a bare TPU (`_attend_expanded_kernel`) by two kernels of
+``ops/pallas/latent_attention.py`` that count a chunk's key blocks (of
+1,024) by one rule: `latent_expand` writes the keys and values of the
+blocks up to the chunk's last into arrays as wide as the table, whose
+other blocks it never touches (PR 66; until then two einsums over the
+whole table, the part no token had reached with it), and
+`latent_prefill_attention` reads no further. Earlier chunks' LIVE
+latents are expanded again in every later chunk
+(``stats()["latent_tokens_expanded"]``: whole key blocks up to each
+chunk's end, in every attention sublayer).
 
 The program takes the context's true length: positions from it on are
 padding, their expert pairs are left out, and the logits returned are
@@ -236,25 +246,33 @@ def _attend_expanded(q_nope, q_pe, pool, page_ids, start, p, cfg):
 
 
 def _attend_expanded_kernel(q_nope, q_pe, pool, page_ids, start, p, cfg):
-    """`_attend_expanded` on a bare TPU: the context's whole table of
-    latents is turned into keys and values at once (one layer's, for
-    16,384 tokens, are 1.07 GB; the expansion is a few ms) and one
-    flash-style kernel attends them, skipping what lies past the chunk
-    (``ops/pallas/latent_attention.py``). The rotary key goes in as the
-    cell holds it, ``[kpe; zeros]``. Returns [C, H, v]."""
-    from ray_tpu.ops.pallas.latent_attention import latent_prefill_attention
+    """`_attend_expanded` on a bare TPU, by two kernels of
+    ``ops/pallas/latent_attention.py`` that count a chunk's key blocks by
+    one rule: `latent_expand` turns the table's latents into keys and
+    values as far as the chunk's last key block (one layer's, for 16,384
+    tokens, are 1.07 GB: the blocks past the chunk are never written,
+    and hold whatever the buffers held), and the flash-style kernel
+    attends them and reads nothing past that block either. The rotary
+    key goes in as the cell holds it, ``[kpe; zeros]``. Returns [C, H,
+    v]."""
+    from ray_tpu.ops.pallas.latent_attention import (
+        latent_expand,
+        latent_prefill_attention,
+    )
 
-    rank = cfg.kv_lora_rank
+    interpret = chip.platform() != "tpu"
     cells = pool[page_ids].reshape(-1, pool.shape[-1])  # [T, W]
     with jax.named_scope("mla:expand"):
-        k_nope = jnp.einsum("tc,hcd->htd", cells[:, :rank], p["w_uk"])
-        v = jnp.einsum("tc,hcd->htd", cells[:, :rank], p["w_uv"])
+        k_nope, v = latent_expand(
+            cells, p["w_uk"], p["w_uv"], start, n_queries=q_nope.shape[0],
+            interpret=interpret,
+        )
     with jax.named_scope("mla:attend"):
         heads = latent_prefill_attention(
             q_nope.transpose(1, 0, 2),
             pad_to_cell(q_pe, cfg).transpose(1, 0, 2),
-            k_nope, cells[:, rank:], v, start, scale=cfg.softmax_scale,
-            interpret=chip.platform() != "tpu",
+            k_nope, cells[:, cfg.kv_lora_rank:], v, start,
+            scale=cfg.softmax_scale, interpret=interpret,
         )
         return heads.transpose(1, 0, 2)
 
@@ -439,13 +457,15 @@ class LatentServing(Serving):
                       n_write_pages, chunk_pages, slot, length, use_kernel):
         """One prefill program, and its counters: the (query, key) pairs
         it attends under the causal mask, and the cached tokens it turns
-        back into keys and values (the kernel path: the whole table; the
-        XLA path: whole key blocks up to the chunk's end), in every
-        attention sublayer."""
+        back into keys and values (whole key blocks up to the chunk's
+        end: the kernels' blocks, `keys_expanded`, or the XLA path's),
+        in every attention sublayer."""
         cfg, c = self.cfg, tokens.shape[1]
         page_size = cache["latent"].shape[2]
         if use_kernel:
-            expanded = n_write_pages * page_size
+            from ray_tpu.ops.pallas.latent_attention import keys_expanded
+
+            expanded = keys_expanded(int(start), c, n_write_pages * page_size)
         else:
             block = max(cfg.prefill_key_block // page_size, 1) * page_size
             expanded = -(-(int(start) + c) // block) * block

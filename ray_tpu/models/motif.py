@@ -83,6 +83,7 @@ from ray_tpu.models.nemotron_h import (
 from ray_tpu.models.pangu_ultra_moe import pad_to_cell
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.pallas.latent_attention import (
+    latent_expand,
     latent_paged_attention,
     latent_prefill_attention,
 )
@@ -423,10 +424,12 @@ def gdla_prefill_full(h, p, cfg: MotifConfig, pool, base, pages, chunk_pages,
     positions ``start ..`` (page-aligned); ``pool`` the cache's ``cells``
     [pages, P, cell_width], flat over the layers (``base`` this layer's
     first page); ``pages`` the context's table, ``chunk_pages`` the
-    chunk's own. Writes the chunk's cells, turns the whole table's cells
-    back into 16 groups' keys and values (an earlier chunk's again: a
-    few ms at 64k) and attends them causally, by the prefill kernel
-    where ``use_kernel``. Returns (out [C, d], pool)."""
+    chunk's own. Writes the chunk's cells, turns the table's cells back
+    into 16 groups' keys and values (an earlier chunk's again) and
+    attends them causally: where ``use_kernel`` by `latent_expand` and
+    the prefill kernel, neither of which touches a key block past the
+    chunk's last; else the whole table under dense scores. Returns (out
+    [C, d], pool)."""
     c_len = h.shape[0]
     page = pool.shape[1]
     positions = start + jnp.arange(c_len, dtype=jnp.int32)
@@ -437,19 +440,26 @@ def gdla_prefill_full(h, p, cfg: MotifConfig, pool, base, pages, chunk_pages,
         )
     table = jnp.take(pool, base + pages, axis=0, mode="clip")
     table = table.reshape(-1, table.shape[-1])  # [T, cell_width]
-    k_nope, v = _expand(table, p, cfg)
     rank = cfg.kv_lora_rank
-    with jax.named_scope("mla:attend"):
-        if use_kernel:
+    if use_kernel:
+        interpret = chip.platform() != "tpu"
+        with jax.named_scope("mla:expand"):
+            k_nope, v = latent_expand(
+                table, p["w_uk"], p["w_uv"], start, n_queries=c_len,
+                interpret=interpret,
+            )
+        with jax.named_scope("mla:attend"):
             heads = latent_prefill_attention(
                 _heads_first(q_nope), _heads_first(pad_to_cell(q_pe, cfg)),
                 k_nope, table[:, rank:], v, start, scale=cfg.softmax_scale,
-                interpret=chip.platform() != "tpu",
+                interpret=interpret,
             )  # [H, C, v]
             heads = heads.reshape(
                 cfg.n_kv_heads, -1, c_len, heads.shape[-1]
             ).transpose(2, 0, 1, 3)
-        else:
+    else:
+        k_nope, v = _expand(table, p, cfg)
+        with jax.named_scope("mla:attend"):
             hidden = jnp.arange(table.shape[0])[None, :] > positions[:, None]
             heads = _attend_dense(
                 q_nope, q_pe, k_nope, table[:, rank: cfg.latent_dim], v,

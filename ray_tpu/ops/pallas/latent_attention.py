@@ -1,5 +1,6 @@
-"""Latent attention's two kernels: paged decode attention over a LATENT
-page pool, and a chunk's causal attention over expanded keys and values.
+"""Latent attention's three kernels: paged decode attention over a
+LATENT page pool, a chunk's causal attention over expanded keys and
+values, and the expansion in front of it.
 
 **Decode** (``latent_paged_attention``). The sibling of
 ``paged_attention.py`` for multi-head latent attention in its absorbed
@@ -33,7 +34,7 @@ chip it reaches 47% of that in the serving cell's decode steps
 them at 4-16k, a call takes 1.13-1.26 ms on the host's clock for 165,312
 live tokens with blocks of 4 to 16 pages: my chip runs, PR 33): a
 block's two products have 128 rows against a 512-key tile, and the
-running sum is a loop-carried value (ROADMAP R4).
+running sum is a loop-carried value (ROADMAP S15).
 
 **Prefill** (``latent_prefill_attention``): a chunk of C queries at
 positions ``start .. start + C - 1`` over T expanded keys, flash style
@@ -55,6 +56,25 @@ group between ``H / G`` query heads (``models/motif.py``: 16 groups under
 80 heads, so a fifth of the per-head expansion) the heads of a group are
 neighbours and a head's blocks are its group's; with a key and value a
 head (``G == H``) the index maps are the plain ones.
+
+**The expansion** (``latent_expand``, PR 66): the keys and values that
+kernel reads, ``c W_uk`` and ``c W_uv`` a group, made from a table's
+cells as far as the chunk attends and no further. The prefill kernel's
+index maps stop at the key block that holds the chunk's last position
+(`_last_key_block`); the expansion's stop at the same block by the same
+rule: a grid over (blocks of groups, key blocks) whose steps past that
+block do nothing and repeat its index, so that nothing is fetched for
+them and nothing written: the output arrays are as wide as the table and
+their blocks past the chunk's end hold whatever the buffers held. No
+fill passes over them either (1.07 GB of zeros a layer at 16,384 cells
+and 128 heads would cost half of what the bound saves). A step takes a
+block of 1,024 cells' latents once for both products of
+``block_groups`` groups, whose two matrices stay in VMEM while the key
+blocks pass. `keys_expanded` is the same count on the host, for the
+serving objects' counters. Until PR 66 two einsums of XLA's turned the
+WHOLE table into keys and values in every chunk, the part of the bucket
+no token had reached with it (half of the cells expanded in
+``pangu-longdoc-16``'s traffic).
 """
 
 from __future__ import annotations
@@ -239,6 +259,130 @@ def latent_paged_attention(
 
 
 # ------------------------------------------------------------------ prefill
+# Keys a block of the prefill kernel, and so of the expansion in front
+# of it: the two count a chunk's key blocks by one rule.
+_PREFILL_BLOCK_KV = 1024
+
+
+def _last_key_block(start, n_queries, block_kv):
+    """The key block that holds position ``start + n_queries - 1``: the
+    last one that queries ``start ..``, that many, read under the causal
+    mask. Python's integers or traced ones."""
+    return (start + n_queries - 1) // block_kv
+
+
+def keys_expanded(start: int, n_queries: int, n_keys: int,
+                  block_kv: int = _PREFILL_BLOCK_KV) -> int:
+    """The keys `latent_expand` writes for a chunk of ``n_queries`` at
+    ``start`` over a table of ``n_keys``: whole key blocks up to the
+    chunk's end, the table at most. On the host, for the counters."""
+    block_kv = _fit_block(block_kv, n_keys)
+    return min(
+        (_last_key_block(start, n_queries, block_kv) + 1) * block_kv, n_keys
+    )
+
+
+def _expand_kernel(
+    start_ref, cells_ref, wk_ref, wv_ref, k_ref, v_ref, *, n_queries: int,
+    block_kv: int,
+):
+    """One (group block, key block) step: the block's latents times each
+    group's two matrices. Key blocks past the chunk's last do nothing,
+    and their index maps repeat that last block's index: nothing is
+    fetched for them and the output block, which stays where it is, is
+    written back once, when the group block changes."""
+    ki = pl.program_id(1)
+
+    @pl.when(ki <= _last_key_block(start_ref[0], n_queries, block_kv))
+    def _expand():
+        cells = cells_ref[...]  # [block_kv, rank]
+        for g in range(wk_ref.shape[0]):
+            for w_ref, out_ref in ((wk_ref, k_ref), (wv_ref, v_ref)):
+                out_ref[g] = jax.lax.dot(
+                    cells, w_ref[g], preferred_element_type=jnp.float32
+                ).astype(out_ref.dtype)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("n_queries", "block_kv", "block_groups", "interpret"),
+)
+def latent_expand(
+    cells: jnp.ndarray,  # [T, W]: a table's cells, the latent in front
+    w_uk: jnp.ndarray,  # [G, rank, nope]
+    w_uv: jnp.ndarray,  # [G, rank, v]
+    start: jnp.ndarray,  # [] int32: position of the chunk's first query
+    *,
+    n_queries: int,
+    block_kv: int = _PREFILL_BLOCK_KV,
+    # Groups a step: their two matrices stay in VMEM while the key blocks
+    # pass, and a step's two outputs are block_groups x 0.25 MB each at
+    # 1,024 keys of 128 bf16 numbers.
+    block_groups: int = 8,
+    interpret: bool = False,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The latents of a table turned back into keys (without their rotary
+    part) and values, ``c W_uk`` and ``c W_uv`` a group, as far as a
+    chunk of ``n_queries`` at ``start`` attends: returns ``k_nope [G, T,
+    nope]`` and ``v [G, T, v]`` whose key blocks up to the chunk's last
+    (`_last_key_block`, the rule of `latent_prefill_attention`'s index
+    maps at the same ``block_kv``) hold the two products and whose
+    blocks past it are NEVER WRITTEN: what they hold is whatever the
+    buffers held, and the kernel behind reads none of it. Nothing passes
+    over the dead part, to fill it either: the work is the context so
+    far, not the bucket's width."""
+    t = cells.shape[0]
+    groups, rank, nope = w_uk.shape
+    v_dim = w_uv.shape[-1]
+    block_kv = _fit_block(block_kv, t)
+    block_groups = _fit_block(block_groups, groups)
+    dt = w_uk.dtype
+    # A step's blocks, each in two buffers: the latents, the groups' two
+    # matrices, the two outputs.
+    moved = jnp.dtype(dt).itemsize * (
+        block_kv * rank + block_groups * (rank + block_kv) * (nope + v_dim)
+    )
+
+    def live(ki, start):
+        return jnp.minimum(ki, _last_key_block(start[0], n_queries, block_kv))
+
+    return pl.pallas_call(
+        functools.partial(
+            _expand_kernel, n_queries=n_queries, block_kv=block_kv
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(groups // block_groups, t // block_kv),
+            in_specs=[
+                pl.BlockSpec((block_kv, rank), lambda gi, ki, s: (live(ki, s), 0)),
+                pl.BlockSpec((block_groups, rank, nope), lambda gi, ki, s: (gi, 0, 0)),
+                pl.BlockSpec((block_groups, rank, v_dim), lambda gi, ki, s: (gi, 0, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec(
+                    (block_groups, block_kv, nope),
+                    lambda gi, ki, s: (gi, live(ki, s), 0),
+                ),
+                pl.BlockSpec(
+                    (block_groups, block_kv, v_dim),
+                    lambda gi, ki, s: (gi, live(ki, s), 0),
+                ),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((groups, t, nope), dt),
+            jax.ShapeDtypeStruct((groups, t, v_dim), dt),
+        ],
+        # An output block is revisited by the steps past the chunk's end:
+        # the key blocks in order.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=2 * moved + 8 * 1024 * 1024,
+        ),
+        interpret=interpret,
+    )(jnp.reshape(start, (1,)).astype(jnp.int32), cells.astype(dt), w_uk, w_uv)
+
+
 def _prefill_kernel(
     start_ref, qn_ref, qp_ref, kn_ref, kp_ref, v_ref, o_ref, m_ref, l_ref,
     acc_ref, *, block_q: int, block_kv: int, num_kv: int,
@@ -318,7 +462,7 @@ def latent_prefill_attention(
     # 20.5, 512 x 1,024 13.3, 1,024 x 1,024 11.8 (52% of the bf16 peak by
     # the operations the arithmetic needs); the XLA loop took 84.9.
     block_q: int = 1024,
-    block_kv: int = 1024,
+    block_kv: int = _PREFILL_BLOCK_KV,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Causal attention of C queries at ``start ..`` over the keys at
@@ -338,7 +482,9 @@ def latent_prefill_attention(
     def last_needed(qi, ki, start):
         # The last key block a query block reads: steps past it repeat
         # its index, and a block whose index repeats is not fetched.
-        return jnp.minimum(ki, (start[0] + (qi + 1) * block_q - 1) // block_kv)
+        return jnp.minimum(
+            ki, _last_key_block(start[0], (qi + 1) * block_q, block_kv)
+        )
 
     out = pl.pallas_call(
         functools.partial(

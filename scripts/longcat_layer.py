@@ -16,8 +16,25 @@ sublayer's cells against the reference's (sublayer 1's cells depend on
 sublayer 0's attention and the first dense FFN and NOT on the experts:
 the shortcut joins behind them), the share of tokens routed otherwise,
 and the program's time. Then the decode program the same way.
+
+    chiprun -- python scripts/longcat_layer.py expand [groups a step ...]
+
+The expansion alone (`ops/pallas/latent_attention.py latent_expand`, what
+a chunk program runs under `mla:expand`) beside the two einsums over the
+whole table that it replaced: 128 heads (openPangu), 64 (LongCat) and 16
+groups (Motif), tables of 4,096 to 65,536 cells, a 2,048-query chunk at
+the table's start, its middle and its end; a JSON line each, ms a call
+(calls sent back to back, a few in flight, and waited for together: a
+call under ~0.2 ms reads the host's dispatch), the einsums' ms, their
+ratio, what the live key blocks' share of the table would make it, and
+the live blocks' largest difference from the einsums'. (The einsums
+ALONE are no yardstick for the program: jitted by themselves they read
+0.41 us a cell at 128 heads, inside `pangu-longdoc-16`'s chunk programs
+0.175, where the kernel reads 0.183 with its dead steps: my chip runs,
+PR 66, `PERF.md` §6. The kernel's own column is the one to keep.)
 """
 
+import functools
 import json
 import sys
 import time
@@ -37,13 +54,79 @@ from ray_tpu.models import moe  # noqa: E402
 from ray_tpu.models.longcat_flash import init_params  # noqa: E402
 
 TOKENS, PAGE, DECODE = 2048, 64, 4
+EXPAND_GROUPS, EXPAND_TABLES = (128, 64, 16), (4096, 8192, 16384, 65536)
+
+
+def expand_alone(blocks_of_groups):
+    from ray_tpu.ops.pallas.latent_attention import keys_expanded, latent_expand
+
+    rank, width, wide = 512, 640, 128
+    key = jax.random.key(66)
+
+    def bf16(k, *shape):
+        return jax.random.normal(jax.random.fold_in(key, k), shape).astype(
+            jnp.bfloat16
+        )
+
+    @jax.jit
+    def einsums(cells, w_uk, w_uv):
+        return (jnp.einsum("tc,hcd->htd", cells[:, :rank], w_uk),
+                jnp.einsum("tc,hcd->htd", cells[:, :rank], w_uv))
+
+    @functools.partial(jax.jit, static_argnames="live")
+    def furthest(got, want, live):
+        f32 = jnp.float32
+        return jnp.abs(got.astype(f32) - want.astype(f32))[:, :live].max()
+
+    def timed(fn, out_bytes):
+        in_flight = int(max(1, min(8, 2**31 // out_bytes)))
+        jax.block_until_ready(fn())
+        began = time.perf_counter()
+        for _ in range(3):
+            out = None  # the last round's, freed before the next is sent
+            out = jax.block_until_ready([fn() for _ in range(in_flight)])
+        return 1e3 * (time.perf_counter() - began) / (3 * in_flight), out[-1]
+
+    for groups in EXPAND_GROUPS:
+        w_uk = bf16(1, groups, rank, wide) * rank**-0.5
+        w_uv = bf16(2, groups, rank, wide) * rank**-0.5
+        for table in EXPAND_TABLES:
+            cells = bf16(3, table, width)
+            out_bytes = 2 * groups * table * wide * 2
+            whole_ms, want = timed(lambda: einsums(cells, w_uk, w_uv), out_bytes)
+            for start in sorted({0, table // 2, table - TOKENS}):
+                live = keys_expanded(start, TOKENS, table)
+                at = jnp.int32(start)  # on the device before the clock runs
+                for block_groups in blocks_of_groups:
+                    ms, got = timed(
+                        lambda: latent_expand(
+                            cells, w_uk, w_uv, at, n_queries=TOKENS,
+                            block_groups=block_groups,
+                            interpret=chip.platform() != "tpu",
+                        ), out_bytes,
+                    )
+                    err = max(
+                        float(furthest(g, w, live)) for g, w in zip(got, want)
+                    )
+                    print(json.dumps({
+                        "groups": groups, "table": table, "start": start,
+                        "block_groups": block_groups, "ms": round(ms, 3),
+                        "einsums_ms": round(whole_ms, 3),
+                        "of_einsums": round(ms / whole_ms, 3),
+                        "live_share": round(live / table, 3),
+                        "max_abs_err": err,
+                    }), flush=True)
+                    del got
+            del want
 
 
 def main():
-    seed = int(sys.argv[1]) if len(sys.argv) > 1 else 61
     device = jax.devices()[0]
     print(json.dumps({"device": device.device_kind,
                       "platform": device.platform}), flush=True)
+    if sys.argv[1:2] == ["expand"]:
+        return expand_alone([int(a) for a in sys.argv[2:]] or [8])
+    seed = int(sys.argv[1]) if len(sys.argv) > 1 else 61
     path = (sys.argv[2] if len(sys.argv) > 2
             else "benchmarks/configs/longcat-flash-omni-serve1.json")
     with open(path) as f:

@@ -34,6 +34,7 @@ from ray_tpu.models.moe import MOE_PRESETS, init_moe_params, moe_ffn
 from ray_tpu.models.pangu_ultra_moe import PANGU_PRESETS
 from ray_tpu.models.pangu_ultra_moe import init_params as pangu_init_params
 from ray_tpu.ops.norms import rms_norm
+from ray_tpu.ops.pallas.latent_attention import keys_expanded
 
 TOL = 2e-4
 
@@ -429,12 +430,12 @@ def test_prefill_then_decode_equals_the_reference_pass(
     assert stats["pool_bytes"] == eng.cache["latent"].nbytes
     assert stats["state_bytes"] == 0
     assert stats["latent_bytes_per_token"] == 4 * 40 * 4
-    # Per attention sublayer, four of them: the kernel path expands the
-    # whole table in every program, the XLA path whole key blocks (16) up
-    # to the chunk's end.
+    # Per attention sublayer, four of them: whole key blocks up to each
+    # chunk's end, the kernel path's (1,024 keys, so the table of 64 is
+    # one) or the XLA path's (16).
     chunks = [(0, 64)] if chunk is None else [(0, 16), (16, 16), (32, 16)]
-    assert stats["latent_tokens_expanded"] == 4 * (
-        64 * len(chunks) if kernel == "1" else sum(s + c for s, c in chunks)
+    assert stats["latent_tokens_expanded"] == 4 * sum(
+        keys_expanded(s, c, 64) if kernel == "1" else s + c for s, c in chunks
     )
     assert stats["latent_prefill_programs"] == len(chunks)
     assert stats["latent_prefill_pairs"] == 4 * sum(

@@ -26,7 +26,11 @@ from ray_tpu.models import moe, motif
 from ray_tpu.models.moe import moe_ffn
 from ray_tpu.models.motif import MOTIF_PRESETS, MotifConfig, init_params
 from ray_tpu.ops.pallas import expert_rows, grouped_rows
-from ray_tpu.ops.pallas.latent_attention import latent_prefill_attention
+from ray_tpu.ops.pallas.latent_attention import (
+    keys_expanded,
+    latent_expand,
+    latent_prefill_attention,
+)
 from ray_tpu.ops.pallas.window_attention import (
     window_attention,
     window_attention_dense,
@@ -220,7 +224,13 @@ def test_prefill_then_decode_equals_the_reference_pass(params, chunk, calls):
     assert stats["prefill_window_pairs"] == 4 * (
         WINDOW * (WINDOW + 1) // 2 + (n - WINDOW) * WINDOW
     )
-    assert stats["latent_cells_expanded"] > 0
+    # Off the kernels the full layer's dense scores read the whole table
+    # in each of the programs (the bucket's 16 pages: 128 cells), and
+    # each of the four window layers its ring and the program's rows.
+    width = 128 if chunk is None else CHUNK
+    assert stats["latent_cells_expanded"] == calls * (
+        128 + 4 * (WINDOW + width)
+    )
 
 
 def test_kernel_programs_are_the_dense_programs(params):
@@ -253,6 +263,111 @@ def test_kernel_programs_are_the_dense_programs(params):
                             cache["win_cells"][:, 1])
     for dense, kernel in zip(outs[False], outs[True], strict=True):
         np.testing.assert_allclose(kernel, dense, atol=TOL, rtol=0)
+
+
+# ---------------------------------------- the full layer's expansion, bounded
+@pytest.mark.parametrize(
+    "start, table", [(0, 64), (16, 64), (48, 64), (0, 16)],
+    ids=["first_chunk", "middle", "last_chunk", "one_block"],
+)
+def test_bounded_expansion_is_a_groups_einsums_up_to_the_chunks_last_block(
+    params, start, table
+):
+    """`latent_expand` over Motif's two KV groups under ten heads (G <
+    H: a key and a value a GROUP, interpreted, key blocks of 16): the
+    blocks up to the one that holds the chunk's last position are
+    `_expand`'s, and no step writes a block past it (the interpreter
+    hands out NaN for what nothing wrote)."""
+    p = params["blocks"][4]  # the full layer
+    cells = jnp.asarray(
+        np.random.default_rng(start).normal(size=(table, CFG.cell_width)),
+        jnp.float32,
+    )
+    got = latent_expand(
+        cells, p["w_uk"], p["w_uv"], jnp.int32(start), n_queries=CHUNK,
+        block_kv=16, block_groups=1, interpret=True,
+    )
+    live = keys_expanded(start, CHUNK, table, 16)
+    assert live == start + CHUNK
+    for mine, whole in zip(got, motif._expand(cells, p, CFG), strict=True):
+        assert mine.shape == whole.shape and mine.shape[0] == 2
+        np.testing.assert_allclose(
+            mine[:, :live], whole[:, :live], atol=2e-5, rtol=0
+        )
+        assert np.isnan(np.asarray(mine[:, live:])).all()
+
+
+def _full_layer_over_a_long_table(params, start, dead_page=None):
+    """The full layer's mixer for a chunk of 1,024 rows at ``start`` over
+    a table of 3,072 cells (three key blocks of the kernels' 1,024;
+    pages of 8), by dense scores and by the two kernels; with
+    ``dead_page`` the kernels' table points at that page wherever a page
+    lies past the chunk's end. Returns (kernels' out, dense out, the two
+    pools' live cells' largest difference)."""
+    chunk, table = 1024, 3072
+    p = params["blocks"][4]
+    rng = np.random.default_rng(start)
+    n = table // PAGE
+    pages = np.asarray(2 + rng.permutation(n), np.int32)
+    pool = jnp.asarray(
+        rng.normal(size=(2 + n, PAGE, CFG.cell_width)), jnp.float32
+    ).at[..., CFG.latent_dim:].set(0.0).at[1].set(jnp.nan)
+    h = jnp.asarray(rng.normal(size=(chunk, CFG.d_model)), jnp.float32)
+    own = jnp.asarray(pages[start // PAGE: (start + chunk) // PAGE])
+    want, dense_pool = motif.gdla_prefill_full(
+        h, p, CFG, pool, 0, jnp.asarray(pages), own, jnp.int32(start), False
+    )
+    if dead_page is not None:
+        pages[(start + chunk) // PAGE:] = dead_page
+    got, kernel_pool = motif.gdla_prefill_full(
+        h, p, CFG, pool, 0, jnp.asarray(pages), own, jnp.int32(start), True
+    )
+    moved = float(np.abs(np.asarray(kernel_pool[2:] - dense_pool[2:])).max())
+    return np.asarray(got), np.asarray(want), moved
+
+
+@pytest.mark.parametrize("start", [0, 1024, 2048], ids=["first", "middle", "last"])
+def test_a_full_layers_chunk_through_the_bounded_expansion_is_the_dense_one(
+    params, start
+):
+    """`gdla_prefill_full` by `latent_expand` and the grouped prefill
+    kernel (interpreted, at their own blocks of 1,024 keys) against the
+    dense scores over the whole table's expansion: at `start` 0 two of
+    the three key blocks are never expanded, and never read."""
+    got, want, moved = _full_layer_over_a_long_table(params, start)
+    np.testing.assert_allclose(got, want, atol=TOL, rtol=0)
+    assert moved == 0.0
+
+
+@pytest.mark.parametrize("start", [0, 1024], ids=["first", "middle"])
+def test_a_full_layers_pages_of_nan_past_the_chunk_change_nothing(
+    params, start
+):
+    """The table's pages past the chunk's end pointed at a page of NaN:
+    neither kernel touches a key block past the chunk's last, so the
+    mixer's output is finite and is the one of the table as it was."""
+    clean, _, _ = _full_layer_over_a_long_table(params, start)
+    got, want, _ = _full_layer_over_a_long_table(params, start, dead_page=1)
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got, clean)
+    np.testing.assert_allclose(got, want, atol=TOL, rtol=0)
+
+
+def test_the_kernels_count_a_full_layers_cells_up_to_the_chunks_end():
+    """`HybridServing._count` by the kernels: chunks of 2,048 over a
+    table of 8,192 expand 2,048, 4,096 .. cells in the one full layer
+    (whole key blocks of 1,024 up to each chunk's end) where dense
+    scores read the table each time; the window layers' part is the
+    same by either."""
+    rings = 4 * (WINDOW + 2048)
+    for use_kernel, full in ((True, [2048, 4096, 6144, 8192]),
+                             (False, [8192] * 4)):
+        serving = CFG.serving()
+        for i, start in enumerate(range(0, 8192, 2048)):
+            serving._count(start, 2048, 8000, 8192, use_kernel)
+            assert serving.counters()["latent_cells_expanded"] == (
+                sum(full[: i + 1]) + (i + 1) * rings
+            )
 
 
 def test_the_reference_in_token_blocks_is_the_reference(params):

@@ -35,7 +35,11 @@ from ray_tpu.models.pangu_ultra_moe import (
     project_q,
 )
 from ray_tpu.ops.norms import rms_norm
-from ray_tpu.ops.pallas.latent_attention import latent_paged_attention
+from ray_tpu.ops.pallas.latent_attention import (
+    keys_expanded,
+    latent_expand,
+    latent_paged_attention,
+)
 from ray_tpu.ops.rope import rope_frequencies
 
 TOL = 2e-4
@@ -360,6 +364,119 @@ def test_prefill_kernel_equals_the_blockwise_loop(params, start, chunk, table):
     np.testing.assert_allclose(via, want, atol=2e-5, rtol=0)
 
 
+# ------------------------------------------------- the expansion's bound
+@pytest.mark.parametrize(
+    "start, chunk, table, want",
+    [(0, 2048, 16384, 2048), (6144, 2048, 16384, 8192),
+     (14336, 2048, 16384, 16384), (0, 2048, 2048, 2048), (0, 16, 64, 64),
+     (512, 512, 2048, 1024), (2048, 2048, 3072, 3072)],
+    ids=["first_chunk", "middle", "last_chunk", "whole_prompt", "one_block",
+         "half_a_block", "blocks_of_three_quarters"],
+)
+def test_keys_expanded_are_whole_key_blocks_up_to_the_chunks_end(
+    start, chunk, table, want
+):
+    """The counters' rule, on the host: the kernels' key block is 1,024
+    (the table where it holds fewer, the largest divisor under it where
+    1,024 does not divide it), and a chunk expands the blocks up to the
+    one that holds its last position."""
+    assert keys_expanded(start, chunk, table) == want
+
+
+def _expansion(p, start, chunk, table, seed=0):
+    """(keys, values) of `latent_expand` (interpreted, key blocks of 16,
+    two heads a step), the two einsums over the whole table, and the
+    keys the chunk at ``start`` needs."""
+    cells = jnp.asarray(
+        np.random.default_rng(seed).normal(size=(table, CFG.cell_width)),
+        jnp.float32,
+    )
+    got = latent_expand(
+        cells, p["w_uk"], p["w_uv"], jnp.int32(start), n_queries=chunk,
+        block_kv=16, block_groups=2, interpret=True,
+    )
+    rank = CFG.kv_lora_rank
+    want = (jnp.einsum("tc,hcd->htd", cells[:, :rank], p["w_uk"]),
+            jnp.einsum("tc,hcd->htd", cells[:, :rank], p["w_uv"]))
+    return got, want, keys_expanded(start, chunk, table, 16)
+
+
+@pytest.mark.parametrize(
+    "start, chunk, table",
+    [(0, 16, 64), (16, 16, 64), (24, 8, 64), (48, 16, 64), (0, 16, 16),
+     (0, 64, 64)],
+    ids=["first_chunk", "middle", "ends_in_a_block", "last_chunk",
+         "one_block", "whole_prompt"],
+)
+def test_bounded_expansion_is_the_einsums_up_to_the_chunks_last_block(
+    params, start, chunk, table
+):
+    """`latent_expand` (a key and a value a head: G == H) writes the key
+    blocks up to the one that holds the chunk's last position, and they
+    are the two einsums'; no step writes a block past it (the
+    interpreter hands out NaN for what nothing wrote; on a chip it is
+    whatever the buffer held)."""
+    got, want, live = _expansion(params["blocks"][1], start, chunk, table)
+    assert live == -(-(start + chunk) // 16) * 16
+    for mine, whole in zip(got, want, strict=True):
+        assert mine.shape == whole.shape == (4, table, 16)
+        np.testing.assert_allclose(
+            mine[:, :live], whole[:, :live], atol=2e-5, rtol=0
+        )
+        assert np.isnan(np.asarray(mine[:, live:])).all()
+
+
+def _chunk_over_a_long_table(p, start, dead_page=None):
+    """A chunk of 1,024 queries at ``start`` over a table of 3,072 cells
+    (three key blocks of the kernels' 1,024; pages of 64) by the XLA
+    loop and by the two kernels; with ``dead_page`` the kernels' table
+    points at that page wherever a page lies past the chunk's end."""
+    chunk, table, page = 1024, 3072, 64
+    rng = np.random.default_rng(start)
+    n = table // page
+    pages = np.asarray(2 + rng.permutation(n), np.int32)
+    pool = jnp.asarray(
+        rng.normal(size=(2 + n, page, CFG.cell_width)), jnp.float32
+    ).at[..., CFG.latent_dim:].set(0.0).at[1].set(jnp.nan)
+    q_nope = jnp.asarray(rng.normal(size=(chunk, 4, 16)), jnp.float32)
+    q_pe = jnp.asarray(rng.normal(size=(chunk, 4, 8)), jnp.float32)
+    want = latent_kv._attend_expanded(
+        q_nope, q_pe, pool, jnp.asarray(pages), jnp.int32(start), p, CFG
+    )
+    if dead_page is not None:
+        pages[(start + chunk) // page:] = dead_page
+    got = latent_kv._attend_expanded_kernel(
+        q_nope, q_pe, pool, jnp.asarray(pages), jnp.int32(start), p, CFG
+    )
+    return np.asarray(got), np.asarray(want)
+
+
+@pytest.mark.parametrize("start", [0, 1024, 2048], ids=["first", "middle", "last"])
+def test_a_chunk_through_the_bounded_expansion_equals_the_xla_path(
+    params, start
+):
+    """The engine's kernel path (`latent_expand`, then the prefill
+    kernel, both interpreted at their own blocks of 1,024 keys) against
+    the XLA loop over key blocks, which is bounded by the chunk's end
+    itself: at `start` 0 two of the table's three key blocks are never
+    expanded, and never read."""
+    got, want = _chunk_over_a_long_table(params["blocks"][1], start)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
+
+
+@pytest.mark.parametrize("start", [0, 1024], ids=["first", "middle"])
+def test_pages_of_nan_past_the_chunks_end_change_nothing(params, start):
+    """The table's pages past the chunk's end pointed at a page of NaN:
+    neither kernel touches a key block past the chunk's last, so the
+    output is finite and is the one of the table as it was."""
+    p = params["blocks"][1]
+    clean, _ = _chunk_over_a_long_table(p, start)
+    got, want = _chunk_over_a_long_table(p, start, dead_page=1)
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got, clean)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
+
+
 # ------------------------------------------------------------- the engine
 def _engine(params, **kw):
     kw = {"max_batch": 4, "max_seq": 192, "page_size": 8, **kw}
@@ -430,11 +547,12 @@ def test_prefill_then_decode_equals_the_reference_pass(
     assert stats["pool_bytes"] == eng.cache["latent"].nbytes
     assert stats["state_bytes"] == 0
     assert stats["latent_bytes_per_token"] == 4 * 40 * 4
-    # The kernel path expands the whole table in every program, the XLA
-    # path whole key blocks (16) up to the chunk's end; four layers.
+    # Whole key blocks up to each chunk's end, the kernel path's (1,024
+    # keys, so the table of 64 is one) or the XLA path's (16); four
+    # layers.
     chunks = [(0, 64)] if chunk is None else [(0, 16), (16, 16), (32, 16)]
-    assert stats["latent_tokens_expanded"] == 4 * (
-        64 * len(chunks) if kernel == "1" else sum(s + c for s, c in chunks)
+    assert stats["latent_tokens_expanded"] == 4 * sum(
+        keys_expanded(s, c, 64) if kernel == "1" else s + c for s, c in chunks
     )
     assert stats["latent_prefill_programs"] == len(chunks)
     assert stats["latent_prefill_pairs"] == 4 * sum(
@@ -515,6 +633,32 @@ def test_kernel_and_gather_paths_give_identical_greedy_streams(
     assert eng.generate(prompts, sampling) == want
     # A decode step ran under the host's work on the step before it.
     assert eng.stats()["decode_in_flight_pct"] > 0
+
+
+def test_a_long_prompt_expands_key_blocks_up_to_each_chunks_end(
+    params, monkeypatch
+):
+    """2,100 tokens in three chunks of 1,024 over a table of 4,096 cells
+    (the bucket's 64 pages of 64: four key blocks of the kernels'
+    1,024), through the engine's kernel path: the counter is the key
+    blocks up to each chunk's end, 1 + 2 + 3 of the 3 x 4 the whole
+    table would be, in each of the four layers, and the first token's
+    logits are the XLA path's."""
+    kw = {"max_seq": 4096, "page_size": 64, "num_pages": 80,
+          "prefill_chunk": 1024, "max_batch": 1}
+    last = {}
+    for kernel in ("0", "1"):
+        monkeypatch.setenv("RAY_TPU_PAGED_ATTN", kernel)
+        eng = _engine(params, **kw)
+        seen = _tapped(eng)
+        eng.generate([_prompt(9, 2100)], SamplingParams(max_tokens=1))
+        prefills = [s for s in seen if s[0].startswith("prefill")]
+        assert len(prefills) == 3
+        last[kernel] = prefills[-1][1][0, 0]
+    np.testing.assert_allclose(last["1"], last["0"], atol=TOL, rtol=0)
+    stats = eng.stats()
+    assert stats["latent_prefill_programs"] == 3
+    assert stats["latent_tokens_expanded"] == 4 * (1024 + 2048 + 3072)
 
 
 def test_a_preemption_by_recompute_changes_nothing(params):

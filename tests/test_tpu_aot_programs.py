@@ -1210,3 +1210,60 @@ def test_longcat_program_runs_both_sublayers_through_the_kernels_and_fits(
     assert abs(conf["fit"]["argument_bytes"] - counted) < 4e7
     assert counted > 12e9  # what the issue asks the fullest device to hold
     assert counted + memory.temp_size_in_bytes < 15.75 * 2**30
+
+
+# ------------------------------- the chunk programs' expansion, bounded
+# A chunk program's temporaries at the parent (PR 65's tree: the two
+# einsums over the whole table), compiled here for the same described
+# v5e at these fixtures' cuts; bytes. This tree's read 607,101,440 /
+# 1,011,811,328, 497,884,160 / 500,013,056 and 297,347,584 /
+# 827,197,952: the outputs are the einsums' arrays, and the compiler's
+# schedule moves a few hundred KB either way.
+_PARENT_TEMP = {
+    ("latent", "prefill_2048"): 607_585_280,
+    ("latent", "prefill_chunk_2048_of_8192"): 1_013_037_056,
+    ("longcat", "prefill_2048"): 497_368_064,
+    ("longcat", "prefill_chunk_2048_of_8192"): 500_238_848,
+    ("motif", "prefill_chunk_2048_of_8192"): 297_315_328,
+    ("motif", "prefill_chunk_2048_of_65536"): 828_712_448,
+}
+
+
+@pytest.mark.parametrize("family, program", list(_PARENT_TEMP))
+def test_chunk_program_expands_by_the_bounded_kernel_and_fills_nothing(
+    family, program, request
+):
+    """New cases of the three `..._and_fits` tests above, on the programs
+    their fixtures compiled: under `mla:expand` a chunk program holds
+    one call of `latent_expand` an attention sublayer over the table and
+    no product of XLA's (ops/pallas/latent_attention.py: the key blocks
+    past the chunk's end are never written), nothing broadcasts a
+    constant into an array of the expanded keys' shape `[G, T, 128]` (a
+    fill of the dead part would cost half of what the bound saves), and
+    the temporaries are the parent's to within a MiB."""
+    conf, programs = request.getfixturevalue(f"{family}_programs")
+    compiled = programs(program) if callable(programs) else programs[program]
+    text = compiled.as_text()
+    # (Motif: 16 KV groups under its 80 heads, and one full layer among
+    # the fixture's three; its window layers' expansion of ring + chunk,
+    # under `attn:window`, is XLA's as it was.)
+    groups, sublayers = (
+        (16, 1) if family == "motif" else (conf["num_attention_heads"], 2)
+    )
+    table = int(program.rsplit("_", 1)[1])
+    calls = _kernel_calls_under(text, "mla:expand")
+    assert len(calls) == sublayers
+    assert all("jit(latent_expand)" in line for line in calls)
+    assert all(f"bf16[{groups},{table},128]" in line for line in calls)
+    assert not [
+        line for line in text.splitlines()
+        if "mla:expand" in line and "attn:window" not in line
+        and ("convolution" in line or " dot(" in line)
+    ]
+    assert not [
+        line.strip()[:160] for line in text.splitlines()
+        if re.search(rf"= \w+\[{groups},{table},128\]\S* broadcast\(", line)
+    ]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= _PARENT_TEMP[family, program] + 2**20
+

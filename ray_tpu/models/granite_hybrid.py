@@ -7,7 +7,10 @@ Every layer is TWO sublayers, each behind its own RMSNorm:
 ``r = residual_multiplier``. The mixer is a Mamba-2 state-space mixer
 (``layer_types[l] == "mamba"``: ``models/nemotron_h.py``'s
 ``mamba_chunked`` and ``mamba_step``, here with one group shared by all
-heads) or grouped-query attention with no positional embedding and the
+heads; on a TPU a prefill chunk's scan is ``ops/pallas/ssd_chunk.py``'s
+one call a layer, 16 of the 128 heads a grid step, and a decode step's
+state update ``ops/pallas/state_step.py``'s; off it XLA's forms, which
+are the kernels' oracles: ``nemotron_h._dual_form``, ``mamba_step``) or grouped-query attention with no positional embedding and the
 score scale ``attention_multiplier`` (not ``head_dim**-0.5``). The FFN
 is ``models/moe.py``'s ``moe_ffn``: a softmax router over all experts
 whose chosen gates are renormalised (which is the softmax over the

@@ -12,7 +12,9 @@ through this file.
 
 Here: the configuration, an initialiser that makes the tree in the
 dtypes it is held in, and the Mamba-2 mixer in its two forms (many
-tokens, chunked; one token). The expert mixer is ``moe_ffn``; the
+tokens, chunked: the state-space dual form, `_dual_form` in XLA's
+operations and on a TPU ``ops/pallas/ssd_chunk.py``'s one call; one
+token). The expert mixer is ``moe_ffn``; the
 attention mixer is the serving programs' own (``llm/paged_kv.py``),
 which ``llm/hybrid_kv.py`` puts together with these over a cache of
 pages and per-slot state. Blocks are a tuple of per-block trees, run by
@@ -33,6 +35,8 @@ from typing import Any, ClassVar
 import jax
 import jax.numpy as jnp
 
+from ray_tpu._private import chip
+from ray_tpu.ops.pallas.ssd_chunk import ssd_chunk_rule
 from ray_tpu.ops.pallas.state_step import mamba_state_step
 
 Params = dict[str, Any]
@@ -345,6 +349,69 @@ def _project_out(y, z, p, cfg):
         return normed.astype(cfg.dtype) @ p["out_proj"]
 
 
+# `mamba_chunked` scans by `ops/pallas/ssd_chunk.py` from this many
+# tokens a program on, and by XLA's form under it. One mixer alone on a
+# v5e (scripts/ssd_chunk_layer.py, my chip runs, PR 67), ms XLA's form /
+# the call: at Granite's 2,048 tokens 4.76 / 3.10; at Nemotron-3-Nano's
+# widths and 512 tokens 0.367 / 0.380 (the rule alone 0.142 / 0.114, but
+# the convolution's result is then written whole for the call to read,
+# where XLA fuses it into the form's first passes), at 64 tokens 0.078 /
+# 0.076; and each shape of the call adds tracing and lowering to a
+# program's set-up (`trace_lower_s` 9.8 -> 13.5 s in `nemotron-reason-32`
+# with the call in its eight programs, of a `setup_s` of 57). A cell
+# stands on either side: `granite-longdoc-16` prefills 2,048 tokens a
+# program, `nemotron-reason-32` 64 to 512.
+_SCAN_KERNEL_TOKENS = 1024
+
+
+def _dual_form(x, b_in, c_in, dt, a, d, ssm0, size):
+    """The state-space dual form in XLA's own operations, over chunks of
+    ``size`` tokens: x [T, H, P], b_in and c_in [T, G, N], dt [T, H] (0
+    where a token takes no step), a and d [H], ssm0 [H, P, N]. Returns (y
+    [T, H x P] with the skip ``D x`` in it, the state after the last
+    token that takes a step)."""
+    t, h = dt.shape
+    g = b_in.shape[1]
+    r = h // g  # heads of a group share its B and C
+    c = t // size
+    # Chunked, head-major views, heads as (group g, head of group
+    # r): time and the head's own dims are the minor ones.
+    xdt = (x * dt[..., None]).reshape(c, size, g, r, -1)
+    xdt = xdt.transpose(0, 2, 3, 1, 4)  # [c, g, r, l, P]
+    b_c = b_in.reshape(c, size, g, -1).transpose(0, 2, 1, 3)  # [c, g, l, N]
+    c_c = c_in.reshape(c, size, g, -1).transpose(0, 2, 1, 3)
+    cum = jnp.cumsum(
+        (dt * a).reshape(c, size, g, r).transpose(0, 2, 3, 1), axis=-1
+    )  # [c, g, r, l]
+    # Within a chunk: y_l += sum_{s <= l} (C_l . B_s) decay(s -> l)
+    # dt_s x_s.
+    diff = cum[..., :, None] - cum[..., None, :]  # [c, g, r, l, s]
+    causal = jnp.tril(jnp.ones((size, size), bool))
+    decay = jnp.exp(jnp.where(causal, diff, -jnp.inf))
+    cb = jnp.einsum("cgln,cgsn->cgls", c_c, b_c)
+    y = jnp.einsum("cgrls,cgrsp->cgrlp", cb[:, :, None] * decay, xdt)
+    # What each chunk adds to the state by its end.
+    to_end = jnp.exp(cum[..., -1:] - cum)  # [c, g, r, l]
+    added = jnp.einsum(
+        "cgrlp,cgln->cgrpn", xdt * to_end[..., None], b_c
+    )
+    # Between chunks: the state each chunk starts from.
+    whole = jnp.exp(cum[..., -1])  # [c, g, r]
+
+    def carry(state, chunk):
+        decay_c, added_c = chunk
+        return state * decay_c[..., None, None] + added_c, state
+
+    start = ssm0.reshape(g, r, *ssm0.shape[1:])
+    end, starts = jax.lax.scan(carry, start, (whole, added))
+    y = y + jnp.einsum("cgln,cgrpn->cgrlp", c_c, starts) * jnp.exp(
+        cum
+    )[..., None]
+    y = y.transpose(0, 3, 1, 2, 4)  # [c, l, g, r, P]
+    y = y.reshape(t, h, -1) + d[:, None] * x
+    return y.reshape(t, -1), end.reshape(ssm0.shape)
+
+
 def mamba_chunked(u, p, cfg: NemotronHConfig, ssm0, conv0, length):
     """The Mamba-2 mixer over many tokens of one sequence (``cfg``: this
     family's config or one with its Mamba fields, as
@@ -362,14 +429,22 @@ def mamba_chunked(u, p, cfg: NemotronHConfig, ssm0, conv0, length):
     ``ssd_minimal``); between chunks it is the state. Decays, cumulative
     sums and the state are float32; the products take their operands as
     the matmul unit does (bf16 on a TPU), as the published kernels do.
+
+    On a TPU the dual form, between the convolution's output and
+    ``_project_out``, is one call of ``ops/pallas/ssd_chunk.py`` (the
+    same arithmetic with nothing of shape ``[.., Q, Q]`` in HBM and no
+    head-major copy of ``x dt`` or ``y``; PR 67; its docstring has the
+    layer-alone table); elsewhere `_dual_form`, XLA's form, which is
+    tier 1's path and the kernel's oracle
+    (tests/test_ssd_chunk_kernel.py), and a TPU's too for programs
+    shorter than `_SCAN_KERNEL_TOKENS`. The platform and the program's
+    length decide: no flag, as for ``moe_ffn``'s kernels and the two
+    delta rules.
     """
     t = u.shape[0]
-    h, g = cfg.mamba_heads, cfg.ssm_groups
-    r = h // g  # heads of a group share its B and C
     size = min(cfg.chunk_size, t)
     if t % size:
         raise ValueError(f"{t} tokens do not divide into chunks of {size}")
-    c = t // size
     z, xbc, dt_raw = _project_in(u, p, cfg)
 
     with jax.named_scope("ssm:conv"):
@@ -381,48 +456,23 @@ def mamba_chunked(u, p, cfg: NemotronHConfig, ssm0, conv0, length):
         )
         # Row i of `seq` is the input at position i - (K - 1).
         conv_end = jax.lax.dynamic_slice_in_dim(seq, length, k - 1, axis=0)
-        x, b_in, c_in = _split_xbc(jax.nn.silu(conv), cfg)
+        xbc = jax.nn.silu(conv)
+        x, b_in, c_in = _split_xbc(xbc, cfg)
 
     with jax.named_scope("ssm:scan"):
         dt, a = _steps(dt_raw, p)
         dt = jnp.where(jnp.arange(t)[:, None] < length, dt, 0.0)  # [T, H]
-        # Chunked, head-major views, heads as (group g, head of group
-        # r): time and the head's own dims are the minor ones.
-        xdt = (x * dt[..., None]).reshape(c, size, g, r, -1)
-        xdt = xdt.transpose(0, 2, 3, 1, 4)  # [c, g, r, l, P]
-        b_c = b_in.reshape(c, size, g, -1).transpose(0, 2, 1, 3)  # [c, g, l, N]
-        c_c = c_in.reshape(c, size, g, -1).transpose(0, 2, 1, 3)
-        cum = jnp.cumsum(
-            (dt * a).reshape(c, size, g, r).transpose(0, 2, 3, 1), axis=-1
-        )  # [c, g, r, l]
-        # Within a chunk: y_l += sum_{s <= l} (C_l . B_s) decay(s -> l)
-        # dt_s x_s.
-        diff = cum[..., :, None] - cum[..., None, :]  # [c, g, r, l, s]
-        causal = jnp.tril(jnp.ones((size, size), bool))
-        decay = jnp.exp(jnp.where(causal, diff, -jnp.inf))
-        cb = jnp.einsum("cgln,cgsn->cgls", c_c, b_c)
-        y = jnp.einsum("cgrls,cgrsp->cgrlp", cb[:, :, None] * decay, xdt)
-        # What each chunk adds to the state by its end.
-        to_end = jnp.exp(cum[..., -1:] - cum)  # [c, g, r, l]
-        added = jnp.einsum(
-            "cgrlp,cgln->cgrpn", xdt * to_end[..., None], b_c
-        )
-        # Between chunks: the state each chunk starts from.
-        whole = jnp.exp(cum[..., -1])  # [c, g, r]
-
-        def carry(state, chunk):
-            decay_c, added_c = chunk
-            return state * decay_c[..., None, None] + added_c, state
-
-        start = ssm0.reshape(g, r, *ssm0.shape[1:])
-        end, starts = jax.lax.scan(carry, start, (whole, added))
-        y = y + jnp.einsum("cgln,cgrpn->cgrlp", c_c, starts) * jnp.exp(
-            cum
-        )[..., None]
-        y = y.transpose(0, 3, 1, 2, 4)  # [c, l, g, r, P]
-        y = y.reshape(t, h, -1) + p["D"][:, None] * x
-    out = _project_out(y.reshape(t, -1), z, p, cfg)
-    return out, end.reshape(ssm0.shape), conv_end.astype(conv0.dtype)
+        # Chosen by the platform, as `moe_ffn` chooses its kernels, and
+        # by the program's length.
+        if chip.platform() == "tpu" and t >= _SCAN_KERNEL_TOKENS:
+            y, end = ssd_chunk_rule(
+                xbc, dt, a, p["D"], ssm0, length, groups=cfg.ssm_groups,
+                chunk=size,
+            )
+        else:
+            y, end = _dual_form(x, b_in, c_in, dt, a, p["D"], ssm0, size)
+    out = _project_out(y, z, p, cfg)
+    return out, end, conv_end.astype(conv0.dtype)
 
 
 def _step_operands(u, p, cfg, conv):

@@ -12,7 +12,8 @@ query and 2 KV heads (Qwen3-Next's). The kernel that reads the touched
 experts (ops/pallas/expert_rows.py), the sorted form's combine and its
 grouped matmuls are compiled at the served widths, the decode step's
 state kernel (ops/pallas/state_step.py) at the three served stacks, the
-gated delta rule's chunk kernel at its served shape. The band kernel
+gated delta rule's chunk kernel at its served shape, Mamba-2's chunked
+scan (ops/pallas/ssd_chunk.py) at Granite's and Nemotron's. The band kernel
 (ops/pallas/window_attention.py) is compiled at 72 query heads over 8 KV
 heads and the prefill and paged kernels at 48 over 8 (groups of 9 and
 6). For GLM-5.3-Flash (models/glm5_next.py): the state kernel's third
@@ -346,6 +347,43 @@ def test_gdn_chunk_kernel_compiles_for_v5e_at_the_served_shape(v5e):
     text = compiled.as_text()
     assert len(_kernel_calls_under(text, "")) == 1
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**20
+
+
+# name: (tokens, heads, head dim, groups, state, chunk)
+SSD_CASES = {
+    "granite4hsmall_2048": (2048, 128, 64, 1, 128, 256),
+    "nemotron3nano_512": (512, 64, 64, 8, 128, 128),
+    "nemotron3nano_64": (64, 64, 64, 8, 128, 64),
+}
+
+
+@pytest.mark.parametrize("case", list(SSD_CASES))
+def test_ssd_chunk_kernel_compiles_for_v5e_at_the_served_shapes(v5e, case):
+    """Mamba-2's chunked scan (ops/pallas/ssd_chunk.py) at
+    granite4hsmall-serve1's prefill chunk (128 heads of 64 in one group,
+    chunks of 256) and at nemotron3nano-serve1's longest and shortest
+    programs (64 heads in 8 groups, chunks of 128; 64 tokens are one
+    chunk of 64): the running sum by a product with ones, the transpose
+    of a grid step's per-token numbers, the gather along lanes that
+    spreads two heads' columns over a tile and the product that
+    contracts a chunk's tokens lower for the chip, inside the VMEM the
+    call asks for; beside the arguments only `dt` head-major is made."""
+    from ray_tpu.ops.pallas import ssd_chunk
+
+    t, h, p, g, n, chunk = SSD_CASES[case]
+
+    def on_chip(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    compiled = jax.jit(
+        partial(ssd_chunk.ssd_chunk_rule, groups=g, chunk=chunk)
+    ).lower(
+        on_chip(t, h * p + 2 * g * n), on_chip(t, h), on_chip(h), on_chip(h),
+        on_chip(h, p, n), on_chip(dtype=jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert len(_kernel_calls_under(text, "")) == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**20
 
 
 def _copies_of(text: str, shape: tuple) -> list[str]:

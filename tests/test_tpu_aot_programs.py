@@ -401,6 +401,13 @@ def test_hybrid_program_moves_no_pages_state_or_expert_stack(
     # (the decode program's temporaries are a few MB).
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 64 * d * f * 2
+    # The chunked scan is `ops/pallas/ssd_chunk.py`'s one call a Mamba
+    # block from 1,024 tokens a program on (PR 67;
+    # `nemotron_h._SCAN_KERNEL_TOKENS`): the cell's own programs, of 64
+    # to 512 tokens, keep XLA's form.
+    assert len(_kernel_calls_under(text, "ssm:scan")) == (
+        2 if sorted_form else 0
+    )
     if program == "decode":
         assert temp < state * 4
         # The state update is the kernel's one pass over the decoding
@@ -572,6 +579,15 @@ def test_granite_program_moves_no_pages_state_or_stack_and_fits(
         assert "prefill_attention" in text and "ragged-dot" not in text
         assert _expert_kernel_calls(text) == []
         assert len(_grouped_kernel_calls(text)) == 4  # two a layer's FFN
+        # The chunked scan is `ops/pallas/ssd_chunk.py`'s one call the
+        # Mamba layer (PR 67): XLA's dual form wrote the decays and the
+        # decayed scores as float32 [8, 1, 128, 256, 256] (268 MB each)
+        # and y through a copy out of the head-major layout; x, B and C
+        # are views of the convolution's one result, not slices of it.
+        assert len(_kernel_calls_under(text, "ssm:scan")) == 1
+        assert not re.search(r"f32\[8,1,128,256,256\]", text)
+        assert _copies_of(text, (8, 256, 1, 128, 64)) == []
+        assert _entry_results(text, r"f32\[2048,8192\]\S* slice") == []
         # No array with the chunk's queries against the table's keys,
         # whatever the leading dimensions and the dtype.
         assert not re.search(rf"\[[\d,]*{chunk},{table}\]", text)

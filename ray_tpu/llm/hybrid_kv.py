@@ -51,7 +51,19 @@ The cache is ONE donated tree with two kinds of per-sequence state:
   layer, the key of position ``t`` at ring index ``t % W`` (positions
   major over heads: a decode step writes one ``[Hkv, Dh]`` row a slot,
   and it is the layout XLA gives the leaf in both programs when asked
-  for the other). They do not grow with the context either, and a
+  for the other). Where ``cfg.ring_pages`` the two are ``[L_window,
+  max_batch * W / P + 1, Hkv, P, Dh]``: a slot's ring is ``W / P`` PAGES
+  of the pool's page size in the pool's cell layout (ring index ``r`` at
+  cell ``r % P`` of the slot's page ``r // P``), and a layer's last page
+  is its dump page. A decode step then writes and attends a ring with
+  the pool's own two kernels (`_decode_attention` over this second,
+  small pool: a window is the paged kernel's causal mask at position
+  ``min(t, W - 1)``), which fix its layout. (Why: a model whose ``Hkv``
+  does not fill a tile's rows, 10 pairs for 16, would hold ``[.., W, Hkv,
+  Dh]`` 1.6 times its size; XLA then picks ``[.., Hkv, W, Dh]`` for the
+  einsums and ``[.., W, Hkv, Dh]`` for the scatter whichever the leaf is
+  given as, and the decode program copied both leaves in and out, 1.3 GB
+  a step at 8 layers x 32 slots: the compiled text, PR 68.) They do not grow with the context either, and a
   request's pages are the ``*`` layers' alone. (The other design, one pool and pages released behind
   the window, was not taken: a slot's pages would differ by layer kind,
   so one block table a request could not serve both, and the kernels
@@ -152,8 +164,16 @@ from ray_tpu.models.nemotron_h import (
     mamba_step,
     mamba_step_live,
 )
+from ray_tpu.models.phi4_flash import (
+    diff_inputs,
+    diff_output,
+    gmu,
+    mamba1_chunked,
+    mamba1_step,
+    mamba1_step_live,
+)
 from ray_tpu.models.qwen3_next import gdn_chunked, gdn_step, gdn_step_live
-from ray_tpu.ops.norms import rms_norm
+from ray_tpu.ops.norms import layer_norm, rms_norm
 from ray_tpu.ops.pallas.latent_attention import keys_expanded
 from ray_tpu.ops.pallas.state_step import live_order
 from ray_tpu.ops.pallas.window_attention import (
@@ -187,7 +207,10 @@ class _Recurrent(NamedTuple):
     by ``ops/pallas/state_step.py``, which owns that mask: a slot that
     does not decode is not read), the cache's leaves for its state and
     its convolution tail, the prefix of its named scopes, and a slot's
-    (state shape, convolution channels) from a config."""
+    (state shape, convolution channels) from a config. ``memory``: the
+    three return ``(out, y)`` for ``out``, ``y`` what the mixer computed
+    before its gate, which the programs carry on to the blocks that read
+    it (`gmu`)."""
 
     chunked: Callable
     step: Callable
@@ -196,6 +219,7 @@ class _Recurrent(NamedTuple):
     conv: str
     scope: str
     shapes: Callable
+    memory: bool = False
 
 
 _RECURRENT = {
@@ -215,6 +239,13 @@ _RECURRENT = {
         lambda c: (
             (c.kda_heads, c.kda_head_dim, c.kda_head_dim), c.kda_conv_dim
         ),
+    ),
+    # Mamba-1: a decay a channel and state index, the state in
+    # `ops/pallas/selective_scan.py`'s layout.
+    "S": _Recurrent(
+        mamba1_chunked, mamba1_step, mamba1_step_live, "ssm1", "ssm1_conv",
+        "ssm", lambda c: ((c.ssm_state, c.ssm_rows, 128), c.d_inner),
+        memory=True,
     ),
 }
 
@@ -240,6 +271,13 @@ def init_hybrid_cache(
             )
     if n := cfg.count("W"):
         ring = (n, max_batch, cfg.sliding_window, cfg.n_kv_heads, cfg.head_dim)
+        if cfg.ring_pages:
+            # A slot's ring as W / P pages of the pool's page size, in
+            # the pool's cell layout; one dump page a layer behind them.
+            if cfg.sliding_window % page_size:
+                raise ValueError("the page size does not divide the window")
+            ring = (n, max_batch * (cfg.sliding_window // page_size) + 1,
+                    cfg.n_kv_heads, page_size, cfg.head_dim)
         cache["win_k"] = jnp.zeros(ring, cfg.dtype)
         cache["win_v"] = jnp.zeros(ring, cfg.dtype)
     if n := cfg.count("L"):
@@ -276,6 +314,14 @@ def _embed(params, tokens, cfg):
             x[..., None, :], (*x.shape[:-1], cfg.hc_mult, x.shape[-1])
         )
     return x
+
+
+def _norm(x, p, cfg, name: str = "norm"):
+    """A sublayer's norm as the config has it: RMSNorm, or LayerNorm
+    with a bias where ``cfg.layer_norm``."""
+    if cfg.layer_norm:
+        return layer_norm(x, p[name], p[f"{name}_bias"], cfg.norm_eps)
+    return rms_norm(x, p[name], cfg.norm_eps)
 
 
 def _read(x, p, cfg):
@@ -376,6 +422,10 @@ def _attention_inputs(x, p, cfg, positions, kind: str = "*"):
     a family adds is skipped at its config's off value, so that the
     others' programs are `paged_kv._project_qkv`'s three products and
     nothing else."""
+    if cfg.differential:
+        # Paired heads (`models/phi4_flash.py`): queries held a pair
+        # wide, keys and values as pairs; no rotation, no gate.
+        return (*diff_inputs(x, p, cfg, cross=kind == "C"), None)
     b, s, _ = x.shape
     dh = cfg.head_dim
     n_heads, rotary_dim, theta, yarn = _attention_kind(cfg, kind)
@@ -402,6 +452,8 @@ def _attention_inputs(x, p, cfg, positions, kind: str = "*"):
 def _attention_output(attn, gate, p, cfg):
     """attn [B, S, H, Dh] (times ``sigmoid(gate)`` where the model gates
     its heads) through ``W_o``: [B, S, d]."""
+    if cfg.differential:
+        return diff_output(attn, p, cfg)
     if gate is not None:
         with jax.named_scope("attn:gate"):
             attn = (
@@ -410,14 +462,18 @@ def _attention_output(attn, gate, p, cfg):
     return attn.reshape(*attn.shape[:2], -1) @ p["wo"]
 
 
-def _full_scope(cfg):
+def _full_scope(cfg, reader: str | None = None):
     """The scope a ``*`` block's page write and attention run under where
     the model has window layers beside it, so that a trace tells the two
     kinds apart; none for a model with one kind, whose programs stay as
-    they were."""
-    if cfg.count("W"):
+    they were. ``reader``: a child scope that says which block attends
+    the pool layer (``self``: the one that owns and writes it; ``cross``:
+    one that reads it with its own queries), where the model has both."""
+    if not cfg.count("W"):
+        return contextlib.nullcontext()
+    if reader is None or not cfg.count("C"):
         return jax.named_scope("attn:full")
-    return contextlib.nullcontext()
+    return jax.named_scope(f"attn:full/{reader}")
 
 
 def _window_prefill(q, k, v, win_k, win_v, at, start, n_live, cfg,
@@ -433,16 +489,27 @@ def _window_prefill(q, k, v, win_k, win_v, at, start, n_live, cfg,
     same mask. Then the last W REAL positions go back to the ring, each
     to its own index. Returns (attn [C, H, Dh], win_k, win_v)."""
     c, w = q.shape[0], cfg.sliding_window
+    # The axis of a slot's ring (and of the band) that positions lie
+    # along: [W, Hkv, Dh], or [Hkv, W, Dh] where the rings are pages
+    # (`_slot_ring`).
+    along = 1 if cfg.ring_pages else 0
     with jax.named_scope("attn:window"):
         # Ring index of position start - W + j.
         order = (start + jnp.arange(w, dtype=jnp.int32)) % w
 
         def band(ring, new):
-            carried = jnp.take(ring[at], order, axis=0)  # [W, Hkv, Dh]
-            return jnp.concatenate([carried, new.astype(ring.dtype)], axis=0)
+            carried = jnp.take(_slot_ring(ring, at, cfg), order, axis=along)
+            if along:
+                new = new.transpose(1, 0, 2)
+            return jnp.concatenate(
+                [carried, new.astype(ring.dtype)], axis=along
+            )
 
         keys, values = band(win_k, k), band(win_v, v)  # [W + C, Hkv, Dh]
-        head_major = (q, keys.transpose(1, 0, 2), values.transpose(1, 0, 2))
+        if along:
+            head_major = (q, keys, values)
+        else:
+            head_major = (q, keys.transpose(1, 0, 2), values.transpose(1, 0, 2))
         if use_kernel and band_blocks(c, w) is not None:
             attn = window_attention(
                 *head_major, start, window=w, scale=cfg.attention_scale,
@@ -458,10 +525,66 @@ def _window_prefill(q, k, v, win_k, win_v, at, start, n_live, cfg,
         back = (jnp.arange(w, dtype=jnp.int32) - (start + n_live)) % w
 
         def left(ring, band_):
-            last = jax.lax.dynamic_slice_in_dim(band_, n_live, w, axis=0)
-            return ring.at[at].set(jnp.take(last, back, axis=0))
+            last = jax.lax.dynamic_slice_in_dim(band_, n_live, w, axis=along)
+            last = jnp.take(last, back, axis=along)
+            if not cfg.ring_pages:
+                return ring.at[at].set(last)
+            hkv, _, dh = last.shape
+            pages = last.reshape(hkv, -1, ring.shape[3], dh).transpose(1, 0, 2, 3)
+            layer, slot = at
+            return jax.lax.dynamic_update_slice(
+                ring, pages[None], (layer, slot * pages.shape[0], 0, 0, 0)
+            )
 
         return attn.astype(q.dtype), left(win_k, keys), left(win_v, values)
+
+
+def _slot_ring(ring, at, cfg):
+    """A slot's ring in one layer, by ring index: ``ring[at]`` [W, Hkv,
+    Dh], or, where the rings are pages, the slot's W / P pages put
+    together, [Hkv, W, Dh]."""
+    if not cfg.ring_pages:
+        return ring[at]
+    layer, slot = at
+    per = cfg.sliding_window // ring.shape[3]
+    pages = jax.lax.dynamic_slice_in_dim(ring[layer], slot * per, per, axis=0)
+    return pages.transpose(1, 0, 2, 3).reshape(
+        ring.shape[2], cfg.sliding_window, ring.shape[4]
+    )
+
+
+def _window_decode_pages(q, k, v, win_k, win_v, layer: int, positions, active,
+                         cfg, use_kernel: bool):
+    """`_window_decode` where the rings are pages: `_decode_attention`
+    over the rings as a second pool. A slot's table is its W / P pages of
+    the layer; its cell goes to ring index ``position % W`` (a slot that
+    is not decoding: to the layer's dump page); it attends cells ``<=
+    min(position, W - 1)``, which are the positions ``> position - W``
+    the request has written (a softmax does not ask their order)."""
+    b = q.shape[0]
+    w, page = cfg.sliding_window, win_k.shape[3]
+    per, held = w // page, win_k.shape[1]
+    slots = jnp.arange(b, dtype=jnp.int32)
+    cell = positions % w
+    seen = jnp.minimum(positions, w - 1)
+    geometry = (
+        None,
+        jnp.arange(w)[None, None, :] > seen[:, None, None],  # hidden
+        jnp.where(active, slots * per + cell // page, held - 1)[:, None],
+        (cell % page)[:, None],
+        slots[:, None] * per + jnp.arange(per, dtype=jnp.int32)[None, :],
+    )
+
+    def flat(ring):
+        return ring.reshape(-1, *ring.shape[2:])
+
+    with jax.named_scope("attn:window"):
+        attn, flat_k, flat_v = _decode_attention(
+            q, k.astype(cfg.dtype), v.astype(cfg.dtype), flat(win_k),
+            flat(win_v), layer * held, geometry, seen, cfg, use_kernel,
+            cfg.attention_scale,
+        )
+    return attn, flat_k.reshape(win_k.shape), flat_v.reshape(win_v.shape)
 
 
 def _window_decode(q, k, v, win_k, win_v, layer: int, positions, active, cfg):
@@ -503,7 +626,7 @@ def _dense_ffn(x, p, cfg):
     PolyNorm where that is the model's ``expert_kind``."""
     h, mix = _read(x, p, cfg)
     with jax.named_scope("ffn:dense"):
-        h = rms_norm(h, p["norm"], cfg.norm_eps)
+        h = _norm(h, p, cfg)
         if cfg.expert_kind == "polynorm":
             act = poly_glu(
                 h, p["w_gate"], p["w_up"],
@@ -520,6 +643,11 @@ def _logits(x, params, cfg):
     the residual streams where the programs carry more than one."""
     if cfg.hc_mult:
         x = x.astype(jnp.float32).sum(-2).astype(x.dtype)
+    if cfg.layer_norm:
+        x = _norm(x, params, cfg, "final_norm")
+        return jnp.einsum(
+            "...d,vd->...v", x, params["tok_emb"]
+        ).astype(jnp.float32)
     return _head(
         x, params, cfg.norm_eps, cfg.tie_word_embeddings, cfg.logits_scaling
     )
@@ -537,15 +665,21 @@ def _hybrid_prefill(
     n_write_pages: int,
     chunk_pages: int,
     use_kernel: bool,
+    self_only: bool = False,
 ):
     """A prompt (``start`` 0, ``chunk_pages == n_write_pages``) or one
-    chunk of it. ``use_kernel``: where the table holds more than
+    chunk of it. ``self_only`` (a model with a cross-decoder,
+    ``cfg.cross_from``, whose blocks write nothing to the cache): the
+    chunk does not hold position ``length - 1``, so the program stops
+    where the cross-decoder begins and returns no logits; without it the
+    cross-decoder and the head run on that ONE row (`_last_row`). ``use_kernel``: where the table holds more than
     ``_DENSE_ATTENTION_KEYS`` keys the chunk's queries attend the
     context's pages, gathered as they lie, by the prefill kernel;
     otherwise dense float32 scores over the whole table under a mask
     (at a 256-page table and a 2,048-token chunk 4.3 GB of them).
     Returns (logits [1, 1, V] float32 of position ``length - 1`` —
-    meaningful in the chunk that holds it —, cache, record)."""
+    meaningful in the chunk that holds it —, cache, record; the record
+    None where no block leaves one)."""
     c = tokens.shape[1]
     page_size = cache["k"].shape[3]
     num_pages = cache["k"].shape[1]
@@ -563,22 +697,49 @@ def _hybrid_prefill(
     x = _embed(params, tokens, cfg)
     record = _new_record()
     seen = dict.fromkeys(cfg.block_kinds, 0)  # blocks of each kind so far
-    for kind, p in zip(cfg.pattern, params["blocks"], strict=True):
+    memory = None  # the last `memory` block's y, [C, d_inner]
+    cross_from = cfg.cross_from
+    for i, (kind, p) in enumerate(
+        zip(cfg.pattern, params["blocks"], strict=True)
+    ):
+        if i == cross_from:
+            if self_only:
+                break
+            # Nothing from here on writes the cache, and one row's
+            # logits are returned: that row alone goes on.
+            x = _last_row(x, start, length)
+            memory = _last_row(memory[None], start, length)
         if kind in _RECURRENT:
             block = _RECURRENT[kind]
             fresh = start == 0
             at = (seen[kind], slot)
             h, mix = _read(x, p, cfg)
             out, s_end, c_end = block.chunked(
-                rms_norm(h, p["norm"], cfg.norm_eps)[0], p, cfg,
+                _norm(h, p, cfg)[0], p, cfg,
                 jnp.where(fresh, 0.0, state[block.state][at]),
                 jnp.where(fresh, 0, state[block.conv][at]),
                 jnp.clip(length - start, 0, c),
             )
+            if block.memory:
+                out, memory = out
             with jax.named_scope(f"{block.scope}:scan"):
                 state[block.state] = state[block.state].at[at].set(s_end)
                 state[block.conv] = state[block.conv].at[at].set(c_end)
             x = _residual(x, out[None], cfg, mix)
+        elif kind == "U":
+            x = _residual(x, gmu(x, memory, p, cfg), cfg)
+        elif kind == "C":
+            # The one row's queries over the request's pages of the `*`
+            # block's pool layer (the only one: base 0), up to its own
+            # position. Nothing is written.
+            q, _, _, _ = _attention_inputs(x, p, cfg, None, kind)
+            with _full_scope(cfg, "cross"):
+                attn = _attend_pages(
+                    q, k_pages, v_pages, pages[None, :],
+                    (jnp.arange(window) >= length)[None, None, :],
+                    (length - 1)[None], cfg, use_kernel,
+                )
+            x = _residual(x, _attention_output(attn, None, p, cfg), cfg)
         elif kind == "E":
             x = _experts(x, p, cfg, live, record)
         elif kind == "D":
@@ -618,7 +779,7 @@ def _hybrid_prefill(
         else:
             base = seen[kind] * num_pages
             q, k, v, gate = _attention_inputs(x, p, cfg, pos)  # [1, C, H, Dh]
-            with _full_scope(cfg):
+            with _full_scope(cfg, "self"):
                 k_pages, v_pages = _write_pages(
                     k_pages, v_pages, k, v, base + chunk_slice, cfg
                 )
@@ -636,35 +797,84 @@ def _hybrid_prefill(
                     )
             x = _residual(x, _attention_output(attn, gate, p, cfg), cfg)
         seen[kind] += 1
-    last = jax.lax.dynamic_slice_in_dim(x, length - 1 - start, 1, axis=1)
+    if self_only:
+        carried = _carried(cache, k_pages, v_pages, state)
+        return None, carried, _record_or_none(record)
+    last = x if cross_from is not None else _last_row(x, start, length)
     carried = _carried(cache, k_pages, v_pages, state)
-    return _logits(last, params, cfg), carried, _record(record)
+    return _logits(last, params, cfg), carried, _record_or_none(record)
+
+
+def _last_row(x, start, length):
+    """Row ``length - 1 - start`` of x [1, C, ..]: the last real token's,
+    in the chunk that holds it."""
+    return jax.lax.dynamic_slice_in_dim(x, length - 1 - start, 1, axis=1)
+
+
+def _record_or_none(record):
+    """`_record`, or None for a program without expert blocks."""
+    return _record(record) if record["routes"] else None
+
+
+def _attend_pages(q, k_pages, v_pages, tables, mask, positions, cfg,
+                  use_kernel: bool):
+    """Attention of q [B, 1, H, Dh] over pool pages it does NOT write: a
+    block that reads another block's keys and values with its own
+    queries. ``tables`` [B, n] the pages in the flat pool (>= 0),
+    ``positions`` [B] the last position each row may see, ``mask`` [B,
+    1, n x P] the gather path's (True = hidden). By the decode kernel
+    where ``use_kernel`` (each slot's live pages and no more), else the
+    gather path. Returns [B, 1, H, Dh]."""
+    if use_kernel:
+        from ray_tpu.ops.pallas.paged_attention import paged_attention
+
+        return paged_attention(
+            q, k_pages, v_pages, tables, positions,
+            n_kv_heads=cfg.n_kv_heads, interpret=chip.platform() != "tpu",
+            scale=cfg.attention_scale,
+        )
+    return _gather_page_attention(
+        q, k_pages, v_pages, tables, mask, cfg, cfg.attention_scale
+    )
 
 
 def prefill_program(cfg: NemotronHConfig, n_write_pages: int,
-                    chunk_pages: int, use_kernel: bool | None = None):
+                    chunk_pages: int, use_kernel: bool | None = None,
+                    self_only: bool = False):
     """`_hybrid_prefill` jitted for one shape, under a name that says
     which (``hybrid_prefill_<chunk pages>_of_<table pages>``): a trace
     then names each bucket's program, and an instruction name is looked
-    up in the text of the program it ran in. ``use_kernel`` None: as an
+    up in the text of the program it ran in. A model with a
+    cross-decoder has two programs a shape, and their names say which
+    half of the model they run: ``hybrid_prefill_self_..`` (``self_only``:
+    a chunk that does not hold the prompt's last token) and
+    ``hybrid_prefill_cross_..`` (the chunk that does: the cross-decoder
+    and the head too). ``use_kernel`` None: as an
     engine on this platform says without being told (a bare TPU: the
     kernel), which is what a caller that lowers the programs for their
     text or their fit wants."""
     if use_kernel is None:
         use_kernel = chip.platform() == "tpu"
-    return _prefill_program(cfg, n_write_pages, chunk_pages, bool(use_kernel))
+    if self_only and cfg.cross_from is None:
+        raise ValueError("self_only: the model has no cross-decoder")
+    return _prefill_program(
+        cfg, n_write_pages, chunk_pages, bool(use_kernel), bool(self_only)
+    )
 
 
 @functools.lru_cache(maxsize=None)
 def _prefill_program(cfg, n_write_pages: int, chunk_pages: int,
-                     use_kernel: bool):
+                     use_kernel: bool, self_only: bool = False):
     def program(params, tokens, cache, pages, start, slot, length):
         return _hybrid_prefill(
             params, tokens, cache, pages, start, slot, length, cfg,
-            n_write_pages, chunk_pages, use_kernel,
+            n_write_pages, chunk_pages, use_kernel, self_only,
         )
 
-    program.__name__ = f"hybrid_prefill_{chunk_pages}_of_{n_write_pages}"
+    half = ""
+    if cfg.cross_from is not None:
+        half = "self_" if self_only else "cross_"
+    program.__name__ = f"hybrid_prefill_{half}{chunk_pages}_of_{n_write_pages}"
     return jax.jit(program, donate_argnames=("cache",))
 
 
@@ -706,11 +916,12 @@ def hybrid_decode(
     # Chosen by the platform alone, as `moe_ffn` chooses its kernels.
     live = live_order(active) if chip.platform() == "tpu" else None
     seen = dict.fromkeys(cfg.block_kinds, 0)  # blocks of each kind so far
+    memory = None  # the last `memory` block's y, [B, d_inner]
     for kind, p in zip(cfg.pattern, params["blocks"], strict=True):
         if kind in _RECURRENT:
             block, at = _RECURRENT[kind], seen[kind]
             h, mix = _read(x, p, cfg)
-            u = rms_norm(h, p["norm"], cfg.norm_eps)[:, 0]
+            u = _norm(h, p, cfg)[:, 0]
             old_c = state[block.conv][at]
             if live is not None:
                 out, state[block.state], new_c = block.step_live(
@@ -723,6 +934,8 @@ def hybrid_decode(
                     state[block.state] = state[block.state].at[at].set(
                         jnp.where(active[:, None, None, None], new_s, old_s)
                     )
+            if block.memory:
+                out, memory = out
             # The write back is the state update's other half: under
             # its scope, so that its time is read with it.
             with jax.named_scope(f"{block.scope}:update"):
@@ -730,6 +943,19 @@ def hybrid_decode(
                     jnp.where(active[:, None, None], new_c, old_c)
                 )
             x = _residual(x, out[:, None], cfg, mix)
+        elif kind == "U":
+            x = _residual(x, gmu(x, memory[:, None], p, cfg), cfg)
+        elif kind == "C":
+            # Every slot's query over its pages of the `*` block's pool
+            # layer (base 0), the step's own cell among them: that
+            # block wrote it. Nothing is written here.
+            q, _, _, _ = _attention_inputs(x, p, cfg, None, kind)
+            with _full_scope(cfg, "cross"):
+                attn = _attend_pages(
+                    q, k_pages, v_pages, geometry[4], geometry[1], positions,
+                    cfg, use_kernel,
+                )
+            x = _residual(x, _attention_output(attn, None, p, cfg), cfg)
         elif kind == "E":
             x = _experts(x, p, cfg, active, record)
         elif kind == "D":
@@ -763,16 +989,22 @@ def hybrid_decode(
             q, k, v, gate = _attention_inputs(
                 x, p, cfg, positions[:, None], kind
             )
-            attn, state["win_k"], state["win_v"] = _window_decode(
-                q, k, v, state["win_k"], state["win_v"], seen[kind],
-                positions, active, cfg,
-            )
+            if cfg.ring_pages:
+                attn, state["win_k"], state["win_v"] = _window_decode_pages(
+                    q, k, v, state["win_k"], state["win_v"], seen[kind],
+                    positions, active, cfg, use_kernel,
+                )
+            else:
+                attn, state["win_k"], state["win_v"] = _window_decode(
+                    q, k, v, state["win_k"], state["win_v"], seen[kind],
+                    positions, active, cfg,
+                )
             x = _residual(x, _attention_output(attn, gate, p, cfg), cfg)
         else:
             q, k, v, gate = _attention_inputs(
                 x, p, cfg, positions[:, None]
             )  # [B, 1, H, Dh]
-            with _full_scope(cfg):
+            with _full_scope(cfg, "self"):
                 attn, k_pages, v_pages = _decode_attention(
                     q, k.astype(cfg.dtype), v.astype(cfg.dtype), k_pages,
                     v_pages, seen[kind] * num_pages, geometry, positions, cfg,
@@ -783,7 +1015,7 @@ def hybrid_decode(
     logits = _logits(x, params, cfg)  # [B, 1, V]
     sampled = _sample_tokens(logits, temperature, rng_key)
     carried = _carried(cache, k_pages, v_pages, state)
-    return sampled, logits[:, 0], carried, _record(record)
+    return sampled, logits[:, 0], carried, _record_or_none(record)
 
 
 def _band_pairs_before(m: int, w: int) -> int:
@@ -808,6 +1040,10 @@ class HybridServing(Serving):
         self._window_pairs = self._window_bytes = 0
         self._index_pairs = self._selected_pairs = self._causal_pairs = 0
         self._latent_bytes = self._index_bytes = self._cells_expanded = 0
+        # A model with a cross-decoder (`cfg.cross_from`).
+        self._self_only_chunks = self._cross_rows = 0
+        self._shared_kv_bytes = self._decode_steps = 0
+        self._kv_token_bytes = 0
 
     def init_cache(self, num_pages: int, page_size: int, max_batch: int,
                    shardings=None):
@@ -815,6 +1051,10 @@ class HybridServing(Serving):
         self._window_bytes = leaf_bytes(cache, ("win_k", "win_v", "win_cells"))
         self._latent_bytes = leaf_bytes(cache, ("latent", "cells"))
         self._index_bytes = leaf_bytes(cache, ("index", "index_tail"))
+        # One token's keys and values in one pool layer.
+        self._kv_token_bytes = leaf_bytes(cache, ("k", "v")) // max(
+            cache["k"].shape[0] * num_pages * page_size, 1
+        )
         return cache
 
     def counters(self) -> dict:
@@ -824,7 +1064,9 @@ class HybridServing(Serving):
         # ran in `ops/pallas/gdn_chunk.py` (all of them on a TPU, none
         # off it: `gdn_chunked` asks the platform and nothing else);
         # the causal (query, key) pairs the attention's arithmetic needed, summed over the blocks that
-        # attend the whole context (`LlamaServing` counts the same) and,
+        # attend the whole context (`LlamaServing` counts the same; a
+        # program that stops before a cross-decoder attends nothing
+        # there, `_count`) and,
         # apart, over the window blocks, where a query at position t
         # needs min(t + 1, W) keys, and the live tokens those blocks
         # took. `window_bytes`: what of the cache's per-slot bytes (the
@@ -864,7 +1106,9 @@ class HybridServing(Serving):
                 if self.cfg.hc_mult else 0
             ),
             "prefill_programs": self._prefill_programs,
-            "ssm_scan_tokens": self.cfg.count("M") * self._live_tokens,
+            "ssm_scan_tokens": (
+                self.cfg.count("M") + self.cfg.count("S")
+            ) * self._live_tokens,
             "gdn_scan_tokens": gdn_tokens,
             "gdn_kernel_tokens": gdn_tokens if on_tpu else 0,
             "prefill_attn_pairs": self._prefill_pairs,
@@ -879,16 +1123,41 @@ class HybridServing(Serving):
             # `ops/pallas/state_step.py` over the decoding slots on a
             # TPU, XLA's masked form elsewhere.
             out["state_step_kernel"] = on_tpu
+        if readers := self.cfg.count("C"):
+            # A cross-decoder: the prefill programs that stopped before
+            # it; the rows that went through it (one a prompt, one a
+            # decoding slot a step); how many blocks attend the ONE pool
+            # layer (the block that writes it and the cross blocks), and
+            # the bytes of it they have to read, all together, over the
+            # decode steps and as one step's mean.
+            out.update(
+                prefill_self_only_chunks=self._self_only_chunks,
+                cross_decoder_rows=self._cross_rows,
+                shared_kv_reads=readers + 1,
+                shared_kv_bytes=self._shared_kv_bytes,
+                shared_kv_bytes_per_decode_step=(
+                    self._shared_kv_bytes / self._decode_steps
+                    if self._decode_steps else 0.0
+                ),
+            )
         return out
 
     def _count(self, start: int, width: int, length: int, table: int,
-               use_kernel: bool) -> None:
+               use_kernel: bool, self_only: bool = False) -> None:
         n = max(min(width, length - start), 0)
         self._prefill_programs += 1
         self._live_tokens += n
-        self._prefill_pairs += (self.cfg.count("*") + self.cfg.count("A")) * (
-            n * start + n * (n + 1) // 2
-        )
+        if self_only:
+            # The program stops before the cross-decoder, and the full
+            # layer is the self-decoder's last: nothing reads what its
+            # attention would add to the stream, so the compiler drops
+            # the attend (the layer's keys and values are written).
+            self._self_only_chunks += 1
+        else:
+            full = self.cfg.count("*") + self.cfg.count("A")
+            self._prefill_pairs += full * (n * start + n * (n + 1) // 2)
+            if self.cfg.count("C"):
+                self._cross_rows += 1
         if rings := self.cfg.count("R"):
             self._cells_expanded += rings * (self.cfg.sliding_window + width)
         if full := self.cfg.count("A"):
@@ -913,15 +1182,39 @@ class HybridServing(Serving):
     def prefill_chunk(self, params, tokens, cache, pages, start, *,
                       n_write_pages, chunk_pages, slot, length,
                       use_kernel=False):
+        # A model with a cross-decoder: which half of it this chunk
+        # needs is decided here, from where the chunk lies; the engine
+        # reads the logits of a prompt's last chunk alone.
+        self_only = (
+            self.cfg.cross_from is not None
+            and int(start) + tokens.shape[1] < length
+        )
         self._count(
             int(start), tokens.shape[1], length,
             n_write_pages * tokens.shape[1] // chunk_pages, use_kernel,
+            self_only,
         )
         return prefill_program(
-            self.cfg, n_write_pages, chunk_pages, use_kernel
+            self.cfg, n_write_pages, chunk_pages, use_kernel, self_only
         )(
             params, tokens, cache, pages, start, np.int32(slot),
             np.int32(length),
+        )
+
+    def decode(self, params, tokens, cache, block_tables, positions,
+               temperature, rng_key, *, use_kernel, stochastic, active):
+        if readers := self.cfg.count("C"):
+            live = np.asarray(active, bool)
+            self._decode_steps += 1
+            self._cross_rows += int(live.sum())
+            # Each decoding slot's context, its new token included.
+            self._shared_kv_bytes += (readers + 1) * self._kv_token_bytes * int(
+                (np.asarray(positions)[live] + 1).sum()
+            )
+        return super().decode(
+            params, tokens, cache, block_tables, positions, temperature,
+            rng_key, use_kernel=use_kernel, stochastic=stochastic,
+            active=active,
         )
 
     def _decode_one(self, *args, **kwargs):

@@ -148,6 +148,17 @@ class NemotronHConfig:
     # the residual path (`models/motif.py`; None, here and in the
     # families above: nothing of it in a program).
     hidden_clamp: float | None = None
+    # What a family of LayerNorms and paired attention heads adds
+    # (`models/phi4_flash.py`), off here and in the families above, whose
+    # programs hold none of it: every norm a LayerNorm with a bias, and
+    # attention whose heads pair up and subtract one map from the other.
+    layer_norm: bool = False
+    differential: bool = False
+    # Window rings held as pages of the pool's page size in the pool's
+    # cell layout, written and attended by the pool's kernels
+    # (`llm/hybrid_kv.py`): for a family whose KV heads do not fill a
+    # tile's rows.
+    ring_pages: bool = False
     # Identity outputs behind the router's experts (`moe.MoEConfig`):
     # none in any family served through this class.
     zero_experts: int = 0
@@ -184,6 +195,13 @@ class NemotronHConfig:
 
     def count(self, kind: str) -> int:
         return self.pattern.count(kind)
+
+    @property
+    def cross_from(self) -> int | None:
+        """The first sublayer of a cross-decoder, whose blocks write
+        nothing to the cache (`models/phi4_flash.py`); None: no family
+        above has one."""
+        return None
 
     def serving(self):
         """What `LLMEngine` serves this model through: its cache and its

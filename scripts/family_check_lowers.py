@@ -31,6 +31,14 @@ Motif-3-Beta's (`benchmarks/reference_motif.py`), ~20 s a switch on the
         --config motif3beta-serve1 --seed 7 --skip-whole --skip-long --lower none \
         weights_e4m3 no_noise lambda_const window_as_full polynorm_as_silu \
         static_h router_bf16
+
+Phi-4-mini-flash-reasoning's (`benchmarks/reference_phi4flash.py`), the
+9,000-token prompt alone:
+
+    chiprun --timeout 3000 -- python scripts/family_check_lowers.py \
+        --config phi4miniflash-serve1 --seed 7 --skip-whole --skip-long --lower none \
+        weights_e4m3 state_bf16 lam_const window_511 memory_after_gate \
+        cross_own_keys
 """
 
 import argparse

@@ -13,7 +13,8 @@ experts (ops/pallas/expert_rows.py), the sorted form's combine and its
 grouped matmuls are compiled at the served widths, the decode step's
 state kernel (ops/pallas/state_step.py) at the three served stacks, the
 gated delta rule's chunk kernel at its served shape, Mamba-2's chunked
-scan (ops/pallas/ssd_chunk.py) at Granite's and Nemotron's. The band kernel
+scan (ops/pallas/ssd_chunk.py) at Granite's and Nemotron's, Mamba-1's two
+kernels (ops/pallas/selective_scan.py) at Phi-4-mini-flash's. The band kernel
 (ops/pallas/window_attention.py) is compiled at 72 query heads over 8 KV
 heads and the prefill and paged kernels at 48 over 8 (groups of 9 and
 6). For GLM-5.3-Flash (models/glm5_next.py): the state kernel's third
@@ -382,6 +383,48 @@ def test_ssd_chunk_kernel_compiles_for_v5e_at_the_served_shapes(v5e, case):
         on_chip(h, p, n), on_chip(dtype=jnp.int32),
     ).compile()
     text = compiled.as_text()
+    assert len(_kernel_calls_under(text, "")) == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**20
+
+
+@pytest.mark.parametrize("case", ["chunk", "step"])
+def test_selective_scan_kernels_compile_for_v5e_at_the_served_shapes(v5e, case):
+    """Mamba-1's two kernels (ops/pallas/selective_scan.py) at
+    phi4miniflash-serve1's shapes: a 2,048-token chunk of 5,120 channels
+    and 16 state indices (x, dt and y bfloat16 in [T, 40, 128] views, B
+    and C a block of scalars in SMEM, the state a VMEM scratch) inside
+    the VMEM the call asks for; and the decode step's update of 32 slots
+    in the nine layers' stack, the stack aliased to the result and
+    copied nowhere."""
+    from ray_tpu.ops.pallas import selective_scan
+
+    t, width, n, rows, slots, layers = 2048, 5120, 16, 40, 32, 9
+
+    def on_chip(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    bf16 = partial(on_chip, dtype=jnp.bfloat16)
+    if case == "chunk":
+        compiled = jax.jit(selective_scan.selective_scan_chunk).lower(
+            bf16(t, width), bf16(t, width), bf16(t, n), bf16(t, n),
+            on_chip(n, rows, 128), on_chip(width), on_chip(width),
+            on_chip(n, rows, 128), on_chip(dtype=jnp.int32),
+        ).compile()
+        text = compiled.as_text()
+        # Nothing of [T, N, d_inner] beside the kernel.
+        assert f"[{t},{n}," not in text.replace(f"[{t},{n}]", "")
+    else:
+        stack = (layers, slots, n, rows, 128)
+        compiled = jax.jit(
+            selective_scan.selective_state_step, donate_argnums=0
+        ).lower(
+            on_chip(*stack), on_chip(dtype=jnp.int32),
+            on_chip(slots, dtype=jnp.int32), on_chip(1, dtype=jnp.int32),
+            on_chip(slots, width), on_chip(slots, width), on_chip(slots, n),
+            on_chip(slots, n), on_chip(n, rows, 128),
+        ).compile()
+        text = compiled.as_text()
+        assert _copies_of(text, stack) == []
     assert len(_kernel_calls_under(text, "")) == 1
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**20
 

@@ -31,7 +31,9 @@ longcat-flash-omni-serve1's own programs at one double layer (two
 latent-attention sublayers at 64 heads, two dense FFNs, the shortcut's
 experts behind a 768-wide router) with the whole configuration's pages:
 both latent kernels twice a layer, the identity outputs' sum no kernel's
-work.
+work. phi4miniflash-serve1's own programs at 8 layers split as the model
+splits its 32 (every letter) with the whole configuration's pages and
+slots: both chunk programs and the decode program.
 """
 
 import math
@@ -1106,6 +1108,106 @@ def test_motif_program_moves_no_pool_ring_or_stack_and_fits(
     assert abs(arguments - counted) < 3.2e7
     assert arguments > 0.25 * 16 * 2**30  # the floor a new cell is held to
     assert arguments + memory.temp_size_in_bytes < 15.75 * 2**30
+
+
+@pytest.fixture(scope="module")
+def phi4flash_programs(v5e):
+    """phi4miniflash-serve1's own sizes (benchmarks/configs) at 8 of its
+    32 layers, split as the model splits them (Mamba, window, Mamba, the
+    full layer; GMU, cross, GMU, cross: every letter), with the whole
+    configuration's pages and slots: what `aot_fit_serve_family` lowers,
+    BOTH chunk programs at the narrowest and the widest table of the
+    mix. Compiled when first asked for."""
+    import json
+
+    from benchmarks import aot_fit_serve_family
+    from ray_tpu._private import chip
+
+    here = os.path.join(os.path.dirname(__file__), "..", "benchmarks")
+    with open(os.path.join(here, "configs", "phi4miniflash-serve1.json")) as f:
+        whole = json.load(f)
+    conf = {**whole, "num_hidden_layers": 8, "mb_per_layer": 0}
+    traffic = {"fit_prefill_buckets": [4096, 26112]}
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(chip, "platform", lambda: "tpu")
+        lowered = aot_fit_serve_family.lowered_programs(
+            conf, traffic, next(iter(v5e.device_set))
+        )
+    compiled = {}
+
+    def program(name):
+        if name not in compiled:
+            compiled[name] = lowered[name].compile()
+        return compiled[name]
+
+    return whole, program
+
+
+@pytest.mark.parametrize(
+    "program",
+    ["prefill_chunk_2048_of_4096_self", "prefill_chunk_2048_of_26112",
+     "decode"],
+)
+def test_phi4flash_program_moves_no_pool_ring_or_state_and_stops_halfway(
+    phi4flash_programs, program
+):
+    """Through the same `llm/hybrid_kv.py` with the letters `S`, `U` and
+    `C`: the donated cache updated in place (the ONE pool layer, the
+    rings as a second small pool, Mamba's state stack); the scan and the
+    state update by `ops/pallas/selective_scan.py`, with nothing of
+    shape [tokens, 16, 5,120] beside them; the program that stops before
+    the cross-decoder holds none of its scopes and none of its weights
+    (nor the full layer's own attention: its output is read by nobody);
+    the one that runs it attends the pool once more a cross layer, on
+    one row; the decode program attends the pool once a block that reads
+    it and the rings by the pool's own kernels."""
+    conf, compiled_program = phi4flash_programs
+    eng = conf["engine"]
+    pages, slots, w = eng["num_pages"] + 1, eng["max_batch"], conf["sliding_window"]
+    chunk, wide, n = eng["prefill_chunk"], 2 * conf["hidden_size"], 16
+    ring_pages = slots * (w // PAGE) + 1
+    shapes = {
+        "pool": ((10, PAGE, 128), pages * 10 * PAGE * 128),
+        "rings": ((10, PAGE, 128), ring_pages * 10 * PAGE * 128),
+        "state": ((n, wide // 128, 128), slots * n * wide),
+    }
+    compiled = compiled_program(program)
+    text = compiled.as_text()
+    assert _hybrid_moves(text, shapes) == []
+    memory = compiled.memory_analysis()
+    if program == "decode":
+        # Layer 3's write and attend and two cross attends of the pool;
+        # the window layer's write and attend of its ring.
+        assert len(_kernel_calls_under(text, "attn:full/self")) == 2
+        assert len(_kernel_calls_under(text, "attn:full/cross")) == 2
+        assert len(_kernel_calls_under(text, "attn:window")) == 2
+        assert len(_kernel_calls_under(text, "ssm:update")) == 2
+        assert "jit(selective_state_step)" in text
+        assert memory.temp_size_in_bytes < 2**27
+        return
+    assert len(_kernel_calls_under(text, "ssm:scan")) == 2
+    assert "jit(selective_scan_chunk)" in text
+    for shape in (f"[{chunk},{n},{wide}]", f"[{chunk},{wide},{n}]",
+                  f"[{chunk},{n},{wide // 128},128]"):
+        assert shape not in text
+    assert len(_kernel_calls_under(text, "attn:window")) == 1
+    assert "attn:full/self/scatter" in text  # the chunk's pages, written
+    table = int(program.split("_of_")[1].split("_")[0])
+    assert f"[40,{chunk},{table}]" not in text  # no score over the table
+    cross = [scope for scope in ("attn:full/cross", "gmu:gate", "gmu:out")
+             if scope in text]
+    if program.endswith("_self"):
+        assert cross == [] and f"[1,1,{conf['vocab_size']}]" not in text
+        # Nothing reads what the full layer's attention and its MLP
+        # would add to the stream, so the compiler drops both: the
+        # layer's keys and values are all this program needs of it.
+        assert _kernel_calls_under(text, "attn:full") == []
+    else:
+        assert len(cross) == 3
+        assert "jit(prefill_attention)" in text
+        assert len(_kernel_calls_under(text, "attn:full/self")) == 1
+        assert len(_kernel_calls_under(text, "attn:full/cross")) == 2
+    assert memory.temp_size_in_bytes < 2**29
 
 
 @pytest.mark.parametrize("family", ["hybrid", "granite", "qwen3next", "laguna"])
